@@ -5,6 +5,7 @@ backoff), usecases/memwatch/monitor CheckAlloc semantics, monitoring
 registry exposition.
 """
 
+import os
 import time
 
 import pytest
@@ -240,3 +241,52 @@ def test_metrics_depth_exposed(tmp_path):
     assert "weaviate_tpu_vector_index_compressed" in body
     assert "weaviate_tpu_lsm_memtable_bytes" in body
     db.close()
+
+
+# -- persistent compile cache placement ---------------------------------------
+
+
+@pytest.fixture
+def fresh_compile_cache(monkeypatch):
+    """``ensure_compile_cache`` with its once-flag reset and jax's cache
+    config restored afterwards."""
+    import jax
+
+    from weaviate_tpu.runtime import compile_cache
+
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setattr(compile_cache, "_done", False)
+    yield compile_cache
+    jax.config.update("jax_compilation_cache_dir", prev_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
+
+
+def test_compile_cache_env_set_code_sets_no_dir(fresh_compile_cache,
+                                                monkeypatch, tmp_path):
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    fresh_compile_cache.ensure_compile_cache()
+    # jax read the variable itself at import; the code touched nothing
+    assert jax.config.jax_compilation_cache_dir == before
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_compile_cache_unset_uses_fixed_checkout_path(fresh_compile_cache,
+                                                      monkeypatch):
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seen = []
+    for _ in range(2):
+        monkeypatch.setattr(fresh_compile_cache, "_done", False)
+        fresh_compile_cache.ensure_compile_cache()
+        seen.append(fresh_compile_cache.cache_dir())
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert seen == [os.path.join(checkout, ".cache", "jax")] * 2
+    # nothing of the user, the process or the clock in the path
+    assert str(os.getpid()) not in seen[0].replace(checkout, "")
+    assert "tmp" not in seen[0].replace(checkout, "")

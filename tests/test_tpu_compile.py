@@ -1,0 +1,180 @@
+"""Ask the v5e's compiler, without a chip, whether the served path's kernels
+compile at real widths.
+
+The only file in the repo that describes a TPU. Everything that touches the
+described topology lives inside the module-scoped fixtures below — nothing
+at import, not ``autouse``, not in ``conftest.py`` — because only one
+process may hold the TPU library and every xdist worker imports every test
+file. A compile that passes here is not a chip run: nothing executes, no
+result or time is checked. ``chip_smoke.py`` is the chip run.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from weaviate_tpu.ops import pallas_kernels as pk
+
+N = 1 << 20  # 1M corpus rows: the served flat collection's scale
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable is written to the persistent cache but
+    # cannot be read back without a chip; keep these compiles out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes):
+    """``jit(fn).lower(shapes).compile()`` with every shape on ``sharding``;
+    raises what the chip's compiler would raise."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("d", [128, 1536])
+def test_distance_block(one_chip, dtype, d):
+    fn = functools.partial(pk.distance_block, metric="l2-squared",
+                           interpret=False)
+    _assert_kernel(_compile(fn, one_chip, ((64, d), dtype), ((8192, d), dtype)))
+
+
+def test_chunked_topk_approx_1m(one_chip, monkeypatch):
+    from weaviate_tpu.ops.topk import chunked_topk_distances
+
+    # the call's interpret=None asks recommended(); the process sees the CPU,
+    # so steer it here, in the test, to the branch a TPU process takes
+    monkeypatch.setattr(pk, "recommended", lambda: True)
+    fn = functools.partial(chunked_topk_distances, k=10, chunk_size=8192,
+                           metric="l2-squared", use_pallas=True,
+                           selection="approx")
+    c = _compile(lambda q, x, v, n: fn(q, x, valid=v, x_sq_norms=n), one_chip,
+                 ((64, 128), jnp.bfloat16), ((N, 128), jnp.bfloat16),
+                 ((N,), jnp.bool_), ((N,), jnp.float32))
+    _assert_kernel(c)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "allow_bits"])
+@pytest.mark.parametrize("b,k,d", [(8, 10, 128), (64, 100, 128), (64, 10, 768)])
+def test_fused_topk_scan(one_chip, masked, b, k, d):
+    def fn(q, x, v, n, *bits):
+        return pk.fused_topk_scan(q, x, k=k, valid=v, x_sq_norms=n,
+                                  interpret=False,
+                                  allow_bits=bits[0] if bits else None)
+
+    shapes = [((b, d), jnp.bfloat16), ((N, d), jnp.bfloat16),
+              ((N,), jnp.bool_), ((N,), jnp.float32)]
+    if masked:
+        shapes.append(((b, N // 32), jnp.uint32))
+    _assert_kernel(_compile(fn, one_chip, *shapes))
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "allow_bits"])
+@pytest.mark.parametrize("b,d", [(8, 128), (64, 128), (64, 768)])
+def test_bq_scan_reduce(one_chip, masked, b, d):
+    w = d // 32
+
+    def fn(q, x, v, *bits):
+        return pk.bq_scan_reduce(q, x, valid=v, interpret=False,
+                                 allow_bits=bits[0] if bits else None)
+
+    shapes = [((b, w), jnp.uint32), ((N, w), jnp.uint32), ((N,), jnp.bool_)]
+    if masked:
+        shapes.append(((b, N // 32), jnp.uint32))
+    _assert_kernel(_compile(fn, one_chip, *shapes))
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "allow_bits"])
+@pytest.mark.parametrize("b,d", [(8, 128), (64, 128), (64, 768)])
+def test_pq4_scan_reduce(one_chip, masked, b, d):
+    m = d // 4  # 4 dims per segment, the store's pq4 default
+
+    def fn(lut, codes, v, *bits):
+        return pk.pq4_scan_reduce(lut, codes, valid=v, interpret=False,
+                                  allow_bits=bits[0] if bits else None)
+
+    shapes = [((b, m, 16), jnp.float32), ((N, m), jnp.uint8),
+              ((N,), jnp.bool_)]
+    if masked:
+        shapes.append(((b, N // 32), jnp.uint32))
+    _assert_kernel(_compile(fn, one_chip, *shapes))
+
+
+def test_bq_mxu_block(one_chip):
+    fn = functools.partial(pk.bq_mxu_block, interpret=False)
+    _assert_kernel(_compile(fn, one_chip, ((64, 24), jnp.uint32),
+                            ((8192, 24), jnp.uint32)))
+
+
+def test_pq4_lut_block(one_chip):
+    fn = functools.partial(pk.pq4_lut_block, interpret=False)
+    _assert_kernel(_compile(fn, one_chip, ((64, 32, 16), jnp.float32),
+                            ((8192, 32), jnp.uint8)))
+
+
+def test_fused_topk_pairs(one_chip):
+    fn = functools.partial(pk.fused_topk_pairs, k=100, interpret=False)
+    _assert_kernel(_compile(fn, one_chip, ((64, 16384), jnp.float32),
+                            ((64, 16384), jnp.int32)))
+
+
+def test_bm25_block(one_chip):
+    b, s, t, c = 32, 8, 4, 8192
+    fn = functools.partial(pk.bm25_block, interpret=False)
+    f32 = jnp.float32
+    _assert_kernel(_compile(
+        fn, one_chip,
+        ((b, s, c), f32), ((b, s, c), f32), ((b, s), jnp.int32),
+        ((b, s), f32), ((b, s), f32), ((b, t), f32),
+        ((b,), f32), ((b,), f32), ((b,), f32), ((b, c // 32), jnp.uint32)))
+
+
+def test_sharded_topk_four_chips(topo, monkeypatch):
+    from weaviate_tpu.parallel.mesh import SHARD_AXIS
+    from weaviate_tpu.parallel.sharded_search import _sharded_topk_jit
+
+    monkeypatch.setattr(pk, "recommended", lambda: True)
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+    rows = NamedSharding(mesh, PartitionSpec(SHARD_AXIS))
+    repl = NamedSharding(mesh, PartitionSpec())
+    n = 4 * N
+    sds = jax.ShapeDtypeStruct
+    c = _sharded_topk_jit.lower(
+        sds((64, 128), jnp.bfloat16, sharding=repl),
+        sds((n, 128), jnp.bfloat16, sharding=rows),
+        sds((n,), jnp.bool_, sharding=rows),
+        sds((n,), jnp.float32, sharding=rows),
+        k=10, chunk_size=8192, metric="l2-squared", mesh=mesh,
+        use_pallas=True, selection="approx").compile()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text

@@ -61,7 +61,7 @@ _UNIT_HELP_RE = re.compile(
 #: bench/trace timing fields with an ambiguous or nonstandard unit
 #: suffix — the repo convention is ``<what>_ms``
 _AMBIG_FIELD_RE = re.compile(
-    r"^(wall|host|device|tunnel|e2e|elapsed|dispatch|fetch)"
+    r"^(wall|host|device|e2e|elapsed|dispatch|fetch)"
     r"_(s|sec|secs|seconds|millis|milliseconds|time|duration)$")
 #: device-attributed timing aliases that fork the ``device_ms`` schema
 _DEVICE_ALIAS_RE = re.compile(r"^(dev_ms|device_time_ms|device_timing_ms)$")

@@ -68,19 +68,9 @@ from weaviate_tpu.runtime.transfer import DeviceResultHandle
 _SUPPORTED_METRICS = ("l2-squared", "dot", "cosine", "cosine-dot")
 
 
-@functools.lru_cache(maxsize=1)
-def _dummy_bits_cached():
-    return jnp.zeros((1, _MASK_WORDS), dtype=jnp.uint32)
-
-
 def _dummy_bits():
     """Placeholder ``allow_bits`` operand for ``use_allow=False`` probe
-    variants: one cached buffer so repeated unfiltered searches reuse the
-    same device constant instead of uploading a fresh dummy per call.
-    Under an active trace the cache must be bypassed — caching the
-    tracer would poison every later eager caller."""
-    if jax.core.trace_state_clean():
-        return _dummy_bits_cached()
+    variants (64 bytes of zeros; never read)."""
     return jnp.zeros((1, _MASK_WORDS), dtype=jnp.uint32)
 
 

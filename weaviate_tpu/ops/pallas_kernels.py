@@ -59,11 +59,10 @@ _SUBLANE = 8  # f32 sublane count: second-to-last dim multiple.
 
 
 def recommended() -> bool:
-    """True when compiled Pallas kernels should be used (TPU backend)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when compiled Pallas kernels should be used (TPU backend).
+    A backend that fails to initialise raises — it is an error, not a
+    reason to serve from the interpreter."""
+    return jax.default_backend() == "tpu"
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -175,9 +174,10 @@ def allow_bits_for_ids(bits: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
 
 
 def _fit_mask_words(allow_bits, b_pad: int, n_cols: int):
-    """Pad/slice packed words to [b_pad, n_cols // 32] int32 (Mosaic wants
-    signed lanes; bit extraction is sign-agnostic). Padding rows/columns
-    are zeros = disallowed, matching the dead-row masking."""
+    """Pad/slice packed words [B, >= n_cols // 32] to whole blocks
+    [b_pad, n_cols // MASK_BLOCK, 16] int32 (Mosaic wants signed lanes;
+    bit extraction is sign-agnostic). Padding rows/columns are zeros =
+    disallowed, matching the dead-row masking."""
     wn = n_cols // 32
     ab = jnp.asarray(allow_bits)
     if ab.shape[1] < wn:
@@ -188,7 +188,25 @@ def _fit_mask_words(allow_bits, b_pad: int, n_cols: int):
         ab = jnp.pad(ab, ((0, b_pad - ab.shape[0]), (0, 0)))
     if ab.dtype == jnp.uint32:
         ab = jax.lax.bitcast_convert_type(ab, jnp.int32)
-    return ab.astype(jnp.int32)
+    return ab.astype(jnp.int32).reshape(
+        b_pad, n_cols // MASK_BLOCK, _MASK_WORDS)
+
+
+def _block_major_mask(allow_bits, b_pad: int, n_cols: int):
+    """The scan kernels' mask operand: BLOCK-MAJOR [n_blocks, b_pad, 16].
+    A grid step and the subtile loop then index whole packed blocks on
+    the LEADING axis — the chip's compiler refuses both a 16-lane block
+    of a wider array and a dynamic lane offset it cannot prove
+    128-aligned, while the trailing (b_pad, 16) dims here are always the
+    full array dims. One XLA transpose of B * N / 8 bytes per call."""
+    return jnp.swapaxes(_fit_mask_words(allow_bits, b_pad, n_cols), 0, 1)
+
+
+def _mask_block_spec(blocks: int, b: int):
+    """BlockSpec of ``blocks`` consecutive packed blocks per grid step
+    over a ``_block_major_mask`` operand."""
+    return pl.BlockSpec((blocks, b, _MASK_WORDS), lambda i: (i, 0, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _mask_unpack_block(mw, interpret: bool):
@@ -202,18 +220,12 @@ def _mask_unpack_block(mw, interpret: bool):
     return jax.lax.shift_right_logical(rep, shift) & 1
 
 
-def _mask_unpack_cols(mw, cols: int, interpret: bool):
-    """Unpack ``cols`` columns (a 512-multiple) from words [B, cols//32]:
+def _mask_unpack_blocks(mw, interpret: bool):
+    """Unpack whole blocks [nb, B, W] int32 -> [B, nb * 32W] 0/1 int32:
     per-block repeat+shift, lane-concat across blocks."""
-    nb = cols // MASK_BLOCK
-    if nb == 1:
-        return _mask_unpack_block(mw, interpret)
-    parts = [
-        _mask_unpack_block(
-            mw[:, i * _MASK_WORDS:(i + 1) * _MASK_WORDS], interpret)
-        for i in range(nb)
-    ]
-    return jnp.concatenate(parts, axis=1)
+    parts = [_mask_unpack_block(mw[i], interpret)
+             for i in range(mw.shape[0])]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 def _distance_kernel(metric: str):
@@ -742,9 +754,10 @@ def _fold_tile_topk(d, tile_ids, cd, ci, k, interpret):
 def _fused_topk_kernel(metric: str, k: int, interpret: bool,
                        masked: bool = False):
     """Distance tile + in-VMEM top-k fold. refs: q [B,d], x [TILE,d],
-    valid [1,TILE] f32, xn [1,TILE] f32, (masked: am [B,TILE/32] i32
-    packed per-query allow words), outs [B,k] f32 / [B,k] i32, scratch
-    carries cd [B,k] f32 / ci [B,k] i32 (persist across the grid)."""
+    valid [1,TILE] f32, xn [1,TILE] f32, (masked: am [TILE/512,B,16] i32
+    block-major packed per-query allow words), outs [B,k] f32 / [B,k]
+    i32, scratch carries cd [B,k] f32 / ci [B,k] i32 (persist across the
+    grid)."""
 
     def kernel(q_ref, x_ref, valid_ref, xn_ref, *refs):
         if masked:
@@ -785,7 +798,7 @@ def _fused_topk_kernel(metric: str, k: int, interpret: bool,
             # per-query allow bitmask, unpacked tile-locally in VMEM and
             # folded into the same validity epilogue — disallowed rows can
             # never enter the carry, exactly like dead rows
-            bits = _mask_unpack_cols(am_ref[:], t, interpret)
+            bits = _mask_unpack_blocks(am_ref[:], interpret)
             ok = jnp.logical_and(ok, bits > 0)
         d = jnp.where(ok, d, jnp.float32(MASKED_DISTANCE))
         base = step * t
@@ -817,9 +830,7 @@ def _fused_topk_tiled(q, x, valid_f, xn, am, metric, k, tile_n, masked,
     ]
     operands = (q, x, valid_f, xn)
     if masked:
-        in_specs.append(
-            pl.BlockSpec((b, tile_n // 32), lambda i: (0, i),
-                         memory_space=pltpu.VMEM))
+        in_specs.append(_mask_block_spec(tile_n // MASK_BLOCK, b))
         operands = operands + (am,)
     return pl.pallas_call(
         _fused_topk_kernel(metric, k, interpret, masked),
@@ -932,7 +943,7 @@ def fused_topk_scan(
         xn = jnp.pad(x_sq_norms.astype(jnp.float32), (0, pn - n))
 
     am = (None if allow_bits is None
-          else _fit_mask_words(allow_bits, pb, pn))
+          else _block_major_mask(allow_bits, pb, pn))
     out_d, out_i = _fused_topk_tiled(
         q, x, valid_f[None, :], xn[None, :], am, metric, k, tile_n,
         allow_bits is not None, interpret)
@@ -1056,9 +1067,9 @@ def _bq_scan_kernel(qmat_ref, x_ref, bias_ref, *refs,
     [1, ST] int32. Emits packed int32 [B, ST/L]; driver unpacks
     vals = packed >> 6 (+qpop) and ids = (packed & 63)*out_w + column.
 
-    With ``masked``, an extra [B, ST/32] int32 ref carries per-query
-    packed allow words (pack_allow_bitmask layout); disallowed slots are
-    forced to INT32_MAX before the strided min so they can never win.
+    With ``masked``, an extra [ST/512, B, 16] int32 ref carries per-query
+    packed allow words (_block_major_mask); disallowed slots are forced
+    to INT32_MAX before the strided min so they can never win.
     """
     if masked:
         am_ref, out_ref = refs
@@ -1088,8 +1099,8 @@ def _bq_scan_kernel(qmat_ref, x_ref, bias_ref, *refs,
         )  # [B, sub] = (hamming - qpop) << 6
         packed = dots + bias_ref[:, pl.ds(j * sub_rows, sub_rows)]
         if masked:
-            mw = am_ref[:, pl.ds(j * (sub_rows // 32), sub_rows // 32)]
-            bits = _mask_unpack_cols(mw, sub_rows, interpret)
+            nb = sub_rows // MASK_BLOCK
+            bits = _mask_unpack_blocks(am_ref[pl.ds(j * nb, nb)], interpret)
             packed = jnp.where(bits > 0, packed,
                                jnp.iinfo(jnp.int32).max)
         for s in range(slices_per_sub):
@@ -1127,9 +1138,7 @@ def _bq_scan_tiled(qmat, x_t, bias, am, supertile, sub_rows, out_w,
     ]
     operands = (qmat, x_t, bias)
     if masked:
-        in_specs.append(
-            pl.BlockSpec((b, supertile // 32), lambda i: (0, i),
-                         memory_space=pltpu.VMEM))
+        in_specs.append(_mask_block_spec(supertile // MASK_BLOCK, b))
         operands = operands + (am,)
     return pl.pallas_call(
         functools.partial(_bq_scan_kernel, w=w, subtiles=subtiles,
@@ -1270,7 +1279,7 @@ def bq_scan_reduce(
     if x_t.dtype == jnp.uint32:
         x_t = jax.lax.bitcast_convert_type(x_t, jnp.int32)
     am = (None if allow_bits is None
-          else _fit_mask_words(allow_bits, pb, pn))
+          else _block_major_mask(allow_bits, pb, pn))
     packed = _bq_scan_tiled(qmat, x_t, bias[None, :], am, supertile,
                             sub_rows, out_w, row_major,
                             allow_bits is not None, interpret)
@@ -1298,8 +1307,8 @@ def _pq4_scan_kernel(lut_ref, c_ref, bias_ref, *refs,
     [m, ST] transposed, bias [1, ST] int32 carrying the strided slice id
     (low 6 bits) and a dead-row offset. One int8 matmul against the
     in-VMEM one-hot gives integer ADC sums; merge is shift + add + min.
-    ``masked``: extra [B, ST/32] int32 ref of per-query packed allow
-    words, applied exactly like _bq_scan_kernel's.
+    ``masked``: extra [ST/512, B, 16] int32 ref of per-query packed
+    allow words, applied exactly like _bq_scan_kernel's.
     """
     if masked:
         am_ref, out_ref = refs
@@ -1329,8 +1338,8 @@ def _pq4_scan_kernel(lut_ref, c_ref, bias_ref, *refs,
         packed = (jax.lax.shift_left(dots, _SCAN_ID_BITS)
                   + bias_ref[:, pl.ds(j * sub_rows, sub_rows)])
         if masked:
-            mw = am_ref[:, pl.ds(j * (sub_rows // 32), sub_rows // 32)]
-            bits = _mask_unpack_cols(mw, sub_rows, interpret)
+            nb = sub_rows // MASK_BLOCK
+            bits = _mask_unpack_blocks(am_ref[pl.ds(j * nb, nb)], interpret)
             packed = jnp.where(bits > 0, packed,
                                jnp.iinfo(jnp.int32).max)
         for s in range(slices_per_sub):
@@ -1370,9 +1379,7 @@ def _pq4_scan_tiled(lut8, codes, bias, am, supertile, sub_rows, out_w,
     ]
     operands = (lut8, codes, bias)
     if masked:
-        in_specs.append(
-            pl.BlockSpec((b, supertile // 32), lambda i: (0, i),
-                         memory_space=pltpu.VMEM))
+        in_specs.append(_mask_block_spec(supertile // MASK_BLOCK, b))
         operands = operands + (am,)
     return pl.pallas_call(
         functools.partial(_pq4_scan_kernel, m=m, subtiles=subtiles,
@@ -1478,7 +1485,7 @@ def pq4_scan_reduce(
         dead = jnp.logical_or(dead, pos >= n)
     bias = slice_id + jnp.where(dead, dead_off << _SCAN_ID_BITS, 0)
     am = (None if allow_bits is None
-          else _fit_mask_words(allow_bits, pb, pn))
+          else _block_major_mask(allow_bits, pb, pn))
     packed = _pq4_scan_tiled(lut8, codes, bias[None, :], am, supertile,
                              sub_rows, out_w, row_major,
                              allow_bits is not None, interpret)
@@ -1539,16 +1546,16 @@ def bq_hamming_block(
 def _bm25_kernel(tf_ref, ln_ref, mw_ref, term_ref, boost_ref, avg_ref,
                  idf_ref, sc_ref, o_ref, *, interpret: bool):
     s = tf_ref.shape[1]        # static: block shapes carry S and T
-    t = idf_ref.shape[1]
+    t = idf_ref.shape[2]
     tf = tf_ref[0]                                     # [S, tile]
     ln = ln_ref[0]
-    k1 = sc_ref[0, 0]
-    bb = sc_ref[0, 1]
-    omb = sc_ref[0, 2]
+    k1 = sc_ref[0, 0, 0]
+    bb = sc_ref[0, 0, 1]
+    omb = sc_ref[0, 0, 2]
     contribs = []
     for si in range(s):
-        norm = omb + (bb * ln[si:si + 1, :]) / avg_ref[0, si]
-        ctb = (boost_ref[0, si] * tf[si:si + 1, :]) \
+        norm = omb + (bb * ln[si:si + 1, :]) / avg_ref[0, 0, si]
+        ctb = (boost_ref[0, 0, si] * tf[si:si + 1, :]) \
             / jnp.maximum(norm, jnp.float32(1e-9))
         # adding exact 0.0 for misses keeps f32 parity with the host's
         # skip-the-miss accumulation (and guards padded segments)
@@ -1557,50 +1564,54 @@ def _bm25_kernel(tf_ref, ln_ref, mw_ref, term_ref, boost_ref, avg_ref,
     for ti in range(t):
         acc = jnp.zeros_like(score)
         for si in range(s):
-            acc = acc + jnp.where(term_ref[0, si] == ti,
+            acc = acc + jnp.where(term_ref[0, 0, si] == ti,
                                   contribs[si], 0.0)
-        score = score + (idf_ref[0, ti] * acc) / (k1 + acc)
-    ok = _mask_unpack_cols(mw_ref[:], score.shape[1], interpret)
-    o_ref[:] = jnp.where(ok > 0, -score, MASKED_DISTANCE)
+        score = score + (idf_ref[0, 0, ti] * acc) / (k1 + acc)
+    ok = _mask_unpack_blocks(mw_ref[:], interpret)
+    o_ref[0] = jnp.where(ok > 0, -score, MASKED_DISTANCE)
 
 
 @functools.partial(
     jax.jit, static_argnames=("s", "t", "tile_c", "interpret"))
 def _bm25_tiled(tf, ln, mw, term, boost, avg, idf, sc, s, t, tile_c,
                 interpret):
+    """Every per-row operand carries a unit middle axis ([B, 1, X]) so a
+    one-row block's trailing dims are the full array dims — the TPU
+    lowering refuses a (1, X) block of a [B, X] array. ``mw`` is
+    [B * C/512, 1, 16]: row i's packed blocks, one after another."""
     b, _, c = tf.shape
-    grid = (b, c // tile_c)
-    return pl.pallas_call(
+    tiles = c // tile_c
+    nb = tile_c // MASK_BLOCK
+
+    def smem(width):
+        return pl.BlockSpec((1, 1, width), lambda i, j: (i, 0, 0),
+                            memory_space=pltpu.SMEM)
+
+    out = pl.pallas_call(
         functools.partial(_bm25_kernel, interpret=interpret),
-        grid=grid,
+        grid=(b, tiles),
         in_specs=[
             pl.BlockSpec((1, s, tile_c), lambda i, j: (i, 0, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, s, tile_c), lambda i, j: (i, 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_c // 32), lambda i, j: (i, j),
+            pl.BlockSpec((nb, 1, _MASK_WORDS),
+                         lambda i, j: (i * tiles + j, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, s), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, s), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, s), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
+            smem(s), smem(s), smem(s), smem(t), smem(4),
         ],
-        out_specs=pl.BlockSpec((1, tile_c), lambda i, j: (i, j),
+        out_specs=pl.BlockSpec((1, 1, tile_c), lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
         cost_estimate=pl.CostEstimate(
             flops=b * c * (4 * s + t * (s + 3)),
             bytes_accessed=2 * tf.size * 4 + b * c * 4 + mw.size * 4,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(tf, ln, mw, term, boost, avg, idf, sc)
+    )(tf, ln, mw, term[:, None, :], boost[:, None, :], avg[:, None, :],
+      idf[:, None, :], sc[:, None, :])
+    return out[:, 0, :]
 
 
 def bm25_block(seg_tf, seg_len, seg_term, seg_boost, seg_avg, idf,
@@ -1623,7 +1634,8 @@ def bm25_block(seg_tf, seg_len, seg_term, seg_boost, seg_avg, idf,
     b_n, s, c = seg_tf.shape
     t = idf.shape[1]
     tile_c = min(tile_c, c)
-    mw = _fit_mask_words(cand_bits, b_n, c)
+    mw = _fit_mask_words(cand_bits, b_n, c).reshape(
+        b_n * (c // MASK_BLOCK), 1, _MASK_WORDS)
     sc = jnp.stack([jnp.asarray(k1, jnp.float32),
                     jnp.asarray(b, jnp.float32),
                     jnp.asarray(omb, jnp.float32),

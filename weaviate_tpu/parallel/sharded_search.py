@@ -45,16 +45,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
-
-try:  # jax >= 0.6: top-level export, replication check renamed check_vma
-    from jax import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental home, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
 
 from weaviate_tpu.ops.pallas_kernels import _MASK_WORDS
 from weaviate_tpu.ops.topk import chunked_topk_distances, topk_smallest
@@ -67,14 +59,6 @@ from weaviate_tpu.parallel.mesh import (
     n_row_shards,
 )
 from weaviate_tpu.runtime import kernelscope, tracing
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable shard_map (the replication-check kwarg moved and
-    the symbol left jax.experimental between the pinned jax releases)."""
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: check_vma})
 
 
 def dcn_compact_default() -> bool:

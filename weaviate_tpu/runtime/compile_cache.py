@@ -2,12 +2,14 @@
 point and the offline tools (bulk builds, benchmarks).
 
 The vector store's pow2 capacity ladder and the bulk-build link pipeline
-re-jit per shape level; each program costs 0.5-20 s to compile (more on a
-remote-compile rig). Two defaults make every process after the first
-start warm:
+re-jit per shape level; each program costs 0.5-20 s to compile. Two
+defaults make every process after the first start warm:
 
-- cache dir in the USER cache location (keys are program + hardware, not
-  instance state), overridable via JAX_COMPILATION_CACHE_DIR
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+  this module sets no directory; otherwise the cache lives at ONE fixed
+  path inside the checkout, ``<checkout>/.cache/jax`` (git-ignored) —
+  the path is part of the cache key, so a directory that moves with the
+  user, a temp name, a pid or a time would never hit
 - persistence threshold 0: jax's default skips sub-1 s compiles, which
   is exactly the population the capacity ladder is made of
 """
@@ -98,34 +100,38 @@ def install_compile_metrics() -> None:
         logger.debug("compile metrics unavailable: %s", e)
 
 
+#: the one in-code cache location: derived from the package's own place
+#: on disk, identical for every process started from this checkout
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache", "jax")
+
+
+def cache_dir() -> str | None:
+    """Directory the persistent cache writes to (None: not persisting)."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or None
+
+
 def ensure_compile_cache() -> None:
-    """Idempotent; call before the first jit dispatch."""
+    """Idempotent; call before the first jit dispatch. A cache that
+    cannot be set up is an error — a silently cold server recompiles its
+    whole ladder on every start."""
     global _done
     if _done:
         return
     _done = True
     install_compile_metrics()
-    try:
-        import jax
+    import jax
 
-        explicit = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-        if not explicit and jax.default_backend() == "cpu":
-            # CPU-platform AOT executables embed the COMPILING machine's
-            # feature set; on rigs where compiles are serviced remotely
-            # the cached artifact can then be loaded on a host missing
-            # those features (observed: +amx entries from the compile
-            # service loaded on a non-amx host — a SIGILL hazard). CPU
-            # compiles are cheap locally; cache only accelerator
-            # programs unless the user opts in with an explicit dir.
-            return
-        if not explicit:
-            cache_root = os.environ.get("XDG_CACHE_HOME") or \
-                os.path.join(os.path.expanduser("~"), ".cache")
-            cache_dir = os.path.join(cache_root, "weaviate-tpu",
-                                     "xla-cache")
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception as e:  # noqa: BLE001 — cache is best-effort
-        logger.warning("compilation cache disabled: %s", e)
+    explicit = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    if not explicit and jax.default_backend() == "cpu":
+        # tier-1 runs six CPU workers at once and must not write a cache
+        # (nor read one another run left behind); CPU compiles are cheap.
+        # Cache only accelerator programs unless the variable opts in.
+        return
+    if not explicit:
+        os.makedirs(CHECKOUT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

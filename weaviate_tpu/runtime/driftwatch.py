@@ -489,7 +489,7 @@ def seal_live_baseline(section: dict, fingerprint: dict) -> dict | None:
             "value": round(float(g_us), 2), "band": _live_band(),
             "direction": "lower", "kind": "device", "unit": "us",
             "reason": "self-sealed sampled-memcpy EWMA — a drift means "
-                      "D2H transfer cost moved (PCIe/tunnel change or "
+                      "D2H transfer cost moved (host-link change or "
                       "attribution bug), which silently skews every "
                       "drain-source residency number",
         })

@@ -1,8 +1,7 @@
 """HBM ledger: host-side accounting of labeled device allocations.
 
 The allocator's own stats (``jax device.memory_stats()``) answer "how
-full is the device" but return ``{}`` on CPU meshes and remote-tunnel
-TPUs — and even where they exist they cannot answer "WHICH collection/
+full is the device" but return ``{}`` on CPU meshes — and even where they exist they cannot answer "WHICH collection/
 shard/tenant owns my HBM". The reference's memwatch (usecases/memwatch/
 monitor.go CheckAlloc) refuses imports *before* allocating; Milvus-style
 quota/segment accounting keeps a host-side ledger per segment. This

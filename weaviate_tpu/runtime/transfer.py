@@ -1,10 +1,9 @@
 """Device-resident result handles and the double-buffered D2H drain.
 
-The serving gap (ROADMAP item 1, BENCH_r04): the device sustains ~450k
-QPS on flat bf16 b=1024 while the served path peaks at ~12k — the
-difference lives in the Python stack, and the single worst offender is
-the synchronous ``np.asarray`` at the end of every search: the dispatch
-thread blocks on the device, the device then idles while Python slices
+The serving gap (ROADMAP item 1): the device scans far faster than the
+served path answers — the difference lives in the Python stack, and the
+single worst offender is the synchronous ``np.asarray`` at the end of
+every search: the dispatch thread blocks on the device, the device then idles while Python slices
 and routes results, and neither side ever overlaps the other.
 
 This module is the fix's substrate (ISSUE 7 tentpole):

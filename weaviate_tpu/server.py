@@ -51,9 +51,16 @@ class Server:
 
         # persistent XLA compilation cache (shared helper — the offline
         # tools and bulk builds need the same warm starts as the server)
-        from weaviate_tpu.runtime.compile_cache import ensure_compile_cache
+        import jax
+
+        from weaviate_tpu.runtime.compile_cache import (cache_dir,
+                                                        ensure_compile_cache)
 
         ensure_compile_cache()
+        devices = jax.devices()
+        logger.info("devices: platform=%s device_kind=%s count=%d "
+                    "compile_cache=%s", devices[0].platform,
+                    devices[0].device_kind, len(devices), cache_dir())
 
         from weaviate_tpu.auth import AuthConfig, AuthStack
         from weaviate_tpu.modules import default_provider
