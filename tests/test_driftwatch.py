@@ -22,7 +22,8 @@ import numpy as np
 import pytest
 
 from weaviate_tpu.db.database import Database
-from weaviate_tpu.runtime import degrade, driftwatch, faultline
+from weaviate_tpu.runtime import (degrade, driftwatch, faultline,
+                                  kernelscope, tailboard)
 from weaviate_tpu.schema.config import CollectionConfig
 
 
@@ -197,44 +198,74 @@ def _searches(shard, n, dim=8, seed=3):
                             .astype(np.float32), 10)
 
 
-def test_injected_dispatch_latency_trips_live_finding(tmp_path):
-    """The e2e incident chain: faultline latency inside
-    ``batcher.dispatch`` inflates the kernelscope residency EWMA past
-    the self-sealed band => typed ``live`` regression finding =>
-    ``drift:live`` unhealthy => flight-recorder snapshot on disk =>
-    disarm + traffic decay clears it all."""
+def _dispatches(n, residency_s, k=64):
+    """``n`` dispatch records with STATED stamps, through the one entry
+    point the batcher's residency goes through (its flight record ->
+    ``kernelscope.fold_dispatch``): the residency a cycle classifies is
+    what the stamps say, whatever the machine's load."""
+    for _ in range(n):
+        rec = tailboard.record_dispatch(
+            "batcher", tailboard.new_dispatch("batcher", "flat"),
+            batch=4, b_pad=4, k=k, queue_depth=0, wait_ms=0.1,
+            stamps={"exec": 50.0, "fetch1": 50.0 + residency_s})
+        kernelscope.fold_dispatch(rec, "drain")
+
+
+def test_injected_dispatch_latency_trips_live_finding(tmp_path, monkeypatch):
+    """The e2e incident chain: dispatch latency inflates the kernelscope
+    residency EWMA past the self-sealed band => typed ``live``
+    regression finding => ``drift:live`` unhealthy => flight-recorder
+    snapshot on disk => clean dispatches decay it and clear it all.
+
+    The residency is fed from the dispatch record with injected stamps
+    (2 ms clean, 32 ms slowed): wall-clock residency of real dispatches
+    converged inside the 75 % band only on an idle machine, and this
+    test runs beside five other workers. The canary is kept out (its
+    probes ride the real batcher on the real clock). That a real
+    ``batcher.dispatch`` latency reaches the record is the last block's,
+    which needs a lower bound only."""
+    monkeypatch.setenv("WEAVIATE_TPU_DRIFT_CANARY_MAX_ROWS", "4")
     db, col = _mk_db(tmp_path)
     try:
-        shard = _shard(col)
-        _searches(shard, 40)              # warm past min-samples AND
-        db.cycles.run_now("driftwatch")   # decay the cold-compile
-                                          # sample out of the EWMA so
-                                          # the convergence guard seals
+        _dispatches(40, 0.002)
+        db.cycles.run_now("driftwatch")
         snap = driftwatch.snapshot()
         assert snap["gateOk"] and snap["live"]["baselineSource"]
 
-        faultline.arm("batcher.dispatch", "latency", latency_s=0.03,
-                      every=1)
-        _searches(shard, 8)
+        _dispatches(8, 0.032)
         db.cycles.run_now("driftwatch")
-        faultline.disarm()
 
         snap = driftwatch.snapshot()
         assert not snap["gateOk"]
         live = [f for f in snap["findings"]
                 if f["leg"] == "live" and f["kind"] == "regression"]
         assert live and live[0]["flips_health"]
+        assert live[0]["key"] == "live:live.residency.flat/b4/k64:regression"
+        # 2 + 30 * (1 - 0.8 ** 8): the EWMA of the stated samples, exactly
+        assert live[0]["baseline"] == 2.0
+        assert live[0]["value"] == pytest.approx(26.9668, abs=1e-3)
         assert not degrade.health()["healthy"]
         assert "drift:live" in degrade.health()["unhealthy"]
         assert glob.glob(str(tmp_path / "flightrecorder" / "flight-*"))
 
-        # heal: clean traffic decays the EWMA back inside the band
-        _searches(shard, 40)
+        # heal: clean dispatches decay the EWMA back inside the band
+        _dispatches(40, 0.002)
         db.cycles.run_now("driftwatch")
         snap = driftwatch.snapshot()
         assert snap["gateOk"], snap["findings"]
         assert degrade.health()["healthy"]
+
+        # and a real injected latency does land in a real dispatch's
+        # record (at least the injected 30 ms, however loaded the box)
+        faultline.arm("batcher.dispatch", "latency", latency_s=0.03,
+                      every=1)
+        _searches(_shard(col), 2)
+        faultline.disarm()
+        real = [r for r in tailboard.debug_flight()["dispatches"]
+                if r.get("k") == 16]
+        assert real and all(r["device_ms"] >= 30.0 for r in real)
     finally:
+        faultline.disarm()
         db.close()
 
 
@@ -277,11 +308,18 @@ def test_sabotaged_id_mapping_trips_canary_recall_finding(tmp_path):
 # -- history ring + offline replay --------------------------------------------
 
 
-def test_history_ring_and_offline_replay(tmp_path):
+def test_history_ring_and_offline_replay(tmp_path, monkeypatch):
     """Every cycle appends one JSONL record under <data_dir>/driftwatch
     and ``python -m tools.driftwatch`` re-classifies them offline
     against the node's sealed baseline with benchkeeper exit-code
-    semantics (0 clean, 1 regressed cycle or open canary finding)."""
+    semantics (0 clean, 1 regressed cycle or open canary finding).
+
+    The residency here is that of real dispatches on the real clock
+    (the canary's probes ride the batcher in every cycle), beside five
+    other workers: the band sealed for THIS test is 20x wide, so a
+    loaded machine's jitter between the two cycles is no regression,
+    and the doctored excursion below is over 100x."""
+    monkeypatch.setenv("WEAVIATE_TPU_DRIFT_LIVE_BAND", "20")
     db, col = _mk_db(tmp_path)
     try:
         shard = _shard(col)
@@ -305,12 +343,12 @@ def test_history_ring_and_offline_replay(tmp_path):
     assert clean.returncode == 0, clean.stdout + clean.stderr
     assert "GATE PASS" in clean.stdout
 
-    # doctor the newest record into a 10x residency excursion: replay
+    # doctor the newest record into a 100x residency excursion: replay
     # must classify it as a regression and exit 1 — triage works from
     # the ring alone, no node required
     doctored = json.loads(json.dumps(records[-1]))
     for v in doctored["live"]["metrics"]["residency"].values():
-        v["ewma_ms"] = (v["ewma_ms"] or 0.0) * 10 + 100.0
+        v["ewma_ms"] = (v["ewma_ms"] or 0.0) * 100 + 1000.0
     with open(hist, "a") as f:
         f.write(json.dumps(doctored) + "\n")
     bad = subprocess.run(

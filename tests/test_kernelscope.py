@@ -182,10 +182,11 @@ def test_drain_attribution_populates_device_phase():
         qb._dispatch([p])
         assert p.event.wait(timeout=10.0)
         assert p.error is None
-        # per-request attribution rode the dispatch back to the waiter
-        assert p.device_source == "drain"
-        assert p.device_s is not None and p.device_s >= 0.03
-        assert p.transfer_s == pytest.approx(0.004)
+        # per-request attribution rode the dispatch's record back to
+        # the waiter (every waiter of one dispatch reads the same record)
+        assert p.rec["t_source"] == "drain"
+        assert p.rec["device_ms"] >= 30.0
+        assert p.rec["transfer_ms"] == pytest.approx(4.0)
     finally:
         qb.stop()
     snap = kernelscope.snapshot()
@@ -446,12 +447,36 @@ def test_g1_baseline_stays_empty_for_dispatch_path():
 # -- face 4: on-demand kernel profiles ----------------------------------------
 
 
+def _meta(kind, pid, name, tid=None):
+    ev = {"ph": "M", "name": kind, "pid": pid, "args": {"name": name}}
+    if tid is not None:
+        ev["tid"] = tid
+    return ev
+
+
+def _x(pid, tid, name, ts, dur):
+    return {"ph": "X", "pid": pid, "tid": tid, "name": name, "ts": ts,
+            "dur": dur}
+
+
+# one device plane (its op line and its module line) and the host's
+# threads in ONE trace, as jax.profiler writes them (ts/dur in us)
 _FAKE_EVENTS = [
-    {"ph": "X", "name": "jit_fused_topk_scan.3", "dur": 1500.0},
-    {"ph": "X", "name": "pq4_lut_matmul", "dur": 800.0},
-    {"ph": "X", "name": "fusion.42_misc", "dur": 100.0},
-    {"ph": "M", "name": "process_name"},  # metadata event: ignored
+    _meta("process_name", 1, "/device:TPU:0"),
+    _meta("thread_name", 1, "XLA Ops", tid=1),
+    _meta("thread_name", 1, "XLA Modules", tid=2),
+    _meta("process_name", 7, "/host:CPU"),
+    _x(1, 1, "jit_fused_topk_scan.3", 0.0, 1500.0),
+    _x(1, 1, "pq4_lut_matmul", 5000.0, 800.0),
+    _x(1, 1, "fusion.42_misc", 5800.0, 100.0),
+    # the module line repeats the ops' time: never counted twice
+    _x(1, 2, "jit_bq_topk(123)", 0.0, 1500.0),
+    # host threads: a lookalike name, and the program's stage annotations
+    _x(7, 30, "jit_fused_topk_scan.3", 0.0, 6000.0),
+    _x(7, 31, "wtpu.rescore", 1500.0, 3000.0),
+    _x(7, 32, "wtpu.idle", 1400.0, 400.0),
 ]
+_FAKE_N = len(_FAKE_EVENTS)
 
 
 def test_capture_ranks_kernels_and_prunes(tmp_path):
@@ -464,7 +489,7 @@ def test_capture_ranks_kernels_and_prunes(tmp_path):
     kernelscope.configure(data_dir=str(tmp_path), keep=2, capturer=fake)
     rec = kernelscope.capture_profile(7)
     assert calls == [7]
-    assert rec["ms"] == 7 and rec["raw_events"] == 4
+    assert rec["ms"] == 7 and rec["raw_events"] == _FAKE_N
     ranked = [(k["kernel"], k["device_ms"]) for k in rec["kernels"]]
     assert ranked == [("fused_topk_scan", 1.5), ("pq4_scan_reduce", 0.8),
                       ("other", 0.1)]
@@ -514,6 +539,38 @@ def test_profile_rest_endpoint(served, tmp_path):
     with pytest.raises(RestError) as e:
         client.request("GET", "/v1/debug/profile?id=cap-0-0")
     assert e.value.status == 404
+
+
+def test_summarize_counts_device_planes_only_and_names_gaps_by_stage():
+    """What the benchmark reads from the .xplane.pb, read from the
+    server: host threads are never device time, busy is the union of the
+    device's op line, the idle share is over the captured window, and
+    the longest gaps carry the ``wtpu.<stage>`` that covers most of
+    each."""
+    out = kernelscope.summarize_trace_events(_FAKE_EVENTS)
+    assert out["device_planes"] == ["/device:TPU:0"]
+    assert out["total_device_ms"] == pytest.approx(2.4)   # no host 6 ms
+    assert out["window_ms"] == pytest.approx(6.0)
+    assert out["busy_ms"] == pytest.approx(2.4)
+    assert out["device_idle_pct"] == pytest.approx(60.0)
+    gaps = {g["stage"]: g["gap_ms"] for g in out["idle_gaps"]}
+    # 1.5..5.0 ms lies under wtpu.rescore (3.0 of 3.5 ms; wtpu.idle only
+    # 0.3), 5.9..6.0 under no stage at all
+    assert gaps == {"wtpu.rescore": pytest.approx(3.5),
+                    "no wtpu stage": pytest.approx(0.1)}
+    assert sum(gaps.values()) == pytest.approx(
+        out["window_ms"] - out["busy_ms"])
+    # nested ops count once in busy (a loop and its body)
+    nested = _FAKE_EVENTS + [_x(1, 1, "while.body", 100.0, 500.0)]
+    assert kernelscope.summarize_trace_events(nested)["busy_ms"] == \
+        pytest.approx(2.4)
+
+
+def test_summarize_a_trace_without_a_device_plane_has_no_device_time():
+    host_only = [e for e in _FAKE_EVENTS if e.get("pid") == 7]
+    out = kernelscope.summarize_trace_events(host_only)
+    assert out["kernels"] == [] and out["total_device_ms"] == 0
+    assert out["device_planes"] == [] and "idle_gaps" not in out
 
 
 def test_summarize_trace_events_tolerates_junk():

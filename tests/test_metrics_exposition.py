@@ -4,6 +4,7 @@ flavor with exemplars, and the label-cardinality guard (ISSUE 15)."""
 
 import re
 import threading
+import time
 
 import pytest
 
@@ -230,6 +231,41 @@ def test_rest_metrics_endpoint_serves_text(tmp_path):
     finally:
         srv.stop()
         db.close()
+
+
+def test_exposition_carries_the_stage_families_and_not_the_old_three():
+    """ISSUE 25: the two stage families are exposed with their label
+    sets; the batcher's three per-request histograms, whose stamps the
+    phase and span series already carried, are gone."""
+    from weaviate_tpu.runtime import tailboard
+    from weaviate_tpu.runtime.metrics import (dispatch_stage_seconds,
+                                              registry,
+                                              request_stage_seconds)
+
+    assert request_stage_seconds.label_names == ("operation", "stage")
+    assert dispatch_stage_seconds.label_names == ("kind", "stage")
+    t = time.perf_counter()
+    with tailboard.request("grpc.search", t_entry=t, t_arrival=t):
+        tailboard.mark("parse")
+    rec = tailboard.new_dispatch("batcher", "flat")
+    tailboard.bind_dispatch(rec, "worker")
+    with tailboard.dispatch_stage("launch"):
+        pass
+    tailboard.unbind_dispatch()
+    tailboard.flush()
+    body = registry.expose()
+    parsed = parse_openmetrics(body)
+    assert parsed["types"]["weaviate_tpu_request_stage_seconds"] == \
+        parsed["types"]["weaviate_tpu_dispatch_stage_seconds"] == \
+        "histogram"
+    seen = {(x["name"], tuple(sorted(x["labels"].items())))
+            for x in parsed["samples"]}
+    assert ("weaviate_tpu_request_stage_seconds_count",
+            (("operation", "grpc.search"), ("stage", "pool_wait"))) in seen
+    assert ("weaviate_tpu_dispatch_stage_seconds_sum",
+            (("kind", "flat"), ("stage", "launch"))) in seen
+    for gone in ("wait", "execute", "transfer"):
+        assert f"weaviate_tpu_query_batcher_{gone}_seconds" not in body
 
 
 def test_machine_id_persists_across_boots(tmp_path):

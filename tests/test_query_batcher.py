@@ -441,3 +441,291 @@ def test_shard_batch_async_merges_queued_tail(tmp_path):
         assert [int(ids_a[r, 0]) for r in range(5)] == list(range(5))
     finally:
         db.close()
+
+
+# -- the dispatch record: one per dispatch, read by every consumer (ISSUE 25) --
+
+
+def _async_batcher(fin_sleep=0.0, kind="flat", gate=None):
+    """Async batcher over a stub program: ``gate`` (an Event) holds the
+    FIRST launch so that what arrives meanwhile coalesces."""
+    launches = []
+
+    def async_fn(queries, k, allow):
+        b = len(queries)
+        launches.append(b)
+        if gate is not None and len(launches) == 1:
+            assert gate.wait(timeout=10.0)
+
+        def fin():
+            time.sleep(fin_sleep)
+            return (np.arange(b * k, dtype=np.int64).reshape(b, k),
+                    np.zeros((b, k), np.float32))
+
+        return DeviceResultHandle((), finish=fin)
+
+    def sync_fn(queries, k, allow):  # pragma: no cover — must not run
+        raise AssertionError("sync path used")
+
+    return QueryBatcher(sync_fn, async_batch_fn=async_fn, kind=kind), \
+        launches
+
+
+def test_coalesced_dispatch_is_one_record_read_by_n_requests():
+    """n waiters of one coalesced dispatch: ONE dispatch record, n
+    request records, and the requests' ``device`` and ``queue_wait`` are
+    read from that record (they agree because there is nothing else to
+    read), one ``wake`` each."""
+    from weaviate_tpu.runtime import tailboard
+
+    n = 5
+    gate = threading.Event()
+    qb, launches = _async_batcher(fin_sleep=0.01, gate=gate)
+    timelines = {}
+
+    def client(i):
+        t = time.perf_counter()
+        with tailboard.request("grpc.search", t_entry=t,
+                               t_arrival=t) as tl:
+            qb.search(np.full(4, float(i), np.float32), 3)
+            timelines[i] = tl
+
+    try:
+        first = threading.Thread(target=client, args=(0,))
+        first.start()
+        deadline = time.time() + 10.0
+        while not launches and time.time() < deadline:
+            time.sleep(0.002)          # the first dispatch is in its launch
+        rest = [threading.Thread(target=client, args=(i,))
+                for i in range(1, n + 1)]
+        for t in rest:
+            t.start()
+        while len(qb._queue) < n and time.time() < deadline:
+            time.sleep(0.002)          # n requests queued behind it
+        gate.set()
+        for t in [first] + rest:
+            t.join(timeout=10.0)
+    finally:
+        qb.stop()
+    assert launches == [1, 8]          # one solo-sized, one coalesced (pad 8)
+    records = [r for r in tailboard.debug_flight()["dispatches"]
+               if r.get("batch") == n]
+    assert len(records) == 1
+    rec = records[0]
+    assert rec["t_source"] == "drain" and rec["kind"] == "flat"
+    rode = [timelines[i] for i in range(1, n + 1)]
+    assert {tl.phases["device"] for tl in rode} == \
+        {rec["device_ms"] / 1000.0}
+    waits = [tl.phases["queue_wait"] for tl in rode]
+    assert round(max(waits) * 1000.0, 3) == rec["wait_ms"]
+    assert all(0 < w <= max(waits) for w in waits)
+    assert all(tl.stages["wake"] > 0 for tl in rode)
+    # both sides stamped into the one record, and their stages cover
+    # their wall time
+    worker = dict(rec["worker_ms"])
+    wall = worker.pop("worker_wall")
+    assert set(worker) <= set(tailboard.DISPATCH_STAGES)
+    assert sum(worker.values()) == pytest.approx(wall, rel=0.05)
+    # the drain's side runs from the fetch's start past the ``done``
+    # stamp (the delivery follows it), with no gap between its stages
+    drain = rec["drain_ms"]
+    assert set(drain) <= set(tailboard.DISPATCH_STAGES)
+    st = rec["stamps"]
+    assert sum(drain.values()) >= (st["done"] - st["fetch0"]) * 1000.0
+    assert rec["drain_ms"]["finish"] >= 10.0      # the stub's finish step
+    assert "launch" in rec["worker_ms"] and "deliver" in rec["drain_ms"]
+
+
+def _dispatch_stage_reader(kind):
+    """(total, count) of the kind's dispatch stages SINCE this call: the
+    registry's series live as long as the process."""
+    from weaviate_tpu.runtime import tailboard
+    from weaviate_tpu.runtime.metrics import dispatch_stage_seconds
+
+    def now(stage):
+        child = dispatch_stage_seconds.labels(kind, stage)
+        return child.total, child.count
+
+    tailboard.flush()
+    names = tailboard.DISPATCH_STAGES + ("worker_wall",)
+    base = {s: now(s) for s in names}
+
+    def since(stage):
+        tailboard.flush()
+        total, count = now(stage)
+        return total - base[stage][0], count - base[stage][1]
+
+    return since
+
+
+def test_worker_stages_cover_the_workers_wall_time():
+    """``idle`` + ``slot_wait`` + the worker's other stages cover the
+    worker thread's wall time: nothing it does between two dispatches is
+    unaccounted."""
+    from weaviate_tpu.runtime import tailboard
+
+    since = _dispatch_stage_reader("flat")
+    qb, launches = _async_batcher(fin_sleep=0.002)
+    t0 = time.perf_counter()
+    try:
+        for i in range(6):
+            qb.search(np.zeros(4, np.float32), 3)
+            time.sleep(0.01)           # the worker idles between requests
+        t1 = time.perf_counter()
+    finally:
+        qb.stop()
+
+    wall = since("worker_wall")[0]
+    # async path: d2h_wait, rescore, deliver and finish are the drain's
+    worker = sum(since(s)[0] for s in ("idle", "slot_wait", "assemble",
+                                       "mask_pack", "launch"))
+    assert since("launch")[1] == 6 and since("worker_wall")[1] == 6
+    assert worker == pytest.approx(wall, rel=0.01)
+    assert since("idle")[0] > 0.04     # five 10-ms pauses
+    # and the records' walls cover the time the worker was alive (its
+    # last wait, which the stop ended, belongs to no dispatch)
+    assert 0.75 * (t1 - t0) < wall <= (t1 - t0) + 0.01
+    # the drain's side: one ``finish`` a dispatch, each at least the
+    # stub's 2-ms finish step
+    assert since("finish")[1] == 6 and since("finish")[0] >= 6 * 0.002
+
+
+def test_annotations_only_on_the_worker_and_drain_threads(monkeypatch):
+    """A profiler annotation is entered for the dispatch stages on the
+    batcher's worker and the drain thread, and on NO request thread: the
+    benchmark's gap-namer sums an event name's cover over threads."""
+    from weaviate_tpu.runtime import tailboard, tracing
+
+    seen = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append((self.name, threading.current_thread().name))
+
+        def __exit__(self, *exc):
+            return False
+
+        @staticmethod
+        def is_enabled():              # a profiler session is running
+            return True
+
+    monkeypatch.setattr(tailboard, "_annotation_cls", Recorder)
+    qb, _ = _async_batcher(fin_sleep=0.001)
+
+    def client():
+        t = time.perf_counter()
+        with tailboard.request("grpc.search", t_entry=t, t_arrival=t), \
+                tracing.trace("grpc.Search"):
+            # request-thread stages of both kinds of name: neither may
+            # reach the profiler from here
+            with tracing.span("shard.allow_mask", stage="filter"):
+                pass
+            with tracing.span("store.mask_pack", stage="mask_pack"):
+                pass
+            with tailboard.dispatch_stage("launch"):
+                pass
+            qb.search(np.zeros(4, np.float32), 3)
+
+    try:
+        threads = [threading.Thread(target=client, name=f"request-{i}")
+                   for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=10.0)
+    finally:
+        qb.stop()
+    assert {thread for _, thread in seen} == {"query-batcher",
+                                              "qb-transfer"}
+    by_thread = {}
+    for name, thread in seen:
+        by_thread.setdefault(thread, set()).add(name)
+    assert {"wtpu.idle", "wtpu.assemble", "wtpu.launch"} <= \
+        by_thread["query-batcher"]
+    assert {"wtpu.finish", "wtpu.d2h_wait", "wtpu.deliver"} <= \
+        by_thread["qb-transfer"]
+    # the slot wait is the drain's busy time: never annotated
+    assert all(name != "wtpu.slot_wait" for name, _ in seen)
+    assert all(name.startswith("wtpu.") and name[5:] in
+               tailboard.DISPATCH_STAGES for name, _ in seen)
+
+
+def test_solo_dispatch_is_its_own_record_under_its_own_kind():
+    """A highly selective filter goes solo: a dispatch record of its own
+    (``<kind>.solo`` in the stage family), read by its one waiter."""
+    from weaviate_tpu.runtime import tailboard
+
+    solo = _dispatch_stage_reader("flat.solo")
+    coalesced = _dispatch_stage_reader("flat")
+    idx, rng = _corpus_index(n=512, dim=16)
+    qb = QueryBatcher(idx.search_by_vector_batch,
+                      supports_filter_batching=True,
+                      capacity_fn=lambda: 512, kind="flat")
+    try:
+        allow = np.zeros(512, bool)
+        allow[:4] = True                         # 4 <= 512 / 64: solo
+        t = time.perf_counter()
+        with tailboard.request("grpc.search", t_entry=t,
+                               t_arrival=t) as tl:
+            ids, _ = qb.search(rng.standard_normal(16).astype(np.float32),
+                               3, allow=allow)
+    finally:
+        qb.stop()
+    assert set(ids.tolist()) <= {0, 1, 2, 3}
+    assert solo("launch")[1] == 1 and solo("launch")[0] > 0
+    assert solo("d2h_wait")[1] == 1
+    assert coalesced("launch")[1] == 0 and coalesced("assemble")[1] == 1
+    assert tl.phases["device"] > 0 and tl.stages["wake"] > 0
+
+
+def test_gathered_solo_scan_and_full_scan_compile_under_different_names(
+        monkeypatch):
+    """Programs that differ in role differ in module name: the scan over
+    a dense gather of the few allowed rows (the solo path) is
+    ``jit_gathered_topk_distances``, the full scan keeps
+    ``jit_chunked_topk_distances`` (the benchmark's ``scan_programs``
+    pattern), the device-side fold of a shared allow list is
+    ``jit_apply_allow_mask``. Naming only: same body, same answers."""
+    import jax.numpy as jnp
+
+    from weaviate_tpu.engine.store import apply_allow_mask
+    from weaviate_tpu.ops import candidates
+    from weaviate_tpu.ops.topk import (chunked_topk_distances,
+                                       gathered_topk_distances)
+
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((2, 16)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((128, 16)), jnp.float32)
+    kw = dict(k=4, chunk_size=128, metric="l2-squared")
+
+    def module(fn, *args, **kwargs):
+        return fn.lower(*args, **kwargs).as_text().split("module @")[1] \
+            .split()[0]
+
+    assert module(chunked_topk_distances, q, x, **kw) == \
+        "jit_chunked_topk_distances"
+    assert module(gathered_topk_distances, q, x, **kw) == \
+        "jit_gathered_topk_distances"
+    assert module(apply_allow_mask, jnp.ones(8, bool),
+                  jnp.ones(8, bool)) == "jit_apply_allow_mask"
+    full = chunked_topk_distances(q, x, **kw)
+    gathered = gathered_topk_distances(q, x, **kw)
+    assert all(np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(full, gathered))
+    # and the store's gathered cutover really runs under the new name
+    calls = []
+
+    def spy(*args, **kwargs):
+        calls.append(kwargs["chunk_size"])
+        return gathered_topk_distances(*args, **kwargs)
+
+    monkeypatch.setattr(candidates, "gathered_topk_distances", spy)
+    idx, rng = _corpus_index(n=512, dim=16)
+    allow = np.zeros(512, bool)
+    allow[:4] = True
+    idx.store.search(rng.standard_normal((1, 16)).astype(np.float32), 3,
+                     allow_mask=allow)
+    assert calls == [128]              # the 4 allowed rows' pow2 bucket

@@ -396,3 +396,208 @@ def test_flight_ring_wraps_and_orders():
     snap = ring.snapshot()
     assert len(snap) == 8
     assert [r["i"] for r in snap] == list(range(12, 20))
+
+
+# -- stages: one clock from the wire to the reply (ISSUE 25) ------------------
+
+
+@pytest.fixture
+def grpc_search(tmp_path):
+    """A real GrpcServer over a socket and a unary Search callable."""
+    import grpc
+
+    from weaviate_tpu.api.grpc import v1_pb2 as pb
+    from weaviate_tpu.api.grpc.server import GrpcServer
+    from weaviate_tpu.schema.config import CollectionConfig, Property
+
+    db = Database(str(tmp_path))
+    db.create_collection(CollectionConfig(name="Stage", properties=[
+        Property(name="bucket", data_type="int")]))
+    col = db.get_collection("Stage")
+    rng = np.random.default_rng(5)
+    for i in range(64):
+        col.put_object({"bucket": i % 10},
+                       vector=rng.standard_normal(8).astype(np.float32))
+    server = GrpcServer(db).start()
+    channel = grpc.insecure_channel(f"127.0.0.1:{server.port}")
+    call = channel.unary_unary(
+        "/weaviate.v1.Weaviate/Search",
+        request_serializer=pb.SearchRequest.SerializeToString,
+        response_deserializer=pb.SearchReply.FromString)
+
+    def search(filtered: bool):
+        req = pb.SearchRequest(collection="Stage", limit=3,
+                               uses_123_api=True)
+        req.near_vector.vector_bytes = rng.standard_normal(8).astype(
+            "<f4").tobytes()
+        req.metadata.uuid = True
+        if filtered:
+            req.filters.operator = pb.Filters.OPERATOR_LESS_THAN
+            req.filters.target.property = "bucket"
+            req.filters.value_int = 5
+        assert len(call(req).results) == 3
+
+    yield search
+    channel.close()
+    server.stop()
+    db.close()
+
+
+def _stage(operation, stage):
+    from weaviate_tpu.runtime.metrics import request_stage_seconds
+
+    return request_stage_seconds.labels(operation, stage)
+
+
+def test_every_stage_of_a_grpc_search_is_observed_once_and_sums(grpc_search):
+    """Over a real socket: every stage is observed once per Search (zero
+    included), the additive stages sum to ``server_residency``, and the
+    four phases sum to the timeline's duration exactly as before the
+    stages existed."""
+    from weaviate_tpu.runtime.metrics import request_phase_seconds
+
+    every = tailboard.REQUEST_STAGES + tailboard.REQUEST_EXTRAS
+
+    def read():
+        """(count, total) by stage, and the four phases' total: the
+        registry's series live as long as the process, so deltas."""
+        tailboard.flush()
+        stages = {s: (_stage("grpc.search", s).count,
+                      _stage("grpc.search", s).total) for s in every}
+        host = request_phase_seconds.labels("grpc.search", "host",
+                                            "Stage", "-")
+        return stages, host.count, sum(request_phase_seconds.labels(
+            "grpc.search", p, "Stage", "-").total
+            for p in tailboard.PHASES)
+
+    base, base_n, base_phases = read()
+    n = 12
+    for i in range(n):
+        grpc_search(filtered=i % 3 == 0)
+    # the record is whole at the RPC's termination, which the server
+    # sees a moment after the client has its reply
+    deadline = time.time() + 10.0
+    while time.time() < deadline:
+        now, now_n, now_phases = read()
+        if now["server_residency"][0] - base["server_residency"][0] == n:
+            break
+        time.sleep(0.02)
+    assert {s: now[s][0] - base[s][0] for s in every} == \
+        {s: n for s in every}
+    total = {s: now[s][1] - base[s][1] for s in every}
+    assert sum(total[s] for s in tailboard.REQUEST_STAGES) == \
+        pytest.approx(total["server_residency"], rel=1e-9)
+    assert total["filter"] > 0 and total["fetch"] > 0
+    assert total["queue_wait"] > 0 and total["device"] > 0
+    assert total["pool_wait"] > 0 and total["send"] > 0
+    assert 0 < total["handler_cpu"] < total["server_residency"]
+    # the phases' clock starts where it did before the stages existed,
+    # below the handler's metadata and deadline preamble: what the
+    # handler's wall time holds beyond them is that preamble, the first
+    # part of ``parse``
+    handler = total["server_residency"] - total["pool_wait"] - total["send"]
+    phases = now_phases - base_phases
+    assert 0.0 <= handler - phases <= total["parse"]
+    # the phase series, with its collection label, saw the same requests
+    assert now_n - base_n == n
+
+
+class _Clock:
+    """A stated clock in tailboard's place of ``time``."""
+
+    def __init__(self):
+        self.now = 100.0
+        self.cpu = 7.0
+
+    def perf_counter(self):
+        return self.now
+
+    def thread_time(self):
+        return self.cpu
+
+    monotonic = staticmethod(time.monotonic)
+    time = staticmethod(time.time)
+
+
+class _Ctx:
+    def add_callback(self, cb):
+        self.cb = cb
+        return True
+
+
+def _phase_totals(operation):
+    from weaviate_tpu.runtime.metrics import request_phase_seconds
+
+    tailboard.flush()
+    return {p: request_phase_seconds.labels(operation, p, "-", "-").total
+            for p in tailboard.PHASES}
+
+
+def test_stages_from_stated_stamps_and_phases_unchanged(monkeypatch):
+    """The same stamps through a staged and an unstaged timeline: the
+    phases are the same to the digit (``host`` stays the handler's
+    remainder), and each stage is what its stamps say."""
+    clock = _Clock()
+    monkeypatch.setattr(tailboard, "time", clock)
+
+    def drive(operation, staged):
+        clock.now, clock.cpu = 100.002, 7.0     # handler entry
+        ctx = _Ctx()
+        with tailboard.request(
+                operation, t_entry=clock.now if staged else None,
+                t_arrival=100.000 if staged else None) as tl:
+            if staged:
+                tl.defer_to(ctx)
+            clock.now = 100.003
+            tailboard.mark("parse")
+            tailboard.request_stage("filter", 0.0005)
+            tailboard.phase("queue_wait", 0.001)
+            tailboard.phase("device", 0.004)
+            tailboard.request_stage("wake", 0.0005)
+            tailboard.request_stage("fetch", 0.001)
+            clock.now = 100.012
+            tailboard.mark("search")
+            tailboard.complete(200)
+            clock.now, clock.cpu = 100.013, 7.0015  # handler return
+        clock.now = 100.015                          # RPC termination
+        if staged:
+            ctx.cb()
+
+    drive("op.staged", True)
+    drive("op.plain", False)
+    staged, plain = _phase_totals("op.staged"), _phase_totals("op.plain")
+    assert staged == plain
+    assert plain["host"] == pytest.approx(0.011 - 0.005)
+    got = {s: _stage("op.staged", s).total
+           for s in tailboard.REQUEST_STAGES + tailboard.REQUEST_EXTRAS}
+    want = {"pool_wait": 0.002, "parse": 0.001, "filter": 0.0005,
+            "queue_wait": 0.001, "device": 0.004, "transfer": 0.0,
+            "wake": 0.0005, "fetch": 0.001,
+            "search_other": 0.009 - 0.007, "reply": 0.001, "send": 0.002,
+            "handler_cpu": 0.0015, "server_residency": 0.015}
+    assert got == pytest.approx(want, abs=1e-9)
+    assert _stage("op.plain", "parse").count == 0   # unstaged: no stages
+
+
+def test_termination_before_the_handler_returns_gives_send_zero(
+        monkeypatch):
+    """A cancelled RPC terminates while its handler still runs: the
+    record is pushed by the handler's return, ``send`` is 0 and the
+    residency ends at the return."""
+    clock = _Clock()
+    monkeypatch.setattr(tailboard, "time", clock)
+    ctx = _Ctx()
+    with tailboard.request("op.cancelled", t_entry=100.0,
+                           t_arrival=100.0) as tl:
+        tl.defer_to(ctx)
+        clock.now = 100.004
+        ctx.cb()                    # termination first
+        tailboard.flush()
+        assert _stage("op.cancelled", "send").count == 0  # not yet whole
+        clock.now = 100.010
+        tailboard.complete(499)
+    tailboard.flush()
+    assert _stage("op.cancelled", "send").count == 1
+    assert _stage("op.cancelled", "send").total == 0.0
+    assert _stage("op.cancelled", "server_residency").total == \
+        pytest.approx(0.010)

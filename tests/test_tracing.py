@@ -215,6 +215,93 @@ def test_batcher_coalesced_waiters_each_record_their_split():
         qb.stop()
 
 
+def test_coalesced_dispatch_spans_are_derived_from_its_record():
+    """Every waiter's spans come from the ONE dispatch record: the
+    followers' waits end at the same stamp, and a filtered dispatch
+    carries ``batcher.assemble`` where ``batcher.mask_pack`` used to
+    misname the copy of the query block."""
+    from weaviate_tpu.runtime.query_batcher import QueryBatcher
+
+    release = threading.Event()
+    calls = []
+
+    def batch_fn(queries, k, allow):
+        calls.append(len(queries))
+        if len(calls) == 1:
+            release.wait(5)
+        b = len(queries)
+        return (np.zeros((b, k), np.int64), np.zeros((b, k), np.float32))
+
+    qb = QueryBatcher(batch_fn, supports_filter_batching=True)
+    t_ref = time.perf_counter()
+    ends = {}
+
+    def one(i):
+        with tracing.trace("req"):
+            qb.search(np.zeros(4, np.float32), k=2,
+                      allow=np.ones(8, bool) if i else None)
+            tr = tracing.capture()[0]
+        # absolute end of this waiter's queue wait, on one clock
+        wait = _spans(tr.to_dict(), "batcher.wait")[0]
+        ends[i] = (tr._t0 - t_ref) * 1000.0 + wait["start_ms"] \
+            + wait["duration_ms"]
+
+    try:
+        threads = [threading.Thread(target=one, args=(i,))
+                   for i in range(4)]
+        threads[0].start()
+        time.sleep(0.05)
+        for t in threads[1:]:
+            t.start()
+        time.sleep(0.05)
+        release.set()
+        for t in threads:
+            t.join(5)
+        traces = tracing.recent_traces(10)
+        assert all(not _spans(t, "batcher.mask_pack") for t in traces)
+        # the three filtered followers rode one dispatch; the unfiltered
+        # first request's dispatch assembled no allow lists
+        assert sum(1 for t in traces
+                   if _spans(t, "batcher.assemble")) == 3
+        # their waits end at the same stamp (the record's ``exec``), to
+        # the rounding of a span's start and duration
+        followers = sorted(ends.values())[1:]
+        assert followers[-1] - followers[0] < 0.01
+    finally:
+        release.set()
+        qb.stop()
+
+
+def test_staged_span_stamps_once_for_trace_and_timeline():
+    """A span site that is also a stage hands ONE pair of stamps to the
+    trace and to the request's timeline: the two durations are the same
+    number, and the stage is recorded with or without a trace."""
+    from weaviate_tpu.runtime import tailboard
+
+    t = time.perf_counter()
+    with tailboard.request("grpc.search", t_entry=t, t_arrival=t) as tl:
+        with tracing.trace("req"):
+            with tracing.span("shard.allow_mask", stage="filter",
+                              shard="s"):
+                time.sleep(0.003)
+        traced = tl.stages["filter"]
+        with tracing.span("objects.fetch", stage="fetch") as sp:
+            time.sleep(0.001)             # no trace: the stage alone
+        assert sp is tracing.NULL_SPAN
+    span = _spans(tracing.recent_traces(1)[0], "shard.allow_mask")[0]
+    assert span["attrs"] == {"shard": "s"}     # ``stage`` is no attribute
+    assert span["duration_ms"] == round(traced * 1000.0, 3) >= 3.0
+    assert tl.stages["fetch"] >= 0.001
+    # outside a staged timeline the site is a plain span, or nothing
+    with tracing.span("shard.allow_mask", stage="filter") as sp:
+        pass
+    assert sp is tracing.NULL_SPAN
+    with tailboard.request("rest.search") as plain:
+        with tracing.span("shard.allow_mask", stage="filter"):
+            pass
+    assert plain.stages is None
+
+
 # -- traceparent over the in-proc transport -----------------------------------
 
 def test_traceparent_round_trip():

@@ -9,7 +9,9 @@ reference's parse/prepare split.
 
 from __future__ import annotations
 
+import contextvars
 import logging
+import os
 import time
 import uuid as _uuid
 from concurrent.futures import ThreadPoolExecutor
@@ -25,6 +27,25 @@ from weaviate_tpu.schema.config import DataType
 logger = logging.getLogger(__name__)
 
 _SERVICE = "weaviate.v1.Weaviate"
+
+# perf_counter stamp of the RPC's arrival, set by _ArrivalInterceptor on
+# gRPC's serving thread. grpc runs the interceptor pipeline and, later,
+# the handler in ONE contextvars.Context per RPC, so the handler's pool
+# thread reads what the serving thread set.
+_arrival: contextvars.ContextVar[float | None] = contextvars.ContextVar(
+    "weaviate_tpu_grpc_arrival", default=None)
+
+
+class _ArrivalInterceptor(grpc.ServerInterceptor):
+    """Stamps the RPC's arrival BEFORE the handler's thread pool: the
+    ``pool_wait`` stage (pool queue, request deserialisation, the pool
+    thread's first wait for the interpreter lock) runs from this stamp
+    to the handler's entry. It runs on gRPC's single serving thread, so
+    it does nothing but take the stamp."""
+
+    def intercept_service(self, continuation, handler_call_details):
+        _arrival.set(time.perf_counter())
+        return continuation(handler_call_details)
 
 _CONSISTENCY = {
     pb.CONSISTENCY_LEVEL_UNSPECIFIED: "QUORUM",
@@ -291,8 +312,6 @@ class GrpcServer:
         self.modules = modules
         self.auth = auth
         if max_workers is None:
-            import os
-
             max_workers = int(os.environ.get("GRPC_MAX_WORKERS", "64"))
         self._max_workers = max_workers
         handlers = {
@@ -312,11 +331,17 @@ class GrpcServer:
         method_handlers = {}
         for name, fn in handlers.items():
             method_handlers[name] = grpc.unary_unary_rpc_method_handler(
-                self._wrap(fn, verbs[name], name),
+                # Search is the STAGED rpc: its timeline carries the
+                # stages from the wire to the reply
+                self._wrap(fn, verbs[name], name, staged=name == "Search"),
                 request_deserializer=req_types[name].FromString,
                 response_serializer=lambda resp: resp.SerializeToString(),
             )
-        self._server = grpc.server(ThreadPoolExecutor(max_workers=self._max_workers))
+        # the interceptor costs nothing the benchmark can see (cell 1
+        # with and without it, PERF.md PR 25), so it carries no switch
+        self._server = grpc.server(
+            ThreadPoolExecutor(max_workers=self._max_workers),
+            interceptors=(_ArrivalInterceptor(),))
         self._server.add_generic_rpc_handlers(
             (grpc.method_handlers_generic_handler(_SERVICE, method_handlers),))
         # grpc.health.v1.Health/Check — the official v4 client health-checks
@@ -374,10 +399,17 @@ class GrpcServer:
         except TypeError:  # unhashable stub in tests
             return 500
 
-    def _wrap(self, fn, verb: str = "write", rpc_name: str = "rpc"):
+    def _wrap(self, fn, verb: str = "write", rpc_name: str = "rpc",
+              staged: bool = False):
+        """``staged``: the handler's timeline carries the stages from
+        the wire to the reply (tailboard.REQUEST_STAGES); ``pool_wait``
+        ends and ``parse`` begins at the handler's first stamp, while
+        the phases' clock starts where it always did, at the timeline's
+        opening below the metadata and deadline preamble."""
         from weaviate_tpu.runtime import tracing
 
         def handler(request, context):
+            t_entry = time.perf_counter() if staged else None
             # request root trace; clients force device-time sampling by
             # sending an "x-trace: true" metadata key (the gRPC analog
             # of the REST ?trace=true param)
@@ -421,7 +453,13 @@ class GrpcServer:
             # always-on timeline (tailboard): the rpc name is the
             # operation label; complete() runs BEFORE each abort (abort
             # raises) so the tail keep/drop decision sees the status
-            with tailboard.request(f"grpc.{rpc_name.lower()}"):
+            with tailboard.request(f"grpc.{rpc_name.lower()}",
+                                   t_entry=t_entry,
+                                   t_arrival=_arrival.get()) as tl:
+                if staged and tl is not None:
+                    # send and server_residency end at the RPC's
+                    # termination, not at this handler's return
+                    tl.defer_to(context)
                 try:
                     # auth precedes the trace: rejected clients must not
                     # be able to fill the debug-trace ring
@@ -534,6 +572,11 @@ class GrpcServer:
 
         results = None
         fetched_objects = None
+        # stage marks (tailboard): ``parse`` ends where the collection is
+        # called, ``search`` is that call's wall time (the stages stamped
+        # inside it are taken out of it at the fold: ``search_other`` is
+        # what is left), ``reply`` runs to the handler's return
+        tailboard.mark("parse")
         if search_kind == "near_vector":
             nv = req.near_vector
             vec = _vector_from(nv.vector_bytes, nv.vector)
@@ -543,6 +586,7 @@ class GrpcServer:
             max_dist = nv.distance if nv.HasField("distance") else (
                 2 * (1 - nv.certainty) if nv.HasField("certainty") else None)
             vec_name = nv.target_vectors[0] if nv.target_vectors else ""
+            tailboard.mark("parse")
             results = col.near_vector(
                 vec, k=limit + req.offset, vec_name=vec_name, tenant=tenant,
                 where=where, max_distance=max_dist, autocut=autocut)
@@ -610,6 +654,7 @@ class GrpcServer:
                 limit=limit, offset=req.offset, sort=sort or None,
                 where=where, tenant=tenant, after=req.after or None)
 
+        tailboard.mark("search")
         if results is not None and req.offset:
             results = results[req.offset:]
         if results is not None:

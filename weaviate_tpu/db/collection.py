@@ -903,7 +903,7 @@ class Collection:
                 missing.setdefault(r.shard, []).append(r)
         if not missing:
             return
-        with tracing.span("objects.fetch",
+        with tracing.span("objects.fetch", stage="fetch",
                           n=sum(len(rs) for rs in missing.values()),
                           shards=len(missing)):
             for name, rs in missing.items():

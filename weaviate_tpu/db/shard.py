@@ -1131,7 +1131,8 @@ class Shard:
             return None
         from weaviate_tpu.filters import compute_allow_mask
 
-        with tracing.span("shard.allow_mask", shard=self.name):
+        with tracing.span("shard.allow_mask", stage="filter",
+                          shard=self.name):
             with self._lock:
                 return compute_allow_mask(where, self._inverted,
                                           self.doc_id_space)

@@ -590,7 +590,8 @@ class QuantizedVectorStore:
         epoch store already normalized once for every epoch (normalizing
         per epoch would not be bit-identical to the single-store path).
         Returns ``(d_dev, i_dev, tiers)``."""
-        from weaviate_tpu.engine.store import (batched_mask_operands,
+        from weaviate_tpu.engine.store import (apply_allow_mask,
+                                               batched_mask_operands,
                                                normalize_allow_mask)
 
         queries = np.asarray(queries, dtype=np.float32)
@@ -611,7 +612,7 @@ class QuantizedVectorStore:
                 full = np.zeros(capacity, dtype=bool)
                 w = min(len(allow_mask), capacity)
                 full[:w] = allow_mask[:w]
-                valid = jnp.logical_and(valid, self._placed(full))
+                valid = apply_allow_mask(valid, self._placed(full))
             d, i = self._scan(jnp.asarray(queries), min(k_cand, capacity),
                               valid, min(k_out, capacity),
                               allow_bits=allow_bits,
@@ -650,7 +651,8 @@ class QuantizedVectorStore:
         device-resident in the returned handle, whose finish step runs
         the exact host rescore (when this store's rescore mode needs
         one) after the boundary transfer."""
-        from weaviate_tpu.engine.store import normalize_allow_mask
+        from weaviate_tpu.engine.store import (apply_allow_mask,
+                                               normalize_allow_mask)
 
         queries = np.asarray(queries, dtype=np.float32)
         squeeze = queries.ndim == 1
@@ -689,7 +691,7 @@ class QuantizedVectorStore:
                 elif allow_mask is not None:
                     full = np.zeros(capacity, dtype=bool)
                     full[: len(allow_mask)] = allow_mask[:capacity]
-                    valid = jnp.logical_and(valid, self._placed(full))
+                    valid = apply_allow_mask(valid, self._placed(full))
                 if inline_rescore:
                     k_cand = min(max(k * self.rescore_limit, k), capacity)
                     k_out = min(k, capacity)
@@ -740,7 +742,7 @@ class QuantizedVectorStore:
                     _tiers=rescore_tiers):
             i_np = i_np.astype(np.int64, copy=False)
             if _post:
-                with tracing.span("store.host_rescore",
+                with tracing.span("store.host_rescore", stage="rescore",
                                   candidates=int(i_np.shape[1])):
                     d_np, i_np = self._host_rescore(
                         _queries, i_np, _k, capacity=_cap,

@@ -71,6 +71,15 @@ def normalize_allow_mask(allow_mask, n_queries: int):
     return allow_mask
 
 
+@jax.jit
+def apply_allow_mask(valid, allow):
+    """A shared allow list folded into the live-row mask on the device.
+    One op in one program, as the eager ``logical_and`` it replaces was:
+    jitted only so that a profile names the program by its role
+    (``jit_apply_allow_mask``) instead of by its op."""
+    return jnp.logical_and(valid, allow)
+
+
 def batched_mask_operands(allow_mask, n_queries: int, capacity: int, mesh,
                           owner: dict | None = None):
     """[B, capacity] per-query mask -> scan-kernel operands, under a
@@ -81,7 +90,8 @@ def batched_mask_operands(allow_mask, n_queries: int, capacity: int, mesh,
     labels the transient device buffer in the HBM ledger (weakref-
     tracked: the entry lives exactly as long as the buffer)."""
     owner = owner or {}
-    with tracing.span("store.mask_pack", queries=n_queries):
+    with tracing.span("store.mask_pack", stage="mask_pack",
+                      queries=n_queries):
         if mesh is None:
             from weaviate_tpu.ops.pallas_kernels import (mask_pad_cols,
                                                          pack_allow_bitmask)
@@ -587,7 +597,7 @@ class DeviceVectorStore:
                             else 0.0)
                         full = np.zeros(capacity, dtype=bool)
                         full[: len(allow_mask)] = allow_mask
-                        valid = jnp.logical_and(valid, self._placed(full))
+                        valid = apply_allow_mask(valid, self._placed(full))
                         slot_buf = None
                 else:
                     kernelscope.explain_note(
@@ -667,7 +677,7 @@ class DeviceVectorStore:
                 full = np.zeros(capacity, dtype=bool)
                 w = min(len(allow_mask), capacity)
                 full[:w] = allow_mask[:w]
-                valid = jnp.logical_and(valid, self._placed(full))
+                valid = apply_allow_mask(valid, self._placed(full))
             k_eff = min(k, capacity)
             metric = ("cosine" if self.metric in ("cosine", "cosine-dot")
                       else self.metric)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from weaviate_tpu.ops.distances import MASKED_DISTANCE, normalize
 from weaviate_tpu.ops.pallas_kernels import allow_bits_for_ids
-from weaviate_tpu.ops.topk import chunked_topk_distances, topk_smallest
+from weaviate_tpu.ops.topk import gathered_topk_distances, topk_smallest
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -129,7 +129,7 @@ def shared_candidates_topk(q, cand_slots, rows, k: int, metric: str, *,
         g_norms = (row_norms[safe].astype(jnp.float32)
                    if row_norms is not None
                    else jnp.sum(g_rows.astype(jnp.float32) ** 2, axis=-1))
-    return chunked_topk_distances(
+    return gathered_topk_distances(
         q, g_rows, k=min(k, slots.shape[0]), chunk_size=slots.shape[0],
         metric=metric, valid=g_valid, x_sq_norms=g_norms,
         use_pallas=use_pallas, selection=selection, row_ids=slots)

@@ -319,6 +319,27 @@ def chunked_topk_distances(
     return final_d, final_i
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("k", "chunk_size", "metric", "use_pallas", "selection"),
+)
+def gathered_topk_distances(q, x, k, chunk_size, metric="l2-squared",
+                            valid=None, x_sq_norms=None, use_pallas=False,
+                            selection="exact", row_ids=None):
+    """``chunked_topk_distances`` under a module name of its own: the
+    scan over a dense gather of the few rows a highly selective filter
+    allows (ops/candidates.shared_candidates_topk: the store's gathered
+    cutover, the batcher's solo path). The same ops in the same one
+    program, told apart by ROLE in a profile
+    (``jit_gathered_topk_distances`` on the device's module line) and in
+    the compile cache; ``jit_chunked_topk_distances`` keeps naming the
+    full scans. It takes the arguments the gathered path passes."""
+    return chunked_topk_distances.__wrapped__(
+        q, x, k=k, chunk_size=chunk_size, metric=metric, valid=valid,
+        x_sq_norms=x_sq_norms, use_pallas=use_pallas, selection=selection,
+        row_ids=row_ids)
+
+
 def chunked_topk(q, x, k, chunk_size=8192, metric="l2-squared", valid=None,
                  x_sq_norms=None, id_offset=0, selection="exact",
                  allow_bits=None, allow_rows=None):

@@ -52,6 +52,7 @@ import glob
 import gzip
 import json
 import os
+import re
 import tempfile
 import threading
 import time
@@ -161,6 +162,30 @@ def record_dispatch(kind: str, b_bucket: int, k_bucket: int,
             key[0], str(key[1]), str(key[2]), source).observe(device_s)
     except Exception:
         pass
+
+
+def fold_dispatch(rec: dict, source: str, nbytes: int = 0
+                  ) -> tuple[float, float]:
+    """Residency of one dispatch, derived from its record's stamps (the
+    batcher's flight record, ``rec["stamps"]``: ``exec`` = launch start,
+    ``fetch1`` = drain complete, ``done`` = results routed) instead of
+    from a call with numbers of its own: ``drain`` attributes
+    ``exec..fetch1`` less the memcpy estimate for ``nbytes``, ``wall``
+    the sync window ``exec..done``. Feeds the per-variant EWMA and
+    histogram, writes ``device_ms`` / ``transfer_ms`` / ``t_source``
+    back into the record (the waiters' phases read them there) and
+    returns ``(device_s, transfer_s)``."""
+    st = rec["stamps"]
+    if source == "drain":
+        device_s, transfer_s = attribute(st["fetch1"] - st["exec"], nbytes)
+    else:
+        device_s, transfer_s = max(0.0, st["done"] - st["exec"]), 0.0
+    rec["device_ms"] = device_s * 1000.0
+    rec["transfer_ms"] = transfer_s * 1000.0
+    rec["t_source"] = source
+    record_dispatch(rec.get("kind", ""), rec.get("b_pad") or 1,
+                    rec.get("k") or 0, device_s, source)
+    return device_s, transfer_s
 
 
 def apportion(device_s: float, weights: list[float]) -> list[float]:
@@ -318,24 +343,85 @@ def classify_kernel(event_name: str) -> str:
     return "other"
 
 
+_DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+")
+_OPS_THREAD = "XLA Ops"
+_GAPS_NAMED = 200  # only the longest gaps are matched against stages
+
+
+def _merged(intervals: list) -> list:
+    """Sorted, merged [start, end) intervals."""
+    out: list[list[float]] = []
+    for start, end in sorted(intervals):
+        if out and start <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], end)
+        else:
+            out.append([start, end])
+    return out
+
+
 def summarize_trace_events(events) -> dict:
-    """Aggregate chrome-trace complete events (``ph == "X"``, ``dur`` in
-    microseconds) into per-kernel device-ms ranked descending, with the
-    top raw event names kept per kernel for drill-down."""
-    by_kernel: dict[str, dict] = {}
+    """Reduce chrome-trace events (``ph == "X"``: ``ts`` and ``dur`` in
+    microseconds; ``ph == "M"`` process_name / thread_name metadata) the
+    way the benchmark reduces the ``.xplane.pb`` (benchmarks/
+    trace_reduce.py), so an operator reads from the server what the
+    benchmark reads from the file:
+
+    - DEVICE PLANES ONLY (processes named ``/device:TPU:n``; their
+      ``XLA Ops`` thread where threads are named): host threads are in
+      the same trace and are never device time. Per-kernel device-ms is
+      ranked descending with the top raw event names kept for drill-down;
+    - busy = the union of the device's op intervals (nested ops count
+      once), mean over planes; idle share over the captured window;
+    - the longest idle gaps, each named after the ``wtpu.<stage>``
+      annotation (tailboard's dispatch stages, stamped by the batcher's
+      worker and the drain thread) that covers most of it.
+
+    A trace with no device plane (the CPU backend) has no device time:
+    ``kernels`` is empty and ``device_planes`` says why."""
+    procs: dict = {}
+    threads: dict = {}
+    spans = []
     for ev in events or ():
-        if not isinstance(ev, dict) or ev.get("ph") != "X":
+        if not isinstance(ev, dict):
             continue
-        name = str(ev.get("name", ""))
-        dur_ms = float(ev.get("dur", 0) or 0) / 1000.0
-        if dur_ms <= 0:
-            continue
-        k = classify_kernel(name)
-        agg = by_kernel.setdefault(
-            k, {"kernel": k, "device_ms": 0.0, "events": 0, "names": {}})
-        agg["device_ms"] += dur_ms
-        agg["events"] += 1
-        agg["names"][name] = agg["names"].get(name, 0.0) + dur_ms
+        if ev.get("ph") == "M":
+            label = (ev.get("args") or {}).get("name")
+            if ev.get("name") == "process_name":
+                procs[ev.get("pid")] = str(label)
+            elif ev.get("name") == "thread_name":
+                threads[(ev.get("pid"), ev.get("tid"))] = str(label)
+        elif ev.get("ph") == "X":
+            dur = float(ev.get("dur", 0) or 0)
+            if dur > 0:
+                spans.append((ev.get("pid"), ev.get("tid"),
+                              str(ev.get("name", "")),
+                              float(ev.get("ts", 0) or 0), dur))
+    device_pids = sorted((pid for pid, name in procs.items()
+                          if _DEVICE_PLANE.match(name)), key=str)
+    by_kernel: dict[str, dict] = {}
+    busy_us = []
+    gaps: list[tuple[float, float]] = []
+    first = min((ts for *_x, ts, _d in spans), default=0.0)
+    last = max((ts + d for *_x, ts, d in spans), default=0.0)
+    for pid in device_pids:
+        named = any(p == pid and n == _OPS_THREAD
+                    for (p, _t), n in threads.items())
+        ops = [(name, ts, dur) for p, tid, name, ts, dur in spans
+               if p == pid and (not named
+                                or threads.get((p, tid)) == _OPS_THREAD)]
+        merged = _merged([(ts, ts + dur) for _n, ts, dur in ops])
+        busy_us.append(sum(e - st for st, e in merged))
+        edges = [first] + [t for iv in merged for t in iv] + [last]
+        gaps += [(edges[i], edges[i + 1])
+                 for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
+        for name, _ts, dur in ops:
+            k = classify_kernel(name)
+            agg = by_kernel.setdefault(
+                k, {"kernel": k, "device_ms": 0.0, "events": 0,
+                    "names": {}})
+            agg["device_ms"] += dur / 1000.0
+            agg["events"] += 1
+            agg["names"][name] = agg["names"].get(name, 0.0) + dur / 1000.0
     kernels = []
     for agg in by_kernel.values():
         top = sorted(agg.pop("names").items(), key=lambda kv: -kv[1])[:5]
@@ -344,9 +430,34 @@ def summarize_trace_events(events) -> dict:
                              for n, ms in top]
         kernels.append(agg)
     kernels.sort(key=lambda a: -a["device_ms"])
-    return {"kernels": kernels,
-            "total_device_ms": round(sum(a["device_ms"] for a in kernels),
-                                     3)}
+    out = {"kernels": kernels,
+           "total_device_ms": round(sum(a["device_ms"] for a in kernels),
+                                    3)}
+    if not spans:
+        return out
+    out["device_planes"] = [procs[pid] for pid in device_pids]
+    if not device_pids:
+        return out
+    stages = [(name, ts, ts + dur) for pid, _t, name, ts, dur in spans
+              if pid not in device_pids and name.startswith("wtpu.")]
+    by_stage: dict[str, float] = {}
+    for g0, g1 in sorted(gaps, key=lambda g: g[0] - g[1])[:_GAPS_NAMED]:
+        cover: dict[str, float] = {}
+        for name, s0, s1 in stages:
+            overlap = min(s1, g1) - max(s0, g0)
+            if overlap > 0:
+                cover[name] = cover.get(name, 0.0) + overlap
+        name = max(cover, key=cover.get) if cover else "no wtpu stage"
+        by_stage[name] = by_stage.get(name, 0.0) + (g1 - g0) / 1000.0
+    window_ms = (last - first) / 1000.0
+    busy_ms = sum(busy_us) / 1000.0 / len(device_pids)
+    out.update(
+        window_ms=round(window_ms, 3), busy_ms=round(busy_ms, 3),
+        device_idle_pct=round(100.0 * (1.0 - busy_ms / window_ms), 3)
+        if window_ms > 0 else None,
+        idle_gaps=[{"stage": n, "gap_ms": round(ms, 3)} for n, ms in
+                   sorted(by_stage.items(), key=lambda kv: -kv[1])[:10]])
+    return out
 
 
 def _jax_capture(ms: int):

@@ -254,6 +254,19 @@ class _HistogramChild:
                 self.exemplars[min(idx, len(self.buckets))] = ex
                 self.exemplars[len(self.buckets)] = ex
 
+    def observe_many(self, values) -> None:
+        """A batch of observations under ONE lock acquisition: the
+        deferred folds (tailboard) observe a dozen stages per request
+        and amortize here what per-value ``observe`` calls would cost."""
+        buckets = self.buckets
+        idxs = [bisect_left(buckets, v) for v in values]
+        with self._lock:
+            self.total += sum(values)
+            self.count += len(idxs)
+            slots = self.slot_counts
+            for idx in idxs:
+                slots[idx] += 1
+
     def cumulative_counts(self) -> list[int]:
         """Per-``le`` cumulative counts (the exposition's bucket lines).
         Caller need not hold the lock; a racing observe skews one scrape
@@ -493,13 +506,6 @@ batcher_batch_size = registry.histogram(
     "weaviate_tpu_query_batcher_batch_size",
     "Queries coalesced per device dispatch", (),
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
-batcher_wait_duration = registry.histogram(
-    "weaviate_tpu_query_batcher_wait_seconds",
-    "Time a query waits in the batcher queue before its dispatch starts")
-batcher_execute_duration = registry.histogram(
-    "weaviate_tpu_query_batcher_execute_seconds",
-    "Device dispatch+materialize time of the coalesced batch a query "
-    "rode in")
 batcher_filtered_batched = registry.counter(
     "weaviate_tpu_query_batcher_filtered_batched_total",
     "Filtered requests served inside a coalesced bitmask-batched "
@@ -516,10 +522,6 @@ batcher_overlapped = registry.counter(
     "weaviate_tpu_query_batcher_overlapped_total",
     "Dispatches launched while a previous batch was still draining "
     "D2H — the overlap the double-buffered pipeline exists for")
-batcher_transfer_duration = registry.histogram(
-    "weaviate_tpu_query_batcher_transfer_seconds",
-    "D2H drain time (transfer.d2h window) of the coalesced batch a "
-    "query rode in, overlapped with the next dispatch")
 batcher_hybrid_batched = registry.counter(
     "weaviate_tpu_query_batcher_hybrid_batched_total",
     "Hybrid (sparse+dense) requests served inside a coalesced device "
@@ -667,6 +669,28 @@ try:
         "WEAVIATE_TPU_PHASE_MAX_SERIES", "16000") or 16000)
 except ValueError:
     request_phase_seconds.max_series = 16000
+_STAGE_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
+                  0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0)
+request_stage_seconds = registry.histogram(
+    "weaviate_tpu_request_stage_seconds",
+    "Stages of a staged request (gRPC Search), stamped where the work "
+    "happens and observed once per request per stage, zero included, "
+    "from the same record the phases fold from: pool_wait, parse, "
+    "filter, queue_wait, device, transfer, wake, fetch, search_other, "
+    "reply, send (these sum to server_residency: RPC arrival to "
+    "termination) and handler_cpu (the handler thread's CPU time)",
+    ("operation", "stage"), buckets=_STAGE_BUCKETS)
+dispatch_stage_seconds = registry.histogram(
+    "weaviate_tpu_dispatch_stage_seconds",
+    "Leaf-level stages of one batcher dispatch, stamped into its flight "
+    "record by the worker (idle, slot_wait, assemble, mask_pack, launch; "
+    "d2h_wait, rescore, deliver on the sync path) and the drain thread "
+    "(d2h_wait, rescore, deliver, finish), observed once per dispatch "
+    "in which the stage ran; worker_wall is the worker side's wall time "
+    "(its stages cover it). kind is the index kind (<kind>.solo: "
+    "a solo filtered dispatch). The same stages are "
+    "jax.profiler.TraceAnnotation(\"wtpu.<stage>\") events in a trace",
+    ("kind", "stage"), buckets=_STAGE_BUCKETS)
 tail_retained_total = registry.counter(
     "weaviate_tpu_tail_retained_total",
     "Traces kept by the tail-based retention decision at request "

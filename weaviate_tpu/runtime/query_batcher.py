@@ -80,10 +80,7 @@ class DeviceHybridUnavailable(RuntimeError):
 
 class _Pending:
     __slots__ = ("query", "k", "allow", "sparse", "event", "ids", "dists",
-                 "error", "ctx", "t_enqueue", "t_exec_start", "t_exec_end",
-                 "batch_size", "t_mask_start", "t_mask_end",
-                 "t_fetch_start", "t_fetch_end", "epochs",
-                 "device_s", "transfer_s", "device_source",
+                 "error", "ctx", "t_enqueue", "t_deliver", "rec",
                  "explain_on", "explain")
 
     def __init__(self, query, k, allow, sparse=None):
@@ -97,30 +94,21 @@ class _Pending:
         # enqueue stamp: the flight recorder's wait_ms and the tailboard
         # queue_wait phase both derive from it
         self.t_enqueue = 0.0
+        # stamped by the delivering thread just before ``event.set()``:
+        # the request's ``wake`` stage runs from it
+        self.t_deliver: float | None = None
         self.ids = None
         self.dists = None
         self.error: Exception | None = None
         # trace context of the submitting request: the worker dispatches
         # under ONE waiter's context (device spans land in that trace)
-        # and stamps exec timings every waiter records into its own
         self.ctx = tracing.capture()
-        self.t_exec_start: float | None = None
-        self.t_exec_end: float | None = None
-        self.t_mask_start: float | None = None
-        self.t_mask_end: float | None = None
-        self.t_fetch_start: float | None = None
-        self.t_fetch_end: float | None = None
-        self.batch_size = 1
-        # epoch fanout of the dispatch this request rode in (the epoch
-        # store's handle reports how many per-epoch scans fused into
-        # the one merged program) — 0 for single-buffer stores
-        self.epochs = 0
-        # kernelscope attribution of the dispatch this request rode in:
-        # device residency vs memcpy split (source "drain") or the
-        # dispatch wall window (source "wall" — sync/null-device paths)
-        self.device_s: float | None = None
-        self.transfer_s = 0.0
-        self.device_source: str | None = None
+        # the record of the dispatch this request rode in (tailboard's
+        # flight record = the dispatch's stamp sheet): its stamps, batch
+        # size, epoch fanout and kernelscope attribution are read back
+        # on the request thread — every waiter of one dispatch reads the
+        # same record, so they agree by construction
+        self.rec: dict | None = None
         # per-query EXPLAIN: captured on the request thread at enqueue
         # (the worker has no request context); the dispatch plan is
         # merged back into the request sink after the waiter wakes
@@ -287,62 +275,56 @@ class QueryBatcher:
 
             deadline_exceeded_total.labels("batcher").inc()
             raise retry.DeadlineExceeded("batcher")
-        # wait-vs-execute split, recorded into THIS request's trace from
-        # the worker's stamps (the worker thread has no request context)
-        if item.t_exec_start is not None:
-            tracing.record_span("batcher.wait", t_enqueue,
-                                item.t_exec_start)
-            if item.t_mask_start is not None:
-                tracing.record_span("batcher.mask_pack", item.t_mask_start,
-                                    item.t_mask_end or item.t_mask_start)
-            tracing.record_span("batcher.execute", item.t_exec_start,
-                                item.t_exec_end or time.perf_counter(),
-                                batch=item.batch_size,
-                                **({"epochs": item.epochs}
-                                   if item.epochs else {}))
-            if item.t_fetch_start is not None:
+        # Everything below is DERIVED from the record of the dispatch
+        # this request rode in (the worker and the drain thread stamped
+        # it; nothing is timed a second time here): the trace's spans,
+        # the always-on phases and the wake stage.
+        rec = item.rec
+        if rec is not None:
+            t_wake = time.perf_counter()
+            st = rec["stamps"]
+            t_exec = st["exec"]
+            t_done = item.t_deliver or st.get("done") or t_wake
+            tracing.record_span("batcher.wait", t_enqueue, t_exec)
+            if rec.get("filtered") and "assemble0" in st:
+                # a filtered dispatch's copy of its query rows and allow
+                # lists into the padded block — NOT the mask pack, which
+                # happens inside batch_fn under ``store.mask_pack``
+                tracing.record_span("batcher.assemble", st["assemble0"],
+                                    t_exec)
+            tracing.record_span("batcher.execute", t_exec, t_done,
+                                batch=rec.get("batch") or 1,
+                                **({"epochs": rec["epochs"]}
+                                   if rec.get("epochs") else {}))
+            if "fetch0" in st:
                 # the pipelined D2H drain for this request's batch (the
                 # transfer thread's handle.result() window)
-                tracing.record_span("batcher.transfer",
-                                    item.t_fetch_start,
-                                    item.t_fetch_end
-                                    or item.t_fetch_start)
-            from weaviate_tpu.runtime.metrics import (
-                batcher_execute_duration, batcher_wait_duration)
-
-            batcher_wait_duration.observe(item.t_exec_start - t_enqueue)
-            if item.t_exec_end is not None:
-                batcher_execute_duration.observe(
-                    item.t_exec_end - item.t_exec_start)
-            if item.t_fetch_start is not None \
-                    and item.t_fetch_end is not None:
-                from weaviate_tpu.runtime.metrics import (
-                    batcher_transfer_duration)
-
-                batcher_transfer_duration.observe(
-                    item.t_fetch_end - item.t_fetch_start)
+                tracing.record_span("batcher.transfer", st["fetch0"],
+                                    st["fetch1"])
             # always-on phase attribution (tailboard), folded into this
             # request's live timeline on the request thread. "device" is
             # kernelscope's attributed residency: the drain-thread stamp
             # window minus the sampled-memcpy EWMA (source=drain,
             # block_until_ready-free) or the dispatch wall window on
             # sync/null-device paths (source=wall); "transfer" is the
-            # memcpy share. The pre-kernelscope wall split stays as the
-            # fallback for dispatches that died before attribution.
-            tailboard.phase("queue_wait", item.t_exec_start - t_enqueue)
-            if item.device_s is not None:
-                tailboard.phase("device", item.device_s)
-                if item.transfer_s > 0:
-                    tailboard.phase("transfer", item.transfer_s)
-            elif item.t_fetch_start is not None:
-                tailboard.phase("device",
-                                item.t_fetch_start - item.t_exec_start)
-                tailboard.phase("transfer",
-                                (item.t_fetch_end or item.t_fetch_start)
-                                - item.t_fetch_start)
-            elif item.t_exec_end is not None:
-                tailboard.phase("device",
-                                item.t_exec_end - item.t_exec_start)
+            # memcpy share. The plain wall split stays as the fallback
+            # for dispatches that died before attribution.
+            tailboard.phase("queue_wait", t_exec - t_enqueue)
+            device_ms = rec.get("device_ms")
+            if device_ms is not None:
+                tailboard.phase("device", device_ms / 1000.0)
+                if rec["transfer_ms"] > 0:
+                    tailboard.phase("transfer", rec["transfer_ms"] / 1000.0)
+            elif "fetch0" in st:
+                tailboard.phase("device", st["fetch0"] - t_exec)
+                tailboard.phase("transfer", st["fetch1"] - st["fetch0"])
+            elif "done" in st:
+                tailboard.phase("device", st["done"] - t_exec)
+            if item.t_deliver is not None:
+                # event set -> this thread running again: with 32
+                # request threads on one interpreter lock this is where
+                # a woken waiter queues for it
+                tailboard.request_stage("wake", t_wake - item.t_deliver)
         if item.explain is not None:
             # fold the dispatch's plan into the request-level explain
             # sink (installed by the REST/gRPC edge on THIS thread)
@@ -354,38 +336,64 @@ class QueryBatcher:
     # -- worker ---------------------------------------------------------------
 
     def _run(self):
+        from weaviate_tpu.runtime.metrics import batcher_batch_size
+
         while True:
-            # pipeline pacing: with the transfer window full (one batch
-            # computing, one draining), DON'T drain yet — arriving
-            # requests keep coalescing into the next batch, so the
-            # pipeline keeps the sync path's batch sizes AND the overlap
-            tp = self._transfer
-            if tp is not None:
-                tp.wait_slot()
-            with self._cv:
+            # the dispatch record is opened BEFORE the wait for work, so
+            # the wait that precedes a dispatch is stamped into that
+            # dispatch's sheet; this thread is its ``worker`` side.
+            # ``assemble`` is the worker's own work on a dispatch: it
+            # runs wherever no other stage is marked (the two waits,
+            # launch, mask_pack, ...), so the side's stages never
+            # overlap and cover its wall time
+            rec = tailboard.new_dispatch("batcher", self.kind)
+            side = tailboard.bind_dispatch(rec, "worker", "assemble")
+            drained = self._await_drain(side)
+            if drained is not None:
+                try:
+                    batcher_batch_size.observe(len(drained))
+                    self._dispatch(drained, rec)
+                except Exception as e:  # noqa: BLE001 — to every waiter
+                    for it in drained:
+                        if not it.event.is_set():
+                            it.error = e
+                            it.event.set()
+            # a worker that woke only to stop leaves no record
+            tailboard.unbind_dispatch(keep=drained is not None)
+            if drained is None:
+                return
+
+    def _await_drain(self, side) -> list[_Pending] | None:
+        """Block until there is work; -> the drained requests (None:
+        stopped). The two waits are ``side``'s ``slot_wait`` and
+        ``idle`` stages, marked only where the worker really waits."""
+        # pipeline pacing: with the transfer window full (one batch
+        # computing, one draining), DON'T drain yet — arriving
+        # requests keep coalescing into the next batch, so the
+        # pipeline keeps the sync path's batch sizes AND the overlap
+        tp = self._transfer
+        if tp is not None and tp.inflight >= tp.depth:
+            side.mark("slot_wait")
+            tp.wait_slot()
+            side.mark("assemble")
+        with self._cv:
+            if not self._queue and not self._stopped:
+                side.mark("idle")
                 while not self._queue and not self._stopped:
                     self._cv.wait(timeout=1.0)
-                if self._stopped:
-                    for it in self._queue:
-                        it.error = RuntimeError("query batcher stopped")
-                        it.event.set()
-                    self._queue.clear()
-                    return
-                drained = self._queue[: self.max_batch]
-                del self._queue[: len(drained)]
-                # queue depth AFTER the drain (what the next batch
-                # inherits) — the flight recorder's congestion signal
-                self._queue_depth_at_drain = len(self._queue)
-            try:
-                from weaviate_tpu.runtime.metrics import batcher_batch_size
-
-                batcher_batch_size.observe(len(drained))
-                self._dispatch(drained)
-            except Exception as e:  # noqa: BLE001 — deliver to every waiter
-                for it in drained:
-                    if not it.event.is_set():
-                        it.error = e
-                        it.event.set()
+                side.mark("assemble")
+            if self._stopped:
+                for it in self._queue:
+                    it.error = RuntimeError("query batcher stopped")
+                    it.event.set()
+                self._queue.clear()
+                return None
+            drained = self._queue[: self.max_batch]
+            del self._queue[: len(drained)]
+            # queue depth AFTER the drain (what the next batch
+            # inherits) — the flight recorder's congestion signal
+            self._queue_depth_at_drain = len(self._queue)
+        return drained
 
     def _allowed_count(self, allow) -> int:
         """Selectivity of an allow list (bool mask over doc-id space or
@@ -409,7 +417,12 @@ class QueryBatcher:
             return False
         return self._allowed_count(it.allow) <= cap // 64
 
-    def _dispatch(self, drained: list[_Pending]):
+    def _dispatch(self, drained: list[_Pending], rec: dict | None = None):
+        """One drain -> its dispatches. ``rec`` is the record the worker
+        opened before it waited (its side is bound to this thread); a
+        direct caller gets a fresh one."""
+        if rec is None:
+            rec = tailboard.new_dispatch("batcher", self.kind)
         # split the drain: filtered requests coalesce with the plain ones
         # into ONE bitmask-batched device program; only index types
         # without batched-filter support and highly selective filters
@@ -427,27 +440,34 @@ class QueryBatcher:
                 coal.append(it)
         for it in solo:
             plan = {} if it.explain_on else None
+            # a solo dispatch is a dispatch of its own: its own record
+            # (path=solo: the stage family labels it ``<kind>.solo``),
+            # bound over the drain's while it runs; not filed in the
+            # flight ring, which keeps one entry per drain
+            srec = it.rec = tailboard.new_dispatch("batcher", self.kind)
+            t_exec = time.perf_counter()
+            srec.update(path="solo", batch=1, b_pad=1, k=it.k,
+                        stamps={"exec": t_exec})
+            tailboard.bind_dispatch(srec, "worker", "assemble", t_exec)
             try:
-                it.t_exec_start = time.perf_counter()
-                if plan is None:
-                    ids, dists = tracing.run_in(
-                        it.ctx, self._batch_fn, it.query[None, :], it.k,
-                        it.allow)
-                else:
-                    with kernelscope.explain_scope(plan):
+                with tailboard.dispatch_stage("launch"):
+                    if plan is None:
                         ids, dists = tracing.run_in(
                             it.ctx, self._batch_fn, it.query[None, :],
                             it.k, it.allow)
+                    else:
+                        with kernelscope.explain_scope(plan):
+                            ids, dists = tracing.run_in(
+                                it.ctx, self._batch_fn, it.query[None, :],
+                                it.k, it.allow)
                 it.ids, it.dists = ids[0], dists[0]
             except Exception as e:  # noqa: BLE001
                 it.error = e
-            it.t_exec_end = time.perf_counter()
+            srec["stamps"]["done"] = time.perf_counter()
             # no drain stamps on the solo path (sync device call):
             # wall-window attribution, metered against this batcher's
             # owner like any other dispatch
-            wall = max(0.0, it.t_exec_end - it.t_exec_start)
-            it.device_s, it.transfer_s, it.device_source = wall, 0.0, "wall"
-            kernelscope.record_dispatch(self.kind, 1, it.k, wall, "wall")
+            wall, _ = kernelscope.fold_dispatch(srec, "wall")
             kernelscope.meter(*self._meter_labels, wall)
             if plan is not None:
                 plan["batcher"] = {
@@ -456,17 +476,20 @@ class QueryBatcher:
                     "filtered": int(it.allow is not None), "solo": True,
                     "async": False, "kind": self.kind}
                 it.explain = plan
-            it.event.set()
+            with tailboard.dispatch_stage("deliver"):
+                it.t_deliver = time.perf_counter()
+                it.event.set()
+            tailboard.unbind_dispatch()
         if not coal:
             # a purely-solo drain still leaves a flight-recorder record
             # (batch=0): the solo/gathered path is exactly the regression
             # surface an r05-style post-hoc investigation digs through
             if solo:
                 tailboard.record_dispatch(
-                    "batcher", batch=0, b_pad=0, k=0,
+                    "batcher", rec, batch=0, b_pad=0, k=0,
                     queue_depth=self._queue_depth_at_drain,
                     wait_ms=round(max(
-                        ((it.t_exec_start or it.t_enqueue) - it.t_enqueue)
+                        (it.rec["stamps"]["exec"] - it.t_enqueue)
                         * 1000.0 for it in solo), 3),
                     filtered=len(solo), solo=len(solo),
                     window_inflight=0, epochs=0)
@@ -483,7 +506,7 @@ class QueryBatcher:
             k_bucket = max(it.k for it in coal)
         filtered = [it for it in coal if it.allow is not None]
         hybrid = [it for it in coal if it.sparse is not None]
-        t_mask0 = time.perf_counter()
+        t_assemble0 = time.perf_counter()
         allows = None
         if filtered:
             # per-request allow lists ride along row-aligned; unfiltered
@@ -497,7 +520,6 @@ class QueryBatcher:
         queries = np.zeros((b_pad,) + coal[0].query.shape, dtype=np.float32)
         for row, it in enumerate(coal):
             queries[row] = it.query
-        t_mask1 = time.perf_counter()
         self.dispatches += 1
         self.batched_queries += b
         self.filtered_batched += len(filtered)
@@ -519,39 +541,35 @@ class QueryBatcher:
         # bit-identical
         plan = {} if any(it.explain_on for it in coal) else None
         t0 = time.perf_counter()
-        for it in coal:
-            it.t_exec_start = t0
-            it.batch_size = b
-            if filtered:
-                it.t_mask_start, it.t_mask_end = t_mask0, t_mask1
+        # the dispatch's stamp sheet: perf_counter stamps every consumer
+        # derives from — ``assemble0`` (query block copy begins), ``exec``
+        # (launch begins: the end of every waiter's queue_wait), then
+        # ``fetch0``/``fetch1`` (drain thread) and ``done`` (results
+        # routed, or the failure)
+        stamps = {"assemble0": t_assemble0, "exec": t0}
         # flight-recorder dispatch record (lock-free ring): the dispatch
         # history a post-hoc regression investigation replays. epochs is
         # patched in below once the async handle reports its fanout.
         tp0 = self._transfer
         flight_rec = tailboard.record_dispatch(
-            "batcher", batch=b, b_pad=b_pad, k=k_bucket,
+            "batcher", rec, batch=b, b_pad=b_pad, k=k_bucket,
             queue_depth=self._queue_depth_at_drain,
             wait_ms=round(max(
                 (t0 - it.t_enqueue) * 1000.0 for it in coal), 3),
             filtered=len(filtered), solo=len(solo),
             window_inflight=tp0.inflight if tp0 is not None else 0,
-            epochs=0)
+            epochs=0, stamps=stamps)
+        for it in coal:
+            it.rec = flight_rec
 
-        def _attribute(device_s: float, transfer_s: float, source: str):
-            """Kernelscope fold for this dispatch: stamp every waiter's
-            attribution (each reads it back on its own request thread),
-            feed the per-compiled-variant residency EWMA + histogram,
-            patch the flight record, and meter the apportioned
-            residency per tenant."""
-            device_s = max(0.0, device_s)
-            for it in coal:
-                it.device_s = device_s
-                it.transfer_s = max(0.0, transfer_s)
-                it.device_source = source
-            flight_rec["device_ms"] = round(device_s * 1000.0, 3)
-            flight_rec["t_source"] = source
-            kernelscope.record_dispatch(self.kind, b_pad, k_bucket,
-                                        device_s, source)
+        def _attribute(source: str, nbytes: int = 0):
+            """Kernelscope fold for this dispatch, from the record's
+            stamps: the attribution lands in the record (each waiter
+            reads it back on its own request thread), feeds the
+            per-compiled-variant residency EWMA + histogram, and is
+            metered per tenant."""
+            device_s, _ = kernelscope.fold_dispatch(flight_rec, source,
+                                                    nbytes)
             # apportion across the coalesced requests, weighted by rows
             # scanned — one batcher serves one (shard, vector), so rows
             # and owner labels are uniform per dispatch: the weights
@@ -576,23 +594,32 @@ class QueryBatcher:
             — an unset event hangs its client forever (the transfer
             thread swallows callback exceptions by design)."""
             _hbm.release(pad_key)
-            t1 = time.perf_counter()
+            t1 = stamps.setdefault("done", time.perf_counter())
             for it in coal:
                 if not it.event.is_set():
-                    it.t_exec_end = t1
                     it.error = err
+                    it.t_deliver = t1
                     it.event.set()
+
+        def _launch(fn, *args):
+            """The ``batch_fn`` / ``async_fn`` call: cache lookup, H2D of
+            the query block, ``Execute`` (and, on the sync path, the
+            stages nested in it: mask_pack, d2h_wait, rescore)."""
+            with tailboard.dispatch_stage("launch"):
+                if plan is None:
+                    return tracing.run_in(ctx, fn, *args)
+                # engine plan notes are emitted while the program is
+                # built/launched here (host side); an async handle's
+                # finish step runs later on the transfer thread and
+                # stays outside the sink by design
+                with kernelscope.explain_scope(plan):
+                    return tracing.run_in(ctx, fn, *args)
 
         def _sync_batch():
             # faultline point: one coalesced device dispatch (the
             # deterministic schedule sees retries as separate calls)
             faultline.fire("batcher.dispatch", batch=b, k=k_bucket)
-            if plan is None:
-                return tracing.run_in(ctx, self._batch_fn, queries,
-                                      k_bucket, allows)
-            with kernelscope.explain_scope(plan):
-                return tracing.run_in(ctx, self._batch_fn, queries,
-                                      k_bucket, allows)
+            return _launch(self._batch_fn, queries, k_bucket, allows)
 
         def _retry_once(first_err: BaseException):
             """Faulted device batch: ONE sync retry. A second failure
@@ -637,23 +664,17 @@ class QueryBatcher:
                 if hf is not None:
                     faultline.fire("batcher.dispatch", batch=b,
                                    k=k_bucket)
-                    if plan is None:
-                        handle = tracing.run_in(ctx, hf, queries,
-                                                k_bucket, allows, sparses)
-                    else:
-                        with kernelscope.explain_scope(plan):
-                            handle = tracing.run_in(ctx, hf, queries,
-                                                    k_bucket, allows,
-                                                    sparses)
+                    handle = _launch(hf, queries, k_bucket, allows,
+                                     sparses)
                 if handle is None:
                     _hbm.release(pad_key)
                     err = DeviceHybridUnavailable(
                         "index cannot run the fused hybrid program for "
                         "this dispatch")
-                    t1 = time.perf_counter()
+                    t1 = stamps["done"] = time.perf_counter()
                     for it in hybrid:
-                        it.t_exec_end = t1
                         it.error = err
+                        it.t_deliver = t1
                         it.event.set()
                     rest = [it for it in coal if it.sparse is None]
                     if rest:
@@ -669,23 +690,12 @@ class QueryBatcher:
                 # device-resident handle to the transfer thread, return
                 # to drain the NEXT batch while this one crosses D2H
                 faultline.fire("batcher.dispatch", batch=b, k=k_bucket)
-                if plan is None:
-                    handle = tracing.run_in(ctx, self._async_fn, queries,
-                                            k_bucket, allows)
-                else:
-                    # engine plan notes are emitted while the program is
-                    # built/launched here (host side); the handle's
-                    # finish step runs later on the transfer thread and
-                    # stays outside the sink by design
-                    with kernelscope.explain_scope(plan):
-                        handle = tracing.run_in(ctx, self._async_fn,
-                                                queries, k_bucket, allows)
+                handle = _launch(self._async_fn, queries, k_bucket,
+                                 allows)
             if handle is not None:
                 n_ep = int(handle.attrs.get("epochs", 0) or 0)
                 if n_ep:
                     flight_rec["epochs"] = n_ep
-                    for it in coal:
-                        it.epochs = n_ep
             if handle is None:
                 ids, dists = _sync_batch()
         except Exception as e:  # noqa: BLE001
@@ -710,11 +720,11 @@ class QueryBatcher:
                     it.explain = plan
         if handle is None:
             _hbm.release(pad_key)
-            t1 = time.perf_counter()
+            t1 = stamps["done"] = time.perf_counter()
             # sync path: no drain stamps exist — wall-window attribution
             # with an explicit source label (the null-device deflake
             # guard: degrade, don't crash or report zeros)
-            _attribute(t1 - t0, 0.0, "wall")
+            _attribute("wall")
             self._deliver(coal, ids, dists, t1)
             _mark_served()
             return
@@ -726,7 +736,7 @@ class QueryBatcher:
 
         def _finish(res):
             try:
-                t1 = time.perf_counter()
+                t1 = stamps["done"] = time.perf_counter()
                 self._deliver(coal, res[0], res[1], t1)
                 _hbm.release(pad_key)
                 _mark_served()
@@ -736,8 +746,7 @@ class QueryBatcher:
                 _fail(e)
 
         def _complete(res, err, t_fetch0, t_fetch1):
-            for it in coal:
-                it.t_fetch_start, it.t_fetch_end = t_fetch0, t_fetch1
+            stamps["fetch0"], stamps["fetch1"] = t_fetch0, t_fetch1
             if err is not None and hybrid:
                 # the sync retry path can't re-run a hybrid program
                 # (no sparse-operand slot) — deliver the fault
@@ -749,9 +758,7 @@ class QueryBatcher:
                 # this result size = attributed device residency with
                 # ZERO added syncs — the drain blocked on this handle's
                 # D2H anyway
-                dev_s, mem_s = kernelscope.attribute(
-                    t_fetch1 - t0, kernelscope.result_nbytes(res))
-                _attribute(dev_s, mem_s, "drain")
+                _attribute("drain", kernelscope.result_nbytes(res))
                 _finish(res)
                 return
             # the device batch (or its D2H drain) faulted on the
@@ -768,7 +775,8 @@ class QueryBatcher:
                     # the retry served through the sync path: wall
                     # attribution (the drain stamps belong to the
                     # faulted attempt, not this result)
-                    _attribute(time.perf_counter() - t0, 0.0, "wall")
+                    stamps["done"] = time.perf_counter()
+                    _attribute("wall")
                     _finish(res2)
 
             threading.Thread(target=_retry_path, daemon=True,
@@ -779,7 +787,7 @@ class QueryBatcher:
             if tp.inflight > 0:
                 self.overlapped_dispatches += 1
                 batcher_overlapped.inc()
-            tp.submit(handle, _complete, ctx=ctx)
+            tp.submit(handle, _complete, ctx=ctx, rec=flight_rec)
         except Exception as e:  # noqa: BLE001 — stopped mid-shutdown
             _fail(e)
 
@@ -787,10 +795,14 @@ class QueryBatcher:
     def _deliver(coal: list[_Pending], ids, dists, t1: float):
         """Route one batch's host results to their waiters (identical
         slicing for the sync and pipelined paths — parity by
-        construction)."""
-        for row, it in enumerate(coal):
-            it.t_exec_end = t1
-            kk = min(it.k, ids.shape[1])
-            it.ids = ids[row, :kk]
-            it.dists = dists[row, :kk]
-            it.event.set()
+        construction). ``t1`` is the record's ``done`` stamp, which the
+        caller has already written: kept in the signature for callers
+        that wrap this (the benchmark's fault injection); each waiter's
+        ``t_deliver`` is taken here, just before its event is set."""
+        with tailboard.dispatch_stage("deliver"):
+            for row, it in enumerate(coal):
+                kk = min(it.k, ids.shape[1])
+                it.ids = ids[row, :kk]
+                it.dists = dists[row, :kk]
+                it.t_deliver = time.perf_counter()
+                it.event.set()

@@ -52,6 +52,24 @@ the hot path.
    write it; a torn read under wrap-around drops one record instead of
    ever blocking a dispatch loop.
 
+5. **Stages** (ISSUE 25) — the same two records, stamped where the
+   work happens. A *staged* request (gRPC Search) carries named stages
+   from the wire to the reply (:data:`REQUEST_STAGES`: ``pool_wait`` ..
+   ``send``, which sum to ``server_residency``, plus ``handler_cpu``)
+   and folds them into
+   ``weaviate_tpu_request_stage_seconds{operation,stage}`` from the same
+   pending record the phases fold from. A dispatch record (the flight
+   record of point 4) is the dispatch's stamp sheet: the batcher's
+   worker and the transfer pipeline's drain thread each bind one *side*
+   of it (:func:`bind_dispatch`) and time leaf-level, non-overlapping
+   :data:`DISPATCH_STAGES` into it, each wrapped in a
+   ``jax.profiler.TraceAnnotation("wtpu.<stage>")`` so a profiler
+   session sees the program's own names on the device's clock. Sides
+   fold into ``weaviate_tpu_dispatch_stage_seconds{kind,stage}`` off the
+   dispatch loop. Annotations exist ONLY on those two threads: a trace
+   reader that sums an event name's cover over threads would let an
+   annotation on 32 request threads swallow every gap.
+
 Env surface (all lazy-read, re-read after :func:`reset_for_tests`):
 
 - ``WEAVIATE_TPU_TAILBOARD``        1 (default) / 0 — timeline on/off
@@ -82,6 +100,28 @@ from collections import deque
 logger = logging.getLogger(__name__)
 
 PHASES = ("queue_wait", "device", "transfer", "host")
+
+#: additive stages of a staged request, in wire order; they sum to
+#: ``server_residency`` up to the stamps' own gaps. ``queue_wait``,
+#: ``device`` and ``transfer`` are the phases of the same name;
+#: ``search_other`` is the collection call's wall time less the stages
+#: stamped inside it (the shard/collection glue's own cost)
+REQUEST_STAGES = ("pool_wait", "parse", "filter", "queue_wait", "device",
+                  "transfer", "wake", "fetch", "search_other", "reply",
+                  "send")
+#: observed beside them, never part of the sum
+REQUEST_EXTRAS = ("handler_cpu", "server_residency")
+
+#: leaf-level stages of one dispatch. ``idle`` (queue empty) and
+#: ``slot_wait`` (transfer window full) are the worker's two waits;
+#: ``finish`` is the drain thread's remainder (result routing glue)
+DISPATCH_STAGES = ("idle", "slot_wait", "assemble", "mask_pack", "launch",
+                   "d2h_wait", "rescore", "deliver", "finish")
+#: the slot wait is the drain thread's busy time by construction: an
+#: annotation over it would out-cover the drain's own stage names in
+#: every device gap a trace reader attributes by summed cover
+_UNANNOTATED = frozenset(("slot_wait",))
+_DISPATCH_STAGE_SET = frozenset(DISPATCH_STAGES)
 
 #: tail-retention reasons, in decision priority order
 TAIL_REASONS = ("deadline", "error", "degraded", "fault", "slow")
@@ -300,9 +340,13 @@ class Timeline:
     waiter wakes, on the request thread), so no lock."""
 
     __slots__ = ("operation", "method", "collection", "tenant", "status",
-                 "degraded", "fault", "phases", "trace", "_t0")
+                 "degraded", "fault", "phases", "trace", "_t0", "stages",
+                 "_entry", "_mark", "_arrival", "_cpu0", "_return", "_term",
+                 "_sides", "_record")
 
-    def __init__(self, operation: str, method: str = ""):
+    def __init__(self, operation: str, method: str = "",
+                 t_entry: float | None = None,
+                 t_arrival: float | None = None):
         self.operation = operation
         self.method = method
         self.collection: str | None = None
@@ -312,11 +356,52 @@ class Timeline:
         self.fault = False
         self.phases: dict[str, float] = {}
         self.trace: dict | None = None  # attached by on_trace_complete
+        # the phases' clock starts here, staged or not: ``host`` and the
+        # request's duration read what they always read
         self._t0 = time.perf_counter()
+        # staged requests only (an edge that passes its entry stamp):
+        # stage name -> seconds, stamped on the request thread
+        self.stages: dict[str, float] | None = None
+        self._sides = None
+        if t_entry is not None:
+            self.stages = {}
+            self._entry = self._mark = t_entry
+            self._arrival = t_entry if t_arrival is None else t_arrival
+            self._cpu0 = time.thread_time()
+            self._return = self._term = 0.0
+            self._record = None
 
     def add_phase(self, name: str, seconds: float) -> None:
         if seconds > 0.0:
             self.phases[name] = self.phases.get(name, 0.0) + seconds
+
+    def add_stage(self, name: str, seconds: float) -> None:
+        st = self.stages
+        if st is not None and seconds > 0.0:
+            st[name] = st.get(name, 0.0) + seconds
+
+    def defer_to(self, context) -> None:
+        """Hold the staged record until the RPC terminates
+        (``context.add_callback``), so ``send`` and ``server_residency``
+        are measured and not guessed. Whichever of handler return and
+        termination comes second pushes the record (``next`` on a shared
+        counter is atomic under the GIL). A context without the hook, or
+        an RPC already over, leaves the timeline undeferred."""
+        if self.stages is None:
+            return
+        self._sides = itertools.count()
+        try:
+            armed = context.add_callback(self._terminated)
+        except Exception:  # noqa: BLE001 — tests stub the context
+            armed = False
+        if not armed:
+            self._sides = None
+
+    def _terminated(self) -> None:
+        # runs on gRPC's serving thread: a stamp and (at most) one push
+        self._term = time.perf_counter()
+        if next(self._sides) == 1:
+            _push_staged(self)
 
 
 _timeline: contextvars.ContextVar[Timeline | None] = contextvars.ContextVar(
@@ -339,8 +424,8 @@ _NULL_TIMELINE_CM = _NullTimelineCM()
 class _TimelineCM:
     __slots__ = ("_tl", "_token")
 
-    def __init__(self, operation: str, method: str):
-        self._tl = Timeline(operation, method)
+    def __init__(self, operation: str, method: str, t_entry, t_arrival):
+        self._tl = Timeline(operation, method, t_entry, t_arrival)
 
     def __enter__(self):
         self._token = _timeline.set(self._tl)
@@ -355,12 +440,16 @@ class _TimelineCM:
         return False
 
 
-def request(operation: str, method: str = ""):
+def request(operation: str, method: str = "",
+            t_entry: float | None = None, t_arrival: float | None = None):
     """Edge entry point: open the always-on timeline for one request.
-    Cheap no-op when the tailboard is disabled."""
+    Cheap no-op when the tailboard is disabled. An edge that passes
+    ``t_entry`` (its handler's first stamp) opens a STAGED timeline;
+    ``t_arrival`` is the stamp taken before the handler's thread pool
+    (``pool_wait`` runs from it to ``t_entry``)."""
     if not enabled():
         return _NULL_TIMELINE_CM
-    return _TimelineCM(operation, method)
+    return _TimelineCM(operation, method, t_entry, t_arrival)
 
 
 def current() -> Timeline | None:
@@ -374,6 +463,25 @@ def phase(name: str, seconds: float) -> None:
     tl = _timeline.get()
     if tl is not None:
         tl.add_phase(name, seconds)
+
+
+def mark(stage: str) -> None:
+    """Close a sequential stage of the live staged timeline at NOW: the
+    time since the previous mark (or the handler's entry) is ``stage``'s.
+    No-op outside a staged timeline."""
+    tl = _timeline.get()
+    if tl is not None and tl.stages is not None:
+        now = time.perf_counter()
+        st = tl.stages
+        st[stage] = st.get(stage, 0.0) + now - tl._mark
+        tl._mark = now
+
+
+def request_stage(name: str, seconds: float) -> None:
+    """Fold an externally-timed stage into the live staged timeline."""
+    tl = _timeline.get()
+    if tl is not None:
+        tl.add_stage(name, seconds)
 
 
 def annotate(collection: str | None = None, tenant: str | None = None) -> None:
@@ -507,13 +615,57 @@ _FOLD_EVERY = 512
 _PENDING_SIZE = 4096
 
 _fold_lock = threading.Lock()
-_pending_buf: list = [None] * _PENDING_SIZE
-_pending_seq = itertools.count(1)
-_pending_folded = 0  # last folded seq (guarded by _fold_lock)
+
+
+class _PendingRing:
+    """Lock-free ring of finished records awaiting the fold. Writers
+    claim a seq (``next`` on a count: atomic under the GIL) and store
+    ``(seq, *fields)``; the fold, under ``_fold_lock``, takes every
+    record past the last folded seq.
+
+    Loss bound: a writer preempted between claiming its seq and storing
+    the record can have that ONE record skipped (a fold that ran in
+    between advances past its seq) — the same drop-one-rather-than-block
+    tradeoff as :class:`FlightRing`, and it costs one observation, never
+    a tail-ring entry (those are kept synchronously at completion)."""
+
+    __slots__ = ("buf", "seq", "folded")
+
+    def __init__(self):
+        self.buf: list = [None] * _PENDING_SIZE
+        self.seq = itertools.count(1)
+        self.folded = 0  # last folded seq (guarded by _fold_lock)
+
+    def push(self, *fields) -> int:
+        seq = next(self.seq)
+        self.buf[seq % _PENDING_SIZE] = (seq,) + fields
+        return seq
+
+    def take(self) -> list:
+        """Caller holds ``_fold_lock``."""
+        found = [r for r in list(self.buf)
+                 if r is not None and r[0] > self.folded]
+        if found:
+            found.sort()
+            self.folded = found[-1][0]
+        return found
+
+
+_pending = _PendingRing()           # finished requests
+_pending_dispatch = _PendingRing()  # finished dispatch sides
 
 
 def _finish_timeline(tl: Timeline, exc: BaseException | None) -> None:
-    duration = time.perf_counter() - tl._t0
+    now = time.perf_counter()
+    duration = now - tl._t0
+    st = tl.stages
+    if st is not None:
+        # the handler's tail since the last mark (trailers, status) is
+        # the reply's; the CPU this thread really had sits beside it
+        st["reply"] = st.get("reply", 0.0) + (now - tl._mark)
+        st["handler_cpu"] = time.thread_time() - tl._cpu0
+        st["pool_wait"] = max(0.0, tl._entry - tl._arrival)
+        tl._return = now
     reason = _tail_reason(tl, duration, exc)
     trace_id = (tl.trace or {}).get("trace_id")
     if reason is not None:  # rare path: keep the full trace NOW
@@ -522,7 +674,7 @@ def _finish_timeline(tl: Timeline, exc: BaseException | None) -> None:
                      for p, v in tl.phases.items()}
         phases_ms["host"] = round(
             max(duration - attributed, 0.0) * 1000.0, 3)
-        _keep_tail({
+        entry = {
             "reason": reason,
             "operation": tl.operation,
             "method": tl.method,
@@ -533,52 +685,121 @@ def _finish_timeline(tl: Timeline, exc: BaseException | None) -> None:
             "phases_ms": phases_ms,
             "kept_at": time.time(),
             "trace": tl.trace,
-        })
-    # record tuple: (seq, operation, phases, duration_s, errored,
-    # collection, tenant, trace_id, bucket) — a tuple, not a dict: this
-    # build runs on every request's thread
+        }
+        if st is not None:  # what is known at handler return (no send)
+            entry["stages_ms"] = {k: round(v * 1000.0, 3)
+                                  for k, v in st.items()}
+        _keep_tail(entry)
+    # record fields: (operation, phases, duration_s, errored, collection,
+    # tenant, trace_id, bucket, stages) — a tuple, not a dict: this build
+    # runs on every request's thread
     # same status-wins rule as _tail_reason (abort control flow is not
     # an availability failure when the edge already mapped a 4xx)
     errored = ((tl.status >= 500) if tl.status is not None
                else (exc is not None))
-    seq = next(_pending_seq)
-    _pending_buf[seq % _PENDING_SIZE] = (
-        seq, tl.operation, tl.phases, duration, errored,
-        tl.collection, tl.tenant,
-        trace_id if reason is not None else None,
-        int(_mono() // _BUCKET_S),
-    )
+    record = (tl.operation, tl.phases, duration, errored,
+              tl.collection, tl.tenant,
+              trace_id if reason is not None else None,
+              int(_mono() // _BUCKET_S), st)
+    if tl._sides is None:
+        if st is not None:  # no termination hook: the handler's return
+            st["send"] = 0.0  # is all the edge can see
+            st["server_residency"] = now - tl._arrival
+        seq = _pending.push(*record)
+    else:
+        tl._record = record
+        seq = next(_finish_seq)
+        if next(tl._sides) == 1:  # the RPC terminated before the return
+            _push_staged(tl)
     if seq % _FOLD_EVERY == 0:
         flush()
 
 
-def flush() -> None:
-    """Fold every pending completion record into the phase histograms
-    and the SLO windows. Called by read points and the amortized inline
-    trigger; idempotent and cheap when there is no backlog. SLO window
-    increments batch per (objective, bucket) so a 512-record fold takes
-    a handful of lock acquisitions, not thousands.
+# handler returns of deferred (staged) timelines: the amortized fold
+# trigger stays on request threads, never on gRPC's serving thread
+_finish_seq = itertools.count(1)
 
-    Lock-free loss bound: a writer preempted between claiming its seq
-    and storing the record can have that ONE record skipped (a fold
-    that ran in between advances past its seq) — the same
-    drop-one-rather-than-block tradeoff as :class:`FlightRing`, and it
-    costs one phase/SLO observation, never a tail-ring entry (those are
-    kept synchronously at completion)."""
-    global _pending_folded
+
+def _push_staged(tl: Timeline) -> None:
+    """Second of (handler return, RPC termination): the staged record is
+    whole. ``send`` is 0 where the RPC ended before the handler did
+    (cancelled, deadline)."""
+    st = tl.stages
+    st["send"] = max(0.0, tl._term - tl._return)
+    st["server_residency"] = max(tl._term, tl._return) - tl._arrival
+    _pending.push(*tl._record)
+
+
+_stage_child_cache: dict[tuple, object] = {}
+
+
+def _stage_child(metric_name: str, *labels):
+    """Cached histogram child of one of the two stage families (closed
+    label sets: operations x stage names, index kinds x stage names)."""
+    key = (metric_name,) + labels
+    child = _stage_child_cache.get(key)
+    if child is None:
+        from weaviate_tpu.runtime import metrics
+
+        child = getattr(metrics, metric_name).labels(*labels)
+        if len(_stage_child_cache) < 4096:
+            _stage_child_cache[key] = child
+    return child
+
+
+def _stage_values(phases: dict, stages: dict) -> tuple:
+    """One staged request -> a value per REQUEST_STAGES + REQUEST_EXTRAS
+    entry, in their order, zero included, so the stages' means sum to
+    the residency's. ``search_other`` is the collection call's wall time
+    less the stages and phases stamped inside it (tests/test_tailboard.py
+    holds every stage to its stated stamps)."""
+    get, phase = stages.get, phases.get
+    queue_wait, device, transfer = (phase("queue_wait", 0.0),
+                                    phase("device", 0.0),
+                                    phase("transfer", 0.0))
+    filt, wake, fetch = get("filter", 0.0), get("wake", 0.0), \
+        get("fetch", 0.0)
+    other = max(0.0, get("search", 0.0) - (
+        filt + wake + fetch + queue_wait + device + transfer))
+    return (get("pool_wait", 0.0), get("parse", 0.0), filt, queue_wait,
+            device, transfer, wake, fetch, other, get("reply", 0.0),
+            get("send", 0.0), get("handler_cpu", 0.0),
+            get("server_residency", 0.0))
+
+
+def _observe_columns(metric_name: str, columns: dict) -> None:
+    """``{label tuple: [values]}`` into a stage family, one lock
+    acquisition a series."""
+    for labels, values in columns.items():
+        _stage_child(metric_name, *labels).observe_many(values)
+
+
+def flush() -> None:
+    """Fold every pending completion record into the phase and stage
+    histograms and the SLO windows, and every finished dispatch side
+    into the dispatch-stage histogram. Called by read points and the
+    amortized inline trigger; idempotent and cheap when there is no
+    backlog. SLO window increments batch per (objective, bucket) so a
+    512-record fold takes a handful of lock acquisitions, not
+    thousands."""
     with _fold_lock:
-        found = [r for r in list(_pending_buf)
-                 if r is not None and r[0] > _pending_folded]
+        columns: dict[tuple, list] = {}
+        try:
+            for (_seq, side) in _pending_dispatch.take():
+                _fold_side(side, columns)
+            _observe_columns("dispatch_stage_seconds", columns)
+        except Exception:  # pragma: no cover — never fail a reader
+            pass
+        found = _pending.take()
         if not found:
             return
-        found.sort()
-        _pending_folded = found[-1][0]
         eng = slo_engine()
         horizon = eng.horizon_buckets()
         tenant_guard, coll_guard = _guards()
         slo_acc: dict[tuple, list[float]] = {}  # (obj, bucket) -> [g, b]
+        staged: dict[str, list] = {}  # operation -> [[a value a stage]]
         for (_seq, operation, phases, duration_s, errored, collection,
-             tenant, trace_id, bucket) in found:
+             tenant, trace_id, bucket, stages) in found:
             host = duration_s - sum(phases.values())
             collection = coll_guard.clamp(collection)
             tenant = tenant_guard.clamp(tenant)
@@ -592,6 +813,9 @@ def flush() -> None:
                 _phase_child(operation, "host", collection,
                              tenant).observe(max(host, 0.0),
                                              exemplar=exemplar)
+                if stages is not None:
+                    staged.setdefault(operation, []).append(
+                        _stage_values(phases, stages))
             except Exception:  # pragma: no cover — never fail a reader
                 pass
             for o in eng.objectives_for(operation):
@@ -600,6 +824,14 @@ def flush() -> None:
                 if verdict is not None:
                     cell = slo_acc.setdefault((o, bucket), [0.0, 0.0])
                     cell[0 if verdict else 1] += 1.0
+        try:
+            names = REQUEST_STAGES + REQUEST_EXTRAS
+            for operation, rows in staged.items():
+                _observe_columns("request_stage_seconds", {
+                    (operation, s): col
+                    for s, col in zip(names, zip(*rows))})
+        except Exception:  # pragma: no cover — never fail a reader
+            pass
         for (o, bucket), (good, bad) in slo_acc.items():
             o.record_bulk(bucket, good, bad, horizon)
     eng.maybe_sweep()
@@ -941,15 +1173,217 @@ def _slowlog() -> FlightRing:
     return _slowlog_ring
 
 
-def record_dispatch(plane: str, **fields) -> dict:
+def new_dispatch(plane: str, kind: str = "") -> dict:
+    """A dispatch record not yet in the ring: the worker opens one
+    before it waits for work, so the wait that precedes a dispatch is
+    stamped into that dispatch's sheet. :func:`record_dispatch` files
+    it."""
+    return {"plane": plane, "kind": kind}
+
+
+def record_dispatch(plane: str, rec: dict | None = None, **fields) -> dict:
     """One dispatch record from the query batcher or the native plane.
     Lock-free, allocation-light — safe on the dispatch hot loop. Returns
-    the live record so a caller may patch in late-arriving fields (the
-    batcher learns its epoch fanout only after the async launch)."""
-    rec = {"plane": plane, "t": time.time()}
+    the live record: it is the dispatch's stamp sheet, which both of its
+    threads stamp into (:func:`bind_dispatch`) and every consumer reads
+    (the waiters' phases and spans, kernelscope's residency, the stage
+    fold), and which late-arriving fields are patched into (the batcher
+    learns its epoch fanout only after the async launch). ``rec``: a
+    record opened earlier by :func:`new_dispatch`."""
+    if rec is None:
+        rec = {"plane": plane}
+    rec["t"] = time.time()
     rec.update(fields)
     _flight().append(rec)
     return rec
+
+
+# -- dispatch sides: leaf-level stages on the profiler's clock ----------------
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, or False (no jax)
+_ANNOTATION_NAMES = {s: "wtpu." + s for s in DISPATCH_STAGES
+                     if s not in _UNANNOTATED}
+
+
+def _resolve_annotation_cls():
+    global _annotation_cls
+    try:
+        from jax.profiler import TraceAnnotation as cls
+    except Exception:  # noqa: BLE001 — observability never requires jax
+        cls = False
+    _annotation_cls = cls
+    return cls
+
+
+_PAUSED = object()  # a side's mark while a nested side runs
+
+
+class _Side:
+    """One thread's side of one dispatch record: RAW STAMPS ONLY. The
+    dispatch threads sit between a notify and a block with the
+    interpreter lock in hand, so they compute nothing here: ``marks`` is
+    the flat list ``[t0, stage0, t1, stage1, ..., t_end]`` (``stage_i``
+    ran from ``t_i`` to ``t_i+1``; None: no stage), and the fold
+    (:func:`_fold_side`, off the dispatch loop) turns it into per-stage
+    seconds. One stage runs at a time (:meth:`mark` names the one that
+    runs from now on and hands back the one that ran until now, which a
+    nested stage restores when it ends), so a side's stages never
+    overlap and cover its wall time.
+
+    While a profiler session runs, and only then
+    (``TraceAnnotation.is_enabled()``: one flag read), the running stage
+    holds a ``TraceAnnotation("wtpu.<stage>")``, so its event lands in
+    the host plane of the same ``.xplane.pb`` as the device's ops. A
+    stage already running when the session starts is not in the trace,
+    as a TraceMe built before the session would not be either."""
+
+    __slots__ = ("rec", "name", "marks", "cur", "ann", "outer")
+
+    def __init__(self, rec: dict, name: str, now: float, stage, outer):
+        self.rec = rec
+        self.name = name
+        self.marks: list = []
+        self.cur = self.ann = None
+        self.outer = outer  # (the side this one paused, its stage) or None
+        self.mark(stage, now)
+
+    def mark(self, stage, now: float | None = None):
+        """``stage`` runs from ``now`` on; -> the stage it takes over
+        from."""
+        if now is None:
+            now = time.perf_counter()
+        ann = self.ann
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            self.ann = None
+        prev, self.cur = self.cur, stage
+        self.marks += (now, stage)
+        cls = _annotation_cls
+        if cls and cls.is_enabled():
+            label = _ANNOTATION_NAMES.get(stage)
+            if label is not None:
+                self.ann = ann = cls(label)
+                ann.__enter__()
+        return prev
+
+
+_bound = threading.local()
+
+
+def bind_dispatch(rec: dict, side: str, stage: str | None = None,
+                  now: float | None = None) -> _Side:
+    """Make ``rec`` this thread's dispatch record until
+    :func:`unbind_dispatch`: ``side`` is ``worker`` or ``drain``,
+    ``stage`` the side's own work, which runs wherever no other stage is
+    marked (``assemble``, ``finish``). Called by the batcher's worker and
+    the transfer pipeline's drain thread only. A bind inside a bind (a
+    solo dispatch inside a drain) pauses the outer side and resumes it
+    at the unbind. -> the side, for the thread's own
+    ``side.mark(stage)`` calls."""
+    if _annotation_cls is None:
+        _resolve_annotation_cls()
+    if now is None:
+        now = time.perf_counter()
+    outer = getattr(_bound, "side", None)
+    if outer is not None:
+        outer = (outer, outer.mark(_PAUSED, now))
+    new = _bound.side = _Side(rec, side, now, stage, outer)
+    return new
+
+
+def unbind_dispatch(keep: bool = True) -> None:
+    """Close this thread's side: one last stamp, and the side goes to
+    the fold ring as it is. ``keep=False`` drops it (a worker that woke
+    only to stop)."""
+    side = getattr(_bound, "side", None)
+    if side is None:
+        return
+    now = time.perf_counter()
+    if side.ann is not None:
+        side.ann.__exit__(None, None, None)
+        side.ann = None
+    side.marks.append(now)
+    if side.outer is None:
+        _bound.side = None
+    else:
+        _bound.side, stage = side.outer
+        _bound.side.mark(stage, now)
+    if keep:
+        _pending_dispatch.push(side)
+
+
+def _fold_side(side: _Side, columns: dict) -> None:
+    """One closed side -> its stages' seconds (into ``columns`` for the
+    stage family and, in ms, into the record as ``<side>_ms``). The
+    worker's side also gives ``worker_wall``, its wall time less what a
+    nested side took: the stages cover it, and ``dispatch_busy_pct``
+    divides by it. Runs under ``_fold_lock``, never on a dispatch
+    loop."""
+    marks = side.marks
+    stages: dict[str, float] = {}
+    paused = 0.0
+    for i in range(1, len(marks) - 1, 2):
+        stage, seconds = marks[i], marks[i + 1] - marks[i - 1]
+        if stage is _PAUSED:
+            paused += seconds
+        elif stage is not None:
+            stages[stage] = stages.get(stage, 0.0) + seconds
+    if side.name == "worker":
+        stages["worker_wall"] = marks[-1] - marks[0] - paused
+    rec = side.rec
+    rec[side.name + "_ms"] = {k: v * 1000.0 for k, v in stages.items()}
+    kind = rec.get("kind") or rec["plane"]
+    if rec.get("path") == "solo":
+        kind += ".solo"
+    for name, v in stages.items():
+        columns.setdefault((kind, name), []).append(v)
+
+
+class dispatch_stage:
+    """``with dispatch_stage(name):`` times ``name`` (one of
+    :data:`DISPATCH_STAGES`) into the dispatch record bound to this
+    thread, and gives the time back to the stage it interrupted; a
+    no-op on every other thread."""
+
+    __slots__ = ("_name", "_side", "_prev")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self):
+        self._side = side = getattr(_bound, "side", None)
+        if side is not None:
+            self._prev = side.mark(self._name)
+        return side
+
+    def __exit__(self, *exc):
+        if self._side is not None:
+            self._side.mark(self._prev)
+        return False
+
+
+def open_stage(name: str, t0: float):
+    """``tracing.span(..., stage=name)``'s hand-over: the span took its
+    stamp once and gives it to the record too. A dispatch stage goes to
+    the side bound to this thread, any other name to the live staged
+    timeline. -> a token for :func:`close_stage`, None where neither
+    record is live here."""
+    if name in _DISPATCH_STAGE_SET:
+        side = getattr(_bound, "side", None)
+        return None if side is None else (side, side.mark(name, t0))
+    tl = _timeline.get()
+    if tl is None or tl.stages is None:
+        return None
+    return (tl, name, t0)
+
+
+def close_stage(token, t1: float) -> None:
+    if len(token) == 2:  # (side, the stage this one interrupted)
+        token[0].mark(token[1], t1)
+    else:
+        tl, name, t0 = token
+        st = tl.stages
+        st[name] = st.get(name, 0.0) + t1 - t0
 
 
 def slow_root(record: dict) -> None:
@@ -1083,7 +1517,7 @@ def reset_for_tests() -> None:
     global _enabled_cached, _forced, _slow_map, _data_dir
     global _tail_ring, _flight_ring, _slowlog_ring, _slo_engine
     global _tenant_guard, _collection_guard, _last_snapshot
-    global _pending_seq, _pending_folded
+    global _pending, _pending_dispatch, _finish_seq
     _enabled_cached = None
     _forced = None
     _slow_map = None
@@ -1096,10 +1530,10 @@ def reset_for_tests() -> None:
     _tenant_guard = None
     _collection_guard = None
     _phase_child_cache.clear()
+    _stage_child_cache.clear()
     with _fold_lock:
-        for i in range(len(_pending_buf)):
-            _pending_buf[i] = None
-        _pending_seq = itertools.count(1)
-        _pending_folded = 0
+        _pending = _PendingRing()
+        _pending_dispatch = _PendingRing()
+        _finish_seq = itertools.count(1)
     with _snapshot_lock:
         _last_snapshot = None

@@ -52,6 +52,8 @@ import threading
 import time
 from collections import deque
 
+from weaviate_tpu.runtime import tailboard
+
 logger = logging.getLogger(__name__)
 slow_logger = logging.getLogger("weaviate_tpu.slow_query")
 
@@ -78,7 +80,7 @@ class Span:
                  "start_ms", "duration_ms", "_t0")
 
     def __init__(self, trace_id: str, parent_id: str | None, name: str,
-                 attrs: dict, start_ms: float):
+                 attrs: dict, start_ms: float, t0: float | None = None):
         self.trace_id = trace_id
         self.span_id = _new_id(8)
         self.parent_id = parent_id
@@ -86,7 +88,7 @@ class Span:
         self.attrs = attrs
         self.start_ms = start_ms
         self.duration_ms = 0.0
-        self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter() if t0 is None else t0
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -272,8 +274,9 @@ def _observe_metric(name: str, duration_s: float) -> None:
         pass
 
 
-def _finish(tr: Trace, sp: Span) -> None:
-    sp.duration_ms = (time.perf_counter() - sp._t0) * 1000.0
+def _finish(tr: Trace, sp: Span, t1: float | None = None) -> None:
+    sp.duration_ms = ((time.perf_counter() if t1 is None else t1)
+                      - sp._t0) * 1000.0
     tr.add(sp.to_dict())
     _observe_metric(sp.name, sp.duration_ms / 1000.0)
 
@@ -306,8 +309,6 @@ def _finalize(tr: Trace, root: Span) -> None:
     # trace and decides when the timeline closes (status known); outside
     # one it makes a standalone slow/fault decision
     try:
-        from weaviate_tpu.runtime import tailboard
-
         tailboard.on_trace_complete(d, root.name, root.duration_ms)
     except Exception:  # observability must never fail the request
         pass
@@ -332,8 +333,6 @@ def _finalize(tr: Trace, root: Span) -> None:
 
         slow_logger.warning("slow_query %s", _json.dumps(record))
         try:
-            from weaviate_tpu.runtime import tailboard
-
             tailboard.slow_root(record)
         except Exception:
             pass
@@ -371,9 +370,53 @@ class _SpanCM:
         return False
 
 
-def span(name: str, **attrs) -> _SpanCM:
-    """Nested span under the current trace; no-op outside one."""
-    return _SpanCM(name, attrs)
+class _StagedSpanCM(_SpanCM):
+    """A span site that is also a stage of the always-on records
+    (tailboard): ONE stamp at entry and one at exit, handed to both the
+    trace (when there is one) and the request's or the dispatch's
+    record. The stage is recorded with or without a trace."""
+
+    __slots__ = ("stage", "_stage_token")
+
+    def __init__(self, name: str, attrs: dict, stage: str):
+        self.name = name
+        self.attrs = attrs
+        self.stage = stage
+
+    def __enter__(self):
+        t0 = time.perf_counter()
+        self._stage_token = tailboard.open_stage(self.stage, t0)
+        cur = _current.get()
+        if cur is None:
+            self._pair = None
+            return NULL_SPAN
+        tr, parent = cur
+        sp = Span(tr.trace_id, parent.span_id, self.name, self.attrs,
+                  (t0 - tr._t0) * 1000.0, t0)
+        self._pair = (tr, sp)
+        self._token = _current.set((tr, sp))
+        return sp
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._stage_token is not None:
+            tailboard.close_stage(self._stage_token, t1)
+        if self._pair is None:
+            return False
+        tr, sp = self._pair
+        _finish(tr, sp, t1)
+        _current.reset(self._token)
+        return False
+
+
+def span(name: str, stage: str | None = None, **attrs) -> _SpanCM:
+    """Nested span under the current trace; no-op outside one.
+    ``stage`` names the tailboard stage this site also is (a request
+    stage on a request thread, a dispatch stage on the batcher's worker
+    or the drain thread): the site takes its stamps once for both."""
+    if stage is None:
+        return _SpanCM(name, attrs)
+    return _StagedSpanCM(name, attrs, stage)
 
 
 def record_span(name: str, start_s: float, end_s: float, **attrs) -> None:
@@ -465,7 +508,7 @@ def d2h(*values):
     import numpy as _np
 
     n_arrays = sum(1 for v in values if v is not None)
-    with span("transfer.d2h", arrays=n_arrays) as sp:
+    with span("transfer.d2h", stage="d2h_wait", arrays=n_arrays) as sp:
         cur = _current.get()
         synced = False
         if cur is not None and cur[0].sampled and n_arrays:

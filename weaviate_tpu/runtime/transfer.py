@@ -42,7 +42,7 @@ import threading
 import time
 from collections import deque
 
-from weaviate_tpu.runtime import faultline, tracing
+from weaviate_tpu.runtime import faultline, tailboard, tracing
 
 _UNSET = object()
 
@@ -144,7 +144,11 @@ class TransferPipeline:
     N+1 dispatched, batch N+2's dispatcher waits). ``callback(value,
     error, t_fetch_start, t_fetch_end)`` runs on the drain thread;
     ``ctx`` (a ``tracing.capture()`` handle) scopes the fetch so the
-    ``transfer.d2h`` span lands in a real request trace.
+    ``transfer.d2h`` span lands in a real request trace. ``rec`` (the
+    submitter's tailboard dispatch record) makes the drain thread that
+    record's ``drain`` side while it fetches and calls back: the
+    ``d2h_wait`` / ``rescore`` / ``deliver`` stages stamped underneath
+    land in it, and ``finish`` is what is left of the drain's time.
 
     ``stop()`` drains everything already submitted — in-flight waiters
     get their results (or the fetch error), never a hang — then joins
@@ -180,7 +184,8 @@ class TransferPipeline:
                    and len(self._q) + self._inflight >= self.depth):
                 self._cv.wait(timeout=1.0)
 
-    def submit(self, handle: DeviceResultHandle, callback, ctx=None):
+    def submit(self, handle: DeviceResultHandle, callback, ctx=None,
+               rec: dict | None = None):
         # kernelscope's dispatch-submit stamp: paired with the drain
         # thread's post-``result()`` stamp (t_fetch_end in the callback)
         # it bounds the device+memcpy window of this handle without a
@@ -192,7 +197,7 @@ class TransferPipeline:
                 self._cv.wait(timeout=1.0)
             if self._stopped:
                 raise RuntimeError(f"transfer pipeline {self.name} stopped")
-            self._q.append((handle, callback, ctx))
+            self._q.append((handle, callback, ctx, rec))
             if self._thread is None or not self._thread.is_alive():
                 self._thread = threading.Thread(
                     target=self._run, name=self.name, daemon=True)
@@ -215,20 +220,26 @@ class TransferPipeline:
                 if not self._q:  # stopped and drained
                     self._cv.notify_all()
                     return
-                handle, callback, ctx = self._q.popleft()
+                handle, callback, ctx, rec = self._q.popleft()
                 self._inflight += 1
             err = None
             value = None
             t0 = time.perf_counter()
+            if rec is not None:
+                # ``finish`` runs wherever the fetch and the callback
+                # mark no stage of their own (d2h_wait, rescore, deliver)
+                tailboard.bind_dispatch(rec, "drain", "finish", t0)
             try:
                 value = tracing.run_in(ctx, handle.result)
-            except BaseException as e:  # noqa: BLE001 — deliver to waiters
+            except BaseException as e:  # noqa: BLE001 — to waiters
                 err = e
             t1 = time.perf_counter()
             try:
                 callback(value, err, t0, t1)
-            except Exception:  # noqa: BLE001 — a bad callback must not
-                pass           # kill the drain thread for later batches
+            except Exception:  # noqa: BLE001 — a bad callback must
+                pass           # not kill the drain thread
+            if rec is not None:
+                tailboard.unbind_dispatch()
             with self._cv:
                 self._inflight -= 1
                 self.transferred += 1
