@@ -183,6 +183,393 @@ def test_oversized_corpus_is_skipped_with_reason(tmp_path, monkeypatch):
         db.close()
 
 
+# -- canary lifecycle: seal on quiescence, on stated clocks --------------------
+
+
+def _seal_counts():
+    from weaviate_tpu.runtime import metrics
+
+    out = {t: metrics.canary_seals_total.labels(t).value
+           for t in ("quiet", "interval", "forced")}
+    out["seconds"] = sum(metrics.canary_seal_seconds_total.labels(t).value
+                         for t in ("quiet", "interval", "forced"))
+    out["deferrals"] = metrics.canary_deferrals_total.labels().value
+    return out
+
+
+def _delta(before):
+    return {k: v - before[k] for k, v in _seal_counts().items()}
+
+
+def _grow(col, n, dim=8, seed=99):
+    rng = np.random.default_rng(seed)
+    for _ in range(n):
+        col.put_object({}, vector=rng.standard_normal(dim)
+                       .astype(np.float32))
+
+
+def test_first_look_after_the_token_held_still_seals_quiet(tmp_path):
+    """The look reads tokens only; the first one that finds a changed
+    token QUIET_S old gives that canary its cycle: sealed on the whole
+    corpus with trigger ``quiet``, then probed, as at a tick."""
+    db, _ = _mk_db(tmp_path)
+    try:
+        was = _seal_counts()
+        assert driftwatch.look(now=100.0) is True      # first sight: moved
+        assert driftwatch.look(now=101.0) is True      # still for 1 s only
+        assert _delta(was)["quiet"] == 0
+        c = _only_canary(driftwatch.snapshot())
+        assert c["epoch_token"] is None and c["skipped"] is None
+        (canary,) = driftwatch._canaries.values()
+        read, during = canary.corpus_fn, []
+
+        def corpus_fn():
+            during.append(_only_canary(driftwatch.snapshot())["epoch_token"])
+            return read()
+
+        canary.corpus_fn = corpus_fn
+        assert driftwatch.look(now=100.0 + driftwatch.QUIET_S) is False
+        assert during == [None]        # the token is published with the seal
+        d = _delta(was)
+        assert (d["quiet"], d["interval"], d["forced"]) == (1, 0, 0)
+        assert d["seconds"] > 0 and d["deferrals"] == 0
+        c = _only_canary(driftwatch.snapshot())
+        assert "32" in c["epoch_token"] and c["skipped"] is None
+        assert len(c["probe_doc_ids"]) == 8
+        assert c["last"]["recall"] == 1.0 and len(c["history"]) == 1
+        assert driftwatch.snapshot()["cycle"] == 1
+        # sealed on this token: further looks do nothing and back off
+        assert driftwatch.look(now=500.0) is False
+        assert _delta(was)["quiet"] == 1
+    finally:
+        db.close()
+
+
+def test_scheduled_cycle_defers_a_moving_canary(tmp_path):
+    """A tick that finds the token different from the one its previous
+    look saw leaves the canary alone: no ground truth, no probes, the
+    public state as it was but for ``last["deferred"]`` — ``skipped``
+    stays None and ``epoch_token`` the last SEALED token, so a reader
+    that waits for the seal of the whole corpus is not talked past."""
+    db, col = _mk_db(tmp_path)
+    try:
+        assert driftwatch.run_cycle(now=0.0)           # forced: sealed on 32
+        sealed = _only_canary(driftwatch.snapshot())
+        assert sealed["last"]["recall"] == 1.0
+        was = _seal_counts()
+        _grow(col, 8)
+        assert driftwatch.run_cycle(scheduled=True, now=30.0)
+        c = _only_canary(driftwatch.snapshot())
+        assert c["skipped"] is None
+        assert c["epoch_token"] == sealed["epoch_token"]
+        assert c["probe_doc_ids"] == sealed["probe_doc_ids"]
+        assert c["last"]["deferred"]["cycles"] == 1
+        assert "40" in c["last"]["deferred"]["token"]
+        assert c["last"]["recall"] == 1.0              # the older reading
+        assert len(c["history"]) == len(sealed["history"])  # no probe ran
+        d = _delta(was)
+        assert d["deferrals"] == 1
+        assert d["quiet"] + d["interval"] + d["forced"] == 0
+        assert driftwatch.snapshot()["gateOk"]
+        # the writes have stopped: the tick after seals (an interval has
+        # passed since the last seal) and probes the new corpus
+        assert driftwatch.run_cycle(scheduled=True, now=60.0)
+        c = _only_canary(driftwatch.snapshot())
+        assert "40" in c["epoch_token"] and "deferred" not in c["last"]
+        assert c["last"]["recall"] == 1.0
+        assert _delta(was)["quiet"] == 1
+    finally:
+        db.close()
+
+
+def test_deferred_canary_keeps_its_open_finding(tmp_path):
+    """A deferred canary was not probed, so it was not judged: an open
+    recall finding must neither close nor be counted a second time."""
+    from weaviate_tpu.runtime import metrics
+
+    db, col = _mk_db(tmp_path)
+    try:
+        driftwatch.run_cycle(now=0.0)
+        idx = _shard(col).vector_indexes[""]
+        live = int(len(idx))
+        idx._slot_to_id[:live] = np.roll(idx._slot_to_id[:live], 1)
+        driftwatch.run_cycle(now=1.0)
+        assert not driftwatch.snapshot()["gateOk"]
+        opened = metrics.drift_findings_total.labels("canary",
+                                                     "recall").value
+        _grow(col, 1)
+        driftwatch.run_cycle(scheduled=True, now=31.0)  # moving: deferred
+        snap = driftwatch.snapshot()
+        assert not snap["gateOk"]
+        assert "drift:canary" in degrade.health()["unhealthy"]
+        assert metrics.drift_findings_total.labels(
+            "canary", "recall").value == opened
+    finally:
+        db.close()
+
+
+def test_look_runs_only_the_canary_that_went_quiet(tmp_path):
+    """Tenants are shards and each has a canary: a look costs a token
+    read a canary, and the cycle it starts is the quiet canary's alone.
+    The other is neither probed nor judged: its open finding stays."""
+    db, col = _mk_db(tmp_path)
+    try:
+        db.create_collection(CollectionConfig(name="Other"))
+        other = db.get_collection("Other")
+        _grow(other, 16)
+        driftwatch.run_cycle(now=0.0)                  # both sealed
+        idx = _shard(col).vector_indexes[""]
+        live = int(len(idx))
+        idx._slot_to_id[:live] = np.roll(idx._slot_to_id[:live], 1)
+        driftwatch.run_cycle(now=1.0)                  # Drift: recall finding
+        assert not driftwatch.snapshot()["gateOk"]
+        before = driftwatch.snapshot()["canaries"]
+        _grow(other, 4)
+        driftwatch.look(now=40.0)
+        driftwatch.look(now=42.0)                      # Other: quiet
+        after = driftwatch.snapshot()
+        drift = next(k for k in before if k.startswith("Drift/"))
+        moved = next(k for k in before if k.startswith("Other/"))
+        assert len(after["canaries"][drift]["history"]) \
+            == len(before[drift]["history"])
+        assert len(after["canaries"][moved]["history"]) \
+            == len(before[moved]["history"]) + 1
+        assert "20" in after["canaries"][moved]["epoch_token"]
+        assert not after["gateOk"]
+        assert [f["key"] for f in after["findings"]
+                if f["leg"] == "canary"] == [f"canary:{drift}:recall"]
+    finally:
+        db.close()
+
+
+def test_never_quiet_stream_reseals_within_the_stated_bound(tmp_path):
+    """Writes before every tick: MAX_DEFERRALS ticks defer, the next one
+    seals what is there (trigger ``interval``) and probes it, so the
+    ground truth is never older than MAX_DEFERRALS + 1 intervals."""
+    db, col = _mk_db(tmp_path)
+    try:
+        driftwatch.run_cycle(now=0.0)
+        was = _seal_counts()
+        for tick in range(1, driftwatch.MAX_DEFERRALS + 1):
+            _grow(col, 1, seed=tick)
+            driftwatch.run_cycle(scheduled=True, now=30.0 * tick)
+            c = _only_canary(driftwatch.snapshot())
+            assert c["last"]["deferred"]["cycles"] == tick
+            assert "32" in c["epoch_token"]
+        assert _delta(was)["deferrals"] == driftwatch.MAX_DEFERRALS
+        assert _delta(was)["interval"] == 0
+        _grow(col, 1, seed=77)
+        driftwatch.run_cycle(scheduled=True,
+                             now=30.0 * (driftwatch.MAX_DEFERRALS + 1))
+        c = _only_canary(driftwatch.snapshot())
+        rows = 32 + driftwatch.MAX_DEFERRALS + 1
+        assert str(rows) in c["epoch_token"]
+        assert "deferred" not in c["last"] and c["last"]["recall"] == 1.0
+        d = _delta(was)
+        assert (d["interval"], d["quiet"], d["forced"]) == (1, 0, 0)
+        assert d["deferrals"] == driftwatch.MAX_DEFERRALS
+    finally:
+        db.close()
+
+
+def test_quiet_seals_come_at_most_once_an_interval(tmp_path):
+    """Writes in short bursts cost a seal an interval, not a seal a
+    burst: a look that finds the token quiet again sooner waits."""
+    db, col = _mk_db(tmp_path)
+    try:
+        driftwatch.look(now=0.0)
+        driftwatch.look(now=2.0)                       # sealed on 32
+        was = _seal_counts()
+        _grow(col, 1)
+        assert driftwatch.look(now=3.0) is True
+        assert driftwatch.look(now=5.5) is True        # quiet, but too soon
+        assert _delta(was)["quiet"] == 0
+        assert "32" in _only_canary(driftwatch.snapshot())["epoch_token"]
+        assert driftwatch.look(now=2.0 + driftwatch.interval_s() + 1.0) \
+            is False
+        assert _delta(was)["quiet"] == 1
+        assert "33" in _only_canary(driftwatch.snapshot())["epoch_token"]
+    finally:
+        db.close()
+
+
+def test_run_now_seals_at_once_and_the_tick_is_the_scheduled_cycle(tmp_path):
+    """``cycles.run_now("driftwatch")`` is the unconditional entry: no
+    look has seen this corpus, it is sealed and probed all the same
+    (trigger ``forced``). What the scheduler itself calls is the
+    scheduled cycle, which defers at first sight."""
+    db, col = _mk_db(tmp_path)
+    try:
+        was = _seal_counts()
+        assert db.cycles.run_now("driftwatch")
+        c = _only_canary(driftwatch.snapshot())
+        assert "32" in c["epoch_token"] and c["last"]["recall"] == 1.0
+        assert _delta(was)["forced"] == 1
+        _grow(col, 4)
+        cb = db.cycles._callbacks["driftwatch"]
+        cb.run()                                       # as the scheduler does
+        assert _delta(was)["deferrals"] == 1
+        assert "32" in _only_canary(driftwatch.snapshot())["epoch_token"]
+        assert db.cycles.run_now("driftwatch")
+        assert _delta(was)["forced"] == 2
+        assert "36" in _only_canary(driftwatch.snapshot())["epoch_token"]
+        assert "driftwatch-look" in db.cycles.stats()
+    finally:
+        db.close()
+
+
+def test_oversized_corpus_is_skipped_before_any_read(tmp_path, monkeypatch):
+    monkeypatch.setenv("WEAVIATE_TPU_DRIFT_CANARY_MAX_ROWS", "4")
+    db, col = _mk_db(tmp_path)
+    try:
+        shard = _shard(col)
+
+        def no_read(*a, **k):
+            raise AssertionError("the object store was read")
+
+        monkeypatch.setattr(shard.objects, "iter_items", no_read)
+        monkeypatch.setattr(shard, "objects_by_doc_ids", no_read)
+        driftwatch.run_cycle(now=0.0)
+        c = _only_canary(driftwatch.snapshot())
+        assert "32 rows over WEAVIATE_TPU_DRIFT_CANARY_MAX_ROWS" \
+            in c["skipped"]
+        # skipped on this token: later looks and ticks have nothing to do
+        was = _seal_counts()
+        driftwatch.look(now=10.0)
+        driftwatch.look(now=50.0)
+        driftwatch.run_cycle(scheduled=True, now=60.0)
+        d = _delta(was)
+        assert d["quiet"] + d["interval"] + d["forced"] == 0
+    finally:
+        db.close()
+
+
+# -- the one cheap pass: same rows, same ground truth --------------------------
+
+
+def _oracle_corpus(shard, vec_name):
+    """The ground truth's rows as they were read before the one-pass
+    reader: a point look-up and a full ``StorageObject`` a doc."""
+    idx = shard.vector_indexes[vec_name]
+    doc_ids = sorted(int(d) for d in idx._id_to_slot)
+    ids, vecs = [], []
+    for d, obj in zip(doc_ids, shard.objects_by_doc_ids(doc_ids)):
+        v = None if obj is None else obj.vectors.get(vec_name)
+        if v is not None:
+            ids.append(d)
+            vecs.append(np.asarray(v, dtype=np.float32))
+    return np.asarray(ids, dtype=np.int64), np.stack(vecs)
+
+
+def test_ground_truth_equals_the_point_lookup_oracle(tmp_path):
+    """Same seed, same corpus => the probe set and every probe's
+    ground-truth ids are what the N-point-look-up seal computed."""
+    db, col = _mk_db(tmp_path, n=200)
+    try:
+        shard = _shard(col)
+        for u in list(shard._doc_to_uuid.values())[5:40:7]:
+            col.delete_object(u)
+        shard.objects.flush()                           # segments + memtable
+        _grow(col, 30)
+        driftwatch.run_cycle(now=0.0)
+        (c,) = driftwatch._canaries.values()
+        ids, vecs = _oracle_corpus(shard, "")
+        got_ids, got_vecs = c.corpus_fn()
+        assert got_ids.tolist() == ids.tolist()
+        assert got_vecs.tobytes() == vecs.tobytes()
+        n = len(ids)
+        sel = np.sort(driftwatch._probe_rng(c.key).choice(
+            n, size=8, replace=False))
+        assert c.probe_ids.tolist() == ids[sel].tolist()
+        d = np.asarray(shard._host_pairwise(vecs[sel], vecs, "l2-squared"),
+                       dtype=np.float64)
+        top = np.argsort(d, axis=1, kind="stable")[:, :driftwatch.CANARY_K]
+        assert [g.tolist() for g in c.gt] == [ids[t].tolist() for t in top]
+    finally:
+        db.close()
+
+
+@pytest.mark.parametrize("vec_name", ["", "title"])
+def test_one_pass_corpus_matches_from_bytes(tmp_path, vec_name):
+    """corpus_fn's rows are bit for bit ``StorageObject.from_bytes(raw)
+    .vectors[name]``, for the unnamed and a named vector, with objects
+    that lack that vector and a deleted doc left out."""
+    from weaviate_tpu.storage.objects import StorageObject
+
+    db = Database(str(tmp_path))
+    db.create_collection(CollectionConfig(name="Drift"))
+    col = db.get_collection("Drift")
+    rng = np.random.default_rng(3)
+    try:
+        uuids = []
+        for i in range(24):
+            vectors = {"title": rng.standard_normal(6).astype(np.float32),
+                       "body": rng.standard_normal(5).astype(np.float32)}
+            if i % 4 == 0:
+                del vectors["title"]                    # lacks the named one
+            vec = None if i % 3 == 0 else \
+                rng.standard_normal(8).astype(np.float32)  # lacks the unnamed
+            uuids.append(col.put_object({"n": i}, vector=vec,
+                                        vectors=vectors))
+        col.delete_object(uuids[1])
+        col.delete_object(uuids[2])
+        shard = _shard(col)
+        (c,) = [c for c in driftwatch._canaries.values()
+                if c.key.endswith("/" + (vec_name or "-"))]
+        ids, vecs = c.corpus_fn()
+        want = {}
+        for _key, raw in shard.objects.iter_items():
+            obj = StorageObject.from_bytes(raw)
+            if vec_name in obj.vectors:
+                want[obj.doc_id] = obj.vectors[vec_name]
+        assert ids.tolist() == sorted(want)
+        assert len(ids) == {"": 14, "title": 16}[vec_name]
+        for d, row in zip(ids.tolist(), vecs):
+            assert row.tobytes() == want[d].tobytes()
+    finally:
+        db.close()
+
+
+def _obj(vectors):
+    from weaviate_tpu.storage.objects import StorageObject
+
+    return StorageObject(uuid="00000000-0000-0000-0000-00000000002a",
+                         doc_id=42, properties={"a": [1, "x"], "b": None},
+                         vectors=vectors)
+
+
+_V = {name: np.random.default_rng(n).standard_normal(d).astype(np.float32)
+      for n, (name, d) in enumerate([("", 8), ("body", 5), ("title", 8)])}
+
+
+@pytest.mark.parametrize("vectors,name,found", [
+    ({"": _V[""]}, "", True),
+    ({"": _V[""], "body": _V["body"], "title": _V["title"]}, "", True),
+    ({"": _V[""], "body": _V["body"], "title": _V["title"]}, "title", True),
+    ({"body": _V["body"], "title": _V["title"]}, "title", True),
+    ({"": _V[""]}, "title", False),                    # no such vector
+    ({"body": _V["body"]}, "", False),                 # no unnamed vector
+    ({}, "", False),                                   # no vector at all
+    ({"title": _V["body"]}, "title", False),           # another length
+], ids=["unnamed", "unnamed-among-named", "named", "named-without-unnamed",
+        "named-missing", "unnamed-missing", "no-vectors", "other-length"])
+def test_vector_only_reader_matches_from_bytes(vectors, name, found):
+    """``read_vector_into`` beside ``from_bytes``: the same bits in the
+    caller's row and the doc id, or None with the row untouched."""
+    from weaviate_tpu.storage.objects import StorageObject
+
+    raw = _obj(vectors).to_bytes()
+    out = np.full(8, np.float32(7.0))
+    got = StorageObject.read_vector_into(raw, name, out)
+    if found:
+        assert got == 42
+        assert out.tobytes() == \
+            StorageObject.from_bytes(raw).vectors[name].tobytes()
+    else:
+        assert got is None
+        assert out.tobytes() == np.full(8, np.float32(7.0)).tobytes()
+
+
 # -- sabotage-validated incidents (acceptance criteria) -----------------------
 
 

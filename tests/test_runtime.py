@@ -81,6 +81,24 @@ def test_cycle_trigger_and_unregister():
     assert "manual" not in cm.stats()
 
 
+def test_cycle_run_now_calls_the_on_demand_form():
+    """A job that does more when asked for than at a tick registers both:
+    the scheduler's run calls ``fn``, ``run_now`` calls ``on_demand``,
+    and both feed the same bookkeeping."""
+    cm = CycleManager()
+    calls = []
+    cb = cm.register("job", lambda: calls.append("tick") or True,
+                     interval=999.0,
+                     on_demand=lambda: calls.append("asked") or False)
+    plain = cm.register("plain", lambda: calls.append("plain") or True,
+                        interval=999.0)
+    cb.run()
+    assert cm.run_now("job") and cm.run_now("plain")
+    assert calls == ["tick", "asked", "plain"]
+    assert cb.runs == 2 and plain.runs == 1
+    assert cb.current_interval == pytest.approx(999.0 * 2)  # asked: idle
+
+
 # -- memwatch ------------------------------------------------------------------
 
 

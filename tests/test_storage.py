@@ -309,6 +309,40 @@ def test_bloom_filters_short_circuit_get_misses(tmp_path):
     b.close()
 
 
+@pytest.mark.parametrize("start", [None, 0, 4095, 4096, 4097, 9999, 10000])
+def test_segment_walk_crosses_its_index_chunks(tmp_path, start):
+    """A segment's cursor reads its index a chunk of 4,096 entries at a
+    time: every item, in key order, from any seek position."""
+    b = Bucket(str(tmp_path), "objects", "replace")
+    n = 10_000
+    b.put_many((f"k{i:06d}".encode(), i) for i in range(n))
+    b.flush()
+    assert b.segment_count == 1
+    lo = 0 if start is None else start
+    seek = None if start is None else f"k{start:06d}".encode()
+    got = list(b.iter_range(seek))
+    assert got == [(f"k{i:06d}".encode(), i) for i in range(lo, n)]
+    b.close()
+
+
+@pytest.mark.parametrize("n", [0, 1, 7, 1000])
+def test_bloom_words_equal_the_bit_by_bit_filter(n):
+    """The filter built for all keys at once is, word for word, the one
+    the scalar double-hashing loop sets bit by bit (the on-disk format
+    readers probe with that same loop)."""
+    from weaviate_tpu.storage import kv as kv_mod
+
+    keys = [f"key-{i:05d}".encode() for i in range(n)]
+    words = max((n * kv_mod._BLOOM_BITS_PER_KEY + 63) // 64, 1) if n else 0
+    want = np.zeros(words, dtype=np.uint64)
+    for k in keys:
+        h1, h2 = kv_mod._bloom_hashes(k)
+        for i in range(kv_mod._BLOOM_K):
+            bit = (h1 + i * h2) % (words * 64)
+            want[bit >> 6] |= np.uint64(1 << (bit & 63))
+    assert kv_mod._bloom_bytes(keys, words) == want.astype("<u8").tobytes()
+
+
 def test_sealed_unflushed_memtables_survive_crash(tmp_path):
     """Sealed memtables whose segments were never written (background
     flush hadn't run at crash) must replay from their WAL files — the

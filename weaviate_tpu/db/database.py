@@ -88,10 +88,17 @@ class Database:
                              maintenance_interval)
         # driftwatch (ROADMAP item 1c): canary probes through the real
         # batcher + live-telemetry classification against benchkeeper
-        # bands, on its own (longer) period — run_now("driftwatch") is
-        # the deterministic test entry
-        self.cycles.register("driftwatch", driftwatch.run_cycle,
-                             driftwatch.interval_s())
+        # bands, on its own (longer) period. A tick defers a canary whose
+        # corpus still moves; run_now("driftwatch"), the deterministic
+        # test entry, seals whatever differs
+        self.cycles.register(
+            "driftwatch", lambda: driftwatch.run_cycle(scheduled=True),
+            driftwatch.interval_s(), on_demand=driftwatch.run_cycle)
+        # between cycles only each canary's corpus token is read (O(1) a
+        # canary); one that moved and then held still is sealed at once
+        self.cycles.register("driftwatch-look", driftwatch.look,
+                             driftwatch.LOOK_INTERVAL_S,
+                             max_interval=4 * driftwatch.LOOK_INTERVAL_S)
         if start_cycles:
             self.cycles.start()
         self._load_existing()

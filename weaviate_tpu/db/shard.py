@@ -343,17 +343,30 @@ class Shard:
             id_map = getattr(idx, "_id_to_slot", None)
             if not id_map:
                 return None
-            doc_ids = sorted(int(d) for d in id_map)
-            objs = self.objects_by_doc_ids(doc_ids)
-            ids, vecs = [], []
-            for d, obj in zip(doc_ids, objs):
-                v = None if obj is None else obj.vectors.get(vec_name)
-                if v is not None:
-                    ids.append(d)
-                    vecs.append(np.asarray(v, dtype=np.float32))
-            if not ids:
+            # one ordered walk of the objects bucket, each object's one
+            # vector copied straight into its row: no StorageObject is
+            # built and no point look-up made. Which docs count is the
+            # index's id map; the rows are the durable store's own.
+            cap = len(id_map)
+            ids = np.empty(cap, dtype=np.int64)
+            vecs = np.empty((cap, idx.dim), dtype=np.float32)
+            read = StorageObject.read_vector_into
+            n = 0
+            for _key, raw in self.objects.iter_items():
+                if n == cap:  # the corpus grew under the walk
+                    break
+                d = read(raw, vec_name, vecs[n])
+                if d is not None and d in id_map:
+                    ids[n] = d
+                    n += 1
+            if not n:
                 return None
-            return np.asarray(ids, dtype=np.int64), np.stack(vecs)
+            order = np.argsort(ids[:n], kind="stable")
+            return ids[order], vecs[order]
+
+        def rows_fn():
+            idx = _idx()
+            return 0 if idx is None else len(idx)
 
         def epoch_token_fn():
             idx = _idx()
@@ -387,7 +400,8 @@ class Shard:
             f"{self.collection_name}/{self.name}/{vec_name or '-'}",
             collection=self.collection_name, shard=self.name,
             search_fn=search_fn, corpus_fn=corpus_fn,
-            epoch_token_fn=epoch_token_fn, pairwise_fn=pairwise_fn)
+            epoch_token_fn=epoch_token_fn, pairwise_fn=pairwise_fn,
+            rows_fn=rows_fn)
 
     def _tenant_label(self) -> str:
         """Tenants ARE shards in this layout (reference: partitioned

@@ -22,12 +22,15 @@ logger = logging.getLogger(__name__)
 class CycleCallback:
     """One periodic job. ``fn() -> bool`` returns True when it did work
     (resets the interval) and False when idle (backs off up to
-    ``max_interval``)."""
+    ``max_interval``). ``on_demand`` is what ``run_now`` calls in its
+    place, for a job that does more when asked for than at a tick."""
 
     def __init__(self, name: str, fn, interval: float,
-                 max_interval: float | None = None, backoff: float = 2.0):
+                 max_interval: float | None = None, backoff: float = 2.0,
+                 on_demand=None):
         self.name = name
         self.fn = fn
+        self.on_demand = on_demand or fn
         self.base_interval = interval
         self.max_interval = max_interval or interval * 8
         self.backoff = backoff
@@ -37,10 +40,10 @@ class CycleCallback:
         self.failures = 0
         self.active = True
 
-    def run(self) -> None:
+    def run(self, on_demand: bool = False) -> None:
         self.runs += 1
         try:
-            did_work = self.fn()
+            did_work = (self.on_demand if on_demand else self.fn)()
         except Exception:
             self.failures += 1
             logger.exception("cycle callback %s failed", self.name)
@@ -70,8 +73,10 @@ class CycleManager:
         self._thread: threading.Thread | None = None
 
     def register(self, name: str, fn, interval: float,
-                 max_interval: float | None = None) -> CycleCallback:
-        cb = CycleCallback(name, fn, interval, max_interval)
+                 max_interval: float | None = None,
+                 on_demand=None) -> CycleCallback:
+        cb = CycleCallback(name, fn, interval, max_interval,
+                           on_demand=on_demand)
         with self._lock:
             self._callbacks[name] = cb
         self._wake.set()
@@ -131,7 +136,7 @@ class CycleManager:
         if cb is None:
             return False
         with self._pause_lock:
-            cb.run()
+            cb.run(on_demand=True)
         return True
 
     def _loop(self) -> None:

@@ -16,8 +16,9 @@ Leg 1 — serving-path canaries. Per vector index the shard registers a
 canary: a small deterministic probe set (fixed-seed sample of the
 shard's own corpus; ``WEAVIATE_TPU_DRIFT_SEED``) whose host-exact
 ground truth is recomputed ONLY when the corpus epoch token changes
-(insert/delete/seal/compact). Each cycle the probes run *through the
-real query batcher* — the same coalescing, dispatch, faultline point
+(insert/delete/seal/compact), and then once, when the writes have
+stopped ("Canary lifecycle" below). Each cycle the probes run *through
+the real query batcher* — the same coalescing, dispatch, faultline point
 and kernelscope attribution as user traffic, not a side channel —
 measuring recall@10 against the sealed ground truth, attributed
 device-ms (kernelscope residency delta over the probe window; shared
@@ -53,6 +54,23 @@ tailboard flight-recorder snapshot via the existing
 closes. Every cycle appends one JSONL record to a size-ringed history
 under ``<data_dir>/driftwatch/`` that ``python -m tools.driftwatch``
 can replay offline against any baseline.
+
+Canary lifecycle. A seal is the one O(corpus) host pass, so it is made
+of a corpus that holds still. ``look()`` (its own one-second
+cyclemanager callback, backing off while nothing moves) and every
+scheduled cycle read each canary's epoch token — no object is touched,
+O(1) a canary — and note when it last moved. A canary whose token
+differs from the sealed one is sealed as soon as the token has held
+still for ``QUIET_S`` (trigger ``quiet``), and not more often than once
+an interval. A scheduled cycle that finds the token still moving DEFERS
+the canary: no ``corpus_fn``, no probes against a stale ground truth,
+``skipped`` and ``epoch_token`` as they were, ``last["deferred"]`` says
+so. Under a write stream that never pauses a canary is deferred at most
+``MAX_DEFERRALS`` cycles running and sealed at the next (trigger
+``interval``), so its ground truth is never older than ``MAX_DEFERRALS
++ 1`` intervals. ``run_cycle()`` as ``cycles.run_now("driftwatch")``
+calls it (the tests' and the operator's entry) seals whatever differs,
+at once (trigger ``forced``).
 """
 
 from __future__ import annotations
@@ -81,6 +99,17 @@ CANARY_K = 10
 #: wall noise) — sealing then would freeze the inflated level as the
 #: band and mask every regression below it
 _SEAL_CONVERGED_RATIO = 8.0
+
+#: a canary's changed token has to hold still this long before the
+#: O(corpus) ground truth is computed from it
+QUIET_S = 2.0
+
+#: scheduled cycles running that may defer a canary whose token keeps
+#: moving; the next one seals it as it is
+MAX_DEFERRALS = 4
+
+#: base period of the token look (``Database`` registers ``look`` with it)
+LOOK_INTERVAL_S = 1.0
 
 # -- config (lazy env reads, cached; configure()/reset_for_tests drop) --------
 
@@ -194,6 +223,9 @@ def _history_cap_bytes() -> int:
 # -- leg 1: serving-path canaries ---------------------------------------------
 
 
+_UNSEEN = object()
+
+
 class _Canary:
     """One registered probe target (a shard's vector space).
 
@@ -206,12 +238,13 @@ class _Canary:
     metrics)."""
 
     __slots__ = ("key", "collection", "shard", "search_fn", "corpus_fn",
-                 "epoch_token_fn", "pairwise_fn", "token", "probe_ids",
-                 "probe_vecs", "gt", "ref_recall", "ref_device_ms",
-                 "skipped", "last", "history")
+                 "epoch_token_fn", "pairwise_fn", "rows_fn", "token",
+                 "probe_ids", "probe_vecs", "gt", "ref_recall",
+                 "ref_device_ms", "skipped", "last", "history",
+                 "seen_token", "seen_at", "sealed_at", "deferrals")
 
     def __init__(self, key, collection, shard, search_fn, corpus_fn,
-                 epoch_token_fn, pairwise_fn):
+                 epoch_token_fn, pairwise_fn, rows_fn=None):
         self.key = key
         self.collection = collection
         self.shard = shard
@@ -219,6 +252,7 @@ class _Canary:
         self.corpus_fn = corpus_fn
         self.epoch_token_fn = epoch_token_fn
         self.pairwise_fn = pairwise_fn
+        self.rows_fn = rows_fn  # cheap row count, asked before any read
         self.token = None
         self.probe_ids = None   # np.int64 [P] — WHICH corpus rows probe
         self.probe_vecs = None  # np.float32 [P, d]
@@ -228,6 +262,10 @@ class _Canary:
         self.skipped: str | None = None
         self.last: dict | None = None
         self.history: deque = deque(maxlen=64)
+        self.seen_token = _UNSEEN  # the token at the last look
+        self.seen_at = 0.0         # when it last differed from the look before
+        self.sealed_at = None      # when the last seal (or attempt) ended
+        self.deferrals = 0         # scheduled cycles deferred running
 
 
 _canaries: dict[str, _Canary] = {}
@@ -235,12 +273,13 @@ _canaries: dict[str, _Canary] = {}
 
 def register_canary(key: str, *, collection: str = "", shard: str = "",
                     search_fn, corpus_fn, epoch_token_fn,
-                    pairwise_fn) -> None:
+                    pairwise_fn, rows_fn=None) -> None:
     """Idempotent (re)registration — a shard re-opening its index under
     the same key replaces the target and its sealed state."""
     with _lock:
         _canaries[key] = _Canary(key, collection, shard, search_fn,
-                                 corpus_fn, epoch_token_fn, pairwise_fn)
+                                 corpus_fn, epoch_token_fn, pairwise_fn,
+                                 rows_fn)
 
 
 def unregister_canaries(prefix: str) -> None:
@@ -259,15 +298,16 @@ def _probe_rng(key: str) -> np.random.Generator:
         (_seed() ^ zlib.crc32(key.encode())) & 0xFFFFFFFF)
 
 
-def _seal_canary(c: _Canary, token) -> None:
-    """Recompute probe set + host-exact ground truth. Called ONLY on
-    corpus-epoch change (and first sight) — this is the one place
-    driftwatch does O(corpus) host work, off the serving path."""
-    c.token = token
-    c.skipped = None
-    c.gt = None
-    c.ref_recall = None
-    c.ref_device_ms = None
+def _compute_ground_truth(c: _Canary) -> None:
+    """Probe set + host-exact ground truth of the corpus as it stands:
+    the one place driftwatch does O(corpus) host work, off the serving
+    path."""
+    too_many = (f"over WEAVIATE_TPU_DRIFT_CANARY_MAX_ROWS="
+                f"{_max_corpus_rows()} — host-exact ground truth skipped")
+    hint = 0 if c.rows_fn is None else c.rows_fn()
+    if hint > _max_corpus_rows():
+        c.skipped = f"corpus {hint} rows {too_many}"
+        return
     corpus = c.corpus_fn()
     if corpus is None:
         c.skipped = "no host corpus (index without doc map or empty)"
@@ -280,9 +320,7 @@ def _seal_canary(c: _Canary, token) -> None:
         c.skipped = "empty corpus"
         return
     if n > _max_corpus_rows():
-        c.skipped = (f"corpus {n} rows over WEAVIATE_TPU_DRIFT_CANARY_"
-                     f"MAX_ROWS={_max_corpus_rows()} — host-exact ground "
-                     "truth skipped")
+        c.skipped = f"corpus {n} rows {too_many}"
         return
     rng = _probe_rng(c.key)
     # sample over the SORTED id order so the probe set is a pure
@@ -299,19 +337,120 @@ def _seal_canary(c: _Canary, token) -> None:
     c.gt = [ids[top[i]] for i in range(len(rows))]
 
 
-def _run_canary(c: _Canary) -> tuple[dict, list[dict]]:
-    """One canary cycle: reseal on epoch change, run probes through the
-    serving batcher, classify. Returns (cycle record, findings)."""
+def _seal_canary(c: _Canary, token, trigger: str, now: float) -> None:
+    """Seal the canary on ``token``: a failure is a ``skipped`` reason,
+    never an exception; counted by ``trigger`` with its seconds. The
+    token is published last: ``epoch_token`` names a corpus only once
+    its ground truth is there (a reader may be waiting for just that,
+    and the QUIET_S before a seal look like an idle server)."""
+    c.skipped = None
+    c.gt = None
+    c.ref_recall = None
+    c.ref_device_ms = None
+    t0 = time.perf_counter()
     try:
-        token = c.epoch_token_fn()
+        _compute_ground_truth(c)
+    except Exception as e:
+        c.skipped = f"ground-truth seal failed: {e}"
+    seconds = time.perf_counter() - t0
+    c.token = token
+    c.sealed_at = now + seconds
+    c.deferrals = 0
+    try:
+        from weaviate_tpu.runtime.metrics import (canary_seal_seconds_total,
+                                                  canary_seals_total)
+
+        canary_seals_total.labels(trigger).inc()
+        canary_seal_seconds_total.labels(trigger).inc(seconds)
+    except Exception:
+        pass
+
+
+def _observe(c: _Canary, now: float):
+    """Read the canary's token (no object is touched) and note when it
+    last moved. -> (token, state): ``"sealed"`` on this token already,
+    ``"quiet"`` differs from the sealed one and has held still for
+    QUIET_S, else ``"moving"``."""
+    token = c.epoch_token_fn()
+    if token != c.seen_token:
+        c.seen_token, c.seen_at = token, now
+    if token == c.token and (c.gt is not None or c.skipped is not None):
+        return token, "sealed"
+    return token, "quiet" if now - c.seen_at >= QUIET_S else "moving"
+
+
+def _seal_allowed(c: _Canary, now: float) -> bool:
+    """A quiet canary is sealed at once, but not more often than once an
+    interval: writes that come in short bursts cost what a scheduled
+    seal a tick cost, not one seal a burst."""
+    return c.sealed_at is None or now - c.sealed_at >= interval_s()
+
+
+def look(now: float | None = None) -> bool:
+    """The token look, a cyclemanager callback of its own: O(1) a canary.
+    Each one whose changed token has held still gets its cycle at once
+    (seal with trigger ``quiet``, then its probes, as at a tick); the
+    others are not touched. Returns whether any canary still waits for
+    its seal (the callback then keeps its base period, else it backs
+    off)."""
+    if not enabled():
+        return False
+    now = time.monotonic() if now is None else now
+    with _lock:
+        targets = list(_canaries.values())
+    waiting = False
+    due = set()
+    for c in targets:
+        try:
+            _token, state = _observe(c, now)
+        except Exception:  # a closing shard must not kill the look
+            continue
+        if state == "quiet" and _seal_allowed(c, now):
+            due.add(c.key)
+        elif state != "sealed":
+            waiting = True
+    if due:
+        run_cycle(scheduled=True, now=now, only=due)
+    return waiting
+
+
+def _defer(c: _Canary, token, rec: dict) -> dict:
+    """A scheduled cycle leaves a moving canary alone: public state as it
+    was but for ``last["deferred"]``."""
+    c.deferrals += 1
+    rec["deferred"] = {"cycles": c.deferrals, "token": str(token),
+                       "sealed_token": None if c.token is None
+                       else str(c.token)}
+    c.last = dict(c.last or {}, deferred=rec["deferred"])
+    try:
+        from weaviate_tpu.runtime.metrics import canary_deferrals_total
+
+        canary_deferrals_total.inc()
+    except Exception:
+        pass
+    return rec
+
+
+def _run_canary(c: _Canary, now: float,
+                scheduled: bool) -> tuple[dict, list[dict]]:
+    """One canary cycle: seal where the lifecycle says so (module
+    docstring), run probes through the serving batcher, classify.
+    Returns (cycle record, findings)."""
+    rec = {"key": c.key, "collection": c.collection, "shard": c.shard}
+    try:
+        token, state = _observe(c, now)
     except Exception as e:  # a closing shard must not kill the cycle
         return {"key": c.key, "skipped": f"epoch token failed: {e}"}, []
-    if c.gt is None or token != c.token:
-        try:
-            _seal_canary(c, token)
-        except Exception as e:
-            c.skipped = f"ground-truth seal failed: {e}"
-    rec = {"key": c.key, "collection": c.collection, "shard": c.shard}
+    if state != "sealed":
+        if not scheduled:
+            trigger = "forced"
+        elif state == "quiet" and _seal_allowed(c, now):
+            trigger = "quiet"
+        elif c.deferrals >= MAX_DEFERRALS:
+            trigger = "interval"
+        else:
+            return _defer(c, token, rec), []
+        _seal_canary(c, token, trigger, now)
     if c.skipped is not None:
         rec["skipped"] = c.skipped
         return rec, []
@@ -715,23 +854,38 @@ def _apply_findings(new: dict[str, dict]) -> bool:
     return not flips
 
 
-def run_cycle() -> bool:
-    """The cyclemanager callback (and the deterministic test entry):
-    run every canary, classify live telemetry, apply findings, append
-    the history record. Returns whether any leg produced work (False =
-    disabled or nothing registered, letting the cycle back off)."""
+def run_cycle(scheduled: bool = False, now: float | None = None,
+              only: set[str] | None = None) -> bool:
+    """One full cycle: run every canary, classify live telemetry, apply
+    findings, append the history record. As called it is the
+    deterministic entry (``cycles.run_now("driftwatch")``, tests) and
+    seals every canary whose token differs; ``scheduled`` is the
+    cyclemanager tick, which seals only what has gone quiet and defers
+    what still moves (module docstring, "Canary lifecycle"). ``now`` is
+    the lifecycle's clock (``time.monotonic()``); ``only`` keeps the
+    cycle to the canaries with these keys (the look's, for the ones that
+    have just gone quiet). Returns whether any leg produced work (False
+    = disabled or nothing registered, letting the cycle back off)."""
     global _cycle_seq, _last_cycle_t, _last_verdict
     if not enabled():
         return False
+    now = time.monotonic() if now is None else now
     with _lock:
         targets = list(_canaries.values())
         _cycle_seq += 1
         seq = _cycle_seq
+        still_open = dict(_findings)
     canary_records: list[dict] = []
     new_findings: dict[str, dict] = {}
     for c in targets:
-        rec, found = _run_canary(c)
-        canary_records.append(rec)
+        rec = None
+        if only is None or c.key in only:
+            rec, found = _run_canary(c, now, scheduled)
+            canary_records.append(rec)
+        if rec is None or "deferred" in rec:
+            # not probed, so not judged: its open findings stay open
+            found = [f for k, f in still_open.items()
+                     if k.startswith(f"canary:{c.key}:")]
         for f in found:
             new_findings[f["key"]] = f
     fp = live_fingerprint()

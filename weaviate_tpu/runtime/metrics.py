@@ -780,6 +780,21 @@ canary_recall = registry.gauge(
     "Worst canary recall@10 across a shard's vector spaces in the last "
     "driftwatch cycle, measured through the real query batcher against "
     "host-exact ground truth", ("collection", "shard"))
+canary_seals_total = registry.counter(
+    "weaviate_tpu_canary_seals_total",
+    "Canary ground-truth seals (one O(corpus) host pass each) by what "
+    "set them off: quiet = the corpus token moved and then held still, "
+    "interval = the staleness bound ran out under writes that never "
+    "pause, forced = run_now", ("trigger",))
+canary_seal_seconds_total = registry.counter(
+    "weaviate_tpu_canary_seal_seconds_total",
+    "Summed wall seconds of those seals: with the count it tells a "
+    "seal that came late from one that was slow", ("trigger",))
+canary_deferrals_total = registry.counter(
+    "weaviate_tpu_canary_deferrals_total",
+    "Scheduled driftwatch cycles that left a canary alone because its "
+    "corpus token was still moving (no ground truth computed, no probe "
+    "run against a stale one)")
 
 # -- jit compilation (runtime/compile_cache.py installs the listeners) --------
 

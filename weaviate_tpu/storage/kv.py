@@ -221,6 +221,22 @@ def _bloom_hashes(key: bytes) -> tuple[int, int]:
     )
 
 
+def _bloom_bytes(keys: list[bytes], bloom_words: int) -> bytes:
+    """The segment's bloom filter as its little-endian u64 words: bit
+    ``(h1 + i * h2) % m`` set for each key and each i < _BLOOM_K, all
+    keys at once ((h1 + i*h2) % m == (h1 % m + i * (h2 % m)) % m, which
+    fits 64 bits; a scalar read-modify-write a bit was most of a flush)."""
+    if not keys:
+        return b"\x00" * (8 * bloom_words)
+    m = np.uint64(bloom_words * 64)
+    h = np.array([_bloom_hashes(k) for k in keys], dtype=np.uint64)
+    steps = np.arange(_BLOOM_K, dtype=np.uint64)
+    bits = ((h[:, :1] % m) + steps * (h[:, 1:] % m)) % m
+    flags = np.zeros(int(m), dtype=np.bool_)
+    flags[bits.ravel()] = True
+    return np.packbits(flags, bitorder="little").tobytes()
+
+
 class _Segment:
     """Immutable sorted segment file, mmap'd (format v2).
 
@@ -340,8 +356,14 @@ class _Segment:
                     lo = mid + 1
                 else:
                     hi = mid
-        for i in range(lo, self.n):
-            yield self._key_at(i), self._value_at(i)
+        mm = self._mm
+        # a chunk of the index as plain ints: one structured-scalar read
+        # an item costs more than the two slices it leads to
+        for at in range(lo, self.n, 4096):
+            e = self._idx[at : at + 4096]
+            for ko, kl, vo, vl in zip(e["koff"].tolist(), e["klen"].tolist(),
+                                      e["voff"].tolist(), e["vlen"].tolist()):
+                yield mm[ko : ko + kl], mm[vo : vo + vl]
 
     def iter_keys(self) -> Iterator[bytes]:
         for i in range(self.n):
@@ -386,15 +408,7 @@ class _Segment:
             bloom_off = f.tell()
             n = len(keys)
             bloom_words = max((n * _BLOOM_BITS_PER_KEY + 63) // 64, 1) if n else 0
-            bloom = np.zeros(bloom_words, dtype=np.uint64)
-            if n:
-                m = bloom_words * 64
-                for k in keys:
-                    h1, h2 = _bloom_hashes(k)
-                    for i in range(_BLOOM_K):
-                        bit = (h1 + i * h2) % m
-                        bloom[bit >> 6] |= np.uint64(1 << (bit & 63))
-            f.write(bloom.tobytes())
+            f.write(_bloom_bytes(keys, bloom_words))
             foot_off = f.tell()
             f.write(msgpack.packb({
                 "n": n, "keys_off": keys_off, "idx_off": idx_off,

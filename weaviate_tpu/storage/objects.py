@@ -29,6 +29,8 @@ import numpy as np
 
 _VERSION = 1
 _HEADER = struct.Struct("<BQQQ16s")
+_U16 = struct.Struct("<H")
+_U32 = struct.Struct("<I")
 
 
 @dataclass
@@ -94,20 +96,20 @@ class StorageObject:
         if version != _VERSION:
             raise ValueError(f"unsupported storage object version {version}")
         off = _HEADER.size
-        (n_vecs,) = struct.unpack_from("<I", data, off)
+        (n_vecs,) = _U32.unpack_from(data, off)
         off += 4
         vectors: dict[str, np.ndarray] = {}
         for _ in range(n_vecs):
-            (nlen,) = struct.unpack_from("<H", data, off)
+            (nlen,) = _U16.unpack_from(data, off)
             off += 2
             name = data[off : off + nlen].decode("utf-8")
             off += nlen
-            (dim,) = struct.unpack_from("<I", data, off)
+            (dim,) = _U32.unpack_from(data, off)
             off += 4
             vec = np.frombuffer(data, dtype="<f4", count=dim, offset=off).copy()
             off += 4 * dim
             vectors[name] = vec
-        (plen,) = struct.unpack_from("<I", data, off)
+        (plen,) = _U32.unpack_from(data, off)
         off += 4
         props = msgpack.unpackb(data[off : off + plen], raw=False)
         return cls(
@@ -118,6 +120,37 @@ class StorageObject:
             creation_time_ms=ctime,
             last_update_time_ms=mtime,
         )
+
+    @staticmethod
+    def read_vector_into(data, name: str, out: np.ndarray) -> int | None:
+        """Vector-only reader beside ``from_bytes``: copy the vector stored
+        under ``name`` into ``out`` (float32 [dim]) and return the object's
+        doc id; None, with ``out`` untouched, when the object has no such
+        vector or its length is not ``out``'s. Builds no object: the uuid,
+        the other vectors and the properties are skipped, not decoded
+        (driftwatch's ground truth reads a whole corpus through here)."""
+        version, doc_id, _ctime, _mtime, _uid = _HEADER.unpack_from(data, 0)
+        if version != _VERSION:
+            raise ValueError(f"unsupported storage object version {version}")
+        want = name.encode("utf-8")
+        off = _HEADER.size
+        (n_vecs,) = _U32.unpack_from(data, off)
+        off += 4
+        for _ in range(n_vecs):
+            (nlen,) = _U16.unpack_from(data, off)
+            off += 2
+            found = data[off : off + nlen] == want
+            off += nlen
+            (dim,) = _U32.unpack_from(data, off)
+            off += 4
+            if found:
+                if dim != out.shape[0]:
+                    return None
+                out[:] = np.frombuffer(data, dtype="<f4", count=dim,
+                                       offset=off)
+                return doc_id
+            off += 4 * dim
+        return None
 
     def touch(self):
         self.last_update_time_ms = int(time.time() * 1000)
