@@ -94,6 +94,8 @@ def test_config_update_compresses_live_class(tmp_path, rng):
     import copy
     new_cfg = copy.deepcopy(col.config)
     new_cfg.vectors[0].index.quantization = "pq"
+    # the class holds 600 rows: a trainingLimit it has already passed
+    new_cfg.vectors[0].index.pq_training_limit = 512
     db.update_collection(new_cfg)
 
     shard = list(col.shards.values())[0]

@@ -129,6 +129,43 @@ def test_pq4_scan_reduce(one_chip, masked, b, d):
     _assert_kernel(_compile(fn, one_chip, *shapes))
 
 
+# the served PQ path of a `flat` + `pq` class (ISSUE 28) at the
+# deep-pq-cosine configuration's widths: 96 segments x 256 centroids over
+# 96 dims. XLA programs, no Pallas kernel: what is asked is whether the
+# chip's compiler takes them at these shapes without materialising the
+# [rows, segments, centroids] intermediate (6.4 GB at 65,536 rows).
+PQ_M, PQ_K, PQ_D = 96, 256, 96
+
+
+@pytest.mark.parametrize("rows", [4096, 65536],
+                         ids=["import-batch", "training-sample"])
+def test_pq_encode_and_fit_at_96_segments(one_chip, rows):
+    from weaviate_tpu.ops import pq
+
+    book = ((PQ_M, PQ_K, PQ_D // PQ_M), jnp.float32)
+    for fn in (functools.partial(pq._assign, m=PQ_M),
+               functools.partial(pq._lloyd_step, m=PQ_M, k=PQ_K)):
+        c = _compile(fn, one_chip, ((rows, PQ_D), jnp.float32), book)
+        assert c.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("b", [1, 32])
+def test_pq_topk_at_96_segments(one_chip, b):
+    from weaviate_tpu.ops.pq import pq_topk
+
+    rows = 262144
+
+    def fn(q, codes, cent, valid):
+        return pq_topk(q, codes, cent, k=160, chunk_size=8192,
+                       metric="cosine", valid=valid)
+
+    c = _compile(fn, one_chip, ((b, PQ_D), jnp.float32),
+                 ((rows, PQ_M), jnp.uint8),
+                 ((PQ_M, PQ_K, PQ_D // PQ_M), jnp.float32),
+                 ((rows,), jnp.bool_))
+    assert c.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 def test_bq_mxu_block(one_chip):
     fn = functools.partial(pk.bq_mxu_block, interpret=False)
     _assert_kernel(_compile(fn, one_chip, ((64, 24), jnp.uint32),

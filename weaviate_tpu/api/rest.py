@@ -189,6 +189,14 @@ def _index_config_from_json(index_type: str | None, d: dict | None):
         out.quantization = "pq"
         out.pq_segments = pq.get("segments") or None
         out.pq_centroids = pq.get("centroids", out.pq_centroids)
+        out.pq_training_limit = pq.get("trainingLimit",
+                                       out.pq_training_limit)
+        encoder = pq.get("encoder")
+        if encoder is not None:
+            if not isinstance(encoder, dict):
+                raise ValueError(
+                    f"pq.encoder must be an object, got {encoder!r}")
+            out.pq_encoder = encoder.get("type", out.pq_encoder)
     bq = d.get("bq") or {}
     if bq.get("enabled"):
         out.quantization = "bq"
@@ -224,7 +232,9 @@ def class_to_wire(cfg: CollectionConfig) -> dict:
             "maxConnections": ix.max_connections,
             "pq": {"enabled": ix.quantization == "pq",
                    "segments": ix.pq_segments or 0,
-                   "centroids": ix.pq_centroids},
+                   "centroids": ix.pq_centroids,
+                   "trainingLimit": ix.pq_training_limit,
+                   "encoder": {"type": ix.pq_encoder}},
             "bq": {"enabled": ix.quantization == "bq",
                    "rescoreLimit": ix.rescore_limit},
         }

@@ -208,26 +208,11 @@ class Collection:
                     # 0 is meaningful (= auto); only skip absent values
                     if hasattr(idx, attr) and value is not None:
                         setattr(idx, attr, value)
-                if vc.index.quantization and not idx.compressed and \
-                        hasattr(idx, "compress"):
-                    # runtime compression enable (compress.go:38): train
-                    # on live contents and swap to the compressed path.
-                    # Too little data to train yet is not an error — the
-                    # config sticks and a later update/restart retries
-                    # (the reference also defers until enough objects).
-                    try:
-                        idx.compress(
-                            quantization=vc.index.quantization,
-                            pq_segments=vc.index.pq_segments,
-                            pq_centroids=vc.index.pq_centroids,
-                        )
-                    except (RuntimeError, ValueError) as e:
-                        import logging
-
-                        logging.getLogger(__name__).warning(
-                            "collection %s/%s: deferring runtime "
-                            "compression: %s", self.config.name,
-                            vec_name, e)
+                # runtime compression enable (compress.go:38): the
+                # config sticks, and the shard's gate decides when. A pq
+                # class below pq.trainingLimit defers; the import that
+                # crosses the limit, or a restart above it, compresses.
+                shard._maybe_compress(vec_name, idx)
 
     # -- shard management ----------------------------------------------------
 

@@ -302,6 +302,8 @@ class Database:
                         vc.index.quantization = vc_new.index.quantization
                         vc.index.pq_segments = vc_new.index.pq_segments
                         vc.index.pq_centroids = vc_new.index.pq_centroids
+                        vc.index.pq_training_limit = \
+                            vc_new.index.pq_training_limit
                     vc.module_config = vc_new.module_config
 
             self.update_collection_config(new_cfg.name, apply)

@@ -52,11 +52,12 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
         capacity=8192,
         chunk_size=8192,
     )
-    if cfg.index_type == "flat" and cfg.quantization:
+    # a pq class starts on full rows and compresses once, at
+    # pq_training_limit (Shard._maybe_compress); bq needs no training
+    # and is compressed from its first row
+    if cfg.index_type == "flat" and cfg.quantization == "bq":
         return FlatIndex(
-            quantization=cfg.quantization,
-            pq_segments=cfg.pq_segments,
-            pq_centroids=cfg.pq_centroids,
+            quantization="bq",
             rescore_limit=cfg.rescore_limit,
             prefix_bits=cfg.prefix_bits,
             mesh=mesh,
@@ -110,14 +111,12 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
         # dynamic flat→ANN upgrade so small corpora stay exact
         from weaviate_tpu.engine.dynamic import DynamicIndex
 
-        if cfg.quantization:
+        if cfg.quantization == "bq":
             # quantized flat scan is already the fast path; stays flat
             # (DynamicIndex refuses to upgrade a quantized impl)
             return DynamicIndex(
                 threshold=cfg.flat_to_ann_threshold,
-                quantization=cfg.quantization,
-                pq_segments=cfg.pq_segments,
-                pq_centroids=cfg.pq_centroids,
+                quantization="bq",
                 rescore_limit=cfg.rescore_limit,
                 prefix_bits=cfg.prefix_bits,
                 mesh=mesh,
@@ -156,6 +155,9 @@ class Shard:
                                                   "enabled")
         self.async_indexing = async_indexing
         self._index_queues: dict[str, "IndexQueue"] = {}
+        # one compression at a time a shard: the queue's worker and a
+        # config update may both find the gate open
+        self._compress_lock = threading.Lock()
         # server-side dynamic batching: concurrent single-query searches
         # coalesce into one device dispatch (continuous batching — see
         # runtime/query_batcher.py). QUERY_DYNAMIC_BATCHING=false opts out.
@@ -299,13 +301,17 @@ class Shard:
                 )
             idx = self._ensure_vector_index(vec_name, dim)
             if idx is not None and keep:
+                # the bucket walks in uuid order; doc ids were handed out
+                # in import order, and slots in doc-id order are what a
+                # pq codebook's "first pq_training_limit rows" means
+                keep.sort(key=ids.__getitem__)
                 idx.add_batch(
                     np.asarray([ids[j] for j in keep]),
                     np.stack([vecs[j] for j in keep]),
                 )
-                # configs that ask for quantization on a graph/ivf index
-                # compress at runtime (compress.go:38) — re-apply after the
-                # rebuild so a restart doesn't silently lose compression
+                # runtime compression (compress.go:38) is re-applied from
+                # the same gate, so a restart neither loses it nor trains
+                # on other rows than the import did
                 self._maybe_compress(vec_name, idx)
 
     def _ensure_vector_index(self, vec_name: str, dim: int):
@@ -410,27 +416,37 @@ class Shard:
         return self.name if self.config.multi_tenancy.enabled else ""
 
     def _maybe_compress(self, vec_name: str, idx) -> None:
+        """Compress ``idx`` if its config asks for it and its gate
+        (``VectorIndexConfig.compress_due``) is open: the one place
+        runtime compression starts from, for an import, the async
+        queue's drain, a restart and a config update alike."""
         vc = self.config.vector_config(vec_name)
-        if (vc is None or not vc.index.quantization
-                or getattr(idx, "compressed", True)
+        if (vc is None or getattr(idx, "compressed", True)
                 or not hasattr(idx, "compress")
-                # trainability floor — the SAME gate the config-update
-                # path has, so a restart can never silently drop
-                # compression a live update applied
-                or len(idx) < (vc.index.pq_centroids or 16)):
+                or not vc.index.compress_due(len(idx))):
             return
-        try:
-            idx.compress(quantization=vc.index.quantization,
-                         pq_segments=vc.index.pq_segments,
-                         pq_centroids=vc.index.pq_centroids,
-                         rescore_limit=vc.index.rescore_limit,
-                         prefix_bits=vc.index.prefix_bits)
-        except (RuntimeError, ValueError) as e:
-            import logging
+        cfg = vc.index
+        with self._compress_lock:
+            if idx.compressed:  # another thread's drain got here first
+                return
+            try:
+                idx.compress(quantization=cfg.quantization,
+                             pq_segments=cfg.pq_segments,
+                             pq_centroids=cfg.pq_centroids,
+                             rescore_limit=cfg.rescore_limit,
+                             prefix_bits=cfg.prefix_bits,
+                             training_limit=cfg.pq_training_limit)
+            except Exception:  # noqa: BLE001 — the write itself stands
+                # past the gate nothing is left to wait for: a class
+                # that cannot compress is a fault. It keeps answering
+                # exactly from full rows, and the next batch tries again.
+                from weaviate_tpu.runtime.metrics import index_compress_total
 
-            logging.getLogger(__name__).warning(
-                "shard %s/%s: deferring runtime compression: %s",
-                self.name, vec_name, e)
+                index_compress_total.labels(cfg.quantization,
+                                            "failed").inc()
+                logger.exception(
+                    "shard %s/%s: %s compression failed at %d rows",
+                    self.name, vec_name, cfg.quantization, len(idx))
 
     # -- write path ----------------------------------------------------------
 
@@ -692,7 +708,8 @@ class Shard:
         if q is None:
             from weaviate_tpu.runtime.index_queue import IndexQueue
 
-            q = IndexQueue(idx)
+            q = IndexQueue(
+                idx, after_add=lambda: self._maybe_compress(vec_name, idx))
             self._index_queues[vec_name] = q
         return q
 

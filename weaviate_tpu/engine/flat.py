@@ -14,7 +14,10 @@ Internally ids map to store slots; tombstoned slots are reclaimed by
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
+import time
 
 import numpy as np
 
@@ -32,6 +35,21 @@ def _per_query_allow(allow_list) -> bool:
     if not isinstance(allow_list, (list, tuple)) or len(allow_list) == 0:
         return False
     return any(a is None or np.ndim(a) > 0 for a in allow_list)
+
+
+@contextlib.contextmanager
+def _compress_stage(quantization: str, stage: str):
+    """One stage of ``FlatIndex.compress``: a child span of
+    ``index.compress`` where the import is traced, and one observation
+    of ``weaviate_tpu_index_compress_seconds`` either way (none for a
+    stage that raised)."""
+    from weaviate_tpu.runtime.metrics import index_compress_seconds
+
+    t0 = time.perf_counter()
+    with tracing.span(stage) as sp:
+        yield sp
+    index_compress_seconds.labels(quantization, stage).observe(
+        time.perf_counter() - t0)
 
 
 class FlatIndex:
@@ -56,6 +74,9 @@ class FlatIndex:
     # device scans compile one executable per (B, k) shape — the batcher
     # pads drains to pow2 buckets to bound the variant count
     compiled_batch_shapes = True
+    # compact() renumbers slots and counts here; compress() looks, to see
+    # whether the rows it encoded outside the lock still lie where they did
+    _compactions = 0
 
     def __init__(self, dim: int, metric: str = "l2-squared", mesh=None,
                  dtype=None, capacity: int = 8192, chunk_size: int = 8192,
@@ -445,54 +466,119 @@ class FlatIndex:
 
     # -- compression ----------------------------------------------------------
 
-    def compress(self, quantization: str = "pq", **quant_kwargs) -> None:
+    def compress(self, quantization: str = "pq",
+                 training_limit: int | None = None, **quant_kwargs) -> None:
         """Runtime compression: train a quantizer on current contents and swap
         the store (reference: hnsw/compress.go:38, enabled via a config
         update once enough data exists). Slot layout is preserved, so the
-        id<->slot mapping carries over untouched."""
+        id<->slot mapping carries over untouched. ``training_limit``: a
+        PQ codebook is fitted on the first that many live rows in slot
+        order (upstream's pq.trainingLimit), so one import gives one
+        codebook whatever the batch that crossed the limit held.
+
+        Searches go on, exactly, from the old store while the codebook
+        is fitted and the rows are encoded: ``_lock`` is held for the
+        snapshot, and again for the catch-up (what was written or
+        deleted meanwhile) and the swap. On the v5e the fit and the
+        encoding of 100,000 x 96 take 4-16 s (PERF.md, PR 28)."""
         from weaviate_tpu.engine.epochs import EpochStore
+        from weaviate_tpu.engine.quantized import QuantizedVectorStore
+        from weaviate_tpu.runtime.metrics import index_compress_total
+
+        staged = functools.partial(_compress_stage, quantization)
+
+        def twin(old, snap):
+            """-> a trained quantized store that holds ``snap``'s rows."""
+            with staged("train") as sp:
+                new = self._quantized_twin(old, quantization, quant_kwargs)
+                live = np.nonzero(snap["valid"])[0]
+                live_vecs = snap["vectors"][live]
+                if quantization == "pq" and new.codebook is None:
+                    if len(live) < new.pq_centroids:
+                        raise RuntimeError(
+                            f"need >= {new.pq_centroids} live vectors to train PQ, "
+                            f"have {len(live)}"
+                        )
+                    train_vecs = live_vecs[:training_limit]
+                    new.train(train_vecs)
+                    sp.set(rows_trained=len(train_vecs))
+            with staged("encode") as sp:
+                sp.set(rows_encoded=len(live))
+                if len(live):
+                    # vectors were already normalized at original insert
+                    new.set_at_prenormalized(live, live_vecs)
+            new._count = snap["count"]
+            return new
+
+        with tracing.span("index.compress", quantization=quantization,
+                          rows=len(self)):
+            with self._lock:
+                old = self.store
+                if isinstance(old, EpochStore):
+                    if old.quantization:
+                        raise RuntimeError("index is already compressed")
+                    with staged("train"):
+                        self._compress_epochs(old, quantization,
+                                              training_limit=training_limit,
+                                              **quant_kwargs)
+                    index_compress_total.labels(quantization, "ok").inc()
+                    return
+                if isinstance(old, QuantizedVectorStore):
+                    raise RuntimeError("index is already compressed")
+                snap = old.snapshot()
+                compactions = self._compactions
+            new = twin(old, snap)
+            with staged("swap") as sp, self._lock:
+                if self.store is not old:
+                    raise RuntimeError("index is already compressed")
+                if self._compactions != compactions:
+                    # slots were renumbered under the fit: once more, on
+                    # the rows as they lie now, with the lock held
+                    new = twin(old, old.snapshot())
+                else:
+                    sp.set(rows_caught_up=self._catch_up(
+                        new, snap, old.snapshot()))
+                self.store = new
+            index_compress_total.labels(quantization, "ok").inc()
+
+    def _quantized_twin(self, old, quantization: str, quant_kwargs: dict):
+        """An empty quantized store of ``old``'s geometry. It inherits
+        the old store's HBM-ledger owner labels (compress runs outside
+        the shard's owner scope); the old store's entries release via
+        its finalizer once the swap drops the last reference."""
         from weaviate_tpu.engine.quantized import QuantizedVectorStore
         from weaviate_tpu.runtime import hbm_ledger
 
-        with self._lock:
-            old = self.store
-            if isinstance(old, EpochStore):
-                if old.quantization:
-                    raise RuntimeError("index is already compressed")
-                return self._compress_epochs(old, quantization,
-                                             **quant_kwargs)
-            if isinstance(old, QuantizedVectorStore):
-                raise RuntimeError("index is already compressed")
-            snap = old.snapshot()
-            # the swapped-in store inherits the old store's HBM-ledger
-            # owner labels (compress runs outside the shard's owner
-            # scope); the old store's entries release via its finalizer
-            # once the swap drops the last reference
-            own = getattr(old, "_hbm_owner", None) or \
-                hbm_ledger.current_owner()
-            with hbm_ledger.owner(**own):
-                new = QuantizedVectorStore(
-                    dim=self.dim, metric=self.metric,
-                    quantization=quantization,
-                    capacity=old.capacity, chunk_size=old.chunk_size,
-                    mesh=old.mesh, **quant_kwargs,
-                )
-            live = np.nonzero(snap["valid"])[0]
-            live_vecs = snap["vectors"][live]
-            if quantization == "pq" and new.codebook is None:
-                if len(live) < new.pq_centroids:
-                    raise RuntimeError(
-                        f"need >= {new.pq_centroids} live vectors to train PQ, "
-                        f"have {len(live)}"
-                    )
-                new.train(live_vecs)
-            if len(live):
-                # vectors were already normalized at original insert
-                new.set_at_prenormalized(live, live_vecs)
-            new._count = snap["count"]
-            self.store = new
+        own = getattr(old, "_hbm_owner", None) or hbm_ledger.current_owner()
+        with hbm_ledger.owner(**own):
+            return QuantizedVectorStore(
+                dim=self.dim, metric=self.metric, quantization=quantization,
+                capacity=old.capacity, chunk_size=old.chunk_size,
+                mesh=old.mesh, **quant_kwargs)
+
+    @staticmethod
+    def _catch_up(new, then: dict, now: dict) -> int:
+        """Bring ``new``, which holds snapshot ``then`` of the old store,
+        to snapshot ``now`` of it: rows added or overwritten since are
+        encoded, rows deleted since are dropped. -> rows touched."""
+        n = len(now["valid"])
+        was_valid = np.zeros(n, dtype=bool)
+        was_valid[:len(then["valid"])] = then["valid"]
+        differs = np.ones(n, dtype=bool)
+        differs[:len(then["vectors"])] = (
+            now["vectors"][:len(then["vectors"])] != then["vectors"]
+        ).any(axis=1)
+        rewrite = np.nonzero(now["valid"] & (differs | ~was_valid))[0]
+        gone = np.nonzero(was_valid & ~now["valid"])[0]
+        if len(rewrite):
+            new.set_at_prenormalized(rewrite, now["vectors"][rewrite])
+        if len(gone):
+            new.delete(gone)
+        new._count = now["count"]
+        return len(rewrite) + len(gone)
 
     def _compress_epochs(self, old, quantization: str,
+                         training_limit: int | None = None,
                          **quant_kwargs) -> None:
         """Epoch-preserving compression: the quantized twin keeps the
         SAME global slot layout (epochs re-split by epoch_rows), so the
@@ -518,7 +604,7 @@ class FlatIndex:
                     f"have {len(live)}")
         new._restore_rows(live, snap["vectors"], int(snap["count"]))
         if quantization == "pq":
-            new.train(live_vecs)
+            new.train(live_vecs[:training_limit])
         self.store = new
 
     @property
@@ -559,6 +645,7 @@ class FlatIndex:
         """Reclaim tombstoned rows; remaps id→slot tables."""
         with self._lock:
             mapping = self.store.compact()
+            self._compactions += 1
             new_slot_to_id = np.full(self.store.capacity, -1, dtype=np.int64)
             for doc_id, slot in list(self._id_to_slot.items()):
                 ns = int(mapping[slot])

@@ -288,7 +288,8 @@ class QuantizedVectorStore:
         if self.quantization == "pq":
             if self.codebook is None:
                 raise RuntimeError("PQ store not trained; call train() first")
-            return pq_ops.pq_encode(self.codebook, vectors)
+            with tracing.span("store.pq_encode", rows=len(vectors)):
+                return pq_ops.pq_encode(self.codebook, vectors)
         (codes,) = tracing.d2h(bq_ops.bq_encode(jnp.asarray(vectors)))
         return codes
 

@@ -27,8 +27,11 @@ import numpy as np
 
 class IndexQueue:
     def __init__(self, index, batch_size: int = 512,
-                 start_worker: bool = True):
+                 start_worker: bool = True, after_add=None):
         self.index = index
+        # called after each drained batch has reached the index: the
+        # shard's compression gate looks at the new row count there
+        self.after_add = after_add
         self.batch_size = batch_size
         self._lock = threading.Lock()
         self._pending: deque = deque()  # (doc_id, vector) pairs
@@ -122,6 +125,8 @@ class IndexQueue:
                 vecs = np.stack([v for _, v in live])
                 self.index.add_batch(ids, vecs)
             applied = True
+            if live and self.after_add is not None:
+                self.after_add()
             with self._lock:
                 self._flushed += len(live)
             # a delete may have raced the add_batch above: its idx.delete

@@ -796,6 +796,23 @@ canary_deferrals_total = registry.counter(
     "corpus token was still moving (no ground truth computed, no probe "
     "run against a stale one)")
 
+# -- runtime compression (engine/flat.py compress, db/shard.py's gate) --------
+
+index_compress_seconds = registry.histogram(
+    "weaviate_tpu_index_compress_seconds",
+    "Wall seconds of one stage of a runtime compression: train (snapshot "
+    "of the full rows and the codebook fit), encode (every row held, "
+    "into the new store), swap (the new store takes the old one's place)",
+    ("quantization", "stage"),
+    buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
+             120.0))
+index_compress_total = registry.counter(
+    "weaviate_tpu_index_compress_total",
+    "Runtime compressions of an index by outcome: ok = the store was "
+    "swapped, failed = the gate was open and compress() raised (the "
+    "class goes on answering from full rows and tries again)",
+    ("quantization", "result"))
+
 # -- jit compilation (runtime/compile_cache.py installs the listeners) --------
 
 compile_cache_events = registry.counter(

@@ -75,6 +75,13 @@ class VectorIndexConfig:
     # MXU matmul (ops/pallas_kernels.pq4_lut_block); 256 selects the
     # reference-style 8-bit codebook (reconstruct-matmul scan)
     pq_centroids: int = 16
+    # upstream's pq.trainingLimit: a pq class answers exactly from full
+    # rows until its shard holds this many vectors, then fits the
+    # codebook on the first pq_training_limit of them, once, and goes on
+    # compressed (compress_due below is the one gate)
+    pq_training_limit: int = 100_000
+    # upstream's pq.encoder.type; "tile" has no form here
+    pq_encoder: str = "kmeans"
     rescore_limit: int = 16
     # two-stage scan: width (bits, 128/256) of the separately-stored
     # transposed sign prefix — the capacity-regime operating point
@@ -106,6 +113,15 @@ class VectorIndexConfig:
             raise ValueError(f"unknown distance metric {self.metric!r}")
         if self.quantization not in (None, "pq", "bq"):
             raise ValueError(f"unknown quantization {self.quantization!r}")
+        if self.pq_encoder != "kmeans":
+            raise ValueError(
+                f"pq encoder must be 'kmeans', got {self.pq_encoder!r}")
+        if (not isinstance(self.pq_training_limit, int)
+                or isinstance(self.pq_training_limit, bool)
+                or self.pq_training_limit < max(self.pq_centroids, 1)):
+            raise ValueError(
+                f"pq trainingLimit must be an int >= centroids "
+                f"({self.pq_centroids}), got {self.pq_training_limit!r}")
         if self.prefix_bits is not None:
             if not isinstance(self.prefix_bits, int) \
                     or self.prefix_bits not in (128, 256):
@@ -124,6 +140,16 @@ class VectorIndexConfig:
                 raise ValueError(
                     "epoch_rows requires index_type 'flat' (graph/ivf "
                     "layouts have their own reorganize stories)")
+
+
+    def compress_due(self, rows: int) -> bool:
+        """THE gate of runtime compression: whether an index of this
+        config that still holds full rows, ``rows`` of them, compresses
+        now. bq needs no training; pq waits for ``pq_training_limit``
+        rows (upstream compress.go:38 behind pq.trainingLimit)."""
+        if self.quantization == "pq":
+            return rows >= self.pq_training_limit
+        return self.quantization is not None
 
 
 @dataclass
