@@ -6,13 +6,21 @@ the index of the nearest centroid in each segment, a query's look-up table
 holds its distance to every centroid of every segment, and the asymmetric
 distance (ADC) of a row is the SUM over segments of the table entries its
 code names (reference product_quantization.go:440). The device scan
-(``ops/pq.py::pq_topk``) reconstructs rows and multiplies instead; because
-the segments are orthogonal the two are the same number up to rounding.
+(``ops/pq.py::pq_topk``) looks each chunk's codes up once for all queries
+(a lane gather on the chip, ``jnp.take`` elsewhere: the centroid's own
+float32 either way), and multiplies the reconstructed rows by the queries
+instead; because the segments are orthogonal the two are the same number
+up to rounding.
 """
 
 from __future__ import annotations
 
 import numpy as np
+
+# (segments, dims a segment, centroids) the 8-bit look-up is held to: the
+# benchmark cell's, this tree's default (d/8 segments of 8), fewer centroids,
+# a count that is no power of two
+GEOMETRIES_8BIT = [(96, 1, 256), (12, 8, 256), (24, 4, 64), (8, 4, 17)]
 
 
 def _segment_distances(codebook: np.ndarray, vectors: np.ndarray):
@@ -34,6 +42,14 @@ def encode(codebook: np.ndarray, vectors: np.ndarray) -> np.ndarray:
     return codes
 
 
+def reconstruct(codebook: np.ndarray, codes: np.ndarray) -> np.ndarray:
+    """-> [n, m * ds] float32: each segment's centroid as its code names it,
+    ``codebook[s, codes[:, s]]``, a value moved and nothing computed."""
+    m = codebook.shape[0]
+    return codebook[np.arange(m)[None, :], codes.astype(np.int64)].reshape(
+        len(codes), -1)
+
+
 def encode_margin(codebook: np.ndarray, vectors: np.ndarray,
                   codes: np.ndarray) -> np.ndarray:
     """[n, m]: how much farther the centroid ``codes`` names is than the
@@ -48,13 +64,13 @@ def lookup_table(codebook: np.ndarray, query: np.ndarray,
                  metric: str) -> np.ndarray:
     """-> [m, k]: the query's distance to every centroid, by segment.
     ``cosine`` takes a unit query and unit rows: 1 - q.x, the 1 added once
-    by ``adc``."""
+    by ``adc``; ``dot`` is -q.x, the same table without the 1."""
     m, _, ds = codebook.shape
     q = np.asarray(query, np.float32).reshape(m, 1, ds)
     if metric == "l2-squared":
         diff = q - codebook
         return (diff * diff).sum(-1)
-    if metric == "cosine":
+    if metric in ("cosine", "dot"):
         return -(q * codebook).sum(-1)
     raise ValueError(f"no plain PQ table for metric {metric!r}")
 

@@ -322,3 +322,70 @@ def test_quantized_store_prefix_twostage(rng):
     st3 = QuantizedVectorStore(dim=96, quantization="bq", prefix_bits=128)
     assert st3.prefix_t is None
     st3.add(rng.standard_normal((50, 96)).astype(np.float32))  # must not crash
+
+
+# -- 8-bit PQ look-up (pq8_lookup_block) -------------------------------------
+
+import pq_reference  # noqa: E402 — the plain numpy quantizer, tests/
+
+
+def _pq8_case(rng, m, ds, k, n):
+    cent = rng.standard_normal((m, k, ds)).astype(np.float32)
+    cent[0, 0, 0] = -0.0  # a value is moved, not summed: the sign survives
+    codes = rng.integers(0, k, (n, m)).astype(np.uint8)
+    codes[0] = 0
+    codes[1] = k - 1
+    return cent, codes, pq_reference.reconstruct(cent, codes)
+
+
+@pytest.mark.parametrize("n", [1024, 300], ids=["lane-aligned", "ragged"])
+@pytest.mark.parametrize("m,ds,k", pq_reference.GEOMETRIES_8BIT)
+def test_pq8_lookup_block_is_the_table_lookup(rng, m, ds, k, n):
+    """The lane-gather kernel (interpret mode) against numpy's
+    ``centroids[s, codes[:, s]]`` and against its jnp twin, bit for bit."""
+    from weaviate_tpu.ops.pq import pq_reconstruct
+
+    cent, codes, want = _pq8_case(rng, m, ds, k, n)
+    got = np.asarray(pk.pq8_lookup_block(jnp.asarray(codes),
+                                         jnp.asarray(cent), interpret=True))
+    assert got.dtype == np.float32 and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    twin = np.asarray(pq_reconstruct(jnp.asarray(codes), jnp.asarray(cent), m))
+    assert twin.tobytes() == want.tobytes()
+
+
+def test_pq8_lookup_block_splits_wide_rows(rng):
+    """Over 512 dimensions the sublane axis is cut into blocks of its own
+    grid axis (768 -> 2 x 384), each with its rows of the table."""
+    cent, codes, want = _pq8_case(rng, 96, 8, 256, 256)
+    got = np.asarray(pk.pq8_lookup_block(jnp.asarray(codes),
+                                         jnp.asarray(cent), interpret=True))
+    assert got.tobytes() == want.tobytes()
+
+
+def test_pq8_lookup_block_refuses_more_than_256_levels(rng):
+    with pytest.raises(ValueError, match="k <= 256"):
+        pk.pq8_lookup_block(jnp.zeros((8, 4), jnp.uint8),
+                            jnp.zeros((4, 257, 2), jnp.float32),
+                            interpret=True)
+
+
+@pytest.mark.parametrize("metric", ["l2-squared", "dot", "cosine"])
+def test_pq_topk_with_the_kernel_matches_its_twin(rng, metric, monkeypatch):
+    """The whole scan with the kernel (interpret mode) in the place its
+    twin has on the CPU: same candidates, same distances."""
+    from weaviate_tpu.ops import pq as pq_ops
+
+    m, ds, k, n = 12, 8, 256, 1024
+    cent, codes, _ = _pq8_case(rng, m, ds, k, n)
+    q = rng.standard_normal((3, m * ds)).astype(np.float32)
+    valid = rng.random(n) > 0.1
+    args = (jnp.asarray(q), jnp.asarray(codes), jnp.asarray(cent))
+    kw = dict(k=20, chunk_size=256, metric=metric, valid=jnp.asarray(valid))
+    want_d, want_i = pq_ops.pq_topk.__wrapped__(*args, **kw)
+    monkeypatch.setattr(
+        pq_ops, "_rows_from_codes",
+        lambda c, t: pk.pq8_lookup_block(c, t, interpret=True))
+    got_d, got_i = pq_ops.pq_topk.__wrapped__(*args, **kw)
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(got_d), np.asarray(want_d))

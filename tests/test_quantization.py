@@ -73,6 +73,65 @@ def test_pq_recall_on_clustered_data(rng):
     assert recall > 0.45, recall  # un-rescored compressed recall
 
 
+# -- the look-up and the scan's contract (ISSUE 29) ---------------------------
+
+import pq_reference  # noqa: E402 — the plain numpy quantizer, tests/
+
+
+@pytest.mark.parametrize("m,ds,k", pq_reference.GEOMETRIES_8BIT)
+def test_pq_reconstruct_is_the_table_lookup(rng, m, ds, k):
+    """``[N, m] u8 -> [N, d] f32``, bit-equal to ``centroids[s, codes[:, s]]``
+    whatever the geometry (the cell's 96 x 1 x 256, the default d/8 x 8 x
+    256, fewer centroids, a count that is no power of two)."""
+    cent = rng.standard_normal((m, k, ds)).astype(np.float32)
+    codes = rng.integers(0, k, (700, m)).astype(np.uint8)
+    codes[0], codes[1] = 0, k - 1
+    got = np.asarray(pq_ops.pq_reconstruct(jnp.asarray(codes),
+                                           jnp.asarray(cent), m))
+    assert got.dtype == np.float32
+    assert got.tobytes() == pq_reference.reconstruct(cent, codes).tobytes()
+
+
+@pytest.mark.parametrize("b", [1, 3, 32])
+@pytest.mark.parametrize("chunk", [1024, 256], ids=["one-chunk", "four-chunks"])
+@pytest.mark.parametrize("masks", ["valid", "valid+allow_bits"])
+@pytest.mark.parametrize("metric", ["l2-squared", "dot", "cosine"])
+def test_pq_topk_against_the_plain_quantizer(rng, metric, masks, chunk, b):
+    """``pq_topk`` on a shared codebook and shared codes against
+    tests/pq_reference.py: the same candidates (up to rows within 1e-4 of
+    the cut) at the same distances, with dead rows, with a per-query allow
+    list, over one chunk and over several, at three batch sizes."""
+    from weaviate_tpu.ops.pallas_kernels import pack_allow_bitmask
+
+    n, m, ds, k, n_cand = 1024, 8, 4, 64, 10
+    cent = (rng.standard_normal((m, k, ds)) * 0.3).astype(np.float32)
+    codes = rng.integers(0, k, (n, m)).astype(np.uint8)
+    q = rng.standard_normal((b, m * ds)).astype(np.float32)
+    if metric == "cosine":
+        q /= np.linalg.norm(q, axis=1, keepdims=True)
+    valid = rng.random(n) > 0.1
+    allow = None
+    if masks == "valid+allow_bits":
+        allow = rng.random((b, n)) > 0.5
+    d, i = pq_ops.pq_topk(
+        jnp.asarray(q), jnp.asarray(codes), jnp.asarray(cent), k=n_cand,
+        chunk_size=chunk, metric=metric, valid=jnp.asarray(valid),
+        allow_bits=(None if allow is None
+                    else jnp.asarray(pack_allow_bitmask(allow))))
+    d, i = np.asarray(d), np.asarray(i)
+    for r in range(b):
+        live = valid if allow is None else valid & allow[r]
+        want, dist = pq_reference.candidates(cent, codes, q[r], metric,
+                                             n_cand, live)
+        got = set(i[r].tolist())
+        assert len(got) == n_cand and live[i[r]].all()
+        cut = dist[want[-1]]
+        for row in got ^ set(want.tolist()):
+            assert abs(dist[row] - cut) < 1e-4, (r, row, dist[row], cut)
+        np.testing.assert_allclose(d[r], dist[i[r]], rtol=1e-5, atol=1e-4)
+        assert (np.diff(d[r]) >= 0).all()
+
+
 # -- BQ ops ------------------------------------------------------------------
 
 def test_bq_encode_matches_numpy(rng):

@@ -11,6 +11,7 @@ result or time is checked. ``chip_smoke.py`` is the chip run.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -149,21 +150,34 @@ def test_pq_encode_and_fit_at_96_segments(one_chip, rows):
         assert c.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
-@pytest.mark.parametrize("b", [1, 32])
-def test_pq_topk_at_96_segments(one_chip, b):
+@pytest.mark.parametrize("b", [1, 8, 32])
+@pytest.mark.parametrize("m,ds", [(PQ_M, 1), (PQ_D // 8, 8)],
+                         ids=["m=96", "m=12-default"])
+def test_pq_topk_at_96_segments(one_chip, monkeypatch, m, ds, b):
+    """The served 8-bit scan at the cell's geometry and at this tree's
+    default one (d/8 segments of 8 dims): the chip's compiler takes it with
+    the look-up kernel in it, no [chunk, segments, centroids] one-hot and no
+    reconstructed chunk is left in HBM, and no XLA gather over the chunk's
+    codes (the 249-ms disease, ISSUE 29) is left in the optimised program."""
     from weaviate_tpu.ops.pq import pq_topk
 
-    rows = 262144
+    # the process sees the CPU: steer the look-up to the branch a TPU takes
+    monkeypatch.setattr(pk, "recommended", lambda: True)
+    rows, chunk = 262144, 8192
 
     def fn(q, codes, cent, valid):
-        return pq_topk(q, codes, cent, k=160, chunk_size=8192,
+        return pq_topk(q, codes, cent, k=160, chunk_size=chunk,
                        metric="cosine", valid=valid)
 
     c = _compile(fn, one_chip, ((b, PQ_D), jnp.float32),
-                 ((rows, PQ_M), jnp.uint8),
-                 ((PQ_M, PQ_K, PQ_D // PQ_M), jnp.float32),
+                 ((rows, m), jnp.uint8), ((m, PQ_K, ds), jnp.float32),
                  ((rows,), jnp.bool_))
     assert c.memory_analysis().temp_size_in_bytes < 256 << 20
+    text = c.as_text()
+    assert "pq8_lookup" in text and "tpu_custom_call" in text
+    for shape in re.findall(r"= \w+\[([\d,]*)\][^ ]* gather\(", text):
+        elements = int(np.prod([int(x) for x in shape.split(",") if x]))
+        assert elements < chunk * m, f"a gather over [{shape}] is left"
 
 
 def test_bq_mxu_block(one_chip):

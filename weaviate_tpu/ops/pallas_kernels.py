@@ -32,6 +32,11 @@ Kernels:
                         LUT-ADC semantics (reference DistanceLookUpTable,
                         product_quantization.go:440) at mk=4d FLOPs/row with
                         m=d/4 codes reading 8-32x fewer HBM bytes per row.
+- ``pq8_lookup_block``  8-bit-PQ dequantisation: codes -> reconstructed rows
+                        through a lane gather inside the vreg (corpus rows
+                        on lanes, a dimension's 256 levels in two vregs),
+                        where XLA's gather is one scalar load a value.
+                        Exact; what ``ops/pq.py::pq_topk`` scans with.
 
 On CPU (tests, dev) the kernels run through the Pallas interpreter —
 bit-identical semantics, no Mosaic compile. ``recommended()`` says whether
@@ -679,6 +684,100 @@ def pq4_recon_block(
     out = _pq4_recon_tiled(q, cflat, codes, valid_f[None, :], k, m,
                            metric, tile_n, interpret)
     return out[:b, :n]
+
+
+# -- 8-bit PQ: the code look-up as a lane gather ------------------------------
+#
+# ``centroids[s, codes[n, s]]`` is a look-up in a table of at most 256
+# entries. As an XLA gather it is one scalar load a value, serialised:
+# 786,432 of them for an 8,192-row chunk at 96 segments, 249 ms a dispatch
+# of the served scan on the v5e (PERF.md, PR 28). With the corpus rows on
+# LANES it is what a vreg does in one instruction: sublane row r of a tile
+# holds dimension r of 128 corpus rows, row r of the table holds that
+# dimension's levels, 128 to a vreg, and ``take_along_axis(axis=1)`` on a
+# [rows, 128] tile is a gather inside each sublane row. 256 levels are two
+# halves of 128 lanes and a select on the code's bit 7. A value is moved,
+# never multiplied: the result is the centroid's own float32, bit for bit.
+
+
+def _pq8_lookup_kernel(codes_ref, table_ref, out_ref):
+    """codes [TD, TN] int32 (rows on lanes; a segment's code repeated on
+    each of its ds sublane rows), table [TD, 128 or 256] f32 (row r = the
+    levels of dimension r) -> out[r, n] = table[r, codes[r, n]]."""
+    for j in range(codes_ref.shape[1] // _LANE):
+        cols = slice(j * _LANE, (j + 1) * _LANE)
+        code = codes_ref[:, cols]
+        low = code & (_LANE - 1)
+        val = jnp.take_along_axis(table_ref[:, :_LANE], low, axis=1)
+        for h in range(1, table_ref.shape[1] // _LANE):
+            upper = jnp.take_along_axis(
+                table_ref[:, h * _LANE:(h + 1) * _LANE], low, axis=1)
+            val = jnp.where((code >> 7) == h, upper, val)
+        out_ref[:, cols] = val
+
+
+@functools.partial(jax.jit, static_argnames=("tile_d", "tile_n", "interpret"))
+def _pq8_lookup_tiled(codes_t, table, tile_d, tile_n, interpret):
+    dp, pn = codes_t.shape
+    width = table.shape[1]
+    return pl.pallas_call(
+        _pq8_lookup_kernel,
+        grid=(dp // tile_d, pn // tile_n),
+        in_specs=[
+            pl.BlockSpec((tile_d, tile_n), lambda i, j: (i, j),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((tile_d, width), lambda i, j: (i, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((tile_d, tile_n), lambda i, j: (i, j),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((dp, pn), jnp.float32),
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=codes_t.size * 8 + table.size * 4),
+        interpret=interpret,
+        name="pq8_lookup",
+    )(codes_t, table)
+
+
+def pq8_lookup_block(
+    codes: jnp.ndarray,
+    centroids: jnp.ndarray,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Dequantise 8-bit PQ codes: codes [N, m] uint8, centroids [m, k<=256,
+    ds] f32 -> x_hat [N, m*ds] f32 with x_hat[n, s*ds + j] =
+    centroids[s, codes[n, s], j], bit-equal to the table look-up.
+
+    Any geometry: the tile is cut from the shapes (dimensions in blocks of
+    at most 512 sublane rows, corpus rows in lanes of at most 1,024, 1 MiB
+    a block), 17..128 centroids take one 128-lane half of the table and
+    129..256 two. The transposes in and out are XLA's, which folds the
+    one out into the distance matmul's dimension numbers.
+    """
+    if interpret is None:
+        interpret = not recommended()
+    m, k, ds = centroids.shape
+    if k > 256:
+        raise ValueError(f"pq8 look-up takes k <= 256 centroids, got {k}")
+    n = codes.shape[0]
+    d = m * ds
+    d_blocks = -(-d // 512)
+    tile_d = _pad_to(-(-d // d_blocks), _SUBLANE)
+    dp = d_blocks * tile_d
+    tile_n = 1024
+    while tile_n > _LANE and (tile_d * tile_n * 4 > (1 << 20)
+                              or tile_n >= 2 * _pad_to(max(n, 1), _LANE)):
+        tile_n //= 2
+    pn = _pad_to(max(n, 1), tile_n)
+    # table[s*ds + j, c] = centroids[s, c, j]
+    table = jnp.transpose(centroids.astype(jnp.float32), (0, 2, 1))
+    table = jnp.pad(table.reshape(d, k),
+                    ((0, dp - d), (0, _pad_to(k, _LANE) - k)))
+    codes_t = jnp.repeat(codes.astype(jnp.int32), ds, axis=1).T  # [d, N]
+    codes_t = jnp.pad(codes_t, ((0, dp - d), (0, pn - n)))
+    out = _pq8_lookup_tiled(codes_t, table, tile_d, tile_n, interpret)
+    return out[:d, :n].T
 
 
 # -- fused distance + top-k scan ---------------------------------------------

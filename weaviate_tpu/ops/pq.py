@@ -13,12 +13,18 @@ are orthogonal, the asymmetric distance
 is *exactly* ``dist(q, x_hat_n)`` where ``x_hat_n`` is the vector
 reconstructed from centroids. So compressed search becomes:
 
-    per chunk: gather codes -> reconstruct [chunk, d] -> one distance matmul
+    per chunk: look codes up -> x_hat [chunk, d] -> one distance matmul
 
-The reconstruction gather is per-*chunk* (amortized over the whole query
-batch), and the distance is the same MXU matmul as the uncompressed path,
-reading 16-64x fewer HBM bytes (codes are m uint8s instead of d floats).
-Identical results to LUT-ADC, radically better TPU utilization.
+The look-up is per *chunk* (shared by the whole query batch) and exact: it
+moves a centroid's float32 value, so the distance is the same float32 matmul
+as the uncompressed path over 16-64x fewer HBM bytes (codes are m uint8s
+instead of d floats). HOW the look-up is done decides everything: as an XLA
+gather (``jnp.take`` a segment) the chip does one scalar load a value,
+249 ms a dispatch at 262,144 x 96 codes; as a lane gather inside the vreg
+(``pallas_kernels.pq8_lookup_block``: rows on lanes, a dimension's 256
+levels in two vregs) it is 0.25 ms of a 2-6 ms dispatch (PERF.md, PR 29).
+``pq_reconstruct`` takes the kernel on a TPU and ``jnp.take``, its twin and
+its definition, elsewhere; every 8-bit geometry takes the same path.
 
 k-means fit runs as batched Lloyd iterations over all segments at once
 (einsum over [N, m, ds]), chunk-scanned so HBM never holds [N, m, k].
@@ -147,20 +153,33 @@ def pq_encode(codebook: PQCodebook, vectors: np.ndarray, batch: int = 65536) -> 
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("m",))
-def pq_reconstruct(codes: jnp.ndarray, centroids: jnp.ndarray, m: int):
-    """codes [N, m] uint8 -> x_hat [N, d] f32 via per-segment centroid gather.
+def _rows_from_codes(codes: jnp.ndarray, centroids: jnp.ndarray):
+    """codes [N, m] uint8 -> x_hat [N, d] f32, x_hat[n, s*ds + j] =
+    centroids[s, codes[n, s], j]. Traced inside the caller's program (no
+    jit of its own), so the choice below is made once a program: on a TPU
+    the lane-gather kernel, for every geometry; elsewhere the take it is
+    held equal to (XLA:CPU gathers well, XLA:TPU one scalar at a time)."""
+    from weaviate_tpu.ops import pallas_kernels as pk
 
-    This is the decompression half of the gather-matmul: the gather indexes
-    tiny [k, ds] tables and is amortized over the whole query batch.
-    """
+    if pk.recommended():
+        return pk.pq8_lookup_block(codes, centroids, interpret=False)
     idx = codes.astype(jnp.int32)  # [N, m]
-    # vmap the per-segment table lookup over segments
     gathered = jax.vmap(
         lambda table, ix: jnp.take(table, ix, axis=0), in_axes=(0, 1), out_axes=1
     )(centroids, idx)  # [N, m, ds]
-    n = codes.shape[0]
-    return gathered.reshape(n, m * centroids.shape[2])
+    return gathered.reshape(codes.shape[0], -1)
+
+
+@functools.partial(jax.jit, static_argnames=("m",))
+def pq_reconstruct(codes: jnp.ndarray, centroids: jnp.ndarray, m: int):
+    """codes [N, m] uint8 -> x_hat [N, d] f32: each segment's centroid,
+    bit-equal to ``centroids[s, codes[:, s]]``.
+
+    The decompression half of the scan (``pq_topk`` traces the same
+    look-up into its own program) and IVF's residual encode.
+    """
+    assert codes.shape[1] == m == centroids.shape[0]
+    return _rows_from_codes(codes, centroids)
 
 
 @functools.partial(
@@ -178,7 +197,8 @@ def pq_topk(
     m: int | None = None,
     allow_bits: jnp.ndarray | None = None,
 ):
-    """Compressed brute-force top-k: scan codes in chunks, reconstruct, score.
+    """Compressed brute-force top-k: scan codes in chunks, look each chunk's
+    codes up (``_rows_from_codes``: exact), score the rows.
 
     Matches LUT-ADC results exactly for l2-squared/dot/cosine (orthogonal
     segments). Returns (dists [B,k], ids [B,k]) like chunked_topk.
@@ -210,7 +230,7 @@ def pq_topk(
     def body(carry, inp):
         best_d, best_i = carry
         chunk_idx, cc, vc, ac = inp
-        x_hat = pq_reconstruct(cc, centroids, m)
+        x_hat = _rows_from_codes(cc, centroids)
         d = pairwise_distance(q, x_hat, metric=metric)
         if vc is not None:
             d = jnp.where(vc[None, :], d, MASKED_DISTANCE)
