@@ -5,7 +5,7 @@ user calls.
     python chip_smoke.py --chips 4    # four chips: the sharded phase only
 
 Starts a real ``weaviate_tpu.server.Server`` in this process on loopback
-ports (the pattern of tools/bench_e2e.py, which mirrors the reference's
+ports (the pattern of benchmarks/, which mirrors the reference's
 test/benchmark/benchmark_sift.go) with a fresh temp data dir and seeded
 data, and talks to it over sockets only: schema + deletes + reads over
 REST, import + search over gRPC, hybrid over GraphQL. Each phase checks its
@@ -68,7 +68,7 @@ def report(phase: str, problems: list[str], **obs) -> None:
 
 def clustered(rng, n: int, dim: int, centers=None, spread: float = 0.35,
               members: int = 8):
-    """Mixture of gaussians as bench.py's ``clustered_corpus`` makes it
+    """Mixture of gaussians as ``benchmarks/datagen/clustered.py`` makes it
     (real embeddings cluster; i.i.d. gaussian is the adversarial floor for
     the compressed phase): ``n // members`` centers, at most 65536.
     Returns (rows, centers)."""
@@ -476,7 +476,7 @@ def p4_hybrid(args, rng, server, wire) -> None:
 
 def p5_compressed(args, rng, server, wire) -> None:
     n = max(4096, args.rows // 8) if args.rows else ADA_ROWS
-    # 128 members per cluster, not bench.py's 8: a 10 % filter that is
+    # 128 members per cluster, not the default 8: a 10 % filter that is
     # independent of the clusters must leave a neighbourhood (~13 rows)
     # behind. With 8, under one member survives the filter, the masked
     # top-10 is other clusters' rows at near-equal distances — the i.i.d.

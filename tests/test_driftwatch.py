@@ -1,7 +1,7 @@
 """Driftwatch (ISSUE 19): online recall & perf drift detection.
 
-Covers the three legs end to end: band-classification parity with the
-benchkeeper CLI (same core.compare, same verdict statuses, same
+Covers the three legs end to end: band classification of live
+telemetry (``runtime/bands``: verdict statuses, counts and the
 cross-fingerprint refusal), canary determinism + epoch-change
 ground-truth invalidation against a real Database, and the two
 sabotage-validated incident paths the acceptance criteria name —
@@ -27,7 +27,7 @@ from weaviate_tpu.runtime import (degrade, driftwatch, faultline,
 from weaviate_tpu.schema.config import CollectionConfig
 
 
-# -- leg 2 units: parity with the benchkeeper CLI -----------------------------
+# -- leg 2 units: band classification ------------------------------------------
 
 
 def _section(ewma_ms: float) -> dict:
@@ -39,28 +39,65 @@ def _section(ewma_ms: float) -> dict:
 
 
 def test_live_classification_is_benchkeeper_band_math():
-    """pass / regression / stale out of driftwatch's classifier must be
-    the literal benchkeeper verdict for the same synthetic run — one
-    band implementation, not a lookalike."""
-    from tools.benchkeeper import core as bk
+    """pass / regression / stale out of driftwatch's classifier for a
+    self-sealed baseline: each entry's status and normalized delta, and
+    the verdict's counts and gate."""
+    from weaviate_tpu.runtime import bands
 
     fp = {"platform": "cpu"}
     baseline = driftwatch.seal_live_baseline(_section(2.0), fp)
-    bk.validate_baseline(baseline, "<test>")
+    bands.validate_baseline(baseline, "<test>")
 
-    for value, want in ((2.5, "pass"),        # +25% inside the 75% band
-                        (20.0, "regression"),  # +900%
-                        (0.2, "stale")):       # -90% unexplained
+    for value, want, delta in (
+            (2.5, "pass", 0.25),         # +25% inside the 75% band
+            (20.0, "regression", 9.0),   # +900%
+            (0.2, "stale", -0.9)):       # -90% unexplained
         verdict = driftwatch.classify_live(_section(value), baseline, fp)
-        direct = bk.compare({"env_fingerprint": fp,
-                             "sections": {"live": _section(value)}},
-                            baseline)
-        by_id = {r["id"]: r["status"] for r in verdict["entries"]}
-        assert by_id["live.residency.flat/b8/k16"] == want
+        assert verdict["refused"] is None
         assert [(r["id"], r["status"], r["delta_frac"])
-                for r in verdict["entries"]] \
-            == [(r["id"], r["status"], r["delta_frac"])
-                for r in direct["entries"]]
+                for r in verdict["entries"]] == [
+            ("live.residency.flat/b8/k16", want, delta),
+            ("live.compile_miss_per_cycle", "pass", 0.0)]
+        counts = {"passed": 1, "regressions": 0, "stale": 0, "missing": 0}
+        counts[{"pass": "passed", "regression": "regressions",
+                "stale": "stale"}[want]] += 1
+        assert verdict["checked"] == 2
+        assert {k: verdict[k] for k in counts} == counts
+        assert verdict["ok"] is (want == "pass")
+
+
+def test_live_leg_classifies_without_the_checkout_on_the_path(tmp_path):
+    """The package is the whole of what a server needs: with only
+    ``weaviate_tpu`` importable (no ``tools``, no repo root on
+    ``sys.path``) a cycle's live leg seals its baseline, persists it and
+    classifies against it."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    site = tmp_path / "site"
+    site.mkdir()
+    os.symlink(os.path.join(repo, "weaviate_tpu"), site / "weaviate_tpu")
+    script = """
+import importlib.util, json, sys
+assert importlib.util.find_spec("tools") is None, sys.path
+from weaviate_tpu.runtime import driftwatch, kernelscope
+driftwatch.configure(data_dir=sys.argv[1], enabled=True)
+for _ in range(8):
+    kernelscope.record_dispatch("flat", 8, 16, 0.002, "wall")
+driftwatch.run_cycle()
+print(json.dumps(driftwatch.snapshot()["live"]))
+"""
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(site))
+    proc = subprocess.run(
+        [sys.executable, "-c", script, str(tmp_path / "data")],
+        capture_output=True, text=True, env=env, cwd=str(tmp_path))
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    live = json.loads(proc.stdout.splitlines()[-1])
+    assert live["baselineError"] is None
+    assert live["baselineSource"] == "sealed:" + str(
+        tmp_path / "data" / "driftwatch" / "live_baseline.json")
+    assert {t["id"]: t["status"] for t in live["trends"]} == {
+        "live.residency.flat/b8/k16": "pass",
+        "live.compile_miss_per_cycle": "pass"}
 
 
 def test_refused_fingerprint_matches_cli_and_does_not_flip_health():
@@ -698,7 +735,7 @@ def test_sabotaged_id_mapping_trips_canary_recall_finding(tmp_path):
 def test_history_ring_and_offline_replay(tmp_path, monkeypatch):
     """Every cycle appends one JSONL record under <data_dir>/driftwatch
     and ``python -m tools.driftwatch`` re-classifies them offline
-    against the node's sealed baseline with benchkeeper exit-code
+    against the node's sealed baseline with ``runtime/bands`` exit-code
     semantics (0 clean, 1 regressed cycle or open canary finding).
 
     The residency here is that of real dispatches on the real clock

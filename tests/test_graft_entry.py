@@ -1,4 +1,5 @@
-"""Driver-contract tests: entry() compiles, dryrun_multichip(8) runs."""
+"""Driver-contract tests: entry() compiles, dryrun_multichip(8) runs, and
+every console script of pyproject.toml resolves."""
 
 import sys
 
@@ -24,8 +25,21 @@ def test_dryrun_multichip():
     ge.dryrun_multichip(8)
 
 
-def test_dryrun_benchkeeper():
-    """The perf-gate machinery self-test is part of the driver contract
-    (ISSUE 6): parsing, band math, stale detection, fingerprint refusal
-    and exit codes all behave on a synthetic run — no device needed."""
-    ge.dryrun_benchkeeper()
+def _console_scripts() -> dict:
+    import os
+    import tomllib
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "pyproject.toml"), "rb") as f:
+        return tomllib.load(f)["project"]["scripts"]
+
+
+@pytest.mark.parametrize("target", sorted(_console_scripts().values()))
+def test_console_script_resolves_to_a_callable(target):
+    """Every ``[project.scripts]`` entry names a module that imports and
+    a callable in it: a script whose target was deleted fails here, not
+    on an operator's shell."""
+    import importlib
+
+    module, _, attr = target.partition(":")
+    assert callable(getattr(importlib.import_module(module), attr))

@@ -886,24 +886,22 @@ def test_g5_runtime_lint_checks_exemplar_grammar():
     assert any("ascending" in p for p in g5_metrics.lint(reg2))
 
 
-def test_g5_timing_fields_gate_bench_and_benchkeeper(tmp_path):
-    """bench.py and tools/benchkeeper are in G5 scope (their JSON is
-    benchkeeper's wire format); tests stay excluded."""
+def test_g5_timing_fields_scope_is_the_package(tmp_path):
+    """The timing-field convention gates the package (``runtime/bands``
+    reads ``device_ms`` by name); tests, tools and top-level scripts
+    stay excluded."""
     src = """
         def section():
             return {"device_seconds": 0.5}
     """
     res = lint_tree(tmp_path, {
-        "bench.py": src,
-        "tools/benchkeeper/core.py": src,
-        "tests/test_fx.py": src,          # out of scope
-        "tools/bench_e2e.py": src,        # legacy bench scripts too
+        "weaviate_tpu/runtime/fx.py": src,
+        "tests/test_fx.py": src,
+        "tools/fx/core.py": src,
+        "fx.py": src,
     })
-    g5 = [(v.check, v.path) for v in res.violations if v.check == "G5"]
-    assert ("G5", "bench.py") in g5
-    assert ("G5", "tools/benchkeeper/core.py") in g5
-    assert all(p not in ("tests/test_fx.py", "tools/bench_e2e.py")
-               for _, p in g5)
+    g5 = {v.path for v in res.violations if v.check == "G5"}
+    assert g5 == {"weaviate_tpu/runtime/fx.py"}
 
 
 def test_g5_runtime_lint_reexported_through_shim():
@@ -1261,13 +1259,13 @@ def test_g7_guarded_write_is_not_an_fsync(tmp_path):
 
 
 def test_g7_scope_covers_state_owners_only(tmp_path):
-    """storage/cluster/engine + benchkeeper/crashtest own durable state;
+    """storage/cluster/engine + crashtest own durable state;
     api/runtime/tests do not (their writes are reports/sockets)."""
     res = lint_tree(tmp_path, {
         "weaviate_tpu/storage/fx.py": G7_POSITIVE,
         "weaviate_tpu/cluster/fx.py": G7_POSITIVE,
         "weaviate_tpu/engine/fx.py": G7_POSITIVE,
-        "tools/benchkeeper/fx.py": G7_POSITIVE,
+        "tools/crashtest/fx.py": G7_POSITIVE,
         "weaviate_tpu/api/fx.py": G7_POSITIVE,
         "weaviate_tpu/runtime/fx.py": G7_POSITIVE,
         "tests/test_fx.py": G7_POSITIVE,
@@ -1276,7 +1274,7 @@ def test_g7_scope_covers_state_owners_only(tmp_path):
     assert flagged == {"weaviate_tpu/storage/fx.py",
                        "weaviate_tpu/cluster/fx.py",
                        "weaviate_tpu/engine/fx.py",
-                       "tools/benchkeeper/fx.py"}
+                       "tools/crashtest/fx.py"}
 
 
 def test_g7_fsutil_itself_is_exempt(tmp_path):
@@ -1290,8 +1288,7 @@ def test_g7_fsutil_itself_is_exempt(tmp_path):
 def test_g7_baseline_stays_empty_for_storage_engine_cluster():
     """ISSUE 9 acceptance: the durable tree itself carries ZERO G7
     grandfathers — the fsync ordering was fixed by routing through
-    fsutil, not baselined. Only the advisory benchkeeper writers may be
-    baselined (with reasons)."""
+    fsutil, not baselined."""
     entries = core.load_baseline(core.default_baseline_path(REPO_ROOT))
     g7_state = [e for e in entries
                 if e.get("check") == "G7"
@@ -1419,10 +1416,8 @@ def test_cli_json_output_and_exit_codes(tmp_path):
 def test_repo_gate_zero_nonbaselined_violations():
     """Every future PR runs this: the production tree must be clean
     modulo the checked-in baseline, and the baseline must not be stale.
-    bench.py and tools/benchkeeper ride the gate too — their JSON
-    fields are the perf gate's wire format (G5 timing conventions)."""
-    res = run(["weaviate_tpu", "bench.py", "tools/benchkeeper",
-               "tools/crashtest"],
+    The paths are ``python -m tools.graftlint``'s defaults."""
+    res = run(["weaviate_tpu", "tools/crashtest"],
               REPO_ROOT, use_cache=False,
               baseline_path=core.default_baseline_path(REPO_ROOT))
     assert res.errors == []

@@ -63,7 +63,7 @@ def test_sharded_quantized_recall_vs_exact(rng, quantization, rescore):
     rec_si = np.mean([len(set(i_si[r]) & set(gt[r])) / k for r in range(len(q))])
     # parity gate: sharding must not degrade the quantizer's recall
     # (absolute recall at this dim/data is a quantizer property — the
-    # 1M-scale recall bars live in bench.py on real data shapes)
+    # recall at scale is what benchmarks/ checks on the chip)
     assert rec_sh >= rec_si - 0.05, (quantization, rescore, rec_sh, rec_si)
     assert rec_sh >= 0.5, (quantization, rescore, rec_sh)
     # top-1 after exact rescore must match ground truth everywhere

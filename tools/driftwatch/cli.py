@@ -1,14 +1,14 @@
-"""driftwatch CLI — replay a history ring against a benchkeeper baseline.
+"""driftwatch CLI — replay a history ring against a baseline file.
 
 Each history record already carries the raw live-telemetry section
 (kernelscope residency EWMAs, memcpy estimator, per-cycle counters) and
 the environment fingerprint it was measured under, so classification is
 exactly what the runtime did: rebuild the synthetic one-section run and
-hand it to ``tools.benchkeeper.core.compare`` — same band math, same
+hand it to ``weaviate_tpu.runtime.bands.compare`` — same band math, same
 verdict statuses, same cross-fingerprint refusal. Canary records are
 summarized as a recall/residency trend alongside.
 
-Exit codes mirror benchkeeper: 0 = every replayed cycle gates clean,
+Exit codes (``runtime/bands``): 0 = every replayed cycle gates clean,
 1 = at least one cycle regressed (or an open canary finding), 2 = usage
 or refused comparison.
 """
@@ -20,7 +20,7 @@ import json
 import os
 import sys
 
-from tools.benchkeeper import core as bk
+from weaviate_tpu.runtime import bands
 
 
 def _load_history(path: str) -> list[dict]:
@@ -43,7 +43,7 @@ def _load_history(path: str) -> list[dict]:
 
 
 def _cycle_run(rec: dict) -> dict | None:
-    """Rebuild the synthetic benchkeeper run the runtime classified."""
+    """Rebuild the synthetic one-section run the runtime classified."""
     metrics = (rec.get("live") or {}).get("metrics")
     if not metrics:
         return None
@@ -69,12 +69,12 @@ def main(argv: list[str] | None = None) -> int:
         prog="driftwatch",
         description="Replay a driftwatch JSONL history ring offline, "
                     "re-classifying each cycle's live telemetry against "
-                    "a benchkeeper baseline.")
+                    "a baseline file.")
     ap.add_argument("history", nargs="?",
                     help="path to history.jsonl (or a data dir "
                          "containing driftwatch/history.jsonl)")
     ap.add_argument("--baseline",
-                    help="benchkeeper baseline to classify against "
+                    help="baseline file to classify against "
                          "(default: live_baseline.json next to the "
                          "history file — the node's own sealed bands)")
     ap.add_argument("--last", type=int, default=0, metavar="N",
@@ -91,25 +91,25 @@ def main(argv: list[str] | None = None) -> int:
             else os.path.join(path, "history.jsonl")
     if not os.path.exists(path) and not os.path.exists(path + ".1"):
         print(f"driftwatch: no history at {path}", file=sys.stderr)
-        return 2
+        return bands.EXIT_REFUSED
 
     baseline_path = args.baseline or os.path.join(
         os.path.dirname(path) or ".", "live_baseline.json")
     try:
-        baseline = bk.load_baseline(baseline_path)
-    except (bk.BaselineError, OSError) as e:
+        baseline = bands.load_baseline(baseline_path)
+    except (bands.BaselineError, OSError) as e:
         print(f"driftwatch: cannot load baseline {baseline_path}: {e}",
               file=sys.stderr)
-        return 2
+        return bands.EXIT_REFUSED
 
     records = _load_history(path)
     if args.last > 0:
         records = records[-args.last:]
     if not records:
         print(f"driftwatch: history at {path} is empty", file=sys.stderr)
-        return 2
+        return bands.EXIT_REFUSED
 
-    worst = 0
+    worst = bands.EXIT_OK
     for rec in records:
         run = _cycle_run(rec)
         head = (f"cycle {rec.get('cycle', '?')} @ {rec.get('t', 0):.0f} "
@@ -122,9 +122,10 @@ def main(argv: list[str] | None = None) -> int:
                                   "skipped": "no live metrics"}))
             else:
                 print(head + ": no live metrics recorded")
-            worst = max(worst, 1 if canary_open else 0)
+            worst = max(worst, bands.EXIT_GATE_FAIL if canary_open
+                        else bands.EXIT_OK)
             continue
-        verdict = bk.compare(run, baseline, baseline_path=baseline_path)
+        verdict = bands.compare(run, baseline, baseline_path=baseline_path)
         if args.json:
             verdict["cycle"] = rec.get("cycle")
             verdict["canaries"] = rec.get("canaries", [])
@@ -134,11 +135,11 @@ def main(argv: list[str] | None = None) -> int:
             cl = _canary_line(rec)
             if cl:
                 print("  canaries: " + cl)
-            bk.render(verdict)
+            bands.render(verdict)
         if verdict.get("refused"):
-            worst = max(worst, 2)
+            worst = max(worst, bands.EXIT_REFUSED)
         elif not verdict["ok"] or canary_open:
-            worst = max(worst, 1)
+            worst = max(worst, bands.EXIT_GATE_FAIL)
     return worst
 
 

@@ -1354,8 +1354,7 @@ def main(argv: list[str] | None = None) -> int:
                     "lock-discipline invariants (G1..G5).")
     ap.add_argument("paths", nargs="*", default=None,
                     help="files or directories (default: the tier-1 "
-                         "gate set — weaviate_tpu, bench.py, "
-                         "tools/benchkeeper, tools/crashtest)")
+                         "gate set — weaviate_tpu, tools/crashtest)")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="machine-readable output")
     ap.add_argument("--update-baseline", action="store_true",
@@ -1387,8 +1386,7 @@ def main(argv: list[str] | None = None) -> int:
     # default = the exact tree test_repo_gate_zero_nonbaselined_violations
     # enforces; a narrower scan would misreport baseline entries for the
     # unscanned tools as stale
-    paths = args.paths or ["weaviate_tpu", "bench.py",
-                           "tools/benchkeeper", "tools/crashtest"]
+    paths = args.paths or ["weaviate_tpu", "tools/crashtest"]
     paths = [p for p in paths
              if os.path.exists(os.path.join(root, p))] or ["weaviate_tpu"]
     baseline_path = args.baseline or default_baseline_path(root)

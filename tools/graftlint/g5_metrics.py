@@ -11,26 +11,21 @@ in a module no test imports still fails the gate. Non-literal
 registrations (the registry's own internals, dynamic names) are skipped,
 not guessed at; the runtime lint still covers those.
 
-Timing conventions (the benchkeeper tentpole made these load-bearing:
-the perf gate compares fields by NAME across runs, so an ambiguous
-unit is a silent 1000x comparison error):
+Timing conventions (fields are compared by NAME across runs and
+traces, so an ambiguous unit is a silent 1000x comparison error):
 
 - a registered metric whose name says it measures time (``*duration*``,
   ``*latency*``, ``*elapsed*``) must state its unit — a ``_seconds`` /
   ``_ms`` / ``_us`` / ``_ns`` name suffix, or an explicit unit word in
   the HELP text;
-- bench/trace timing FIELDS (dict keys, ``sp.set(...)`` attrs) must
+- trace timing FIELDS (dict keys, ``sp.set(...)`` attrs) must
   not use ambiguous or nonstandard unit suffixes: ``wall_s`` /
   ``device_seconds`` / ``host_time`` etc. are flagged — the repo
   convention is ``*_ms``;
 - device-attributed timings are named exactly ``device_ms`` (that is
-  the field run_section rolls up, benchkeeper gates on, and
-  tracing.device_sync emits) — aliases like ``dev_ms`` /
-  ``device_time_ms`` fork the schema.
-
-This checker also covers ``bench.py`` and ``tools/benchkeeper/`` —
-the bench JSON is the perf gate's wire format, so its field hygiene
-is as production as the runtime's.
+  the field tracing.device_sync emits and ``runtime/bands`` reports as
+  a section's noise) — aliases like ``dev_ms`` / ``device_time_ms``
+  fork the schema.
 
 ``lint(registry)`` below is the runtime half, kept verbatim from
 tools/lint_metrics.py so that file can become a thin shim without
@@ -154,14 +149,9 @@ class MetricsConventionChecker(Checker):
     name = "metrics-conventions"
 
     def applies_to(self, path: str) -> bool:
-        # production modules, plus the bench harness and the perf gate
-        # — their JSON fields are benchkeeper's wire format (tests
-        # still register throwaway metrics on private registries on
-        # purpose and stay excluded)
-        return path.endswith(".py") and (
-            path.startswith("weaviate_tpu/")
-            or path == "bench.py"
-            or path.startswith("tools/benchkeeper/"))
+        # production modules (tests register throwaway metrics on
+        # private registries on purpose and stay excluded)
+        return path.endswith(".py") and path.startswith("weaviate_tpu/")
 
     def check(self, ctx: FileContext) -> list[Violation]:
         out: list[Violation] = []
@@ -274,9 +264,8 @@ class MetricsConventionChecker(Checker):
                 out.append(self._violation(
                     ctx, anchor,
                     f"device-attributed timing field {key!r} must be "
-                    "named 'device_ms' — benchkeeper and run_section "
-                    "compare that exact field across runs; an alias "
-                    "forks the schema"))
+                    "named 'device_ms' — traces and band verdicts "
+                    "read that exact field; an alias forks the schema"))
             elif _AMBIG_FIELD_RE.match(key):
                 want = key.split("_", 1)[0] + "_ms"
                 out.append(self._violation(

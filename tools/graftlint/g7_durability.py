@@ -9,7 +9,7 @@ regression a code review misses because the happy path is identical.
 This checker gates the directories that own durable state:
 
 - ``os.replace`` calls in ``weaviate_tpu/storage|cluster|engine/`` and
-  ``tools/benchkeeper|crashtest/`` must live in fsutil itself (the one
+  ``tools/crashtest/`` must live in fsutil itself (the one
   audited implementation). Exception: quarantine renames whose
   destination is a ``... + ".corrupt"`` expression — those move
   evidence aside, they don't create durable state, and routing them
@@ -20,12 +20,6 @@ This checker gates the directories that own durable state:
   never fsyncs anything is a durability hole (the WAL ``reset`` pattern
   passes: it fsyncs conditionally; the old hnsw ``condense`` pattern
   fails: tmp written, never synced).
-
-Pre-existing writers with their own audited discipline (benchkeeper's
-``_atomic_write_json``: tmp + file-fsync + replace, no dir fsync — its
-artifacts are advisory perf verdicts, losing one rolls back to the
-previous verdict) are grandfathered in the baseline WITH reasons, per
-graftlint convention.
 """
 
 from __future__ import annotations
@@ -38,7 +32,6 @@ _SCOPES = (
     "weaviate_tpu/storage/",
     "weaviate_tpu/cluster/",
     "weaviate_tpu/engine/",
-    "tools/benchkeeper/",
     "tools/crashtest/",
 )
 _FSUTIL = "weaviate_tpu/storage/fsutil.py"

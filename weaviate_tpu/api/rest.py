@@ -86,9 +86,6 @@ DEBUG_ENDPOINTS = {
     "replication": "Anti-entropy convergence: hashbeat rounds, "
                    "divergent-entry estimates, staged-2PC state and "
                    "breaker/peer health per replicated shard.",
-    "perf": "Last benchkeeper perf-gate verdict: per-entry values, "
-            "deltas vs the reasoned baseline, regressions/stale/"
-            "missing counts.",
     "slo": "SLO engine state: per-objective availability/latency "
            "windows, good/bad counts, multi-window burn rates, and "
            "which objectives are currently burning.",
@@ -111,7 +108,7 @@ DEBUG_ENDPOINTS = {
                "persisted capture).",
     "drift": "Driftwatch verdict plane: open findings with the gate "
              "verdict, per-entry trend deltas from the last live "
-             "telemetry classification against benchkeeper bands, and "
+             "telemetry classification against its baseline bands, and "
              "per-canary state (probe set, sealed references, recall/"
              "residency history through the real query batcher).",
 }
@@ -1052,13 +1049,6 @@ class RestServer:
             return 200, self._debug_storage()
         if name == "replication":
             return 200, self._debug_replication()
-        if name == "perf":
-            # last benchkeeper gate verdict + per-section trend deltas
-            # (tools/benchkeeper persists the artifact; perfgate loads
-            # it and republishes the weaviate_tpu_bench_* gauges)
-            from weaviate_tpu.runtime import perfgate
-
-            return 200, perfgate.snapshot()
         if name == "slo":
             # objectives + sliding-window burn rates (refreshes the
             # weaviate_tpu_slo_burn_rate gauges + incident sweep)

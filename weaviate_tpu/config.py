@@ -59,8 +59,8 @@ class ServerConfig:
     # persistence (PERSISTENCE_DATA_PATH, environment.go)
     data_path: str = "./data"
     # PERSISTENCE_WAL_SYNC: fsync every WAL append before acking the
-    # write (durability over throughput — see bench.py durability_tax
-    # for the cost). Off = the OS page cache decides when acked writes
+    # write (durability over throughput; the cost is not measured on
+    # the chip's host). Off = the OS page cache decides when acked writes
     # hit disk, so a POWER failure (not a process crash) can lose the
     # tail. The raft bucket is pinned sync regardless (cluster/node.py).
     wal_sync: bool = False
@@ -97,7 +97,7 @@ class ServerConfig:
     # persisted under <data_dir>/kernelscope (PROFILING_KEEP)
     profile_keep: int = 8
     # driftwatch: online recall/perf drift plane (canary probes + live
-    # telemetry vs benchkeeper bands) on a cyclemanager period
+    # telemetry vs baseline bands) on a cyclemanager period
     driftwatch_enabled: bool = True
     drift_interval_s: float = 30.0
     log_level: str = "info"

@@ -87,7 +87,7 @@ class Database:
         self.cycles.register("epoch-maintenance", self._epoch_cycle,
                              maintenance_interval)
         # driftwatch (ROADMAP item 1c): canary probes through the real
-        # batcher + live-telemetry classification against benchkeeper
+        # batcher + live-telemetry classification against baseline
         # bands, on its own (longer) period. A tick defers a canary whose
         # corpus still moves; run_now("driftwatch"), the deterministic
         # test entry, seals whatever differs

@@ -563,8 +563,9 @@ class DeviceVectorStore:
                         owner=self._hbm_owner)
                 elif allow_mask is not None:
                     allowed = np.flatnonzero(allow_mask)
-                    # selectivity policy (measured,
-                    # tools/bench_filtered.py, hoist-proof harness):
+                    # selectivity policy (numbers from a pre-chip rig,
+                    # not measured on the v5e; the filtered cell in
+                    # benchmarks/ serves the batched-mask branch above):
                     # masked full scan is selectivity-
                     # independent (~11.1 ms at 1M×128 B=256); gather is
                     # ~1.4 ms + linear (5.2 ms at 10%, 23 ms at 50%) —
@@ -732,8 +733,7 @@ class DeviceVectorStore:
 
     def _search_gathered(self, queries: np.ndarray, k: int,
                          allowed: np.ndarray, squeeze: bool):
-        """Dispatch + finish in one call (tools/bench_filtered.py drives
-        the gathered path directly through this)."""
+        """Dispatch + finish in one call."""
         with self._lock:
             d, i, slot_buf = self._dispatch_gathered(queries, k, allowed)
         d_np, i_np = self._finish_gathered(np.asarray(d), np.asarray(i),

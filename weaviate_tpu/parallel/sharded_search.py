@@ -186,9 +186,8 @@ def topology_dcn_candidate_bytes(n_hosts: int, n_local: int, k: int,
                                  compact: bool = False) -> int:
     """Pure topology math: per-query candidate bytes ONE host sends
     across DCN during the merge, for an ``n_hosts x n_local`` pod.
-    Rig-independent — the benchkeeper ``dcn_bytes_ratio`` gate computes
-    this for the reference 2x4 topology no matter what hardware the
-    bench runs on. ``kk`` is the per-device candidate count (defaults
+    Rig-independent: the same number for a topology whatever hardware
+    it is computed on. ``kk`` is the per-device candidate count (defaults
     to k); ``compact`` counts the bf16+uint32 wire format (6 B/pair vs
     8)."""
     kk = k if kk is None else kk

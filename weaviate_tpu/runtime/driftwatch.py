@@ -2,8 +2,8 @@
 
 The three landed observability planes attribute what happened — tracing
 (per-request spans), tailboard (phase timelines + SLOs), kernelscope
-(device-time truth). Nothing *watches for change*: perf gating lives in
-the offline benchkeeper loop and recall is never measured in
+(device-time truth). Nothing *watches for change*: perf is measured
+offline by the benchmark and recall is never measured in
 production, so an IVF drift-retrain, epoch compaction, quantization
 upgrade or kernel regression can degrade answers with zero signal
 (ROADMAP item 1c: the r05 flat b=64 121k->40k QPS collapse had no
@@ -30,18 +30,18 @@ Leg 2 — live telemetry drift. Kernelscope's per-(kind, B-bucket,
 k-bucket) residency EWMAs, the memcpy EWMA, batcher overlap counters
 and compile-cache events are folded into a synthetic bench-shaped run
 (``{"sections": {"live": ...}}``) and compared against a
-fingerprint-scoped benchkeeper baseline with
-``tools.benchkeeper.core.compare`` — the SAME band math, verdict
-statuses (pass/regression/stale/missing) and cross-fingerprint REFUSAL
-as the CLI. The baseline is either explicit
+fingerprint-scoped baseline file with ``runtime/bands.compare`` — the
+SAME band math, verdict statuses (pass/regression/stale/missing) and
+cross-fingerprint REFUSAL as the offline replay
+(``python -m tools.driftwatch``). The baseline is either explicit
 (``WEAVIATE_TPU_DRIFT_BASELINE``) or self-sealed: once a variant has
 ``WEAVIATE_TPU_DRIFT_MIN_SAMPLES`` dispatches its EWMA level is sealed
 as the reference (persisted to ``<data_dir>/driftwatch/
 live_baseline.json`` so restarts keep comparing against the same
-bands). Divergence from the CLI gate, on purpose: only ``regression``
-findings flip health — a serving node legitimately has unexercised
-variants after a restart (``missing``) and an unexplained improvement
-(``stale``) is visible but not an incident.
+bands). Divergence from the replay's exit code, on purpose: only
+``regression`` findings flip health — a serving node legitimately has
+unexercised variants after a restart (``missing``) and an unexplained
+improvement (``stale``) is visible but not an incident.
 
 Leg 3 — verdict plane + forensics. ``GET /v1/debug/drift`` serves
 per-finding verdicts, trend deltas and canary history; gauges
@@ -84,6 +84,8 @@ import zlib
 from collections import deque
 
 import numpy as np
+
+from weaviate_tpu.runtime import bands
 
 logger = logging.getLogger(__name__)
 
@@ -502,7 +504,7 @@ def _run_canary(c: _Canary, now: float,
                        f"(band {_recall_band():.3f}) — answers degraded "
                        "on the live serving path"),
         })
-    # same normalized-delta band math as benchkeeper (direction
+    # same normalized-delta band math as bands.compare (direction
     # "lower": positive delta = regressing)
     if c.ref_device_ms > 1e-6:
         delta = (device_ms - c.ref_device_ms) / c.ref_device_ms
@@ -525,7 +527,7 @@ def _run_canary(c: _Canary, now: float,
     return rec, findings
 
 
-# -- leg 2: live telemetry vs benchkeeper bands -------------------------------
+# -- leg 2: live telemetry vs baseline bands ----------------------------------
 
 _live_baseline: dict | None = None
 _live_baseline_source: str | None = None
@@ -536,7 +538,7 @@ _last_verdict: dict | None = None
 
 def live_fingerprint() -> dict:
     """The environment this node's live telemetry was measured in —
-    the same keys benchkeeper baselines name, so an explicit TPU-rig
+    the same keys a baseline's fingerprint names, so an explicit TPU-rig
     baseline REFUSES comparison on a CPU node instead of gating noise."""
     try:
         import jax
@@ -559,8 +561,8 @@ def live_section() -> dict:
     """The synthetic bench section driftwatch classifies: kernelscope's
     per-variant residency EWMAs, the memcpy estimator, and per-cycle
     counter deltas (compile-cache misses, batcher overlap). Counter
-    deltas are exported ``_p1`` (value + 1): benchkeeper refuses a
-    zero reference value, and the quiet steady state IS zero."""
+    deltas are exported ``_p1`` (value + 1): a baseline entry may not
+    hold a zero reference value, and the quiet steady state IS zero."""
     from weaviate_tpu.runtime import kernelscope
     from weaviate_tpu.runtime.metrics import (batcher_overlapped,
                                               compile_cache_events)
@@ -593,8 +595,8 @@ def live_section() -> dict:
 
 
 def seal_live_baseline(section: dict, fingerprint: dict) -> dict | None:
-    """Self-seal a benchkeeper-shaped baseline from the current live
-    telemetry: one ``kind: device`` entry per residency variant with
+    """Self-seal a baseline (``runtime/bands`` format) from the current
+    live telemetry: one ``kind: device`` entry per residency variant with
     enough samples, the memcpy level, and the compile-storm detector.
     Returns None when nothing is warm enough to seal yet."""
     entries = []
@@ -639,7 +641,7 @@ def seal_live_baseline(section: dict, fingerprint: dict) -> dict | None:
         "value": 1.0, "band": 2.0,
         "direction": "lower", "kind": "wall", "unit": "events",
         "reason": "compile-storm detector: steady state recompiles "
-                  "nothing per cycle (p1 metric = misses + 1, benchkeeper "
+                  "nothing per cycle (p1 metric = misses + 1, a baseline "
                   "refuses a zero reference). More than two persistent-"
                   "cache misses in one cycle means the bounded pow2 "
                   "variant set broke (shape leak) or the cache is gone — "
@@ -667,42 +669,39 @@ def _sealed_baseline_path() -> str | None:
 
 def _ensure_live_baseline(section: dict, fingerprint: dict):
     """Resolve the live-leg baseline: explicit env path > previously
-    sealed on-disk file > seal now from warm telemetry. Validation and
-    persistence both reuse benchkeeper's code."""
+    sealed on-disk file > seal now from warm telemetry."""
     global _live_baseline, _live_baseline_source, _live_baseline_error
     with _lock:
         if _live_baseline is not None:
             return _live_baseline
-    from tools.benchkeeper import core as bk
-
     env_path = os.environ.get("WEAVIATE_TPU_DRIFT_BASELINE", "")
     if env_path:
         try:
-            base = bk.load_baseline(env_path)
+            base = bands.load_baseline(env_path)
             src, err = f"env:{env_path}", None
-        except bk.BaselineError as e:
+        except bands.BaselineError as e:
             base, src, err = None, None, str(e)
     else:
         base, src, err = None, None, None
         path = _sealed_baseline_path()
         if path and os.path.exists(path):
             try:
-                base = bk.load_baseline(path)
+                base = bands.load_baseline(path)
                 src = f"sealed:{path}"
-            except bk.BaselineError as e:
+            except bands.BaselineError as e:
                 err = str(e)  # corrupt seal: reseal below
         if base is None:
             sealed = seal_live_baseline(section, fingerprint)
             if sealed is not None:
                 try:
-                    bk.validate_baseline(sealed, "<driftwatch-seal>")
-                except bk.BaselineError as e:
+                    bands.validate_baseline(sealed, "<driftwatch-seal>")
+                except bands.BaselineError as e:
                     sealed, err = None, str(e)
             if sealed is not None:
                 base, src, err = sealed, "sealed:memory", None
                 if path:
                     try:
-                        bk._atomic_write_json(path, sealed)
+                        bands._atomic_write_json(path, sealed)
                         src = f"sealed:{path}"
                     except OSError:
                         pass  # memory seal still classifies
@@ -715,16 +714,12 @@ def _ensure_live_baseline(section: dict, fingerprint: dict):
 
 def classify_live(section: dict, baseline: dict,
                   fingerprint: dict | None = None) -> dict:
-    """Classify one live-telemetry section against a benchkeeper
-    baseline — literally ``tools.benchkeeper.core.compare`` on a
-    synthetic one-section run, so verdict statuses and the
-    cross-fingerprint refusal are benchkeeper's own (the parity the
-    tests pin)."""
-    from tools.benchkeeper import core as bk
-
+    """Classify one live-telemetry section against a baseline:
+    ``bands.compare`` on a synthetic one-section run, the same call the
+    offline replay makes on a history record."""
     run = {"env_fingerprint": fingerprint or live_fingerprint(),
            "sections": {"live": section}}
-    return bk.compare(run, baseline)
+    return bands.compare(run, baseline)
 
 
 def _live_findings(verdict: dict) -> list[dict]:
@@ -892,13 +887,7 @@ def run_cycle(scheduled: bool = False, now: float | None = None,
     section = live_section()
     verdict_summary = None
     classified = False
-    try:
-        baseline = _ensure_live_baseline(section, fp)
-    except Exception as e:  # tools/ stripped from the install
-        baseline = None
-        with _lock:
-            global _live_baseline_error
-            _live_baseline_error = f"benchkeeper unavailable: {e}"
+    baseline = _ensure_live_baseline(section, fp)
     if baseline is not None:
         verdict = classify_live(section, baseline, fp)
         classified = True

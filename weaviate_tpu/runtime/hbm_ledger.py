@@ -269,8 +269,8 @@ class HBMLedger:
             self._host_count_hint = max(1, int(n_hosts))
 
     def refresh_host_gauge(self) -> dict:
-        """Scrape-time refresh of ``weaviate_tpu_hbm_host_bytes`` (the
-        perfgate.refresh pattern): the split depends on LIVE totals, so
+        """Scrape-time refresh of ``weaviate_tpu_hbm_host_bytes``:
+        the split depends on LIVE totals, so
         recomputing at exposition keeps the gauge summing exactly to
         the live device total instead of whatever the last REST read
         left behind."""

@@ -40,8 +40,7 @@ attributed chip time, on every request:
    already-wired ``jax.profiler`` programmatic trace, parses the
    perfetto/chrome events into per-kernel device-ms ranked by
    :data:`KERNEL_REGISTRY`, and persists the last K captures under the
-   data dir (``GET /v1/debug/profile?ms=N``; ``benchkeeper --explain``
-   attaches capture deltas to a regression verdict).
+   data dir (``GET /v1/debug/profile?ms=N``).
 """
 
 from __future__ import annotations

@@ -738,42 +738,17 @@ device_seconds_total = registry.counter(
     "device residency",
     ("collection", "tenant"))
 
-# -- perf gate (runtime/perfgate.py republishes these from the last
-#    persisted benchkeeper verdict; see tools/benchkeeper) --------------------
-
-bench_gate_ok = registry.gauge(
-    "weaviate_tpu_bench_gate_ok",
-    "1 when the last benchkeeper perf-gate verdict passed, 0 when it "
-    "failed (regression, stale baseline, or missing metric)")
-bench_gate_regressions = registry.gauge(
-    "weaviate_tpu_bench_gate_regressions",
-    "Out-of-band regressions in the last benchkeeper verdict")
-bench_gate_stale = registry.gauge(
-    "weaviate_tpu_bench_gate_stale_entries",
-    "Baseline entries flagged stale (unexplained improvement beyond "
-    "band) in the last benchkeeper verdict")
-bench_metric_value = registry.gauge(
-    "weaviate_tpu_bench_metric_value",
-    "Last benchkeeper-checked value per baseline entry; the unit label "
-    "carries the entry's unit (ms for device-attributed timings, qps, "
-    "...)", ("entry", "unit"))
-bench_delta_frac = registry.gauge(
-    "weaviate_tpu_bench_delta_frac",
-    "Fractional delta vs the baseline reference per entry, normalized "
-    "so positive = regressing direction (slower scan / lower qps)",
-    ("entry",))
-
 # -- driftwatch (runtime/driftwatch.py: online recall/perf drift plane) -------
 
 drift_gate_ok = registry.gauge(
     "weaviate_tpu_drift_gate_ok",
     "1 when no open driftwatch finding flips health (canary recall "
-    "holds, live telemetry inside its benchkeeper bands), 0 during a "
+    "holds, live telemetry inside its baseline bands), 0 during a "
     "drift incident")
 drift_findings_total = registry.counter(
     "weaviate_tpu_drift_findings_total",
     "Driftwatch findings opened, by leg (canary = serving-path probe "
-    "set, live = telemetry vs benchkeeper bands) and kind (recall, "
+    "set, live = telemetry vs baseline bands) and kind (recall, "
     "residency, regression, stale, refused)", ("leg", "kind"))
 canary_recall = registry.gauge(
     "weaviate_tpu_canary_recall",
@@ -833,17 +808,10 @@ OPENMETRICS_CONTENT_TYPE = \
 
 def scrape(openmetrics: bool = False) -> tuple[bytes, str]:
     """One metrics scrape, shared by the REST /v1/metrics route and the
-    monitoring port: run the read-point refreshes (benchkeeper verdict
-    pickup, per-host HBM attribution, tailboard fold + SLO burn
-    gauges), then render the negotiated exposition. Returns
+    monitoring port: run the read-point refreshes (per-host HBM
+    attribution, tailboard fold + SLO burn gauges), then render the negotiated exposition. Returns
     ``(body, content_type)``; every refresh is best-effort — a broken
     helper must never fail a scrape."""
-    try:
-        from weaviate_tpu.runtime import perfgate
-
-        perfgate.refresh()
-    except Exception:
-        pass
     try:
         from weaviate_tpu.runtime.hbm_ledger import ledger
 

@@ -194,7 +194,7 @@ class QueryBatcher:
         self._worker: threading.Thread | None = None
         self._stopped = False
         self._queue_depth_at_drain = 0
-        # observability (tools/bench_e2e asserts coalescing happens;
+        # observability (tests/test_concurrency.py asserts coalescing;
         # tests/test_query_batcher.py asserts the pipeline overlaps)
         self.dispatches = 0
         self.batched_queries = 0

@@ -1072,8 +1072,7 @@ class SloEngine:
 
     def refresh(self, now: float | None = None) -> None:
         """Republish the burn-rate gauges + run the incident sweep —
-        called at scrape time and from /v1/debug/slo, like
-        perfgate.refresh."""
+        called at scrape time and from /v1/debug/slo."""
         now = _mono() if now is None else now
         bucket = int(now // _BUCKET_S)
         try:
