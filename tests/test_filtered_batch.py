@@ -323,6 +323,190 @@ def test_batcher_selective_filter_goes_solo(rng):
     assert (got >= 0).sum() == len(tiny)
 
 
+# -- allow-list intake: doc-id space -> slot mask (FlatIndex._allow_mask) ----
+
+
+def _translations():
+    """(mask, ids) children of weaviate_tpu_allow_translate_total; the
+    registry lives as long as the process, so tests read deltas."""
+    from weaviate_tpu.runtime.metrics import allow_translate_total
+
+    return (allow_translate_total.labels("mask"),
+            allow_translate_total.labels("ids"))
+
+
+def slot_mask_by_definition(idx, allow):
+    """What a bool mask over doc ids has always meant as a slot mask:
+    list its ids, sort them, binary-search every slot's doc id in them.
+    The gather through the slot table is held to this, bit for bit."""
+    from weaviate_tpu import native
+
+    table = idx._slot_to_id[: idx.store.capacity]
+    return native.membership(table, np.unique(np.nonzero(allow)[0]))
+
+
+def _intake_index(rng, layout, n=300, d=8):
+    """A small index in one of the states the slot table can be in, and
+    the size of the doc-id space a mask over it would have."""
+    from weaviate_tpu.engine.flat import FlatIndex
+
+    corpus = rng.standard_normal((n, d)).astype(np.float32)
+    idx = FlatIndex(dim=d, capacity=512, selection="exact")
+    space = n
+    if layout == "shuffled":
+        # slots not in doc-id order: the import arrives out of order, a
+        # third of it is imported again (set_at: same slots, new rows)
+        # and some ids leave and come back in fresh slots
+        order = rng.permutation(n)
+        idx.add_batch(order, corpus[order])
+        again = order[: n // 3]
+        corpus[again] += 1.0
+        idx.add_batch(again, corpus[again])
+        back = order[-20:]
+        idx.delete(*back.tolist())
+        idx.add_batch(back, corpus[back])
+        assert not np.all(np.diff(idx._slot_to_id[:n]) > 0)
+    else:
+        idx.add_batch(np.arange(n), corpus)
+    if layout in ("deleted", "compacted"):
+        idx.delete(*rng.choice(n, 70, replace=False).tolist())
+    if layout == "compacted":
+        idx.compact()
+        assert len(idx) == n - 70
+    if layout == "short_mask":
+        # rows added after the mask was built: their doc ids lie past it
+        space = n - 40
+    if layout == "long_mask":
+        # the shard's doc-id counter runs ahead of what this index holds
+        space = n + 333
+    return idx, corpus, space
+
+
+@pytest.mark.parametrize("pct", [0, 1, 10, 50, 99, 100])
+@pytest.mark.parametrize("layout", ["in_order", "deleted", "short_mask",
+                                    "long_mask", "compacted", "shuffled"])
+def test_mask_form_equals_definition(rng, layout, pct):
+    """A bool mask becomes a slot mask by one gather; the result is the
+    old nonzero -> unique -> membership form bit for bit, and the same
+    set given as doc ids (which still goes through native.membership)
+    finds the same neighbours."""
+    idx, _corpus, space = _intake_index(rng, layout)
+    allow = rng.random(space) < pct / 100.0   # 0: none, 100: every id
+    want = slot_mask_by_definition(idx, allow)
+    as_mask, as_ids = _translations()
+    m0, i0 = as_mask.value, as_ids.value
+    got = idx._allow_mask(allow)
+    assert (as_mask.value, as_ids.value) == (m0 + 1, i0)
+    assert got.dtype == np.bool_ and got.shape == want.shape
+    assert len(got) == len(idx._slot_to_id[: idx.store.capacity])
+    assert np.array_equal(got, want)
+    # a dead slot reads False whatever the mask says
+    assert not got[idx._slot_to_id[: len(got)] < 0].any()
+
+    ids = np.flatnonzero(allow).astype(np.int64)
+    from_ids = idx._allow_mask(ids)
+    assert (as_mask.value, as_ids.value) == (m0 + 1, i0 + 1)
+    assert np.array_equal(from_ids, want)
+
+    q = rng.standard_normal((3, idx.dim)).astype(np.float32)
+    by_mask = idx.search_by_vector_batch(q, 5, [allow] * 3)
+    by_ids = idx.search_by_vector_batch(q, 5, [ids] * 3)
+    assert np.array_equal(by_mask[0], by_ids[0])
+    assert np.array_equal(by_mask[1], by_ids[1])
+    live = by_mask[0][by_mask[0] >= 0]
+    assert np.isin(live, ids).all()
+    one_m = idx.search_by_vector(q[0], 5, allow)
+    one_i = idx.search_by_vector(q[0], 5, ids)
+    assert np.array_equal(one_m[0], one_i[0])
+
+
+@pytest.mark.parametrize("quant", [None, "bq", "epoch"])
+def test_mask_form_on_every_flat_store(rng, quant):
+    """Plain, compressed and epoch-stacked stores share the intake: the
+    gather reads the index's slot table, never the store."""
+    from weaviate_tpu.engine.flat import FlatIndex
+
+    n, d = 200, 32
+    corpus = rng.standard_normal((n, d)).astype(np.float32)
+    kw = {"epoch": {"epoch_rows": 64}, "bq": {"quantization": "bq"},
+          None: {}}[quant]
+    idx = FlatIndex(dim=d, capacity=256, **kw)
+    order = rng.permutation(n)
+    idx.add_batch(order, corpus[order])
+    idx.delete(*order[:25].tolist())
+    for space in (n - 30, n, n + 100):
+        allow = rng.random(space) < 0.4
+        assert np.array_equal(idx._allow_mask(allow),
+                              slot_mask_by_definition(idx, allow)), space
+
+
+def test_empty_mask_allows_nothing(rng):
+    """A mask over an empty doc-id space (a filter built before the
+    first import) has nothing to gather from: every slot reads False."""
+    idx, _, _ = _intake_index(rng, "in_order", n=50)
+    empty = np.zeros(0, dtype=bool)
+    got = idx._allow_mask(empty)
+    assert got.dtype == np.bool_ and not got.any()
+    assert np.array_equal(got, slot_mask_by_definition(idx, empty))
+    assert idx._allow_mask(None) is None
+
+
+@pytest.mark.parametrize("form", ["mask", "ids", "ids+mask"])
+def test_search_batch_span_names_the_form(rng, form):
+    from weaviate_tpu.runtime import tracing
+
+    idx, _, space = _intake_index(rng, "in_order", n=100)
+    allow = rng.random(space) < 0.5
+    given = {"mask": [allow, None], "ids": [np.flatnonzero(allow), None],
+             "ids+mask": [allow, np.flatnonzero(allow)]}[form]
+    q = rng.standard_normal((2, idx.dim)).astype(np.float32)
+    with tracing.trace("test.form", force=True):
+        idx.search_by_vector_batch(q, 3, given)
+        idx.search_by_vector_batch(q, 3)
+    spans = [s for s in tracing.recent_traces(1)[0]["spans"]
+             if s["name"] == "flat.search_batch"]
+    assert [s["attrs"].get("form") for s in spans] == [form, None]
+
+
+def test_coalesced_dispatch_counts_one_translation_a_request(rng):
+    """A coalesced filtered dispatch of B requests translates B masks on
+    the worker, padded rows none; an unfiltered dispatch reaches the
+    intake's first line and no further."""
+    from weaviate_tpu.engine.flat import FlatIndex
+    from weaviate_tpu.runtime.query_batcher import _Pending
+
+    n, d, k = 300, 16, 4
+    idx = FlatIndex(dim=d, capacity=512, selection="fused")
+    idx.add_batch(np.arange(n),
+                  rng.standard_normal((n, d)).astype(np.float32))
+    qb, calls = _make_batcher(idx)
+    as_mask, as_ids = _translations()
+
+    def pending(allow):
+        return _Pending(rng.standard_normal(d).astype(np.float32), k,
+                        allow)
+
+    try:
+        m0, i0 = as_mask.value, as_ids.value
+        plain = [pending(None) for _ in range(5)]
+        qb._dispatch(plain)
+        assert all(p.error is None for p in plain)
+        assert (as_mask.value, as_ids.value) == (m0, i0)
+
+        b = 5                                   # padded to 8 rows
+        masked = [pending(rng.random(n) < 0.5) for _ in range(b)]
+        qb._dispatch(masked)
+        assert all(p.error is None for p in masked)
+        assert [c["rows"] for c in calls] == [8, 8]
+        assert calls[1]["per_query"]
+        assert (as_mask.value, as_ids.value) == (m0 + b, i0)
+        for p in masked:
+            got = np.asarray(p.ids)
+            assert p.allow[got[got >= 0]].all()
+    finally:
+        qb.stop()
+
+
 def test_mask_block_constant():
     # every masked kernel unpacks whole 512-column blocks; the packers
     # and kernels must agree on the constant

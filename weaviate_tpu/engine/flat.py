@@ -24,6 +24,7 @@ import numpy as np
 from weaviate_tpu import native
 from weaviate_tpu.engine.store import DeviceVectorStore
 from weaviate_tpu.runtime import kernelscope, tracing
+from weaviate_tpu.runtime.metrics import allow_translate_total
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
 
 
@@ -35,6 +36,13 @@ def _per_query_allow(allow_list) -> bool:
     if not isinstance(allow_list, (list, tuple)) or len(allow_list) == 0:
         return False
     return any(a is None or np.ndim(a) > 0 for a in allow_list)
+
+
+def _allow_form(allow_list: np.ndarray) -> str:
+    """The form ``FlatIndex._allow_mask`` translates ``allow_list`` in:
+    the ``form`` of ``weaviate_tpu_allow_translate_total`` and of the
+    ``flat.search_batch`` span."""
+    return "mask" if allow_list.dtype == np.bool_ else "ids"
 
 
 @contextlib.contextmanager
@@ -221,10 +229,10 @@ class FlatIndex:
         per_query = _per_query_allow(allow_list)
         with tracing.span("flat.search_batch", k=k, queries=len(queries),
                           filtered=allow_list is not None,
-                          per_query_filters=per_query):
+                          per_query_filters=per_query) as sp:
             with self._lock:
                 kind, allow_mask = self._translate_batch_allow(
-                    queries, allow_list, per_query)
+                    queries, allow_list, per_query, sp)
                 kernelscope.explain_note(
                     "index", kind=str(self.index_type),
                     per_query_filters=bool(per_query),
@@ -253,18 +261,26 @@ class FlatIndex:
                                -1)
                 return ids, d
 
-    def _translate_batch_allow(self, queries, allow_list, per_query: bool):
+    def _translate_batch_allow(self, queries, allow_list, per_query: bool,
+                               sp):
         """Allow-list intake shared by the sync and async batch paths.
-        Caller holds ``_lock``. Returns ("mask", mask-or-None) for the
-        single-dispatch forms, or ("rowwise", per-row masks) when the
-        store cannot take a 2-D mask."""
-        if not per_query:
-            return "mask", self._allow_mask(allow_list)
-        if len(allow_list) != len(queries):
+        Caller holds ``_lock`` and passes its span, which takes the
+        ``form`` the lists were translated in ("mask", "ids", or
+        "ids+mask" for a batch that held both). Returns ("mask",
+        mask-or-None) for the single-dispatch forms, or ("rowwise",
+        per-row masks) when the store cannot take a 2-D mask."""
+        if per_query and len(allow_list) != len(queries):
             raise ValueError(
                 f"{len(allow_list)} allow lists != "
                 f"{len(queries)} queries")
-        masks = [self._allow_mask(a) for a in allow_list]
+        lists = [None if a is None else np.asarray(a)
+                 for a in (allow_list if per_query else [allow_list])]
+        forms = sorted({_allow_form(a) for a in lists if a is not None})
+        if forms:
+            sp.set(form="+".join(forms))
+        masks = [self._allow_mask(a) for a in lists]
+        if not per_query:
+            return "mask", masks[0]
         if all(m is None for m in masks):
             return "mask", None
         if not self.supports_batched_filters:
@@ -303,10 +319,11 @@ class FlatIndex:
         per_query = _per_query_allow(allow_list)
         with tracing.span("flat.search_batch", k=k, queries=len(queries),
                           filtered=allow_list is not None,
-                          per_query_filters=per_query, dispatch="async"):
+                          per_query_filters=per_query,
+                          dispatch="async") as sp:
             with self._lock:
                 kind, allow_mask = self._translate_batch_allow(
-                    queries, allow_list, per_query)
+                    queries, allow_list, per_query, sp)
                 if kind == "rowwise":
                     return None
                 # EXPLAIN: index-level plan facts (host ints only; the
@@ -372,10 +389,10 @@ class FlatIndex:
         fetch = max([k] + [int(op.fetch) for op in live_ops])
         f_depth = 1 << max(0, fetch - 1).bit_length()
         with tracing.span("flat.hybrid_batch", k=k, queries=len(queries),
-                          hybrid=len(live_ops), dispatch="async"):
+                          hybrid=len(live_ops), dispatch="async") as sp:
             with self._lock:
                 kind, allow_mask = self._translate_batch_allow(
-                    queries, allow_list, per_query)
+                    queries, allow_list, per_query, sp)
                 if kind == "rowwise":
                     return None
                 if allow_mask is not None and allow_mask.ndim == 1:
@@ -442,18 +459,39 @@ class FlatIndex:
     # -- helpers --------------------------------------------------------------
 
     def _allow_mask(self, allow_list):
+        """Allow list over doc ids -> bool mask over the store's slots
+        (None = unfiltered), ``len(_slot_to_id[:capacity])`` long. A
+        dead slot reads False, and so does a slot whose doc id the list
+        does not cover. The input's dtype picks the form, and both give
+        the same mask bit for bit:
+
+        - a BOOL MASK over the doc-id space (what ``Shard.allow_mask``
+          hands every served request) is looked up, one gather through
+          the slot table: 0.32 ms at 262,144 slots whatever the
+          selectivity, where listing its ids and searching them took
+          2.4 to 21 ms a request on the batcher's worker (PERF.md, PR
+          31: on the chip's host);
+        - an ARRAY OF DOC IDS (REST paths, tests) is sorted and
+          binary-searched per slot in the native library
+          (csrc/weaviate_native.cpp): a list of a few ids is not worth a
+          mask over the whole id space.
+        """
         if allow_list is None:
             return None
         allow_list = np.asarray(allow_list)
-        if allow_list.dtype == np.bool_:
-            allow_list = np.nonzero(allow_list)[0]
+        form = _allow_form(allow_list)
+        allow_translate_total.labels(form).inc()
         with self._lock:
-            # vectorized doc-id -> slot translation via the inverse table;
-            # a Python-loop of dict lookups here would dominate filtered
-            # queries with large allow lists. Binary-search membership runs
-            # in the native library (csrc/weaviate_native.cpp).
             table = self._slot_to_id[: self.store.capacity]
-            return native.membership(table, np.unique(allow_list))
+            if form == "ids":
+                return native.membership(table, np.unique(allow_list))
+            if not len(allow_list):
+                return np.zeros(len(table), dtype=bool)
+            # read as uint64 a dead slot's -1 is the largest id there
+            # is, so one compare rejects it together with the ids of rows
+            # added after the mask was built
+            covered = table.view(np.uint64) < np.uint64(len(allow_list))
+            return allow_list.take(table, mode="clip") & covered
 
     def _slot_to_id_safe(self, slots):
         clipped = np.clip(slots, 0, len(self._slot_to_id) - 1)

@@ -510,6 +510,12 @@ batcher_filtered_batched = registry.counter(
     "weaviate_tpu_query_batcher_filtered_batched_total",
     "Filtered requests served inside a coalesced bitmask-batched "
     "dispatch (instead of a solo device program)")
+allow_translate_total = registry.counter(
+    "weaviate_tpu_allow_translate_total",
+    "Allow lists translated from doc-id space to a store's slot mask "
+    "(engine/flat.py _allow_mask), by the form the input took: mask = a "
+    "bool mask over doc ids, one gather through the slot table; ids = an "
+    "array of doc ids, sorted and binary-searched", ("form",))
 batcher_compile_bucket = registry.counter(
     "weaviate_tpu_query_batcher_compile_bucket_total",
     "Coalesced dispatches by padded pow2 (batch, k) bucket — the bucket "
