@@ -7,6 +7,12 @@ import in batches that straddle the limit), judged by the benchmark's own
 reference (``benchmarks/reference.py``) at the limits of
 ``benchmarks/configs/deep-pq-cosine.json``; and the device scan against a
 plain numpy quantizer (``tests/pq_reference.py``) on a shared codebook.
+The lifecycle is the same for a class created with
+``vectorIndexConfig.sq.enabled`` (ISSUE 32: two scalars where pq fits a
+codebook), so the ``life`` fixture runs it for that quantizer too, at the
+limits of ``benchmarks/configs/gist-sq-l2.json`` and against
+``tests/sq_reference.py``; what only sq has is in
+``tests/test_sq_lifecycle.py``.
 CPU, small sizes: nothing here is a device time."""
 
 import copy
@@ -21,6 +27,7 @@ import numpy as np
 import pytest
 
 import pq_reference
+import sq_reference
 from weaviate_tpu.api.client import Client, RestError
 from weaviate_tpu.api.rest import (RestServer, class_to_wire,
                                    config_from_json)
@@ -37,6 +44,11 @@ import reference  # noqa: E402 — the benchmark's plain reference and judge
 with open(os.path.join(REPO, "benchmarks", "configs",
                        "deep-pq-cosine.json")) as _f:
     DEEP = json.load(_f)
+with open(os.path.join(REPO, "benchmarks", "configs",
+                       "gist-sq-l2.json")) as _f:
+    GIST = json.load(_f)
+# the configuration a quantizer's lifecycle is judged by
+CONFIG_OF = {"pq": DEEP, "sq": GIST}
 
 DIM, K = 32, DEEP["k"]
 LIMIT, ROWS, BATCH = 1024, 2400, 300    # batch 4 (rows 900..1199) crosses
@@ -69,13 +81,14 @@ def clustered(seed: int, rows: int, dim: int = DIM):
 
 
 def class_json(name: str, segments: int = DIM, limit: int = LIMIT,
-               index_type: str = "flat") -> dict:
+               index_type: str = "flat", quantizer: str = "pq") -> dict:
     """The configuration's own class, cut to the test's width and limit."""
-    klass = copy.deepcopy(DEEP["class"])
+    klass = copy.deepcopy(CONFIG_OF[quantizer]["class"])
     klass["class"] = name
     klass["vectorIndexType"] = index_type
-    klass["vectorIndexConfig"]["pq"].update(segments=segments,
-                                            trainingLimit=limit)
+    klass["vectorIndexConfig"][quantizer].update(trainingLimit=limit)
+    if quantizer == "pq":
+        klass["vectorIndexConfig"]["pq"].update(segments=segments)
     return klass
 
 
@@ -105,11 +118,11 @@ def replies_of(col, queries, which):
     return out
 
 
-def judged(col, queries, corpus_so_far):
+def judged(col, queries, corpus_so_far, cfg=DEEP):
     props = {"bucket": np.arange(len(corpus_so_far)) % 100}
     return reference.judge(
         replies_of(col, queries, range(len(queries))), queries,
-        corpus_so_far, props, DEEP["metric"], K, None, DEEP["limits"])
+        corpus_so_far, props, cfg["metric"], K, None, cfg["limits"])
 
 
 # -- the two keys: parse, validate, round-trip -------------------------------
@@ -177,26 +190,31 @@ def test_rest_refuses_a_tile_encoder_and_writes_the_keys_back(tmp_path):
 # -- the lifecycle through the normal path -----------------------------------
 
 
-@pytest.fixture(scope="module", params=[DIM, DIM // 4],
-                ids=["m=dim", "m=dim/4"])
+@pytest.fixture(scope="module",
+                params=[("pq", DIM), ("pq", DIM // 4), ("sq", DIM)],
+                ids=["m=dim", "m=dim/4", "sq"])
 def life(request, tmp_path_factory):
     """One import through REST class JSON + Collection batches, with what
-    the tests look at kept from each moment of it."""
-    segments = request.param
-    data_dir = str(tmp_path_factory.mktemp(f"pq{segments}"))
-    corpus, queries = clustered(28 + segments, ROWS)
+    the tests look at kept from each moment of it; once a quantizer."""
+    quantizer, segments = request.param
+    cfg = CONFIG_OF[quantizer]
+    data_dir = str(tmp_path_factory.mktemp(f"{quantizer}{segments}"))
+    corpus, queries = clustered(28 + segments + (quantizer == "sq"), ROWS)
     db = Database(data_dir)
     srv = RestServer(db)
     srv.start()
-    out = types.SimpleNamespace(segments=segments, corpus=corpus,
+    out = types.SimpleNamespace(quantizer=quantizer, cfg=cfg,
+                                segments=segments, corpus=corpus,
                                 queries=queries, data_dir=data_dir)
     try:
-        Client(srv.address).create_class(class_json("Deep", segments))
+        Client(srv.address).create_class(
+            class_json("Deep", segments, quantizer=quantizer))
+        out.wire = Client(srv.address).get_class("Deep")["vectorIndexConfig"]
         col = db.get_collection("Deep")
         for start in range(0, CROSSING.start, BATCH):
             put(col, corpus, range(start, start + BATCH))
         out.store_before = type(index_of(col).store)
-        out.before = judged(col, queries, corpus[:CROSSING.start])
+        out.before = judged(col, queries, corpus[:CROSSING.start], cfg)
         out.before_ids = replies_of(col, queries, range(16))["ids"]
         # searches from a second thread while the crossing batch trains,
         # encodes and swaps under the index's lock
@@ -232,10 +250,11 @@ def life(request, tmp_path_factory):
         out.later_trace = tracing.recent_traces(1)[0]
         for start in range(CROSSING.stop + BATCH, ROWS, BATCH):
             put(col, corpus, range(start, start + BATCH))
-        out.after = judged(col, queries, corpus)
+        out.after = judged(col, queries, corpus, cfg)
         out.after_replies = replies_of(col, queries, range(len(queries)))
         idx = index_of(col)
-        out.codebook = np.asarray(idx.store.codebook.centroids).copy()
+        # what the quantizer fitted: pq's centroids, sq's two scalars
+        out.codebook = quantizer_state(idx.store)
         out.codes = np.asarray(idx.store.codes).copy()
         out.slot_of = dict(idx._id_to_slot)
         out.unit_rows = idx.store._host_vectors.copy()
@@ -246,14 +265,20 @@ def life(request, tmp_path_factory):
     return out
 
 
+def quantizer_state(store) -> np.ndarray:
+    if store.quantization == "sq":
+        return np.asarray(store.sq_quantizer[:2], np.float32)
+    return np.asarray(store.codebook.centroids).copy()
+
+
 def test_before_the_limit_answers_are_exact(life):
     assert life.store_before is DeviceVectorStore
     assert life.before["correct"], life.before["numbers"]
     assert life.before["recall_at_k"] == 1.0
     # the same ids, in the same order, as the reference's own top k
     exact = reference.lower_precision(
-        life.queries, life.corpus[:CROSSING.start], {}, DEEP["metric"], K,
-        None, [(i, -1) for i in range(16)], "float32")
+        life.queries, life.corpus[:CROSSING.start], {}, life.cfg["metric"],
+        K, None, [(i, -1) for i in range(16)], "float32")
     assert np.array_equal(life.before_ids, exact["ids"])
 
 
@@ -261,6 +286,17 @@ def test_the_store_is_swapped_once_the_limit_is_crossed(life):
     store = life.store_after
     assert isinstance(store, QuantizedVectorStore) and store.trained
     assert store.rescore == "host"
+    assert store.quantization == life.quantizer
+    assert life.wire[life.quantizer]["enabled"] is True
+    assert life.wire[life.quantizer]["trainingLimit"] == LIMIT
+    if life.quantizer == "sq":
+        # one signed byte a dimension, and the int32 a row adds to a scan
+        assert life.codes.dtype == np.int8
+        assert life.codes.shape == (store.capacity, DIM)
+        assert store.row_terms.dtype == np.int32
+        assert store.row_terms.shape == (store.capacity,)
+        assert life.codebook.shape == (2,)
+        return
     assert life.codes.dtype == np.uint8
     assert life.codes.shape == (store.capacity, life.segments)
     assert life.codebook.shape == (life.segments, 256, DIM // life.segments)
@@ -268,7 +304,8 @@ def test_the_store_is_swapped_once_the_limit_is_crossed(life):
 
 def test_after_the_limit_answers_pass_the_configurations_limits(life):
     assert life.after["correct"], life.after["numbers"]
-    assert life.after["recall_at_k"] >= DEEP["limits"]["recall_at_k_min"]
+    assert life.after["recall_at_k"] >= \
+        life.cfg["limits"]["recall_at_k_min"]
 
 
 def test_every_object_of_the_crossing_batch_is_found_and_readable(life):
@@ -288,7 +325,8 @@ def test_searches_during_the_swap_all_return(life):
 
 def test_the_compression_is_traced_stage_by_stage(life):
     spans = {s["name"]: s for s in life.trace["spans"]}
-    assert spans["index.compress"]["attrs"]["quantization"] == "pq"
+    assert spans["index.compress"]["attrs"]["quantization"] == \
+        life.quantizer
     for child in ("train", "encode", "swap"):
         assert spans[child]["parent_id"] == \
             spans["index.compress"]["span_id"], child
@@ -297,7 +335,7 @@ def test_the_compression_is_traced_stage_by_stage(life):
     assert spans["encode"]["attrs"]["rows_encoded"] == CROSSING.stop
     # a later batch is encoded as it arrives, under the import's spans
     later = [s for s in life.later_trace["spans"]
-             if s["name"] == "store.pq_encode"]
+             if s["name"] == f"store.{life.quantizer}_encode"]
     assert [s["attrs"]["rows"] for s in later] == [BATCH]
 
 
@@ -305,9 +343,9 @@ def test_the_compression_is_on_the_metrics_page(life):
     page = metrics.registry.expose()
     for stage in ("train", "encode", "swap"):
         assert (f'weaviate_tpu_index_compress_seconds_count{{quantization='
-                f'"pq",stage="{stage}"}}') in page
-    assert ('weaviate_tpu_index_compress_total{quantization="pq",'
-            'result="ok"}') in page
+                f'"{life.quantizer}",stage="{stage}"}}') in page
+    assert (f'weaviate_tpu_index_compress_total{{quantization='
+            f'"{life.quantizer}",result="ok"}}') in page
 
 
 def test_a_restart_gives_the_same_codebook_and_the_same_answers(life):
@@ -317,8 +355,11 @@ def test_a_restart_gives_the_same_codebook_and_the_same_answers(life):
         col.near_vector(life.queries[0], k=K)   # loads the shard
         store = index_of(col).store
         assert isinstance(store, QuantizedVectorStore)
-        assert np.array_equal(np.asarray(store.codebook.centroids),
-                              life.codebook)
+        assert np.array_equal(quantizer_state(store), life.codebook)
+        if life.quantizer == "sq":
+            # the same two scalars give the same bytes, slot for slot
+            assert dict(index_of(col)._id_to_slot) == life.slot_of
+            assert np.array_equal(np.asarray(store.codes), life.codes)
         again = replies_of(col, life.queries, range(len(life.queries)))
         assert np.array_equal(again["ids"], life.after_replies["ids"])
         # rows that arrived compressed were normalised on the host, and
@@ -338,11 +379,17 @@ def test_the_whole_search_agrees_with_the_plain_quantizer(life):
         slot_row[slot] = doc    # doc ids are the import's row numbers
     valid = slot_row >= 0
     agree = 0
+    metric = life.cfg["metric"]
     for r, qi in enumerate(life.after_replies["query"]):
-        q = reference.prepare(life.queries[qi][None], DEEP["metric"])[0]
-        slots, _ = pq_reference.search(
-            life.codebook, life.codes, life.unit_rows, q, DEEP["metric"],
-            K, life.rescore_limit, valid)
+        q = reference.prepare(life.queries[qi][None], metric)[0]
+        if life.quantizer == "sq":
+            slots, _ = sq_reference.search(
+                *life.codebook, life.codes.view(np.uint8) ^ 0x80,
+                life.unit_rows, q, metric, K, life.rescore_limit, valid)
+        else:
+            slots, _ = pq_reference.search(
+                life.codebook, life.codes, life.unit_rows, q, metric,
+                K, life.rescore_limit, valid)
         agree += len(set(slot_row[slots].tolist())
                      & set(life.after_replies["ids"][r].tolist()))
     assert agree >= 0.99 * K * len(life.after_replies["query"])
@@ -574,7 +621,8 @@ def test_a_served_import_shows_the_compression_on_both_surfaces(
     try:
         rest, grpc = wire.Rest(server.rest.address), wire.Grpc(server.grpc.port)
         before = rest.metrics().total(
-            "weaviate_tpu_index_compress_seconds_count", {"stage": "swap"})
+            "weaviate_tpu_index_compress_seconds_count",
+            {"quantization": "pq", "stage": "swap"})
         rest.create_class(class_json("Deep"))
         grpc.import_rows("Deep", corpus,
                          {"bucket": np.arange(len(corpus)) % 100}, 512)

@@ -1,4 +1,4 @@
-"""PQ/BQ conformance and recall tests.
+"""PQ/BQ/SQ conformance and recall tests.
 
 Mirrors the reference's compression tests (compressionhelpers tests +
 hnsw/compress_recall_test.go): codebook quality, encode/decode roundtrip,
@@ -181,8 +181,10 @@ def test_pq_store_lifecycle(rng):
     assert i[0] == 3 and d[0] < 1e-3
 
 
-def test_untrained_pq_store_raises_on_search(rng):
-    store = QuantizedVectorStore(dim=16, quantization="pq", pq_centroids=8)
+@pytest.mark.parametrize("quantization", ["pq", "sq"])
+def test_untrained_pq_store_raises_on_search(rng, quantization):
+    store = QuantizedVectorStore(dim=16, quantization=quantization,
+                                 pq_centroids=8)
     # adds are allowed before training (vectors accumulate on host)...
     store.add(rng.standard_normal((40, 16)).astype(np.float32))
     # ...but searching without a codebook must fail loudly
@@ -194,7 +196,10 @@ def test_untrained_pq_store_raises_on_search(rng):
     assert i[0] == 7
 
 
-def test_flat_index_compress_runtime(rng):
+@pytest.mark.parametrize("quantization,kwargs", [
+    ("pq", dict(pq_segments=8, pq_centroids=64)), ("sq", {})],
+    ids=["pq", "sq"])
+def test_flat_index_compress_runtime(rng, quantization, kwargs):
     """Reference compress.go semantics: build uncompressed, compress at
     runtime, mapping and recall preserved."""
     x = clustered_data(rng, n=1200, dim=32)
@@ -203,8 +208,8 @@ def test_flat_index_compress_runtime(rng):
     idx.add_batch(ids, x)
     idx.delete(ids[7])
     assert not idx.compressed
-    idx.compress("pq", pq_segments=8, pq_centroids=64, rescore_limit=8)
-    assert idx.compressed
+    idx.compress(quantization, rescore_limit=8, **kwargs)
+    assert idx.compressed and idx.store.quantization == quantization
     got, d = idx.search_by_vector(x[100], k=5)
     assert got[0] == ids[100] and d[0] < 1e-3
     got, _ = idx.search_by_vector(x[7], k=5)
@@ -219,25 +224,30 @@ def test_flat_index_compress_runtime(rng):
     assert hits / 100 > 0.85, hits / 100
 
 
-def test_quantized_snapshot_restore(rng):
+@pytest.mark.parametrize("quantization", ["bq", "sq"])
+def test_quantized_snapshot_restore(rng, quantization):
     x = clustered_data(rng, n=600, dim=32)
-    idx = FlatIndex(dim=32, capacity=1024, chunk_size=1024, quantization="bq",
-                    rescore_limit=8)
+    idx = FlatIndex(dim=32, capacity=1024, chunk_size=1024,
+                    quantization=quantization, rescore_limit=8)
     idx.add_batch(np.arange(600), x)
+    idx.store.train()        # bq: nothing to fit; sq: the rows' range
     snap = idx.snapshot()
     idx2 = FlatIndex.restore(snap)
-    assert idx2.compressed
+    assert idx2.compressed and idx2.store.trained
+    assert np.array_equal(np.asarray(idx2.store.codes),
+                          np.asarray(idx.store.codes))
     got, d = idx2.search_by_vector(x[42], k=3)
     assert got[0] == 42 and d[0] < 1e-3
 
 
-def test_compress_twice_raises(rng):
+@pytest.mark.parametrize("quantization", ["bq", "sq"])
+def test_compress_twice_raises(rng, quantization):
     x = clustered_data(rng, n=300, dim=16)
     idx = FlatIndex(dim=16, capacity=512, chunk_size=512)
     idx.add_batch(np.arange(300), x)
-    idx.compress("bq")
+    idx.compress(quantization)
     with pytest.raises(RuntimeError):
-        idx.compress("bq")
+        idx.compress(quantization)
 
 
 def test_pq_twostage_prefix_matches_full_scan():

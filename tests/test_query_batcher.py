@@ -729,3 +729,187 @@ def test_gathered_solo_scan_and_full_scan_compile_under_different_names(
     idx.store.search(rng.standard_normal((1, 16)).astype(np.float32), 3,
                      allow_mask=allow)
     assert calls == [128]              # the 4 allowed rows' pow2 bucket
+
+
+# -- the wait for company (PR 32) --------------------------------------------
+
+
+class _Held:
+    """A batcher whose dispatches stay in the transfer window until they
+    are released, one event each; ``log`` holds (batch size, launch time)."""
+
+    def __init__(self, **attrs):
+        self.log = []
+        self.release = []
+
+        def async_fn(queries, k, allow):
+            b = len(queries)
+            ev = threading.Event()
+            self.release.append(ev)
+            self.log.append((b, time.perf_counter()))
+
+            def fin():
+                assert ev.wait(timeout=10.0)
+                return (np.zeros((b, k), np.int64),
+                        np.zeros((b, k), np.float32))
+
+            return DeviceResultHandle((), finish=fin)
+
+        self.qb = QueryBatcher(
+            lambda *a: (_ for _ in ()).throw(AssertionError("sync path")),
+            async_batch_fn=async_fn, pad_pow2=False)
+        for name, value in attrs.items():
+            setattr(self.qb, name, value)
+        self.threads = []
+        self.sent = []
+
+    def send(self):
+        self.sent.append(time.perf_counter())
+        t = threading.Thread(
+            target=lambda: self.qb.search(np.zeros(4, np.float32), 3))
+        t.start()
+        self.threads.append(t)
+
+    def wait_for(self, n_dispatches, timeout=5.0):
+        deadline = time.time() + timeout
+        while len(self.log) < n_dispatches and time.time() < deadline:
+            time.sleep(0.002)
+        assert len(self.log) >= n_dispatches, self.log
+
+    def close(self):
+        for ev in self.release:
+            ev.set()
+        deadline = time.time() + 5.0
+        while time.time() < deadline and any(
+                t.is_alive() for t in self.threads):
+            for ev in list(self.release):
+                ev.set()
+            time.sleep(0.005)
+        self.qb.stop()
+        for t in self.threads:
+            t.join(timeout=5.0)
+
+
+def _primed(peak, gap_s, **attrs):
+    """A held batcher with one request in the window, that remembers
+    ``peak`` requests held at once and dispatches that took 2 x gap_s."""
+    h = _Held(COALESCE_GAP_MAX_S=gap_s, **attrs)
+    h.send()
+    h.wait_for(1)
+    with h.qb._cv:
+        h.qb._peak = float(peak)
+    h.qb._flight_s = 2.0 * gap_s
+    return h
+
+
+def test_company_that_can_still_arrive_is_waited_for_and_no_longer():
+    """Four held at once of late, one in the window: three can still
+    come. The first two wait; the third completes the company and the
+    drain leaves at once, one dispatch of three."""
+    h = _primed(peak=4, gap_s=0.5)
+    try:
+        h.send()
+        time.sleep(0.1)
+        h.send()
+        time.sleep(0.1)
+        assert len(h.log) == 1, "left without the company it could expect"
+        h.send()
+        h.wait_for(2)
+        assert h.log[1][0] == 3
+        assert h.log[1][1] - h.sent[3] < 0.25, "waited past a full company"
+    finally:
+        h.close()
+    assert h.qb._in_window == 0
+
+
+def test_a_request_nobody_follows_leaves_after_one_gap():
+    h = _primed(peak=8, gap_s=0.15)
+    try:
+        h.send()
+        h.wait_for(2)
+        waited = h.log[1][1] - h.sent[1]
+        assert h.log[1][0] == 1 and 0.12 <= waited < 0.45, waited
+    finally:
+        h.close()
+
+
+def test_arrivals_that_never_stop_are_cut_off_after_four_gaps():
+    h = _primed(peak=64, gap_s=0.1)
+    try:
+        t_first = time.perf_counter()
+        while len(h.log) < 2 and time.perf_counter() - t_first < 2.0:
+            h.send()
+            time.sleep(0.05)
+        assert len(h.log) == 2
+        waited = h.log[1][1] - h.sent[1]
+        assert 0.35 <= waited < 0.7 and 5 <= h.log[1][0] <= 12, (
+            waited, h.log)
+    finally:
+        h.close()
+
+
+def test_enough_company_ends_the_wait():
+    h = _primed(peak=64, gap_s=0.5, COALESCE_MIN=4)
+    try:
+        for _ in range(4):
+            h.send()
+        h.wait_for(2)
+        assert h.log[1][0] == 4 and h.log[1][1] - h.sent[-1] < 0.25
+    finally:
+        h.close()
+
+
+@pytest.mark.parametrize("case", ["alone_after_a_crowd", "two_alternate",
+                                  "rule_off"])
+def test_who_finds_nobody_to_wait_for_leaves_at_once(case):
+    """A request that finds the batcher empty goes whatever the past
+    held; with two clients that take turns the other one is in the
+    window, so nobody can arrive; COALESCE_MIN = 1 is the old rule."""
+    if case == "alone_after_a_crowd":
+        h = _Held(COALESCE_GAP_MAX_S=0.5)
+        with h.qb._cv:
+            h.qb._peak = 32.0
+        h.qb._flight_s = 1.0
+        first = 0
+    else:
+        h = _primed(peak=2 if case == "two_alternate" else 32, gap_s=0.5,
+                    **({"COALESCE_MIN": 1} if case == "rule_off" else {}))
+        first = 1
+    try:
+        h.send()
+        h.wait_for(first + 1)
+        assert h.log[first][0] == 1
+        assert h.log[first][1] - h.sent[first] < 0.2
+    finally:
+        h.close()
+
+
+def test_the_window_count_comes_back_to_zero_after_a_fault_and_a_retry():
+    """``_in_window`` is what the expectation is reckoned from: a
+    dispatch that faults on the transfer thread and is served by the sync
+    retry has to leave the window like any other."""
+    calls = []
+
+    def async_fn(queries, k, allow):
+        b = len(queries)
+
+        def fin():
+            raise RuntimeError("device fault")
+
+        return DeviceResultHandle((), finish=fin)
+
+    def sync_fn(queries, k, allow):
+        calls.append(len(queries))
+        b = len(queries)
+        return (np.zeros((b, k), np.int64), np.zeros((b, k), np.float32))
+
+    qb = QueryBatcher(sync_fn, async_batch_fn=async_fn)
+    try:
+        ids, _ = qb.search(np.zeros(4, np.float32), 3)
+        assert ids.shape == (3,) and calls == [1]
+        deadline = time.time() + 5.0
+        while qb._in_window and time.time() < deadline:
+            time.sleep(0.005)
+        assert qb._in_window == 0
+    finally:
+        qb.stop()

@@ -75,9 +75,11 @@ def test_hnsw_compress_persistence(tmp_path, rng):
     assert ids[0] == 42 and dists[0] < 1e-4
 
 
-def test_config_update_compresses_live_class(tmp_path, rng):
-    """The reference lifecycle: PUT schema with pq.enabled on a LIVE class
-    (config_update.go) → index trains + swaps in place, recall gated."""
+@pytest.mark.parametrize("quantization", ["pq", "sq"])
+def test_config_update_compresses_live_class(tmp_path, rng, quantization):
+    """The reference lifecycle: PUT schema with pq.enabled (or sq.enabled)
+    on a LIVE class (config_update.go) → index trains + swaps in place,
+    recall gated."""
     db = Database(str(tmp_path))
     col = db.create_collection(CollectionConfig(
         name="Things", properties=[Property(name="t", data_type="text")],
@@ -93,14 +95,14 @@ def test_config_update_compresses_live_class(tmp_path, rng):
 
     import copy
     new_cfg = copy.deepcopy(col.config)
-    new_cfg.vectors[0].index.quantization = "pq"
+    new_cfg.vectors[0].index.quantization = quantization
     # the class holds 600 rows: a trainingLimit it has already passed
-    new_cfg.vectors[0].index.pq_training_limit = 512
+    setattr(new_cfg.vectors[0].index, f"{quantization}_training_limit", 512)
     db.update_collection(new_cfg)
 
     shard = list(col.shards.values())[0]
     idx = next(iter(shard.vector_indexes.values()))
-    assert idx.compressed
+    assert idx.compressed and idx.store.quantization == quantization
     res_after = col.near_vector(vecs[50], k=10)
     ids_after = {r.uuid for r in res_after}
     assert res_after[0].uuid == uuids[50]

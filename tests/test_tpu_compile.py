@@ -180,6 +180,66 @@ def test_pq_topk_at_96_segments(one_chip, monkeypatch, m, ds, b):
         assert elements < chunk * m, f"a gather over [{shape}] is left"
 
 
+SQ_D = 960                 # gist-sq-l2's width
+
+
+@pytest.mark.parametrize("rows", [1024, 65536],
+                         ids=["one-import-batch", "one-compress-batch"])
+def test_sq_encode_at_960_dims(one_chip, rows):
+    """What a write of the sq store runs (an import batch of 1,024; a
+    piece of the compression's encode stage): float32 rows in, encoded by
+    ``sq_encode`` and scattered, codes and terms, into the resident arrays,
+    which are donated: no second copy of the codes."""
+    from weaviate_tpu.engine.quantized import _scatter_sq_rows
+    from weaviate_tpu.ops import sq
+
+    cap = 262144
+    for metric in ("l2-squared", "cosine"):
+        c = _compile(functools.partial(sq.sq_encode, metric=metric), one_chip,
+                     ((rows, SQ_D), jnp.float32), ((3,), jnp.float32))
+        assert len(c.output_shardings) == 2 and "s8[" in c.as_text()
+        c = _compile(functools.partial(_scatter_sq_rows, metric=metric),
+                     one_chip, ((cap, SQ_D), jnp.int8), ((cap,), jnp.int32),
+                     ((cap,), jnp.bool_), ((rows,), jnp.int32),
+                     ((rows, SQ_D), jnp.float32), ((rows,), jnp.bool_),
+                     ((3,), jnp.float32))
+        # the v5e compiler scatters int8 rows through ONE relaid-out copy
+        # of the codes (1,024 lanes a row), as it does pq's uint8 codes:
+        # a write's cost, 0.3 ms of HBM traffic, never a scan's
+        assert c.memory_analysis().temp_size_in_bytes < 2 * cap * 1024
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "allow_bits"])
+@pytest.mark.parametrize("b", [1, 2, 4, 8, 16, 32])
+def test_sq_topk_at_960_dims(one_chip, masked, b):
+    """The served scan of ``gist-sq-l2.c32`` at every padded batch: the
+    chip's compiler takes the int8 x int8 -> int32 contraction (at b = 1
+    it turns the matrix-vector product into an int32 multiply-and-sum on
+    the vector unit: as exact), no contraction runs in floats, and neither
+    a [b, rows] score matrix nor a copy of the codes is left in HBM (a
+    [chunks, chunk, d] view of them cost 268 MB a dispatch: the chip lays
+    an int8 [rows, 960] out with the rows on the lanes)."""
+    from weaviate_tpu.ops.sq import sq_topk
+
+    rows, chunk = 262144, 8192
+
+    def fn(q, codes, terms, params, valid, bits=None):
+        return sq_topk(q, codes, terms, params, k=160, chunk_size=chunk,
+                       metric="l2-squared", valid=valid, allow_bits=bits)
+
+    shapes = [((b, SQ_D), jnp.float32), ((rows, SQ_D), jnp.int8),
+              ((rows,), jnp.int32), ((3,), jnp.float32),
+              ((rows,), jnp.bool_)]
+    if masked:
+        shapes.append(((b, rows // 32), jnp.uint32))
+    c = _compile(fn, one_chip, *shapes)
+    # the unpacked allow rows are the masked form's, as in pq_topk
+    assert c.memory_analysis().temp_size_in_bytes < (256 if masked else 16) << 20
+    text = c.as_text()
+    dots = re.findall(r"= (\w+)\[[\d,]*\][^ ]* (?:convolution|dot)\(", text)
+    assert set(dots) <= {"s32"} and (dots or b == 1), dots
+
+
 def test_bq_mxu_block(one_chip):
     fn = functools.partial(pk.bq_mxu_block, interpret=False)
     _assert_kernel(_compile(fn, one_chip, ((64, 24), jnp.uint32),

@@ -159,6 +159,10 @@ def property_from_json(d: dict) -> Property:
     )
 
 
+# the compression blocks of a vectorIndexConfig this tree can honour
+_COMPRESSIONS = ("pq", "bq", "sq")
+
+
 def _index_config_from_json(index_type: str | None, d: dict | None):
     """Map the reference's vectorIndexConfig JSON (entities/vectorindex/
     {hnsw,flat}/config.go) onto VectorIndexConfig; native snake_case keys
@@ -198,6 +202,27 @@ def _index_config_from_json(index_type: str | None, d: dict | None):
     if bq.get("enabled"):
         out.quantization = "bq"
         out.rescore_limit = bq.get("rescoreLimit", out.rescore_limit)
+    sq = d.get("sq") or {}
+    if sq.get("enabled"):
+        out.quantization = "sq"
+        out.sq_training_limit = sq.get("trainingLimit",
+                                       out.sq_training_limit)
+        out.rescore_limit = sq.get("rescoreLimit", out.rescore_limit)
+    # a compression the request enables is honoured or refused, never
+    # dropped: two at once, or a block this tree does not know
+    # (upstream's rq, or whatever comes next)
+    enabled = [k for k, v in d.items()
+               if isinstance(v, dict) and v.get("enabled")]
+    unknown = [k for k in enabled if k not in _COMPRESSIONS]
+    if unknown:
+        raise ValueError(
+            f"vectorIndexConfig.{unknown[0]} is enabled, and this server "
+            f"has no such compression (it has "
+            f"{', '.join(_COMPRESSIONS)})")
+    if len(enabled) > 1:
+        raise ValueError(
+            f"vectorIndexConfig enables {' and '.join(sorted(enabled))}: "
+            f"at most one compression a vector index")
     return out
 
 
@@ -233,6 +258,9 @@ def class_to_wire(cfg: CollectionConfig) -> dict:
                    "trainingLimit": ix.pq_training_limit,
                    "encoder": {"type": ix.pq_encoder}},
             "bq": {"enabled": ix.quantization == "bq",
+                   "rescoreLimit": ix.rescore_limit},
+            "sq": {"enabled": ix.quantization == "sq",
+                   "trainingLimit": ix.sq_training_limit,
                    "rescoreLimit": ix.rescore_limit},
         }
         if ix.index_type == "dynamic":

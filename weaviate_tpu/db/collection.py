@@ -95,6 +95,12 @@ class Collection:
                  sync_wal: bool | None = None,
                  node_hbm_provider=None):
         config.validate()
+        if mesh is not None and any(v.index.quantization == "sq"
+                                    for v in config.vectors):
+            # refused at creation, not at the swap: the sq store has no
+            # mesh-sharded scan (engine/quantized.py)
+            raise ValueError("sq is not supported on a mesh-sharded "
+                             "database")
         self.config = config
         self.data_dir = data_dir
         self.mesh = mesh

@@ -304,6 +304,8 @@ class Database:
                         vc.index.pq_centroids = vc_new.index.pq_centroids
                         vc.index.pq_training_limit = \
                             vc_new.index.pq_training_limit
+                        vc.index.sq_training_limit = \
+                            vc_new.index.sq_training_limit
                     vc.module_config = vc_new.module_config
 
             self.update_collection_config(new_cfg.name, apply)

@@ -52,9 +52,9 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
         capacity=8192,
         chunk_size=8192,
     )
-    # a pq class starts on full rows and compresses once, at
-    # pq_training_limit (Shard._maybe_compress); bq needs no training
-    # and is compressed from its first row
+    # a pq or sq class starts on full rows and compresses once, at its
+    # training limit (Shard._maybe_compress); bq needs no training and is
+    # compressed from its first row
     if cfg.index_type == "flat" and cfg.quantization == "bq":
         return FlatIndex(
             quantization="bq",
@@ -126,6 +126,10 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
             threshold=cfg.flat_to_ann_threshold, mesh=mesh,
             nlist=cfg.ivf_nlist, nprobe=cfg.ivf_nprobe,
             dtype=jnp.bfloat16 if cfg.storage_dtype == "bfloat16" else jnp.float32,
+            # an sq class stays flat, as a bq one does: exact until
+            # sq.trainingLimit, then the compressed scan (the IVF index
+            # it would upgrade into has no sq form)
+            upgradable=cfg.quantization != "sq",
             **common,
         )
     raise ValueError(f"unknown index type {cfg.index_type}")
@@ -435,7 +439,7 @@ class Shard:
                              pq_centroids=cfg.pq_centroids,
                              rescore_limit=cfg.rescore_limit,
                              prefix_bits=cfg.prefix_bits,
-                             training_limit=cfg.pq_training_limit)
+                             training_limit=cfg.training_limit)
             except Exception:  # noqa: BLE001 — the write itself stands
                 # past the gate nothing is left to wait for: a class
                 # that cannot compress is a fault. It keeps answering

@@ -31,6 +31,7 @@ class DynamicIndex:
                  threshold: int = 100_000, mesh=None, capacity: int = 8192,
                  chunk_size: int = 8192, nlist: int = 0, nprobe: int = 0,
                  upgrade_quantization: str | None = None,
+                 upgradable: bool = True,
                  **flat_kwargs):
         self.dim = dim
         self.metric = metric
@@ -43,6 +44,10 @@ class DynamicIndex:
         # precision (exact scan is the point), but the IVF index it
         # migrates into can start life residual-quantized
         self._upgrade_quantization = upgrade_quantization
+        # False: the flat regime is final (an sq class, whose compressed
+        # scan the IVF index has no form of: it stays flat at any size,
+        # as a bq class does once its store is quantized)
+        self._upgradable = upgradable
         self._lock = threading.RLock()
         # captured so the runtime flat->IVF upgrade (which runs on an
         # insert thread, outside any shard owner scope) keeps the new
@@ -64,7 +69,8 @@ class DynamicIndex:
         """Reference ShouldUpgrade (dynamic/index.go:348). Mesh-sharded and
         quantized flat stay flat: the SPMD exact scan already scales across
         devices, and the PQ/BQ-compressed scan is already the fast path."""
-        return (not self.upgraded and self.mesh is None
+        return (self._upgradable and not self.upgraded
+                and self.mesh is None
                 and not self._impl.compressed
                 and len(self._impl) >= self.threshold)
 
@@ -133,6 +139,7 @@ class DynamicIndex:
         snap["dynamic_threshold"] = self.threshold
         snap["dynamic_upgraded"] = self.upgraded
         snap["dynamic_upgrade_quantization"] = self._upgrade_quantization
+        snap["dynamic_upgradable"] = self._upgradable
         return snap
 
     @classmethod
@@ -146,6 +153,7 @@ class DynamicIndex:
         idx._nprobe = snap.get("nprobe", 0)
         idx._chunk_size = snap.get("chunk_size", 8192)
         idx._upgrade_quantization = snap.get("dynamic_upgrade_quantization")
+        idx._upgradable = snap.get("dynamic_upgradable", True)
         idx._lock = threading.RLock()
         from weaviate_tpu.runtime import hbm_ledger
 

@@ -168,6 +168,11 @@ class EpochStore:
                  quant_kwargs: dict | None = None):
         import jax.numpy as jnp
 
+        if quantization == "sq":
+            # one range would have to be shared by every epoch as the PQ
+            # codebook is (train below): not carried through yet
+            raise ValueError("quantization='sq' has no epoch-stacked form "
+                             "yet: epoch_rows must be 0")
         self.dim = dim
         self.metric = metric
         self.epoch_rows = int(epoch_rows) or DEFAULT_EPOCH_ROWS or (1 << 20)

@@ -510,9 +510,10 @@ class FlatIndex:
         the store (reference: hnsw/compress.go:38, enabled via a config
         update once enough data exists). Slot layout is preserved, so the
         id<->slot mapping carries over untouched. ``training_limit``: a
-        PQ codebook is fitted on the first that many live rows in slot
-        order (upstream's pq.trainingLimit), so one import gives one
-        codebook whatever the batch that crossed the limit held.
+        PQ codebook, or an SQ range, is fitted on the first that many
+        live rows in slot order (upstream's pq.trainingLimit and
+        sq.trainingLimit), so one import gives one quantizer whatever
+        the batch that crossed the limit held.
 
         Searches go on, exactly, from the old store while the codebook
         is fitted and the rows are encoded: ``_lock`` is held for the
@@ -531,10 +532,11 @@ class FlatIndex:
                 new = self._quantized_twin(old, quantization, quant_kwargs)
                 live = np.nonzero(snap["valid"])[0]
                 live_vecs = snap["vectors"][live]
-                if quantization == "pq" and new.codebook is None:
-                    if len(live) < new.pq_centroids:
+                if not new.trained:
+                    if len(live) < new.min_training_rows:
                         raise RuntimeError(
-                            f"need >= {new.pq_centroids} live vectors to train PQ, "
+                            f"need >= {new.min_training_rows} live vectors "
+                            f"to train {quantization.upper()}, "
                             f"have {len(live)}"
                         )
                     train_vecs = live_vecs[:training_limit]

@@ -1,19 +1,25 @@
-"""Quantized (PQ/BQ) vector store: compressed codes in HBM, exact rescore.
+"""Quantized (PQ/BQ/SQ) vector store: compressed codes in HBM, exact rescore.
 
 Reference parity:
 - flat BQ path with rescore: vector/flat/index.go:347 (searchByVectorBQ)
 - HNSW runtime compression hook: vector/hnsw/compress.go:38 (train on
   current contents, swap cache for a compressed one)
 - compressor plumbing: compressionhelpers/compression.go:37
+- scalar quantization: upstream compressionhelpers/scalar_quantization.go
+  (v1.26+; ops/sq.py)
 - compression composes with sharding because quantizer state is per-shard
   (compress.go:38 inside usecases/sharding/state.go:28) — here the same
   composition is one SPMD program over a device mesh
   (parallel/sharded_search.py:sharded_quantized_topk).
 
 Memory layout: HBM holds only the codes ([C, m] uint8 for PQ — 16-64x
-smaller than f32; [C, w] uint32 sign-bits for BQ — 32x smaller) plus the
-valid mask; on a mesh both are row-sharded over the ``shard`` axis. Three
-rescore modes pick where full-precision candidates come from:
+smaller than f32; [C, w] uint32 sign-bits for BQ — 32x smaller; [C, d]
+int8 for SQ, one byte a dimension, 4x smaller, with ``row_terms`` [C]
+int32 beside them: what each row adds to the integer score of any scan,
+written once with its code) plus the valid mask; on a mesh PQ and BQ are
+row-sharded over the ``shard`` axis (SQ has no SPMD form yet and refuses
+a mesh). Three rescore modes pick where full-precision candidates come
+from:
 
 - ``"host"``  (default): f32 rows in host RAM; the compressed scan returns
   an oversampled candidate set and the exact rescore is a tiny host gather
@@ -40,6 +46,7 @@ import numpy as np
 
 from weaviate_tpu.ops import bq as bq_ops
 from weaviate_tpu.ops import pq as pq_ops
+from weaviate_tpu.ops import sq as sq_ops
 from weaviate_tpu.ops.candidates import gather_rescore_topk
 from weaviate_tpu.ops.distances import normalize_np
 from weaviate_tpu.parallel.mesh import n_row_shards, shardable_capacity
@@ -64,6 +71,24 @@ def _scatter_codes(codes, valid, slots, new_codes, write_mask):
     codes = codes.at[tgt].set(new_codes, mode="drop")
     valid = valid.at[tgt].set(True, mode="drop")
     return codes, valid
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
+                   static_argnames=("metric",))
+def _scatter_sq_rows(codes, terms, valid, slots, rows, write_mask, params,
+                     metric: str):
+    """SQ's write: float32 rows are encoded where they land (codes and
+    the per-row int32 terms scattered in the same program), so nothing
+    comes back to the host and the writer waits for no device result."""
+    new_codes, new_terms = sq_ops.sq_encode(rows, params, metric)
+    tgt = jnp.where(write_mask, slots, codes.shape[0])
+    return (codes.at[tgt].set(new_codes, mode="drop"),
+            terms.at[tgt].set(new_terms, mode="drop"),
+            valid.at[tgt].set(True, mode="drop"))
+
+
+# rows a piece of an SQ write: bounds the float32 block that goes up
+_SQ_WRITE_ROWS = 65536
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -91,7 +116,8 @@ def _set_valid(codes, valid, slots, write_mask):
 
 
 class QuantizedVectorStore:
-    """PQ- or BQ-compressed store with the DeviceVectorStore method surface.
+    """PQ-, BQ- or SQ-compressed store with the DeviceVectorStore method
+    surface.
 
     On a mesh, codes (and bf16 rescore rows in ``rescore="device"`` mode)
     are row-sharded over the ``shard`` axis and every search runs SPMD.
@@ -132,8 +158,27 @@ class QuantizedVectorStore:
         # device bytes are individually visible and individually released
         component_suffix: str = "",
     ):
-        if quantization not in ("pq", "bq"):
+        if quantization not in ("pq", "bq", "sq"):
             raise ValueError(f"unknown quantization {quantization!r}")
+        if quantization == "sq":
+            # refused, never dropped: each of these would serve another
+            # store than the one asked for
+            if mesh is not None:
+                raise ValueError(
+                    "quantization='sq' has no mesh-sharded scan yet")
+            if prefix_bits:
+                raise ValueError("prefix_bits requires quantization pq or bq")
+            if selection == "fused":
+                raise ValueError(
+                    "selection='fused' needs the pq4 scan-reduce kernel "
+                    "(pq_centroids <= 16) or quantization='bq'")
+            if metric not in sq_ops.SQ_METRICS:
+                raise ValueError(
+                    f"no scalar-quantized scan for metric {metric!r}")
+            if dim > sq_ops.SQ_MAX_DIM:
+                raise ValueError(
+                    f"quantization='sq' sums in int32: dim {dim} is over "
+                    f"{sq_ops.SQ_MAX_DIM}")
         if rescore not in ("host", "device", "none"):
             raise ValueError(f"unknown rescore mode {rescore!r}")
         if selection not in ("approx", "fused"):
@@ -163,6 +208,8 @@ class QuantizedVectorStore:
             self.pq_segments = pq_ops.default_pq_segments(dim, pq_centroids)
         self.pq_centroids = pq_centroids
         self.codebook = codebook
+        # SQ's twin of the codebook: the fitted range (train, restore)
+        self.sq_quantizer: sq_ops.SQQuantizer | None = None
         self.normalize_on_add = (
             metric in ("cosine", "cosine-dot")
             if normalize_on_add is None
@@ -231,10 +278,17 @@ class QuantizedVectorStore:
     def _code_width(self) -> int:
         if self.quantization == "pq":
             return self.pq_segments
+        if self.quantization == "sq":
+            return self.dim
         return bq_ops.bq_words(self.dim)
 
     def _code_dtype(self):
-        return jnp.uint8 if self.quantization == "pq" else jnp.uint32
+        return {"pq": jnp.uint8, "sq": jnp.int8}.get(self.quantization,
+                                                     jnp.uint32)
+
+    def _scan_metric(self) -> str:
+        return ("cosine" if self.metric in ("cosine", "cosine-dot")
+                else self.metric)
 
     def _zeros(self, shape, dtype):
         if self.mesh is None:
@@ -246,6 +300,10 @@ class QuantizedVectorStore:
     def _alloc_codes(self):
         w = self._code_width()
         self.codes = self._zeros((self.capacity, w), self._code_dtype())
+        self.row_terms = (
+            jnp.zeros((self.capacity,), jnp.int32)
+            if self.quantization == "sq" else None
+        )
         self.prefix_t = (
             jnp.zeros((self.prefix_words, self.capacity), jnp.uint32)
             if self.prefix_words else None
@@ -262,7 +320,8 @@ class QuantizedVectorStore:
 
     def _hbm_sync(self):
         """Publish the device footprint per component: codes (+valid),
-        the transposed prefix, bf16 rescore rows, and the PQ codebook."""
+        SQ's per-row terms, the transposed prefix, bf16 rescore rows, and
+        the PQ codebook."""
         sharding = "sharded" if self.mesh is not None else "single"
 
         def _set(component, nbytes, dtype=None):
@@ -273,6 +332,9 @@ class QuantizedVectorStore:
 
         _set("codes", int(self.codes.nbytes) + int(self.valid.nbytes),
              dtype=jnp.dtype(self._code_dtype()).name)
+        _set("row_terms",
+             0 if self.row_terms is None else int(self.row_terms.nbytes),
+             dtype="int32")
         _set("prefix",
              0 if self.prefix_t is None else int(self.prefix_t.nbytes),
              dtype="uint32")
@@ -290,6 +352,9 @@ class QuantizedVectorStore:
                 raise RuntimeError("PQ store not trained; call train() first")
             with tracing.span("store.pq_encode", rows=len(vectors)):
                 return pq_ops.pq_encode(self.codebook, vectors)
+        if self.quantization == "sq":
+            # encoded on the device by the write itself (_write_codes)
+            return None
         (codes,) = tracing.d2h(bq_ops.bq_encode(jnp.asarray(vectors)))
         return codes
 
@@ -302,11 +367,19 @@ class QuantizedVectorStore:
 
     @property
     def trained(self) -> bool:
+        if self.quantization == "sq":
+            return self.sq_quantizer is not None
         return self.quantization == "bq" or self.codebook is not None
 
+    @property
+    def min_training_rows(self) -> int:
+        """Fewest rows ``train`` can fit this store's quantizer on."""
+        return self.pq_centroids if self.quantization == "pq" else 1
+
     def train(self, vectors: np.ndarray | None = None, iters: int = 8, seed: int = 0):
-        """Fit the PQ codebook (on given vectors or current live contents)
-        and (re-)encode everything stored so far."""
+        """Fit the quantizer (PQ: the codebook; SQ: the range) on given
+        vectors or current live contents, and (re-)encode everything
+        stored so far."""
         if self.quantization == "bq":
             return
         with self._lock:
@@ -314,10 +387,13 @@ class QuantizedVectorStore:
                 live = np.nonzero(self._valid_np)[0]
                 vectors = self._vectors_for(live)
             vectors = self._maybe_norm(np.asarray(vectors, dtype=np.float32))
-            self.codebook = pq_ops.pq_fit(
-                vectors, m=self.pq_segments, k=self.pq_centroids,
-                iters=iters, seed=seed,
-            )
+            if self.quantization == "sq":
+                self.sq_quantizer = sq_ops.sq_fit(vectors)
+            else:
+                self.codebook = pq_ops.pq_fit(
+                    vectors, m=self.pq_segments, k=self.pq_centroids,
+                    iters=iters, seed=seed,
+                )
             self._reencode_all()
             self._hbm_sync()
 
@@ -398,6 +474,14 @@ class QuantizedVectorStore:
         m = len(slots)
         if m == 0:
             return
+        # SQ encodes on the device, inside the write: rows in, no codes
+        sq_rows = (self.quantization == "sq" and codes is None
+                   and rows is not None and self.trained)
+        if sq_rows and m > _SQ_WRITE_ROWS:
+            for s in range(0, m, _SQ_WRITE_ROWS):
+                self._write_codes(slots[s:s + _SQ_WRITE_ROWS], None,
+                                  rows[s:s + _SQ_WRITE_ROWS])
+            return
         bucket = _next_pow2(max(m, 8))
         slot_buf = np.zeros(bucket, dtype=np.int32)
         slot_buf[:m] = slots
@@ -405,7 +489,17 @@ class QuantizedVectorStore:
         mask[:m] = True
         slot_dev = self._placed_replicated(slot_buf)
         mask_dev = self._placed_replicated(mask)
-        if codes is not None:
+        if sq_rows:
+            with tracing.span("store.sq_encode", rows=m):
+                rbuf = rows        # an import batch fills its bucket
+                if m < bucket:
+                    rbuf = np.zeros((bucket, self.dim), dtype=np.float32)
+                    rbuf[:m] = rows
+                self.codes, self.row_terms, self.valid = _scatter_sq_rows(
+                    self.codes, self.row_terms, self.valid, slot_dev,
+                    jnp.asarray(rbuf), mask_dev, self.sq_quantizer.params,
+                    metric=self._scan_metric())
+        elif codes is not None:
             w = self._code_width()
             cbuf = np.zeros((bucket, w), dtype=np.asarray(codes).dtype)
             cbuf[:m] = codes
@@ -457,6 +551,8 @@ class QuantizedVectorStore:
         self.valid = grow_rows(self.valid, pad, self.mesh)
         if self.rescore_rows is not None:
             self.rescore_rows = grow_rows(self.rescore_rows, pad, self.mesh)
+        if self.row_terms is not None:
+            self.row_terms = jnp.pad(self.row_terms, (0, pad))
         if self.prefix_t is not None:
             self.prefix_t = jnp.pad(self.prefix_t, ((0, 0), (0, pad)))
         self._hbm_sync()
@@ -505,7 +601,13 @@ class QuantizedVectorStore:
         feeds the SPMD path, which packs each shard's slice on device."""
         capacity = self.capacity
         cs = min(self.chunk_size, capacity // self.n_shards)
-        metric = "cosine" if self.metric in ("cosine", "cosine-dot") else self.metric
+        metric = self._scan_metric()
+        if self.quantization == "sq":
+            return sq_ops.sq_topk(
+                queries_dev, self.codes, self.row_terms,
+                self.sq_quantizer.params, k=k_cand, chunk_size=cs,
+                metric=metric, valid=valid, allow_bits=allow_bits,
+            )
         if self.quantization == "pq":
             quant_key = "pq4" if self.pq_centroids <= 16 else "pq"
             cent = self.codebook.centroids
@@ -601,7 +703,9 @@ class QuantizedVectorStore:
         allow_mask = normalize_allow_mask(allow_mask, len(queries))
         with self._lock:
             if not self.trained:
-                raise RuntimeError("PQ store not trained; call train() first")
+                raise RuntimeError(
+                    f"{self.quantization.upper()} store not trained; "
+                    f"call train() first")
             capacity = self.capacity
             valid = self.valid
             allow_bits = allow_rows_dev = None
@@ -677,7 +781,8 @@ class QuantizedVectorStore:
             with self._lock:
                 if not self.trained:
                     raise RuntimeError(
-                        "PQ store not trained; call train() first")
+                        f"{self.quantization.upper()} store not trained; "
+                        f"call train() first")
                 capacity = self.capacity
                 valid = self.valid
                 allow_bits = allow_rows_dev = None
@@ -720,12 +825,10 @@ class QuantizedVectorStore:
                     # plane — the full-precision tier is already in HBM,
                     # so the old host gather roundtrip buys nothing
                     sp.set(path="device_plane_rescore")
-                    metric = ("cosine"
-                              if self.metric in ("cosine", "cosine-dot")
-                              else self.metric)
                     d, i = gather_rescore_topk(
                         jnp.asarray(queries), i.astype(jnp.int32),
-                        self.rescore_rows, min(k, k_out), metric)
+                        self.rescore_rows, min(k, k_out),
+                        self._scan_metric())
                 # dispatch-time snapshot for the finish step's rescore:
                 # the scan's candidate slot-ids are only meaningful
                 # against THIS capacity/row layout — compact()/_grow()
@@ -774,7 +877,7 @@ class QuantizedVectorStore:
         # the tier pick (host rows -> device bf16 rows -> fetch_fn)
         cand = ((vectors_for or self._vectors_for)(
             safe.reshape(-1))).reshape(b, kc, self.dim)
-        metric = "cosine" if self.metric in ("cosine", "cosine-dot") else self.metric
+        metric = self._scan_metric()
         if metric == "dot":
             dd = -np.einsum("bd,bkd->bk", queries, cand)
         elif metric == "cosine":
@@ -843,6 +946,10 @@ class QuantizedVectorStore:
                     None if self.codebook is None
                     else np.asarray(self.codebook.centroids)
                 ),
+                "sq_quantizer": (
+                    None if self.sq_quantizer is None
+                    else np.asarray(self.sq_quantizer[:2], np.float32)
+                ),
             }
             if self._host_vectors is not None:
                 snap["vectors"] = self._host_vectors.copy()
@@ -877,6 +984,8 @@ class QuantizedVectorStore:
         )
         if snap.get("codebook") is not None:
             store.codebook = pq_ops.PQCodebook(jnp.asarray(snap["codebook"]))
+        if snap.get("sq_quantizer") is not None:
+            store.sq_quantizer = sq_ops.sq_quantizer(*snap["sq_quantizer"])
         live = np.nonzero(snap["valid"])[0]
         if len(live):
             if "vectors" in snap:
@@ -885,6 +994,10 @@ class QuantizedVectorStore:
                 # codes-only snapshot: restore codes directly
                 store._valid_np[live] = True
                 store._write_codes(live, snap["codes"][live], rows=None)
+                if store.row_terms is not None:
+                    # SQ's terms follow from the codes: summed again
+                    store.row_terms = sq_ops.sq_row_terms(
+                        store.codes, store._scan_metric())
                 if snap.get("prefix_t") is not None \
                         and store.prefix_t is not None:
                     pt = snap["prefix_t"]

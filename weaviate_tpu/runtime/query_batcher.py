@@ -12,6 +12,15 @@ worker drains the whole queue into ONE batched dispatch as soon as the
 device frees up. Under load the batch size self-tunes to the arrival
 rate, exactly like continuous batching in model serving.
 
+One wait is added to that (PR 32, ``_await_company``): where the batcher
+already holds two or more requests (queued, or in the transfer window)
+and has lately held more at once than it does now, the worker waits
+while requests keep arriving, for at most half a recent dispatch's
+launch-to-delivery time between two of them. A request that finds the
+batcher empty still leaves at once. Without it 32 closed-loop clients
+can settle into dispatches of one to four requests, each paying the
+whole per-dispatch cost on the host.
+
 Filtered requests coalesce too (ISSUE 3): when the index advertises
 ``supports_batched_filters`` the drain ships each request's allow list
 alongside its query row and the engine folds them into per-query packed
@@ -137,6 +146,15 @@ class QueryBatcher:
     like HNSW (padded rows run real graph searches), so those opt out.
     """
 
+    #: ``_await_company``: the queue length at which a drain stops waiting
+    #: for company; the wait for the next arrival as a share of a recent
+    #: dispatch's launch-to-delivery time, and its cap (s); what a
+    #: dispatch leaves of the remembered peak of requests held at once
+    COALESCE_MIN = 16
+    COALESCE_FLIGHT_SHARE = 0.5
+    COALESCE_GAP_MAX_S = 0.010
+    COALESCE_PEAK_DECAY = 0.98
+
     def __init__(self, batch_fn, max_batch: int = 256,
                  supports_filter_batching: bool = False,
                  capacity_fn=None, pad_pow2: bool = True,
@@ -194,6 +212,12 @@ class QueryBatcher:
         self._worker: threading.Thread | None = None
         self._stopped = False
         self._queue_depth_at_drain = 0
+        # what ``_await_company`` goes by: the most requests held at once
+        # of late (queued + in the transfer window; decays a dispatch),
+        # those in the window now, a running mean of launch -> delivered (s)
+        self._peak = 0.0
+        self._in_window = 0
+        self._flight_s = 0.0
         # observability (tests/test_concurrency.py asserts coalescing;
         # tests/test_query_batcher.py asserts the pipeline overlaps)
         self.dispatches = 0
@@ -262,6 +286,8 @@ class QueryBatcher:
                     f"({len(self._queue)}/{self.max_queue})",
                     retry_after_s=0.1)
             self._queue.append(item)
+            self._peak = max(self._peak,
+                             len(self._queue) + self._in_window)
             self._ensure_worker()
             self._cv.notify()
         rem = retry.remaining()
@@ -382,6 +408,8 @@ class QueryBatcher:
                 while not self._queue and not self._stopped:
                     self._cv.wait(timeout=1.0)
                 side.mark("assemble")
+            if tp is not None:
+                self._await_company(side)
             if self._stopped:
                 for it in self._queue:
                     it.error = RuntimeError("query batcher stopped")
@@ -390,10 +418,60 @@ class QueryBatcher:
                 return None
             drained = self._queue[: self.max_batch]
             del self._queue[: len(drained)]
+            self._peak *= self.COALESCE_PEAK_DECAY
             # queue depth AFTER the drain (what the next batch
             # inherits) — the flight recorder's congestion signal
             self._queue_depth_at_drain = len(self._queue)
         return drained
+
+    def _await_company(self, side) -> None:
+        """Caller holds ``_cv``. Every dispatch costs the interpreter the
+        same launch, fetch and delivery whether it carries one request or
+        sixteen, and under many closed-loop clients that cost is what
+        bounds the server: once replies leave one at a time, requests
+        come back one at a time, dispatches of one to four trickle
+        through at two thirds of the rate, and nothing brings the large
+        batches back (PERF.md section 5, bottleneck 6). So the worker
+        waits (its ``slot_wait`` stage) for the company it can expect:
+        ``_peak`` is how many requests this batcher has recently held at
+        once, queued and in the transfer window, so ``_peak`` less those
+        in the window now are the most that can still arrive, and
+        ``COALESCE_MIN`` is enough. It waits while requests keep
+        arriving: until that many are queued, until none has arrived for
+        ``gap`` (half of a recent dispatch's launch-to-delivery time, at
+        most ``COALESCE_GAP_MAX_S``: never long beside what the dispatch
+        itself will take), or until the oldest has waited four gaps. A
+        request that finds the batcher empty leaves at once whatever the
+        past held, and two clients that alternate find nobody to wait
+        for."""
+        if len(self._queue) + self._in_window < 2:
+            return
+        gap = min(self.COALESCE_FLIGHT_SHARE * self._flight_s,
+                  self.COALESCE_GAP_MAX_S)
+        waited = False
+        while self._queue and not self._stopped:
+            if len(self._queue) >= min(self.COALESCE_MIN,
+                                       int(self._peak) - self._in_window):
+                break
+            left = min(self._queue[-1].t_enqueue + gap,
+                       self._queue[0].t_enqueue + 4.0 * gap
+                       ) - time.perf_counter()
+            if left <= 0:
+                break
+            if not waited:
+                side.mark("slot_wait")
+                waited = True
+            self._cv.wait(timeout=left)
+        if waited:
+            side.mark("assemble")
+
+    def _landed(self, b: int) -> None:
+        """A dispatch of ``b`` requests has left the transfer window
+        (drain thread, or the worker where the submit failed): they may
+        come back now, so the worker's expectation changes."""
+        with self._cv:
+            self._in_window -= b
+            self._cv.notify_all()
 
     def _allowed_count(self, allow) -> int:
         """Selectivity of an allow list (bool mask over doc-id space or
@@ -737,6 +815,7 @@ class QueryBatcher:
         def _finish(res):
             try:
                 t1 = stamps["done"] = time.perf_counter()
+                self._flight_s += 0.125 * (t1 - t0 - self._flight_s)
                 self._deliver(coal, res[0], res[1], t1)
                 _hbm.release(pad_key)
                 _mark_served()
@@ -747,6 +826,10 @@ class QueryBatcher:
 
         def _complete(res, err, t_fetch0, t_fetch1):
             stamps["fetch0"], stamps["fetch1"] = t_fetch0, t_fetch1
+            if err is None or hybrid:
+                # served or failed here and now: from the worker's side
+                # these requests can come back from this moment on
+                self._landed(b)
             if err is not None and hybrid:
                 # the sync retry path can't re-run a hybrid program
                 # (no sparse-operand slot) — deliver the fault
@@ -770,26 +853,35 @@ class QueryBatcher:
             # in-flight batch's D2H behind one faulted batch.
 
             def _retry_path():
-                res2 = _retry_once(err)
-                if res2 is not None:
-                    # the retry served through the sync path: wall
-                    # attribution (the drain stamps belong to the
-                    # faulted attempt, not this result)
-                    stamps["done"] = time.perf_counter()
-                    _attribute("wall")
-                    _finish(res2)
+                try:
+                    res2 = _retry_once(err)
+                    if res2 is not None:
+                        # the retry served through the sync path: wall
+                        # attribution (the drain stamps belong to the
+                        # faulted attempt, not this result)
+                        stamps["done"] = time.perf_counter()
+                        _attribute("wall")
+                        _finish(res2)
+                finally:
+                    self._landed(b)
 
             threading.Thread(target=_retry_path, daemon=True,
                              name="batcher-fault-retry").start()
 
+        counted = False
         try:
             tp = self._ensure_transfer()
             if tp.inflight > 0:
                 self.overlapped_dispatches += 1
                 batcher_overlapped.inc()
+            with self._cv:
+                self._in_window += b
+            counted = True
             tp.submit(handle, _complete, ctx=ctx, rec=flight_rec)
         except Exception as e:  # noqa: BLE001 — stopped mid-shutdown
             _fail(e)
+            if counted:
+                self._landed(b)
 
     @staticmethod
     def _deliver(coal: list[_Pending], ids, dists, t1: float):

@@ -68,8 +68,9 @@ class VectorIndexConfig:
     index_type: str = "flat"  # flat | hnsw | dynamic | noop (reference set + ivf)
     metric: str = "l2-squared"
     storage_dtype: str = "float32"  # float32 | bfloat16
-    # quantization
-    quantization: str | None = None  # None | pq | bq
+    # quantization: product (ops/pq.py), binary (ops/bq.py) or scalar
+    # (ops/sq.py: one byte a dimension); at most one a vector space
+    quantization: str | None = None  # None | pq | bq | sq
     pq_segments: int | None = None
     # TPU-first default: 16 centroids = 4-bit codes whose ADC lookup is one
     # MXU matmul (ops/pallas_kernels.pq4_lut_block); 256 selects the
@@ -82,6 +83,9 @@ class VectorIndexConfig:
     pq_training_limit: int = 100_000
     # upstream's pq.encoder.type; "tile" has no form here
     pq_encoder: str = "kmeans"
+    # upstream's sq.trainingLimit: as pq_training_limit, for the two
+    # scalars an sq class fits (the range of its first rows)
+    sq_training_limit: int = 100_000
     rescore_limit: int = 16
     # two-stage scan: width (bits, 128/256) of the separately-stored
     # transposed sign prefix — the capacity-regime operating point
@@ -111,7 +115,7 @@ class VectorIndexConfig:
             raise ValueError(f"unknown vector index type {self.index_type!r}")
         if self.metric not in DISTANCE_METRICS:
             raise ValueError(f"unknown distance metric {self.metric!r}")
-        if self.quantization not in (None, "pq", "bq"):
+        if self.quantization not in (None, "pq", "bq", "sq"):
             raise ValueError(f"unknown quantization {self.quantization!r}")
         if self.pq_encoder != "kmeans":
             raise ValueError(
@@ -122,13 +126,21 @@ class VectorIndexConfig:
             raise ValueError(
                 f"pq trainingLimit must be an int >= centroids "
                 f"({self.pq_centroids}), got {self.pq_training_limit!r}")
+        if (not isinstance(self.sq_training_limit, int)
+                or isinstance(self.sq_training_limit, bool)
+                or self.sq_training_limit < 1):
+            raise ValueError(
+                f"sq trainingLimit must be an int >= 1, got "
+                f"{self.sq_training_limit!r}")
+        if self.quantization == "sq":
+            self._validate_sq()
         if self.prefix_bits is not None:
             if not isinstance(self.prefix_bits, int) \
                     or self.prefix_bits not in (128, 256):
                 raise ValueError(
                     f"prefix_bits must be 128 or 256, got "
                     f"{self.prefix_bits!r}")
-            if self.quantization is None:
+            if self.quantization not in ("pq", "bq"):
                 raise ValueError(
                     "prefix_bits requires quantization pq or bq")
         if self.epoch_rows:
@@ -142,14 +154,41 @@ class VectorIndexConfig:
                     "layouts have their own reorganize stories)")
 
 
+    def _validate_sq(self):
+        """Where sq cannot be honoured the class is refused, never built
+        as something else: the scalar-quantized store is the flat scan's
+        (engine/quantized.py), one device, one buffer."""
+        from weaviate_tpu.ops.sq import SQ_METRICS
+
+        if self.index_type in ("hnsw", "ivf"):
+            raise ValueError(
+                f"sq is not supported on vectorIndexType "
+                f"{self.index_type!r}: the scalar-quantized scan is the "
+                f"flat index's (ROADMAP D4)")
+        if self.epoch_rows:
+            raise ValueError("sq requires epoch_rows 0: the epoch-stacked "
+                             "store has no sq form")
+        if self.metric not in SQ_METRICS:
+            raise ValueError(
+                f"sq is not supported for distance {self.metric!r}")
+
+    @property
+    def training_limit(self) -> int | None:
+        """Rows the enabled quantizer waits for before it is fitted
+        (upstream's pq.trainingLimit / sq.trainingLimit); None where
+        nothing is fitted."""
+        return {"pq": self.pq_training_limit,
+                "sq": self.sq_training_limit}.get(self.quantization)
+
     def compress_due(self, rows: int) -> bool:
         """THE gate of runtime compression: whether an index of this
         config that still holds full rows, ``rows`` of them, compresses
-        now. bq needs no training; pq waits for ``pq_training_limit``
-        rows (upstream compress.go:38 behind pq.trainingLimit)."""
-        if self.quantization == "pq":
-            return rows >= self.pq_training_limit
-        return self.quantization is not None
+        now. bq needs no training; pq and sq wait for their
+        ``training_limit`` rows (upstream compress.go:38 behind
+        pq.trainingLimit)."""
+        limit = self.training_limit
+        return self.quantization is not None and (
+            limit is None or rows >= limit)
 
 
 @dataclass
