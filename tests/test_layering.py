@@ -39,7 +39,7 @@ ALLOWED = {
             "replication", "runtime", "schema", "storage"},
     "auth": set(),
     "backup": {"cluster", "db", "modules", "schema"},
-    "classification": {"filters", "ops", "storage", "text"},
+    "classification": {"ops", "storage", "text"},
     "cluster": {"backup", "db", "filters", "query", "replication",
                 "runtime", "schema", "storage"},
     "db": {"backup", "cluster", "config", "engine", "filters", "modules",

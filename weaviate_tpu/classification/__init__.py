@@ -140,21 +140,14 @@ class ClassificationManager:
         usecases/classification/filters.go). Masks are evaluated
         PER SHARD — doc ids are per-shard counters, so one shard's mask
         must never be applied to another shard's objects."""
-        from weaviate_tpu.filters.filters import compute_allow_mask
         from weaviate_tpu.storage.objects import StorageObject
 
         unlabeled, labeled = [], []
         # MT collections classify ONE tenant's shard; others span all local
         # shards (col._target_shards enforces the tenant requirement)
         for shard in col._target_shards(tenant):
-            src_mask = train_mask = None
-            if source_where is not None:
-                src_mask = compute_allow_mask(source_where, shard._inverted,
-                                              shard.doc_id_space)
-            if training_where is not None:
-                train_mask = compute_allow_mask(training_where,
-                                                shard._inverted,
-                                                shard.doc_id_space)
+            src_mask = shard.allow_mask(source_where)
+            train_mask = shard.allow_mask(training_where)
 
             def hit(mask, obj):
                 return mask is None or (obj.doc_id < len(mask)

@@ -9,6 +9,7 @@ shard_read.go (ObjectVectorSearch / ObjectSearch).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -17,9 +18,11 @@ import numpy as np
 
 from weaviate_tpu.engine.flat import FlatIndex
 from weaviate_tpu.runtime import tracing
+from weaviate_tpu.runtime.metrics import filter_leaf_total
 from weaviate_tpu.schema.config import CollectionConfig, VectorConfig
 from weaviate_tpu.storage.kv import KVStore
 from weaviate_tpu.storage.objects import StorageObject
+from weaviate_tpu.text.inverted import InvertedIndex, LeafStats
 
 logger = logging.getLogger(__name__)
 
@@ -199,6 +202,13 @@ class Shard:
         self.dir = os.path.join(data_dir, collection.name, name)
         os.makedirs(self.dir, exist_ok=True)
         self._lock = threading.RLock()
+        # write generation (a sequence lock): odd while a section that
+        # mutates the inverted index or the doc-id space runs
+        # (``_writing``), bumped under ``_lock`` and read without it
+        # by ``allow_mask``, which builds a filter's mask outside the lock
+        # and keeps it only if no such section ran meanwhile
+        self._write_gen = 0
+        self._write_depth = 0
         self.store = KVStore(self.dir, sync_wal=self.sync_wal)
         self.objects = self.store.bucket(BUCKET_OBJECTS, "replace")
         self.docid = self.store.bucket(BUCKET_DOCID, "replace")
@@ -243,8 +253,6 @@ class Shard:
         self.mesh = mesh
         # named vector indexes, built lazily at first insert (dim inference)
         self.vector_indexes: dict[str, FlatIndex] = {}
-        from weaviate_tpu.text.inverted import InvertedIndex
-
         # persistent inverted index: postings/filterables write through the
         # shard's own LSM store and are read on demand — NOT rebuilt from
         # objects at open (reference: inverted/ lsmkv buckets)
@@ -454,6 +462,23 @@ class Shard:
 
     # -- write path ----------------------------------------------------------
 
+    @contextlib.contextmanager
+    def _writing(self):
+        """Caller holds ``_lock``. Wraps a section that mutates the
+        inverted index or the doc-id space: ``_write_gen`` is odd from its
+        first statement to its last (the outermost section's, where they
+        nest), so a reader outside the lock can tell that its evaluation
+        overlapped none (``allow_mask``)."""
+        self._write_depth += 1
+        if self._write_depth == 1:
+            self._write_gen += 1
+        try:
+            yield
+        finally:
+            self._write_depth -= 1
+            if self._write_depth == 0:
+                self._write_gen += 1
+
     def _next_doc_id(self) -> int:
         with self._lock:
             doc_id = self._counter
@@ -525,7 +550,7 @@ class Shard:
                         logger.exception(  # typed 507 below is the answer
                             "shard %s/%s: memory-pressure rescue failed",
                             self.collection_name, self.name)
-        with self._lock:
+        with self._lock, self._writing():
             if self.read_only:
                 raise ShardReadOnlyError(
                     f"shard {self.name!r} is read-only (status READONLY)")
@@ -750,7 +775,7 @@ class Shard:
     def delete_object(self, uuid: str, tombstone_ms: int | None = None) -> bool:
         import time as _time
 
-        with self._lock:
+        with self._lock, self._writing():
             if self.read_only:
                 raise ShardReadOnlyError(
                     f"shard {self.name!r} is read-only (status READONLY)")
@@ -1161,16 +1186,56 @@ class Shard:
 
     def allow_mask(self, where) -> np.ndarray | None:
         """Filter tree → bool mask over this shard's doc-id space
-        (reference: inverted.Searcher → helpers.AllowList)."""
+        (reference: inverted.Searcher → helpers.AllowList). The one entry
+        point of every filtered read, and the owner of its invariants:
+
+        - **I1, isolation:** the mask equals what an evaluation under
+          ``Shard._lock`` would return at an instant, between the call and
+          its return, at which no write to the shard was in progress. The
+          mask is BUILT outside the lock (leaf clauses from the inverted
+          index's memo, ``InvertedIndex.leaf_mask``) and kept only if the
+          write generation was even when the build began and has not
+          moved when it ends: every section that mutates the inverted
+          index or the doc-id space runs inside ``_writing``, so no
+          such section overlapped the build. Otherwise (a write was in
+          progress, or began meanwhile) the filter is evaluated again
+          under the lock, as it always was (``result="locked"``). The
+          inverted index's ``_version`` could not carry this alone: it
+          moves once, at a mutation's END, and an update is an unindex and
+          an index inside one section.
+        - **I2, read your writes:** a write acknowledged before the call
+          ended its section, dropping the memo, before the generation was
+          read here: the mask holds it; a delete likewise.
+        - **I3:** a memoised mask is shared and read-only
+          (``flags.writeable`` False). No consumer writes to the mask it
+          is handed; one that tried would raise.
+
+        The span is the request's ``filter`` stage; ``leaf_hits`` /
+        ``leaf_misses`` / ``locked`` on it are this call's leaf look-ups
+        (``weaviate_tpu_filter_leaf_total{result}`` sums them)."""
         if where is None:
             return None
         from weaviate_tpu.filters import compute_allow_mask
 
         with tracing.span("shard.allow_mask", stage="filter",
-                          shard=self.name):
-            with self._lock:
-                return compute_allow_mask(where, self._inverted,
-                                          self.doc_id_space)
+                          shard=self.name) as sp:
+            stats = LeafStats()
+            gen = self._write_gen
+            mask = None if gen & 1 else compute_allow_mask(
+                where, self._inverted, self.doc_id_space, stats)
+            locked = mask is None or self._write_gen != gen
+            if locked:
+                with self._lock:
+                    mask = compute_allow_mask(where, self._inverted,
+                                              self.doc_id_space, stats)
+                filter_leaf_total.labels("locked").inc()
+            if stats.hits:
+                filter_leaf_total.labels("hit").inc(stats.hits)
+            if stats.misses:
+                filter_leaf_total.labels("miss").inc(stats.misses)
+            sp.set(leaf_hits=stats.hits, leaf_misses=stats.misses,
+                   locked=locked)
+            return mask
 
     def set_read_only(self, value: bool) -> None:
         """Persisted so a restart keeps the freeze (reference persists
@@ -1260,7 +1325,7 @@ class Shard:
         leaves a double-present object (never a lost one, and the
         pre-ingest markers mean deletes reach both copies); after it,
         reads route through the markers to the destination."""
-        with self._lock:
+        with self._lock, self._writing():
             keys = [u.encode() for u in uuids]
             pairs = []
             for u, k in zip(uuids, keys):

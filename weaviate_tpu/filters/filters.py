@@ -20,7 +20,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from weaviate_tpu.schema.config import DataType
-from weaviate_tpu.text.inverted import InvertedIndex, parse_date
+from weaviate_tpu.text.inverted import InvertedIndex, LeafStats, parse_date
 from weaviate_tpu.text.tokenizer import tokenize
 
 
@@ -117,33 +117,68 @@ def _geo_distance_m(lat1, lon1, lat2, lon2):
     return 2 * 6_371_000.0 * np.arcsin(np.sqrt(np.clip(a, 0, 1)))
 
 
-def compute_allow_mask(f: Filter, inv: InvertedIndex, size: int) -> np.ndarray:
-    """Evaluate a filter tree to a bool mask over [0, size) doc ids."""
-    return _eval(f, inv, size)
+def compute_allow_mask(f: Filter, inv: InvertedIndex, size: int,
+                       stats: LeafStats | None = None) -> np.ndarray:
+    """Evaluate a filter tree to a bool mask over [0, size) doc ids.
+
+    A LEAF clause (a value match, a range, ``IsNull``, ``Like``, and the
+    "all live docs" mask that ``Not``, ``NotEqual`` and ``IsNull false``
+    take their complement against) resolves through the inverted index's
+    memo (``InvertedIndex.leaf_mask``) to a shared, READ-ONLY array that
+    is exact for one state of the index. ``And`` / ``Or`` / ``Not``
+    combine leaves into new arrays (``out = a & b``), so a shared mask
+    is never written; a filter that IS one leaf returns the shared array
+    itself: no consumer may write to a mask it was handed.
+    ``WithinGeoRange`` keeps its own grid cache. ``stats`` counts the
+    leaf look-ups. This function takes no lock and is exact only on an
+    index no write is in progress on: requests come through
+    ``Shard.allow_mask``, which owns that."""
+    return _eval(f, inv, size, LeafStats() if stats is None else stats)
 
 
-def _full(inv: InvertedIndex, size: int) -> np.ndarray:
-    return _from_ids(inv.all_docs(), size)
+def _full(inv: InvertedIndex, size: int, stats) -> np.ndarray:
+    return inv.leaf_mask(("all",), size,
+                         lambda: _from_ids(inv.all_docs(), size), stats)
 
 
-def _from_ids(ids, size: int) -> np.ndarray:
-    """Sorted id array (or any iterable of ids) -> dense bool mask."""
+def _set_ids(mask: np.ndarray, ids: np.ndarray) -> None:
+    """``mask[ids] = True`` for an id array as the inverted index caches
+    it (SORTED, uint64); ids past the mask's end are dropped."""
+    if len(ids):
+        if int(ids[-1]) >= len(mask):
+            ids = ids[: np.searchsorted(ids, len(mask))]
+        # doc ids are far below 2**63: the view spares the cast numpy
+        # would make of a uint64 index (half of the scatter's time)
+        mask[ids.view(np.int64)] = True
+
+
+def _from_ids(ids: np.ndarray, size: int) -> np.ndarray:
+    """One such id array -> a new dense bool mask."""
     mask = np.zeros(size, dtype=bool)
-    arr = np.asarray(ids, dtype=np.int64) if not isinstance(ids, np.ndarray) \
-        else ids.astype(np.int64, copy=False)
-    if len(arr):
-        arr = arr[arr < size]
-        if len(arr):
-            mask[arr] = True
+    _set_ids(mask, ids)
     return mask
 
 
-def _eval(f: Filter, inv: InvertedIndex, size: int) -> np.ndarray:
+def _canonical(value):
+    """A hashable form of a clause's value under which equal clauses
+    meet: the type is part of it (``True == 1 == 1.0`` in a dict, and
+    they are three different filter keys). None for a value no leaf can
+    match."""
+    if isinstance(value, bool):
+        return ("b", value)
+    if isinstance(value, (int, float)):
+        return ("f", float(value))
+    if isinstance(value, str):
+        return ("s", value)
+    return None
+
+
+def _eval(f: Filter, inv: InvertedIndex, size: int, stats) -> np.ndarray:
     op = f.operator
     if op in Operator.LOGICAL:
         if not f.operands:
             raise ValueError(f"{op} filter requires operands")
-        masks = [_eval(o, inv, size) for o in f.operands]
+        masks = [_eval(o, inv, size, stats) for o in f.operands]
         if op == Operator.AND:
             out = masks[0]
             for m in masks[1:]:
@@ -158,17 +193,19 @@ def _eval(f: Filter, inv: InvertedIndex, size: int) -> np.ndarray:
         out = masks[0]
         for m in masks[1:]:
             out = out | m
-        return _full(inv, size) & ~out
+        return _full(inv, size, stats) & ~out
 
     prop = f.prop
     if prop is None:
         raise ValueError(f"filter {op} requires a path")
 
     if op == Operator.IS_NULL:
-        null_mask = _from_ids(inv.null_ids(prop), size)
+        null_mask = inv.leaf_mask(
+            ("null", prop), size,
+            lambda: _from_ids(inv.null_ids(prop), size), stats)
         if f.value:
             return null_mask
-        return _full(inv, size) & ~null_mask
+        return _full(inv, size, stats) & ~null_mask
 
     if op == Operator.WITHIN_GEO_RANGE:
         grid = inv.geo_grid(prop)
@@ -194,34 +231,20 @@ def _eval(f: Filter, inv: InvertedIndex, size: int) -> np.ndarray:
         if isinstance(threshold, str):
             threshold = parse_date(threshold)
         threshold = float(threshold)
-        # LSM range scan over order-preserving numeric keys; array props
-        # index every element, giving any-element semantics for free
-        # (reference: searcher.go range row readers)
-        if op == Operator.GREATER_THAN:
-            ids = inv.numeric_range_ids(prop, threshold, None, lo_incl=False)
-        elif op == Operator.GREATER_THAN_EQUAL:
-            ids = inv.numeric_range_ids(prop, threshold, None, lo_incl=True)
-        elif op == Operator.LESS_THAN:
-            ids = inv.numeric_range_ids(prop, None, threshold, hi_incl=False)
-        else:
-            ids = inv.numeric_range_ids(prop, None, threshold, hi_incl=True)
-        return _from_ids(ids, size)
+        return inv.leaf_mask(
+            ("range", prop, op, threshold), size,
+            lambda: _range_mask(inv, prop, op, threshold, size), stats)
 
     if op == Operator.LIKE:
-        # ?/* wildcards range-scanned over the text vocabulary
-        # (reference: inverted/like_regexp.go)
         pattern = str(f.value).lower()
-        rx = re.compile(fnmatch.translate(pattern))
-        mask = np.zeros(size, dtype=bool)
-        for token, ids in inv.text_vocab(prop):
-            if rx.match(token.lower()):
-                mask |= _from_ids(ids, size)
-        return mask
+        return inv.leaf_mask(
+            ("like", prop, pattern), size,
+            lambda: _like_mask(inv, prop, pattern, size), stats)
 
     if op in (Operator.EQUAL, Operator.NOT_EQUAL,
               Operator.CONTAINS_ANY, Operator.CONTAINS_ALL):
         values = f.value if isinstance(f.value, (list, tuple)) else [f.value]
-        masks = [_match_value(inv, prop, v, size) for v in values]
+        masks = [_match_value(inv, prop, v, size, stats) for v in values]
         if op == Operator.CONTAINS_ALL:
             out = masks[0]
             for m in masks[1:]:
@@ -231,36 +254,81 @@ def _eval(f: Filter, inv: InvertedIndex, size: int) -> np.ndarray:
         for m in masks[1:]:
             out = out | m
         if op == Operator.NOT_EQUAL:
-            return _full(inv, size) & ~out
+            return _full(inv, size, stats) & ~out
         return out
 
     raise ValueError(f"unknown filter operator {op!r}")
 
 
-def _match_value(inv: InvertedIndex, prop: str, value, size: int) -> np.ndarray:
-    """Exact-match a single value against the filterable index. Text values
-    tokenize; multi-token text matches docs containing ALL tokens
-    (reference Equal-on-text semantics)."""
-    if isinstance(value, bool):
-        return _from_ids(inv.filterable_ids(prop, value), size)
-    if isinstance(value, (int, float)):
-        return _from_ids(inv.filterable_ids(prop, float(value)), size)
-    if isinstance(value, str):
-        # date-valued? keys are floats for date props
-        sch = inv.config.property(prop)
-        if sch is not None and sch.data_type in (DataType.DATE, DataType.DATE_ARRAY):
-            try:
-                return _from_ids(inv.filterable_ids(prop, parse_date(value)), size)
-            except ValueError:
-                return np.zeros(size, dtype=bool)
-        if sch is not None and sch.data_type in (DataType.UUID, DataType.UUID_ARRAY):
-            return _from_ids(inv.filterable_ids(prop, value), size)
-        tokenization = sch.tokenization if sch is not None else "word"
-        tokens = tokenize(value, tokenization)
-        if not tokens:
+def _range_mask(inv: InvertedIndex, prop: str, op: str, threshold: float,
+                size: int) -> np.ndarray:
+    """LSM range scan over order-preserving numeric keys; array props
+    index every element, giving any-element semantics for free
+    (reference: searcher.go range row readers). Each value's ids are
+    OR-ed straight into the mask: no concatenation, no sort."""
+    lo, hi = ((threshold, None) if op in (Operator.GREATER_THAN,
+                                          Operator.GREATER_THAN_EQUAL)
+              else (None, threshold))
+    parts = inv.numeric_range_parts(
+        prop, lo, hi, lo_incl=op != Operator.GREATER_THAN,
+        hi_incl=op == Operator.LESS_THAN_EQUAL)
+    mask = np.zeros(size, dtype=bool)
+    for ids in parts:
+        _set_ids(mask, ids)
+    return mask
+
+
+def _like_mask(inv: InvertedIndex, prop: str, pattern: str,
+               size: int) -> np.ndarray:
+    """?/* wildcards range-scanned over the text vocabulary (reference:
+    inverted/like_regexp.go)."""
+    rx = re.compile(fnmatch.translate(pattern))
+    mask = np.zeros(size, dtype=bool)
+    for token, ids in inv.text_vocab(prop):
+        if rx.match(token.lower()):
+            _set_ids(mask, ids)
+    return mask
+
+
+def _match_value(inv: InvertedIndex, prop: str, value, size: int,
+                 stats) -> np.ndarray:
+    """Exact-match a single value against the filterable index: one leaf
+    a value, shared by ``Equal``, ``NotEqual``, ``ContainsAny`` and
+    ``ContainsAll``. Text values tokenize; multi-token text matches docs
+    containing ALL tokens (reference Equal-on-text semantics)."""
+    canon = _canonical(value)
+    if canon is None:
+        return np.zeros(size, dtype=bool)
+    if canon[0] != "s":
+        return inv.leaf_mask(
+            ("match", prop, canon), size,
+            lambda: _from_ids(inv.filterable_ids(prop, canon[1]), size),
+            stats)
+    # what a string matches depends on the property's type and
+    # tokenization, which a schema update can change with no write to the
+    # index: they are part of the clause
+    sch = inv.config.property(prop)
+    kind = (sch.data_type, sch.tokenization) if sch is not None else None
+    return inv.leaf_mask(("match", prop, canon, kind), size,
+                         lambda: _match_string(inv, prop, value, sch, size),
+                         stats)
+
+
+def _match_string(inv: InvertedIndex, prop: str, value: str, sch,
+                  size: int) -> np.ndarray:
+    # date-valued? keys are floats for date props
+    if sch is not None and sch.data_type in (DataType.DATE, DataType.DATE_ARRAY):
+        try:
+            return _from_ids(inv.filterable_ids(prop, parse_date(value)), size)
+        except ValueError:
             return np.zeros(size, dtype=bool)
-        out = _from_ids(inv.filterable_ids(prop, tokens[0]), size)
-        for t in tokens[1:]:
-            out = out & _from_ids(inv.filterable_ids(prop, t), size)
-        return out
-    return np.zeros(size, dtype=bool)
+    if sch is not None and sch.data_type in (DataType.UUID, DataType.UUID_ARRAY):
+        return _from_ids(inv.filterable_ids(prop, value), size)
+    tokenization = sch.tokenization if sch is not None else "word"
+    tokens = tokenize(value, tokenization)
+    if not tokens:
+        return np.zeros(size, dtype=bool)
+    out = _from_ids(inv.filterable_ids(prop, tokens[0]), size)
+    for t in tokens[1:]:
+        out = out & _from_ids(inv.filterable_ids(prop, t), size)
+    return out

@@ -544,6 +544,15 @@ postings_cache_misses = registry.counter(
     "host-side cost floor of BM25 planning and the hybridplane's "
     "posting pack")
 
+filter_leaf_total = registry.counter(
+    "weaviate_tpu_filter_leaf_total",
+    "Leaf clauses of filters looked up in the inverted index's memo of "
+    "read-only masks (db/shard.py allow_mask): hit = served from the "
+    "memo, miss = built (outside Shard._lock) and memoised if no write "
+    "ended meanwhile; locked = a filter evaluated again under "
+    "Shard._lock because a write was in progress or began during its "
+    "build (one a request, not a leaf)", ("result",))
+
 # -- epoch store (engine/epochs.py publishes on seal/compact/drop;
 #    db/collection.py bumps the migration counter) ----------------------------
 

@@ -1327,6 +1327,34 @@ class Bucket:
             if v is not _TOMBSTONE:
                 yield k, v
 
+    def keys_in_range(self, start: bytes | None, stop: bytes | None,
+                      limit: int) -> list[bytes] | None:
+        """The keys in [start, stop) of every layer, in no order, with no
+        value read or merged: a SUPERSET of the live keys (a key whose
+        newest layer is a tombstone is listed, and reads as empty).
+        None when there are more than ``limit``: the caller then walks
+        the range once (``iter_range``) instead of reading a key at a
+        time."""
+        segments, mems = self._merged_layers(start, stop)
+        keys: set[bytes] = set()
+        for data in mems:
+            if isinstance(data, list):  # native table: already cut to range
+                keys.update(k for k, _raw in data)
+            else:
+                keys.update(k for k in data
+                            if (start is None or k >= start)
+                            and (stop is None or k < stop))
+            if len(keys) > limit:
+                return None
+        for seg in segments:
+            for k, _raw in seg.iter_items(start=start):
+                if stop is not None and k >= stop:
+                    break
+                keys.add(k)
+                if len(keys) > limit:
+                    return None
+        return list(keys)
+
     def __len__(self) -> int:
         n = 0
         for _ in self.iter_items():

@@ -40,6 +40,7 @@ import math
 import struct
 import threading
 from collections import OrderedDict
+from dataclasses import dataclass
 from datetime import datetime, timezone
 
 import numpy as np
@@ -252,12 +253,55 @@ class _LRU:
         self.d.clear()
 
 
-class InvertedIndex:
-    """All six bucket families for one shard, with RAM LRU caches in front.
+@dataclass
+class LeafStats:
+    """Leaf look-ups of one filter evaluation (``InvertedIndex.leaf_mask``):
+    the ``leaf_hits`` / ``leaf_misses`` of the ``shard.allow_mask`` span and
+    the ``hit`` / ``miss`` of ``weaviate_tpu_filter_leaf_total``."""
 
-    Thread-safety: a single RLock guards cache + meta mutations (writes
-    come in under the shard lock anyway; queries take it to snapshot).
+    hits: int = 0
+    misses: int = 0
+
+
+class InvertedIndex:
+    """All six bucket families for one shard, with RAM caches in front.
+
+    Thread-safety: a single RLock guards the caches, the meta and
+    ``_version``; it is held for look-ups and fills, never while a
+    bucket is read or a mask is built. Writes come in under the shard
+    lock. A reader takes ``_version`` with its look-up, reads the buckets
+    unlocked, and its fill is kept only if the version has not moved.
+
+    Three caches, one protocol: ``_post_cache`` and ``_bitmap_cache``
+    (decoded postings and id arrays a key; a write drops the keys it
+    touched), ``_geo_cache`` (a grid a property), and the **leaf memo**
+    (``leaf_mask``): a filter's leaf clause -> a READ-ONLY dense
+    ``bool[doc_id_space]`` mask, valid for one ``_version``. Every
+    mutation (``index_objects``, ``unindex_objects``,
+    ``reconcile_doc_count``) drops the memo whole where it bumps the
+    version, under the lock (``_bump``): a mask's length is the doc-id
+    space, which every insert moves, so there is no per-key bookkeeping.
+    Leaves, not whole trees: a search page's clauses repeat far more
+    often than their combinations, and ``And`` / ``Or`` / ``Not`` make
+    new arrays from them, so a memoised mask is never written
+    (``flags.writeable`` is False: an in-place write raises). The memo is
+    capped in bytes (``LEAF_MEMO_MAX_BYTES``), least recently used out.
+
+    ``_version`` moves ONCE a mutation, at its end, so "version equal
+    before and after" does not prove that a mask saw no half-written
+    batch. What the memo guarantees is narrower and enough: a mask built
+    across a mutation is dropped by that mutation's bump or refused at
+    the fill, so no such mask outlives the mutation. Isolation from a
+    write IN PROGRESS is the shard's (``Shard.allow_mask``, its write
+    generation).
     """
+
+    # the leaf memo's cap: 256 masks at 262,144 doc ids, 64 at a million
+    LEAF_MEMO_MAX_BYTES = 64 << 20
+    # a numeric range of up to this many distinct values is read a key at a
+    # time through ``_bitmap_cache`` (a sixteenth of its entries); a wider
+    # one (a price, a timestamp) is one merged walk of the LSM
+    RANGE_KEYS_CACHED = 4096
 
     def __init__(self, config: CollectionConfig, store=None):
         self.config = config
@@ -290,8 +334,10 @@ class InvertedIndex:
         self._post_cache = _LRU()
         self._bitmap_cache = _LRU()
         self._geo_cache: dict[str, tuple] = {}
-        # bumped under _lock on every mutation; readers capture it before
-        # the (unlocked) bucket read and only cache if unchanged — a
+        self._leaf_memo: OrderedDict = OrderedDict()
+        self._leaf_memo_bytes = 0
+        # bumped under _lock on every mutation (_bump); readers capture it
+        # before the (unlocked) bucket read and only cache if unchanged — a
         # concurrent write's invalidation can never be overwritten by a
         # stale fill
         self._version = 0
@@ -330,7 +376,14 @@ class InvertedIndex:
             if self.doc_count != actual:
                 self._meta["doc_count"] = int(actual)
                 self._save_meta()
-                self._version += 1
+                self._bump()
+
+    def _bump(self) -> None:
+        """A mutation's last step, under ``_lock``: a new version, and no
+        leaf mask of the old one left."""
+        self._version += 1
+        self._leaf_memo.clear()
+        self._leaf_memo_bytes = 0
 
     # -- mutation -------------------------------------------------------------
 
@@ -400,7 +453,7 @@ class InvertedIndex:
                 pm["total_len"] += dl
                 pm["len_count"] += dc
             self._save_meta()
-            self._version += 1
+            self._bump()
             # cache invalidation for every touched key; when a batch
             # touches more keys than the cache could plausibly hold hot,
             # one clear beats tens of thousands of per-key pops (the pops
@@ -611,7 +664,7 @@ class InvertedIndex:
                 pm["total_len"] += dl
                 pm["len_count"] += dc
             self._save_meta()
-            self._version += 1
+            self._bump()
             for k in search_del:
                 self._post_cache.pop(k)
             for k in filter_del:
@@ -733,17 +786,27 @@ class InvertedIndex:
         return out
 
     def _bitmap(self, bucket_name: str, bucket, key: bytes) -> np.ndarray:
-        ck = (bucket_name, key)
+        return self._bitmaps(bucket_name, bucket, [key])[0]
+
+    def _bitmaps(self, bucket_name: str, bucket,
+                 keys: list[bytes]) -> list[np.ndarray]:
+        """The sorted uint64 id arrays of ``keys``, through the version-
+        checked ``_bitmap_cache``, with ONE look-up and one fill under the
+        lock for all of them: a hundred short lock sections a call make
+        request threads queue behind whichever was descheduled inside
+        one."""
         with self._lock:
-            hit = self._bitmap_cache.get(ck)
-            if hit is not None:
-                return hit
+            out = [self._bitmap_cache.get((bucket_name, k)) for k in keys]
             version = self._version
-        arr = bucket.get_bitmap(key)
-        with self._lock:
-            if self._version == version:
-                self._bitmap_cache.put(ck, arr)
-        return arr
+        missing = [i for i, arr in enumerate(out) if arr is None]
+        if missing:
+            for i in missing:
+                out[i] = bucket.get_bitmap(keys[i])
+            with self._lock:
+                if self._version == version:
+                    for i in missing:
+                        self._bitmap_cache.put((bucket_name, keys[i]), out[i])
+        return out
 
     def all_docs(self) -> np.ndarray:
         """Sorted uint64 ids of live docs."""
@@ -769,11 +832,16 @@ class InvertedIndex:
             if len(ids):
                 yield k[len(pfx):].decode(), ids
 
-    def numeric_range_ids(self, prop: str, lo: float | None, hi: float | None,
-                          lo_incl: bool = True, hi_incl: bool = False):
-        """Union of doc bitmaps for values in the given range — an LSM
-        range scan over order-preserving keys (reference: searcher.go
-        range row readers over roaringset)."""
+    def numeric_range_parts(self, prop: str, lo: float | None,
+                            hi: float | None, lo_incl: bool = True,
+                            hi_incl: bool = False) -> list[np.ndarray]:
+        """The id arrays (each sorted, uint64) of the values in the given
+        range, one a distinct value, in no order: what a mask needs, which
+        asks neither order nor uniqueness across values. Few distinct
+        values come a key at a time through ``_bitmap_cache``, which a
+        write drops only for the keys it touched; many come from one
+        merged LSM range scan over the order-preserving keys (reference:
+        searcher.go range row readers over roaringset)."""
         from weaviate_tpu import native
 
         pfx = prop.encode() + _SEP
@@ -789,17 +857,56 @@ class InvertedIndex:
             stop = pfx + _enc_f64(hi)
             if hi_incl:
                 stop += b"\x00"
-        parts = []
-        for _k, v in self.numeric_bucket.iter_range(start, stop):
-            ids = native.difference_sorted(v["add"], v["del"])
-            if len(ids):
-                parts.append(ids)
+        keys = self.numeric_bucket.keys_in_range(start, stop,
+                                                 self.RANGE_KEYS_CACHED)
+        if keys is not None:
+            parts = self._bitmaps(B_NUMERIC, self.numeric_bucket, keys)
+        else:
+            parts = [native.difference_sorted(v["add"], v["del"])
+                     for _k, v in self.numeric_bucket.iter_range(start, stop)]
+        return [ids for ids in parts if len(ids)]
+
+    def numeric_range_ids(self, prop: str, lo: float | None, hi: float | None,
+                          lo_incl: bool = True, hi_incl: bool = False):
+        """Sorted unique doc ids with a value in the given range (array
+        props index every element: any-element semantics)."""
+        parts = self.numeric_range_parts(prop, lo, hi, lo_incl, hi_incl)
         if not parts:
             return np.empty(0, np.uint64)
         # one concatenate+unique instead of repeated pairwise unions —
         # a wide range over mostly-unique values would otherwise go
         # quadratic in the number of distinct keys
         return np.unique(np.concatenate(parts))
+
+    def leaf_mask(self, key: tuple, size: int, build,
+                  stats: LeafStats) -> np.ndarray:
+        """The read-only ``bool[size]`` mask of one filter leaf, from the
+        memo or from ``build()`` (which returns a new array over [0, size)
+        and runs with no lock held). ``key`` names the clause: the
+        property, the operator and the value in canonical form; the mask's
+        length is part of the entry's key. A fill is kept only if no
+        mutation ended between the look-up and the fill."""
+        ck = (size, *key)
+        with self._lock:
+            mask = self._leaf_memo.get(ck)
+            if mask is not None:
+                self._leaf_memo.move_to_end(ck)
+            version = self._version
+        if mask is not None:
+            stats.hits += 1
+            return mask
+        stats.misses += 1
+        mask = build()
+        mask.flags.writeable = False
+        with self._lock:
+            if (self._version == version and ck not in self._leaf_memo
+                    and mask.nbytes <= self.LEAF_MEMO_MAX_BYTES):
+                self._leaf_memo[ck] = mask
+                self._leaf_memo_bytes += mask.nbytes
+                while self._leaf_memo_bytes > self.LEAF_MEMO_MAX_BYTES:
+                    _k, old = self._leaf_memo.popitem(last=False)
+                    self._leaf_memo_bytes -= old.nbytes
+        return mask
 
     def geo_arrays(self, prop: str):
         """(ids int64, lats f64, lons f64) for every doc with a geo value
