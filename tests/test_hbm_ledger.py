@@ -76,6 +76,40 @@ def test_track_releases_with_array_lifetime():
     assert led.collection_bytes("T") == 0
 
 
+@pytest.mark.parametrize("entry", ["release_many", "track"])
+def test_a_finalizer_takes_no_lock(entry):
+    """A weakref finalizer can fire from a cyclic GC inside any locked
+    section of its thread, ``hbm_bytes.labels()`` under ``register``
+    included (a tier-1 run hung there: the batcher's worker took the
+    gauge's plain Lock a second time). So what a finalizer runs returns
+    while the ledger's lock and the gauge's are both held elsewhere, and
+    the ledger's next call releases the entry."""
+    import threading
+
+    from weaviate_tpu.runtime.metrics import hbm_bytes
+
+    class Buffer:
+        nbytes = 4096
+
+    led = HBMLedger()
+    owner = [Buffer()]
+    if entry == "track":
+        led.track("corpus", owner[0], collection="Fin", shard="f0")
+    else:
+        import weakref
+
+        keys = [led.register("corpus", 4096, collection="Fin", shard="f0")]
+        weakref.finalize(owner[0], led.release_many, keys)
+    assert led.shard_bytes("Fin", "f0") == 4096
+    dropped = threading.Thread(target=owner.clear, daemon=True)
+    with led._lock, hbm_bytes._lock:
+        dropped.start()
+        dropped.join(5.0)
+        assert not dropped.is_alive()
+    assert led.shard_bytes("Fin", "f0") == 0
+    assert led.total_bytes() == 0
+
+
 def test_gauges_follow_ledger_and_drop_on_release():
     from weaviate_tpu.runtime.metrics import registry
 

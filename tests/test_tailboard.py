@@ -31,6 +31,9 @@ def served(tmp_path, monkeypatch):
     monkeypatch.setenv("WEAVIATE_TPU_TAIL_SLOW_MS",
                        json.dumps({"graphql": 30, "*": 250}))
     tracing.reset_policy_for_tests()
+    # the ring of finished traces is the process's: a forced trace of a
+    # test that ran earlier in this worker is not this server's
+    tracing.clear_traces()
     tailboard.reset_for_tests()
     db = Database(str(tmp_path))
     srv = RestServer(db)
@@ -402,17 +405,20 @@ def test_flight_ring_wraps_and_orders():
 
 
 @pytest.fixture
-def grpc_search(tmp_path):
-    """A real GrpcServer over a socket and a unary Search callable."""
+def grpc_search(tmp_path, request):
+    """A real GrpcServer over a socket and a unary Search callable; the
+    collection has one shard, or as many as the test's parameter says."""
     import grpc
 
     from weaviate_tpu.api.grpc import v1_pb2 as pb
     from weaviate_tpu.api.grpc.server import GrpcServer
-    from weaviate_tpu.schema.config import CollectionConfig, Property
+    from weaviate_tpu.schema.config import (CollectionConfig, Property,
+                                            ShardingConfig)
 
     db = Database(str(tmp_path))
     db.create_collection(CollectionConfig(name="Stage", properties=[
-        Property(name="bucket", data_type="int")]))
+        Property(name="bucket", data_type="int")],
+        sharding=ShardingConfig(desired_count=getattr(request, "param", 1))))
     col = db.get_collection("Stage")
     rng = np.random.default_rng(5)
     for i in range(64):
@@ -471,6 +477,8 @@ def test_every_stage_of_a_grpc_search_is_observed_once_and_sums(grpc_search):
             for p in tailboard.PHASES)
 
     base, base_n, base_phases = read()
+    fanout_base = [_stage("grpc.search", s).count
+                   for s in tailboard.FANOUT_STAGES]
     n = 12
     for i in range(n):
         grpc_search(filtered=i % 3 == 0)
@@ -487,6 +495,9 @@ def test_every_stage_of_a_grpc_search_is_observed_once_and_sums(grpc_search):
     total = {s: now[s][1] - base[s][1] for s in every}
     assert sum(total[s] for s in tailboard.REQUEST_STAGES) == \
         pytest.approx(total["server_residency"], rel=1e-9)
+    # a one-shard request observes neither stage of a fan-out
+    assert fanout_base == [_stage("grpc.search", s).count
+                           for s in tailboard.FANOUT_STAGES]
     assert total["filter"] > 0 and total["fetch"] > 0
     assert total["queue_wait"] > 0 and total["device"] > 0
     assert total["pool_wait"] > 0 and total["send"] > 0
@@ -500,6 +511,54 @@ def test_every_stage_of_a_grpc_search_is_observed_once_and_sums(grpc_search):
     assert 0.0 <= handler - phases <= total["parse"]
     # the phase series, with its collection label, saw the same requests
     assert now_n - base_n == n
+
+
+@pytest.mark.parametrize("grpc_search", [4], indirect=True)
+def test_a_fanned_out_search_stays_additive(grpc_search):
+    """Four local shards: the request is charged ONE queue_wait, device
+    and transfer (its critical path's), observes ``fanout_wait`` and
+    ``merge`` once each, and the stages, those two included, sum to
+    ``server_residency`` as a one-shard request's do without them."""
+    from weaviate_tpu.runtime.metrics import (fanout_shards_total,
+                                              fanout_width,
+                                              request_phase_seconds)
+
+    every = tailboard.REQUEST_STAGES + tailboard.FANOUT_STAGES \
+        + tailboard.REQUEST_EXTRAS
+
+    def read():
+        tailboard.flush()
+        return ({s: (_stage("grpc.search", s).count,
+                     _stage("grpc.search", s).total) for s in every},
+                {p: request_phase_seconds.labels(
+                    "grpc.search", p, "Stage", "-").count
+                 for p in ("queue_wait", "device", "host")})
+
+    base, base_phases = read()
+    shards = fanout_shards_total.labels("Stage").value
+    widths = fanout_width.labels().count
+    n = 10
+    for i in range(n):
+        grpc_search(filtered=i % 2 == 0)
+    deadline = time.time() + 10.0
+    while time.time() < deadline:
+        now, now_phases = read()
+        if now["server_residency"][0] - base["server_residency"][0] == n:
+            break
+        time.sleep(0.02)
+    assert {s: now[s][0] - base[s][0] for s in every} == \
+        {s: n for s in every}
+    assert {p: now_phases[p] - base_phases[p] for p in now_phases} == \
+        {p: n for p in now_phases}      # one a request, not one a shard
+    total = {s: now[s][1] - base[s][1] for s in every}
+    assert sum(total[s] for s in tailboard.REQUEST_STAGES
+               + tailboard.FANOUT_STAGES) == \
+        pytest.approx(total["server_residency"], rel=1e-9)
+    assert total["fanout_wait"] > 0 and total["merge"] > 0
+    assert total["queue_wait"] > 0 and total["device"] > 0
+    assert total["search_other"] > 0    # the glue: not swallowed, not negative
+    assert fanout_shards_total.labels("Stage").value - shards == 4 * n
+    assert fanout_width.labels().count - widths == n
 
 
 class _Clock:
@@ -540,7 +599,7 @@ def test_stages_from_stated_stamps_and_phases_unchanged(monkeypatch):
     clock = _Clock()
     monkeypatch.setattr(tailboard, "time", clock)
 
-    def drive(operation, staged):
+    def drive(operation, staged, fanned=False):
         clock.now, clock.cpu = 100.002, 7.0     # handler entry
         ctx = _Ctx()
         with tailboard.request(
@@ -555,6 +614,8 @@ def test_stages_from_stated_stamps_and_phases_unchanged(monkeypatch):
             tailboard.phase("device", 0.004)
             tailboard.request_stage("wake", 0.0005)
             tailboard.request_stage("fetch", 0.001)
+            if fanned:
+                tailboard.fanout(0.0012, 0.0003)
             clock.now = 100.012
             tailboard.mark("search")
             tailboard.complete(200)
@@ -577,6 +638,18 @@ def test_stages_from_stated_stamps_and_phases_unchanged(monkeypatch):
             "handler_cpu": 0.0015, "server_residency": 0.015}
     assert got == pytest.approx(want, abs=1e-9)
     assert _stage("op.plain", "parse").count == 0   # unstaged: no stages
+    assert _stage("op.staged", "fanout_wait").count == 0
+    # the same stamps with a fan-out's two stages: they come out of
+    # ``search_other`` and nothing else moves
+    drive("op.fanned", True, fanned=True)
+    assert _phase_totals("op.fanned") == plain
+    got = {s: _stage("op.fanned", s).total
+           for s in tailboard.REQUEST_STAGES + tailboard.FANOUT_STAGES
+           + tailboard.REQUEST_EXTRAS}
+    assert got == pytest.approx(dict(
+        want, fanout_wait=0.0012, merge=0.0003,
+        search_other=0.009 - 0.007 - 0.0015), abs=1e-9)
+    assert _stage("op.fanned", "merge").count == 1
 
 
 def test_termination_before_the_handler_returns_gives_send_zero(

@@ -9,6 +9,7 @@ shard per tenant.
 from __future__ import annotations
 
 import functools
+import heapq
 import logging
 import os
 import threading
@@ -22,7 +23,7 @@ from weaviate_tpu.db.shard import Shard
 from weaviate_tpu.db.sharding import ShardingState
 from weaviate_tpu.runtime import degrade
 from weaviate_tpu.runtime import metrics as monitoring
-from weaviate_tpu.runtime import tracing
+from weaviate_tpu.runtime import tailboard, tracing
 from weaviate_tpu.schema.config import CollectionConfig
 from weaviate_tpu.storage.objects import StorageObject
 
@@ -84,6 +85,25 @@ def _timed(query_type: str):
         return wrapper
 
     return deco
+
+
+class _ShardHits:
+    """A local shard's answer to one fanned-out search, as arrays:
+    ``[pos]`` builds the result of one position (None where the object
+    has been deleted since), so that a merge pays for its winners."""
+
+    __slots__ = ("name", "shard", "ids", "dists")
+
+    def __init__(self, name: str, shard, ids, dists):
+        self.name, self.shard, self.ids, self.dists = name, shard, ids, dists
+
+    def __len__(self) -> int:
+        return len(self.ids)
+
+    def __getitem__(self, pos: int):
+        uuid = self.shard._doc_to_uuid.get(int(self.ids[pos]))
+        return None if uuid is None else SearchResult(
+            uuid=uuid, distance=float(self.dists[pos]), shard=self.name)
 
 
 class Collection:
@@ -1250,41 +1270,43 @@ class Collection:
         return to_mask(a, size) & to_mask(b, size)
 
     @staticmethod
-    def _merge_by_distance(gathered: list[list], k: int) -> list:
-        """Cross-shard reduce: each shard's list is already ascending, so
-        the k-way heap merge runs in the native library
-        (csrc/weaviate_native.cpp wn_merge_topk; reference:
-        index.go:1644-1648 sort+truncate)."""
-        lists = [g for g in gathered if g]
-        if not lists:
+    def _merge_by_distance(gathered: list, k: int) -> list:
+        """Cross-shard reduce: each shard's entry is already ascending, so
+        a k-way heap merge walks them in order and stops at k (reference:
+        index.go:1644-1648 sort+truncate). An entry is a list of results
+        (a remote shard's, or one local shard's) or a ``_ShardHits``: a
+        local shard's doc ids and distances, whose uuids are looked up
+        and whose results are built HERE, for the winners alone. Plain
+        Python on purpose: with 32 request threads on one interpreter a
+        call that gives the lock up (ctypes, as the native merge this
+        replaced; numpy over more than a few hundred elements) waits its
+        turn to take it back, 20 ms a merge on the chip's host (PERF.md
+        section 6, PR 34), where the whole walk is some tens of heap
+        steps."""
+        sources = [g for g in gathered if len(g)]
+        if not sources:
             return []
-        if len(lists) == 1:
-            return lists[0][:k]
-        from weaviate_tpu import native
+        if len(sources) == 1 and isinstance(sources[0], list):
+            return sources[0][:k]
 
-        width = max(len(g) for g in lists)
-        d = np.full((len(lists), width), np.float32(3.0e38), dtype=np.float32)
-        idx = np.full((len(lists), width), -1, dtype=np.int64)
-        flat: list = []
-        for li, g in enumerate(lists):
-            for pos, r in enumerate(g):
-                d[li, pos] = r.distance
-                idx[li, pos] = len(flat)
-                flat.append(r)
-        # merge OVERSAMPLED (2k) so the uuid dedup below can drop a
-        # transient double-present copy without eating into the k
-        # contract — a duplicate pair in the top-k would otherwise
-        # shadow the next distinct candidate
-        _, out_i = native.merge_topk_host(d, idx, k=min(2 * k, len(flat)))
+        def ascending(li, g):
+            dists = g.dists.tolist() if isinstance(g, _ShardHits) else \
+                [r.distance for r in g]
+            for pos, d in enumerate(dists):
+                yield d, li, pos
+
         # dedup by uuid, best (first, ascending) distance wins: an
         # epoch-migration crash window can briefly leave an object
-        # present on two shards — it must never be served twice.
-        # Results without a uuid (score-only merges) always pass.
+        # present on two shards — it must never be served twice, and the
+        # walk goes on past a duplicate so that it never eats into the k
+        # contract. Results without a uuid (score-only merges) always
+        # pass.
         out, seen = [], set()
-        for i in out_i.tolist():
-            if i < 0:
+        for _d, li, pos in heapq.merge(*(ascending(li, g)
+                                         for li, g in enumerate(sources))):
+            r = sources[li][pos]
+            if r is None:   # deleted since the shard answered
                 continue
-            r = flat[i]
             u = getattr(r, "uuid", None)
             if u is not None:
                 if u in seen:
@@ -1304,42 +1326,30 @@ class Collection:
         """Scatter-gather nearVector (reference: index.go:1541
         objectVectorSearch -> per-shard parallel search -> merge+truncate).
         ``where``: optional Filter tree, evaluated per shard to an AllowList
-        mask applied inside the device scan."""
+        mask applied inside the device scan.
+
+        More than one shard (``_fan_out``): the request's own thread
+        builds each LOCAL shard's allow mask, enqueues on every local
+        shard's batcher, waits for all of them under the request's one
+        deadline and merges once; no thread is held a local shard.
+        Remote shards keep the pool: a blocking HTTP call is what a pool
+        is for. The request is charged ONE ``queue_wait``, ``device`` and
+        ``transfer``, those of the local shard whose answer arrived last
+        (its critical path), and one that fanned out over several LOCAL
+        shards carries two request stages more (runtime/tailboard.py):
+        ``fanout_wait``, first enqueue to last delivery less those three,
+        and ``merge``."""
         query = np.asarray(query, dtype=np.float32)
         names = self._target_shard_names(tenant)
-
-        def one(name: str) -> list[SearchResult]:
-            if self._is_local(name):
-                shard = self._load_shard(name)
-                allow = None if allow_list_by_shard is None else \
-                    allow_list_by_shard.get(name)
-                if where is not None:
-                    fmask = shard.allow_mask(where)
-                    allow = fmask if allow is None else \
-                        self._and_masks(allow, fmask)
-                ids, dists = shard.vector_search(query, k, vec_name, allow)
-                out = []
-                for doc_id, dist in zip(ids.tolist(), dists.tolist()):
-                    uuid = shard._doc_to_uuid.get(doc_id)
-                    if uuid is not None:
-                        out.append(SearchResult(uuid=uuid, distance=dist,
-                                                shard=name))
-                return out
-            # remote shard: the owning node evaluates filters and resolves
-            # objects (reference: remote.SearchShard, index.go:1607);
-            # replica failover + degraded (partial) results on total loss
-            items = self._remote_search_degraded(
-                name, vector=query, k=k, vec_name=vec_name,
-                where=where.to_dict() if where is not None else None,
-                include_objects=include_objects)
-            if items is None:
-                return []
-            return [_remote_result(i, name) for i in items]
-
-        gathered = [one(names[0])] if len(names) == 1 else \
-            list(self._pool.map(tracing.propagate(one), names))
-
-        merged = self._merge_by_distance(gathered, k)
+        if len(names) == 1:
+            merged = self._merge_by_distance(
+                [self._near_vector_shard(names[0], query, k, vec_name,
+                                         allow_list_by_shard, where,
+                                         include_objects)], k)
+        else:
+            merged = self._fan_out(names, query, k, vec_name,
+                                   allow_list_by_shard, where,
+                                   include_objects)
         if max_distance is not None:
             merged = [r for r in merged if r.distance <= max_distance]
         if autocut > 0 and merged:
@@ -1348,6 +1358,103 @@ class Collection:
             merged = merged[: _autocut([r.distance for r in merged], autocut)]
         if include_objects:
             self._attach_objects(merged)
+        return merged
+
+    def _shard_allow(self, name: str, shard, allow_list_by_shard, where):
+        """A local shard's allow mask: the caller's list for it, ANDed
+        with the filter evaluated on that shard."""
+        allow = None if allow_list_by_shard is None else \
+            allow_list_by_shard.get(name)
+        if where is not None:
+            fmask = shard.allow_mask(where)
+            allow = fmask if allow is None else \
+                self._and_masks(allow, fmask)
+        return allow
+
+    def _near_vector_shard(self, name: str, query, k: int, vec_name: str,
+                           allow_list_by_shard, where,
+                           include_objects: bool) -> list[SearchResult]:
+        """One shard's nearVector in one blocking call: the whole of a
+        one-shard request, and a remote shard's part of a fan-out."""
+        if self._is_local(name):
+            shard = self._load_shard(name)
+            ids, dists = shard.vector_search(
+                query, k, vec_name,
+                self._shard_allow(name, shard, allow_list_by_shard, where))
+            out = []
+            for doc_id, dist in zip(ids.tolist(), dists.tolist()):
+                uuid = shard._doc_to_uuid.get(doc_id)
+                if uuid is not None:
+                    out.append(SearchResult(uuid=uuid, distance=dist,
+                                            shard=name))
+            return out
+        # remote shard: the owning node evaluates filters and resolves
+        # objects (reference: remote.SearchShard, index.go:1607);
+        # replica failover + degraded (partial) results on total loss
+        items = self._remote_search_degraded(
+            name, vector=query, k=k, vec_name=vec_name,
+            where=where.to_dict() if where is not None else None,
+            include_objects=include_objects)
+        if items is None:
+            return []
+        return [_remote_result(i, name) for i in items]
+
+    def _fan_out(self, names, query, k: int, vec_name: str,
+                 allow_list_by_shard, where,
+                 include_objects: bool) -> list[SearchResult]:
+        """``near_vector`` over several shards: remote shards go to the
+        pool as futures; every local shard is enqueued from the
+        request's own thread, all are waited for under the one deadline,
+        and everything is merged once."""
+        local = [n for n in names if self._is_local(n)]
+        remote = {n: self._pool.submit(
+            tracing.propagate(self._near_vector_shard), n, query, k,
+            vec_name, allow_list_by_shard, where, include_objects)
+            for n in names if n not in local}
+        shards = {n: self._load_shard(n) for n in local}
+        allows = {n: self._shard_allow(n, shard, allow_list_by_shard, where)
+                  for n, shard in shards.items()}
+        t_first = time.perf_counter()
+        searches: dict = {}
+        hits: dict = {}
+        try:
+            for n, shard in shards.items():
+                searches[n] = shard.vector_search_begin(query, k, vec_name,
+                                                        allows[n])
+            for search in searches.values():
+                search.wait()
+            # the critical path: the shard whose answer arrived last is
+            # the one this request is charged for, and is finished first
+            # (its ``wake`` runs from that delivery to now)
+            t_waited = time.perf_counter()
+            last = max(local, key=lambda n: searches[n].t_deliver,
+                       default=None)
+            for n in sorted(local, key=lambda n: n != last):
+                hits[n] = _ShardHits(n, shards[n], *shards[n]
+                                     .vector_search_end(searches[n],
+                                                        charge=n == last))
+        except BaseException:
+            # a shard that refused or raised, or the budget spent (the
+            # typed DeadlineExceeded, once): what is still queued
+            # elsewhere for this request leaves its queue, and every
+            # shard not yet finished closes its span
+            for search in searches.values():
+                search.discard()
+            raise
+        gathered = [hits[n] if n in hits else remote[n].result()
+                    for n in names]
+        t_merge = time.perf_counter()
+        merged = self._merge_by_distance(gathered, k)
+        if len(local) > 1:
+            # observed for a request that fanned out over local shards,
+            # and for no other (runtime/tailboard.py, point 5)
+            monitoring.fanout_shards_total.labels(self.config.name).inc(
+                len(local))
+            monitoring.fanout_width.observe(len(local))
+            t_last = searches[last].t_deliver or t_waited
+            tailboard.fanout(
+                (t_last - t_first) - sum(searches[last].phases()),
+                time.perf_counter() - t_merge)
         return merged
 
     @_timed("bm25")
@@ -1362,13 +1469,10 @@ class Collection:
         def one(name: str) -> list[SearchResult]:
             if self._is_local(name):
                 shard = self._load_shard(name)
-                allow = None if allow_list_by_shard is None else \
-                    allow_list_by_shard.get(name)
-                if where is not None:
-                    fmask = shard.allow_mask(where)
-                    allow = fmask if allow is None else \
-                        self._and_masks(allow, fmask)
-                ids, scores = shard.bm25_search(query, k, properties, allow)
+                ids, scores = shard.bm25_search(
+                    query, k, properties,
+                    self._shard_allow(name, shard, allow_list_by_shard,
+                                      where))
                 out = []
                 for doc_id, score in zip(ids.tolist(), scores.tolist()):
                     uuid = shard._doc_to_uuid.get(doc_id)

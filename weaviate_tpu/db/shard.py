@@ -138,6 +138,46 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
     raise ValueError(f"unknown index type {cfg.index_type}")
 
 
+class _Search:
+    """One shard search between its two halves
+    (``Shard.vector_search_begin`` / ``vector_search_end``)."""
+
+    __slots__ = ("k", "queued", "batcher", "item", "found", "span")
+
+    def __init__(self, k: int, queued, found=None):
+        self.k = k
+        self.queued = queued    # (ids, dists) of not-yet-indexed vectors
+        self.batcher = None     # the QueryBatcher that holds ``item``
+        self.item = None
+        self.found = found      # (ids, dists) where nothing was enqueued
+        self.span = None
+
+    def wait(self) -> None:
+        """Block until the shard's batcher has delivered, under the
+        request's deadline."""
+        if self.item is not None:
+            self.batcher.wait(self.item)
+
+    def discard(self) -> None:
+        """The request is over: an item still queued leaves its queue,
+        and a span that ``vector_search_end`` did not reach is closed
+        (the shard stays in the trace of a request that failed)."""
+        if self.item is not None:
+            self.batcher.discard(self.item)
+        span, self.span = self.span, None
+        tracing.close_span(span)
+
+    @property
+    def t_deliver(self) -> float:
+        """When the answer was there (0.0: at once, nothing enqueued)."""
+        return (self.item.t_deliver or 0.0) if self.item is not None \
+            else 0.0
+
+    def phases(self) -> tuple[float, float, float]:
+        return self.batcher.phases(self.item) if self.item is not None \
+            else (0.0, 0.0, 0.0)
+
+
 class Shard:
     def __init__(self, data_dir: str, collection: CollectionConfig, name: str,
                  mesh=None, memwatch=None, async_indexing: bool | None = None,
@@ -646,19 +686,6 @@ class Shard:
                     self._maybe_compress(vec_name, idx)
         return doc_ids
 
-    def _batched_search(self, vec_name: str, idx, query: np.ndarray, k: int,
-                        allow_list):
-        """Dynamic-batched single-query search: concurrent callers share
-        one device dispatch (VERDICT r1 item 6). Falls back to the direct
-        path for index types without a batch entry point."""
-        if getattr(idx, "search_by_vector_batch", None) is None:
-            return idx.search_by_vector(query, k, allow_list=allow_list)
-        b = self._query_batcher(vec_name, idx)
-        ids, dists = b.search(query, k, allow_list)
-        live = ids >= 0
-        return (np.asarray(ids)[live].astype(np.int64),
-                np.asarray(dists)[live].astype(np.float32))
-
     def _query_batcher(self, vec_name: str, idx):
         """The shard's per-vector-space QueryBatcher, built lazily (shared
         by the dense path and the hybridplane's fused dispatch)."""
@@ -848,23 +875,73 @@ class Shard:
             return np.empty(0, np.int64), np.empty(0, np.float32)
         with tracing.span("shard.vector_search", shard=self.name, k=k,
                           filtered=allow_list is not None):
-            return self._vector_search_traced(idx, query, k, vec_name,
-                                              allow_list)
+            return self._search_end(
+                self._search_begin(idx, query, k, vec_name, allow_list))
 
-    def _vector_search_traced(self, idx, query, k, vec_name, allow_list):
+    def vector_search_begin(self, query: np.ndarray, k: int,
+                            vec_name: str = "",
+                            allow_list: np.ndarray | None = None
+                            ) -> "_Search":
+        """First half of ``vector_search``, for a request that searches
+        several shards (``Collection.near_vector``): the snapshot of the
+        queued vectors and the enqueue on this shard's batcher. Returns
+        at once where the index has a batched entry point; the caller's
+        thread is held by no shard. ``vector_search_end`` gives what
+        ``vector_search`` gives."""
+        idx = self.vector_indexes.get(vec_name)
+        if idx is None:
+            return _Search(k, None, found=(np.empty(0, np.int64),
+                                           np.empty(0, np.float32)))
+        span = tracing.open_span("shard.vector_search", shard=self.name,
+                                 k=k, filtered=allow_list is not None)
+        search = tracing.run_in(span, self._search_begin, idx, query, k,
+                                vec_name, allow_list)
+        search.span = span
+        return search
+
+    def vector_search_end(self, search: "_Search", charge: bool = True):
+        """Second half: wait for the answer under the request's deadline
+        and merge the queued vectors in. ``charge``: whether this
+        search's queue_wait, device and transfer are the request's (a
+        fan-out charges the one on its critical path)."""
+        span, search.span = search.span, None
+        try:
+            return tracing.run_in(span, self._search_end, search, charge)
+        finally:
+            tracing.close_span(span)
+
+    def _search_begin(self, idx, query, k, vec_name, allow_list
+                      ) -> "_Search":
         # snapshot BEFORE the index search: every queued vector is either
         # in the snapshot or already drained into the index by the time
         # the index search runs — the union misses nothing (the reverse
         # order races a drain finishing between the two reads)
-        queued = self._queued_candidates(vec_name, query, allow_list)
-        if self.dynamic_batching and query.ndim == 1:
-            ids, dists = self._batched_search(vec_name, idx, query, k,
-                                              allow_list)
+        search = _Search(k, self._queued_candidates(vec_name, query,
+                                                    allow_list))
+        if self.dynamic_batching and query.ndim == 1 and getattr(
+                idx, "search_by_vector_batch", None) is not None:
+            # dynamic-batched single-query search: concurrent callers
+            # share one device dispatch (VERDICT r1 item 6)
+            search.batcher = self._query_batcher(vec_name, idx)
+            search.item = search.batcher.enqueue(query, k, allow_list)
         else:
-            ids, dists = idx.search_by_vector(query, k, allow_list=allow_list)
-        if queued is None:
+            # index types without a batch entry point: the direct path
+            search.found = idx.search_by_vector(query, k,
+                                                allow_list=allow_list)
+        return search
+
+    def _search_end(self, search: "_Search", charge: bool = True):
+        if search.item is not None:
+            ids, dists = search.batcher.finish(
+                search.batcher.wait(search.item), charge)
+            live = ids >= 0
+            ids, dists = (np.asarray(ids)[live].astype(np.int64),
+                          np.asarray(dists)[live].astype(np.float32))
+        else:
+            ids, dists = search.found
+        if search.queued is None:
             return ids, dists
-        q_ids, q_dists = queued
+        q_ids, q_dists = search.queued
         cat_ids = np.concatenate([np.asarray(ids, np.int64), q_ids])
         cat_d = np.concatenate([np.asarray(dists, np.float32), q_dists])
         order = np.argsort(cat_d, kind="stable")
@@ -879,7 +956,7 @@ class Shard:
             seen.add(did)
             out_ids.append(did)
             out_d.append(float(cat_d[j]))
-            if len(out_ids) == k:
+            if len(out_ids) == search.k:
                 break
         return (np.asarray(out_ids, np.int64),
                 np.asarray(out_d, np.float32))
@@ -912,7 +989,7 @@ class Shard:
         counts), or ``None`` when the index has no async path — the
         plane then falls back to the synchronous call. The queued-tail
         snapshot is taken BEFORE the index dispatch (same ordering
-        invariant as ``_vector_search_traced``) and merged in the
+        invariant as ``_search_begin``) and merged in the
         handle's host finish step."""
         idx = self.vector_indexes.get(vec_name)
         if idx is None:

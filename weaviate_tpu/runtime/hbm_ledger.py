@@ -38,6 +38,7 @@ import contextlib
 import contextvars
 import threading
 import weakref
+from collections import deque
 from dataclasses import dataclass
 
 _UNOWNED = {"collection": "_unowned", "shard": "-", "tenant": ""}
@@ -81,11 +82,16 @@ class HBMLedger:
     """Thread-safe allocation registry with running totals + peaks."""
 
     def __init__(self):
-        # RLock: weakref.finalize callbacks (track()) release entries and
-        # can fire from cyclic GC triggered by an allocation INSIDE a
-        # locked section on the same thread — a plain Lock would
-        # self-deadlock there
         self._lock = threading.RLock()
+        # keys whose owner was garbage-collected. A weakref.finalize
+        # callback can fire from a cyclic GC INSIDE any locked section
+        # of its thread: this ledger's, or a metric's child table (the
+        # gauge export under ``register`` sits in ``hbm_bytes.labels()``
+        # when the collector runs, and a release from there would take
+        # that plain Lock a second time). So a finalizer takes no lock:
+        # it queues the key, and the next call that holds ``_lock``
+        # releases it (``_release_dropped``).
+        self._dropped: deque[int] = deque()
         self._entries: dict[int, Entry] = {}
         self._next_key = 1
         self._device_total = 0
@@ -121,6 +127,7 @@ class HBMLedger:
             placement=placement,
         )
         with self._lock:
+            self._release_dropped()
             e.key = self._next_key
             self._next_key += 1
             self._entries[e.key] = e
@@ -130,6 +137,7 @@ class HBMLedger:
     def update(self, key: int, nbytes: int) -> None:
         """Resize an existing entry (capacity grow / shrink-on-compact)."""
         with self._lock:
+            self._release_dropped()
             e = self._entries.get(key)
             if e is None:
                 return
@@ -139,17 +147,19 @@ class HBMLedger:
 
     def release(self, key: int) -> None:
         with self._lock:
+            self._release_dropped()
             e = self._entries.pop(key, None)
             if e is None:
                 return
             self._apply_delta(e, -e.nbytes)
 
     def release_many(self, keys) -> None:
-        """Finalizer-friendly bulk release (missing keys are fine). Takes
-        the live list object so keys added after finalize() registration
-        are still honored."""
-        for k in list(keys):
-            self.release(k)
+        """Bulk release for a weakref finalizer (missing keys are fine):
+        queued, and applied by the ledger's next call. Takes the live
+        list object so keys added after finalize() registration are
+        still honored."""
+        self._dropped.extend(list(keys))
+
 
     def set_keyed(self, keys: dict, component: str, nbytes: int, *,
                   owner: dict | None = None, dtype=None,
@@ -181,13 +191,20 @@ class HBMLedger:
         key = self.register(component, nbytes,
                             dtype=getattr(array, "dtype", None), **labels)
         try:
-            weakref.finalize(array, self.release, key)
+            weakref.finalize(array, self._dropped.append, key)
         except TypeError:
             self.release(key)
             return None
         return key
 
     # -- internals ------------------------------------------------------------
+
+    def _release_dropped(self) -> None:
+        """Caller holds ``_lock``: what the finalizers queued goes."""
+        while self._dropped:
+            e = self._entries.pop(self._dropped.popleft(), None)
+            if e is not None:
+                self._apply_delta(e, -e.nbytes)
 
     def _apply_delta(self, e: Entry, delta: int) -> None:
         """Caller holds ``_lock``. Gauges are updated outside-in: the
@@ -237,18 +254,22 @@ class HBMLedger:
         """Live device bytes across every registration (the projection
         ``check_device_alloc`` uses when allocator stats are absent)."""
         with self._lock:
+            self._release_dropped()
             return self._device_total
 
     def peak_bytes(self) -> int:
         with self._lock:
+            self._release_dropped()
             return self._device_peak
 
     def collection_bytes(self, collection: str) -> int:
         with self._lock:
+            self._release_dropped()
             return self._by_collection.get(str(collection), 0)
 
     def shard_bytes(self, collection: str, shard: str) -> int:
         with self._lock:
+            self._release_dropped()
             return self._by_shard.get((str(collection), str(shard)), 0)
 
     def shard_component_bytes(self, collection: str, shard: str) -> dict:
@@ -258,6 +279,7 @@ class HBMLedger:
         bytes — and that compaction/migration actually released them."""
         collection, shard = str(collection), str(shard)
         with self._lock:
+            self._release_dropped()
             return {comp: b for (c, s, comp), b in self._by_gauge.items()
                     if c == collection and s == shard}
 
@@ -295,6 +317,7 @@ class HBMLedger:
         n_hosts = max(1, int(n_hosts))
         out = {f"host-{i}": 0 for i in range(n_hosts)}
         with self._lock:
+            self._release_dropped()
             entries = [(e.sharding, e.nbytes) for e in
                        self._entries.values() if e.placement == "device"]
         for sharding, nbytes in entries:
@@ -319,6 +342,7 @@ class HBMLedger:
         and component splits. Device placement only (host-tier entries —
         e.g. HNSW graph arrays — roll up under ``hostBytes``)."""
         with self._lock:
+            self._release_dropped()
             entries = list(self._entries.values())
         out: dict[str, dict] = {}
         for e in entries:
@@ -337,6 +361,7 @@ class HBMLedger:
     def top(self, n: int = 20) -> list[dict]:
         """Largest live allocations, for the debug endpoint."""
         with self._lock:
+            self._release_dropped()
             entries = sorted(self._entries.values(),
                              key=lambda e: e.nbytes, reverse=True)[:n]
         return [{
@@ -358,10 +383,12 @@ class HBMLedger:
     def reset(self) -> None:
         """Drop every entry (tests)."""
         with self._lock:
+            self._release_dropped()
             entries = list(self._entries)
         for k in entries:
             self.release(k)
         with self._lock:
+            self._release_dropped()
             self._device_peak = self._device_total
 
 

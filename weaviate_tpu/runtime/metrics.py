@@ -693,8 +693,21 @@ request_stage_seconds = registry.histogram(
     "from the same record the phases fold from: pool_wait, parse, "
     "filter, queue_wait, device, transfer, wake, fetch, search_other, "
     "reply, send (these sum to server_residency: RPC arrival to "
-    "termination) and handler_cpu (the handler thread's CPU time)",
+    "termination) and handler_cpu (the handler thread's CPU time). A "
+    "request that fanned out over several local shards is charged the "
+    "queue_wait, device and transfer of the shard that answered last "
+    "and observes two stages more, part of its sum: fanout_wait (first "
+    "enqueue to last delivery, less those three) and merge",
     ("operation", "stage"), buckets=_STAGE_BUCKETS)
+fanout_shards_total = registry.counter(
+    "weaviate_tpu_fanout_shards_total",
+    "Local shard searches enqueued by requests that fanned out over "
+    "more than one local shard (db/collection.py near_vector)",
+    ("collection",))
+fanout_width = registry.histogram(
+    "weaviate_tpu_fanout_width",
+    "Local shards searched by one fanned-out request", (),
+    buckets=(2, 4, 8, 16, 32, 64))
 dispatch_stage_seconds = registry.histogram(
     "weaviate_tpu_dispatch_stage_seconds",
     "Leaf-level stages of one batcher dispatch, stamped into its flight "

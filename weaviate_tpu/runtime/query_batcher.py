@@ -230,7 +230,7 @@ class QueryBatcher:
         self.overlapped_dispatches = 0
 
     def _ensure_worker(self):
-        """Caller holds ``_cv`` (search() enqueues under it)."""
+        """Caller holds ``_cv`` (enqueue() appends under it)."""
         if self._worker is None or not self._worker.is_alive():
             self._worker = threading.Thread(
                 target=self._run, name="query-batcher", daemon=True)
@@ -263,22 +263,29 @@ class QueryBatcher:
 
     def search(self, query: np.ndarray, k: int,
                allow: np.ndarray | None = None, sparse=None):
-        """Blocking per-request entry; coalesces under concurrency.
+        """Blocking per-request entry; coalesces under concurrency:
+        ``enqueue``, ``wait`` and ``finish``, one after the other. A
+        caller that searches several batchers for one request (a
+        collection's fan-out over its shards) calls the three itself."""
+        return self.finish(self.wait(self.enqueue(query, k, allow, sparse)))
+
+    def enqueue(self, query: np.ndarray, k: int,
+                allow: np.ndarray | None = None, sparse=None) -> _Pending:
+        """First half of ``search``: queue the request and return at
+        once.
 
         ``sparse`` (a packed ``ops/bm25.SparseOperand``) marks a hybrid
         request: it rides the coalesced dispatch the way allow lists do
         and the drain runs the fused sparse+dense device program.
 
         Deadline-aware: a request that arrives with its budget spent
-        fails typed BEFORE enqueueing, and the wait below is capped at
-        the remaining budget — a client can never hang past its
-        deadline on a wedged dispatch. Overload-aware: a full queue
+        fails typed BEFORE enqueueing. Overload-aware: a full queue
         sheds with a retriable OverloadedError instead of queueing
         latency the budget can't absorb."""
         retry.check("batcher")
         item = _Pending(np.asarray(query, dtype=np.float32), k, allow,
                         sparse)
-        t_enqueue = item.t_enqueue = time.perf_counter()
+        item.t_enqueue = time.perf_counter()
         with self._cv:
             if len(self._queue) >= self.max_queue:
                 raise retry.OverloadedError(
@@ -290,28 +297,76 @@ class QueryBatcher:
                              len(self._queue) + self._in_window)
             self._ensure_worker()
             self._cv.notify()
+        return item
+
+    def wait(self, item: _Pending) -> _Pending:
+        """Block until ``item`` is delivered, at most for what is left
+        of the request's budget: a client can never hang past its
+        deadline on a wedged dispatch. A budget that runs out drops the
+        item from the queue if it still waits there (a dispatch that
+        already carries it completes, its results discarded) and gives
+        THIS client the typed timeout now."""
         rem = retry.remaining()
         if rem is None:
             item.event.wait()
         elif not item.event.wait(timeout=min(rem, threading.TIMEOUT_MAX)):
-            # budget spent while queued/dispatched: the worker will
-            # still complete the batch (results discarded), but THIS
-            # client gets the typed timeout now
             from weaviate_tpu.runtime.metrics import deadline_exceeded_total
 
+            self.discard(item)
             deadline_exceeded_total.labels("batcher").inc()
             raise retry.DeadlineExceeded("batcher")
-        # Everything below is DERIVED from the record of the dispatch
-        # this request rode in (the worker and the drain thread stamped
-        # it; nothing is timed a second time here): the trace's spans,
-        # the always-on phases and the wake stage.
+        return item
+
+    def discard(self, item: _Pending) -> None:
+        """Take an abandoned request out of the queue, if it is still
+        there: nobody will read its answer."""
+        with self._cv:
+            try:
+                self._queue.remove(item)
+            except ValueError:
+                pass
+
+    @staticmethod
+    def phases(item: _Pending) -> tuple[float, float, float]:
+        """(queue_wait, device, transfer) seconds of a delivered request,
+        from the record of the dispatch it rode in. "device" is
+        kernelscope's attributed residency: the drain-thread stamp
+        window minus the sampled-memcpy EWMA (source=drain,
+        block_until_ready-free) or the dispatch wall window on
+        sync/null-device paths (source=wall); "transfer" is the memcpy
+        share. The plain wall split stays as the fallback for dispatches
+        that died before attribution."""
+        rec = item.rec
+        if rec is None:
+            return 0.0, 0.0, 0.0
+        st = rec["stamps"]
+        t_exec = st["exec"]
+        device_ms = rec.get("device_ms")
+        if device_ms is not None:
+            return (t_exec - item.t_enqueue, device_ms / 1000.0,
+                    rec["transfer_ms"] / 1000.0)
+        if "fetch0" in st:
+            return (t_exec - item.t_enqueue, st["fetch0"] - t_exec,
+                    st["fetch1"] - st["fetch0"])
+        return (t_exec - item.t_enqueue,
+                st["done"] - t_exec if "done" in st else 0.0, 0.0)
+
+    def finish(self, item: _Pending, charge: bool = True):
+        """Second half of ``search``, for a delivered ``item``: -> (ids,
+        dists), or the dispatch's error. Everything here is DERIVED from
+        the record of the dispatch this request rode in (the worker and
+        the drain thread stamped it; nothing is timed a second time):
+        the trace's spans and, with ``charge``, the always-on phases and
+        the wake stage. A fan-out charges one of its searches, the one
+        on its critical path (``Collection.near_vector``); the spans are
+        recorded for every one."""
         rec = item.rec
         if rec is not None:
             t_wake = time.perf_counter()
             st = rec["stamps"]
             t_exec = st["exec"]
             t_done = item.t_deliver or st.get("done") or t_wake
-            tracing.record_span("batcher.wait", t_enqueue, t_exec)
+            tracing.record_span("batcher.wait", item.t_enqueue, t_exec)
             if rec.get("filtered") and "assemble0" in st:
                 # a filtered dispatch's copy of its query rows and allow
                 # lists into the padded block — NOT the mask pack, which
@@ -327,30 +382,19 @@ class QueryBatcher:
                 # transfer thread's handle.result() window)
                 tracing.record_span("batcher.transfer", st["fetch0"],
                                     st["fetch1"])
-            # always-on phase attribution (tailboard), folded into this
-            # request's live timeline on the request thread. "device" is
-            # kernelscope's attributed residency: the drain-thread stamp
-            # window minus the sampled-memcpy EWMA (source=drain,
-            # block_until_ready-free) or the dispatch wall window on
-            # sync/null-device paths (source=wall); "transfer" is the
-            # memcpy share. The plain wall split stays as the fallback
-            # for dispatches that died before attribution.
-            tailboard.phase("queue_wait", t_exec - t_enqueue)
-            device_ms = rec.get("device_ms")
-            if device_ms is not None:
-                tailboard.phase("device", device_ms / 1000.0)
-                if rec["transfer_ms"] > 0:
-                    tailboard.phase("transfer", rec["transfer_ms"] / 1000.0)
-            elif "fetch0" in st:
-                tailboard.phase("device", st["fetch0"] - t_exec)
-                tailboard.phase("transfer", st["fetch1"] - st["fetch0"])
-            elif "done" in st:
-                tailboard.phase("device", st["done"] - t_exec)
-            if item.t_deliver is not None:
-                # event set -> this thread running again: with 32
-                # request threads on one interpreter lock this is where
-                # a woken waiter queues for it
-                tailboard.request_stage("wake", t_wake - item.t_deliver)
+            if charge:
+                # always-on phase attribution (tailboard), folded into
+                # this request's live timeline on the request thread
+                queue_wait, device, transfer = self.phases(item)
+                tailboard.phase("queue_wait", queue_wait)
+                tailboard.phase("device", device)
+                tailboard.phase("transfer", transfer)
+                if item.t_deliver is not None:
+                    # event set -> this thread running again: with 32
+                    # request threads on one interpreter lock this is
+                    # where a woken waiter queues for it
+                    tailboard.request_stage("wake",
+                                            t_wake - item.t_deliver)
         if item.explain is not None:
             # fold the dispatch's plan into the request-level explain
             # sink (installed by the REST/gRPC edge on THIS thread)

@@ -70,6 +70,17 @@ the hot path.
    reader that sums an event name's cover over threads would let an
    annotation on 32 request threads swallow every gap.
 
+   A request that searches several local shards (a collection's
+   fan-out, ISSUE 34) enqueues on every shard's batcher from its own
+   thread and waits for all of them. Its stages stay additive by the
+   **critical-path rule**: it is charged ONE ``queue_wait``, ``device``
+   and ``transfer``, those of the shard whose answer arrived last; what
+   passed between the first enqueue and that last delivery beyond those
+   three is ``fanout_wait``, and the merge of the shards' answers is
+   ``merge`` (:data:`FANOUT_STAGES`). The two are part of the sum for a
+   fanned-out request and are observed for such a request only: a
+   one-shard request makes the observations it always made.
+
 Env surface (all lazy-read, re-read after :func:`reset_for_tests`):
 
 - ``WEAVIATE_TPU_TAILBOARD``        1 (default) / 0 — timeline on/off
@@ -109,6 +120,9 @@ PHASES = ("queue_wait", "device", "transfer", "host")
 REQUEST_STAGES = ("pool_wait", "parse", "filter", "queue_wait", "device",
                   "transfer", "wake", "fetch", "search_other", "reply",
                   "send")
+#: two stages more of a request that fanned out over several local
+#: shards (:func:`fanout`), part of ITS sum and observed for it alone
+FANOUT_STAGES = ("fanout_wait", "merge")
 #: observed beside them, never part of the sum
 REQUEST_EXTRAS = ("handler_cpu", "server_residency")
 
@@ -484,6 +498,18 @@ def request_stage(name: str, seconds: float) -> None:
         tl.add_stage(name, seconds)
 
 
+def fanout(wait_s: float, merge_s: float) -> None:
+    """The two stages of a fan-out over several local shards, on the
+    live staged timeline: ``wait_s`` is first enqueue -> last delivery
+    less the charged shard's queue_wait, device and transfer; ``merge_s``
+    the merge of the shards' answers. Kept at zero too: their presence
+    is what marks the request as fanned out at the fold."""
+    tl = _timeline.get()
+    if tl is not None and tl.stages is not None:
+        tl.stages["fanout_wait"] = max(0.0, wait_s)
+        tl.stages["merge"] = max(0.0, merge_s)
+
+
 def annotate(collection: str | None = None, tenant: str | None = None) -> None:
     """Attach collection/tenant identity to the live timeline (no-op
     outside one)."""
@@ -751,8 +777,9 @@ def _stage_values(phases: dict, stages: dict) -> tuple:
     """One staged request -> a value per REQUEST_STAGES + REQUEST_EXTRAS
     entry, in their order, zero included, so the stages' means sum to
     the residency's. ``search_other`` is the collection call's wall time
-    less the stages and phases stamped inside it (tests/test_tailboard.py
-    holds every stage to its stated stamps)."""
+    less the stages and phases stamped inside it, a fan-out's two
+    included (tests/test_tailboard.py holds every stage to its stated
+    stamps)."""
     get, phase = stages.get, phases.get
     queue_wait, device, transfer = (phase("queue_wait", 0.0),
                                     phase("device", 0.0),
@@ -760,7 +787,8 @@ def _stage_values(phases: dict, stages: dict) -> tuple:
     filt, wake, fetch = get("filter", 0.0), get("wake", 0.0), \
         get("fetch", 0.0)
     other = max(0.0, get("search", 0.0) - (
-        filt + wake + fetch + queue_wait + device + transfer))
+        filt + wake + fetch + queue_wait + device + transfer
+        + get("fanout_wait", 0.0) + get("merge", 0.0)))
     return (get("pool_wait", 0.0), get("parse", 0.0), filt, queue_wait,
             device, transfer, wake, fetch, other, get("reply", 0.0),
             get("send", 0.0), get("handler_cpu", 0.0),
@@ -798,6 +826,7 @@ def flush() -> None:
         tenant_guard, coll_guard = _guards()
         slo_acc: dict[tuple, list[float]] = {}  # (obj, bucket) -> [g, b]
         staged: dict[str, list] = {}  # operation -> [[a value a stage]]
+        fanned: dict[str, list] = {}  # the same, FANOUT_STAGES alone
         for (_seq, operation, phases, duration_s, errored, collection,
              tenant, trace_id, bucket, stages) in found:
             host = duration_s - sum(phases.values())
@@ -816,6 +845,9 @@ def flush() -> None:
                 if stages is not None:
                     staged.setdefault(operation, []).append(
                         _stage_values(phases, stages))
+                    if "fanout_wait" in stages:
+                        fanned.setdefault(operation, []).append(
+                            (stages["fanout_wait"], stages["merge"]))
             except Exception:  # pragma: no cover — never fail a reader
                 pass
             for o in eng.objectives_for(operation):
@@ -825,11 +857,12 @@ def flush() -> None:
                     cell = slo_acc.setdefault((o, bucket), [0.0, 0.0])
                     cell[0 if verdict else 1] += 1.0
         try:
-            names = REQUEST_STAGES + REQUEST_EXTRAS
-            for operation, rows in staged.items():
-                _observe_columns("request_stage_seconds", {
-                    (operation, s): col
-                    for s, col in zip(names, zip(*rows))})
+            for names, by_op in ((REQUEST_STAGES + REQUEST_EXTRAS, staged),
+                                 (FANOUT_STAGES, fanned)):
+                for operation, rows in by_op.items():
+                    _observe_columns("request_stage_seconds", {
+                        (operation, s): col
+                        for s, col in zip(names, zip(*rows))})
         except Exception:  # pragma: no cover — never fail a reader
             pass
         for (o, bucket), (good, bad) in slo_acc.items():

@@ -439,6 +439,24 @@ def record_span(name: str, start_s: float, end_s: float, **attrs) -> None:
     _observe_metric(name, max(0.0, end_s - start_s))
 
 
+def open_span(name: str, **attrs):
+    """A span under the current one that is NOT made current, for work
+    whose two halves are separate calls with other such spans open
+    between them (a collection's fan-out over its shards): -> the
+    context ``run_in`` runs either half under and ``close_span`` ends;
+    None outside a trace."""
+    cur = _current.get()
+    if cur is None:
+        return None
+    tr, parent = cur
+    return tr, Span(tr.trace_id, parent.span_id, name, attrs, tr.now_ms())
+
+
+def close_span(ctx) -> None:
+    if ctx is not None:
+        _finish(*ctx)
+
+
 def is_active() -> bool:
     return _current.get() is not None
 
