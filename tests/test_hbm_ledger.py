@@ -157,10 +157,12 @@ def test_compress_swaps_attribution_without_leaking():
     idx.compress(quantization="bq")
     gc.collect()  # old store's finalizer releases its corpus entry
     after = led.collection_bytes("CompressCol")
-    # quantized codes replace the f32 corpus: attribution stays on the
-    # collection, the old corpus bytes are gone
+    # quantized codes and the float32 rescore rows (resident where they
+    # fit) replace the f32 corpus: attribution stays on the collection,
+    # the old corpus bytes are gone
     assert after > 0
-    expected = int(idx.store.codes.nbytes) + int(idx.store.valid.nbytes)
+    expected = (int(idx.store.codes.nbytes) + int(idx.store.valid.nbytes)
+                + int(idx.store.rescore_rows.nbytes))
     assert after == expected
     del idx
     gc.collect()

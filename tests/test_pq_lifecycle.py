@@ -257,7 +257,10 @@ def life(request, tmp_path_factory):
         out.codebook = quantizer_state(idx.store)
         out.codes = np.asarray(idx.store.codes).copy()
         out.slot_of = dict(idx._id_to_slot)
-        out.unit_rows = idx.store._host_vectors.copy()
+        # the float32 rows the rescore reads: resident on the device in a
+        # store a Server builds, with no host copy (tests/test_device_rescore)
+        assert idx.store._host_vectors is None
+        out.unit_rows = np.array(idx.store.rescore_rows)[:, :DIM]
         out.rescore_limit = idx.store.rescore_limit
     finally:
         srv.stop()
@@ -285,7 +288,10 @@ def test_before_the_limit_answers_are_exact(life):
 def test_the_store_is_swapped_once_the_limit_is_crossed(life):
     store = life.store_after
     assert isinstance(store, QuantizedVectorStore) and store.trained
-    assert store.rescore == "host"
+    # the float32 rows that rescore stay on the device, beside the codes
+    assert store.rescore_mode() == "fused"
+    assert store.rescore_rows.dtype == np.float32
+    assert store.rescore_rows.shape == (store.capacity, 128)  # whole lanes
     assert store.quantization == life.quantizer
     assert life.wire[life.quantizer]["enabled"] is True
     assert life.wire[life.quantizer]["trainingLimit"] == LIMIT

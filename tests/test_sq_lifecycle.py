@@ -273,7 +273,7 @@ def test_the_masked_scan_returns_allowed_live_rows_only(metric):
     live[gone] = False
     a, b = store.sq_quantizer[:2]
     plain = np.asarray(store.codes).view(np.uint8) ^ 0x80
-    unit = store._host_vectors
+    unit = np.array(store.rescore_rows)[:, :DIM]
     d_rows, i_rows = store.search(queries, K, allow_mask=masks)
     d_one, i_one = store.search(queries, K, allow_mask=masks[1])
     for r, q in enumerate(reference.prepare(queries, metric) if

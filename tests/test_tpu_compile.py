@@ -240,6 +240,67 @@ def test_sq_topk_at_960_dims(one_chip, masked, b):
     assert set(dots) <= {"s32"} and (dots or b == 1), dots
 
 
+@pytest.mark.parametrize("b", [1, 16, 32])
+@pytest.mark.parametrize("cell", ["cohere-bq-cosine", "deep-pq-cosine",
+                                  "gist-sq-l2"])
+def test_served_scans_with_the_rescore_tail(one_chip, monkeypatch, cell, b):
+    """The three compressed cells' programs as a ``Server`` runs them
+    (ISSUE 38): the scan ENDS with the gather of its candidates' float32
+    rows, the exact distances and the final top-k. The chip lays a
+    float32 [262144, 960] (and a [262144, 96]) out with the ROWS on the
+    lanes and a program that gathers rows from one copies ALL of it
+    first, every dispatch; rows as wide as whole lanes
+    (``engine/quantized.py _row_lanes``) arrive row-major: no copy of
+    them is left and the program's temporaries stay small."""
+    from weaviate_tpu.engine.quantized import _row_lanes
+    from weaviate_tpu.ops.bq import bq_topk
+    from weaviate_tpu.ops.pq import pq_topk
+    from weaviate_tpu.ops.sq import sq_topk
+
+    monkeypatch.setattr(pk, "recommended", lambda: True)
+    f32, chunk = jnp.float32, 8192
+    if cell == "cohere-bq-cosine":
+        rows, d, k, kc = 131072, 768, 100, 1600
+
+        def fn(q, codes, valid, rr):
+            return bq_topk(q[:, :24].astype(jnp.uint32), codes, k=kc,
+                           chunk_size=chunk, valid=valid, use_pallas=True,
+                           rescore_q=q, rescore_rows=rr, rescore_k=k,
+                           rescore_metric="cosine")
+
+        shapes = [((b, d), f32), ((rows, 24), jnp.uint32)]
+    elif cell == "deep-pq-cosine":
+        rows, d, k, kc = 262144, PQ_D, 10, 160
+
+        def fn(q, codes, cent, valid, rr):
+            return pq_topk(q, codes, cent, k=kc, chunk_size=chunk,
+                           metric="cosine", valid=valid, rescore_rows=rr,
+                           rescore_k=k)
+
+        shapes = [((b, d), f32), ((rows, PQ_M), jnp.uint8),
+                  ((PQ_M, PQ_K, 1), f32)]
+    else:
+        rows, d, k, kc = 262144, SQ_D, 10, 160
+
+        def fn(q, codes, terms, params, valid, rr):
+            return sq_topk(q, codes, terms, params, k=kc, chunk_size=chunk,
+                           metric="l2-squared", valid=valid,
+                           rescore_rows=rr, rescore_k=k)
+
+        shapes = [((b, d), f32), ((rows, d), jnp.int8),
+                  ((rows,), jnp.int32), ((3,), f32)]
+    lanes = _row_lanes(d)
+    c = _compile(fn, one_chip, *shapes, ((rows,), jnp.bool_),
+                 ((rows, lanes), f32))
+    text = c.as_text()
+    assert text.count(f"[{b},{k}]{{1,0") >= 2         # [b, k] goes back
+    assert f"f32[{rows},{lanes}]{{1,0" in text        # row-major, as it lies
+    assert not re.search(rf"= f32\[{rows},{lanes}\][^ ]* copy\(", text)
+    # the gathered candidates ([b, kc, lanes]) are the largest temporary
+    assert c.memory_analysis().temp_size_in_bytes < \
+        (64 << 20) + 2 * b * kc * lanes * 4
+
+
 def test_bq_mxu_block(one_chip):
     fn = functools.partial(pk.bq_mxu_block, interpret=False)
     _assert_kernel(_compile(fn, one_chip, ((64, 24), jnp.uint32),

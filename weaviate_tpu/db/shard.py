@@ -43,7 +43,7 @@ BUCKET_DOCID = "docid"  # uuid -> doc_id  (adapters/repos/db/docid)
 BUCKET_META = "meta"  # counters, checkpoints (indexcounter/)
 
 
-def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
+def _make_vector_index(vc: VectorConfig, dim: int, mesh=None, memwatch=None):
     cfg = vc.index
     if cfg.index_type == "noop":
         return None
@@ -55,6 +55,10 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
         capacity=8192,
         chunk_size=8192,
     )
+    # the watchdog goes down to whatever store a flat index builds, now
+    # or when it compresses: a compressed store asks it whether its
+    # float32 rescore rows may live on the device (engine/quantized.py)
+    flat = dict(common, memwatch=memwatch)
     # a pq or sq class starts on full rows and compresses once, at its
     # training limit (Shard._maybe_compress); bq needs no training and is
     # compressed from its first row
@@ -65,14 +69,14 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
             prefix_bits=cfg.prefix_bits,
             mesh=mesh,
             epoch_rows=cfg.epoch_rows,
-            **common,
+            **flat,
         )
     if cfg.index_type == "flat":
         return FlatIndex(
             mesh=mesh,
             dtype=jnp.bfloat16 if cfg.storage_dtype == "bfloat16" else jnp.float32,
             epoch_rows=cfg.epoch_rows,
-            **common,
+            **flat,
         )
     if cfg.index_type == "ivf":
         from weaviate_tpu.engine.ivf import IVFIndex
@@ -82,7 +86,7 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
             # the flat scan (documented fallback, not a silent drop)
             return FlatIndex(quantization="bq", mesh=mesh,
                              rescore_limit=cfg.rescore_limit,
-                             prefix_bits=cfg.prefix_bits, **common)
+                             prefix_bits=cfg.prefix_bits, **flat)
         # mesh forwarded so the single-replica guard fires loudly instead of
         # silently landing a sharded corpus on one device
         return IVFIndex(nlist=cfg.ivf_nlist, nprobe=cfg.ivf_nprobe,
@@ -101,7 +105,7 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
         if cfg.quantization == "bq":
             return FlatIndex(quantization="bq", mesh=mesh,
                              rescore_limit=cfg.rescore_limit,
-                             prefix_bits=cfg.prefix_bits, **common)
+                             prefix_bits=cfg.prefix_bits, **flat)
         from weaviate_tpu.engine.hnsw import HNSWIndex
 
         return HNSWIndex(
@@ -123,7 +127,7 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
                 rescore_limit=cfg.rescore_limit,
                 prefix_bits=cfg.prefix_bits,
                 mesh=mesh,
-                **common,
+                **flat,
             )
         return DynamicIndex(
             threshold=cfg.flat_to_ann_threshold, mesh=mesh,
@@ -133,7 +137,7 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None):
             # sq.trainingLimit, then the compressed scan (the IVF index
             # it would upgrade into has no sq form)
             upgradable=cfg.quantization != "sq",
-            **common,
+            **flat,
         )
     raise ValueError(f"unknown index type {cfg.index_type}")
 
@@ -379,7 +383,8 @@ class Shard:
 
         with hbm_ledger.owner(self.collection_name, self.name,
                               tenant=self._tenant_label()):
-            idx = _make_vector_index(vc, dim, mesh=self.mesh)
+            idx = _make_vector_index(vc, dim, mesh=self.mesh,
+                                     memwatch=self.memwatch)
         self.vector_indexes[vec_name] = idx
         self._register_drift_canary(vec_name)
         return idx

@@ -189,6 +189,11 @@ class EpochStore:
         self._lock = threading.RLock()
         self._owner = hbm_ledger.current_owner()
         self._codebook = self._quant_kwargs.pop("codebook", None)
+        if quantization:
+            # an epoch's store keeps its rescore rows on the host unless
+            # told otherwise: the one rescore of a dispatch is the finish
+            # step's, over every epoch's tier (_dispatch_quantized_locked)
+            self._quant_kwargs.setdefault("rescore", "host")
         self._next_slot = 0
         self._next_eid = 0
         self.compactions_total = 0
@@ -551,11 +556,12 @@ class EpochStore:
         rl = template.rescore_limit
         snaps = []  # (base, span, local_of, tiers, count) at dispatch
         parts, maps = [], []
-        # "plane" (single-device bf16 rows) degrades to "post" here: the
-        # merged candidates span per-epoch tier SNAPSHOTS, so the exact
-        # pass must route through the epoch-aware _vectors_for gather —
-        # the device plane has no cross-snapshot view
-        if mode == "plane":
+        # "fused" (single-device rows resident on the device) degrades to
+        # "post" here: the merged candidates span per-epoch tier
+        # SNAPSHOTS, so the exact pass must route through the epoch-aware
+        # _vectors_for gather — a scan's own tail has no cross-snapshot
+        # view
+        if mode == "fused":
             mode = "post"
         # both rescore modes need the oversampled candidate set — the
         # inline (in-SPMD) rescore sees k_cand code-distance candidates
@@ -575,7 +581,7 @@ class EpochStore:
                           else ep.local_of.copy(), tiers,
                           int(ep.store.count)))
         k_merge = k_cand if mode == "post" else k
-        # EXPLAIN: epoch fanout, merge shape and the (possibly plane->
+        # EXPLAIN: epoch fanout, merge shape and the (possibly fused->
         # post degraded) rescore mode of this dispatch — host ints only
         kernelscope.explain_note(
             "epochs", epochs=len(parts), merge_fanin=len(parts),

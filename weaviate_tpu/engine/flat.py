@@ -85,16 +85,21 @@ class FlatIndex:
     # compact() renumbers slots and counts here; compress() looks, to see
     # whether the rows it encoded outside the lock still lie where they did
     _compactions = 0
+    # runtime/memwatch.py MemoryMonitor (None: a store makes its own):
+    # handed to the quantized store this index builds, here or in
+    # compress(), which asks it where its rescore rows may live
+    memwatch = None
 
     def __init__(self, dim: int, metric: str = "l2-squared", mesh=None,
                  dtype=None, capacity: int = 8192, chunk_size: int = 8192,
                  quantization: str | None = None, store=None,
                  selection: str = "approx", epoch_rows: int = 0,
-                 **quant_kwargs):
+                 memwatch=None, **quant_kwargs):
         import jax.numpy as jnp
 
         self.dim = dim
         self.metric = metric
+        self.memwatch = memwatch
         if store is not None:
             # injected store (IVFIndex subclass passes an IVFStore; the
             # id<->slot bookkeeping below is store-agnostic)
@@ -118,7 +123,7 @@ class FlatIndex:
             self.store = QuantizedVectorStore(
                 dim=dim, metric=metric, quantization=quantization,
                 capacity=capacity, chunk_size=chunk_size, mesh=mesh,
-                selection=selection, **quant_kwargs,
+                selection=selection, memwatch=memwatch, **quant_kwargs,
             )
         else:
             if quant_kwargs:
@@ -594,7 +599,8 @@ class FlatIndex:
             return QuantizedVectorStore(
                 dim=self.dim, metric=self.metric, quantization=quantization,
                 capacity=old.capacity, chunk_size=old.chunk_size,
-                mesh=old.mesh, **quant_kwargs)
+                mesh=old.mesh, memwatch=self.memwatch,
+                **quant_kwargs)
 
     @staticmethod
     def _catch_up(new, then: dict, now: dict) -> int:
@@ -705,6 +711,7 @@ class FlatIndex:
         idx = cls.__new__(cls)
         idx.dim = snap["dim"]
         idx.metric = snap["metric"]
+        idx.memwatch = kwargs.pop("memwatch", None)
         if snap.get("epoch_rows"):
             from weaviate_tpu.engine.epochs import EpochStore
 
@@ -712,7 +719,8 @@ class FlatIndex:
         elif snap.get("quantization"):
             from weaviate_tpu.engine.quantized import QuantizedVectorStore
 
-            idx.store = QuantizedVectorStore.restore(snap, mesh=mesh, **kwargs)
+            idx.store = QuantizedVectorStore.restore(
+                snap, mesh=mesh, memwatch=idx.memwatch, **kwargs)
         else:
             idx.store = DeviceVectorStore.restore(snap, mesh=mesh, **kwargs)
         idx._lock = threading.RLock()

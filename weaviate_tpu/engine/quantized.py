@@ -12,31 +12,50 @@ Reference parity:
   composition is one SPMD program over a device mesh
   (parallel/sharded_search.py:sharded_quantized_topk).
 
-Memory layout: HBM holds only the codes ([C, m] uint8 for PQ — 16-64x
+Memory layout: HBM holds the codes ([C, m] uint8 for PQ — 16-64x
 smaller than f32; [C, w] uint32 sign-bits for BQ — 32x smaller; [C, d]
 int8 for SQ, one byte a dimension, 4x smaller, with ``row_terms`` [C]
 int32 beside them: what each row adds to the integer score of any scan,
 written once with its code) plus the valid mask; on a mesh PQ and BQ are
 row-sharded over the ``shard`` axis (SQ has no SPMD form yet and refuses
-a mesh). Three rescore modes pick where full-precision candidates come
-from:
+a mesh).
 
-- ``"host"``  (default): f32 rows in host RAM; the compressed scan returns
-  an oversampled candidate set and the exact rescore is a tiny host gather
-  + batched numpy distance. Right when host RAM >> HBM.
-- ``"device"``: bf16 rows row-sharded in HBM next to the codes; each device
-  rescores ITS OWN candidates inside the same SPMD program before the ICI
-  merge (owning-device rescore — vectors never cross the interconnect).
-  Costs 2 bytes/dim of HBM; the serving path never touches the host.
-- ``"none"``: codes only — the capacity regime (e.g. 100M x 768 BQ = 9.6 GB
-  across a mesh). Results are code-distance ordered unless ``fetch_fn``
-  (ids -> f32 rows, e.g. backed by the shard's LSM objects bucket) is
-  given, which re-enables exact rescore from durable storage.
+A store keeps ONE full-precision tier, the rows the exact rescore reads,
+and where it lives is decided by residency, not by a name:
+
+- **float32 rows in HBM beside the codes** (``rescore_rows`` [C, d
+  rounded up to whole 128-lane tiles: ``_row_lanes``]), wherever the memory watchdog grants them (``runtime/memwatch.py
+  device_fits``: the device budget and high watermark every import
+  passes): what a single-device store gets unless it is told otherwise,
+  so what every compressed class of a ``Server`` gets. The rescore is
+  then the LAST step of the scan's own program (``jit_bq_topk``,
+  ``jit_pq_topk``, ``jit_sq_topk``; ops/candidates.py ``rescore_tail``):
+  the candidates' rows are gathered, scored in float32 arithmetic
+  (``Precision.HIGHEST``) and cut to the request's k on the device, one
+  program a dispatch, [B, k] back to the host. No host copy is kept:
+  ``get`` and ``snapshot`` read the device rows.
+- **float32 rows in host RAM** (``_host_vectors``), where the watchdog
+  does not grant them, or from the grow that would pass its watermark on
+  (one D2H, once, logged; never promoted back): the scan returns its
+  oversampled candidates and the handle's finish step gathers and scores
+  them in numpy. Also what ``rescore="host"`` pins (the epoch store's
+  per-epoch stores, whose merged candidates span tier snapshots) and
+  what a mesh-sharded store defaults to.
+- ``rescore="device"`` on a MESH: bf16 rows row-sharded next to the
+  codes; each device rescores ITS OWN candidates inside the same SPMD
+  program before the ICI merge (owning-device rescore — vectors never
+  cross the interconnect). No cell and no ``Server`` reaches it yet
+  (``Server`` passes ``mesh=None``).
+- ``rescore="none"``: codes only — the capacity regime (e.g. 100M x 768
+  BQ = 9.6 GB across a mesh). Results are code-distance ordered unless
+  ``fetch_fn`` (ids -> f32 rows, e.g. backed by the shard's LSM objects
+  bucket) is given, which re-enables exact rescore from durable storage.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import threading
 import weakref
 
@@ -47,11 +66,14 @@ import numpy as np
 from weaviate_tpu.ops import bq as bq_ops
 from weaviate_tpu.ops import pq as pq_ops
 from weaviate_tpu.ops import sq as sq_ops
-from weaviate_tpu.ops.candidates import gather_rescore_topk
 from weaviate_tpu.ops.distances import normalize_np
 from weaviate_tpu.parallel.mesh import n_row_shards, shardable_capacity
 from weaviate_tpu.runtime import hbm_ledger, kernelscope, tracing
+from weaviate_tpu.runtime.memwatch import MemoryMonitor
+from weaviate_tpu.runtime.metrics import rescore_dispatch_total
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
+
+logger = logging.getLogger(__name__)
 
 _DEFAULT_CHUNK = 8192
 
@@ -87,8 +109,9 @@ def _scatter_sq_rows(codes, terms, valid, slots, rows, write_mask, params,
             valid.at[tgt].set(True, mode="drop"))
 
 
-# rows a piece of an SQ write: bounds the float32 block that goes up
-_SQ_WRITE_ROWS = 65536
+# rows a piece of a write that carries float32 rows to the device (SQ's
+# encode, the resident rescore rows): bounds the block that goes up
+_WRITE_ROWS = 65536
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -100,8 +123,24 @@ def _scatter_prefix(prefix_t, slots, new_cols, write_mask):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_rescore(rows, slots, new_rows, write_mask):
+    """``new_rows`` [m, dim] into the resident ``rows`` [C, >= dim] (the
+    float32 tier is as wide as whole lanes: ``_row_lanes``)."""
     tgt = jnp.where(write_mask, slots, rows.shape[0])
-    return rows.at[tgt].set(new_rows.astype(rows.dtype), mode="drop")
+    new_rows = jnp.pad(new_rows.astype(rows.dtype),
+                       ((0, 0), (0, rows.shape[1] - new_rows.shape[1])))
+    return rows.at[tgt].set(new_rows, mode="drop")
+
+
+def _row_lanes(dim: int) -> int:
+    """Width of the resident float32 rescore rows: ``dim`` rounded up to
+    whole 128-lane tiles, the rest zeros (they add nothing to a dot
+    product or a squared difference). The chip lays a float32 [rows,
+    960] or [rows, 96] out with the ROWS on the lanes, and a program
+    that gathers rows from it first copies ALL of it, row-major, every
+    dispatch (1 GB at 262,144 x 960: PERF.md, PR 32 and PR 38); a width
+    of whole tiles is kept row-major, and a row is one contiguous
+    read."""
+    return -(-dim // 128) * 128
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -121,6 +160,14 @@ class QuantizedVectorStore:
 
     On a mesh, codes (and bf16 rescore rows in ``rescore="device"`` mode)
     are row-sharded over the ``shard`` axis and every search runs SPMD.
+
+    ``rescore=None`` (what every caller but a test or the epoch store
+    leaves it at) is the residency rule of the module docstring: a
+    single-device store keeps float32 rows on the device where
+    ``memwatch`` (the process's ``MemoryMonitor``, handed down by the
+    shard; None: one that reads the backend's own limit) grants them
+    and on the host where not; a mesh-sharded one keeps them on the
+    host.
     """
 
     def __init__(
@@ -140,7 +187,7 @@ class QuantizedVectorStore:
         normalize_on_add: bool | None = None,
         codebook: pq_ops.PQCodebook | None = None,
         mesh=None,
-        rescore: str = "host",
+        rescore: str | None = None,
         fetch_fn=None,
         # BQ capacity regime: width (in bits, multiple of 128) of a
         # separately-stored transposed sign-bit prefix. Searches then run
@@ -157,6 +204,9 @@ class QuantizedVectorStore:
         # prefix/rescore_rows register as "codes@e3" etc. so per-epoch
         # device bytes are individually visible and individually released
         component_suffix: str = "",
+        # the gate device allocations pass (runtime/memwatch.py): decides
+        # whether the float32 rescore rows may live in HBM
+        memwatch: MemoryMonitor | None = None,
     ):
         if quantization not in ("pq", "bq", "sq"):
             raise ValueError(f"unknown quantization {quantization!r}")
@@ -179,6 +229,8 @@ class QuantizedVectorStore:
                 raise ValueError(
                     f"quantization='sq' sums in int32: dim {dim} is over "
                     f"{sq_ops.SQ_MAX_DIM}")
+        if rescore is None:
+            rescore = "device" if mesh is None else "host"
         if rescore not in ("host", "device", "none"):
             raise ValueError(f"unknown rescore mode {rescore!r}")
         if selection not in ("approx", "fused"):
@@ -200,6 +252,7 @@ class QuantizedVectorStore:
         self.chunk_size = chunk_size
         self.rescore_limit = rescore_limit
         self.rescore = rescore
+        self._memwatch = memwatch or MemoryMonitor()
         self.fetch_fn = fetch_fn
         self.selection = selection
         if pq_segments:
@@ -247,10 +300,6 @@ class QuantizedVectorStore:
                          self._hbm_keys.values())
         self.capacity = self._align(capacity)
         self._valid_np = np.zeros(self.capacity, dtype=bool)
-        self._host_vectors = (
-            np.zeros((self.capacity, dim), dtype=np.float32)
-            if rescore == "host" else None
-        )
         self._alloc_codes()
 
     # -- internals -----------------------------------------------------------
@@ -312,15 +361,55 @@ class QuantizedVectorStore:
             self.valid = self._placed(jnp.asarray(self._valid_np))
         else:
             self.valid = self._zeros((self.capacity,), jnp.bool_)
-        self.rescore_rows = (
-            self._zeros((self.capacity, self.dim), jnp.bfloat16)
-            if self.rescore == "device" else None
-        )
+        self._alloc_rows()
         self._hbm_sync()
+
+    def _rows_fit(self, capacity: int) -> bool:
+        """May float32 rescore rows of ``capacity`` slots be allocated on
+        the device? The watchdog's answer, on top of what is there now
+        (a grow holds the old rows until the new ones are written)."""
+        return self._memwatch.device_fits(
+            capacity * _row_lanes(self.dim) * 4)
+
+    def _alloc_rows(self):
+        """The full-precision tier, empty, at this capacity: on the
+        device where it is wanted and granted, else on the host. Caller
+        holds ``_lock`` (or is ``__init__``)."""
+        self.rescore_rows = self._host_vectors = None
+        if self.rescore == "device" and self.mesh is not None:
+            self.rescore_rows = self._zeros((self.capacity, self.dim),
+                                            jnp.bfloat16)
+        elif self.rescore == "device" and self._rows_fit(self.capacity):
+            self.rescore_rows = jnp.zeros(
+                (self.capacity, _row_lanes(self.dim)), jnp.float32)
+        elif self.rescore != "none":
+            if self.rescore == "device":
+                logger.warning(
+                    "%s store of %d x %d: no room on the device for its "
+                    "float32 rescore rows (%d bytes); they stay on the "
+                    "host", self.quantization, self.capacity, self.dim,
+                    self.capacity * _row_lanes(self.dim) * 4)
+            self._host_vectors = np.zeros((self.capacity, self.dim),
+                                          dtype=np.float32)
+
+    def _rows_to_host(self):
+        """The device tier becomes the host tier: one D2H, once (a grow
+        would pass the watchdog's watermark). Caller holds ``_lock``."""
+        logger.warning(
+            "%s store of %d x %d: growing its float32 rescore rows would "
+            "pass the device's high watermark; they move to the host",
+            self.quantization, self.capacity, self.dim)
+        self._host_vectors = self._device_rows_np()
+        self.rescore_rows = None
+
+    def _device_rows_np(self) -> np.ndarray:
+        """All of the device tier as float32 [capacity, dim] on the host."""
+        return np.array(
+            np.asarray(self.rescore_rows)[:, :self.dim], dtype=np.float32)
 
     def _hbm_sync(self):
         """Publish the device footprint per component: codes (+valid),
-        SQ's per-row terms, the transposed prefix, bf16 rescore rows, and
+        SQ's per-row terms, the transposed prefix, the rescore rows, and
         the PQ codebook."""
         sharding = "sharded" if self.mesh is not None else "single"
 
@@ -340,7 +429,8 @@ class QuantizedVectorStore:
              dtype="uint32")
         _set("rescore_rows",
              0 if self.rescore_rows is None
-             else int(self.rescore_rows.nbytes), dtype="bfloat16")
+             else int(self.rescore_rows.nbytes),
+             dtype="float32" if self.mesh is None else "bfloat16")
         _set("codebook",
              0 if self.codebook is None
              else int(np.asarray(self.codebook.centroids).nbytes),
@@ -398,20 +488,31 @@ class QuantizedVectorStore:
             self._hbm_sync()
 
     def _vectors_for(self, slots: np.ndarray) -> np.ndarray:
-        """Full-precision rows for given slots from whichever tier has them."""
-        return self._tier_vectors(self._host_vectors, self.rescore_rows,
-                                  self.fetch_fn, slots)
+        """Full-precision rows for given slots from whichever tier has
+        them (under ``_lock``: a write donates the device rows)."""
+        with self._lock:
+            return self._tier_vectors(*self._tiers(), slots)
+
+    def _tiers(self) -> tuple:
+        """The full-precision tiers as they stand (the finish step of a
+        dispatch keeps this snapshot). Caller holds ``_lock``."""
+        return (self._host_vectors, self.rescore_rows, self.fetch_fn,
+                self.dim)
 
     @staticmethod
-    def _tier_vectors(host_vectors, rescore_rows, fetch_fn,
+    def _tier_vectors(host_vectors, rescore_rows, fetch_fn, dim: int,
                       slots: np.ndarray) -> np.ndarray:
         """Tier pick shared by the live path (``_vectors_for``) and the
-        async finish step's dispatch-time snapshot."""
+        async finish step's dispatch-time snapshot (``_tiers``)."""
         if host_vectors is not None:
             return host_vectors[slots]
         if rescore_rows is not None:
-            return np.asarray(
-                rescore_rows[jnp.asarray(slots)], dtype=np.float32)
+            # one gather program a pow2 bucket of slots, not one a length
+            m = len(slots)
+            buf = np.zeros(_next_pow2(max(m, 8)), dtype=np.int32)
+            buf[:m] = slots
+            return np.asarray(rescore_rows[jnp.asarray(buf)],
+                              dtype=np.float32)[:m, :dim]
         if fetch_fn is not None:
             return np.asarray(fetch_fn(slots), dtype=np.float32)
         raise RuntimeError(
@@ -461,8 +562,9 @@ class QuantizedVectorStore:
 
     def _write_codes(self, slots: np.ndarray, codes: np.ndarray | None,
                      rows: np.ndarray | None, pref: np.ndarray | None = None):
-        """Scatter codes (and bf16 rescore rows) into the device arrays,
-        donated in place; padding to pow2 buckets bounds compiled variants."""
+        """Scatter codes (and the resident rescore rows) into the device
+        arrays, donated in place; padding to pow2 buckets bounds compiled
+        variants."""
         if (pref is None and rows is not None and self.prefix_words
                 and self.quantization == "pq" and codes is not None):
             # PQ prefix comes from the raw vectors' sign bits, not the
@@ -477,10 +579,14 @@ class QuantizedVectorStore:
         # SQ encodes on the device, inside the write: rows in, no codes
         sq_rows = (self.quantization == "sq" and codes is None
                    and rows is not None and self.trained)
-        if sq_rows and m > _SQ_WRITE_ROWS:
-            for s in range(0, m, _SQ_WRITE_ROWS):
-                self._write_codes(slots[s:s + _SQ_WRITE_ROWS], None,
-                                  rows[s:s + _SQ_WRITE_ROWS])
+        rows_up = rows is not None and (sq_rows
+                                        or self.rescore_rows is not None)
+        if rows_up and m > _WRITE_ROWS:
+            for s in range(0, m, _WRITE_ROWS):
+                e = s + _WRITE_ROWS
+                self._write_codes(
+                    slots[s:e], None if codes is None else codes[s:e],
+                    rows[s:e], None if pref is None else pref[s:e])
             return
         bucket = _next_pow2(max(m, 8))
         slot_buf = np.zeros(bucket, dtype=np.int32)
@@ -489,15 +595,20 @@ class QuantizedVectorStore:
         mask[:m] = True
         slot_dev = self._placed_replicated(slot_buf)
         mask_dev = self._placed_replicated(mask)
+        rows_dev = None
+        if rows_up:
+            # the float32 block goes up ONCE, for SQ's encode and the
+            # resident rescore rows alike
+            rbuf = rows            # an import batch fills its bucket
+            if m < bucket:
+                rbuf = np.zeros((bucket, self.dim), dtype=np.float32)
+                rbuf[:m] = rows
+            rows_dev = self._placed_replicated(rbuf)
         if sq_rows:
             with tracing.span("store.sq_encode", rows=m):
-                rbuf = rows        # an import batch fills its bucket
-                if m < bucket:
-                    rbuf = np.zeros((bucket, self.dim), dtype=np.float32)
-                    rbuf[:m] = rows
                 self.codes, self.row_terms, self.valid = _scatter_sq_rows(
                     self.codes, self.row_terms, self.valid, slot_dev,
-                    jnp.asarray(rbuf), mask_dev, self.sq_quantizer.params,
+                    rows_dev, mask_dev, self.sq_quantizer.params,
                     metric=self._scan_metric())
         elif codes is not None:
             w = self._code_width()
@@ -524,11 +635,8 @@ class QuantizedVectorStore:
             self.valid = _set_valid(self.codes, self.valid, slot_dev,
                                     mask_dev)
         if self.rescore_rows is not None and rows is not None:
-            rbuf = np.zeros((bucket, self.dim), dtype=np.float32)
-            rbuf[:m] = rows
             self.rescore_rows = _scatter_rescore(
-                self.rescore_rows, slot_dev,
-                self._placed_replicated(rbuf), mask_dev)
+                self.rescore_rows, slot_dev, rows_dev, mask_dev)
 
     def _grow(self, min_capacity: int):
         """Capacity-double codes/valid/mirrors. Caller holds ``_lock``."""
@@ -537,6 +645,9 @@ class QuantizedVectorStore:
             return
         old_cap = self.capacity
         pad = new_cap - old_cap
+        if (self.rescore_rows is not None and self.mesh is None
+                and not self._rows_fit(new_cap)):
+            self._rows_to_host()
         grown_m = np.zeros(new_cap, dtype=bool)
         grown_m[:old_cap] = self._valid_np
         self._valid_np = grown_m
@@ -593,20 +704,24 @@ class QuantizedVectorStore:
         return self._vectors_for(slots).copy()
 
     def _scan(self, queries_dev, k_cand: int, valid, k_out: int,
-              allow_bits=None, allow_rows=None):
+              allow_bits=None, allow_rows=None, rescore_rows=None):
         """Dispatch the compressed scan (single-device or SPMD).
 
         ``allow_bits`` ([B, C/32] uint32 packed per-query masks) feeds the
         single-device kernels; ``allow_rows`` ([B, C] bool, column-sharded)
-        feeds the SPMD path, which packs each shard's slice on device."""
+        feeds the SPMD path, which packs each shard's slice on device.
+        ``rescore_rows`` (single-device: the resident float32 tier) makes
+        the scan's program end with the exact rescore, cut to ``k_out``."""
         capacity = self.capacity
         cs = min(self.chunk_size, capacity // self.n_shards)
         metric = self._scan_metric()
+        tail = {} if rescore_rows is None else dict(
+            rescore_rows=rescore_rows, rescore_k=k_out)
         if self.quantization == "sq":
             return sq_ops.sq_topk(
                 queries_dev, self.codes, self.row_terms,
                 self.sq_quantizer.params, k=k_cand, chunk_size=cs,
-                metric=metric, valid=valid, allow_bits=allow_bits,
+                metric=metric, valid=valid, allow_bits=allow_bits, **tail,
             )
         if self.quantization == "pq":
             quant_key = "pq4" if self.pq_centroids <= 16 else "pq"
@@ -638,46 +753,46 @@ class QuantizedVectorStore:
                     k=k_cand, refine=max(2, self.rescore_limit // 2),
                     metric=metric, valid=valid, m=self.pq_segments,
                     use_pallas=self.use_pallas, selection=self.selection,
-                    allow_bits=allow_bits,
+                    allow_bits=allow_bits, **tail,
                 )
             if quant_key == "pq4":
                 return pq_ops.pq4_topk(
                     queries_dev, self.codes, cent, k=k_cand, chunk_size=cs,
                     metric=metric, valid=valid, selection=self.selection,
-                    allow_bits=allow_bits,
+                    allow_bits=allow_bits, **tail,
                 )
             return pq_ops.pq_topk(
                 queries_dev, self.codes, cent, k=k_cand, chunk_size=cs,
-                metric=metric, valid=valid, allow_bits=allow_bits,
+                metric=metric, valid=valid, allow_bits=allow_bits, **tail,
             )
+        if tail:    # hamming scans see code words: the queries ride along
+            tail.update(rescore_q=queries_dev, rescore_metric=metric)
         if self.prefix_t is not None:
             return bq_ops.bq_topk_twostage(
                 qw, self.codes, self.prefix_t, k=k_cand,
                 refine=max(2, self.rescore_limit // 2), valid=valid,
                 use_pallas=self.use_pallas, selection=self.selection,
-                allow_bits=allow_bits,
+                allow_bits=allow_bits, **tail,
             )
         return bq_ops.bq_topk(
             qw, self.codes, k=k_cand, chunk_size=cs, valid=valid,
             use_pallas=self.use_pallas, selection=self.selection,
-            allow_bits=allow_bits,
+            allow_bits=allow_bits, **tail,
         )
 
     def rescore_mode(self) -> str:
-        """Where the exact rescore happens for this store's config:
-        ``"inline"`` (inside the SPMD program, distances already exact),
-        ``"plane"`` (single-device bf16 rows: the oversampled candidates
-        rescore ON DEVICE through the shared candidate plane — the epoch
+        """Where the exact rescore happens for this store as it stands:
+        ``"fused"`` (single-device float32 rows resident: the LAST step
+        of the scan's own program, distances already exact — the epoch
         store treats this like ``"post"`` because its candidates span
-        per-epoch tier snapshots), ``"post"`` (oversampled candidates
-        come back for a host rescore), or ``"none"`` (code-distance
-        order is the contract)."""
-        if self.rescore == "device" and self.mesh is not None:
-            return "inline"
-        if self.rescore == "device" and self.rescore_rows is not None:
-            return "plane"
+        per-epoch tier snapshots), ``"inline"`` (inside the SPMD program
+        of a mesh, against each device's own bf16 rows), ``"post"``
+        (oversampled candidates come back for a host rescore: host
+        rows, or ``fetch_fn``), or ``"none"`` (code-distance order is
+        the contract)."""
+        if self.rescore_rows is not None:
+            return "fused" if self.mesh is None else "inline"
         if (self._host_vectors is not None
-                or (self.rescore == "device" and self.mesh is None)
                 or (self.rescore == "none" and self.fetch_fn is not None)):
             return "post"
         return "none"
@@ -722,7 +837,7 @@ class QuantizedVectorStore:
                               valid, min(k_out, capacity),
                               allow_bits=allow_bits,
                               allow_rows=allow_rows_dev)
-            tiers = (self._host_vectors, self.rescore_rows, self.fetch_fn)
+            tiers = self._tiers()
         return d, i, tiers
 
     def search(self, queries: np.ndarray, k: int, allow_mask: np.ndarray | None = None):
@@ -730,11 +845,12 @@ class QuantizedVectorStore:
 
         Reference BQ rescore: flat/index.go:347; oversampling factor =
         ``rescore_limit`` (*k candidates pulled from the compressed scan).
-        In ``rescore="device"`` mode the rescore happens inside the SPMD
-        program on the owning device; in ``"host"`` (or ``"none"`` +
-        ``fetch_fn``) the oversampled candidates come back to the host for
-        a vectorized exact rescore; plain ``"none"`` returns code-distance
-        order directly.
+        Where the full-precision rows are resident on the device the
+        rescore is the last step of the scan's program (on a mesh: inside
+        the SPMD program, on the owning device); where they are on the
+        host (or behind ``fetch_fn``) the oversampled candidates come back
+        for a vectorized exact rescore; plain ``"none"`` returns
+        code-distance order directly (``rescore_mode``).
 
         ``allow_mask`` accepts the same two forms as
         ``DeviceVectorStore.search``: a shared [capacity] bool mask, or
@@ -743,8 +859,8 @@ class QuantizedVectorStore:
         become rescore candidates).
 
         Like the plain store, this is ``search_async(...).result()`` —
-        the D2H transfer (and host rescore, which needs host
-        candidates) rides the handle's finish step.
+        the D2H transfer (and the host rescore, where the rows are on
+        the host) rides the handle's finish step.
         """
         return self.search_async(queries, k, allow_mask).result()
 
@@ -752,10 +868,11 @@ class QuantizedVectorStore:
                      allow_mask: np.ndarray | None = None
                      ) -> DeviceResultHandle:
         """Dispatch-only twin of ``search``: the compressed scan
-        launches under ``_lock``; the oversampled candidates stay
-        device-resident in the returned handle, whose finish step runs
-        the exact host rescore (when this store's rescore mode needs
-        one) after the boundary transfer."""
+        launches under ``_lock``; its result ([B, k] exact answers
+        where the rescore ran in the program, else the oversampled
+        candidates) stays device-resident in the returned handle, whose
+        finish step runs the exact host rescore (when this store's
+        rescore mode needs one) after the boundary transfer."""
         from weaviate_tpu.engine.store import (apply_allow_mask,
                                                normalize_allow_mask)
 
@@ -765,15 +882,6 @@ class QuantizedVectorStore:
             queries = queries[None, :]
         queries = self._maybe_norm(queries)
         allow_mask = normalize_allow_mask(allow_mask, len(queries))
-        # inline = exact rescore happens inside the SPMD program; post =
-        # oversampled candidates come back for a host-side exact pass
-        # (sourced from host rows, single-device HBM rows, or fetch_fn).
-        # ONE classifier (rescore_mode) serves this and the epoch-store
-        # dispatch so the two paths can never drift.
-        mode = self.rescore_mode()
-        inline_rescore = mode == "inline"
-        plane_rescore = mode == "plane"
-        post_rescore = mode == "post"
         with tracing.span("store.quantized_scan", rows=self.capacity,
                           queries=len(queries), k=k,
                           quantization=self.quantization,
@@ -783,6 +891,14 @@ class QuantizedVectorStore:
                     raise RuntimeError(
                         f"{self.quantization.upper()} store not trained; "
                         f"call train() first")
+                # fused / inline = the exact rescore happens inside the
+                # scan's program; post = oversampled candidates come back
+                # for a host-side exact pass (host rows or fetch_fn). ONE
+                # classifier (rescore_mode), read under the lock (a grow
+                # can move the rows to the host), serves this and the
+                # epoch-store dispatch so the two paths can never drift.
+                mode = self.rescore_mode()
+                post_rescore = mode == "post"
                 capacity = self.capacity
                 valid = self.valid
                 allow_bits = allow_rows_dev = None
@@ -798,15 +914,17 @@ class QuantizedVectorStore:
                     full = np.zeros(capacity, dtype=bool)
                     full[: len(allow_mask)] = allow_mask[:capacity]
                     valid = apply_allow_mask(valid, self._placed(full))
-                if inline_rescore:
-                    k_cand = min(max(k * self.rescore_limit, k), capacity)
-                    k_out = min(k, capacity)
-                elif post_rescore or plane_rescore:
-                    k_cand = min(max(k * self.rescore_limit, k), capacity)
-                    k_out = k_cand
+                if mode == "none":
+                    k_cand = k_out = min(k, capacity)
                 else:
-                    k_cand = min(k, capacity)
-                    k_out = k_cand
+                    # the scan oversamples; only a host rescore needs
+                    # the candidates themselves back
+                    k_cand = min(max(k * self.rescore_limit, k), capacity)
+                    k_out = k_cand if post_rescore else min(k, capacity)
+                    rescore_dispatch_total.labels(
+                        "host" if post_rescore else "device").inc()
+                    if not post_rescore:
+                        sp.set(path="device_rescore")
                 # EXPLAIN: host ints only (no device reads), a no-op
                 # when nobody asked — the rescore plan of this dispatch
                 kernelscope.explain_note(
@@ -816,27 +934,18 @@ class QuantizedVectorStore:
                     path=("bitmask_batched" if allow_bits is not None
                           else "shared_mask" if allow_mask is not None
                           else "full_scan"))
-                d, i = self._scan(jnp.asarray(queries), k_cand, valid,
-                                  k_out, allow_bits=allow_bits,
-                                  allow_rows=allow_rows_dev)
-                if plane_rescore:
-                    # oversampled candidates rescore ON DEVICE against
-                    # the bf16 rescore rows through the shared candidate
-                    # plane — the full-precision tier is already in HBM,
-                    # so the old host gather roundtrip buys nothing
-                    sp.set(path="device_plane_rescore")
-                    d, i = gather_rescore_topk(
-                        jnp.asarray(queries), i.astype(jnp.int32),
-                        self.rescore_rows, min(k, k_out),
-                        self._scan_metric())
+                d, i = self._scan(
+                    jnp.asarray(queries), k_cand, valid, k_out,
+                    allow_bits=allow_bits, allow_rows=allow_rows_dev,
+                    rescore_rows=(self.rescore_rows if mode == "fused"
+                                  else None))
                 # dispatch-time snapshot for the finish step's rescore:
                 # the scan's candidate slot-ids are only meaningful
                 # against THIS capacity/row layout — compact()/_grow()
                 # replace the full-precision tiers wholesale, and with
                 # the pipelined drain the dispatch->finish window is a
                 # whole overlapped batch, not microseconds
-                rescore_tiers = (self._host_vectors, self.rescore_rows,
-                                 self.fetch_fn)
+                rescore_tiers = self._tiers() if post_rescore else None
         # materialization + host rescore live in the handle's finish
         # step: the candidates cross D2H at the API boundary (or on the
         # serving pipeline's transfer thread), never under the lock
@@ -874,7 +983,7 @@ class QuantizedVectorStore:
         b, kc = cand_ids.shape
         cap = self.capacity if capacity is None else capacity
         safe = np.clip(cand_ids, 0, cap - 1)
-        # the tier pick (host rows -> device bf16 rows -> fetch_fn)
+        # the tier pick (host rows -> device rows -> fetch_fn)
         cand = ((vectors_for or self._vectors_for)(
             safe.reshape(-1))).reshape(b, kc, self.dim)
         metric = self._scan_metric()
@@ -919,9 +1028,6 @@ class QuantizedVectorStore:
             self._count = 0
             self.capacity = self._align(max(len(live), 1))
             self._valid_np = np.zeros(self.capacity, dtype=bool)
-            if self._host_vectors is not None:
-                self._host_vectors = np.zeros(
-                    (self.capacity, self.dim), dtype=np.float32)
             self._alloc_codes()
             if len(live):
                 self.set_at_prenormalized(np.arange(len(live)), vecs)
@@ -953,9 +1059,8 @@ class QuantizedVectorStore:
             }
             if self._host_vectors is not None:
                 snap["vectors"] = self._host_vectors.copy()
-            elif self.rescore == "device":
-                snap["vectors"] = np.asarray(
-                    self.rescore_rows, dtype=np.float32)
+            elif self.rescore_rows is not None:
+                snap["vectors"] = self._device_rows_np()
             else:
                 snap["codes"] = np.asarray(self.codes)
                 if self.prefix_t is not None and self.quantization == "pq":

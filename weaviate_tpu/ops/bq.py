@@ -52,7 +52,8 @@ def _auto_reduce_l(n: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk_size", "use_pallas",
-                                             "reduce_l", "selection"))
+                                             "reduce_l", "selection",
+                                             "rescore_k", "rescore_metric"))
 def bq_topk(
     q_words: jnp.ndarray,
     x_words: jnp.ndarray,
@@ -64,6 +65,10 @@ def bq_topk(
     reduce_l: int | None = None,
     selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
+    rescore_q: jnp.ndarray | None = None,
+    rescore_rows: jnp.ndarray | None = None,
+    rescore_k: int = 0,
+    rescore_metric: str = "cosine",
 ):
     """Hamming top-k over packed words: q [B, w] uint32, x [N, w] uint32.
 
@@ -95,10 +100,22 @@ def bq_topk(
     bitmask (pallas_kernels.pack_allow_bitmask layout): the pallas path
     unpacks it subtile-locally in VMEM, the XLA fallback unpacks once and
     folds a per-chunk where.
+
+    ``rescore_rows`` [N, >= d] float32 (a single-device store's resident
+    full-precision tier; ``id_offset`` 0) makes this program END with
+    the exact rescore (ops/candidates.py ``rescore_tail``): the k
+    candidates' rows are gathered, scored against the float32 queries
+    ``rescore_q`` [B, d] under ``rescore_metric`` and cut to
+    ``rescore_k``: ``(exact dists [B, rescore_k], ids)`` come back.
     """
+    from weaviate_tpu.ops.candidates import rescore_tail
     from weaviate_tpu.ops.distances import MASKED_DISTANCE
     from weaviate_tpu.ops.topk import topk_smallest
 
+    tail = functools.partial(
+        rescore_tail, q=rescore_q, rows=rescore_rows, k=rescore_k,
+        metric=rescore_metric,
+        valid=valid, allow_bits=allow_bits)
     n, w = x_words.shape
     b = q_words.shape[0]
 
@@ -109,7 +126,7 @@ def bq_topk(
         rl = reduce_l if reduce_l is not None else _auto_reduce_l(n)
         vals, ids = bq_scan_reduce(q_words, x_words, valid=valid,
                                    reduce_l=rl, allow_bits=allow_bits)
-        return select_survivors(vals, ids, k, selection, id_offset)
+        return tail(*select_survivors(vals, ids, k, selection, id_offset))
 
     allow_rows = None
     if allow_bits is not None:
@@ -177,11 +194,12 @@ def bq_topk(
             body, (init_d, init_i),
             (chunk_ids, x_chunks, valid_chunks, allow_chunks)
         )
-    return fd, fi
+    return tail(fd, fi)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "refine", "use_pallas",
-                                             "selection"))
+                                             "selection", "rescore_k",
+                                             "rescore_metric"))
 def bq_topk_twostage(
     q_words: jnp.ndarray,
     x_words: jnp.ndarray,
@@ -193,6 +211,10 @@ def bq_topk_twostage(
     use_pallas: bool = True,
     selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
+    rescore_q: jnp.ndarray | None = None,
+    rescore_rows: jnp.ndarray | None = None,
+    rescore_k: int = 0,
+    rescore_metric: str = "cosine",
 ):
     """Two-stage BQ scan for the capacity regime.
 
@@ -206,8 +228,10 @@ def bq_topk_twostage(
     stage 2 follows; the only approximation is stage-1 candidate recall
     (tunable via ``refine`` and the prefix width). ``selection="fused"``
     makes the stage-1 refine exact too (fused_topk_pairs instead of
-    approx_max_k, refine*k <= its 256-wide carry).
+    approx_max_k, refine*k <= its 256-wide carry). ``rescore_*``: the
+    exact rescore as the program's last step, as in ``bq_topk``.
     """
+    from weaviate_tpu.ops.candidates import rescore_tail
     from weaviate_tpu.ops.distances import MASKED_DISTANCE
     from weaviate_tpu.ops.topk import topk_smallest
 
@@ -254,7 +278,8 @@ def bq_topk_twostage(
                      constant_values=MASKED_DISTANCE)
         fi = jnp.pad(fi, ((0, 0), (0, k - kk)), constant_values=-1)
     fi = jnp.where(fd >= MASKED_DISTANCE * 0.5, -1, fi + id_offset)
-    return fd, fi
+    return rescore_tail(fd, fi, rescore_q, rescore_rows, rescore_k,
+                        rescore_metric, valid=valid, allow_bits=allow_bits)
 
 
 def bq_hamming_np(a_words: np.ndarray, b_words: np.ndarray) -> np.ndarray:

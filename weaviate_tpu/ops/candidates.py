@@ -13,6 +13,9 @@ top-k shape regardless of how many candidates are actually live:
   block-strided ``allow_bits`` format) fold per CANDIDATE via
   ``allow_bits_for_ids`` — a word gather per slot, never a dense
   ``[B, capacity]`` unpack.
+- ``rescore_tail`` — the same gather as the LAST step of a compressed
+  scan's own program (``bq_topk``, ``pq_topk``, ``sq_topk`` and their
+  kin), against the store's resident float32 rows.
 - ``shared_candidates_topk`` — ONE candidate set shared by the whole
   batch (the low-selectivity filter cutover in ``engine/store.py``):
   gather the bucket once ``[C, d]``, run the standard chunked scan over
@@ -79,19 +82,30 @@ def gather_rescore_topk(q, cand_idx, rows, k: int, metric: str, *,
     q32 = q.astype(jnp.float32)
     if metric in ("cosine", "cosine-dot"):
         q32 = normalize(q32)
-    dots = jnp.einsum("bd,bcd->bc", q32, g,
-                      preferred_element_type=jnp.float32)
-    if metric == "l2-squared":
-        if row_norms is not None:
-            g_norms = row_norms[safe].astype(jnp.float32)
-        else:
-            g_norms = jnp.sum(g * g, axis=-1)
-        q_norms = jnp.sum(q32 * q32, axis=-1, keepdims=True)
-        d = jnp.maximum(q_norms - 2.0 * dots + g_norms, 0.0)
-    elif metric == "dot":
-        d = -dots
-    else:  # cosine family: rows and q unit-norm -> distance 1 - cos
-        d = 1.0 - dots
+    if metric == "l2-squared" and row_norms is None:
+        # the rows are here: subtract, then square (as the host rescore
+        # does), so nothing cancels between two large norms
+        diff = q32[:, None, :] - g
+        d = jnp.sum(diff * diff, axis=-1)
+    else:
+        # float32 rows state float32 arithmetic: at Precision.DEFAULT the
+        # chip makes ONE bf16 pass over float32 operands (distances off
+        # by 1e-3 to 1e-2, which no CPU run shows); the flat scan asks
+        # the same of its float32 rows (ops/distances.py _dot_matrix)
+        dots = jnp.einsum(
+            "bd,bcd->bc", q32, g, preferred_element_type=jnp.float32,
+            precision=(jax.lax.Precision.HIGHEST
+                       if rows.dtype == jnp.float32
+                       else jax.lax.Precision.DEFAULT))
+        if metric == "l2-squared":
+            q_norms = jnp.sum(q32 * q32, axis=-1, keepdims=True)
+            d = jnp.maximum(
+                q_norms - 2.0 * dots + row_norms[safe].astype(jnp.float32),
+                0.0)
+        elif metric == "dot":
+            d = -dots
+        else:  # cosine family: rows and q unit-norm -> distance 1 - cos
+            d = 1.0 - dots
     if ids_of_row is not None:
         ids = jnp.where(live, ids_of_row[safe], -1)
     else:
@@ -103,6 +117,25 @@ def gather_rescore_topk(q, cand_idx, rows, k: int, metric: str, *,
         ok = ok & allow_bits_for_ids(allow_bits, ids)
     d = jnp.where(ok, d, MASKED_DISTANCE)
     return masked_candidate_topk(d, ids, min(k, c))
+
+
+def rescore_tail(fd, fi, q, rows, k: int, metric: str, *, valid=None,
+                 allow_bits=None):
+    """How a compressed scan's program ENDS where the store's float32
+    rows are resident (``rows`` [N, >= d]; None: the scan's own ``(fd,
+    fi)`` go back as they are, for a rescore on the host or none): the
+    scan's candidates ``fi`` [B, k_cand] (-1 = none) are gathered,
+    scored exactly against ``q`` [B, d] and cut to the request's ``k``,
+    so one program a dispatch runs and [B, k] crosses to the host.
+    ``valid`` / ``allow_bits`` are the scan's own masks: a candidate
+    the scan kept only to fill k_cand (fewer live or allowed rows than
+    that) stays out of the answer."""
+    if rows is None:
+        return fd, fi
+    # the resident rows are as wide as whole lanes, zeros past d
+    q = jnp.pad(q, ((0, 0), (0, rows.shape[1] - q.shape[1])))
+    return gather_rescore_topk(q, fi.astype(jnp.int32), rows, k, metric,
+                               valid=valid, allow_bits=allow_bits)
 
 
 def shared_candidates_topk(q, cand_slots, rows, k: int, metric: str, *,

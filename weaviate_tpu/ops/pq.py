@@ -183,7 +183,7 @@ def pq_reconstruct(codes: jnp.ndarray, centroids: jnp.ndarray, m: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("k", "chunk_size", "metric", "m")
+    jax.jit, static_argnames=("k", "chunk_size", "metric", "m", "rescore_k")
 )
 def pq_topk(
     q: jnp.ndarray,
@@ -196,6 +196,8 @@ def pq_topk(
     id_offset: jnp.ndarray | int = 0,
     m: int | None = None,
     allow_bits: jnp.ndarray | None = None,
+    rescore_rows: jnp.ndarray | None = None,
+    rescore_k: int = 0,
 ):
     """Compressed brute-force top-k: scan codes in chunks, look each chunk's
     codes up (``_rows_from_codes``: exact), score the rows.
@@ -203,8 +205,13 @@ def pq_topk(
     Matches LUT-ADC results exactly for l2-squared/dot/cosine (orthogonal
     segments). Returns (dists [B,k], ids [B,k]) like chunked_topk.
     ``allow_bits`` adds a per-query packed allow bitmask, unpacked once
-    and folded per chunk like the shared ``valid``.
+    and folded per chunk like the shared ``valid``. ``rescore_rows``
+    [N, >= d] float32 (a single-device store's resident full-precision
+    tier; ``id_offset`` 0) makes the program END with the exact rescore
+    of its k candidates against ``q``, cut to ``rescore_k``
+    (ops/candidates.py ``rescore_tail``).
     """
+    from weaviate_tpu.ops.candidates import rescore_tail
     from weaviate_tpu.ops.distances import MASKED_DISTANCE, pairwise_distance
     from weaviate_tpu.ops.topk import approx_topk_smallest, topk_smallest
 
@@ -267,7 +274,8 @@ def pq_topk(
             body, (init_d, init_i),
             (chunk_ids, code_chunks, valid_chunks, allow_chunks)
         )
-    return fd, fi
+    return rescore_tail(fd, fi, q, rescore_rows, rescore_k, metric,
+                        valid=valid, allow_bits=allow_bits)
 
 
 # -- 4-bit PQ (k<=16): ADC as one MXU matmul per tile ------------------------
@@ -323,7 +331,7 @@ def pq_lut(q: jnp.ndarray, centroids: jnp.ndarray, metric: str, m: int):
 @functools.partial(jax.jit, static_argnames=("k", "refine", "metric", "m",
                                              "use_pallas",
                                              "chunk_budget_bytes",
-                                             "selection"))
+                                             "selection", "rescore_k"))
 def pq_topk_twostage(
     q: jnp.ndarray,
     q_prefix_words: jnp.ndarray,
@@ -340,6 +348,8 @@ def pq_topk_twostage(
     chunk_budget_bytes: int = 128 << 20,
     selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
+    rescore_rows: jnp.ndarray | None = None,
+    rescore_k: int = 0,
 ):
     """Two-stage PQ scan (the r4 verdict's "extend the prefix idea to PQ").
 
@@ -359,6 +369,7 @@ def pq_topk_twostage(
     per query.
     """
     from weaviate_tpu.ops import bq as bq_ops
+    from weaviate_tpu.ops.candidates import rescore_tail
     from weaviate_tpu.ops.distances import MASKED_DISTANCE
     from weaviate_tpu.ops.topk import topk_smallest
 
@@ -439,11 +450,13 @@ def pq_topk_twostage(
                      constant_values=MASKED_DISTANCE)
         fi = jnp.pad(fi, ((0, 0), (0, k - kk)), constant_values=-1)
     fi = jnp.where(fd >= MASKED_DISTANCE * 0.5, -1, fi + id_offset)
-    return fd, fi
+    return rescore_tail(fd, fi, q, rescore_rows, rescore_k, metric,
+                        valid=valid, allow_bits=allow_bits)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk_size", "metric", "m",
-                                             "reduce_l", "selection"))
+                                             "reduce_l", "selection",
+                                             "rescore_k"))
 def pq4_topk(
     q: jnp.ndarray,
     codes: jnp.ndarray,
@@ -457,6 +470,8 @@ def pq4_topk(
     reduce_l: int | None = None,
     selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
+    rescore_rows: jnp.ndarray | None = None,
+    rescore_k: int = 0,
 ):
     """Compressed brute-force top-k over 4-bit codes via the fused ADC scan
     kernel (pallas_kernels.pq4_scan_reduce: per-query int8 LUT, one-hot
@@ -465,9 +480,10 @@ def pq4_topk(
     ``selection="approx"`` (default) runs one approx_max_k over the
     survivors; ``"fused"`` folds them through the exact in-kernel
     running-carry top-k (pallas_kernels.fused_topk_pairs) instead. Same
-    contract as pq_topk; ``chunk_size`` is accepted for API
-    compatibility."""
+    contract as pq_topk (``rescore_rows`` / ``rescore_k`` too);
+    ``chunk_size`` is accepted for API compatibility."""
     from weaviate_tpu.ops.bq import _auto_reduce_l
+    from weaviate_tpu.ops.candidates import rescore_tail
     from weaviate_tpu.ops.pallas_kernels import pq4_scan_reduce
 
     m = m or centroids.shape[0]
@@ -478,4 +494,6 @@ def pq4_topk(
                                 allow_bits=allow_bits)
     from weaviate_tpu.ops.topk import select_survivors
 
-    return select_survivors(vals, ids, k, selection, id_offset)
+    return rescore_tail(
+        *select_survivors(vals, ids, k, selection, id_offset), q,
+        rescore_rows, rescore_k, metric, valid=valid, allow_bits=allow_bits)

@@ -136,7 +136,8 @@ def sq_encode(x: jnp.ndarray, params: jnp.ndarray, metric: str = "l2-squared"):
     return codes, sq_row_terms(codes, metric)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "chunk_size", "metric"))
+@functools.partial(jax.jit, static_argnames=("k", "chunk_size", "metric",
+                                             "rescore_k"))
 def sq_topk(
     q: jnp.ndarray,
     codes: jnp.ndarray,
@@ -148,13 +149,17 @@ def sq_topk(
     valid: jnp.ndarray | None = None,
     id_offset: jnp.ndarray | int = 0,
     allow_bits: jnp.ndarray | None = None,
+    rescore_rows: jnp.ndarray | None = None,
+    rescore_k: int = 0,
 ):
     """Compressed brute-force top-k over one-byte codes: the float32
     queries [B, d] are encoded in the program, then the codes [N, d] int8
     are scanned in chunks, each an int8 x int8 -> int32 matmul plus the
     rows' resident terms. Returns (dists [B, k] f32, ids [B, k]) like
     ``pq_topk``; ``valid`` and ``allow_bits`` mask as they do there, and
-    so do the selection a chunk and the exact merge."""
+    so do the selection a chunk, the exact merge, and the exact rescore
+    that ends the program where ``rescore_rows`` are given."""
+    from weaviate_tpu.ops.candidates import rescore_tail
     from weaviate_tpu.ops.distances import MASKED_DISTANCE
     from weaviate_tpu.ops.topk import approx_topk_smallest, topk_smallest
 
@@ -208,4 +213,5 @@ def sq_topk(
 
     (fd, fi), _ = jax.lax.scan(body, (init_d, init_i),
                                jnp.arange(num_chunks, dtype=jnp.int32))
-    return fd, fi
+    return rescore_tail(fd, fi, q, rescore_rows, rescore_k, metric,
+                        valid=valid, allow_bits=allow_bits)

@@ -148,15 +148,9 @@ class MemoryMonitor:
         semantics: refuse BEFORE allocating, don't OOM mid-import).
         Hysteresis: once tripped, keeps refusing until usage falls under
         the low watermark."""
-        # one stats probe serves budget + usage;
-        # the explicit-limit fast path skips it entirely
-        stats = None if self.device_limit is not None \
-            else device_memory_stats()
-        budget = self.device_budget(stats)
+        budget, in_use = self._device_budget_and_use()
         if budget is None:
             return
-        in_use = self.device_in_use() if stats is None \
-            else self.device_in_use(stats)
         source = getattr(self, "_last_source", "ledger")
         projected = in_use + int(nbytes)
         high = budget * self.high_watermark
@@ -178,6 +172,30 @@ class MemoryMonitor:
                 f"{self.high_watermark:.0%} of HBM budget {budget} "
                 f"({source} usage {in_use})",
                 projected=projected, budget=budget, source=source)
+
+    def device_fits(self, nbytes: int) -> bool:
+        """Would ``nbytes`` more on the device stay under the high
+        watermark? ``check_device_alloc``'s rule as a question: nothing
+        is raised, latched or counted. For a caller that has somewhere
+        else to put the bytes (a compressed store's float32 rescore
+        rows: the host), where a refused import has not. No budget (a
+        backend without allocator stats and no configured limit): yes."""
+        budget, in_use = self._device_budget_and_use()
+        return budget is None or (
+            not self.under_pressure
+            and in_use + int(nbytes) <= budget * self.high_watermark)
+
+    def _device_budget_and_use(self) -> tuple[int | None, int]:
+        """(budget, bytes in use) from ONE stats probe; the
+        explicit-limit fast path skips the probe for the budget. No
+        budget: ``(None, 0)``."""
+        stats = None if self.device_limit is not None \
+            else device_memory_stats()
+        budget = self.device_budget(stats)
+        if budget is None:
+            return None, 0
+        return budget, (self.device_in_use() if stats is None
+                        else self.device_in_use(stats))
 
     @staticmethod
     def _pressure_event(action: str, projected: int, budget: int,

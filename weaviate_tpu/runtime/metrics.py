@@ -516,6 +516,13 @@ allow_translate_total = registry.counter(
     "(engine/flat.py _allow_mask), by the form the input took: mask = a "
     "bool mask over doc ids, one gather through the slot table; ids = an "
     "array of doc ids, sorted and binary-searched", ("form",))
+rescore_dispatch_total = registry.counter(
+    "weaviate_tpu_rescore_dispatch_total",
+    "Compressed-store search dispatches that rescore exactly, by where "
+    "the float32 rows they read live (engine/quantized.py): device = "
+    "resident in HBM, the rescore is the last step of the scan's own "
+    "program; host = in host RAM (or behind fetch_fn), the drain thread "
+    "gathers and scores the candidates in numpy", ("tier",))
 batcher_compile_bucket = registry.counter(
     "weaviate_tpu_query_batcher_compile_bucket_total",
     "Coalesced dispatches by padded pow2 (batch, k) bucket — the bucket "
