@@ -635,7 +635,10 @@ def test_stages_from_stated_stamps_and_phases_unchanged(monkeypatch):
             "queue_wait": 0.001, "device": 0.004, "transfer": 0.0,
             "wake": 0.0005, "fetch": 0.001,
             "search_other": 0.009 - 0.007, "reply": 0.001, "send": 0.002,
-            "handler_cpu": 0.0015, "server_residency": 0.015}
+            "handler_cpu": 0.0015, "server_residency": 0.015,
+            # the handler's wall (0.015 less pool_wait and send) less the
+            # waits it was meant to make (0.005) less its CPU
+            "off_cpu": 0.011 - 0.005 - 0.0015}
     assert got == pytest.approx(want, abs=1e-9)
     assert _stage("op.plain", "parse").count == 0   # unstaged: no stages
     assert _stage("op.staged", "fanout_wait").count == 0

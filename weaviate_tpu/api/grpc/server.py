@@ -340,7 +340,9 @@ class GrpcServer:
         # the interceptor costs nothing the benchmark can see (cell 1
         # with and without it, PERF.md PR 25), so it carries no switch
         self._server = grpc.server(
-            ThreadPoolExecutor(max_workers=self._max_workers),
+            # named for the thread account (tailboard.thread_role)
+            ThreadPoolExecutor(max_workers=self._max_workers,
+                               thread_name_prefix="grpc-pool"),
             interceptors=(_ArrivalInterceptor(),))
         self._server.add_generic_rpc_handlers(
             (grpc.method_handlers_generic_handler(_SERVICE, method_handlers),))

@@ -90,7 +90,10 @@ class ServerConfig:
     # timeline can be disabled wholesale (bench A/B, emergencies); SLO
     # objectives are a JSON list (WEAVIATE_TPU_SLO) overriding the
     # built-in availability/latency defaults — see runtime/tailboard.py
-    tailboard_enabled: bool = True
+    # None = WEAVIATE_TPU_TAILBOARD decides (default on), so a config
+    # built in code (an embedding program, the benchmark's server) still
+    # answers to the flag
+    tailboard_enabled: bool | None = None
     slo_config: str = ""
     profiling_port: int = 0  # 0 = profiler server off (PROFILING_PORT)
     # kernelscope: how many /v1/debug/profile?ms=N captures to keep

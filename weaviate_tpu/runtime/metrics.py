@@ -502,10 +502,6 @@ replica_divergent_entries = registry.gauge(
 
 # -- dynamic query batching ---------------------------------------------------
 
-batcher_batch_size = registry.histogram(
-    "weaviate_tpu_query_batcher_batch_size",
-    "Queries coalesced per device dispatch", (),
-    buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
 batcher_filtered_batched = registry.counter(
     "weaviate_tpu_query_batcher_filtered_batched_total",
     "Filtered requests served inside a coalesced bitmask-batched "
@@ -527,10 +523,6 @@ batcher_compile_bucket = registry.counter(
     "weaviate_tpu_query_batcher_compile_bucket_total",
     "Coalesced dispatches by padded pow2 (batch, k) bucket — the bucket "
     "set bounds the number of compiled program variants", ("b", "k"))
-batcher_async_dispatched = registry.counter(
-    "weaviate_tpu_query_batcher_async_dispatched_total",
-    "Coalesced drains dispatched through the zero-sync pipeline: "
-    "results stay device-resident and drain D2H on the transfer thread")
 batcher_overlapped = registry.counter(
     "weaviate_tpu_query_batcher_overlapped_total",
     "Dispatches launched while a previous batch was still draining "
@@ -700,7 +692,10 @@ request_stage_seconds = registry.histogram(
     "from the same record the phases fold from: pool_wait, parse, "
     "filter, queue_wait, device, transfer, wake, fetch, search_other, "
     "reply, send (these sum to server_residency: RPC arrival to "
-    "termination) and handler_cpu (the handler thread's CPU time). A "
+    "termination), handler_cpu (the handler thread's CPU time) and "
+    "off_cpu (the handler's wall time less queue_wait, device and "
+    "transfer, the waits it was meant to make, less handler_cpu: the "
+    "time its thread was meant to run and did not). A "
     "request that fanned out over several local shards is charged the "
     "queue_wait, device and transfer of the shard that answered last "
     "and observes two stages more, part of its sum: fanout_wait (first "
@@ -726,6 +721,60 @@ dispatch_stage_seconds = registry.histogram(
     "a solo filtered dispatch). The same stages are "
     "jax.profiler.TraceAnnotation(\"wtpu.<stage>\") events in a trace",
     ("kind", "stage"), buckets=_STAGE_BUCKETS)
+dispatch_stage_cpu_seconds = registry.histogram(
+    "weaviate_tpu_dispatch_stage_cpu_seconds",
+    "CPU time (time.thread_time) the dispatch thread had inside each "
+    "stage of weaviate_tpu_dispatch_stage_seconds, from a stamp taken "
+    "beside each wall stamp on every fourth dispatch of a thread (the "
+    "clock is a system call; scale the sum by the wall family's count "
+    "over this one's) and observed with it: wall less CPU is the "
+    "time the thread was inside the stage and not running (a device or "
+    "condition wait where the stage waits by design; the interpreter "
+    "lock or a core where it does not). Where the kernel moves a "
+    "thread's CPU clock in ticks one observation is 0 or a whole tick: "
+    "read the sums. Absent with the tailboard off",
+    ("kind", "stage"), buckets=_STAGE_BUCKETS)
+thread_cpu_seconds_total = registry.counter(
+    "weaviate_tpu_thread_cpu_seconds_total",
+    "CPU time of this process's threads by role, read from "
+    "/proc/self/task at the scrape (nothing on a request's path): "
+    "grpc_serve (gRPC's one Python serving thread), grpc_pool (the "
+    "handlers' pool), batcher_worker, batcher_drain, cyclemanager, rest, "
+    "python_other; native threads by comm: grpc_core, device_runtime, "
+    "native_other; exited is the process's total beyond its live "
+    "threads and beyond what threads that have gone were charged while "
+    "they lived (a thread that began and ended between two scrapes, a "
+    "seen thread's last stretch), so the roles add up to the process. "
+    "Divide deltas by weaviate_tpu_scrape_clock_seconds deltas for cores",
+    ("role",))
+thread_runqueue_wait_seconds_total = registry.counter(
+    "weaviate_tpu_thread_runqueue_wait_seconds_total",
+    "Time the role's threads were runnable and waited for a core "
+    "(schedstat's run delay): high means the host has no core to give, "
+    "not that the interpreter was taken", ("role",))
+threads_by_role = registry.gauge(
+    "weaviate_tpu_threads",
+    "Live threads by role at the scrape: grpc_pool at the pool's size "
+    "means every handler thread exists (requests queue in pool_wait "
+    "beyond it); a role that grows from scrape to scrape leaks threads",
+    ("role",))
+thread_account_walk_seconds = registry.gauge(
+    "weaviate_tpu_thread_account_walk_seconds",
+    "What the last scrape's walk over /proc/self/task took: one small "
+    "read a thread, made with the interpreter lock held and handed over "
+    "as any computing thread hands it over, every switch interval")
+scrape_clock_seconds = registry.gauge(
+    "weaviate_tpu_scrape_clock_seconds",
+    "time.monotonic() when the thread account was read: the wall a "
+    "reader divides the thread counters' deltas by")
+interpreter_wait_seconds = registry.histogram(
+    "weaviate_tpu_interpreter_wait_seconds",
+    "How late a thread that sleeps 20 ms is back in the interpreter "
+    "(the lock-probe thread, 50 samples a second, folded at the "
+    "scrape): what any thread pays to re-enter the interpreter after a "
+    "blocking call. On an idle server it reads the timer's slack",
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.05, 0.1))
 tail_retained_total = registry.counter(
     "weaviate_tpu_tail_retained_total",
     "Traces kept by the tail-based retention decision at request "

@@ -406,8 +406,6 @@ class QueryBatcher:
     # -- worker ---------------------------------------------------------------
 
     def _run(self):
-        from weaviate_tpu.runtime.metrics import batcher_batch_size
-
         while True:
             # the dispatch record is opened BEFORE the wait for work, so
             # the wait that precedes a dispatch is stamped into that
@@ -421,7 +419,6 @@ class QueryBatcher:
             drained = self._await_drain(side)
             if drained is not None:
                 try:
-                    batcher_batch_size.observe(len(drained))
                     self._dispatch(drained, rec)
                 except Exception as e:  # noqa: BLE001 — to every waiter
                     for it in drained:
@@ -851,10 +848,7 @@ class QueryBatcher:
             _mark_served()
             return
         self.async_dispatches += 1
-        from weaviate_tpu.runtime.metrics import (batcher_async_dispatched,
-                                                  batcher_overlapped)
-
-        batcher_async_dispatched.inc()
+        from weaviate_tpu.runtime.metrics import batcher_overlapped
 
         def _finish(res):
             try:

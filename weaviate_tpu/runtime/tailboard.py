@@ -81,6 +81,30 @@ the hot path.
    fanned-out request and are observed for such a request only: a
    one-shard request makes the observations it always made.
 
+6. **The interpreter's account** (ISSUE 39) — what one interpreter lock
+   costs, measured and no longer inferred, under the same switch and
+   with nothing new on a request's path. (a) At the scrape the kernel
+   is asked what every thread of the process has used
+   (:class:`ThreadAccount`: ``/proc/self/task/*/schedstat``), by a
+   fixed set of roles (:data:`THREAD_ROLES`) found from a Python
+   thread's name and a native thread's ``comm``:
+   ``weaviate_tpu_thread_cpu_seconds_total{role}``,
+   ``..._thread_runqueue_wait_seconds_total{role}``,
+   ``weaviate_tpu_threads{role}``, ``weaviate_tpu_scrape_clock_seconds``,
+   ``weaviate_tpu_thread_account_walk_seconds`` (what the walk took).
+   (b) One daemon thread (``lock-probe``) sleeps 20 ms at a time and
+   notes how late it is back: CPython hands the lock to any waiter, so
+   its lateness has the distribution every thread pays to re-enter the
+   interpreter after a blocking call
+   (``weaviate_tpu_interpreter_wait_seconds``, folded at the scrape).
+   (c) Every :data:`CPU_STAMP_EVERY`-th dispatch side of a thread (of
+   one kind) takes ``time.thread_time()`` beside each wall stamp, and the fold gives
+   ``weaviate_tpu_dispatch_stage_cpu_seconds{kind,stage}`` and
+   ``<side>_cpu_ms`` in the flight record; a staged request's
+   ``off_cpu`` (the handler's wall time less the waits it was meant to
+   make, less its CPU) is computed at the fold from stamps it already
+   took. ``WEAVIATE_TPU_TAILBOARD=0`` stops all three.
+
 Env surface (all lazy-read, re-read after :func:`reset_for_tests`):
 
 - ``WEAVIATE_TPU_TAILBOARD``        1 (default) / 0 — timeline on/off
@@ -99,6 +123,7 @@ Env surface (all lazy-read, re-read after :func:`reset_for_tests`):
 from __future__ import annotations
 
 import contextvars
+import ctypes
 import fnmatch
 import itertools
 import json
@@ -123,8 +148,11 @@ REQUEST_STAGES = ("pool_wait", "parse", "filter", "queue_wait", "device",
 #: two stages more of a request that fanned out over several local
 #: shards (:func:`fanout`), part of ITS sum and observed for it alone
 FANOUT_STAGES = ("fanout_wait", "merge")
-#: observed beside them, never part of the sum
-REQUEST_EXTRAS = ("handler_cpu", "server_residency")
+#: observed beside them, never part of the sum. ``off_cpu`` is computed
+#: at the fold: the handler's wall time less queue_wait, device and
+#: transfer (the waits it was meant to make; a fan-out's critical
+#: path's) less ``handler_cpu``: its thread was meant to run and did not
+REQUEST_EXTRAS = ("handler_cpu", "server_residency", "off_cpu")
 
 #: leaf-level stages of one dispatch. ``idle`` (queue empty) and
 #: ``slot_wait`` (transfer window full) are the worker's two waits;
@@ -272,6 +300,7 @@ def configure(data_dir: str | None = None, enabled: bool | None = None,
         # other ServerConfig field (from_env feeds the env value here
         # anyway, so env-driven deployments are unchanged)
         force_enabled(bool(enabled))
+    start_probe()
     if slos_json:
         try:
             slo_engine().configure_json(slos_json)
@@ -789,10 +818,14 @@ def _stage_values(phases: dict, stages: dict) -> tuple:
     other = max(0.0, get("search", 0.0) - (
         filt + wake + fetch + queue_wait + device + transfer
         + get("fanout_wait", 0.0) + get("merge", 0.0)))
-    return (get("pool_wait", 0.0), get("parse", 0.0), filt, queue_wait,
+    pool_wait, send, cpu, residency = (
+        get("pool_wait", 0.0), get("send", 0.0), get("handler_cpu", 0.0),
+        get("server_residency", 0.0))
+    off_cpu = max(0.0, residency - pool_wait - send
+                  - (queue_wait + device + transfer) - cpu)
+    return (pool_wait, get("parse", 0.0), filt, queue_wait,
             device, transfer, wake, fetch, other, get("reply", 0.0),
-            get("send", 0.0), get("handler_cpu", 0.0),
-            get("server_residency", 0.0))
+            send, cpu, residency, off_cpu)
 
 
 def _observe_columns(metric_name: str, columns: dict) -> None:
@@ -812,10 +845,12 @@ def flush() -> None:
     thousands."""
     with _fold_lock:
         columns: dict[tuple, list] = {}
+        cpu_columns: dict[tuple, list] = {}
         try:
             for (_seq, side) in _pending_dispatch.take():
-                _fold_side(side, columns)
+                _fold_side(side, columns, cpu_columns)
             _observe_columns("dispatch_stage_seconds", columns)
+            _observe_columns("dispatch_stage_cpu_seconds", cpu_columns)
         except Exception:  # pragma: no cover — never fail a reader
             pass
         found = _pending.take()
@@ -1269,12 +1304,18 @@ class _Side:
     stage already running when the session starts is not in the trace,
     as a TraceMe built before the session would not be either."""
 
-    __slots__ = ("rec", "name", "marks", "cur", "ann", "outer")
+    __slots__ = ("rec", "name", "marks", "cpus", "cur", "ann", "outer")
 
-    def __init__(self, rec: dict, name: str, now: float, stage, outer):
+    def __init__(self, rec: dict, name: str, now: float, stage, outer,
+                 cpu: bool = True):
         self.rec = rec
         self.name = name
         self.marks: list = []
+        # this thread's CPU clock, one stamp beside each wall stamp
+        # (None on the sides in between, and with the tailboard off):
+        # stage by stage, wall less CPU is what the thread spent not
+        # running
+        self.cpus: list | None = [] if cpu else None
         self.cur = self.ann = None
         self.outer = outer  # (the side this one paused, its stage) or None
         self.mark(stage, now)
@@ -1282,6 +1323,9 @@ class _Side:
     def mark(self, stage, now: float | None = None):
         """``stage`` runs from ``now`` on; -> the stage it takes over
         from."""
+        cpus = self.cpus
+        if cpus is not None:
+            cpus.append(time.thread_time())
         if now is None:
             now = time.perf_counter()
         ann = self.ann
@@ -1301,6 +1345,14 @@ class _Side:
 
 _bound = threading.local()
 
+#: a thread stamps its CPU clock on one dispatch side in so many. The
+#: clock is a system call: 0.3 us a read on a plain kernel, 6 us on the
+#: chip's hosts (a sandbox kernel), where ~12 reads on every dispatch
+#: cost ``sift-flat-l2.c1`` 2 % of its qps (PERF.md section 6, PR 39).
+#: The CPU histogram's own count says how many sides were stamped, so a
+#: reader scales a series' sum by the wall series' count over it
+CPU_STAMP_EVERY = 4
+
 
 def bind_dispatch(rec: dict, side: str, stage: str | None = None,
                   now: float | None = None) -> _Side:
@@ -1319,7 +1371,18 @@ def bind_dispatch(rec: dict, side: str, stage: str | None = None,
     outer = getattr(_bound, "side", None)
     if outer is not None:
         outer = (outer, outer.mark(_PAUSED, now))
-    new = _bound.side = _Side(rec, side, now, stage, outer)
+    # one count a label of the stage family (the dispatch's kind; a
+    # nested side is a solo dispatch's): one count over sides that
+    # alternate would stamp one sort only, and a reader scales a
+    # series' CPU sum by that series' own counts
+    counts = getattr(_bound, "sides", None)
+    if counts is None:
+        counts = _bound.sides = {}
+    key = (rec.get("kind") or rec.get("plane"), outer is not None)
+    n = counts.get(key, 0)
+    counts[key] = n + 1
+    new = _bound.side = _Side(rec, side, now, stage, outer,
+                              n % CPU_STAMP_EVERY == 0 and enabled())
     return new
 
 
@@ -1330,6 +1393,8 @@ def unbind_dispatch(keep: bool = True) -> None:
     side = getattr(_bound, "side", None)
     if side is None:
         return
+    if side.cpus is not None:
+        side.cpus.append(time.thread_time())
     now = time.perf_counter()
     if side.ann is not None:
         side.ann.__exit__(None, None, None)
@@ -1344,24 +1409,36 @@ def unbind_dispatch(keep: bool = True) -> None:
         _pending_dispatch.push(side)
 
 
-def _fold_side(side: _Side, columns: dict) -> None:
+def _fold_side(side: _Side, columns: dict, cpu_columns: dict) -> None:
     """One closed side -> its stages' seconds (into ``columns`` for the
-    stage family and, in ms, into the record as ``<side>_ms``). The
+    stage family and, in ms, into the record as ``<side>_ms``) and,
+    where the side took CPU stamps, the CPU seconds its thread had
+    inside each (``cpu_columns``, ``<side>_cpu_ms``). A stage's CPU is
+    NOT cut to its wall time: where the kernel moves a thread's CPU
+    clock in ticks (the chip's hosts: PERF.md section 6, PR 39) one
+    stage reads 0 or a whole tick, and only the uncut readings add up
+    to what the thread used. The
     worker's side also gives ``worker_wall``, its wall time less what a
     nested side took: the stages cover it, and ``dispatch_busy_pct``
     divides by it. Runs under ``_fold_lock``, never on a dispatch
     loop."""
-    marks = side.marks
+    marks, cpus = side.marks, side.cpus
     stages: dict[str, float] = {}
-    paused = 0.0
-    for i in range(1, len(marks) - 1, 2):
+    cpu: dict[str, float] = {}
+    paused = paused_cpu = 0.0
+    for n, i in enumerate(range(1, len(marks) - 1, 2)):
         stage, seconds = marks[i], marks[i + 1] - marks[i - 1]
+        had = max(cpus[n + 1] - cpus[n], 0.0) if cpus else 0.0
         if stage is _PAUSED:
             paused += seconds
+            paused_cpu += had
         elif stage is not None:
             stages[stage] = stages.get(stage, 0.0) + seconds
+            cpu[stage] = cpu.get(stage, 0.0) + had
     if side.name == "worker":
         stages["worker_wall"] = marks[-1] - marks[0] - paused
+        if cpus:
+            cpu["worker_wall"] = max(cpus[-1] - cpus[0] - paused_cpu, 0.0)
     rec = side.rec
     rec[side.name + "_ms"] = {k: v * 1000.0 for k, v in stages.items()}
     kind = rec.get("kind") or rec["plane"]
@@ -1369,6 +1446,10 @@ def _fold_side(side: _Side, columns: dict) -> None:
         kind += ".solo"
     for name, v in stages.items():
         columns.setdefault((kind, name), []).append(v)
+    if cpus:
+        rec[side.name + "_cpu_ms"] = {k: cpu[k] * 1000.0 for k in stages}
+        for name in stages:
+            cpu_columns.setdefault((kind, name), []).append(cpu[name])
 
 
 class dispatch_stage:
@@ -1522,6 +1603,296 @@ def on_component_unhealthy(component: str, reason: str) -> None:
     snapshot_to_disk(f"component:{component}")
 
 
+# -- the interpreter's account: threads by role, the lock probe ---------------
+
+#: the ``role`` label's values, all of them. Python threads by name,
+#: native threads by ``comm``; the canary's work runs on the
+#: cyclemanager's thread and the probe itself is ``python_other``.
+#: ``exited`` is what the process has used beyond its live threads and
+#: beyond what threads that have gone were charged while they lived:
+#: threads that began and ended between two walks (a REST handler's, a
+#: compile pool's) and a seen thread's last stretch, so that the roles
+#: add up to the process
+THREAD_ROLES = ("grpc_serve", "grpc_pool", "batcher_worker", "batcher_drain",
+                "cyclemanager", "rest", "python_other", "grpc_core",
+                "device_runtime", "native_other", "exited")
+_PYTHON_ROLES = (("grpc-pool", "grpc_pool"),
+                 ("query-batcher", "batcher_worker"),
+                 ("dp-dispatch", "batcher_worker"),
+                 ("cyclemanager", "cyclemanager"), ("rest-", "rest"))
+#: native threads by the ``comm`` prefixes a serving process showed on
+#: the chip's host (PERF.md section 6, PR 39): grpc's ``event_engine``,
+#: ``grpc_global_tim`` and ``lifeguard``; the TPU runtime's and XLA's
+#: ``pjrt-tpu-*``, ``tfrt-*``, ``tf_XLAEigen``, ``llvm-worker-*`` and
+#: the two a process on the CPU backend (same grpc, same jax) does not
+#: have, ``EventFDAsyncWor`` and ``futex-default-S``
+_NATIVE_ROLES = (
+    (("event_engine", "grpc", "lifeguard"), "grpc_core"),
+    (("pjrt", "tfrt", "tf_", "llvm", "EventFDAsyncWor", "futex-default-S"),
+     "device_runtime"))
+
+
+def thread_role(name: str | None, comm: str = "") -> str:
+    """A thread's role: ``name`` is a Python thread's
+    (``threading.enumerate()``), None for a native one, which is told by
+    its ``comm``. grpc starts its serving thread as
+    ``Thread(target=_serve)``, which Python names ``Thread-N (_serve)``;
+    the pool's and every long-lived thread of this package carry a name
+    of their own, so ``python_other`` stays small."""
+    if name is not None:
+        if name.endswith("(_serve)"):
+            return "grpc_serve"
+        if name.endswith("-transfer"):
+            return "batcher_drain"
+        if name.endswith("(process_request_thread)"):
+            return "rest"
+        for prefix, role in _PYTHON_ROLES:
+            if name.startswith(prefix):
+                return role
+        return "python_other"
+    for prefixes, role in _NATIVE_ROLES:
+        if comm.startswith(prefixes):
+            return role
+    return "native_other"
+
+
+def _procfs_reader():
+    """-> ``read(path) -> bytes`` for small procfs files. Called through
+    ``ctypes.PyDLL`` the three libc calls KEEP the interpreter lock: a
+    plain ``open().read()`` gives it up round every system call, and on
+    a loaded server a thread queues milliseconds to take it back, a few
+    hundred times a walk (the readings of one walk would then lie
+    seconds apart). The walk still runs bytecode between two reads, so
+    like any busy Python thread it hands the lock over when a waiter's
+    switch interval (5 ms) has run out: no thread waits for a scrape
+    longer than for any other thread that computes."""
+    libc = ctypes.PyDLL(None)
+    c_open, c_read, c_close = libc.open, libc.read, libc.close
+    c_open.argtypes = (ctypes.c_char_p, ctypes.c_int)
+    c_read.argtypes = (ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t)
+    c_read.restype = ctypes.c_ssize_t
+    buf = ctypes.create_string_buffer(512)
+
+    def read(path: str) -> bytes:
+        fd = c_open(path.encode(), 0)
+        if fd < 0:
+            return b""
+        n = c_read(fd, buf, 512)
+        c_close(fd)
+        return buf.raw[:n] if n > 0 else b""
+
+    return read
+
+
+class ThreadAccount:
+    """What the kernel has charged each thread of this process, by role:
+    ``walk`` reads ``<root>/<tid>/schedstat`` (ns on a core, ns runnable
+    and waiting for one; ``stat``'s utime + stime is the first in 10-ms
+    ticks and stands in where the kernel keeps no schedstat) for every
+    thread, and ``comm`` for the native ones, and gives back what each
+    role has used SINCE THE LAST WALK. A thread seen for the first time
+    brings all it has used so far; one that exited has its readings up
+    to the last walk in its role's total, which therefore never falls,
+    and what it used after that walk comes in under ``exited``.
+
+    A walk is ONE read a thread once it knows them: a native thread's
+    role is kept from its second sighting on (its first may come before
+    the thread has named itself), and a kernel that showed no schedstat
+    is not asked again. ``walk_seconds`` is what the last walk took: on
+    the chip's hosts ~55 us a read, 13-17 ms at 280 threads on an idle
+    server and 29-36 ms under load, its own waits for the interpreter
+    included (PERF.md section 6, PR 39). Nothing here runs anywhere but
+    at a scrape."""
+
+    def __init__(self, proc: str = "/proc/self", read=None):
+        self.proc = proc
+        self.root = proc + "/task"
+        self._read = read or _procfs_reader()
+        self._last: dict[int, tuple[float, float]] = {}
+        self._native_role: dict[int, str] = {}
+        self._schedstat: bool | None = None
+        self._vanished = 0.0  # last readings of seen threads that went
+        self._exited = 0.0
+        self._tick = 1.0 / os.sysconf("SC_CLK_TCK")
+        self.walk_seconds = 0.0
+
+    def _stat_cpu(self, path: str) -> float | None:
+        """utime + stime of a ``stat`` file, in seconds."""
+        fields = self._read(path).rpartition(b")")[2].split()
+        if len(fields) < 13:
+            return None
+        return (int(fields[11]) + int(fields[12])) * self._tick
+
+    def _usage(self, tid: str) -> tuple[float, float] | None:
+        """(CPU s, run-queue wait s) of one thread; None: it has gone."""
+        base = f"{self.root}/{tid}/"
+        if self._schedstat is not False:
+            fields = self._read(base + "schedstat").split()
+            if len(fields) >= 2:
+                self._schedstat = True
+                return int(fields[0]) * 1e-9, int(fields[1]) * 1e-9
+        cpu = self._stat_cpu(base + "stat")
+        if cpu is None:
+            return None
+        if self._schedstat is None:
+            # the first thread read has no such file: this kernel keeps
+            # none, and is not asked again
+            self._schedstat = False
+        return cpu, 0.0
+
+    def _role(self, tid: int, name: str | None, known: bool) -> str:
+        if name is not None:
+            return thread_role(name)
+        role = self._native_role.get(tid)
+        if role is None:
+            role = thread_role(None, self._read(
+                f"{self.root}/{tid}/comm").decode(errors="replace").strip())
+            if known:
+                self._native_role[tid] = role
+        return role
+
+    def walk(self, python_threads: dict[int, str]) -> tuple[dict, dict, dict]:
+        """``python_threads``: native id -> name of every Python thread.
+        -> ({role: CPU s}, {role: run-queue wait s}) since the last
+        walk, {role: live threads}; every role of :data:`THREAD_ROLES`
+        is a key of each."""
+        t0 = time.perf_counter()
+        cpu = dict.fromkeys(THREAD_ROLES, 0.0)
+        wait = dict.fromkeys(THREAD_ROLES, 0.0)
+        live = dict.fromkeys(THREAD_ROLES, 0)
+        last, seen = self._last, {}
+        try:
+            tids = os.listdir(self.root)
+        except OSError:
+            tids = []
+        for tid in tids:
+            usage = self._usage(tid)
+            if usage is None:
+                continue
+            tid_n = int(tid)
+            was = last.pop(tid_n, None)
+            if was is not None and usage[0] < was[0]:
+                # the id, under another thread: the old one has gone
+                self._vanished += was[0]
+                self._native_role.pop(tid_n, None)
+                was = None
+            role = self._role(tid_n, python_threads.get(tid_n),
+                              was is not None)
+            if was is None:
+                was = (0.0, 0.0)
+            cpu[role] += usage[0] - was[0]
+            wait[role] += max(0.0, usage[1] - was[1])
+            live[role] += 1
+            seen[tid_n] = usage
+        # what is left of the last walk's threads has gone: their
+        # readings stay in their roles, so they come off the remainder
+        for tid_n, was in last.items():
+            self._vanished += was[0]
+            self._native_role.pop(tid_n, None)
+        self._last = seen
+        # the process's own total holds the threads that are no more
+        total = self._stat_cpu(self.proc + "/stat")
+        if total is not None:
+            gone = (total - sum(u[0] for u in seen.values())
+                    - self._vanished)
+            if gone > self._exited:
+                cpu["exited"] = gone - self._exited
+                self._exited = gone
+        self.walk_seconds = time.perf_counter() - t0
+        return cpu, wait, live
+
+
+class LockProbe:
+    """The ``lock-probe`` thread: sleep ``PERIOD_S``, note how much
+    later than that the interpreter was back in hand. CPython gives the
+    lock to any waiter, so the lateness is a sample of what every thread
+    of the process pays to re-enter the interpreter after a blocking
+    call (plus the timer's slack, which is all it reads on an idle
+    server). 50 samples a second into a bounded deque, and nothing else
+    on any thread: :func:`scrape_refresh` folds them."""
+
+    PERIOD_S = 0.02
+
+    def __init__(self):
+        self.samples: deque = deque(maxlen=1 << 16)
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._run, name="lock-probe",
+                                        daemon=True)
+        self._thread.start()
+
+    def _run(self) -> None:
+        clock, sleep, period = time.perf_counter, time.sleep, self.PERIOD_S
+        put, stopped = self.samples.append, self._stop.is_set
+        while not stopped():
+            t0 = clock()
+            sleep(period)
+            put(clock() - t0 - period)
+
+    def alive(self) -> bool:
+        return self._thread.is_alive()
+
+    def stop(self) -> None:
+        self._stop.set()
+
+    def take(self) -> list[float]:
+        out, pop = [], self.samples.popleft
+        try:
+            while True:
+                out.append(max(0.0, pop()))
+        except IndexError:
+            return out
+
+
+_account_lock = threading.Lock()
+_account: ThreadAccount | None = None
+_probe: LockProbe | None = None
+
+
+def start_probe() -> None:
+    """Server start (:func:`configure`): the probe runs while the
+    tailboard is on, and only in a process that serves."""
+    global _probe
+    with _account_lock:
+        if enabled() and (_probe is None or not _probe.alive()):
+            _probe = LockProbe()
+
+
+def stop_probe() -> None:
+    global _probe
+    with _account_lock:
+        if _probe is not None:
+            _probe.stop()
+            _probe = None
+
+
+def _account_refresh() -> None:
+    """The scrape's reading of the interpreter's account: the probe's
+    samples into their histogram, the threads' CPU by role into the
+    counters. The clock gauge is taken beside the walk."""
+    global _account
+    from weaviate_tpu.runtime import metrics
+
+    with _account_lock:
+        if _probe is not None:
+            samples = _probe.take()
+            if samples:
+                metrics.interpreter_wait_seconds.labels().observe_many(
+                    samples)
+        if _account is None:
+            _account = ThreadAccount()
+        names = {t.native_id: t.name for t in threading.enumerate()
+                 if t.native_id is not None}
+        now = _mono()
+        cpu, wait, live = _account.walk(names)
+        metrics.scrape_clock_seconds.set(now)
+        metrics.thread_account_walk_seconds.set(_account.walk_seconds)
+        for role in THREAD_ROLES:
+            metrics.thread_cpu_seconds_total.labels(role).inc(cpu[role])
+            metrics.thread_runqueue_wait_seconds_total.labels(role).inc(
+                wait[role])
+            metrics.threads_by_role.labels(role).set(live[role])
+
+
 # -- debug payloads -----------------------------------------------------------
 
 
@@ -1534,10 +1905,12 @@ def debug_slo() -> dict:
 
 def scrape_refresh() -> None:
     """Read-point hook for the /v1/metrics scrape paths: fold the
-    pending completion records, then republish the burn gauges (and run
-    the incident sweep)."""
+    pending completion records, republish the burn gauges (and run the
+    incident sweep), then read the interpreter's account."""
     flush()
     slo_engine().refresh()
+    if enabled():
+        _account_refresh()
 
 
 # -- test isolation -----------------------------------------------------------
@@ -1549,7 +1922,9 @@ def reset_for_tests() -> None:
     global _enabled_cached, _forced, _slow_map, _data_dir
     global _tail_ring, _flight_ring, _slowlog_ring, _slo_engine
     global _tenant_guard, _collection_guard, _last_snapshot
-    global _pending, _pending_dispatch, _finish_seq
+    global _pending, _pending_dispatch, _finish_seq, _account
+    stop_probe()
+    _account = None
     _enabled_cached = None
     _forced = None
     _slow_map = None

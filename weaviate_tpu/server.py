@@ -269,6 +269,9 @@ class Server:
 
     def stop(self) -> None:
         self._stop.set()
+        from weaviate_tpu.runtime import tailboard
+
+        tailboard.stop_probe()
         if self.telemeter is not None:
             self.telemeter.stop()
         if self.metrics_server is not None:
