@@ -283,6 +283,61 @@ def test_consumers_run_on_a_memoised_mask(items, path):
         assert first == (0, N // 10)
 
 
+def _operands(path):
+    from weaviate_tpu.runtime.metrics import filter_operand_total
+
+    return {r: filter_operand_total.labels(path, r).value
+            for r in ("hit", "miss", "shared", "uncached")}
+
+
+def _moved(path, before):
+    return {r: int(v - before[r]) for r, v in _operands(path).items()
+            if v != before[r]}
+
+
+# p = 50 allows half the rows: a row of a coalesced dispatch's bitmask;
+# p = 1 allows 4 <= capacity / 64: solo, the gathered slot list
+@pytest.mark.parametrize("p, path", [(50, "bitmask"), (1, "gathered")])
+def test_a_memo_drop_alone_makes_the_operand_a_miss(items, p, path):
+    """(PR 40) The vector index keeps a memoised mask's device operands
+    under the mask OBJECT. A write that moves no slot of the vector index
+    (an object without a vector) still drops the memo, so the next
+    request brings a NEW object: a miss there, by the key alone."""
+    _db, col, shard = items
+    idx = shard.vector_indexes[""]
+    q = np.random.default_rng(6).standard_normal(8).astype(np.float32)
+
+    def search():
+        before = _operands(path)
+        got = [r.uuid for r in col.near_vector(q, k=5, where=_lt(p))]
+        return got, _moved(path, before)
+
+    first, moved = search()
+    assert moved == {"miss": 1}
+    assert search() == (first, {"hit": 1})
+    gen, kept = idx._slot_gen, shard.allow_mask(_lt(p))
+    col.put_object(_props(0), uuid=_uuid(N + 7))          # no vector
+    assert idx._slot_gen == gen                 # the slot table stood still
+    assert shard.allow_mask(_lt(p)) is not kept  # the memo did not
+    assert search() == (first, {"miss": 1})
+    assert search() == (first, {"hit": 1})
+
+
+def test_a_combined_filter_is_never_kept(items):
+    """(PR 40) ``a AND b`` is a new writeable array a request: its leaves
+    hit the memo, its device operand is built for that dispatch alone."""
+    _db, col, shard = items
+    both = Filter.and_(_lt(50), Filter.where("bucket",
+                                             Operator.GREATER_THAN_EQUAL, 5))
+    q = np.random.default_rng(7).standard_normal(8).astype(np.float32)
+    first = [r.uuid for r in col.near_vector(q, k=5, where=both)]
+    leaves, before = _served(), _operands("bitmask")
+    assert [r.uuid for r in col.near_vector(q, k=5, where=both)] == first
+    assert _moved("bitmask", before) == {"uncached": 1}
+    assert _served()[0] == leaves[0] + 2        # both leaves: hits
+    assert shard.vector_indexes[""]._operands.resident[0] == 0
+
+
 def test_counter_and_span_count_leaf_look_ups(items):
     """(d) Four distinct clauses, forty requests: 4 misses, 36 hits; a
     write between two equal requests: a second miss. The span carries

@@ -507,6 +507,56 @@ def test_coalesced_dispatch_counts_one_translation_a_request(rng):
         qb.stop()
 
 
+@pytest.mark.parametrize("frozen", [True, False])
+@pytest.mark.parametrize("quant", [None, "bq"])
+def test_coalesced_dispatch_translates_a_mask_object_once(rng, quant,
+                                                          frozen):
+    """(PR 40) Rows of one dispatch that carry the SAME mask object are
+    translated once, whatever the array; a read-only one (what the
+    filter memo hands out) is not translated by the next dispatch
+    either, its packed row stayed on the device; a writeable one is
+    translated again, and a write to the index makes both a miss. The
+    answers are the masked reference's throughout."""
+    from weaviate_tpu.engine.flat import FlatIndex
+    from weaviate_tpu.runtime.query_batcher import _Pending
+
+    n, d, k = 300, 16, 4
+    corpus = rng.standard_normal((n, d)).astype(np.float32)
+    idx = FlatIndex(dim=d, capacity=512, selection="fused",
+                    **({"quantization": quant, "rescore_limit": 512}
+                       if quant else {}))
+    idx.add_batch(np.arange(n), corpus)
+    qb, calls = _make_batcher(idx)
+    as_mask, _ = _translations()
+    allow = rng.random(n) < 0.5
+    other = rng.random(n) < 0.5
+    allow.flags.writeable = other.flags.writeable = not frozen
+
+    def dispatch(live):
+        batch = [_Pending(rng.standard_normal(d).astype(np.float32), k, a)
+                 for a in (allow, other, allow, None, allow)]
+        before = as_mask.value
+        qb._dispatch(batch)
+        for p in batch:
+            assert p.error is None
+            want, _ = masked_ref(
+                p.query, corpus,
+                live if p.allow is None else live & p.allow, k)
+            assert np.array_equal(np.asarray(p.ids)[:len(want)], want)
+        return as_mask.value - before
+
+    try:
+        live = np.ones(n, dtype=bool)
+        assert dispatch(live) == 2              # two objects, five rows
+        assert dispatch(live) == (0 if frozen else 2)
+        idx.delete(int(np.flatnonzero(allow)[0]))
+        live[np.flatnonzero(allow)[0]] = False
+        assert dispatch(live) == 2              # the slot table moved
+        assert all(c["per_query"] and c["rows"] == 8 for c in calls)
+    finally:
+        qb.stop()
+
+
 def test_mask_block_constant():
     # every masked kernel unpacks whole 512-column blocks; the packers
     # and kernels must agree on the constant

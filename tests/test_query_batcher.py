@@ -684,8 +684,10 @@ def test_solo_dispatch_is_its_own_record_under_its_own_kind():
 def test_gathered_solo_scan_and_full_scan_compile_under_different_names(
         monkeypatch):
     """Programs that differ in role differ in module name: the scan over
-    a dense gather of the few allowed rows (the solo path) is
-    ``jit_gathered_topk_distances``, the full scan keeps
+    a dense gather of the few allowed rows is
+    ``jit_gathered_topk_distances`` on its own and, as the solo path
+    runs it since PR 40 (the gathers and the scan in ONE program),
+    ``jit_shared_candidates_topk``; the full scan keeps
     ``jit_chunked_topk_distances`` (the benchmark's ``scan_programs``
     pattern), the device-side fold of a shared allow list is
     ``jit_apply_allow_mask``. Naming only: same body, same answers."""
@@ -715,20 +717,27 @@ def test_gathered_solo_scan_and_full_scan_compile_under_different_names(
     gathered = gathered_topk_distances(q, x, **kw)
     assert all(np.array_equal(np.asarray(a), np.asarray(b))
                for a, b in zip(full, gathered))
-    # and the store's gathered cutover really runs under the new name
+    # and the store's gathered cutover really runs under the new name:
+    # one program, which holds the gathered scan
     calls = []
 
-    def spy(*args, **kwargs):
-        calls.append(kwargs["chunk_size"])
-        return gathered_topk_distances(*args, **kwargs)
+    def spy(q, slots, rows, k, metric, **kwargs):
+        calls.append((candidates.shared_candidates_topk.lower(
+            q, slots, rows, k, metric, **kwargs).as_text().split(
+                "module @")[1].split()[0], slots.shape))
+        return candidates.shared_candidates_topk(q, slots, rows, k, metric,
+                                                 **kwargs)
 
-    monkeypatch.setattr(candidates, "gathered_topk_distances", spy)
+    from weaviate_tpu.engine import store as store_mod
+
+    monkeypatch.setattr(store_mod, "shared_candidates_topk", spy)
     idx, rng = _corpus_index(n=512, dim=16)
     allow = np.zeros(512, bool)
     allow[:4] = True
     idx.store.search(rng.standard_normal((1, 16)).astype(np.float32), 3,
                      allow_mask=allow)
-    assert calls == [128]              # the 4 allowed rows' pow2 bucket
+    # the 4 allowed rows' pow2 bucket
+    assert calls == [("jit_shared_candidates_topk", (128,))]
 
 
 # -- the wait for company (PR 32) --------------------------------------------

@@ -301,6 +301,39 @@ def test_served_scans_with_the_rescore_tail(one_chip, monkeypatch, cell, b):
         (64 << 20) + 2 * b * kc * lanes * 4
 
 
+@pytest.mark.parametrize("b_pad", [1, 16, 32])
+def test_filtered_dispatch_programs(one_chip, monkeypatch, b_pad):
+    """The filtered cell's two programs since PR 40, at its shapes
+    (262,144 x 128 f32): a coalesced dispatch stacks ``b_pad`` packed
+    rows that already lie on the device, and a solo dispatch is ONE
+    program that gathers its slot list's rows and scans them. Neither
+    may copy the corpus: what they hold beyond their arguments is the
+    stacked block, or the gathered bucket."""
+    from weaviate_tpu.engine.store import stack_allow_rows
+    from weaviate_tpu.ops.candidates import shared_candidates_topk
+
+    monkeypatch.setattr(pk, "recommended", lambda: True)
+    rows, d, words = 262144, 128, pk.mask_pad_cols(262144) // 32
+    c = _compile(stack_allow_rows, one_chip,
+                 *[((words,), jnp.uint32)] * b_pad)
+    assert f"u32[{b_pad},{words}]" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes <= b_pad * words * 4
+    if b_pad > 1:
+        return                      # the solo path dispatches one query
+    bucket, k = 4096, 16            # 2,621 allowed rows' pow2 bucket
+    fn = functools.partial(shared_candidates_topk, k=k,
+                           metric="l2-squared", use_pallas=True,
+                           selection="approx")
+    c = _compile(lambda q, slots, x, norms, valid: fn(
+        q, slots, x, row_norms=norms, valid=valid), one_chip,
+        ((1, d), jnp.float32), ((bucket,), jnp.int32),
+        ((rows, d), jnp.float32), ((rows,), jnp.float32),
+        ((rows,), jnp.bool_))
+    text = c.as_text()
+    assert not re.search(rf"= f32\[{rows},{d}\][^ ]* copy\(", text)
+    assert c.memory_analysis().temp_size_in_bytes < 4 * bucket * d * 4
+
+
 def test_bq_mxu_block(one_chip):
     fn = functools.partial(pk.bq_mxu_block, interpret=False)
     _assert_kernel(_compile(fn, one_chip, ((64, 24), jnp.uint32),

@@ -724,6 +724,14 @@ class Shard:
                     return 0
                 return s.capacity
 
+            # a mask the index keeps device operands of has its count
+            # there (engine/filter_operands.py); resolved per call like
+            # the rest, None where the index of the moment has no such
+            # method (the batcher then counts)
+            def _allowed_count(allow, i=idx):
+                fn = getattr(i, "allowed_count", None)
+                return None if fn is None else fn(allow)
+
             # zero-sync pipeline: resolved through getattr PER CALL so a
             # compress()/DynamicIndex.upgrade() swapping the impl under
             # the cached batcher degrades to the sync path (None) instead
@@ -750,6 +758,7 @@ class Shard:
                     supports_filter_batching=lambda i=idx: bool(
                         getattr(i, "supports_batched_filters", False)),
                     capacity_fn=_gathered_capacity,
+                    count_fn=_allowed_count,
                     pad_pow2=bool(getattr(idx, "compiled_batch_shapes",
                                           True)),
                     async_batch_fn=(_async_batch if self.async_pipeline

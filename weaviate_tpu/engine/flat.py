@@ -22,9 +22,13 @@ import time
 import numpy as np
 
 from weaviate_tpu import native
-from weaviate_tpu.engine.store import DeviceVectorStore
-from weaviate_tpu.runtime import kernelscope, tracing
-from weaviate_tpu.runtime.metrics import allow_translate_total
+from weaviate_tpu.engine.filter_operands import (FilterOperandCache,
+                                                 stable_mask)
+from weaviate_tpu.engine.store import (AllowBits, AllowSlots,
+                                       DeviceVectorStore, stack_allow_rows)
+from weaviate_tpu.runtime import hbm_ledger, kernelscope, tracing
+from weaviate_tpu.runtime.metrics import (allow_translate_total,
+                                          filter_operand_total)
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
 
 
@@ -89,6 +93,12 @@ class FlatIndex:
     # handed to the quantized store this index builds, here or in
     # compress(), which asks it where its rescore rows may live
     memwatch = None
+    # the slot table's generation: moves under ``_lock`` wherever
+    # ``_slot_to_id`` or the store (its capacity, its identity) changes,
+    # and with it goes every filter operand the index kept on the device
+    # (``_operands``: engine/filter_operands.py, made at its first use)
+    _slot_gen = 0
+    _operands = None
 
     def __init__(self, dim: int, metric: str = "l2-squared", mesh=None,
                  dtype=None, capacity: int = 8192, chunk_size: int = 8192,
@@ -177,6 +187,7 @@ class FlatIndex:
                 for i, s in zip(doc_ids[fresh].tolist(), slots.tolist()):
                     self._id_to_slot[int(i)] = int(s)
                     self._slot_to_id[int(s)] = int(i)
+                self._slots_moved()
 
     def _ensure_slot_map(self):
         """Grow the slot->id reverse map with store capacity. Caller
@@ -185,6 +196,14 @@ class FlatIndex:
             grown = np.full(self.store.capacity, -1, dtype=np.int64)
             grown[: len(self._slot_to_id)] = self._slot_to_id
             self._slot_to_id = grown
+            self._slots_moved()
+
+    def _slots_moved(self) -> None:
+        """Caller holds ``_lock`` and has changed ``_slot_to_id`` or the
+        store: no filter operand built before holds any longer."""
+        self._slot_gen += 1
+        if self._operands is not None:
+            self._operands.clear()
 
     def delete(self, *doc_ids) -> None:
         """Tombstone docs (reference Delete, vector_index.go:28)."""
@@ -194,6 +213,7 @@ class FlatIndex:
             if slots:
                 self._slot_to_id[slots] = -1
                 self.store.delete(np.asarray(slots))
+                self._slots_moved()
 
     def contains(self, doc_id: int) -> bool:
         return int(doc_id) in self._id_to_slot
@@ -214,7 +234,8 @@ class FlatIndex:
         with tracing.span("flat.search", k=k,
                           filtered=allow_list is not None):
             with self._lock:
-                allow_mask = self._allow_mask(allow_list)
+                allow_mask = self._shared_operand(
+                    None if allow_list is None else np.asarray(allow_list))
                 d, slots = self.store.search(np.asarray(query), k,
                                              allow_mask)
                 return self._resolve(d, slots, k)
@@ -271,9 +292,11 @@ class FlatIndex:
         """Allow-list intake shared by the sync and async batch paths.
         Caller holds ``_lock`` and passes its span, which takes the
         ``form`` the lists were translated in ("mask", "ids", or
-        "ids+mask" for a batch that held both). Returns ("mask",
-        mask-or-None) for the single-dispatch forms, or ("rowwise",
-        per-row masks) when the store cannot take a 2-D mask."""
+        "ids+mask" for a batch that held both). Returns ("mask", what
+        the store's ``search_async`` takes: None, a slot mask, or an
+        operand that already lies on the device) for the
+        single-dispatch forms, or ("rowwise", per-row masks) when the
+        store cannot take a 2-D mask."""
         if per_query and len(allow_list) != len(queries):
             raise ValueError(
                 f"{len(allow_list)} allow lists != "
@@ -283,22 +306,160 @@ class FlatIndex:
         forms = sorted({_allow_form(a) for a in lists if a is not None})
         if forms:
             sp.set(form="+".join(forms))
-        masks = [self._allow_mask(a) for a in lists]
         if not per_query:
-            return "mask", masks[0]
-        if all(m is None for m in masks):
+            return "mask", self._shared_operand(lists[0])
+        if all(a is None for a in lists):
             return "mask", None
         if not self.supports_batched_filters:
-            return "rowwise", masks
-        # unfiltered rows get an all-ones mask (the store still ANDs
+            return "rowwise", [self._allow_mask(a) for a in lists]
+        if self._keeps_operands():
+            return "mask", self._bitmask_operand(lists)
+        # a mesh ships bool rows column-sharded, an epoch store slices
+        # them by epoch, an injected store takes what it took: the block.
+        # Unfiltered rows get an all-ones mask (the store still ANDs
         # with its live-slot validity)
-        allow_mask = np.ones((len(masks), self.store.capacity),
+        filter_operand_total.labels("bitmask", "uncached").inc(
+            sum(a is not None for a in lists))
+        allow_mask = np.ones((len(lists), self.store.capacity),
                              dtype=bool)
-        for r, m in enumerate(masks):
-            if m is not None:
+        for r, a in enumerate(lists):
+            if a is not None:
+                m = self._allow_mask(a)
                 allow_mask[r, :] = False
                 allow_mask[r, :len(m)] = m
         return "mask", allow_mask
+
+    # -- a filter's device operands (engine/filter_operands.py) ---------------
+
+    def _keeps_operands(self) -> bool:
+        """Whether this index's store takes filters that already lie on
+        the device: one device, and a store that says so."""
+        store = self.store
+        return (getattr(store, "takes_allow_operands", False)
+                and store.mesh is None)
+
+    def _operand_cache(self) -> FilterOperandCache:
+        """Caller holds ``_lock``."""
+        if self._operands is None:
+            self._operands = FilterOperandCache(
+                getattr(self.store, "_hbm_owner", None))
+        return self._operands
+
+    def allowed_count(self, allow) -> int:
+        """Doc ids an allow list lets through (a bool mask over doc ids,
+        or an array of them): what the batcher's solo cut goes by. A
+        mask this index keeps operands of has its count there."""
+        allow = np.asarray(allow)
+        if allow.dtype != np.bool_:
+            return allow.size
+        cache = self._operands
+        if cache is not None and stable_mask(allow):
+            return cache.doc_count(allow)
+        return int(np.count_nonzero(allow))
+
+    def _bitmask_operand(self, lists) -> AllowBits:
+        """Per-query allow lists (None = unfiltered) -> the dispatch's
+        packed ``allow_bits`` on the device, bit-equal to
+        ``pack_allow_bitmask`` of the translated [B, capacity] block.
+        Caller holds ``_lock``.
+
+        A row refers to its mask's packed row on the device: kept from
+        an earlier dispatch (``hit``), or built now, once a mask OBJECT
+        however many rows carry it (``shared``) and kept where the mask
+        cannot change (``miss``; else ``uncached``); unfiltered and
+        padded rows share one all-ones row. The rows are stacked by one
+        program on the device, so a dispatch whose masks are all known
+        translates, packs and uploads nothing. The ``store.mask_pack``
+        span (the dispatch's ``mask_pack`` stage) is this whole step."""
+        from weaviate_tpu.ops.pallas_kernels import (mask_pad_cols,
+                                                     pack_allow_bitmask)
+
+        import jax.numpy as jnp
+
+        store = self.store
+        capacity = store.capacity
+        n_cols = mask_pad_cols(capacity)
+        stamp = (self._slot_gen, capacity)
+        cache = self._operand_cache()
+        with tracing.span("store.mask_pack", stage="mask_pack",
+                          queries=len(lists)) as sp:
+            rows: list = [None] * len(lists)
+            first: dict[int, int] = {}  # id(mask) -> the first row with it
+            build = []                  # (row, mask, keep) to translate
+            hits = shared = 0
+            ones = None                 # unfiltered and padded rows' one
+            for r, a in enumerate(lists):
+                if a is None:
+                    if ones is None:
+                        ones = cache.ones(stamp, lambda: jnp.asarray(
+                            pack_allow_bitmask(
+                                np.ones(capacity, dtype=bool), n_cols)[0]))
+                    rows[r] = ones
+                elif first.setdefault(id(a), r) != r:
+                    shared += 1
+                else:
+                    keep = stable_mask(a)
+                    e = cache.get(a, stamp) if keep else None
+                    if e is not None and e.bits is not None:
+                        rows[r] = e.bits
+                        hits += 1
+                    else:
+                        build.append((r, a, keep))
+            if build:
+                block = np.zeros((len(build), capacity), dtype=bool)
+                for j, (_r, a, _keep) in enumerate(build):
+                    m = self._allow_mask(a)
+                    block[j, :len(m)] = m
+                packed = pack_allow_bitmask(block, n_cols)
+                for j, (r, a, keep) in enumerate(build):
+                    rows[r] = jnp.asarray(packed[j])
+                    if keep:
+                        cache.attach(a, stamp, bits=rows[r])
+            for r, a in enumerate(lists):
+                if rows[r] is None:
+                    rows[r] = rows[first[id(a)]]
+            bits = stack_allow_rows(*rows)
+            hbm_ledger.ledger.track("allow_bitmask", bits,
+                                    **cache.owner)
+            misses = sum(keep for _r, _a, keep in build)
+            for result, n in (("hit", hits), ("miss", misses),
+                              ("shared", shared),
+                              ("uncached", len(build) - misses)):
+                if n:
+                    filter_operand_total.labels("bitmask", result).inc(n)
+            sp.set(hits=hits, misses=misses, distinct=len(first))
+        return AllowBits(bits)
+
+    def _shared_operand(self, allow):
+        """ONE allow list for the whole batch (None = unfiltered) ->
+        what the store takes. A mask that cannot change and is selective
+        enough for the store's gathered cutover becomes its slot list ON
+        THE DEVICE, built once and kept (``AllowSlots``: a solo dispatch
+        then uploads its query row and nothing else); anything else is
+        translated to a slot mask as it always was, and the store lists
+        it. Caller holds ``_lock``."""
+        if allow is None:
+            return None
+        store = self.store
+        keep = (self._keeps_operands() and hasattr(store, "gathered_slots")
+                and stable_mask(allow))
+        if keep:
+            stamp = (self._slot_gen, store.capacity)
+            cache = self._operand_cache()
+            e = cache.get(allow, stamp)
+            if e is not None and e.slots is not None:
+                filter_operand_total.labels("gathered", "hit").inc()
+                return AllowSlots(e.slots, e.slot_count)
+        slot_mask = self._allow_mask(allow)
+        if keep:
+            op = store.gathered_slots(slot_mask)
+            if op.slots is not None:
+                cache.attach(allow, stamp, slots=op.slots,
+                             slot_count=op.count)
+                filter_operand_total.labels("gathered", "miss").inc()
+                return op
+        filter_operand_total.labels("gathered", "uncached").inc()
+        return slot_mask
 
     def search_by_vector_batch_async(self, queries: np.ndarray, k: int,
                                      allow_list=None):
@@ -388,6 +549,12 @@ class FlatIndex:
         sparse_ops = list(sparse_ops or [None] * len(queries))
         live_ops = [op for op in sparse_ops if op is not None]
         per_query = _per_query_allow(allow_list)
+        if allow_list is not None and not per_query:
+            # ONE list for the batch rides as a row a query (packed
+            # once: the rows share the object): the gathered path's
+            # finish step pads on the HOST, which would break the
+            # on-device fusion composition
+            allow_list, per_query = [allow_list] * len(queries), True
         # dense leg depth: every row's over-fetch must fit so fusion
         # ranks match the host reference; pow2 so the scan compiles per
         # bucket, not per drain
@@ -400,14 +567,6 @@ class FlatIndex:
                     queries, allow_list, per_query, sp)
                 if kind == "rowwise":
                     return None
-                if allow_mask is not None and allow_mask.ndim == 1:
-                    # force the bitmask-batched dispatch: the gathered
-                    # path's finish step remaps slots on the HOST, which
-                    # would break the on-device fusion composition
-                    shared = np.zeros(self.store.capacity, dtype=bool)
-                    shared[:len(allow_mask)] = allow_mask
-                    allow_mask = np.broadcast_to(
-                        shared, (len(queries), self.store.capacity))
                 kernelscope.explain_note(
                     "hybrid", queries=len(queries),
                     hybrid_rows=len(live_ops), k=k, fetch=fetch,
@@ -584,6 +743,7 @@ class FlatIndex:
                     sp.set(rows_caught_up=self._catch_up(
                         new, snap, old.snapshot()))
                 self.store = new
+                self._slots_moved()
             index_compress_total.labels(quantization, "ok").inc()
 
     def _quantized_twin(self, old, quantization: str, quant_kwargs: dict):
@@ -652,6 +812,7 @@ class FlatIndex:
         if quantization == "pq":
             new.train(live_vecs[:training_limit])
         self.store = new
+        self._slots_moved()
 
     @property
     def compressed(self) -> bool:
@@ -698,6 +859,7 @@ class FlatIndex:
                 self._id_to_slot[doc_id] = ns
                 new_slot_to_id[ns] = doc_id
             self._slot_to_id = new_slot_to_id
+            self._slots_moved()
 
     def snapshot(self) -> dict:
         with self._lock:

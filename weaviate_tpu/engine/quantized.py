@@ -170,6 +170,10 @@ class QuantizedVectorStore:
     host.
     """
 
+    # ``search_async`` takes per-query filters an index has already
+    # packed and put on the device (``AllowBits``) where ``mesh`` is None
+    takes_allow_operands = True
+
     def __init__(
         self,
         dim: int,
@@ -873,7 +877,8 @@ class QuantizedVectorStore:
         candidates) stays device-resident in the returned handle, whose
         finish step runs the exact host rescore (when this store's
         rescore mode needs one) after the boundary transfer."""
-        from weaviate_tpu.engine.store import (apply_allow_mask,
+        from weaviate_tpu.engine.store import (AllowBits, apply_allow_mask,
+                                               batched_mask_operands,
                                                normalize_allow_mask)
 
         queries = np.asarray(queries, dtype=np.float32)
@@ -902,10 +907,8 @@ class QuantizedVectorStore:
                 capacity = self.capacity
                 valid = self.valid
                 allow_bits = allow_rows_dev = None
-                if allow_mask is not None and allow_mask.ndim == 2:
-                    from weaviate_tpu.engine.store import (
-                        batched_mask_operands)
-
+                if isinstance(allow_mask, AllowBits) or (
+                        allow_mask is not None and allow_mask.ndim == 2):
                     sp.set(path="bitmask_batched")
                     allow_bits, allow_rows_dev = batched_mask_operands(
                         allow_mask, len(queries), capacity, self.mesh,

@@ -27,6 +27,7 @@ import functools
 import os
 import threading
 import weakref
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -55,12 +56,31 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+class AllowBits(NamedTuple):
+    """Per-query filters as the scan takes them, already on the device:
+    ``bits`` [B, capacity_pad / 32] uint32 in ``pack_allow_bitmask``'s
+    layout. What an index that keeps its filters' operands
+    (engine/filter_operands.py) hands ``search_async`` in place of a
+    [B, capacity] bool block."""
+    bits: jax.Array
+
+
+class AllowSlots(NamedTuple):
+    """ONE filter shared by the batch as the gathered cutover takes it
+    (``DeviceVectorStore.gathered_slots``): ``slots`` [bucket] int32 on
+    the device, the ``count`` allowed slots in ascending order, then -1;
+    None where the filter is too broad for the cutover."""
+    slots: jax.Array | None
+    count: int
+
+
 def normalize_allow_mask(allow_mask, n_queries: int):
     """Shared allow-mask intake for the plain and quantized stores:
     [1, C] broadcasts to the shared [C] form (keeping the gathered
-    low-selectivity cutover); a [B, C] mask must match the query count."""
-    if allow_mask is None:
-        return None
+    low-selectivity cutover); a [B, C] mask must match the query count.
+    Operands an index prepared (``AllowBits``, ``AllowSlots``) pass."""
+    if allow_mask is None or isinstance(allow_mask, (AllowBits, AllowSlots)):
+        return allow_mask
     allow_mask = np.asarray(allow_mask)
     if allow_mask.ndim == 2 and allow_mask.shape[0] == 1:
         allow_mask = allow_mask[0]
@@ -80,6 +100,15 @@ def apply_allow_mask(valid, allow):
     return jnp.logical_and(valid, allow)
 
 
+@jax.jit
+def stack_allow_rows(*rows):
+    """A dispatch's ``allow_bits`` [B, W] from B packed rows [W] that are
+    already on the device, a row a query in order; a mask that several
+    queries carry is passed as often (the same buffer). One program a
+    padded batch size, the scan's own variants and no more."""
+    return jnp.stack(rows)
+
+
 def batched_mask_operands(allow_mask, n_queries: int, capacity: int, mesh,
                           owner: dict | None = None):
     """[B, capacity] per-query mask -> scan-kernel operands, under a
@@ -88,7 +117,10 @@ def batched_mask_operands(allow_mask, n_queries: int, capacity: int, mesh,
     each device packs its own row-aligned slice on device. Returns
     (allow_bits, allow_rows_dev) — exactly one is non-None. ``owner``
     labels the transient device buffer in the HBM ledger (weakref-
-    tracked: the entry lives exactly as long as the buffer)."""
+    tracked: the entry lives exactly as long as the buffer). ``AllowBits``
+    (single device only) were packed by the index that prepared them."""
+    if isinstance(allow_mask, AllowBits):
+        return allow_mask.bits, None
     owner = owner or {}
     with tracing.span("store.mask_pack", stage="mask_pack",
                       queries=n_queries):
@@ -158,6 +190,10 @@ class DeviceVectorStore:
     buffer swaps; reads take a snapshot reference — the analog of the
     reference's sharded RW locks in vector/common/sharded_locks.go).
     """
+
+    # ``search_async`` takes filters an index has already put on the
+    # device (``AllowBits``, ``AllowSlots``) where ``mesh`` is None
+    takes_allow_operands = True
 
     def __init__(
         self,
@@ -549,8 +585,10 @@ class DeviceVectorStore:
                                          self.sq_norms)
                 capacity = self.capacity
                 allow_bits = allow_rows_dev = None
-                if allow_mask is not None and allow_mask.ndim == 2:
-                    slot_buf = None
+                gathered = False
+                if isinstance(allow_mask, AllowBits) or (
+                        isinstance(allow_mask, np.ndarray)
+                        and allow_mask.ndim == 2):
                     sp.set(path="bitmask_batched")
                     # EXPLAIN notes are host ints only (no device reads
                     # — graftlint G1/G5 pin it) and a one-contextvar-
@@ -562,50 +600,34 @@ class DeviceVectorStore:
                         allow_mask, len(queries), capacity, self.mesh,
                         owner=self._hbm_owner)
                 elif allow_mask is not None:
-                    allowed = np.flatnonzero(allow_mask)
-                    # selectivity policy (numbers from a pre-chip rig,
-                    # not measured on the v5e; the filtered cell in
-                    # benchmarks/ serves the batched-mask branch above):
-                    # masked full scan is selectivity-
-                    # independent (~11.1 ms at 1M×128 B=256); gather is
-                    # ~1.4 ms + linear (5.2 ms at 10%, 23 ms at 50%) —
-                    # crossover ≈22%, policy cut at capacity/8 with a
-                    # 1 GB transient-gather HBM budget computed on the
-                    # PADDED pow2 bucket at the actual storage dtype
-                    m_allowed = len(allowed)
-                    bucket = 1 << max(7, (m_allowed - 1).bit_length()) \
-                        if m_allowed else 0
-                    row_bytes = self.dim * jnp.dtype(
-                        self.vectors.dtype).itemsize
-                    if (self.mesh is None and m_allowed > 0
-                            and m_allowed <= capacity // 8
-                            and bucket * row_bytes <= (1 << 30)):
+                    # ONE filter for the batch. An index that keeps its
+                    # filters' operands hands the slot list over as it
+                    # lies on the device (it asked ``gathered_slots``
+                    # when it built the list); anything else is listed
+                    # here, a request at a time
+                    slots, m_allowed = (
+                        allow_mask if isinstance(allow_mask, AllowSlots)
+                        else self.gathered_slots(allow_mask))
+                    gathered = slots is not None
+                    kernelscope.explain_note(
+                        "store",
+                        path="gathered" if gathered else "shared_mask",
+                        rows=capacity, m_allowed=m_allowed,
+                        queries=len(queries), k=k,
+                        selectivity=round(m_allowed / capacity, 6)
+                        if capacity else 0.0)
+                    if gathered:
                         sp.set(path="gathered", allowed=m_allowed)
-                        kernelscope.explain_note(
-                            "store", path="gathered", rows=capacity,
-                            m_allowed=m_allowed, queries=len(queries),
-                            k=k, selectivity=round(
-                                m_allowed / capacity, 6) if capacity
-                            else 0.0)
-                        d, i, slot_buf = self._dispatch_gathered(
-                            queries, k, allowed)
+                        d, i = self._dispatch_gathered(queries, k, slots)
                     else:
-                        kernelscope.explain_note(
-                            "store", path="shared_mask", rows=capacity,
-                            m_allowed=m_allowed, queries=len(queries),
-                            k=k, selectivity=round(
-                                m_allowed / capacity, 6) if capacity
-                            else 0.0)
                         full = np.zeros(capacity, dtype=bool)
                         full[: len(allow_mask)] = allow_mask
                         valid = apply_allow_mask(valid, self._placed(full))
-                        slot_buf = None
                 else:
                     kernelscope.explain_note(
                         "store", path="full_scan", rows=capacity,
                         queries=len(queries), k=k)
-                    slot_buf = None
-                if slot_buf is None:
+                if not gathered:
                     k_eff = min(k, capacity)
                     # cosine runs as "cosine" against rows normalized at
                     # insert (the query side is normalized inside the
@@ -634,11 +656,11 @@ class DeviceVectorStore:
         # handle: a sync here would serialize concurrent readers behind
         # this dispatch AND idle the device between batches
 
-        def _finish(d_np, i_np, _slot_buf=slot_buf, _k=k,
+        def _finish(d_np, i_np, _gathered=gathered, _k=k,
                     _squeeze=squeeze):
-            if _slot_buf is not None:
+            if _gathered:
                 d_np, i_np = DeviceVectorStore._finish_gathered(
-                    d_np, i_np, _slot_buf, _k)
+                    d_np, i_np, _k)
             if _squeeze:
                 return d_np[0], i_np[0]
             return d_np, i_np
@@ -648,9 +670,8 @@ class DeviceVectorStore:
             attrs={"rows": capacity, "queries": len(queries), "k": k,
                    # which dispatch shape ran: the hybridplane composes
                    # on the device arrays and must refuse the gathered
-                   # path (its finish step remaps slots on the HOST)
-                   "path": ("gathered" if slot_buf is not None
-                            else "device")})
+                   # path (its finish step pads to k on the HOST)
+                   "path": "gathered" if gathered else "device"})
 
     def epoch_scan(self, queries: np.ndarray, k: int,
                    allow_mask: np.ndarray | None = None):
@@ -695,32 +716,49 @@ class DeviceVectorStore:
                 use_pallas=self.use_pallas, selection=self.selection,
                 allow_rows=allow_rows_dev)
 
-    def _dispatch_gathered(self, queries: np.ndarray, k: int,
-                           allowed: np.ndarray):
+    def gathered_slots(self, slot_mask: np.ndarray) -> AllowSlots:
+        """The store's cutover for ONE filter shared by a batch: where
+        the mask is selective enough, its allowed slots in a dense pow2
+        bucket on the device, to be gathered and scanned alone; else
+        ``slots`` None and the masked full scan serves it. Numbers from a
+        pre-chip rig, not measured on the v5e (ROADMAP S13): the masked
+        full scan is selectivity-independent (~11.1 ms at 1M x 128,
+        B = 256); the gather is ~1.4 ms + linear (5.2 ms at 10 %, 23 ms
+        at 50 %): crossover ~22 %, policy cut at capacity / 8 with a
+        1-GB budget for the transient gather, counted on the PADDED
+        bucket at the storage dtype. Called with ``_lock`` held, or by
+        an index that holds its own over every write."""
+        m_allowed = int(np.count_nonzero(slot_mask))
+        bucket = 1 << max(7, (m_allowed - 1).bit_length())
+        row_bytes = self.dim * jnp.dtype(self.vectors.dtype).itemsize
+        if (self.mesh is not None
+                or not 0 < m_allowed <= self.capacity // 8
+                or bucket * row_bytes > (1 << 30)):
+            return AllowSlots(None, m_allowed)
+        slot_buf = np.full(bucket, -1, dtype=np.int32)
+        slot_buf[:m_allowed] = np.flatnonzero(slot_mask)
+        return AllowSlots(jnp.asarray(slot_buf), m_allowed)
+
+    def _dispatch_gathered(self, queries: np.ndarray, k: int, slots):
         """Filtered search at low selectivity: gather the allowed rows
         into a dense pow2-padded buffer on device and scan THAT
         (reference analog: flatSearchCutoff routes small filters to
-        brute force over the allow list, hnsw/index.go:95). Called under
-        ``_lock`` by ``search``; dispatch only — results materialize
-        outside the lock. Buckets bound compiled variants. Returns
-        (d_dev, i_dev, slot_buf)."""
-        m = len(allowed)
-        bucket = 1 << max(7, (m - 1).bit_length())
-        slot_buf = np.full(bucket, -1, dtype=np.int32)
-        slot_buf[:m] = allowed
+        brute force over the allow list, hnsw/index.go:95). ``slots``
+        [bucket] int32 on the device: the allowed slots, then -1. Called
+        under ``_lock`` by ``search_async``; dispatch only, ONE program
+        — results materialize outside the lock. Buckets bound compiled
+        variants. Returns (d_dev, i_dev)."""
         metric = ("cosine" if self.metric in ("cosine", "cosine-dot")
                   else self.metric)
-        d, i = shared_candidates_topk(
-            jnp.asarray(queries), jnp.asarray(slot_buf), self.vectors,
-            min(k, bucket), metric, row_norms=self.sq_norms,
+        return shared_candidates_topk(
+            jnp.asarray(queries), slots, self.vectors,
+            min(k, slots.shape[0]), metric, row_norms=self.sq_norms,
             valid=self.valid, use_pallas=self.use_pallas,
             selection=self.selection,
         )
-        return d, i, slot_buf
 
     @staticmethod
-    def _finish_gathered(d_np: np.ndarray, i_np: np.ndarray,
-                         slot_buf: np.ndarray, k: int):
+    def _finish_gathered(d_np: np.ndarray, i_np: np.ndarray, k: int):
         """Host half of the gathered path. The candidate plane remaps
         bucket-local winners to global slots ON DEVICE (row_ids), so
         this is pad-only up to search()'s [B, k] contract."""
@@ -729,17 +767,6 @@ class DeviceVectorStore:
             i_np = np.pad(i_np, ((0, 0), (0, pad)), constant_values=-1)
             d_np = np.pad(d_np, ((0, 0), (0, pad)),
                           constant_values=np.float32(np.inf))
-        return d_np, i_np
-
-    def _search_gathered(self, queries: np.ndarray, k: int,
-                         allowed: np.ndarray, squeeze: bool):
-        """Dispatch + finish in one call."""
-        with self._lock:
-            d, i, slot_buf = self._dispatch_gathered(queries, k, allowed)
-        d_np, i_np = self._finish_gathered(np.asarray(d), np.asarray(i),
-                                           slot_buf, k)
-        if squeeze:
-            return d_np[0], i_np[0]
         return d_np, i_np
 
     def search_by_distance(self, query: np.ndarray, max_distance: float,

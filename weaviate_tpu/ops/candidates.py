@@ -138,6 +138,8 @@ def rescore_tail(fd, fi, q, rows, k: int, metric: str, *, valid=None,
                                valid=valid, allow_bits=allow_bits)
 
 
+@functools.partial(
+    jax.jit, static_argnames=("k", "metric", "use_pallas", "selection"))
 def shared_candidates_topk(q, cand_slots, rows, k: int, metric: str, *,
                            row_norms=None, valid=None, use_pallas=False,
                            selection: str = "exact"):
@@ -150,6 +152,12 @@ def shared_candidates_topk(q, cand_slots, rows, k: int, metric: str, *,
     ``row_ids`` — callers get global ids straight off the handle. This
     is the low-selectivity gathered path: total work is O(B·C), not
     O(B·N), and C tracks the allow-list size.
+
+    ONE program (``jit_shared_candidates_topk`` on the device's module
+    line): the clip, the two compares, the row / norm / valid gathers
+    and the scan were nine programs, eight of them eager one-op
+    dispatches from the batcher's worker (PERF.md, PR 40). The ops and
+    their order are the eager path's, so the answers are bit-equal.
     """
     n = rows.shape[0]
     slots = jnp.asarray(cand_slots, dtype=jnp.int32)

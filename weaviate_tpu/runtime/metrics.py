@@ -512,6 +512,24 @@ allow_translate_total = registry.counter(
     "(engine/flat.py _allow_mask), by the form the input took: mask = a "
     "bool mask over doc ids, one gather through the slot table; ids = an "
     "array of doc ids, sorted and binary-searched", ("form",))
+filter_operand_total = registry.counter(
+    "weaviate_tpu_filter_operand_total",
+    "Filtered query rows by where their device operand came from "
+    "(engine/filter_operands.py), one a row. path: bitmask = a row of a "
+    "coalesced dispatch's packed allow bits; gathered = ONE allow list "
+    "for the batch, scanned over its gathered slots where it is "
+    "selective enough. result: hit = kept on the device from an earlier "
+    "dispatch; miss = translated, packed, uploaded and kept; shared = "
+    "the same mask object as an earlier row of this dispatch; uncached "
+    "= built for this dispatch alone (a writeable array or an id list, "
+    "a mesh, an epoch store's column slices, a list too broad for the "
+    "gathered cut)", ("path", "result"))
+filter_operand_resident = registry.gauge(
+    "weaviate_tpu_filter_operand_resident",
+    "What an index's filter-operand cache holds on the device: kind = "
+    "entries (masks) or bytes (packed bitmap rows and slot lists; the "
+    "HBM ledger's allow_bitmask component books the same bytes)",
+    ("collection", "shard", "kind"))
 rescore_dispatch_total = registry.counter(
     "weaviate_tpu_rescore_dispatch_total",
     "Compressed-store search dispatches that rescore exactly, by where "

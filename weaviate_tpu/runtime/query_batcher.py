@@ -140,7 +140,11 @@ class QueryBatcher:
     backing store's row capacity) powers the per-dispatch selectivity
     heuristic that routes tiny filters to the solo/gathered path — wire
     it ONLY when the store has a gathered cutover; otherwise solo is a
-    full masked scan and strictly worse than batching. ``pad_pow2``
+    full masked scan and strictly worse than batching. ``count_fn``
+    (optional, ``allow -> int``) is where that heuristic reads an allow
+    list's size: an index that keeps its filters' operands knows the
+    count of a mask it has seen, and the batcher counts where no such
+    function is wired or it returns None. ``pad_pow2``
     pads drains to pow2 B/k buckets — right for jitted device programs
     (bounds compiled variants), wasted work for per-row host indexes
     like HNSW (padded rows run real graph searches), so those opt out.
@@ -157,7 +161,7 @@ class QueryBatcher:
 
     def __init__(self, batch_fn, max_batch: int = 256,
                  supports_filter_batching: bool = False,
-                 capacity_fn=None, pad_pow2: bool = True,
+                 capacity_fn=None, count_fn=None, pad_pow2: bool = True,
                  owner: dict | None = None, async_batch_fn=None,
                  transfer_depth: int = 2,
                  max_queue: int | None = None, kind: str = "index",
@@ -188,6 +192,7 @@ class QueryBatcher:
             else max_queue
         self.filter_batching = supports_filter_batching  # bool | callable
         self._capacity_fn = capacity_fn
+        self._count_fn = count_fn
         self.pad_pow2 = pad_pow2
         # HBM-ledger labels for the padded dispatch buffer (the shard
         # layer passes its collection/shard; standalone batchers fall
@@ -517,6 +522,10 @@ class QueryBatcher:
     def _allowed_count(self, allow) -> int:
         """Selectivity of an allow list (bool mask over doc-id space or
         array of allowed ids)."""
+        if self._count_fn is not None:
+            n = self._count_fn(allow)
+            if n is not None:
+                return n
         a = np.asarray(allow)
         return int(np.count_nonzero(a)) if a.dtype == np.bool_ else a.size
 
