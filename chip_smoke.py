@@ -2,7 +2,9 @@
 user calls.
 
     python chip_smoke.py              # one chip: phases P1-P5 below
-    python chip_smoke.py --chips 4    # four chips: the sharded phase only
+    python chip_smoke.py --chips 4    # four chips: a collection's shards
+                                      # on chips of their own (P7), then
+                                      # the mesh-sharded phase (P6)
 
 Starts a real ``weaviate_tpu.server.Server`` in this process on loopback
 ports (the pattern of benchmarks/, which mirrors the reference's
@@ -591,11 +593,98 @@ def run_sharded(args, rng) -> None:
            id_agreement=shared / total)
 
 
+def run_placed(args, rng) -> None:
+    """P7: an eight-shard collection through the served path on a host
+    of several chips: the program spreads the shards evenly over the
+    local devices (runtime/placement.py), every shard's arrays lie on
+    its device, the answers are the union's exact top k and
+    ``/v1/nodes`` names each shard's device. The smoke to run FIRST on
+    four chips."""
+    import jax
+
+    from weaviate_tpu.config import ServerConfig
+    from weaviate_tpu.runtime import placement
+    from weaviate_tpu.server import Server
+
+    n = min(args.rows or PASSAGE_ROWS, PASSAGE_ROWS)
+    corpus, centers = clustered(rng, n, ADA_DIM)
+    queries, _ = clustered(rng, 64, ADA_DIM, centers)
+    data_dir = tempfile.mkdtemp(prefix="chip-smoke-placed-")
+    t0 = time.perf_counter()
+    server = Server(ServerConfig(data_path=data_dir, rest_port=0, grpc_port=0,
+                                 disable_telemetry=True)).start()
+    wire = Wire(server)
+    try:
+        wire.rest.create_class({
+            "class": "Passages", "vectorIndexType": "flat",
+            "vectorIndexConfig": {"distance": "cosine"},
+            "shardingConfig": {"desiredCount": 8},
+            "properties": [{"name": "bucket", "dataType": ["int"]}]})
+        import_s, rate = wire.import_rows(
+            "Passages", corpus, [{"bucket": i % 100} for i in range(n)],
+            batch=1024)
+        found, dists = zip(*[wire.search("Passages", q) for q in queries])
+        # eight clients at once: the fan-out's programs on every chip
+        with ThreadPoolExecutor(N_CLIENTS) as pool:
+            again = list(pool.map(
+                lambda q: wire.search("Passages", q)[0], queries))
+        unit = corpus / np.linalg.norm(corpus, axis=1, keepdims=True)
+        qunit = queries / np.linalg.norm(queries, axis=1, keepdims=True)
+        exact = 1.0 - qunit.astype(np.float64) @ unit.astype(np.float64).T
+        truth = np.argsort(exact, axis=1, kind="stable")[:, :K]
+        rec = recall(list(found), truth)
+        worst = max(abs(d - exact[q, i]) for q in range(len(queries))
+                    for i, d in zip(found[q], dists[q]))
+        shards = server.db.collections["Passages"].shards
+        held: dict[str, list[str]] = {}
+        stray = []
+        for name, shard in sorted(shards.items()):
+            held.setdefault(placement.label(shard.device), []).append(name)
+            store = shard.vector_indexes[""].store
+            for attr, arr in vars(store).items():
+                if isinstance(arr, jax.Array) and \
+                        arr.devices() != {shard.device}:
+                    stray.append(f"{name}.{attr}")
+        nodes = wire.rest.request("GET", "/v1/nodes", {"output": "verbose"})
+        named = {d["name"]: d.get("device")
+                 for d in nodes["nodes"][0].get("shards", [])
+                 if d.get("class") == "Passages"}
+        local = len(jax.local_devices())
+        problems = []
+        if not all(len(f) == K for f in found):
+            problems.append("short result")
+        if rec < 0.99:   # the configuration's limit (approx selection)
+            problems.append("recall below 0.99")
+        if worst > 1e-4:
+            problems.append("a distance is off by more than 1e-4")
+        if [sorted(f) for f in again] != [sorted(f) for f in found]:
+            problems.append("concurrent answers differ from serial ones")
+        if len(held) != min(local, 8) or \
+                {len(v) for v in held.values()} != {8 // min(local, 8)}:
+            problems.append("shards are not spread evenly over the devices")
+        if stray:
+            problems.append("an array is not on its shard's device")
+        if named != {name: placement.label(s.device)
+                     for name, s in shards.items()}:
+            problems.append("/v1/nodes does not name the shards' devices")
+        report("P7_placed", problems, rows=n, dim=ADA_DIM, queries=64,
+               shards_by_device=held, local_devices=local,
+               import_seconds=import_s, import_objects_per_s=rate,
+               recall_at_10=rec, distance_error_max=float(worst),
+               stray_arrays=stray, dispatches=wire.counters()["dispatches"],
+               seconds=time.perf_counter() - t0)
+    finally:
+        wire.close()
+        server.stop()
+        shutil.rmtree(data_dir, ignore_errors=True)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run only the sharded phase and its comparison")
+                    help="4: a collection's shards a chip each, then the "
+                         "mesh-sharded phase and its comparison")
     ap.add_argument("--rows", type=int, default=0,
                     help="rehearsal: rows of the main collection (the "
                          "others scale with it); default is the full size")
@@ -632,6 +721,7 @@ def main() -> int:
 
     rng = np.random.default_rng(args.seed)
     if args.chips == 4:
+        run_placed(args, rng)
         run_sharded(args, rng)
     else:
         run_served(args, rng)

@@ -115,7 +115,8 @@ def test_memwatch_host_gate():
 
 def test_memwatch_device_gate_with_explicit_limit(monkeypatch):
     mon = MemoryMonitor(device_limit_bytes=10_000, max_utilization=0.5)
-    monkeypatch.setattr(MemoryMonitor, "device_in_use", lambda self: 4000)
+    monkeypatch.setattr(MemoryMonitor, "device_in_use",
+                        lambda self, *a, **kw: 4000)
     mon.check_device_alloc(500)  # 4500 < 5000
     with pytest.raises(InsufficientMemoryError):
         mon.check_device_alloc(2000)
@@ -199,7 +200,8 @@ def test_memwatch_gates_batch_import(tmp_path, monkeypatch):
     from weaviate_tpu.db.database import Database
     from weaviate_tpu.schema.config import CollectionConfig
 
-    monkeypatch.setattr(MemoryMonitor, "device_in_use", lambda self: 0)
+    monkeypatch.setattr(MemoryMonitor, "device_in_use",
+                        lambda self, *a, **kw: 0)
     mon = MemoryMonitor(device_limit_bytes=100, max_utilization=1.0)
     db = Database(str(tmp_path), memory_monitor=mon)
     col = db.create_collection(CollectionConfig(name="Gate"))

@@ -1214,6 +1214,7 @@ class RestServer:
         """Per-shard breakdown for ?output=verbose (reference:
         nodes/handler.go verbose output with shard object counts), plus
         each shard's ledger-attributed device bytes."""
+        from weaviate_tpu.runtime import placement
         from weaviate_tpu.runtime.hbm_ledger import ledger
 
         out = []
@@ -1230,6 +1231,9 @@ class RestServer:
                     "vectorQueueLength": sum(
                         q.size() for q in shard._index_queues.values()),
                     "hbmBytes": ledger.shard_bytes(cname, sname),
+                    # the chip of this host the shard's vector indexes
+                    # lie on (runtime/placement.py); "" on a mesh
+                    "device": placement.label(shard.device),
                 })
         return out
 

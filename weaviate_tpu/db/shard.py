@@ -13,11 +13,12 @@ import contextlib
 import logging
 import os
 import threading
+import weakref
 
 import numpy as np
 
 from weaviate_tpu.engine.flat import FlatIndex
-from weaviate_tpu.runtime import tracing
+from weaviate_tpu.runtime import placement, tracing
 from weaviate_tpu.runtime.metrics import filter_leaf_total
 from weaviate_tpu.schema.config import CollectionConfig, VectorConfig
 from weaviate_tpu.storage.kv import KVStore
@@ -295,6 +296,13 @@ class Shard:
         self._counter = self.meta.get(b"doc_counter") or 0
         self.read_only = bool(self.meta.get(b"read_only") or False)
         self.mesh = mesh
+        # the chip of this host the shard's vector indexes live on, from
+        # now until ``close`` (runtime/placement.py: the least-held local
+        # device, so a collection's shards spread evenly and a host of
+        # one chip gives every shard that chip); a mesh places its own
+        self.device = None if mesh is not None else placement.acquire()
+        self._release_device = weakref.finalize(
+            self, placement.release, self.device)
         # named vector indexes, built lazily at first insert (dim inference)
         self.vector_indexes: dict[str, FlatIndex] = {}
         # persistent inverted index: postings/filterables write through the
@@ -382,7 +390,8 @@ class Shard:
         from weaviate_tpu.runtime import hbm_ledger
 
         with hbm_ledger.owner(self.collection_name, self.name,
-                              tenant=self._tenant_label()):
+                              tenant=self._tenant_label(),
+                              device=self.device):
             idx = _make_vector_index(vc, dim, mesh=self.mesh,
                                      memwatch=self.memwatch)
         self.vector_indexes[vec_name] = idx
@@ -766,7 +775,8 @@ class Shard:
                     hybrid_batch_fn=_hybrid_batch,
                     owner={"collection": self.collection_name,
                            "shard": self.name,
-                           "tenant": self._tenant_label()},
+                           "tenant": self._tenant_label(),
+                           "device": self.device},
                     # kernelscope variant label: residency EWMAs key on
                     # (index kind, b bucket, k bucket) compiled variants
                     kind=str(getattr(idx, "index_type", "index")),
@@ -1345,7 +1355,8 @@ class Shard:
         relieves it — the bytes move to a sibling's ledger scope)."""
         what = f"import {self.collection_name}/{self.name}"
         if self.memwatch is not None:
-            self.memwatch.check_device_alloc(nbytes, what=what)
+            self.memwatch.check_device_alloc(nbytes, what=what,
+                                             device=self.device)
         if self.shard_hbm_limit and self.over_shard_limit(nbytes):
             from weaviate_tpu.runtime.hbm_ledger import ledger
             from weaviate_tpu.runtime.memwatch import \
@@ -1665,6 +1676,7 @@ class Shard:
                 continue
             labels = (self.collection_name, self.name, vec_name or "default")
             store = getattr(idx, "store", None)
+            device = placement.label(getattr(idx, "device", None))
             live = len(idx)
             total = getattr(store, "count", live) if store is not None                 else getattr(idx, "_count", live)
             vector_index_tombstones.labels(*labels).set(max(total - live, 0))
@@ -1681,7 +1693,7 @@ class Shard:
                     arr = getattr(st, arr_name, None)
                     if arr is not None and hasattr(arr, "nbytes"):
                         hbm += int(arr.nbytes)
-            vector_index_hbm_bytes.labels(*labels).set(hbm)
+            vector_index_hbm_bytes.labels(*labels, device).set(hbm)
         return did
 
     def close(self):
@@ -1694,3 +1706,4 @@ class Shard:
         for b in self._query_batchers.values():
             b.stop()
         self.store.close()
+        self._release_device()

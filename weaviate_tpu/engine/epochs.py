@@ -109,7 +109,10 @@ class _Epoch:
 
                 self._dev_map = replicate_array(jnp.asarray(host), mesh)
             else:
-                self._dev_map = jnp.asarray(host)
+                from weaviate_tpu.runtime import placement
+
+                self._dev_map = placement.put(
+                    host, getattr(self.store, "device", None))
             self._dev_map_cap = cap
         return self._dev_map
 
@@ -188,6 +191,10 @@ class EpochStore:
         self._initial_capacity = min(capacity, self.epoch_rows)
         self._lock = threading.RLock()
         self._owner = hbm_ledger.current_owner()
+        # the owning shard's chip: every epoch's store is built under
+        # ``_owner``'s scope and commits its arrays there
+        # (runtime/placement.py); None on a mesh
+        self.device = None if mesh is not None else self._owner.get("device")
         self._codebook = self._quant_kwargs.pop("codebook", None)
         if quantization:
             # an epoch's store keeps its rescore rows on the host unless

@@ -26,7 +26,7 @@ from weaviate_tpu.engine.filter_operands import (FilterOperandCache,
                                                  stable_mask)
 from weaviate_tpu.engine.store import (AllowBits, AllowSlots,
                                        DeviceVectorStore, stack_allow_rows)
-from weaviate_tpu.runtime import hbm_ledger, kernelscope, tracing
+from weaviate_tpu.runtime import hbm_ledger, kernelscope, placement, tracing
 from weaviate_tpu.runtime.metrics import (allow_translate_total,
                                           filter_operand_total)
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
@@ -152,6 +152,34 @@ class FlatIndex:
         self._lock = threading.RLock()
         self._id_to_slot: dict[int, int] = {}
         self._slot_to_id: np.ndarray = np.full(self.store.capacity, -1, dtype=np.int64)
+        if self.device is not None:
+            placement.twins.register(self)
+
+    # -- placement (runtime/placement.py) --------------------------------------
+
+    @property
+    def device(self):
+        """The chip this index's store keeps its arrays on: the owning
+        shard's, None on a mesh or outside any shard. Read from the store
+        of the moment, so it outlives ``compress``'s swap."""
+        return getattr(self.store, "device", None)
+
+    def twin_shapes(self):
+        """The store's shapes as ``placement.Twins`` compares them, None
+        for a store that does not say (a mesh, epochs, an injected
+        one)."""
+        fn = getattr(self.store, "twin_shapes", None)
+        return None if fn is None else fn()
+
+    def warm_twin(self, size) -> None:
+        """Run, and so build or load on this index's chip, the program an
+        unfiltered dispatch of ``size`` = (queries, k) takes: an index of
+        the same shapes on another chip has just met it."""
+        b, k = size
+        handle = self.search_by_vector_batch_async(
+            np.zeros((b, self.dim), dtype=np.float32), k)
+        if handle is not None:
+            handle.result()
 
     # -- VectorIndex contract -------------------------------------------------
 
@@ -232,7 +260,8 @@ class FlatIndex:
         # The index lock spans search + id resolution so a concurrent
         # compact() can't remap slots between the scan and _resolve.
         with tracing.span("flat.search", k=k,
-                          filtered=allow_list is not None):
+                          filtered=allow_list is not None,
+                          device=placement.label(self.device)):
             with self._lock:
                 allow_mask = self._shared_operand(
                     None if allow_list is None else np.asarray(allow_list))
@@ -255,7 +284,8 @@ class FlatIndex:
         per_query = _per_query_allow(allow_list)
         with tracing.span("flat.search_batch", k=k, queries=len(queries),
                           filtered=allow_list is not None,
-                          per_query_filters=per_query) as sp:
+                          per_query_filters=per_query,
+                          device=placement.label(self.device)) as sp:
             with self._lock:
                 kind, allow_mask = self._translate_batch_allow(
                     queries, allow_list, per_query, sp)
@@ -374,8 +404,6 @@ class FlatIndex:
         from weaviate_tpu.ops.pallas_kernels import (mask_pad_cols,
                                                      pack_allow_bitmask)
 
-        import jax.numpy as jnp
-
         store = self.store
         capacity = store.capacity
         n_cols = mask_pad_cols(capacity)
@@ -391,9 +419,10 @@ class FlatIndex:
             for r, a in enumerate(lists):
                 if a is None:
                     if ones is None:
-                        ones = cache.ones(stamp, lambda: jnp.asarray(
+                        ones = cache.ones(stamp, lambda: placement.put(
                             pack_allow_bitmask(
-                                np.ones(capacity, dtype=bool), n_cols)[0]))
+                                np.ones(capacity, dtype=bool), n_cols)[0],
+                            store.device))
                     rows[r] = ones
                 elif first.setdefault(id(a), r) != r:
                     shared += 1
@@ -412,7 +441,7 @@ class FlatIndex:
                     block[j, :len(m)] = m
                 packed = pack_allow_bitmask(block, n_cols)
                 for j, (r, a, keep) in enumerate(build):
-                    rows[r] = jnp.asarray(packed[j])
+                    rows[r] = placement.put(packed[j], store.device)
                     if keep:
                         cache.attach(a, stamp, bits=rows[r])
             for r, a in enumerate(lists):
@@ -486,7 +515,8 @@ class FlatIndex:
         with tracing.span("flat.search_batch", k=k, queries=len(queries),
                           filtered=allow_list is not None,
                           per_query_filters=per_query,
-                          dispatch="async") as sp:
+                          dispatch="async",
+                          device=placement.label(self.device)) as sp:
             with self._lock:
                 kind, allow_mask = self._translate_batch_allow(
                     queries, allow_list, per_query, sp)
@@ -501,6 +531,9 @@ class FlatIndex:
                     queries=len(queries), k=k)
                 handle = self.store.search_async(queries, k, allow_mask)
                 table = self._slot_to_id  # replaced (not resized) by compact
+            if allow_list is None and self.device is not None:
+                # one set look-up where the size is known on this chip
+                placement.twins.dispatched(self, (len(queries), k))
 
         def _resolve(res, _table=table):
             d, slots = res
@@ -900,4 +933,6 @@ class FlatIndex:
             for slot, doc in enumerate(slot_to_id)
             if doc >= 0 and snap["valid"][slot]
         }
+        if idx.device is not None:
+            placement.twins.register(idx)
         return idx

@@ -314,14 +314,18 @@ class IVFStore:
         # HBM ledger: centroid + posting-list tensors publish under the
         # owner labels captured here; the delta store self-accounts (it
         # is a DeviceVectorStore constructed in this same owner scope)
-        self._hbm_owner = hbm_ledger.current_owner()
+        # (not PLACED: the list tensors are built on the default device,
+        # so the owner is taken without the shard's chip and the delta
+        # store stays beside them; runtime/placement.py, ROADMAP S12)
+        self._hbm_owner = dict(hbm_ledger.current_owner(), device=None)
         self._hbm_keys: dict[str, int] = {}
         weakref.finalize(self, hbm_ledger.ledger.release_many,
                          self._hbm_keys.values())
         # delta buffer (exact scan); delta slot -> global slot
-        self.delta = DeviceVectorStore(
-            dim, metric, capacity=min(capacity, delta_threshold * 2),
-            chunk_size=chunk_size)
+        with hbm_ledger.owner(**self._hbm_owner):
+            self.delta = DeviceVectorStore(
+                dim, metric, capacity=min(capacity, delta_threshold * 2),
+                chunk_size=chunk_size)
         self._delta_slots: dict[int, int] = {}  # delta slot -> global
         # slot -> ("delta", dslot) | ("list", flat_idx)
         self._slot_loc: dict[int, tuple] = {}

@@ -68,7 +68,7 @@ from weaviate_tpu.ops import pq as pq_ops
 from weaviate_tpu.ops import sq as sq_ops
 from weaviate_tpu.ops.distances import normalize_np
 from weaviate_tpu.parallel.mesh import n_row_shards, shardable_capacity
-from weaviate_tpu.runtime import hbm_ledger, kernelscope, tracing
+from weaviate_tpu.runtime import hbm_ledger, kernelscope, placement, tracing
 from weaviate_tpu.runtime.memwatch import MemoryMonitor
 from weaviate_tpu.runtime.metrics import rescore_dispatch_total
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
@@ -254,6 +254,14 @@ class QuantizedVectorStore:
         self.metric = metric
         self.quantization = quantization
         self.chunk_size = chunk_size
+        # HBM ledger wiring — same pattern as DeviceVectorStore: labels
+        # captured from the ambient owner scope, entries updated across
+        # grows, finalizer-released when the store is dropped; with them
+        # the owning shard's chip, where every array this store makes is
+        # committed (runtime/placement.py; None on a mesh)
+        self._hbm_owner = hbm_ledger.current_owner()
+        self.device = None if mesh is not None \
+            else self._hbm_owner.get("device")
         self.rescore_limit = rescore_limit
         self.rescore = rescore
         self._memwatch = memwatch or MemoryMonitor()
@@ -266,7 +274,7 @@ class QuantizedVectorStore:
         self.pq_centroids = pq_centroids
         self.codebook = codebook
         # SQ's twin of the codebook: the fitted range (train, restore)
-        self.sq_quantizer: sq_ops.SQQuantizer | None = None
+        self.sq_quantizer = None
         self.normalize_on_add = (
             metric in ("cosine", "cosine-dot")
             if normalize_on_add is None
@@ -295,10 +303,6 @@ class QuantizedVectorStore:
         self.use_pallas = recommended()
         self._lock = threading.RLock()
         self._count = 0
-        # HBM ledger wiring — same pattern as DeviceVectorStore: labels
-        # captured from the ambient owner scope, entries updated across
-        # grows, finalizer-released when the store is dropped
-        self._hbm_owner = hbm_ledger.current_owner()
         self._hbm_keys: dict[str, int] = {}
         weakref.finalize(self, hbm_ledger.ledger.release_many,
                          self._hbm_keys.values())
@@ -316,17 +320,52 @@ class QuantizedVectorStore:
 
     def _placed(self, arr, dim=0):
         if self.mesh is None:
-            return jnp.asarray(arr)
+            return placement.put(arr, self.device)
         from weaviate_tpu.parallel.sharded_search import shard_array
 
         return shard_array(jnp.asarray(arr), self.mesh, dim=dim)
 
     def _placed_replicated(self, arr):
         if self.mesh is None:
-            return jnp.asarray(arr)
+            return placement.put(arr, self.device)
         from weaviate_tpu.parallel.sharded_search import replicate_array
 
         return replicate_array(jnp.asarray(arr), self.mesh)
+
+    def _operand(self, arr):
+        """A host operand of one program (a query block, rows to encode)
+        where the program will run: straight to the store's device."""
+        if self.mesh is None:
+            return placement.put(arr, self.device)
+        return jnp.asarray(arr)
+
+    @property
+    def codebook(self):
+        return self._codebook
+
+    @codebook.setter
+    def codebook(self, codebook):
+        """Wherever it was fitted or read from, a codebook this store
+        scans with lies on the store's device. Caller holds ``_lock``
+        (``train``) or has the only reference to the store (``__init__``,
+        ``restore``, an epoch store handing its codebook down)."""
+        if codebook is not None and self.device is not None:
+            codebook = pq_ops.PQCodebook(
+                placement.put(codebook.centroids, self.device))
+        self._codebook = codebook
+
+    @property
+    def sq_quantizer(self):
+        return self._sq_quantizer
+
+    @sq_quantizer.setter
+    def sq_quantizer(self, quantizer):
+        """Caller holds ``_lock`` or has the only reference, as for
+        ``codebook``."""
+        if quantizer is not None and self.device is not None:
+            quantizer = quantizer._replace(
+                params=placement.put(quantizer.params, self.device))
+        self._sq_quantizer = quantizer
 
     def _code_width(self) -> int:
         if self.quantization == "pq":
@@ -345,7 +384,7 @@ class QuantizedVectorStore:
 
     def _zeros(self, shape, dtype):
         if self.mesh is None:
-            return jnp.zeros(shape, dtype)
+            return placement.zeros(shape, dtype, self.device)
         from weaviate_tpu.parallel.sharded_search import sharded_zeros
 
         return sharded_zeros(shape, dtype, self.mesh)
@@ -354,15 +393,16 @@ class QuantizedVectorStore:
         w = self._code_width()
         self.codes = self._zeros((self.capacity, w), self._code_dtype())
         self.row_terms = (
-            jnp.zeros((self.capacity,), jnp.int32)
+            self._zeros((self.capacity,), jnp.int32)
             if self.quantization == "sq" else None
         )
         self.prefix_t = (
-            jnp.zeros((self.prefix_words, self.capacity), jnp.uint32)
+            placement.zeros((self.prefix_words, self.capacity), jnp.uint32,
+                            self.device)
             if self.prefix_words else None
         )
         if self._valid_np.any():
-            self.valid = self._placed(jnp.asarray(self._valid_np))
+            self.valid = self._placed(self._valid_np)
         else:
             self.valid = self._zeros((self.capacity,), jnp.bool_)
         self._alloc_rows()
@@ -373,7 +413,7 @@ class QuantizedVectorStore:
         the device? The watchdog's answer, on top of what is there now
         (a grow holds the old rows until the new ones are written)."""
         return self._memwatch.device_fits(
-            capacity * _row_lanes(self.dim) * 4)
+            capacity * _row_lanes(self.dim) * 4, device=self.device)
 
     def _alloc_rows(self):
         """The full-precision tier, empty, at this capacity: on the
@@ -384,8 +424,9 @@ class QuantizedVectorStore:
             self.rescore_rows = self._zeros((self.capacity, self.dim),
                                             jnp.bfloat16)
         elif self.rescore == "device" and self._rows_fit(self.capacity):
-            self.rescore_rows = jnp.zeros(
-                (self.capacity, _row_lanes(self.dim)), jnp.float32)
+            self.rescore_rows = placement.zeros(
+                (self.capacity, _row_lanes(self.dim)), jnp.float32,
+                self.device)
         elif self.rescore != "none":
             if self.rescore == "device":
                 logger.warning(
@@ -449,7 +490,7 @@ class QuantizedVectorStore:
         if self.quantization == "sq":
             # encoded on the device by the write itself (_write_codes)
             return None
-        (codes,) = tracing.d2h(bq_ops.bq_encode(jnp.asarray(vectors)))
+        (codes,) = tracing.d2h(bq_ops.bq_encode(self._operand(vectors)))
         return codes
 
     def _maybe_norm(self, vectors: np.ndarray) -> np.ndarray:
@@ -575,8 +616,8 @@ class QuantizedVectorStore:
             # codes (the BQ store slices its own codes instead); derived
             # here so every write path — add, re-encode after train,
             # restore-from-vectors — carries it
-            (pref,) = tracing.d2h(bq_ops.bq_encode(
-                jnp.asarray(np.asarray(rows)[:, :self.prefix_words * 32])))
+            (pref,) = tracing.d2h(bq_ops.bq_encode(self._operand(
+                np.asarray(rows)[:, :self.prefix_words * 32])))
         m = len(slots)
         if m == 0:
             return
@@ -631,7 +672,8 @@ class QuantizedVectorStore:
                         pbuf[:m] = pref[:, :self.prefix_words]
                     pcols = pbuf.T.copy()
                 self.prefix_t = _scatter_prefix(
-                    self.prefix_t, slot_dev, jnp.asarray(pcols), mask_dev)
+                    self.prefix_t, slot_dev, self._placed_replicated(pcols),
+                    mask_dev)
         else:
             # mask-redirect padding entries like _scatter_codes does —
             # a bare scatter of the zero-padded slot buffer would mark
@@ -831,13 +873,13 @@ class QuantizedVectorStore:
             if allow_mask is not None and allow_mask.ndim == 2:
                 allow_bits, allow_rows_dev = batched_mask_operands(
                     allow_mask, len(queries), capacity, self.mesh,
-                    owner=self._hbm_owner)
+                    owner=self._hbm_owner, device=self.device)
             elif allow_mask is not None:
                 full = np.zeros(capacity, dtype=bool)
                 w = min(len(allow_mask), capacity)
                 full[:w] = allow_mask[:w]
                 valid = apply_allow_mask(valid, self._placed(full))
-            d, i = self._scan(jnp.asarray(queries), min(k_cand, capacity),
+            d, i = self._scan(self._operand(queries), min(k_cand, capacity),
                               valid, min(k_out, capacity),
                               allow_bits=allow_bits,
                               allow_rows=allow_rows_dev)
@@ -912,7 +954,7 @@ class QuantizedVectorStore:
                     sp.set(path="bitmask_batched")
                     allow_bits, allow_rows_dev = batched_mask_operands(
                         allow_mask, len(queries), capacity, self.mesh,
-                        owner=self._hbm_owner)
+                        owner=self._hbm_owner, device=self.device)
                 elif allow_mask is not None:
                     full = np.zeros(capacity, dtype=bool)
                     full[: len(allow_mask)] = allow_mask[:capacity]
@@ -938,7 +980,7 @@ class QuantizedVectorStore:
                           else "shared_mask" if allow_mask is not None
                           else "full_scan"))
                 d, i = self._scan(
-                    jnp.asarray(queries), k_cand, valid, k_out,
+                    self._operand(queries), k_cand, valid, k_out,
                     allow_bits=allow_bits, allow_rows=allow_rows_dev,
                     rescore_rows=(self.rescore_rows if mode == "fused"
                                   else None))
@@ -1036,6 +1078,16 @@ class QuantizedVectorStore:
                 self.set_at_prenormalized(np.arange(len(live)), vecs)
             return mapping
 
+    def twin_shapes(self):
+        """What decides this store's scan program besides the batch and
+        k (runtime/placement.py ``Twins``); None on a mesh."""
+        if self.mesh is not None:
+            return None
+        return (self.quantization, self.capacity, self.dim, self.metric,
+                self.selection, self.chunk_size, self.rescore_limit,
+                self.pq_segments, self.pq_centroids, self.prefix_words,
+                self.rescore_mode(), self.use_pallas)
+
     def snapshot(self) -> dict:
         with self._lock:
             snap = {
@@ -1109,7 +1161,7 @@ class QuantizedVectorStore:
                 if snap.get("prefix_t") is not None \
                         and store.prefix_t is not None:
                     pt = snap["prefix_t"]
-                    store.prefix_t = jnp.asarray(np.pad(
+                    store.prefix_t = store._placed_replicated(np.pad(
                         pt, ((0, 0),
                              (0, store.capacity - pt.shape[1]))))
         store._count = snap["count"]

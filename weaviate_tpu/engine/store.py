@@ -36,7 +36,7 @@ import numpy as np
 from weaviate_tpu.ops.candidates import shared_candidates_topk
 from weaviate_tpu.ops.distances import normalize
 from weaviate_tpu.ops.topk import chunked_topk_distances
-from weaviate_tpu.runtime import hbm_ledger, kernelscope, tracing
+from weaviate_tpu.runtime import hbm_ledger, kernelscope, placement, tracing
 from weaviate_tpu.runtime import transfer
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
 from weaviate_tpu.parallel.mesh import n_row_shards, shardable_capacity
@@ -110,7 +110,7 @@ def stack_allow_rows(*rows):
 
 
 def batched_mask_operands(allow_mask, n_queries: int, capacity: int, mesh,
-                          owner: dict | None = None):
+                          owner: dict | None = None, device=None):
     """[B, capacity] per-query mask -> scan-kernel operands, under a
     ``store.mask_pack`` span: single-device packs the bitmask on the host
     (32x smaller transfer); a mesh ships the bool mask column-sharded so
@@ -128,8 +128,8 @@ def batched_mask_operands(allow_mask, n_queries: int, capacity: int, mesh,
             from weaviate_tpu.ops.pallas_kernels import (mask_pad_cols,
                                                          pack_allow_bitmask)
 
-            bits = jnp.asarray(pack_allow_bitmask(
-                allow_mask, mask_pad_cols(capacity)))
+            bits = placement.put(pack_allow_bitmask(
+                allow_mask, mask_pad_cols(capacity)), device)
             hbm_ledger.ledger.track("allow_bitmask", bits, **owner)
             return bits, None
         if (allow_mask.shape == (n_queries, capacity)
@@ -257,6 +257,12 @@ class DeviceVectorStore:
         # entries, and a finalizer releases them when the store is
         # dropped (e.g. compress() swapping in a quantized store).
         self._hbm_owner = hbm_ledger.current_owner()
+        # the chip the owning shard was placed on (runtime/placement.py):
+        # every array this store makes, now or when it grows, is
+        # committed there and its programs follow; None on a mesh and
+        # outside any shard (the default device, as before)
+        self.device = None if mesh is not None \
+            else self._hbm_owner.get("device")
         self._hbm_keys: dict[str, int] = {}
         weakref.finalize(self, hbm_ledger.ledger.release_many,
                          self._hbm_keys.values())
@@ -282,13 +288,25 @@ class DeviceVectorStore:
 
     def _placed(self, arr, dim=0):
         if self.mesh is None:
-            return jnp.asarray(arr)
+            return placement.put(arr, self.device)
         return shard_array(jnp.asarray(arr), self.mesh, dim=dim)
 
+    def _operand(self, arr):
+        """A dispatch's host operand (the query block, a slot list)
+        where the program will run: straight to the store's device."""
+        if self.mesh is None:
+            return placement.put(arr, self.device)
+        return jnp.asarray(arr)
+
+    def _zeros(self, shape, dtype):
+        if self.mesh is None:
+            return placement.zeros(shape, dtype, self.device)
+        return shard_array(jnp.zeros(shape, dtype), self.mesh)
+
     def _alloc(self, capacity: int):
-        self.vectors = self._placed(jnp.zeros((capacity, self.dim), dtype=self.dtype))
-        self.valid = self._placed(jnp.zeros((capacity,), dtype=jnp.bool_))
-        self.sq_norms = self._placed(jnp.zeros((capacity,), dtype=jnp.float32))
+        self.vectors = self._zeros((capacity, self.dim), self.dtype)
+        self.valid = self._zeros((capacity,), jnp.bool_)
+        self.sq_norms = self._zeros((capacity,), jnp.float32)
         self._hbm_sync()
 
     def _hbm_sync(self):
@@ -497,7 +515,7 @@ class DeviceVectorStore:
 
     def _placed_replicated(self, arr):
         if self.mesh is None:
-            return jnp.asarray(arr)
+            return placement.put(arr, self.device)
         return replicate_array(jnp.asarray(arr), self.mesh)
 
     # -- queries -------------------------------------------------------------
@@ -529,7 +547,7 @@ class DeviceVectorStore:
         slots = np.atleast_1d(np.asarray(slots, dtype=np.int32))
         with self._lock:
             self._flush_staged_locked()
-            rows = self.vectors[jnp.asarray(slots)]
+            rows = self.vectors[self._operand(slots)]
         return np.asarray(rows, dtype=np.float32)
 
     def search(self, queries: np.ndarray, k: int, allow_mask: np.ndarray | None = None):
@@ -598,7 +616,7 @@ class DeviceVectorStore:
                         queries=len(queries), k=k)
                     allow_bits, allow_rows_dev = batched_mask_operands(
                         allow_mask, len(queries), capacity, self.mesh,
-                        owner=self._hbm_owner)
+                        owner=self._hbm_owner, device=self.device)
                 elif allow_mask is not None:
                     # ONE filter for the batch. An index that keeps its
                     # filters' operands hands the slot list over as it
@@ -638,7 +656,7 @@ class DeviceVectorStore:
                     cs = min(self.chunk_size, capacity // self.n_shards)
                     if self.mesh is None:
                         d, i = chunked_topk_distances(
-                            jnp.asarray(queries), vectors, k=k_eff,
+                            self._operand(queries), vectors, k=k_eff,
                             chunk_size=cs, metric=metric, valid=valid,
                             x_sq_norms=norms, use_pallas=self.use_pallas,
                             selection=self.selection,
@@ -646,7 +664,7 @@ class DeviceVectorStore:
                         )
                     else:
                         d, i = sharded_topk(
-                            jnp.asarray(queries), vectors, valid, norms,
+                            self._operand(queries), vectors, valid, norms,
                             k=k_eff, chunk_size=cs, metric=metric,
                             mesh=self.mesh, use_pallas=self.use_pallas,
                             selection=self.selection,
@@ -694,7 +712,7 @@ class DeviceVectorStore:
             if allow_mask is not None and allow_mask.ndim == 2:
                 allow_bits, allow_rows_dev = batched_mask_operands(
                     allow_mask, len(queries), capacity, self.mesh,
-                    owner=self._hbm_owner)
+                    owner=self._hbm_owner, device=self.device)
             elif allow_mask is not None:
                 full = np.zeros(capacity, dtype=bool)
                 w = min(len(allow_mask), capacity)
@@ -706,12 +724,12 @@ class DeviceVectorStore:
             cs = min(self.chunk_size, capacity // self.n_shards)
             if self.mesh is None:
                 return chunked_topk_distances(
-                    jnp.asarray(queries), vectors, k=k_eff, chunk_size=cs,
+                    self._operand(queries), vectors, k=k_eff, chunk_size=cs,
                     metric=metric, valid=valid, x_sq_norms=norms,
                     use_pallas=self.use_pallas, selection=self.selection,
                     allow_bits=allow_bits)
             return sharded_topk(
-                jnp.asarray(queries), vectors, valid, norms, k=k_eff,
+                self._operand(queries), vectors, valid, norms, k=k_eff,
                 chunk_size=cs, metric=metric, mesh=self.mesh,
                 use_pallas=self.use_pallas, selection=self.selection,
                 allow_rows=allow_rows_dev)
@@ -737,7 +755,7 @@ class DeviceVectorStore:
             return AllowSlots(None, m_allowed)
         slot_buf = np.full(bucket, -1, dtype=np.int32)
         slot_buf[:m_allowed] = np.flatnonzero(slot_mask)
-        return AllowSlots(jnp.asarray(slot_buf), m_allowed)
+        return AllowSlots(placement.put(slot_buf, self.device), m_allowed)
 
     def _dispatch_gathered(self, queries: np.ndarray, k: int, slots):
         """Filtered search at low selectivity: gather the allowed rows
@@ -751,7 +769,7 @@ class DeviceVectorStore:
         metric = ("cosine" if self.metric in ("cosine", "cosine-dot")
                   else self.metric)
         return shared_candidates_topk(
-            jnp.asarray(queries), slots, self.vectors,
+            self._operand(queries), slots, self.vectors,
             min(k, slots.shape[0]), metric, row_norms=self.sq_norms,
             valid=self.valid, use_pallas=self.use_pallas,
             selection=self.selection,
@@ -830,6 +848,17 @@ class DeviceVectorStore:
                 "dtype": jnp.dtype(self.dtype).name,
                 "chunk_size": self.chunk_size,
             }
+
+    def twin_shapes(self):
+        """What decides this store's scan program besides the batch and
+        k, for runtime/placement.py ``Twins``: stores that read equal
+        here on different chips run the same programs, each its own
+        copy. None on a mesh."""
+        if self.mesh is not None:
+            return None
+        return ("flat", self.capacity, self.dim,
+                jnp.dtype(self.dtype).name, self.metric, self.selection,
+                self.chunk_size, self.use_pallas)
 
     @classmethod
     def restore(cls, snap: dict, **kwargs) -> "DeviceVectorStore":

@@ -41,17 +41,24 @@ import weakref
 from collections import deque
 from dataclasses import dataclass
 
-_UNOWNED = {"collection": "_unowned", "shard": "-", "tenant": ""}
+from weaviate_tpu.runtime.placement import label
+
+_UNOWNED = {"collection": "_unowned", "shard": "-", "tenant": "",
+            "device": None}
 
 _owner_ctx: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
     "hbm_owner", default=None)
 
 
 @contextlib.contextmanager
-def owner(collection: str, shard: str = "-", tenant: str = ""):
-    """Scope: allocations registered inside run under these labels."""
+def owner(collection: str, shard: str = "-", tenant: str = "", device=None):
+    """Scope: allocations registered inside run under these labels.
+    ``device`` is the chip the owning shard was placed on
+    (runtime/placement.py; None on a mesh or outside any shard): a store
+    built inside commits its arrays there, and the entries carry it."""
     token = _owner_ctx.set({"collection": str(collection),
-                            "shard": str(shard), "tenant": str(tenant)})
+                            "shard": str(shard), "tenant": str(tenant),
+                            "device": device})
     try:
         yield
     finally:
@@ -59,9 +66,9 @@ def owner(collection: str, shard: str = "-", tenant: str = ""):
 
 
 def current_owner() -> dict:
-    """The ambient (collection, shard, tenant) labels, or the _unowned
-    placeholder for allocations made outside any shard scope (tests,
-    benches, module-level singletons)."""
+    """The ambient (collection, shard, tenant, device) of the owner
+    scope, or the _unowned placeholder for allocations made outside any
+    shard scope (tests, benches, module-level singletons)."""
     return dict(_owner_ctx.get() or _UNOWNED)
 
 
@@ -76,6 +83,7 @@ class Entry:
     nbytes: int
     sharding: str  # "single" | "sharded" | "replicated" | "estimate"
     placement: str  # "device" | "host"
+    device: str = ""  # placement.label of the owner's chip; "" = none
 
 
 class HBMLedger:
@@ -100,6 +108,7 @@ class HBMLedger:
         self._by_collection: dict[str, int] = {}
         self._by_shard: dict[tuple[str, str], int] = {}
         self._by_gauge: dict[tuple[str, str, str], int] = {}
+        self._by_device: dict[str, int] = {}
         # mesh host count hint (set once at startup when the mesh is
         # known) so scrape-time host-gauge refreshes need no mesh access
         self._host_count_hint = 1
@@ -110,7 +119,7 @@ class HBMLedger:
                  collection: str | None = None, shard: str | None = None,
                  tenant: str | None = None, dtype=None,
                  sharding: str = "single",
-                 placement: str = "device") -> int:
+                 placement: str = "device", device=None) -> int:
         """Record an allocation; returns a key for update()/release().
         Labels default from the ambient ``owner()`` scope."""
         own = current_owner()
@@ -125,6 +134,7 @@ class HBMLedger:
             nbytes=max(0, int(nbytes)),
             sharding=sharding,
             placement=placement,
+            device=label(device if device is not None else own["device"]),
         )
         with self._lock:
             self._release_dropped()
@@ -224,6 +234,9 @@ class HBMLedger:
             self._by_collection.get(e.collection, 0) + delta
         if self._by_collection[e.collection] <= 0:
             del self._by_collection[e.collection]
+        self._by_device[e.device] = self._by_device.get(e.device, 0) + delta
+        if self._by_device[e.device] <= 0:
+            del self._by_device[e.device]
         sk = (e.collection, e.shard)
         self._by_shard[sk] = self._by_shard.get(sk, 0) + delta
         if self._by_shard[sk] <= 0:
@@ -233,17 +246,25 @@ class HBMLedger:
         gauge_val = self._by_gauge[gk]
         if gauge_val <= 0:
             del self._by_gauge[gk]
-        self._export_gauges(gk, gauge_val)
+        self._export_gauges(gk, gauge_val, e.device)
 
-    def _export_gauges(self, gk: tuple, gauge_val: int) -> None:
+    def _export_gauges(self, gk: tuple, gauge_val: int,
+                       device: str) -> None:
         try:
             from weaviate_tpu.runtime.metrics import (hbm_bytes,
+                                                      hbm_device_bytes,
                                                       hbm_peak_bytes)
 
             if gauge_val <= 0:
                 hbm_bytes.remove(*gk)
             else:
                 hbm_bytes.labels(*gk).set(float(gauge_val))
+            if device:
+                on_device = self._by_device.get(device, 0)
+                if on_device <= 0:
+                    hbm_device_bytes.remove(device)
+                else:
+                    hbm_device_bytes.labels(device).set(float(on_device))
             hbm_peak_bytes.set(float(self._device_peak))
         except Exception:  # noqa: BLE001 — accounting must never fail allocs
             pass
@@ -261,6 +282,14 @@ class HBMLedger:
         with self._lock:
             self._release_dropped()
             return self._device_peak
+
+    def device_bytes(self) -> dict[str, int]:
+        """Device label -> live device bytes registered by owners placed
+        there (``""``: a mesh's, the runtime's and unowned entries). Sums
+        to ``total_bytes()``."""
+        with self._lock:
+            self._release_dropped()
+            return dict(self._by_device)
 
     def collection_bytes(self, collection: str) -> int:
         with self._lock:
@@ -369,6 +398,7 @@ class HBMLedger:
             "tenant": e.tenant, "component": e.component,
             "dtype": e.dtype, "nbytes": e.nbytes,
             "sharding": e.sharding, "placement": e.placement,
+            "device": e.device,
         } for e in entries]
 
     def snapshot(self) -> dict:
@@ -376,6 +406,7 @@ class HBMLedger:
         return {
             "totalBytes": self.total_bytes(),
             "peakBytes": self.peak_bytes(),
+            "devices": self.device_bytes(),
             "collections": self.breakdown(),
             "top": self.top(),
         }

@@ -79,6 +79,10 @@ _variants: dict[tuple[str, int, int], dict] = {}
 _meters: dict[tuple[str, str], float] = {}
 _total_device_s = 0.0
 _dispatches = {"drain": 0, "wall": 0}
+# chip of the host (``placement.label``) -> [dispatches, device seconds]:
+# what each chip's share of the attributed residency is where a
+# collection's shards lie on several (runtime/placement.py)
+_by_device: dict[str, list] = {}
 
 
 def _bytes_bucket(nbytes: int) -> int:
@@ -135,7 +139,8 @@ def result_nbytes(value) -> int:
 
 
 def record_dispatch(kind: str, b_bucket: int, k_bucket: int,
-                    device_s: float, source: str = "drain") -> None:
+                    device_s: float, source: str = "drain",
+                    device: str = "") -> None:
     """One dispatch's attributed device residency for the (index-kind,
     batch-bucket, k-bucket) compiled variant. ``source`` is ``drain``
     (drain-thread stamps minus memcpy EWMA) or ``wall`` (sync/null-
@@ -156,6 +161,10 @@ def record_dispatch(kind: str, b_bucket: int, k_bucket: int,
             v["source"] = source
         _total_device_s += device_s
         _dispatches[source] = _dispatches.get(source, 0) + 1
+        if device:
+            on = _by_device.setdefault(device, [0, 0.0])
+            on[0] += 1
+            on[1] += device_s
     try:
         dispatch_device_seconds.labels(
             key[0], str(key[1]), str(key[2]), source).observe(device_s)
@@ -183,7 +192,8 @@ def fold_dispatch(rec: dict, source: str, nbytes: int = 0
     rec["transfer_ms"] = transfer_s * 1000.0
     rec["t_source"] = source
     record_dispatch(rec.get("kind", ""), rec.get("b_pad") or 1,
-                    rec.get("k") or 0, device_s, source)
+                    rec.get("k") or 0, device_s, source,
+                    rec.get("device", ""))
     return device_s, transfer_s
 
 
@@ -591,9 +601,12 @@ def snapshot() -> dict:
                   for (c, t), s in sorted(_meters.items())}
         total = _total_device_s
         disp = dict(_dispatches)
+        devices = {d: {"dispatches": n, "device_ms": round(sec * 1e3, 3)}
+                   for d, (n, sec) in sorted(_by_device.items())}
     return {"variants": variants, "memcpy": memcpy, "meters": meters,
             "total_device_seconds": round(total, 6),
-            "dispatches": disp, "captures": len(list_captures())}
+            "dispatches": disp, "devices": devices,
+            "captures": len(list_captures())}
 
 
 def reset_for_tests() -> None:
@@ -611,6 +624,7 @@ def reset_for_tests() -> None:
         _total_device_s = 0.0
         _dispatches.clear()
         _dispatches.update({"drain": 0, "wall": 0})
+        _by_device.clear()
         _capture_seq = 0
     _data_dir = None
     _keep = 8

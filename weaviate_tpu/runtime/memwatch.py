@@ -27,6 +27,8 @@ import os
 import threading
 import time
 
+from weaviate_tpu.runtime.placement import label as _label
+
 #: seconds before an "allocator stats unavailable" verdict is re-probed.
 #: One transient failure (backend still initializing) must not disable
 #: device stats forever; re-probing every request would re-pay backend
@@ -91,12 +93,14 @@ class MemoryMonitor:
 
     # -- device -----------------------------------------------------------
 
-    def device_budget(self, stats: dict | None = None) -> int | None:
+    def device_budget(self, stats: dict | None = None,
+                      device=None) -> int | None:
         """HBM budget in bytes; explicit limit wins, else read from the
-        backend (a TPU exposes memory_stats), else the
+        backend (a TPU exposes memory_stats: ``device``'s own limit
+        where one is named, else the first device's), else the
         HBM_DEVICE_LIMIT_BYTES env override (the only option on backends
         with no allocator stats)."""
-        budget = self._device_budget_raw(stats)
+        budget = self._device_budget_raw(stats, device)
         try:
             from weaviate_tpu.runtime.metrics import hbm_budget_bytes
 
@@ -105,11 +109,13 @@ class MemoryMonitor:
             pass
         return budget
 
-    def _device_budget_raw(self, stats: dict | None = None) -> int | None:
+    def _device_budget_raw(self, stats: dict | None = None,
+                           device=None) -> int | None:
         if self.device_limit is not None:
             return self.device_limit
         stats = device_memory_stats() if stats is None else stats
-        for dev in stats.values():
+        own = stats.get(_label(device))
+        for dev in ([own] if own else []) + list(stats.values()):
             if dev.get("bytesLimit"):
                 return int(dev["bytesLimit"])
         raw = os.environ.get("HBM_DEVICE_LIMIT_BYTES")
@@ -120,17 +126,23 @@ class MemoryMonitor:
                 pass
         return None
 
-    def device_in_use(self, stats: dict | None = None) -> int:
+    def device_in_use(self, stats: dict | None = None, device=None) -> int:
         """Current device usage: allocator stats when the backend has
-        them, else the ledger's registered device bytes. The ledger
-        projection is the LOGICAL global footprint (on a mesh, summed
-        over shards) — conservative against a per-device allocator
-        budget, exact against an operator-granted
+        them, else the ledger's registered device bytes. ``device`` (the
+        chip the bytes are bound for: a shard's own,
+        runtime/placement.py) is asked alone: its allocator's bytes in
+        use, or the ledger's bytes of owners placed there, so a shard
+        bound for an empty chip is not refused because another is full.
+        Without one: the FULLEST device, and the ledger's total. The
+        ledger projection is the LOGICAL global footprint (on a mesh,
+        summed over shards) — conservative against a per-device
+        allocator budget, exact against an operator-granted
         HBM_DEVICE_LIMIT_BYTES. Records which source answered in
         ``_last_source`` (the admission path probes ONCE and threads
         the dict through)."""
         stats = device_memory_stats() if stats is None else stats
-        in_use = [d["bytesInUse"] for d in stats.values()
+        own = stats.get(_label(device))
+        in_use = [d["bytesInUse"] for d in ([own] if own else stats.values())
                   if d.get("bytesInUse") is not None]
         # _last_source is read by the rejection path on other threads —
         # publish it under the monitor lock (callers never hold it here)
@@ -140,15 +152,19 @@ class MemoryMonitor:
             return max(in_use)
         with self._lock:
             self._last_source = "ledger"
+        if device is not None:
+            return self.ledger.device_bytes().get(_label(device), 0)
         return self.ledger.total_bytes()
 
-    def check_device_alloc(self, nbytes: int, what: str = "") -> None:
+    def check_device_alloc(self, nbytes: int, what: str = "",
+                           device=None) -> None:
         """Raise InsufficientMemoryError if landing ``nbytes`` more on the
         device would cross the high watermark (reference CheckAlloc
         semantics: refuse BEFORE allocating, don't OOM mid-import).
         Hysteresis: once tripped, keeps refusing until usage falls under
-        the low watermark."""
-        budget, in_use = self._device_budget_and_use()
+        the low watermark. ``device``: the chip the bytes are bound for
+        (``device_in_use``)."""
+        budget, in_use = self._device_budget_and_use(device)
         if budget is None:
             return
         source = getattr(self, "_last_source", "ledger")
@@ -173,29 +189,29 @@ class MemoryMonitor:
                 f"({source} usage {in_use})",
                 projected=projected, budget=budget, source=source)
 
-    def device_fits(self, nbytes: int) -> bool:
+    def device_fits(self, nbytes: int, device=None) -> bool:
         """Would ``nbytes`` more on the device stay under the high
         watermark? ``check_device_alloc``'s rule as a question: nothing
         is raised, latched or counted. For a caller that has somewhere
         else to put the bytes (a compressed store's float32 rescore
         rows: the host), where a refused import has not. No budget (a
         backend without allocator stats and no configured limit): yes."""
-        budget, in_use = self._device_budget_and_use()
+        budget, in_use = self._device_budget_and_use(device)
         return budget is None or (
             not self.under_pressure
             and in_use + int(nbytes) <= budget * self.high_watermark)
 
-    def _device_budget_and_use(self) -> tuple[int | None, int]:
-        """(budget, bytes in use) from ONE stats probe; the
-        explicit-limit fast path skips the probe for the budget. No
-        budget: ``(None, 0)``."""
+    def _device_budget_and_use(self, device=None
+                               ) -> tuple[int | None, int]:
+        """(budget, bytes in use) of ``device`` (None: see
+        ``device_in_use``) from ONE stats probe; the explicit-limit fast
+        path skips the probe for the budget. No budget: ``(None, 0)``."""
         stats = None if self.device_limit is not None \
             else device_memory_stats()
-        budget = self.device_budget(stats)
+        budget = self.device_budget(stats, device)
         if budget is None:
             return None, 0
-        return budget, (self.device_in_use() if stats is None
-                        else self.device_in_use(stats))
+        return budget, self.device_in_use(stats, device)
 
     @staticmethod
     def _pressure_event(action: str, projected: int, budget: int,
@@ -259,10 +275,10 @@ def _probe_device_stats() -> dict:
     import jax
 
     out = {}
-    for i, dev in enumerate(jax.devices()):
+    for dev in jax.devices():
         stats = dev.memory_stats()
         if stats:
-            out[f"{dev.platform}:{i}"] = {
+            out[_label(dev)] = {
                 "bytesInUse": stats.get("bytes_in_use"),
                 "bytesLimit": stats.get("bytes_limit"),
                 "peakBytesInUse": stats.get("peak_bytes_in_use"),

@@ -470,8 +470,9 @@ vector_index_tombstones = registry.gauge(
     ("collection", "shard", "vector"))
 vector_index_hbm_bytes = registry.gauge(
     "weaviate_tpu_vector_index_hbm_bytes",
-    "Device memory held by the index's arrays",
-    ("collection", "shard", "vector"))
+    "Device memory held by the index's arrays, and the chip of the host "
+    "they lie on (empty on a mesh)",
+    ("collection", "shard", "vector", "device"))
 vector_index_compressed = registry.gauge(
     "weaviate_tpu_vector_index_compressed",
     "1 when the index serves from quantized codes",
@@ -540,7 +541,9 @@ rescore_dispatch_total = registry.counter(
 batcher_compile_bucket = registry.counter(
     "weaviate_tpu_query_batcher_compile_bucket_total",
     "Coalesced dispatches by padded pow2 (batch, k) bucket — the bucket "
-    "set bounds the number of compiled program variants", ("b", "k"))
+    "set bounds the number of compiled program variants — and by the "
+    "chip the batcher's index lies on (runtime/placement.py; empty on a "
+    "mesh)", ("b", "k", "device"))
 batcher_overlapped = registry.counter(
     "weaviate_tpu_query_batcher_overlapped_total",
     "Dispatches launched while a previous batch was still draining "
@@ -605,6 +608,11 @@ hbm_bytes = registry.gauge(
     "weaviate_tpu_hbm_bytes",
     "Live device bytes registered in the HBM ledger",
     ("collection", "shard", "component"))
+hbm_device_bytes = registry.gauge(
+    "weaviate_tpu_hbm_device_bytes",
+    "Live ledger device bytes of the owners placed on one chip of the "
+    "host (runtime/placement.py): what admission asks that chip about "
+    "where the allocator gives no stats", ("device",))
 hbm_peak_bytes = registry.gauge(
     "weaviate_tpu_hbm_peak_bytes",
     "High-water mark of ledger-registered device bytes since process "

@@ -62,8 +62,8 @@ import time
 
 import numpy as np
 
-from weaviate_tpu.runtime import (degrade, faultline, kernelscope, retry,
-                                  tailboard, tracing)
+from weaviate_tpu.runtime import (degrade, faultline, kernelscope, placement,
+                                  retry, tailboard, tracing)
 from weaviate_tpu.runtime.transfer import TransferPipeline
 
 #: bounded intake: past this queue depth the batcher sheds load with a
@@ -198,6 +198,9 @@ class QueryBatcher:
         # layer passes its collection/shard; standalone batchers fall
         # back to the ambient owner scope)
         self._hbm_owner = owner or hbm_ledger.current_owner()
+        # the chip this batcher's index lies on (runtime/placement.py):
+        # the ``device`` label of its dispatch counter and records
+        self._device_label = placement.label(self._hbm_owner.get("device"))
         # metering labels: one batcher serves one (shard, vector), so
         # every request a dispatch coalesces shares these
         self._meter_labels = (
@@ -419,7 +422,8 @@ class QueryBatcher:
             # runs wherever no other stage is marked (the two waits,
             # launch, mask_pack, ...), so the side's stages never
             # overlap and cover its wall time
-            rec = tailboard.new_dispatch("batcher", self.kind)
+            rec = tailboard.new_dispatch("batcher", self.kind,
+                                         self._device_label)
             side = tailboard.bind_dispatch(rec, "worker", "assemble")
             drained = self._await_drain(side)
             if drained is not None:
@@ -550,7 +554,8 @@ class QueryBatcher:
         opened before it waited (its side is bound to this thread); a
         direct caller gets a fresh one."""
         if rec is None:
-            rec = tailboard.new_dispatch("batcher", self.kind)
+            rec = tailboard.new_dispatch("batcher", self.kind,
+                                         self._device_label)
         # split the drain: filtered requests coalesce with the plain ones
         # into ONE bitmask-batched device program; only index types
         # without batched-filter support and highly selective filters
@@ -572,7 +577,8 @@ class QueryBatcher:
             # (path=solo: the stage family labels it ``<kind>.solo``),
             # bound over the drain's while it runs; not filed in the
             # flight ring, which keeps one entry per drain
-            srec = it.rec = tailboard.new_dispatch("batcher", self.kind)
+            srec = it.rec = tailboard.new_dispatch(
+                "batcher", self.kind, self._device_label)
             t_exec = time.perf_counter()
             srec.update(path="solo", batch=1, b_pad=1, k=it.k,
                         stamps={"exec": t_exec})
@@ -654,7 +660,8 @@ class QueryBatcher:
         from weaviate_tpu.runtime.metrics import (
             batcher_compile_bucket, batcher_filtered_batched)
 
-        batcher_compile_bucket.labels(b=str(b_pad), k=str(k_bucket)).inc()
+        batcher_compile_bucket.labels(b=str(b_pad), k=str(k_bucket),
+                                      device=self._device_label).inc()
         if filtered:
             batcher_filtered_batched.inc(len(filtered))
         # the shared dispatch runs under ONE waiter's trace context (the

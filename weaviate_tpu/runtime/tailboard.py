@@ -1240,12 +1240,14 @@ def _slowlog() -> FlightRing:
     return _slowlog_ring
 
 
-def new_dispatch(plane: str, kind: str = "") -> dict:
+def new_dispatch(plane: str, kind: str = "", device: str = "") -> dict:
     """A dispatch record not yet in the ring: the worker opens one
     before it waits for work, so the wait that precedes a dispatch is
     stamped into that dispatch's sheet. :func:`record_dispatch` files
-    it."""
-    return {"plane": plane, "kind": kind}
+    it. ``device``: the chip the dispatch's programs run on
+    (``placement.label``; "" on a mesh or outside any shard), which the
+    record of every ``wtpu.*`` stage of that dispatch then carries."""
+    return {"plane": plane, "kind": kind, "device": device}
 
 
 def record_dispatch(plane: str, rec: dict | None = None, **fields) -> dict:
