@@ -1,8 +1,11 @@
 """A collection of eight shards on one node answers nearVector as ONE
 corpus: held, id for id, against ``tests/multishard_reference.py`` (numpy,
 float64, no notion of a shard), serial and under 32 threads; the fan-out
-holds no pool thread a shard, queues one item a shard a request, and is
-bounded by the request's one deadline (ISSUE 34)."""
+holds no pool thread a shard and is bounded by the request's one deadline
+(ISSUE 34). Since ISSUE 42 a plain request over the eight local shards is
+ONE item on the collection's drain (``db/drain.py``), which launches every
+member index's scan; a filtered one is one item a shard, as before
+(``tests/test_collection_drain.py`` holds the two routes side by side)."""
 
 from __future__ import annotations
 
@@ -191,10 +194,9 @@ def test_32_threads_at_once_give_the_serial_answers(world, metric):
     assert len(got) == 32 * 6
     for (_c, j), ids in got.items():
         assert ids == serial[j]
-    # coalesced: every shard's batcher saw the 32 at once
-    for shard in col.shards.values():
-        b = shard._query_batchers[""]
-        assert b.batched_queries > b.dispatches
+    # coalesced: the collection's drain saw the 32 at once
+    b = col._drains[""].batcher
+    assert b.batched_queries > b.dispatches
 
 
 @pytest.mark.parametrize("metric", METRICS)
@@ -231,6 +233,16 @@ def test_one_shard_answers_as_before(world):
     assert not col._pool._threads
 
 
+def _batchers(col) -> dict:
+    """The batchers a plain ``near_vector`` on ``col`` queues on: the
+    collection's drain where it has several local shards, else its one
+    shard's own."""
+    if len(col.shards) > 1:
+        return {"drain": col._drain_for("", dict(col.shards)).batcher}
+    return {name: shard._query_batcher("", shard.vector_indexes[""])
+            for name, shard in col.shards.items()}
+
+
 class _Gate:
     """Holds the workers of a collection's batchers at their next
     dispatch and counts what each has taken out of its queue."""
@@ -238,10 +250,8 @@ class _Gate:
     def __init__(self, col):
         self.open = threading.Event()
         self.held = {}
-        self.batchers = {}
-        for name, shard in col.shards.items():
-            b = shard._query_batcher("", shard.vector_indexes[""])
-            self.batchers[name] = b
+        self.batchers = _batchers(col)
+        for name, b in self.batchers.items():
             self.held[name] = 0
             b._dispatch = self._gated(name, b._dispatch)
 
@@ -263,10 +273,11 @@ class _Gate:
             del b._dispatch
 
 
-def test_a_request_holds_no_pool_thread_and_queues_one_item_a_shard(world):
-    """32 concurrent requests, the workers held: every shard's batcher
-    holds 32 items (the parent admitted eight shard searches in all), and
-    the collection's pool has started no thread."""
+def test_a_request_holds_no_pool_thread_and_queues_one_item(world):
+    """32 concurrent requests, the worker held: the collection's drain
+    holds 32 items, ONE a request (one a shard a request until ISSUE 42),
+    no shard's own batcher holds any, and the collection's pool has
+    started no thread."""
     col = world.multi["cosine"]
     col.near_vector(world.queries[0], k=10, include_objects=False)
     gate = _Gate(col)
@@ -283,7 +294,9 @@ def test_a_request_holds_no_pool_thread_and_queues_one_item_a_shard(world):
         while time.time() < deadline and \
                 set(gate.enqueued().values()) != {32}:
             time.sleep(0.01)
-        assert gate.enqueued() == {f"shard-{h}": 32 for h in range(SHARDS)}
+        assert gate.enqueued() == {"drain": 32}
+        assert not any(len(b._queue) for shard in col.shards.values()
+                       for b in shard._query_batchers.values())
         assert not col._pool._threads
         assert not got
     finally:
@@ -323,8 +336,7 @@ def test_a_spent_deadline_is_typed_once_and_leaves_nothing_queued(world,
             col.near_vector(world.queries[2], k=10, include_objects=False)
         assert 0.19 < time.perf_counter() - t0 < 2.0
         assert deadline_exceeded_total.labels("batcher").value - counted == 1
-        assert [len(b._queue) for b in gate.batchers.values()] == \
-            [0] * len(col.shards)
+        assert [len(b._queue) for b in gate.batchers.values()] == [0]
         with retry.deadline(1e-9), pytest.raises(retry.DeadlineExceeded):
             col.near_vector(world.queries[2], k=10, include_objects=False)
     finally:
@@ -338,24 +350,37 @@ class _Boom(RuntimeError):
     pass
 
 
+class _Down:
+    """One shard's scans raise: the entry points of its index (what the
+    collection's drain launches, resolved per dispatch) and of its own
+    batcher (what a one-shard request rides)."""
+
+    def __init__(self, shard):
+        self.idx = shard.vector_indexes[""]
+        self.b = shard._query_batcher("", self.idx)
+
+    def __enter__(self):
+        def boom(*_a, **_k):
+            raise _Boom("shard down")
+
+        self.saved = self.b._batch_fn, self.b._async_fn
+        self.b._batch_fn, self.b._async_fn = boom, None
+        self.idx.search_by_vector_batch = boom
+        self.idx.search_by_vector_batch_async = boom
+
+    def __exit__(self, *_exc):
+        self.b._batch_fn, self.b._async_fn = self.saved
+        del self.idx.search_by_vector_batch
+        del self.idx.search_by_vector_batch_async
+
+
 @pytest.mark.parametrize("which", ["single", "multi"])
 def test_a_shard_that_raises_fails_the_request(world, which):
     """The error of one shard's dispatch reaches the caller as itself,
     with one shard and with eight."""
     col = world.single if which == "single" else world.multi["cosine"]
-    shard = list(col.shards.values())[-1]
-    b = shard._query_batcher("", shard.vector_indexes[""])
-
-    def boom(*_a, **_k):
-        raise _Boom("shard down")
-
-    saved = b._batch_fn, b._async_fn
-    b._batch_fn, b._async_fn = boom, None
-    try:
-        with pytest.raises(_Boom):
-            col.near_vector(world.queries[3], k=10, include_objects=False)
-    finally:
-        b._batch_fn, b._async_fn = saved
+    with _Down(list(col.shards.values())[-1]), pytest.raises(_Boom):
+        col.near_vector(world.queries[3], k=10, include_objects=False)
     found = col.near_vector(world.queries[3], k=10, include_objects=False)
     assert_is_the_top_k(world, found, world.queries[3], 10, "cosine")
 
@@ -368,20 +393,9 @@ def test_a_failed_fan_out_keeps_every_shard_in_the_trace(world, which):
     col = world.multi["cosine"]
     col.near_vector(world.queries[0], k=10, include_objects=False)
     if which == "raises":
-        shard = list(col.shards.values())[0]
-        b = shard._query_batcher("", shard.vector_indexes[""])
-
-        def boom(*_a, **_k):
-            raise _Boom("shard down")
-
-        saved = b._batch_fn, b._async_fn
-        b._batch_fn, b._async_fn = boom, None
-        try:
-            with tracing.trace("t", force=True), pytest.raises(_Boom):
-                col.near_vector(world.queries[3], k=10,
-                                include_objects=False)
-        finally:
-            b._batch_fn, b._async_fn = saved
+        with _Down(list(col.shards.values())[0]), \
+                tracing.trace("t", force=True), pytest.raises(_Boom):
+            col.near_vector(world.queries[3], k=10, include_objects=False)
     else:
         gate = _Gate(col)
         try:

@@ -669,10 +669,16 @@ def test_counters_records_spans_and_nodes_name_the_device(spread, corpus):
         assert any(
             line.startswith("weaviate_tpu_query_batcher_compile_bucket_total{")
             and f'device="{label}"' in line for line in page.splitlines())
+    # a plain request is ONE dispatch of the collection's drain (ISSUE
+    # 42), whose eight programs span the chips: its record names none. A
+    # filtered one rides the shards' own batchers, a record a chip
+    assert col._drains[""].batcher._device_label == ""
+    col.near_vector(corpus[1][0], k=K, include_objects=False,
+                    where=Filter.where("bucket", Operator.LESS_THAN, 50))
     tailboard.flush()
     records = [r for r in tailboard.debug_flight()["dispatches"]
                if r.get("plane") == "batcher"]
-    assert {r.get("device") for r in records} >= labels
+    assert {r.get("device") for r in records} >= labels | {""}
     from weaviate_tpu.runtime import kernelscope
 
     by_chip = kernelscope.snapshot()["devices"]

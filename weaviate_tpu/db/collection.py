@@ -19,11 +19,13 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from weaviate_tpu.db.drain import CollectionDrain
 from weaviate_tpu.db.shard import Shard
 from weaviate_tpu.db.sharding import ShardingState
 from weaviate_tpu.runtime import degrade
 from weaviate_tpu.runtime import metrics as monitoring
 from weaviate_tpu.runtime import tailboard, tracing
+from weaviate_tpu.runtime.query_batcher import BatcherStopped
 from weaviate_tpu.schema.config import CollectionConfig
 from weaviate_tpu.storage.objects import StorageObject
 
@@ -175,6 +177,9 @@ class Collection:
         # or they vanish from sharding state on restart
         self._on_sharding_change = on_sharding_change or (lambda col: None)
         self.shards: dict[str, Shard] = {}
+        # vector space -> the ONE batcher over this collection's local
+        # shards (db/drain.py), built by the first request that takes it
+        self._drains: dict[str, CollectionDrain] = {}
         for name in self.sharding.shard_names:
             if self.local_node in self.sharding.nodes_for(name) and \
                     self.sharding.status_of(name) not in ("COLD", "FROZEN"):
@@ -1329,16 +1334,20 @@ class Collection:
         mask applied inside the device scan.
 
         More than one shard (``_fan_out``): the request's own thread
-        builds each LOCAL shard's allow mask, enqueues on every local
-        shard's batcher, waits for all of them under the request's one
-        deadline and merges once; no thread is held a local shard.
-        Remote shards keep the pool: a blocking HTTP call is what a pool
-        is for. The request is charged ONE ``queue_wait``, ``device`` and
-        ``transfer``, those of the local shard whose answer arrived last
-        (its critical path), and one that fanned out over several LOCAL
-        shards carries two request stages more (runtime/tailboard.py):
-        ``fanout_wait``, first enqueue to last delivery less those three,
-        and ``merge``."""
+        searches the LOCAL shards under the request's one deadline and
+        merges once; no thread is held a local shard. A plain request
+        over several of them is ONE item on the collection's drain
+        (db/drain.py: one batcher a collection, every member shard's
+        scan launched over one query block) and is charged that drain's
+        ``queue_wait``, ``device`` and ``transfer``. One that carries a
+        filter or an allow list builds each shard's allow mask, enqueues
+        on every local shard's own batcher, waits for all of them, and
+        is charged those of the shard whose answer arrived last (its
+        critical path). Remote shards keep the pool: a blocking HTTP
+        call is what a pool is for. On both routes a request over
+        several LOCAL shards carries two request stages more
+        (runtime/tailboard.py): ``fanout_wait``, first enqueue to (last)
+        delivery less those three, and ``merge``."""
         query = np.asarray(query, dtype=np.float32)
         names = self._target_shard_names(tenant)
         if len(names) == 1:
@@ -1403,15 +1412,59 @@ class Collection:
                  allow_list_by_shard, where,
                  include_objects: bool) -> list[SearchResult]:
         """``near_vector`` over several shards: remote shards go to the
-        pool as futures; every local shard is enqueued from the
-        request's own thread, all are waited for under the one deadline,
-        and everything is merged once."""
+        pool as futures, the local shards are searched from the
+        request's own thread under the one deadline, and everything is
+        merged once. Several LOCAL shards take one of two routes, by
+        what the request carries (``weaviate_tpu_fanout_route_total``):
+        ``drain``, a plain request: ONE item on the collection's drain
+        (db/drain.py), one wait, one finish, every shard's ``[k]`` from
+        that one delivery; ``shards``, a request with a filter or an
+        allow list (and a drain retired under the request): one item a
+        shard on the shards' own batchers, as every fan-out was until
+        ISSUE 42. A shard does the same for itself on both: its
+        snapshot of queued vectors before the enqueue and their union
+        after, the ``ids >= 0`` filter, its span."""
         local = [n for n in names if self._is_local(n)]
         remote = {n: self._pool.submit(
             tracing.propagate(self._near_vector_shard), n, query, k,
             vec_name, allow_list_by_shard, where, include_objects)
             for n in names if n not in local}
         shards = {n: self._load_shard(n) for n in local}
+        hits = None
+        if len(local) > 1 and allow_list_by_shard is None and where is None:
+            drain = self._drain_for(vec_name, shards)
+            if drain is not None:
+                try:
+                    hits, t_first, t_last, waits = self._search_drain(
+                        drain, shards, query, k, vec_name)
+                except BatcherStopped:
+                    pass    # retired under this request: the shards answer
+        route = "drain" if hits is not None else "shards"
+        if hits is None:
+            hits, t_first, t_last, waits = self._search_shards(
+                shards, query, k, vec_name, allow_list_by_shard, where)
+        gathered = [hits[n] if n in hits else remote[n].result()
+                    for n in names]
+        t_merge = time.perf_counter()
+        merged = self._merge_by_distance(gathered, k)
+        if len(local) > 1:
+            # observed for a request that fanned out over local shards,
+            # and for no other (runtime/tailboard.py, point 5)
+            monitoring.fanout_shards_total.labels(self.config.name).inc(
+                len(local))
+            monitoring.fanout_route_total.labels(self.config.name,
+                                                 route).inc()
+            monitoring.fanout_width.observe(len(local))
+            tailboard.fanout((t_last - t_first) - waits,
+                             time.perf_counter() - t_merge)
+        return merged
+
+    def _search_shards(self, shards: dict, query, k: int, vec_name: str,
+                       allow_list_by_shard, where):
+        """Route ``shards``: every local shard is enqueued on its own
+        batcher and all are waited for. -> (hits a shard, first
+        enqueue, last delivery, the charged shard's queue_wait + device
+        + transfer)."""
         allows = {n: self._shard_allow(n, shard, allow_list_by_shard, where)
                   for n, shard in shards.items()}
         t_first = time.perf_counter()
@@ -1427,9 +1480,9 @@ class Collection:
             # the one this request is charged for, and is finished first
             # (its ``wake`` runs from that delivery to now)
             t_waited = time.perf_counter()
-            last = max(local, key=lambda n: searches[n].t_deliver,
+            last = max(shards, key=lambda n: searches[n].t_deliver,
                        default=None)
-            for n in sorted(local, key=lambda n: n != last):
+            for n in sorted(shards, key=lambda n: n != last):
                 hits[n] = _ShardHits(n, shards[n], *shards[n]
                                      .vector_search_end(searches[n],
                                                         charge=n == last))
@@ -1441,21 +1494,70 @@ class Collection:
             for search in searches.values():
                 search.discard()
             raise
-        gathered = [hits[n] if n in hits else remote[n].result()
-                    for n in names]
-        t_merge = time.perf_counter()
-        merged = self._merge_by_distance(gathered, k)
-        if len(local) > 1:
-            # observed for a request that fanned out over local shards,
-            # and for no other (runtime/tailboard.py, point 5)
-            monitoring.fanout_shards_total.labels(self.config.name).inc(
-                len(local))
-            monitoring.fanout_width.observe(len(local))
-            t_last = searches[last].t_deliver or t_waited
-            tailboard.fanout(
-                (t_last - t_first) - sum(searches[last].phases()),
-                time.perf_counter() - t_merge)
-        return merged
+        if last is None:
+            return hits, t_first, t_waited, 0.0
+        return (hits, t_first, searches[last].t_deliver or t_waited,
+                sum(searches[last].phases()))
+
+    def _drain_for(self, vec_name: str, shards: dict):
+        """The collection's drain over ``shards`` (name -> local shard,
+        in the request's order), built or rebuilt where the set it was
+        built over is no longer this one; None where a shard's searches
+        ride no batcher (``Shard.drain_member``)."""
+        members = []
+        for name, shard in shards.items():
+            idx = shard.drain_member(vec_name)
+            if idx is None:
+                return None
+            members.append((name, shard, idx))
+        drain = self._drains.get(vec_name)
+        if drain is not None and drain.serves(members):
+            return drain
+        with self._lock:
+            old = self._drains.get(vec_name)
+            if old is not None and old.serves(members):
+                return old
+            drain = self._drains[vec_name] = CollectionDrain(
+                self.config.name, members)
+        if old is not None:
+            # what it has launched is delivered; what is still queued is
+            # refused typed and answered by the shards (``_fan_out``)
+            old.stop()
+        return drain
+
+    def _search_drain(self, drain, shards: dict, query, k: int,
+                      vec_name: str):
+        """Route ``drain``: ONE item, one wait under the request's
+        deadline, one finish (the request is charged the drain's
+        queue_wait, device, transfer and wake); every shard keeps its
+        span and its read-your-writes union. -> as ``_search_shards``."""
+        t_first = time.perf_counter()
+        searches: dict = {}
+        item = None
+        try:
+            for n, shard in shards.items():
+                searches[n] = shard.vector_search_begin(
+                    query, k, vec_name, enqueue=False)
+            item = drain.batcher.enqueue(query, k)
+            ids, dists = drain.batcher.finish(drain.batcher.wait(item))
+            hits = {}
+            for s, n in enumerate(drain.names):
+                searches[n].feed(ids[s], dists[s])
+                hits[n] = _ShardHits(n, shards[n], *shards[n]
+                                     .vector_search_end(searches[n],
+                                                        charge=False))
+        except BaseException:
+            # a member that raised, the drain stopped, or the budget
+            # spent (typed once, by ``wait``): the item leaves the queue
+            # if it is still there, and every shard not yet finished
+            # closes its span
+            if item is not None:
+                drain.batcher.discard(item)
+            for search in searches.values():
+                search.discard()
+            raise
+        return (hits, t_first, item.t_deliver or time.perf_counter(),
+                sum(drain.batcher.phases(item)))
 
     @_timed("bm25")
     def bm25(self, query: str, k: int = 10, properties: list[str] | None = None,
@@ -1692,5 +1794,9 @@ class Collection:
 
     def close(self):
         self._pool.shutdown(wait=False)
+        with self._lock:
+            drains, self._drains = list(self._drains.values()), {}
+        for drain in drains:
+            drain.stop()
         for s in self.shards.values():
             s.close()

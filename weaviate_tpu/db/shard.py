@@ -172,6 +172,16 @@ class _Search:
         span, self.span = self.span, None
         tracing.close_span(span)
 
+    def feed(self, ids, dists) -> None:
+        """The index's answer, where the collection's drain searched
+        for this shard (``vector_search_begin(..., enqueue=False)``):
+        one member's ``[k]`` row of the drain's delivery, -1 where the
+        scan found nothing or the block was padded to another member's
+        width."""
+        live = ids >= 0
+        self.found = (ids[live].astype(np.int64, copy=False),
+                      dists[live].astype(np.float32, copy=False))
+
     @property
     def t_deliver(self) -> float:
         """When the answer was there (0.0: at once, nothing enqueued)."""
@@ -902,16 +912,31 @@ class Shard:
             return self._search_end(
                 self._search_begin(idx, query, k, vec_name, allow_list))
 
+    def drain_member(self, vec_name: str = ""):
+        """The index a collection's drain launches for this shard
+        (db/drain.py), or None where this shard's searches ride no
+        batcher: no such vector space, ``QUERY_DYNAMIC_BATCHING`` off,
+        an index without a batched entry point."""
+        idx = self.vector_indexes.get(vec_name)
+        if idx is None or not self.dynamic_batching or getattr(
+                idx, "search_by_vector_batch", None) is None:
+            return None
+        return idx
+
     def vector_search_begin(self, query: np.ndarray, k: int,
                             vec_name: str = "",
-                            allow_list: np.ndarray | None = None
-                            ) -> "_Search":
+                            allow_list: np.ndarray | None = None,
+                            enqueue: bool = True) -> "_Search":
         """First half of ``vector_search``, for a request that searches
         several shards (``Collection.near_vector``): the snapshot of the
         queued vectors and the enqueue on this shard's batcher. Returns
         at once where the index has a batched entry point; the caller's
         thread is held by no shard. ``vector_search_end`` gives what
-        ``vector_search`` gives."""
+        ``vector_search`` gives. ``enqueue=False``: the collection's
+        drain searches this shard's index (``drain_member``) with its
+        other members'; the snapshot and the span are taken here all the
+        same, and the caller ``feed``s the search its member's answer
+        before ``vector_search_end``."""
         idx = self.vector_indexes.get(vec_name)
         if idx is None:
             return _Search(k, None, found=(np.empty(0, np.int64),
@@ -919,7 +944,7 @@ class Shard:
         span = tracing.open_span("shard.vector_search", shard=self.name,
                                  k=k, filtered=allow_list is not None)
         search = tracing.run_in(span, self._search_begin, idx, query, k,
-                                vec_name, allow_list)
+                                vec_name, allow_list, enqueue)
         search.span = span
         return search
 
@@ -934,14 +959,16 @@ class Shard:
         finally:
             tracing.close_span(span)
 
-    def _search_begin(self, idx, query, k, vec_name, allow_list
-                      ) -> "_Search":
+    def _search_begin(self, idx, query, k, vec_name, allow_list,
+                      enqueue: bool = True) -> "_Search":
         # snapshot BEFORE the index search: every queued vector is either
         # in the snapshot or already drained into the index by the time
         # the index search runs — the union misses nothing (the reverse
         # order races a drain finishing between the two reads)
         search = _Search(k, self._queued_candidates(vec_name, query,
                                                     allow_list))
+        if not enqueue:
+            return search
         if self.dynamic_batching and query.ndim == 1 and getattr(
                 idx, "search_by_vector_batch", None) is not None:
             # dynamic-batched single-query search: concurrent callers

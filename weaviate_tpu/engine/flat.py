@@ -19,6 +19,7 @@ import functools
 import threading
 import time
 
+import jax
 import numpy as np
 
 from weaviate_tpu import native
@@ -510,7 +511,10 @@ class FlatIndex:
         race."""
         if not hasattr(self.store, "search_async"):
             return None
-        queries = np.atleast_2d(np.asarray(queries))
+        if not isinstance(queries, jax.Array):
+            # (a block that already lies on this index's device comes
+            # from a collection's drain: ``takes_device_queries``)
+            queries = np.atleast_2d(np.asarray(queries))
         per_query = _per_query_allow(allow_list)
         with tracing.span("flat.search_batch", k=k, queries=len(queries),
                           filtered=allow_list is not None,
@@ -542,6 +546,18 @@ class FlatIndex:
             return ids, d
 
         return handle.map(_resolve)
+
+    @property
+    def takes_device_queries(self) -> bool:
+        """True where ``search_by_vector_batch_async`` takes an
+        UNFILTERED [B, d] float32 block that already lies on this
+        index's device (``placement.put(block, self.device)``) as it
+        takes a numpy one: a collection's drain uploads its block once
+        a chip and hands it to every member shard there
+        (db/drain.py). The plain single-device store alone; any other
+        would fetch the block back to encode or split it."""
+        return type(self.store) is DeviceVectorStore \
+            and self.store.mesh is None
 
     # -- hybrid dataplane (ISSUE 18) ------------------------------------------
 

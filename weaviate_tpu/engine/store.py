@@ -582,8 +582,11 @@ class DeviceVectorStore:
         runs the gathered-path host remapping; the serving pipeline
         instead drains the handle on a dedicated transfer thread while
         the next batch dispatches (runtime/query_batcher.py), so the
-        device never idles on a host sync."""
-        queries = np.asarray(queries, dtype=np.float32)
+        device never idles on a host sync. ``queries``: numpy, or a
+        float32 block that already lies on this store's device (a
+        collection's drain uploads one a chip)."""
+        if not isinstance(queries, jax.Array):
+            queries = np.asarray(queries, dtype=np.float32)
         squeeze = queries.ndim == 1
         if squeeze:
             queries = queries[None, :]

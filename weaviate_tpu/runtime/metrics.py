@@ -732,6 +732,16 @@ fanout_shards_total = registry.counter(
     "Local shard searches enqueued by requests that fanned out over "
     "more than one local shard (db/collection.py near_vector)",
     ("collection",))
+fanout_route_total = registry.counter(
+    "weaviate_tpu_fanout_route_total",
+    "Requests that fanned out over more than one local shard, by the "
+    "route their local shards took: drain = ONE item on the "
+    "collection's drain, which launches every member shard's scan over "
+    "one query block (db/drain.py); shards = one item a shard on the "
+    "shards' own batchers (a request that carries a filter or an allow "
+    "list; a shard whose searches ride no batcher; a drain retired "
+    "under the request)",
+    ("collection", "route"))
 fanout_width = registry.histogram(
     "weaviate_tpu_fanout_width",
     "Local shards searched by one fanned-out request", (),

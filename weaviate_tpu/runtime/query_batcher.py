@@ -52,6 +52,13 @@ transfer window (depth 2) is backpressure: at most two batches are in
 flight past dispatch, so staged host memory stays bounded. Results are
 bit-identical to the sync path — same program, same padding, same
 slicing; only WHERE the transfer happens moves.
+
+One batcher a collection (ISSUE 42): a plain request over several local
+shards is ONE item on the collection's drain (db/drain.py), a
+``QueryBatcher`` whose dispatch launches every member shard's scan over
+the same padded block and hands ONE gathered handle to its one transfer
+thread. The per-shard batchers stay for everything that targets one
+shard or carries a filter.
 """
 
 from __future__ import annotations
@@ -85,6 +92,13 @@ class DeviceHybridUnavailable(RuntimeError):
     could not run the fused device program for this dispatch shape —
     the shard layer catches this and serves the query through the host
     hybrid path instead."""
+
+
+class BatcherStopped(RuntimeError):
+    """The batcher was stopped with this request still queued, or before
+    it could be: a shard that closed, or a collection's drain retired
+    because the set of local shards changed under the request (the
+    collection then answers through its shards' own batchers)."""
 
 
 class _Pending:
@@ -148,6 +162,15 @@ class QueryBatcher:
     pads drains to pow2 B/k buckets — right for jitted device programs
     (bounds compiled variants), wasted work for per-row host indexes
     like HNSW (padded rows run real graph searches), so those opt out.
+
+    Two units drain (ISSUE 42): a shard's index (``Shard._query_batcher``:
+    every request that targets one shard, and a filtered one over
+    several) and a collection's set of local shards (db/drain.py: a plain
+    request over several). The second is this class with a ``batch_fn``
+    / ``async_batch_fn`` that launch one scan a member shard over the one
+    block and answer ``[B, S, k]``: ``program_devices`` names the chip of
+    each program a dispatch launches (the dispatch counter moves once an
+    entry), and ``_deliver`` hands a waiter ``[S, k]``.
     """
 
     #: ``_await_company``: the queue length at which a drain stops waiting
@@ -165,7 +188,7 @@ class QueryBatcher:
                  owner: dict | None = None, async_batch_fn=None,
                  transfer_depth: int = 2,
                  max_queue: int | None = None, kind: str = "index",
-                 hybrid_batch_fn=None):
+                 hybrid_batch_fn=None, program_devices=None):
         from weaviate_tpu.runtime import hbm_ledger
 
         self._batch_fn = batch_fn
@@ -201,6 +224,13 @@ class QueryBatcher:
         # the chip this batcher's index lies on (runtime/placement.py):
         # the ``device`` label of its dispatch counter and records
         self._device_label = placement.label(self._hbm_owner.get("device"))
+        # one entry a PROGRAM a coalesced dispatch launches, the label
+        # of the chip it runs on: the dispatch counter moves once an
+        # entry. One, this batcher's own, for a shard's batcher; a
+        # collection's drain (db/drain.py) launches one scan a member
+        # shard over the one query block and names each member's chip
+        self._program_devices = (self._device_label,) \
+            if program_devices is None else tuple(program_devices)
         # metering labels: one batcher serves one (shard, vector), so
         # every request a dispatch coalesces shares these
         self._meter_labels = (
@@ -263,7 +293,7 @@ class QueryBatcher:
                 # here routes the in-flight drain to its waiters as an
                 # error (via _run's handler / the submit RuntimeError
                 # path below).
-                raise RuntimeError("query batcher stopped")
+                raise BatcherStopped("query batcher stopped")
             if self._transfer is None:
                 self._transfer = TransferPipeline(
                     depth=self._transfer_depth, name="qb-transfer")
@@ -462,7 +492,7 @@ class QueryBatcher:
                 self._await_company(side)
             if self._stopped:
                 for it in self._queue:
-                    it.error = RuntimeError("query batcher stopped")
+                    it.error = BatcherStopped("query batcher stopped")
                     it.event.set()
                 self._queue.clear()
                 return None
@@ -660,8 +690,9 @@ class QueryBatcher:
         from weaviate_tpu.runtime.metrics import (
             batcher_compile_bucket, batcher_filtered_batched)
 
-        batcher_compile_bucket.labels(b=str(b_pad), k=str(k_bucket),
-                                      device=self._device_label).inc()
+        for device in self._program_devices:
+            batcher_compile_bucket.labels(b=str(b_pad), k=str(k_bucket),
+                                          device=device).inc()
         if filtered:
             batcher_filtered_batched.inc(len(filtered))
         # the shared dispatch runs under ONE waiter's trace context (the
@@ -944,11 +975,14 @@ class QueryBatcher:
         construction). ``t1`` is the record's ``done`` stamp, which the
         caller has already written: kept in the signature for callers
         that wrap this (the benchmark's fault injection); each waiter's
-        ``t_deliver`` is taken here, just before its event is set."""
+        ``t_deliver`` is taken here, just before its event is set.
+        ``ids`` and ``dists`` are ``[B, k]``, or ``[B, S, k]`` from a
+        collection's drain over S member shards: a waiter then gets
+        ``[S, k]``, member by member."""
         with tailboard.dispatch_stage("deliver"):
             for row, it in enumerate(coal):
-                kk = min(it.k, ids.shape[1])
-                it.ids = ids[row, :kk]
-                it.dists = dists[row, :kk]
+                kk = min(it.k, ids.shape[-1])
+                it.ids = ids[row, ..., :kk]
+                it.dists = dists[row, ..., :kk]
                 it.t_deliver = time.perf_counter()
                 it.event.set()

@@ -71,15 +71,22 @@ the hot path.
    annotation on 32 request threads swallow every gap.
 
    A request that searches several local shards (a collection's
-   fan-out, ISSUE 34) enqueues on every shard's batcher from its own
-   thread and waits for all of them. Its stages stay additive by the
+   fan-out, ISSUE 34) does so from its own thread. A plain one is ONE
+   item on the collection's drain (ISSUE 42, db/drain.py) and is charged
+   that drain's ``queue_wait``, ``device``, ``transfer`` and ``wake``;
+   the drain's dispatch record is one a drain, so its ``launch``,
+   ``d2h_wait`` and ``deliver`` stages cover one program a member
+   shard. One with a filter or an allow list enqueues on every shard's
+   batcher and waits for all of them; its stages stay additive by the
    **critical-path rule**: it is charged ONE ``queue_wait``, ``device``
-   and ``transfer``, those of the shard whose answer arrived last; what
-   passed between the first enqueue and that last delivery beyond those
-   three is ``fanout_wait``, and the merge of the shards' answers is
-   ``merge`` (:data:`FANOUT_STAGES`). The two are part of the sum for a
-   fanned-out request and are observed for such a request only: a
-   one-shard request makes the observations it always made.
+   and ``transfer``, those of the shard whose answer arrived last. On
+   both routes what passed between the first enqueue and the (last)
+   delivery beyond those three is ``fanout_wait`` (the shards'
+   snapshots of their queued vectors, on the drain), and the merge of
+   the shards' answers is ``merge`` (:data:`FANOUT_STAGES`). The two
+   are part of the sum for a fanned-out request and are observed for
+   such a request only: a one-shard request makes the observations it
+   always made.
 
 6. **The interpreter's account** (ISSUE 39) — what one interpreter lock
    costs, measured and no longer inferred, under the same switch and

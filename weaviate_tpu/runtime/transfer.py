@@ -102,6 +102,34 @@ class DeviceResultHandle:
         return DeviceResultHandle(parent=self, finish=fn,
                                   attrs=dict(self.attrs))
 
+    @classmethod
+    def gather(cls, members, finish=None, attrs=None
+               ) -> "DeviceResultHandle":
+        """ONE handle over the handles of several dispatched programs (a
+        collection's drain: one scan a member shard). ``result()`` starts
+        every member's device->host copy, then resolves the members in
+        order, each through its own finish chain, and returns
+        ``finish(list of the members' results)``. A member that fails
+        fails the whole, as itself."""
+        return cls(parent=_Members(members), finish=finish, attrs=attrs)
+
+    def prefetch(self) -> None:
+        """Start the device->host copy of this handle's arrays and do
+        not wait for it (``copy_to_host_async``): the transfer thread
+        that later resolves several handles one after the other then
+        blocks once, for the program that ends last, where it would
+        have blocked (and given the interpreter up) once an array."""
+        if self._parent is not None:
+            self._parent.prefetch()
+            return
+        for a in self._arrays:
+            start = getattr(a, "copy_to_host_async", None)
+            if start is not None:
+                try:
+                    start()
+                except Exception:  # noqa: BLE001 — result() raises it
+                    return
+
     @property
     def done(self) -> bool:
         return self._value is not _UNSET or self._error is not None
@@ -133,6 +161,24 @@ class DeviceResultHandle:
                 self._arrays = ()  # release the device references
                 self._parent = None
             return self._value
+
+
+class _Members:
+    """The parent of a ``DeviceResultHandle.gather`` handle: the member
+    handles, resolved together."""
+
+    __slots__ = ("_handles",)
+
+    def __init__(self, handles):
+        self._handles = list(handles)
+
+    def prefetch(self) -> None:
+        for h in self._handles:
+            h.prefetch()
+
+    def result(self) -> list:
+        self.prefetch()
+        return [h.result() for h in self._handles]
 
 
 class TransferPipeline:
