@@ -207,6 +207,55 @@ def test_answers_are_those_of_one_visible_device(
         db.close()
 
 
+def test_a_dynamic_class_on_another_device_answers_as_on_the_default_one(
+        corpus, tmp_path, monkeypatch, fresh_placement):
+    """ISSUE 43: the ANN index is placed like every other store. A
+    ``dynamic`` class past its threshold on a chip that is not the default
+    one (the list tensors, the centroids and the delta buffer committed
+    there) gives the ids and distances the same class gives on a host of
+    one visible device, with rows in the delta and after the fold."""
+    rows, queries = corpus
+    dynamic = {"class": "Dyn", "vectorIndexType": "dynamic",
+               "vectorIndexConfig": {"distance": "cosine", "threshold": 1024},
+               "properties": [{"name": "bucket", "dataType": ["int"]},
+                              {"name": "home", "dataType": ["int"]}]}
+
+    def lived(path, first=None):
+        db = Database(str(path))
+        if first is not None:    # takes the default device
+            db.create_collection(config_from_json(klass(first, shards=1)))
+        col = db.create_collection(config_from_json(dynamic))
+        fill(col, rows)
+        (shard,) = col.shards.values()
+        idx = shard.vector_indexes[""]
+        assert idx.upgraded and idx.store._delta_slots
+        got = [answers(col, queries)]
+        assert db.cycles.run_now("epoch-maintenance")
+        assert not idx.store._delta_slots
+        got.append(answers(col, queries))
+        return db, shard, got
+
+    db, shard, there = lived(tmp_path / "second", first="Before")
+    try:
+        assert shard.device == jax.local_devices()[1]
+        store = shard.vector_indexes[""].store
+        assert store.device == store.delta.device == shard.device
+        assert_on(shard.device, device_arrays(store), "the lists")
+        assert_on(shard.device, device_arrays(store.delta), "the delta")
+    finally:
+        db.close()
+    monkeypatch.setattr(placement, "_held", {})
+    (only,) = host_of(1, monkeypatch)
+    db, shard, here = lived(tmp_path / "default")
+    try:
+        assert shard.device == only
+        for (ids, dists), (ids1, dists1) in zip(there, here):
+            assert np.array_equal(ids, ids1)
+            assert np.allclose(dists, dists1, rtol=0, atol=1e-6)
+    finally:
+        db.close()
+
+
 # -- (b) every array on the shard's device, through its life ---------------------
 
 

@@ -135,6 +135,62 @@ def test_rest_answers_422_and_writes_sq_back(tmp_path):
         db.close()
 
 
+@pytest.mark.parametrize("body,match", [
+    ({"threshold": 0}, "threshold"),
+    ({"threshold": "many"}, "threshold"),
+    ({"threshold": True}, "threshold"),
+    ({"hnsw": {"pq": {"enabled": True}}}, "vectorIndexConfig.hnsw.pq"),
+    ({"flat": {"bq": {"enabled": True}}}, "vectorIndexConfig.flat.bq"),
+], ids=["zero", "not-int", "bool", "hnsw.pq", "flat.bq"])
+def test_a_dynamic_class_refuses_what_it_cannot_honour(body, match):
+    """ISSUE 43: upstream's ``threshold`` is read (it used to be written
+    back and never read), and a compression nested under one of upstream's
+    two regimes is refused, not dropped."""
+    with pytest.raises(ValueError, match=match):
+        config_from_json({"class": "C", "vectorIndexType": "dynamic",
+                          "vectorIndexConfig": body}).validate()
+
+
+def test_rest_takes_stores_and_gives_back_a_dynamic_threshold(tmp_path):
+    db = Database(str(tmp_path))
+    srv = RestServer(db)
+    srv.start()
+    try:
+        client = Client(srv.address)
+        for bad in ({"threshold": 0}, {"hnsw": {"pq": {"enabled": True}}}):
+            with pytest.raises(RestError) as e:
+                client.create_class({"class": "Bad",
+                                     "vectorIndexType": "dynamic",
+                                     "vectorIndexConfig": bad})
+            assert e.value.status == 422, bad
+        assert "Bad" not in db.collections
+        client.create_class({
+            "class": "Grows", "vectorIndexType": "dynamic",
+            "vectorIndexConfig": {"distance": "cosine", "threshold": 2500,
+                                  "hnsw": {"pq": {"enabled": False}},
+                                  "flat": {"vectorCacheMaxObjects": 10}}})
+        wire = client.get_class("Grows")
+        assert wire["vectorIndexType"] == "dynamic"
+        assert wire["vectorIndexConfig"]["threshold"] == 2500
+        ix = db.get_collection("Grows").config.vector_config("").index
+        assert (ix.index_type, ix.flat_to_ann_threshold, ix.metric) == (
+            "dynamic", 2500, "cosine")
+        stored = CollectionConfig.from_dict(json.loads(json.dumps(
+            db.get_collection("Grows").config.to_dict())))
+        assert stored.vector_config("").index.flat_to_ann_threshold == 2500
+        # the native key still passes; absent, the default stands
+        again = config_from_json({"class": "C", "vectorIndexType": "dynamic",
+                                  "vectorIndexConfig": {
+                                      "flat_to_ann_threshold": 77}})
+        assert again.vector_config("").index.flat_to_ann_threshold == 77
+        assert config_from_json({"class": "C", "vectorIndexType": "dynamic"}
+                                ).vector_config("").index \
+            .flat_to_ann_threshold == 10_000
+    finally:
+        srv.stop()
+        db.close()
+
+
 def test_a_mesh_sharded_database_refuses_the_class(tmp_path):
     from weaviate_tpu.parallel.mesh import make_mesh
 

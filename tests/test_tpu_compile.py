@@ -301,6 +301,35 @@ def test_served_scans_with_the_rescore_tail(one_chip, monkeypatch, cell, b):
         (64 << 20) + 2 * b * kc * lanes * 4
 
 
+@pytest.mark.parametrize("b", [1, 16])
+def test_ivf_probe_at_the_dynamic_cells_shapes(one_chip, b):
+    """``glove-dynamic-cosine.c32``'s probe program (ISSUE 43) as the
+    served store launches it: 1,024 lists of 512 positions x 100 float32,
+    128 lists probed a query, a chunk of at most 16 queries. The chip's
+    compiler takes it, and its temporaries are the gathered candidates
+    ([b, 65536, 100 floats in 128 lanes]) and not several copies of
+    them: the probe's cost on the chip IS that gather (PERF.md section
+    6, PR 43), so a later PR that changes it is held here first."""
+    from weaviate_tpu.engine.ivf import _ivf_probe_topk
+
+    nlist, cap, d, nprobe, k = 1024, 512, 100, 128, 10
+    f32 = jnp.float32
+
+    def fn(q, cents, c_norms, vecs, valid, slots, norms, bits):
+        return _ivf_probe_topk(q, cents, c_norms, vecs, valid, slots, norms,
+                               bits, k, nprobe, "cosine", False)
+
+    c = _compile(fn, one_chip, ((b, d), f32), ((nlist, d), f32),
+                 ((nlist,), f32), ((nlist, cap, d), f32),
+                 ((nlist, cap), jnp.bool_), ((nlist, cap), jnp.int32),
+                 ((nlist, cap), f32), ((1, 16), jnp.uint32))
+    text = c.as_text()
+    assert text.count(f"[{b},{k}]{{1,0") >= 2          # [b, k] goes back
+    gathered = b * nprobe * cap * 128 * 4
+    assert c.memory_analysis().temp_size_in_bytes < \
+        (nlist * cap * 128 * 4) + 2 * gathered + (64 << 20)
+
+
 @pytest.mark.parametrize("b_pad", [1, 16, 32])
 def test_filtered_dispatch_programs(one_chip, monkeypatch, b_pad):
     """The filtered cell's two programs since PR 40, at its shapes

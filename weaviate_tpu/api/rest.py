@@ -185,6 +185,31 @@ def _index_config_from_json(index_type: str | None, d: dict | None):
         out.ef_construction = d["efConstruction"]
     if "maxConnections" in d:
         out.max_connections = d["maxConnections"]
+    if "threshold" in d:
+        # upstream's dynamic.threshold: rows at which the class leaves
+        # the flat index for the ANN one
+        threshold = d["threshold"]
+        if (not isinstance(threshold, int) or isinstance(threshold, bool)
+                or threshold < 1):
+            raise ValueError(
+                f"vectorIndexConfig.threshold must be an int >= 1, got "
+                f"{threshold!r}")
+        out.flat_to_ann_threshold = threshold
+    if out.index_type == "dynamic":
+        # upstream nests a dynamic class's two regimes (``hnsw``, ``flat``)
+        # with compressions of their own; here the class takes ONE, at
+        # the top level, so a nested one is refused, never dropped
+        for regime in ("hnsw", "flat"):
+            block = d.get(regime)
+            nested = [k for k, v in block.items()
+                      if isinstance(v, dict) and v.get("enabled")] \
+                if isinstance(block, dict) else []
+            if nested:
+                raise ValueError(
+                    f"vectorIndexConfig.{regime}.{nested[0]} is enabled, "
+                    f"and a dynamic class here takes its compression at "
+                    f"the top level of vectorIndexConfig ({', '.join(_COMPRESSIONS)}), "
+                    f"not a regime")
     pq = d.get("pq") or {}
     if pq.get("enabled"):
         out.quantization = "pq"

@@ -1207,7 +1207,7 @@ class Collection:
             dst_node, len(objs))
         return len(objs)
 
-    def epoch_maintenance(self) -> bool:
+    def epoch_maintenance(self, tick: bool = False) -> bool:
         """One background policy cycle (registered with the database's
         cyclemanager as ``epoch-maintenance`` — the ONLY driver of epoch
         upkeep, so the work runs once per interval): per-shard seal /
@@ -1223,7 +1223,7 @@ class Collection:
         with self._lock:
             shards = list(self.shards.values())
         for shard in shards:
-            did = shard.epoch_maintenance() or did
+            did = shard.epoch_maintenance(tick=tick) or did
         for shard in shards:
             if shard.over_shard_limit():
                 did = self.migrate_epoch(shard.name) > 0 or did

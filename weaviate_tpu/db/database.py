@@ -84,8 +84,16 @@ class Database:
         # watermark) and, at a shard's per-shard quota watermark,
         # migrate its coldest sealed epoch to a sibling with headroom
         # instead of letting the quota 507 writes
-        self.cycles.register("epoch-maintenance", self._epoch_cycle,
-                             maintenance_interval)
+        # an index with a maintain hook (IVF: the delta's tail folded
+        # once the writes have paused) answers "work left" while rows
+        # still arrive, which keeps the base interval; the back-off
+        # between ticks of an idle server stays under two intervals so
+        # that the fold follows an import by seconds, not by 40
+        self.cycles.register("epoch-maintenance",
+                             lambda: self._epoch_cycle(tick=True),
+                             maintenance_interval,
+                             max_interval=2 * maintenance_interval,
+                             on_demand=self._epoch_cycle)
         # driftwatch (ROADMAP item 1c): canary probes through the real
         # batcher + live-telemetry classification against baseline
         # bands, on its own (longer) period. A tick defers a canary whose
@@ -110,10 +118,10 @@ class Database:
                 did = shard.maintenance() or did
         return did
 
-    def _epoch_cycle(self) -> bool:
+    def _epoch_cycle(self, tick: bool = False) -> bool:
         did = False
         for col in list(self.collections.values()):
-            did = col.epoch_maintenance() or did
+            did = col.epoch_maintenance(tick=tick) or did
         return did
 
     def _node_hbm(self) -> dict:

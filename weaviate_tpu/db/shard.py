@@ -1467,14 +1467,16 @@ class Shard:
             self.objects.delete_many(keys)
             return len(pairs)
 
-    def epoch_maintenance(self) -> bool:
+    def epoch_maintenance(self, tick: bool = False) -> bool:
         """Run the epoch policy for every epoch-backed index on this
         shard: seal overfull actives, drop empty sealed epochs, fold
         tombstone-heavy ones (reclaims HBM through the ledger
         finalizers). Indexes exposing their own ``maintain`` hook (IVF
         delta fold / drift retrain, dynamic's deferred upgrade) get the
-        same tick. Returns True when work was done (cyclemanager
-        backoff signal)."""
+        same tick; ``tick`` says the call is the cyclemanager's (an IVF
+        index then leaves a part-filled delta alone while writes keep
+        arriving, engine/ivf.py). Returns True when work was done or is
+        left for the next tick (cyclemanager backoff signal)."""
         did = False
         for idx in self.vector_indexes.values():
             es = getattr(idx, "epoch_store", None)
@@ -1482,7 +1484,7 @@ class Shard:
                 did = es.maintain() or did
             idx_maintain = getattr(idx, "maintain", None)
             if idx_maintain is not None:
-                idx_maintain()
+                did = bool(idx_maintain(tick=tick)) or did
         return did
 
     # -- replication support -------------------------------------------------

@@ -19,7 +19,7 @@ import threading
 import numpy as np
 
 from weaviate_tpu.engine.flat import FlatIndex
-from weaviate_tpu.engine.ivf import IVFIndex
+from weaviate_tpu.engine.ivf import IVFIndex, maintain_stage
 
 
 class DynamicIndex:
@@ -80,28 +80,37 @@ class DynamicIndex:
         with self._lock:
             if self.upgraded:
                 return
-            flat = self._impl
-            snap = flat.snapshot()
-            slot_to_id = snap["slot_to_id"]
-            valid = snap["valid"]
-            live = [s for s in range(min(len(slot_to_id), len(valid)))
-                    if valid[s] and slot_to_id[s] >= 0]
-            from weaviate_tpu.runtime import hbm_ledger
+            with maintain_stage("upgrade", "dynamic.upgrade",
+                                rows=len(self._impl)) as (_, less):
+                self._upgrade_locked(less)
 
-            with hbm_ledger.owner(**self._hbm_owner):
-                ivf = IVFIndex(dim=self.dim, metric=self.metric,
-                               chunk_size=self._chunk_size,
-                               nlist=self._nlist, nprobe=self._nprobe,
-                               train_threshold=max(self.threshold, 256),
-                               dtype=getattr(flat.store, "dtype", None),
-                               quantization=self._upgrade_quantization)
-            if live:
-                ids = slot_to_id[live]
-                vecs = snap["vectors"][live]
-                ivf.add_batch(ids, vecs)
-                if not ivf.trained:
-                    ivf.train()
-            self._impl = ivf
+    def _upgrade_locked(self, less: list) -> None:
+        """The migration itself (caller holds ``_lock``). The seconds of
+        the training inside it go to ``less[0]``: they are observed as
+        the ``train`` stage."""
+        flat = self._impl
+        snap = flat.snapshot()
+        slot_to_id = snap["slot_to_id"]
+        valid = snap["valid"]
+        live = [s for s in range(min(len(slot_to_id), len(valid)))
+                if valid[s] and slot_to_id[s] >= 0]
+        from weaviate_tpu.runtime import hbm_ledger
+
+        with hbm_ledger.owner(**self._hbm_owner):
+            ivf = IVFIndex(dim=self.dim, metric=self.metric,
+                           chunk_size=self._chunk_size,
+                           nlist=self._nlist, nprobe=self._nprobe,
+                           train_threshold=max(self.threshold, 256),
+                           dtype=getattr(flat.store, "dtype", None),
+                           quantization=self._upgrade_quantization)
+        if live:
+            ids = slot_to_id[live]
+            vecs = snap["vectors"][live]
+            ivf.add_batch(ids, vecs)
+            if not ivf.trained:
+                ivf.train()
+            less[0] += ivf.store.train_seconds
+        self._impl = ivf
 
     # -- VectorIndex contract (delegated) ------------------------------------
 
@@ -114,17 +123,21 @@ class DynamicIndex:
             if self.should_upgrade():
                 self.upgrade()
 
-    def maintain(self) -> None:
+    def maintain(self, tick: bool = False) -> bool:
         """Maintenance tick (db/shard.py epoch_maintenance): catch a
         deferred upgrade (e.g. after a restore that landed above the
         threshold without an insert) and forward the tick to the live
-        impl — the IVF regime folds its delta / retrains here."""
+        impl — the IVF regime folds its delta / retrains here. -> the
+        impl's answer: work done, or left for the next tick."""
         with self._lock:
+            did = False
             if self.should_upgrade():
                 self.upgrade()
+                did = True
             impl_maintain = getattr(self._impl, "maintain", None)
             if impl_maintain is not None:
-                impl_maintain()
+                did = bool(impl_maintain(tick=tick)) or did
+            return did
 
     def __getattr__(self, name):
         # everything else (search/delete/len/compact/...) hits the live impl

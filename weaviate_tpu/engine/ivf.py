@@ -44,9 +44,11 @@ unchanged — IVFIndex subclasses FlatIndex and swaps the store.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import threading
+import time
 import weakref
 
 import jax
@@ -62,10 +64,33 @@ from weaviate_tpu.ops.distances import (MASKED_DISTANCE, normalize,
 from weaviate_tpu.ops.kmeans import kmeans_assign, kmeans_fit
 from weaviate_tpu.ops.pallas_kernels import _MASK_WORDS, allow_bits_for_ids
 from weaviate_tpu.ops.topk import topk_smallest
-from weaviate_tpu.runtime import hbm_ledger, kernelscope, tracing
+from weaviate_tpu.runtime import hbm_ledger, kernelscope, placement, tracing
+from weaviate_tpu.runtime.metrics import (ivf_candidate_rows_total,
+                                          ivf_delta_rows, ivf_list_capacity,
+                                          ivf_lists, ivf_live_rows,
+                                          ivf_maintain_seconds,
+                                          ivf_probe_programs_total,
+                                          ivf_probed_lists_total,
+                                          ivf_queries_total)
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
 
 _SUPPORTED_METRICS = ("l2-squared", "dot", "cosine", "cosine-dot")
+
+
+@contextlib.contextmanager
+def maintain_stage(stage: str, span: str, **attrs):
+    """One step that builds or keeps the index, off the request path: a
+    span (under a sampled import's trace, or a root of its own) and one
+    observation of ``weaviate_tpu_ivf_maintain_seconds{stage}``, none
+    for a step that raised. Yields ``(span, less)``: seconds added to
+    ``less[0]`` are taken off the observation (the training inside an
+    upgrade is observed as ``train``)."""
+    less = [0.0]
+    t0 = time.perf_counter()
+    with tracing.span(span, **attrs) as sp:
+        yield sp, less
+    ivf_maintain_seconds.labels(stage).observe(
+        max(0.0, time.perf_counter() - t0 - less[0]))
 
 
 def _dummy_bits():
@@ -276,7 +301,12 @@ class IVFStore:
         self.metric = metric
         self.chunk_size = chunk_size
         self.dtype = dtype or jnp.float32
-        self.nlist = nlist  # 0 = auto at train time
+        # the number of lists the user asked for (0 = automatic: about
+        # 2 sqrt(rows), so every retrain re-sizes the partition to the
+        # corpus it finds) and, apart from it, the number the lists were
+        # last trained with
+        self._user_nlist = nlist
+        self.nlist = nlist
         self.nprobe = nprobe  # 0 = auto (nlist/8, min 8)
         self.train_threshold = train_threshold
         self.delta_threshold = delta_threshold
@@ -310,14 +340,19 @@ class IVFStore:
         # full-rebuild, retrain only fires past the drift proxy)
         self.rebuild_count = 0
         self.retrain_count = 0
+        self.train_seconds = 0.0  # wall seconds inside train(), summed
         self._live_at_train = 0
         # HBM ledger: centroid + posting-list tensors publish under the
         # owner labels captured here; the delta store self-accounts (it
         # is a DeviceVectorStore constructed in this same owner scope)
-        # (not PLACED: the list tensors are built on the default device,
-        # so the owner is taken without the shard's chip and the delta
-        # store stays beside them; runtime/placement.py, ROADMAP S12)
-        self._hbm_owner = dict(hbm_ledger.current_owner(), device=None)
+        # and is built on the same chip (runtime/placement.py): the
+        # owning shard's device travels in the owner scope, and every
+        # list tensor, the centroids and a dispatch's operands go
+        # through the one ``put`` under it
+        self._hbm_owner = hbm_ledger.current_owner()
+        self.device = self._hbm_owner.get("device")
+        self._labels = (str(self._hbm_owner.get("collection") or "-"),
+                        str(self._hbm_owner.get("shard") or "-"))
         self._hbm_keys: dict[str, int] = {}
         weakref.finalize(self, hbm_ledger.ledger.release_many,
                          self._hbm_keys.values())
@@ -325,8 +360,15 @@ class IVFStore:
         with hbm_ledger.owner(**self._hbm_owner):
             self.delta = DeviceVectorStore(
                 dim, metric, capacity=min(capacity, delta_threshold * 2),
-                chunk_size=chunk_size)
+                chunk_size=chunk_size, dtype=self.dtype)
         self._delta_slots: dict[int, int] = {}  # delta slot -> global
+        # the same map as an array over the delta's slots (-1 = free),
+        # kept with the dict so that a search builds nothing a slot
+        self._delta_gmap = np.full(self.delta.capacity, -1, np.int32)
+        # writes seen, and as of the last maintenance tick: a tick
+        # folds a part-filled delta only once the writes have paused
+        self._writes = 0
+        self._writes_at_tick = 0
         # slot -> ("delta", dslot) | ("list", flat_idx)
         self._slot_loc: dict[int, tuple] = {}
         # list tensors (allocated at train time)
@@ -352,14 +394,15 @@ class IVFStore:
         hbm_ledger.ledger.set_keyed(
             self._hbm_keys, "centroids", cent, owner=self._hbm_owner,
             dtype="float32")
-        lists = sum(int(a.nbytes) for a in (
-            self.list_vecs, self.list_codes, self.list_norms,
-            self.list_tvals, self.list_valid, self.list_slots)
-            if a is not None)
-        hbm_ledger.ledger.set_keyed(
-            self._hbm_keys, "lists", lists, owner=self._hbm_owner,
-            dtype=("uint8" if self.quantization
-                   else jnp.dtype(self.dtype).name))
+        # the list tensors one component each: /v1/debug/memory lists
+        # them by name, as it lists a compressed store's codes
+        for name in ("list_vecs", "list_codes", "list_norms", "list_tvals",
+                     "list_valid", "list_slots"):
+            arr = getattr(self, name)
+            hbm_ledger.ledger.set_keyed(
+                self._hbm_keys, name, 0 if arr is None else int(arr.nbytes),
+                owner=self._hbm_owner,
+                dtype="" if arr is None else jnp.dtype(arr.dtype).name)
         hbm_ledger.ledger.set_keyed(
             self._hbm_keys, "rescore_rows",
             0 if self._rescore_rows is None
@@ -399,6 +442,7 @@ class IVFStore:
             slots = np.arange(self._count, self._count + len(vectors),
                               dtype=np.int64)
             self._count += len(vectors)
+            self._writes += 1
             self._remember_rows(slots, vectors)
             self._add_to_delta(slots, vectors)
             self._maybe_reorganize()
@@ -421,12 +465,10 @@ class IVFStore:
         self._host_rows[slots] = vectors
         need = _next_pow2(max(mx + 1, 1024))
         if self._rescore_rows is None:
-            self._rescore_rows = jnp.zeros((need, self.dim),
-                                           dtype=self.dtype)
+            self._rescore_rows = self._zeros((need, self.dim), self.dtype)
         elif mx >= self._rescore_rows.shape[0]:
             old = self._rescore_rows
-            self._rescore_rows = (jnp.zeros((need, self.dim),
-                                            dtype=self.dtype)
+            self._rescore_rows = (self._zeros((need, self.dim), self.dtype)
                                   .at[: old.shape[0]].set(old))
         bucket = _next_pow2(max(len(slots), 8))
         i_buf = np.zeros(bucket, np.int32)
@@ -436,12 +478,19 @@ class IVFStore:
         m_buf = np.zeros(bucket, bool)
         m_buf[: len(slots)] = True
         self._rescore_rows = _scatter_rows_at(
-            self._rescore_rows, jnp.asarray(i_buf), jnp.asarray(v_buf),
-            jnp.asarray(m_buf))
+            self._rescore_rows, self._put(i_buf), self._put(v_buf),
+            self._put(m_buf))
         self._hbm_sync()
 
     def _add_to_delta(self, slots: np.ndarray, vectors: np.ndarray):
+        """Rows into the delta buffer, and both maps of its slots.
+        Caller holds ``_lock``."""
         dslots = self.delta.add(vectors)
+        if self.delta.capacity > len(self._delta_gmap):  # the delta grew
+            grown = np.full(self.delta.capacity, -1, np.int32)
+            grown[:len(self._delta_gmap)] = self._delta_gmap
+            self._delta_gmap = grown
+        self._delta_gmap[dslots] = slots
         for g, d in zip(slots.tolist(), dslots.tolist()):
             self._delta_slots[int(d)] = int(g)
             self._slot_loc[int(g)] = ("delta", int(d))
@@ -461,6 +510,7 @@ class IVFStore:
             vectors = vectors[None, :]
         with self._lock:
             self._count = max(self._count, int(slots.max()) + 1 if len(slots) else 0)
+            self._writes += 1
             self._remember_rows(slots, vectors)
             delta_upd_d, delta_upd_v = [], []
             fresh_s, fresh_v = [], []
@@ -478,7 +528,7 @@ class IVFStore:
                     fresh_v.append(v)
             if clear_flat:
                 self.list_valid = _clear_list_rows(
-                    self.list_valid, jnp.asarray(clear_flat, dtype=jnp.int32))
+                    self.list_valid, self._put(np.asarray(clear_flat, dtype=np.int32)))
             if delta_upd_d:
                 self.delta.set_at(np.asarray(delta_upd_d),
                                   np.stack(delta_upd_v))
@@ -489,6 +539,7 @@ class IVFStore:
     def delete(self, slots) -> None:
         slots = np.atleast_1d(np.asarray(slots, dtype=np.int64))
         with self._lock:
+            self._writes += 1
             clear_flat, delta_del = [], []
             for s in slots.tolist():
                 loc = self._slot_loc.pop(int(s), None)
@@ -497,6 +548,7 @@ class IVFStore:
                 if loc[0] == "delta":
                     delta_del.append(loc[1])
                     self._delta_slots.pop(loc[1], None)
+                    self._delta_gmap[loc[1]] = -1
                 else:
                     clear_flat.append(loc[1])
                     self._punch_hole(loc[1])
@@ -504,7 +556,7 @@ class IVFStore:
                 self.delta.delete(np.asarray(delta_del))
             if clear_flat:
                 self.list_valid = _clear_list_rows(
-                    self.list_valid, jnp.asarray(clear_flat, dtype=jnp.int32))
+                    self.list_valid, self._put(np.asarray(clear_flat, dtype=np.int32)))
 
     # -- training / reorganization -------------------------------------------
 
@@ -513,7 +565,20 @@ class IVFStore:
             if len(self._slot_loc) >= self.train_threshold:
                 self.train()
         elif len(self._delta_slots) >= self.delta_threshold:
-            self.flush_delta()
+            # a full delta is folded on the write path, and where the
+            # corpus has outgrown its partition the fold is the retrain:
+            # both fall at row counts the writes alone decide, never at
+            # a maintenance tick's
+            if self._retrain_due():
+                self.train()
+            else:
+                self.flush_delta()
+
+    def _retrain_due(self) -> bool:
+        """The centroid-drift proxy: the live count grew
+        ``retrain_factor`` x since the lists were trained."""
+        return (len(self._slot_loc)
+                >= self.retrain_factor * max(self._live_at_train, 1))
 
     def _auto_nlist(self, n: int) -> int:
         # ~2*sqrt(N) lists, pow2-rounded, clamped: large enough to prune,
@@ -526,22 +591,27 @@ class IVFStore:
         trains PQ once enough data exists — same lifecycle hook). On an
         already-trained store this is the RETRAIN path (``maintain``'s
         drift gate lands here); routine delta absorption goes through
-        ``flush_delta`` without touching the centroids."""
-        with self._lock:
+        ``flush_delta`` without touching the centroids. The number of
+        lists is the user's where one was given and else follows the
+        corpus (``_auto_nlist`` of the live rows), at every retrain."""
+        with self._lock, maintain_stage(
+                "train", "ivf.train", retrain=self.trained) as (sp, _):
+            t0 = time.perf_counter()
             vecs, slots = self._all_live_host()
             n = len(vecs)
             if n == 0:
                 raise RuntimeError("cannot train IVF on an empty store")
             was_trained = self.trained
-            nlist = force_nlist or self.nlist or self._auto_nlist(n)
+            nlist = force_nlist or self._user_nlist or self._auto_nlist(n)
             nlist = min(nlist, n)
             self.nlist = nlist
+            sp.set(rows=n, nlist=nlist)
             cents = kmeans_fit(vecs, nlist, iters=10)
             if self.normalize_on_add:
                 # keep centroids on the sphere so probe distances stay comparable
                 cents = normalize_np(cents)
             self._centroids_np = np.asarray(cents, dtype=np.float32)
-            self.centroids = jnp.asarray(self._centroids_np)
+            self.centroids = self._put(self._centroids_np)
             self._c_norms = jnp.sum(self.centroids * self.centroids, axis=1)
             assign = kmeans_assign(vecs, self._centroids_np)
             if self.quantization:
@@ -561,24 +631,55 @@ class IVFStore:
             if was_trained:
                 self.retrain_count += 1
             self._hbm_sync()
+            self._publish_gauges()
+            self.train_seconds += time.perf_counter() - t0
 
-    def maintain(self) -> None:
+    def maintain(self, tick: bool = False) -> bool:
         """Incremental maintenance hook (db/shard.py epoch maintenance):
         fold the delta into lists; RETRAIN only when the corpus outgrew
         its partition (live count >= retrain_factor x live-at-train — the
         centroid-drift proxy). Compaction never lands here, so steady
-        tombstone churn costs hole-refills, not full rebuilds."""
+        tombstone churn costs hole-refills, not full rebuilds.
+
+        ``tick``: the call is the cyclemanager's, not a caller's who
+        wants the fold now. A tick leaves a part-filled delta alone
+        while writes keep arriving (its rows are searched exactly where
+        they are, a full delta is folded on the write path, and a fold
+        at an arbitrary moment of an import would make the lists depend
+        on when a tick fell) and folds it at the first tick that finds
+        the writes paused. -> whether work was done or is left for the
+        next tick (the cyclemanager's backoff signal)."""
         with self._lock:
+            moved = self._writes != self._writes_at_tick
+            self._writes_at_tick = self._writes
             if not self.trained:
                 if len(self._slot_loc) >= self.train_threshold:
                     self.train()
-                return
-            if (len(self._slot_loc)
-                    >= self.retrain_factor * max(self._live_at_train, 1)):
+                    return True
+                return False
+            if self._retrain_due():
                 self.train()
-                return
-            if self._delta_slots:
+                return True
+            if not self._delta_slots:
+                return False
+            if not (tick and moved):
                 self.flush_delta()
+            return True
+
+    def _publish_gauges(self) -> None:
+        """The index's shape as of this train or flush (caller holds
+        ``_lock``)."""
+        ivf_lists.labels(*self._labels).set(self.nlist)
+        ivf_list_capacity.labels(*self._labels).set(self.list_cap)
+        ivf_delta_rows.labels(*self._labels).set(len(self._delta_slots))
+        ivf_live_rows.labels(*self._labels).set(len(self._slot_loc))
+
+    def _put(self, arr):
+        """``arr`` on this store's device (runtime/placement.py)."""
+        return placement.put(arr, self.device)
+
+    def _zeros(self, shape, dtype):
+        return placement.zeros(shape, dtype, self.device)
 
     def _all_live_host(self):
         """(vectors [L,d] f32, slots [L] int64) for every live slot."""
@@ -636,20 +737,20 @@ class IVFStore:
             cap *= 2  # unplaceable at this cap — relax and retry
         self.list_cap = cap
         if self.quantization:
-            self.list_codes = jnp.zeros(
-                (self.nlist, cap, self.pq_segments), dtype=jnp.uint8)
-            self.list_tvals = jnp.zeros((self.nlist, cap),
-                                        dtype=jnp.float32)
+            self.list_codes = self._zeros(
+                (self.nlist, cap, self.pq_segments), jnp.uint8)
+            self.list_tvals = self._zeros((self.nlist, cap), jnp.float32)
             self.list_vecs = None
             self.list_norms = None
         else:
-            self.list_vecs = jnp.zeros((self.nlist, cap, self.dim),
-                                       dtype=self.dtype)
-            self.list_norms = jnp.zeros((self.nlist, cap), dtype=jnp.float32)
+            self.list_vecs = self._zeros((self.nlist, cap, self.dim),
+                                         self.dtype)
+            self.list_norms = self._zeros((self.nlist, cap), jnp.float32)
             self.list_codes = None
             self.list_tvals = None
-        self.list_valid = jnp.zeros((self.nlist, cap), dtype=jnp.bool_)
-        self.list_slots = jnp.full((self.nlist, cap), -1, dtype=jnp.int32)
+        self.list_valid = self._zeros((self.nlist, cap), jnp.bool_)
+        self.list_slots = self._put(
+            np.full((self.nlist, cap), -1, dtype=np.int32))
         self._fill = np.zeros(self.nlist, dtype=np.int64)
         self._holes = {}
         self.rebuild_count += 1
@@ -718,9 +819,10 @@ class IVFStore:
         FINAL assignment (spill included), so codes always quantize the
         residual of the centroid actually probed."""
         if len(vecs) == 0:
-            return
+            return 0
         assign = np.asarray(assign, dtype=np.int64).copy()
         pos = np.empty(len(assign), dtype=np.int64)
+        spilled = 0
         for i, l in enumerate(assign.tolist()):
             p = self._take_position(int(l))
             if p >= 0:
@@ -730,6 +832,7 @@ class IVFStore:
             if t >= 0:
                 assign[i] = t
                 pos[i] = self._take_position(t)
+                spilled += 1
             else:
                 self._grow_cap()
                 pos[i] = self._take_position(int(l))
@@ -750,7 +853,7 @@ class IVFStore:
             res = vecs - cents
             codes = pq_encode(self.codebook, res)
             rhat = np.asarray(pq_reconstruct(  # graftlint: disable=G1 — maintenance-time boundary (encode, not serving)
-                jnp.asarray(codes), self.codebook.centroids,
+                self._put(codes), self.codebook.centroids,
                 self.codebook.m))
             tvals = (2.0 * np.sum(cents * rhat, axis=1)
                      + np.sum(rhat * rhat, axis=1)).astype(np.float32)
@@ -762,8 +865,8 @@ class IVFStore:
              self.list_tvals) = _scatter_code_lists(
                 self.list_codes, self.list_valid, self.list_slots,
                 self.list_tvals,
-                jnp.asarray(i_buf), jnp.asarray(c_buf), jnp.asarray(t_buf),
-                jnp.asarray(s_buf), jnp.asarray(m_buf))
+                self._put(i_buf), self._put(c_buf), self._put(t_buf),
+                self._put(s_buf), self._put(m_buf))
         else:
             v_buf = np.zeros((bucket, self.dim), np.float32)
             v_buf[:len(vecs)] = vecs
@@ -771,10 +874,11 @@ class IVFStore:
              self.list_norms) = _scatter_lists(
                 self.list_vecs, self.list_valid, self.list_slots,
                 self.list_norms,
-                jnp.asarray(i_buf), jnp.asarray(v_buf), jnp.asarray(s_buf),
-                jnp.asarray(m_buf))
+                self._put(i_buf), self._put(v_buf), self._put(s_buf),
+                self._put(m_buf))
         for s, fi in zip(slots.tolist(), flat_idx.tolist()):
             self._slot_loc[int(s)] = ("list", int(fi))
+        return spilled
 
     def _grow_cap(self):
         """Double per-list capacity (repack on host — rare, amortized)."""
@@ -784,24 +888,25 @@ class IVFStore:
         if self.quantization:
             self.list_codes = jnp.concatenate(
                 [self.list_codes,
-                 jnp.zeros((self.nlist, pad, self.pq_segments),
-                           dtype=jnp.uint8)], axis=1)
+                 self._zeros((self.nlist, pad, self.pq_segments),
+                             jnp.uint8)], axis=1)
             self.list_tvals = jnp.concatenate(
                 [self.list_tvals,
-                 jnp.zeros((self.nlist, pad), dtype=jnp.float32)], axis=1)
+                 self._zeros((self.nlist, pad), jnp.float32)], axis=1)
         else:
             self.list_vecs = jnp.concatenate(
                 [self.list_vecs,
-                 jnp.zeros((self.nlist, pad, self.dim), dtype=self.dtype)],
+                 self._zeros((self.nlist, pad, self.dim), self.dtype)],
                 axis=1)
             self.list_norms = jnp.concatenate(
                 [self.list_norms,
-                 jnp.zeros((self.nlist, pad), dtype=jnp.float32)], axis=1)
+                 self._zeros((self.nlist, pad), jnp.float32)], axis=1)
         self.list_valid = jnp.concatenate(
-            [self.list_valid, jnp.zeros((self.nlist, pad), dtype=jnp.bool_)],
+            [self.list_valid, self._zeros((self.nlist, pad), jnp.bool_)],
             axis=1)
         self.list_slots = jnp.concatenate(
-            [self.list_slots, jnp.full((self.nlist, pad), -1, dtype=jnp.int32)],
+            [self.list_slots,
+             self._put(np.full((self.nlist, pad), -1, dtype=np.int32))],
             axis=1)
         self.list_cap = new_cap
         self._hbm_sync()
@@ -818,29 +923,38 @@ class IVFStore:
         with self._lock:
             if not self.trained:
                 return
-            dsnap = self.delta.snapshot()
-            live = np.nonzero(dsnap["valid"])[0]
-            if len(live) == 0:
-                self._reset_delta()
+            if not self._delta_slots and self.delta.count == 0:
                 return
-            vecs = dsnap["vectors"][live]
-            slots = np.asarray([self._delta_slots[int(d)] for d in live],
-                               dtype=np.int64)
-            if self.quantization and self.codebook is None:
-                # compression was enabled while the store was empty —
-                # the codebook trains on the first flush with enough data
-                # (until then rows stay in the exact delta)
-                if len(vecs) < self.pq_centroids:
-                    return
-                from weaviate_tpu.ops.pq import pq_fit
+            with maintain_stage("flush", "ivf.flush_delta") as (sp, _):
+                self._flush_delta_locked(sp)
+            self._publish_gauges()
 
-                a0 = kmeans_assign(vecs, self._centroids_np)
-                self.codebook = pq_fit(vecs - self._centroids_np[a0],
-                                       m=self.pq_segments,
-                                       k=self.pq_centroids, iters=8)
-            assign = kmeans_assign(vecs, self._centroids_np)
-            self._scatter_assigned(vecs, slots, assign)
+    def _flush_delta_locked(self, sp) -> None:
+        """The fold itself (caller holds ``_lock``)."""
+        dsnap = self.delta.snapshot()
+        live = np.nonzero(dsnap["valid"])[0]
+        if len(live) == 0:
             self._reset_delta()
+            return
+        vecs = dsnap["vectors"][live]
+        slots = np.asarray([self._delta_slots[int(d)] for d in live],
+                           dtype=np.int64)
+        if self.quantization and self.codebook is None:
+            # compression was enabled while the store was empty —
+            # the codebook trains on the first flush with enough data
+            # (until then rows stay in the exact delta)
+            if len(vecs) < self.pq_centroids:
+                return
+            from weaviate_tpu.ops.pq import pq_fit
+
+            a0 = kmeans_assign(vecs, self._centroids_np)
+            self.codebook = pq_fit(vecs - self._centroids_np[a0],
+                                   m=self.pq_segments,
+                                   k=self.pq_centroids, iters=8)
+        assign = kmeans_assign(vecs, self._centroids_np)
+        spilled = self._scatter_assigned(vecs, slots, assign)
+        sp.set(rows=len(vecs), spilled=spilled)
+        self._reset_delta()
 
     def _reset_delta(self):
         """Swap in a fresh delta store. Caller holds ``_lock``."""
@@ -850,8 +964,9 @@ class IVFStore:
             self.delta = DeviceVectorStore(
                 self.dim, self.metric,
                 capacity=min(self.capacity, self.delta_threshold * 2),
-                chunk_size=self.chunk_size)
+                chunk_size=self.chunk_size, dtype=self.dtype)
         self._delta_slots = {}
+        self._delta_gmap = np.full(self.delta.capacity, -1, np.int32)
 
     # -- queries -------------------------------------------------------------
 
@@ -866,16 +981,14 @@ class IVFStore:
         if allow_mask is None:
             return None
         cap_d = self.delta.capacity
+        gmap = self._delta_gmap[:cap_d]
+        ds = np.flatnonzero((gmap >= 0) & (gmap < allow_mask.shape[-1]))
         if allow_mask.ndim == 2:
             out = np.zeros((b, cap_d), dtype=bool)
-            for ds, g in self._delta_slots.items():
-                if ds < cap_d and g < allow_mask.shape[1]:
-                    out[:, ds] = allow_mask[:, g]
+            out[:, ds] = allow_mask[:, gmap[ds]]
             return out
         out = np.zeros(cap_d, dtype=bool)
-        for ds, g in self._delta_slots.items():
-            if ds < cap_d and g < len(allow_mask) and allow_mask[g]:
-                out[ds] = True
+        out[ds] = allow_mask[gmap[ds]]
         return out
 
     def search(self, queries: np.ndarray, k: int,
@@ -916,13 +1029,9 @@ class IVFStore:
                 dd, di = self.delta.epoch_scan(
                     queries, min(k, self.delta.capacity),
                     self._delta_allow(allow_mask, b))
-                gmap = np.full(max(self.delta.capacity, 1), -1, np.int32)
-                for ds, g in self._delta_slots.items():
-                    if ds < len(gmap):
-                        gmap[ds] = g
-                gd = jnp.asarray(gmap)
+                gd = self._put(self._delta_gmap)
                 di = jnp.where(di >= 0,
-                               gd[jnp.clip(di, 0, len(gmap) - 1)], -1)
+                               gd[jnp.clip(di, 0, gd.shape[0] - 1)], -1)
                 legs_d.append(jnp.where(di >= 0, dd, MASKED_DISTANCE))
                 legs_i.append(di.astype(jnp.int32))
             if (self.trained and self._fill is not None
@@ -934,7 +1043,7 @@ class IVFStore:
                     from weaviate_tpu.ops.pallas_kernels import (
                         mask_pad_cols, pack_allow_bitmask)
 
-                    bits = jnp.asarray(pack_allow_bitmask(
+                    bits = self._put(pack_allow_bitmask(
                         allow_mask, mask_pad_cols(self.capacity)))
                     hbm_ledger.ledger.track("allow_bitmask", bits,
                                             **self._hbm_owner)
@@ -956,7 +1065,7 @@ class IVFStore:
                     delta_leg=bool(legs_d))
                 outs_d, outs_i = [], []
                 for s in range(0, b, self.query_chunk):
-                    q_dev = jnp.asarray(queries[s:s + self.query_chunk])
+                    q_dev = self._put(queries[s:s + self.query_chunk])
                     bch = (bits if bits.shape[0] == 1
                            else bits[s:s + self.query_chunk])
                     if self.quantization:
@@ -984,7 +1093,14 @@ class IVFStore:
                 legs_i.append((outs_i[0] if len(outs_i) == 1
                                else jnp.concatenate(outs_i))
                               .astype(jnp.int32))
-            sp.set(nprobe=np_probe, nlist=self.nlist)
+                ivf_queries_total.inc(b)
+                ivf_probed_lists_total.inc(b * np_probe)
+                ivf_candidate_rows_total.inc(b * np_probe * self.list_cap)
+                ivf_probe_programs_total.inc(len(outs_d))
+            sp.set(nprobe=np_probe, nlist=self.nlist,
+                   list_cap=self.list_cap,
+                   delta_rows=len(self._delta_slots),
+                   candidates=np_probe * self.list_cap)
             kernelscope.explain_note("ivf", merge_legs=len(legs_d))
             if not legs_d:
                 d_e = np.full((b, k), MASKED_DISTANCE, np.float32)
@@ -1058,6 +1174,7 @@ class IVFStore:
                 "metric": self.metric,
                 "count": self._count,
                 "nlist": self.nlist if self.trained else 0,
+                "user_nlist": self._user_nlist,
                 "nprobe": self.nprobe,
                 "centroids": (np.asarray(self.centroids, np.float32)
                               if self.trained else None),
@@ -1092,8 +1209,11 @@ class IVFStore:
         # storage dtype survives the round-trip unless explicitly overridden
         # (same contract as DeviceVectorStore.restore)
         dtype = kwargs.pop("dtype", None) or jnp.dtype(snap.get("dtype", "float32"))
+        # (a snapshot from before the two were kept apart pins the
+        # number it was trained with, as that tree did)
         store = cls(dim=snap["dim"], metric=snap["metric"],
-                    nlist=snap.get("nlist", 0), nprobe=snap.get("nprobe", 0),
+                    nlist=snap.get("user_nlist", snap.get("nlist", 0)),
+                    nprobe=snap.get("nprobe", 0),
                     chunk_size=snap.get("chunk_size", 8192),
                     train_threshold=snap.get("train_threshold", 16_384),
                     delta_threshold=snap.get("delta_threshold", 8192),
@@ -1119,7 +1239,7 @@ class IVFStore:
         if snap.get("centroids") is not None:
             store.nlist = snap["nlist"]
             store._centroids_np = np.asarray(snap["centroids"], np.float32)
-            store.centroids = jnp.asarray(store._centroids_np)
+            store.centroids = store._put(store._centroids_np)
             store._c_norms = jnp.sum(store.centroids * store.centroids, axis=1)
             if store.quantization and store.codebook is None:
                 # quantization enabled before any codebook could train
@@ -1175,12 +1295,13 @@ class IVFIndex(FlatIndex):
         with self._lock:
             self.store.train(force_nlist=nlist)
 
-    def maintain(self) -> None:
-        """Incremental maintenance (db/shard.py epoch_maintenance): delta
-        flush always, retrain only past the drift gate — never a
-        compaction-triggered full rebuild."""
+    def maintain(self, tick: bool = False) -> bool:
+        """Incremental maintenance (db/shard.py epoch_maintenance): the
+        delta folded, a retrain only past the drift gate — never a
+        compaction-triggered full rebuild. ``tick`` and the result as
+        ``IVFStore.maintain``."""
         with self._lock:
-            self.store.maintain()
+            return self.store.maintain(tick=tick)
 
     def compress(self, quantization: str = "pq", **quant_kwargs) -> None:
         """Runtime switch to residual-PQ residency: fit a codebook on the

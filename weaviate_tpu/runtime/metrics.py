@@ -908,6 +908,51 @@ index_compress_total = registry.counter(
     "class goes on answering from full rows and tries again)",
     ("quantization", "result"))
 
+# -- the ANN index (engine/ivf.py, engine/dynamic.py) -------------------------
+
+ivf_maintain_seconds = registry.histogram(
+    "weaviate_tpu_ivf_maintain_seconds",
+    "Wall seconds of one step that builds or keeps an IVF index, all off "
+    "the request path: upgrade (a dynamic class's flat rows moved into a "
+    "fresh IVF index at its threshold, less the training inside it), "
+    "train (k-means, assignment and the posting lists rebuilt: the first "
+    "training and every retrain), flush (the delta buffer folded into "
+    "the lists)", ("stage",),
+    buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
+             120.0))
+ivf_queries_total = registry.counter(
+    "weaviate_tpu_ivf_queries_total",
+    "Query rows that probed posting lists (the rows of the padded block "
+    "a dispatch hands the store), one increment a dispatch")
+ivf_probed_lists_total = registry.counter(
+    "weaviate_tpu_ivf_probed_lists_total",
+    "Posting lists probed: query rows x nprobe, one increment a dispatch")
+ivf_candidate_rows_total = registry.counter(
+    "weaviate_tpu_ivf_candidate_rows_total",
+    "Padded list positions gathered and scored: query rows x nprobe x "
+    "the lists' capacity, one increment a dispatch")
+ivf_probe_programs_total = registry.counter(
+    "weaviate_tpu_ivf_probe_programs_total",
+    "Probe programs launched: a dispatch's block is cut into chunks of "
+    "at most query_chunk rows, one program each; one increment a "
+    "dispatch")
+ivf_lists = registry.gauge(
+    "weaviate_tpu_ivf_lists",
+    "Posting lists of a trained IVF index (set at train and flush)",
+    ("collection", "shard"))
+ivf_list_capacity = registry.gauge(
+    "weaviate_tpu_ivf_list_capacity",
+    "Padded positions a posting list holds (the middle axis of the list "
+    "tensors)", ("collection", "shard"))
+ivf_delta_rows = registry.gauge(
+    "weaviate_tpu_ivf_delta_rows",
+    "Rows in the exact delta buffer, not yet folded into the lists, as "
+    "of the last train or flush", ("collection", "shard"))
+ivf_live_rows = registry.gauge(
+    "weaviate_tpu_ivf_live_rows",
+    "Live rows the index holds, lists and delta together, as of the "
+    "last train or flush", ("collection", "shard"))
+
 # -- jit compilation (runtime/compile_cache.py installs the listeners) --------
 
 compile_cache_events = registry.counter(
