@@ -910,9 +910,11 @@ class Collection:
     # -- search --------------------------------------------------------------
 
     def _attach_objects(self, results: list[SearchResult]) -> None:
-        """Fill in .object for results that don't carry one yet — local
-        lookup, or ONE batched remote get per non-local shard (not one
-        RPC per result)."""
+        """Fill in .object for results that don't carry one yet — ONE
+        batched read per local shard (``Shard.get_objects``: one lock
+        acquisition and one search a segment, not a result), ONE batched
+        remote get per non-local shard (not one RPC per result). A result
+        whose object has gone since the search keeps ``object = None``."""
         missing: dict[str, list[SearchResult]] = {}
         for r in results:
             if r.object is None:
@@ -921,12 +923,15 @@ class Collection:
             return
         with tracing.span("objects.fetch", stage="fetch",
                           n=sum(len(rs) for rs in missing.values()),
-                          shards=len(missing)):
+                          shards=len(missing)) as sp:
+            reads, routes = 0, {}
             for name, rs in missing.items():
                 if self._is_local(name):
-                    shard = self._load_shard(name)
-                    for r in rs:
-                        r.object = shard.get_object(r.uuid)
+                    reads += 1
+                    objs = self._load_shard(name).get_objects(
+                        [r.uuid for r in rs], routes)
+                    for r, obj in zip(rs, objs):
+                        r.object = obj
                 else:
                     from weaviate_tpu.cluster.transport import RpcError
 
@@ -945,6 +950,8 @@ class Collection:
                     for r, raw in zip(rs, raws):
                         r.object = StorageObject.from_bytes(raw) \
                             if raw else None
+            sp.set(reads=reads, array_keys=routes.get("array", 0),
+                   scalar_keys=routes.get("scalar", 0))
 
     # -- epoch migration (ROADMAP item 3: ledger-driven epoch placement) ------
 

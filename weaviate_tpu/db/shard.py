@@ -863,6 +863,16 @@ class Shard:
             return None
         return StorageObject.from_bytes(raw)
 
+    def get_objects(self, uuids: list[str],
+                    routes: dict | None = None) -> list[StorageObject | None]:
+        """``[get_object(u) for u in uuids]`` in ONE ``kv.get_many``: a
+        Search's reply reads its objects here, one lock acquisition and
+        one search a segment a request, not a result (``routes``:
+        ``Bucket.get_many``'s tally)."""
+        raws = self.objects.get_many([u.encode() for u in uuids], routes)
+        return [None if raw is None else StorageObject.from_bytes(raw)
+                for raw in raws]
+
     def exists(self, uuid: str) -> bool:
         return self.docid.get(uuid.encode()) is not None
 
@@ -883,19 +893,9 @@ class Shard:
         here, so property fetch on the hot path is one LSM batch per
         reply batch."""
         uuids = [self._doc_to_uuid.get(int(d)) for d in doc_ids]
-        keys = [u.encode() for u in uuids if u is not None]
-        if not keys:
-            return [None] * len(uuids)
-        raws = iter(self.objects.get_many(keys))
-        out: list[StorageObject | None] = []
-        for u in uuids:
-            if u is None:
-                out.append(None)
-                continue
-            raw = next(raws)
-            out.append(None if raw is None
-                       else StorageObject.from_bytes(raw))
-        return out
+        known = [u for u in uuids if u is not None]
+        objs = iter(self.get_objects(known) if known else ())
+        return [None if u is None else next(objs) for u in uuids]
 
     def vector_search(self, query: np.ndarray, k: int, vec_name: str = "",
                       allow_list: np.ndarray | None = None):

@@ -437,6 +437,13 @@ lsm_compaction_duration = registry.histogram(
     "weaviate_tpu_lsm_compaction_duration_seconds",
     "Segment compaction latency", ("bucket",))
 
+kv_batched_keys = registry.counter(
+    "weaviate_tpu_kv_batched_keys_total",
+    "Keys of batched replace reads (Bucket.get_many) by the route that "
+    "resolved them: memtable, array (one vectorised search a fixed-width "
+    "segment) or scalar (the per-key bloom walk and binary search)",
+    ("path",))
+
 # -- crash recovery (storage/recovery.py records these at every bucket
 #    open; /v1/debug/storage serves the same registry as JSON) ----------------
 

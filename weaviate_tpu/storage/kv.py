@@ -42,7 +42,7 @@ import msgpack
 import numpy as np
 
 from weaviate_tpu import native
-from weaviate_tpu.runtime import faultline, tracing
+from weaviate_tpu.runtime import faultline, metrics, tracing
 from weaviate_tpu.storage import fsutil, recovery
 from weaviate_tpu.storage.wal import ReplayReport, WriteAheadLog
 
@@ -197,19 +197,54 @@ def _is_tomb_record(raw: bytes) -> bool:
     return isinstance(obj, dict) and obj.get("__tomb__") is True
 
 
+def _replace_record(raw: bytes):
+    """A replace segment's record -> its value, None for a tombstone:
+    ONE decode for the tombstone test and the value (a 3-KB object was
+    copied out of msgpack twice a hit)."""
+    obj = msgpack.unpackb(raw, raw=False, strict_map_key=False)
+    return None if obj.get("__tomb__") is True else obj["v"]
+
+
 def _replace_segment_lookup(segments_newest_first, key: bytes):
     """Replace-strategy point lookup over a segment stack: first hit wins,
     tombstones shadow. The bloom key hash is computed once and probed
     against every segment (one blake2b per lookup, not per segment).
-    Shared by Bucket.get and Bucket.get_many so batched and single-key
-    reads can never diverge."""
+    The one definition of that rule: Bucket.get calls it, and
+    Bucket.get_many's segment-at-a-time walk is held to it key for key
+    (tests/test_kv_batched_read.py)."""
     hashes = _bloom_hashes(key) if segments_newest_first else None
     for seg in segments_newest_first:
         raw = seg.get(key, hashes)
         if raw is not None:
-            return None if _is_tomb_record(raw) else \
-                _unpack_value("replace", raw)
+            return _replace_record(raw)
     return None
+
+
+_BATCHED_KEYS = {path: metrics.kv_batched_keys.labels(path)
+                 for path in ("memtable", "array", "scalar")}
+
+#: the fewest keys a batched read searches a segment for with the array
+#: calls. They cost ~9 us a segment whatever the batch, the per-key walk
+#: ~15 us a key and ~2 a segment its bloom filter rejects: two keys pay
+#: for the arrays over 1-5 segments, and ONE key is ``get``'s walk
+_ARRAY_MIN_BATCH = 2
+
+
+def _count_routes(n_mem: int, n_array: int, n_scalar: int, sp,
+                  routes: dict | None) -> None:
+    """A batched read's keys by the route that resolved them, into
+    ``weaviate_tpu_kv_batched_keys_total{path}`` (one ``inc`` a route
+    that resolved any), the read's span and the caller's tally.
+    ``memtable``: answered by a memtable, a tombstone there included;
+    ``array`` / ``scalar``: a memtable miss whose segment search ended on
+    that route. A miss of a bucket with no segment is counted nowhere."""
+    for path, n in (("memtable", n_mem), ("array", n_array),
+                    ("scalar", n_scalar)):
+        if n:
+            _BATCHED_KEYS[path].inc(n)
+            if routes is not None:
+                routes[path] = routes.get(path, 0) + n
+    sp.set(memtable=n_mem, array=n_array, scalar=n_scalar)
 
 
 def _bloom_hashes(key: bytes) -> tuple[int, int]:
@@ -253,7 +288,10 @@ class _Segment:
     Only the footer is parsed at open; key lookups binary-search the on-disk
     index through the mmap (reference: segmentindex/ on-disk b-tree-ish
     index + segment.go:28 mmap) after a bloom-filter check
-    (segment_bloom_filters.go).
+    (segment_bloom_filters.go). Where every key has one length (uuid
+    keys: the objects and docid buckets) the key blob is also viewed as
+    ONE fixed-width array over the mmap, and a batch of keys is searched
+    with one vectorised binary search (``find_many``).
     """
 
     _IDX = np.dtype([("koff", "<u8"), ("klen", "<u4"),
@@ -293,6 +331,7 @@ class _Segment:
                                     offset=bloom_off)
         self._bloom_bits = bloom_words * 64
         self._keys_off = keys_off
+        self._keys = self._fixed_width_keys(idx_off)
         # validate extremes once so a bit-flipped index can't point outside
         # the file on later reads
         if self.n:
@@ -303,6 +342,48 @@ class _Segment:
                     raise ValueError("segment index offsets out of range")
 
     # -- key access ----------------------------------------------------------
+
+    def _fixed_width_keys(self, idx_off: int) -> np.ndarray | None:
+        """The key blob as an ``[n]`` array of ``S<w>`` over the mmap (no
+        copy) where every key is ``w`` bytes and the keys lie back to
+        back, else None. numpy compares two ``S<w>`` items of ONE width
+        byte by byte, unsigned, over all ``w`` bytes, which is ``bytes``
+        order; what it strips (trailing NULs) it strips on conversion to
+        ``bytes`` and between widths, and ``find_many`` does neither."""
+        if not self.n:
+            return None
+        w = int(self._idx["klen"][0])
+        if not w or self._keys_off + self.n * w > idx_off \
+                or not (self._idx["klen"] == w).all():
+            return None
+        koff = self._keys_off + w * np.arange(self.n, dtype=np.uint64)
+        if not np.array_equal(self._idx["koff"], koff):
+            return None
+        return np.frombuffer(self._mm, dtype=f"S{w}", count=self.n,
+                             offset=self._keys_off)
+
+    def find_many(self, keys: list[bytes]) -> dict[int, bytes]:
+        """``{j: self.get(keys[j])}`` for the keys a fixed-width segment
+        holds, in one vectorised binary search: no bloom walk, no Python
+        step a level. A key of another width is in no such segment."""
+        seg_keys = self._keys
+        w = seg_keys.dtype.itemsize
+        at = None
+        if set(map(len, keys)) != {w}:
+            at = [j for j, k in enumerate(keys) if len(k) == w]
+            keys = [keys[j] for j in at]
+            if not keys:
+                return {}
+        needles = np.frombuffer(b"".join(keys), dtype=seg_keys.dtype)
+        pos = np.minimum(seg_keys.searchsorted(needles), self.n - 1)
+        hit = np.flatnonzero(seg_keys[pos] == needles)
+        if not len(hit):
+            return {}
+        e = self._idx[pos[hit]]
+        mm = self._mm
+        return {j if at is None else at[j]: mm[vo : vo + vl]
+                for j, vo, vl in zip(hit.tolist(), e["voff"].tolist(),
+                                     e["vlen"].tolist())}
 
     def _key_at(self, i: int) -> bytes:
         e = self._idx[i]
@@ -373,6 +454,7 @@ class _Segment:
         # numpy views pin the mmap buffer — drop them before closing
         self._idx = None
         self._bloom = None
+        self._keys = None
         try:
             self._mm.close()
             self._f.close()
@@ -428,6 +510,8 @@ class _Segment:
 class _SegmentV1:
     """Round-1 segment format reader (footer key list in RAM) — kept so
     restores of old backup fileset still open."""
+
+    _keys = None  # no fixed-width view: batched reads search it key by key
 
     def __init__(self, path: str):
         self.path = path
@@ -1175,26 +1259,28 @@ class Bucket:
                 seen_any = True
         return out if seen_any else None
 
-    def get_many(self, keys: list[bytes]) -> list:
-        """Batched replace-strategy point lookups: ONE layer snapshot for
-        the whole batch instead of a lock + sealed-list copy per key (the
-        per-object docid update-check was ~5 us/object of pure snapshot
-        overhead on the import path).
+    def get_many(self, keys: list[bytes], routes: dict | None = None) -> list:
+        """Batched replace-strategy point lookups: ``[get(k) for k in
+        keys]`` at one lock acquisition a BATCH and one search a SEGMENT.
 
         The memtable probes run UNDER the lock, like ``get``'s — the
         active memtable dict keeps mutating under concurrent writers, so
         probing it unlocked could race a resize (and would let the two
         paths diverge). Segments are immutable once listed, so the disk
-        lookups for memtable misses happen after the lock drops."""
+        lookups for memtable misses happen after the lock drops
+        (``_walk_segments``). ``routes``, where given, has the keys each
+        route resolved added to it (``_count_routes``)."""
         assert self.strategy == "replace"
-        # faultline point: the batched property-fetch feed (native
-        # plane reply building + warm pass read through here) — chaos
-        # runs inject errors/latency/corruption without touching disk
+        # faultline point: every batched object read (a Search's reply,
+        # the native plane's reply building + warm pass, the import's
+        # update check) — chaos runs inject errors/latency/corruption
+        # without touching disk
         directive = faultline.fire("kv.get_many", bucket=self.name,
                                    n=len(keys))
         misses: list[int] = []
         out: list = []
-        with tracing.span("kv.get_many", bucket=self.name, n=len(keys)):
+        with tracing.span("kv.get_many", bucket=self.name,
+                          n=len(keys)) as sp:
             with self._lock:
                 # newest first; replace memtables are always dict-backed
                 mems = [m.data for m in [*self._sealed, self._mem][::-1]]
@@ -1208,8 +1294,11 @@ class Bucket:
                     else:
                         out.append(None)
                         misses.append(idx)
-            for idx in misses:
-                out[idx] = _replace_segment_lookup(segments, keys[idx])
+            n_array, n_scalar = self._walk_segments(
+                segments, keys, misses, out) if misses and segments \
+                else (0, 0)
+            _count_routes(len(keys) - len(misses), n_array, n_scalar,
+                          sp, routes)
             if directive == "corrupt":
                 # deterministic damage: flip the first byte of every
                 # value — consumers must contain the decode failure
@@ -1217,6 +1306,42 @@ class Bucket:
                 out = [bytes([v[0] ^ 0xFF]) + v[1:]
                        if isinstance(v, bytes) and v else v for v in out]
             return out
+
+    @staticmethod
+    def _walk_segments(segments, keys, pending: list[int],
+                       out: list) -> tuple[int, int]:
+        """``out[i] = _replace_segment_lookup(segments, keys[i])`` for
+        every ``i`` of ``pending``, a SEGMENT at a time, newest first:
+        the whole of what is still missing in one vectorised search of a
+        fixed-width segment (``_Segment.find_many``), a hit decoded once
+        and taken out of what the next older segment is asked for. A
+        segment with keys of several lengths is searched key by key, and
+        so is any segment once fewer than ``_ARRAY_MIN_BATCH`` keys are
+        left. -> the keys whose search ENDED on the array route and on
+        the scalar route (the segment that held the key; for a key in
+        none, the oldest)."""
+        ended = [0, 0]  # array, scalar
+        hashes: dict[int, tuple[int, int]] = {}
+        for seg in segments:
+            scalar = seg._keys is None or len(pending) < _ARRAY_MIN_BATCH
+            if scalar:
+                for i in pending:
+                    if i not in hashes:
+                        hashes[i] = _bloom_hashes(keys[i])
+                found = {j: raw for j, i in enumerate(pending)
+                         if (raw := seg.get(keys[i], hashes[i])) is not None}
+            else:
+                found = seg.find_many([keys[i] for i in pending])
+            if found:
+                for j, raw in found.items():
+                    out[pending[j]] = _replace_record(raw)
+                ended[scalar] += len(found)
+                pending = [i for j, i in enumerate(pending)
+                           if j not in found]
+                if not pending:
+                    break
+        ended[scalar] += len(pending)
+        return ended[0], ended[1]
 
     def get_set(self, key: bytes) -> set:
         v = self.get(key)

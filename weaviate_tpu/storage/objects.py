@@ -48,6 +48,20 @@ class StorageObject:
         if not self.last_update_time_ms:
             self.last_update_time_ms = self.creation_time_ms
 
+    def _get_vectors(self) -> dict[str, np.ndarray]:
+        v = self._vectors
+        if v is None:
+            # two threads may both decode: the dicts are equal, one stays
+            data, spans = self._frame
+            v = self._vectors = {
+                name: np.frombuffer(data, dtype="<f4", count=dim,
+                                    offset=off).copy()
+                for name, dim, off in spans}
+        return v
+
+    def _set_vectors(self, value: dict[str, np.ndarray]) -> None:
+        self._vectors, self._frame = value, None
+
     @property
     def vector(self) -> np.ndarray | None:
         """Default (unnamed) vector, stored under ''."""
@@ -92,13 +106,17 @@ class StorageObject:
 
     @classmethod
     def from_bytes(cls, data: bytes) -> "StorageObject":
+        """Decode what every reader reads (header, uuid, properties); the
+        vectors are walked over and decoded from ``data`` at the first
+        read of ``vectors`` (a Search's reply reads them only where the
+        request asks for them, and copied 3 KB a result for nothing)."""
         version, doc_id, ctime, mtime, uid = _HEADER.unpack_from(data, 0)
         if version != _VERSION:
             raise ValueError(f"unsupported storage object version {version}")
         off = _HEADER.size
         (n_vecs,) = _U32.unpack_from(data, off)
         off += 4
-        vectors: dict[str, np.ndarray] = {}
+        spans = []  # (name, dim, offset of its floats)
         for _ in range(n_vecs):
             (nlen,) = _U16.unpack_from(data, off)
             off += 2
@@ -106,20 +124,23 @@ class StorageObject:
             off += nlen
             (dim,) = _U32.unpack_from(data, off)
             off += 4
-            vec = np.frombuffer(data, dtype="<f4", count=dim, offset=off).copy()
+            spans.append((name, dim, off))
             off += 4 * dim
-            vectors[name] = vec
         (plen,) = _U32.unpack_from(data, off)
         off += 4
-        props = msgpack.unpackb(data[off : off + plen], raw=False)
-        return cls(
-            uuid=str(uuid_mod.UUID(bytes=uid)),
+        if off + plen > len(data):
+            raise ValueError("storage object truncated")
+        h = uid.hex()  # str(uuid.UUID(bytes=uid)), at a fifth of its cost
+        obj = cls(
+            uuid=f"{h[:8]}-{h[8:12]}-{h[12:16]}-{h[16:20]}-{h[20:]}",
             doc_id=doc_id,
-            properties=props,
-            vectors=vectors,
+            properties=msgpack.unpackb(data[off : off + plen], raw=False),
             creation_time_ms=ctime,
             last_update_time_ms=mtime,
         )
+        if spans:
+            obj._frame, obj._vectors = (data, spans), None
+        return obj
 
     @staticmethod
     def read_vector_into(data, name: str, out: np.ndarray) -> int | None:
@@ -169,3 +190,12 @@ class StorageObject:
             h.update(np.ascontiguousarray(vec, dtype=np.float32).tobytes())
         h.update(msgpack.packb(self.properties, use_bin_type=True))
         return h.digest()[:16]
+
+
+# ``vectors`` stays a dataclass field (constructor, ``==``, ``repr``) and
+# is served by a property, set here because the decorator would read one
+# defined in the class body as the field's default: of an object read
+# from storage the dict is decoded from the stored frame at its first read
+StorageObject.vectors = property(
+    StorageObject._get_vectors, StorageObject._set_vectors,
+    doc="name -> float32 vector; the default (unnamed) one under ''")
