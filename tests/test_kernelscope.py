@@ -300,6 +300,7 @@ def test_explain_ivf_filtered_plan_and_sync_async_parity():
     assert ivf["filtered"] is True
     assert ivf["queries"] == 3 and ivf["k"] == 10
     assert "merge_legs" in ivf and "delta_leg" in ivf
+    assert ivf["gather"] == "codes"    # "slab" where the lists hold rows
 
     # sync IS async.result() — pin the bit-identical contract, and pin
     # that running WITHOUT a sink changes nothing about the results

@@ -303,13 +303,14 @@ def test_served_scans_with_the_rescore_tail(one_chip, monkeypatch, cell, b):
 
 @pytest.mark.parametrize("b", [1, 16])
 def test_ivf_probe_at_the_dynamic_cells_shapes(one_chip, b):
-    """``glove-dynamic-cosine.c32``'s probe program (ISSUE 43) as the
-    served store launches it: 1,024 lists of 512 positions x 100 float32,
-    128 lists probed a query, a chunk of at most 16 queries. The chip's
-    compiler takes it, and its temporaries are the gathered candidates
-    ([b, 65536, 100 floats in 128 lanes]) and not several copies of
-    them: the probe's cost on the chip IS that gather (PERF.md section
-    6, PR 43), so a later PR that changes it is held here first."""
+    """``glove-dynamic-cosine.c32``'s probe program (ISSUES 43, 47) as
+    the served store launches it: 1,024 lists of 512 positions x 100
+    float32, 128 lists probed a query, a chunk of at most 16 queries. The
+    chip's compiler takes it, the probed lists are gathered as whole
+    slabs (``[b * 128, 512, 100]``: one block a list, not one row a
+    position) and its temporaries are that block ONCE beside the list
+    tensor's one layout copy: a later PR that changes the gather is held
+    here first."""
     from weaviate_tpu.engine.ivf import _ivf_probe_topk
 
     nlist, cap, d, nprobe, k = 1024, 512, 100, 128, 10
@@ -325,9 +326,10 @@ def test_ivf_probe_at_the_dynamic_cells_shapes(one_chip, b):
                  ((nlist, cap), f32), ((1, 16), jnp.uint32))
     text = c.as_text()
     assert text.count(f"[{b},{k}]{{1,0") >= 2          # [b, k] goes back
+    assert f"f32[{b * nprobe},{cap},{d}]" in text       # the slab gather
     gathered = b * nprobe * cap * 128 * 4
     assert c.memory_analysis().temp_size_in_bytes < \
-        (nlist * cap * 128 * 4) + 2 * gathered + (64 << 20)
+        (nlist * cap * 128 * 4) + gathered + (64 << 20)
 
 
 @pytest.mark.parametrize("b_pad", [1, 16, 32])

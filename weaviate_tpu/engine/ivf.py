@@ -9,11 +9,12 @@ possible shape for a systolic array. The TPU-idiomatic replacement
 - **layout**: posting lists as ONE dense padded tensor ``[nlist, cap, d]``
   in HBM (+ valid mask, slot ids, cached norms) — uniform shapes so the
   probe gather is a static-shape `take`, not ragged pointer chasing
-- **search**: query→centroid matmul → top-nprobe lists → candidate-slot
-  plane (ops/candidates.py): one gather-matmul over the probed blocks,
-  per-query ``allow_bits`` folded per candidate, exact top-k. Dispatch
-  only — ``search_async`` returns a DeviceResultHandle and ``search`` is
-  its ``.result()``, so sync and async are bit-exact by construction.
+- **search**: query→centroid matmul → top-nprobe lists → each probed
+  list gathered as the ``[cap, d]`` slab it is and scored in the same
+  program (``_ivf_probe_topk``), per-query ``allow_bits`` folded per
+  candidate, exact top-k. Dispatch only — ``search_async`` returns a
+  DeviceResultHandle and ``search`` is its ``.result()``, so sync and
+  async are bit-exact by construction.
 - **residual PQ** (quantization="pq"): posting lists hold uint8 codes of
   the RESIDUAL ``r = x - centroid[assign]`` (IVF-ADC; the residual has
   ~nlist× less variance than the raw vector, so the same code budget
@@ -58,7 +59,8 @@ import numpy as np
 from weaviate_tpu.engine.flat import FlatIndex
 from weaviate_tpu.engine.store import (DeviceVectorStore, _next_pow2,
                                        normalize_allow_mask)
-from weaviate_tpu.ops.candidates import gather_rescore_topk
+from weaviate_tpu.ops.candidates import (gather_rescore_topk,
+                                         masked_candidate_topk)
 from weaviate_tpu.ops.distances import (MASKED_DISTANCE, normalize,
                                         normalize_np, pairwise_distance)
 from weaviate_tpu.ops.kmeans import kmeans_assign, kmeans_fit
@@ -91,12 +93,6 @@ def maintain_stage(stage: str, span: str, **attrs):
         yield sp, less
     ivf_maintain_seconds.labels(stage).observe(
         max(0.0, time.perf_counter() - t0 - less[0]))
-
-
-def _dummy_bits():
-    """Placeholder ``allow_bits`` operand for ``use_allow=False`` probe
-    variants (64 bytes of zeros; never read)."""
-    return jnp.zeros((1, _MASK_WORDS), dtype=jnp.uint32)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
@@ -248,12 +244,20 @@ def _ivf_probe_topk_pq(q, centroids, c_norms, list_codes, list_valid,
 def _ivf_probe_topk(q, centroids, c_norms, list_vecs, list_valid, list_slots,
                     list_norms, allow_bits, k: int, nprobe: int,
                     metric: str, use_allow: bool):
-    """Full-rows probe: q [B,d] → centroid distances [B,nlist] (MXU
-    matmul) → top-nprobe → flattened probed positions feed the shared
-    candidate plane (ops/candidates.py), which gathers, scores, folds
-    per-query ``allow_bits`` per candidate, and exact-top-k's. Returns
-    (dists [B,k'], slots [B,k']) ascending; dead/filtered rows never
-    surface. Memory is O(B * nprobe * cap * d): callers chunk B."""
+    """Full-rows probe, ONE program a chunk: q [B,d] → centroid
+    distances [B,nlist] (MXU matmul) → top-nprobe → each probed posting
+    list read as the contiguous ``[cap, d]`` slab it is
+    (``list_vecs[probes]``: ``nprobe`` blocks a query, not ``nprobe *
+    cap`` rows: the candidate plane's per-position gather, right for
+    the scattered candidates of its other callers, costs nine tenths
+    of a probe's time here, PERF.md section 6, PRs 43 and 47).
+    The arithmetic is ``gather_rescore_topk``'s: float32 rows at
+    HIGHEST, cosine 1 - dot on unit rows, l2 by the cached norms,
+    ``allow_bits`` folded a candidate by its slot, dead, empty and
+    disallowed positions at ``MASKED_DISTANCE``, exact top-k with ties
+    to the lower position. Returns (dists [B,k'], slots [B,k'] int32)
+    ascending; dead/filtered rows never surface. Memory is
+    O(B * nprobe * cap * d): callers chunk B."""
     nlist, cap, dim = list_vecs.shape
     b = q.shape[0]
     q32 = q.astype(jnp.float32)
@@ -262,15 +266,33 @@ def _ivf_probe_topk(q, centroids, c_norms, list_vecs, list_valid, list_slots,
     cd = pairwise_distance(q32, centroids, metric="l2-squared",
                            x_sq_norms=c_norms)
     _, probes = jax.lax.top_k(-cd, nprobe)  # [B, nprobe]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (b, nprobe, cap), 2)
-    flat = (probes[:, :, None].astype(jnp.int32) * cap
-            + pos).reshape(b, nprobe * cap)
-    return gather_rescore_topk(
-        q32, flat, list_vecs.reshape(nlist * cap, dim), k, metric,
-        ids_of_row=list_slots.reshape(nlist * cap),
-        row_norms=list_norms.reshape(nlist * cap),
-        valid=list_valid.reshape(nlist * cap),
-        allow_bits=allow_bits if use_allow else None)
+    g = list_vecs[probes].astype(jnp.float32)  # [B, nprobe, cap, d]
+    # float32 rows state float32 arithmetic (ops/candidates.py: at
+    # DEFAULT the chip makes one bf16 pass over float32 operands)
+    dots = jnp.einsum(
+        "bd,bpcd->bpc", q32, g, preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if list_vecs.dtype == jnp.float32
+                   else jax.lax.Precision.DEFAULT))
+    if metric == "l2-squared":
+        q_norms = jnp.sum(q32 * q32, axis=-1)[:, None, None]
+        d = jnp.maximum(
+            q_norms - 2.0 * dots + list_norms[probes].astype(jnp.float32),
+            0.0)
+    elif metric == "dot":
+        d = -dots
+    else:  # cosine family: rows and q unit-norm -> distance 1 - cos
+        d = 1.0 - dots
+    # the valid flags folded into the slots over the whole [nlist, cap]
+    # (2 MB in the served cell) so that ONE small slab gather carries both
+    ids = jnp.where(list_valid, list_slots, -1)[probes]  # [B, nprobe, cap]
+    d = d.reshape(b, nprobe * cap)
+    ids = ids.reshape(b, nprobe * cap).astype(jnp.int32)
+    ok = ids >= 0
+    if use_allow:
+        ok = ok & allow_bits_for_ids(allow_bits, ids)
+    d = jnp.where(ok, d, MASKED_DISTANCE)
+    return masked_candidate_topk(d, ids, min(k, nprobe * cap))
 
 
 class IVFStore:
@@ -365,6 +387,10 @@ class IVFStore:
         # the same map as an array over the delta's slots (-1 = free),
         # kept with the dict so that a search builds nothing a slot
         self._delta_gmap = np.full(self.delta.capacity, -1, np.int32)
+        # the unfiltered probe's ``allow_bits`` operand (64 bytes of
+        # zeros, never read), uploaded at the first such search and kept:
+        # made a dispatch it was an eager one-op program beside the probe
+        self._no_bits = None
         # writes seen, and as of the last maintenance tick: a tick
         # folds a part-filled delta only once the writes have paused
         self._writes = 0
@@ -1020,11 +1046,12 @@ class IVFStore:
             queries = queries[None, :]
         b = len(queries)
         allow_mask = normalize_allow_mask(allow_mask, b)
-        np_probe = 0
+        np_probe, gather = 0, ""
         with tracing.span("ivf.search", queries=b, k=k,
                           filtered=allow_mask is not None) as sp, \
                 self._lock:
-            legs_d, legs_i = [], []
+            delta_leg = None          # (dists, slots) where the delta holds rows
+            outs_d, outs_i = [], []   # the probe's, a chunk of queries each
             if self.delta.live_count() > 0:
                 dd, di = self.delta.epoch_scan(
                     queries, min(k, self.delta.capacity),
@@ -1032,8 +1059,8 @@ class IVFStore:
                 gd = self._put(self._delta_gmap)
                 di = jnp.where(di >= 0,
                                gd[jnp.clip(di, 0, gd.shape[0] - 1)], -1)
-                legs_d.append(jnp.where(di >= 0, dd, MASKED_DISTANCE))
-                legs_i.append(di.astype(jnp.int32))
+                delta_leg = (jnp.where(di >= 0, dd, MASKED_DISTANCE),
+                             di.astype(jnp.int32))
             if (self.trained and self._fill is not None
                     and int(self._fill.sum()) > 0):
                 np_probe = min((nprobe or self._effective_nprobe()),
@@ -1048,9 +1075,15 @@ class IVFStore:
                     hbm_ledger.ledger.track("allow_bitmask", bits,
                                             **self._hbm_owner)
                 else:
-                    bits = _dummy_bits()
+                    if self._no_bits is None:
+                        self._no_bits = self._put(
+                            np.zeros((1, _MASK_WORDS), np.uint32))
+                    bits = self._no_bits
                 k_cand = k * self.rescore_limit if self.quantization else k
                 k_eff = min(k_cand, np_probe * self.list_cap)
+                # how the probe reads its lists: whole ``[cap, d]`` slabs
+                # of rows, or slabs of codes and then the survivors' rows
+                gather = "codes" if self.quantization else "slab"
                 # EXPLAIN: the probe plan, host ints only (no device
                 # reads — G1 stays empty); a no-op unless a sink is
                 # installed for this dispatch
@@ -1062,8 +1095,7 @@ class IVFStore:
                     rescored=(k_eff if self.quantization else 0),
                     quantized=bool(self.quantization),
                     filtered=bool(use_allow), queries=b, k=k,
-                    delta_leg=bool(legs_d))
-                outs_d, outs_i = [], []
+                    delta_leg=delta_leg is not None, gather=gather)
                 for s in range(0, b, self.query_chunk):
                     q_dev = self._put(queries[s:s + self.query_chunk])
                     bch = (bits if bits.shape[0] == 1
@@ -1088,11 +1120,6 @@ class IVFStore:
                             np_probe, self.metric, use_allow)
                     outs_d.append(qd)
                     outs_i.append(qs_)
-                legs_d.append(outs_d[0] if len(outs_d) == 1
-                              else jnp.concatenate(outs_d))
-                legs_i.append((outs_i[0] if len(outs_i) == 1
-                               else jnp.concatenate(outs_i))
-                              .astype(jnp.int32))
                 ivf_queries_total.inc(b)
                 ivf_probed_lists_total.inc(b * np_probe)
                 ivf_candidate_rows_total.inc(b * np_probe * self.list_cap)
@@ -1100,22 +1127,35 @@ class IVFStore:
             sp.set(nprobe=np_probe, nlist=self.nlist,
                    list_cap=self.list_cap,
                    delta_rows=len(self._delta_slots),
-                   candidates=np_probe * self.list_cap)
-            kernelscope.explain_note("ivf", merge_legs=len(legs_d))
-            if not legs_d:
+                   candidates=np_probe * self.list_cap, gather=gather)
+            kernelscope.explain_note(
+                "ivf", merge_legs=(delta_leg is not None) + bool(outs_d))
+            if delta_leg is None and not outs_d:
                 d_e = np.full((b, k), MASKED_DISTANCE, np.float32)
                 i_e = np.full((b, k), -1, np.int64)
                 return DeviceResultHandle.ready(
                     (d_e[0], i_e[0]) if squeeze else (d_e, i_e))
-            if len(legs_d) == 1:
-                md, mi = legs_d[0], legs_i[0]
+            if delta_leg is None:
+                # the probe alone (an empty delta): each chunk's pair
+                # crosses to the host as its program left it and the
+                # chunks are joined there: ONE Execute a chunk
+                arrays = tuple(a for pair in zip(outs_d, outs_i)
+                               for a in pair)
+            elif not outs_d:
+                arrays = delta_leg
             else:
-                cat_d = jnp.concatenate(legs_d, axis=1)
-                cat_i = jnp.concatenate(legs_i, axis=1)
-                md, mi = topk_smallest(cat_d, cat_i,
+                probe_leg = [outs[0] if len(outs) == 1
+                             else jnp.concatenate(outs)
+                             for outs in (outs_d, outs_i)]
+                cat_d, cat_i = (jnp.concatenate(pair, axis=1)
+                                for pair in zip(delta_leg, probe_leg))
+                arrays = topk_smallest(cat_d, cat_i,
                                        min(k, cat_d.shape[1]))
 
-        def _finish(d_np, i_np, _k=k, _squeeze=squeeze):
+        def _finish(*host, _k=k, _squeeze=squeeze):
+            d_np, i_np = (host if len(host) == 2 else
+                          (np.concatenate(host[0::2]),
+                           np.concatenate(host[1::2])))
             d_np = np.asarray(d_np, dtype=np.float32)
             i_np = np.asarray(i_np, dtype=np.int64)
             i_np = np.where(d_np >= MASKED_DISTANCE, -1, i_np)
@@ -1130,7 +1170,7 @@ class IVFStore:
 
         lists_frac = (np_probe / self.nlist) if self.nlist else 0.0
         return DeviceResultHandle(
-            (md, mi), finish=_finish,
+            arrays, finish=_finish,
             attrs={"queries": b, "k": k, "nprobe": np_probe,
                    "nlist": self.nlist, "lists_frac": lists_frac})
 

@@ -608,8 +608,8 @@ def sharded_ivf_pq_topk(
     """
     from weaviate_tpu.engine.ivf import _ivf_probe_topk_pq
 
-    # inline, NOT engine.ivf._dummy_bits(): this function body runs under
-    # its own jit trace, and a cached helper must never capture a tracer
+    # inline, not the store's cached operand: this function body runs
+    # under its own jit trace
     dummy_bits = jnp.zeros((1, _MASK_WORDS), dtype=jnp.uint32)
 
     def local_probe(q_, cent_, codes_, valid_, slots_, tvals_, pqc_):
