@@ -88,17 +88,14 @@ def test_interleaved_add_delete_flush_agree(rng):
 
 # -- epoch-stack parity suite -------------------------------------------------
 
-@pytest.mark.parametrize("selection", ["exact", "approx", "fused"])
 @pytest.mark.parametrize("mask_kind", [None, "shared", "per_query"])
-def test_epoch_parity_flat(rng, selection, mask_kind):
+def test_epoch_parity_flat(rng, mask_kind):
     """Search results bit-identical between a 1-buffer store and the
     same corpus split across >=3 epochs with interleaved tombstones,
-    across selections x filter forms."""
+    across filter forms."""
     dim = 16
-    es = EpochStore(dim=dim, epoch_rows=16, capacity=16, chunk_size=16,
-                    selection=selection)
-    bs = DeviceVectorStore(dim=dim, capacity=64, chunk_size=64,
-                           selection=selection)
+    es = EpochStore(dim=dim, epoch_rows=16, capacity=16, chunk_size=16)
+    bs = DeviceVectorStore(dim=dim, capacity=64, chunk_size=64)
     vecs = rng.standard_normal((50, dim)).astype(np.float32)
     # interleave adds and tombstones across epoch boundaries
     for lo in range(0, 50, 10):

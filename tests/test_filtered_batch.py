@@ -15,7 +15,6 @@ import pytest
 
 from weaviate_tpu.ops.pallas_kernels import (
     MASK_BLOCK,
-    fused_topk_scan,
     mask_pad_cols,
     pack_allow_bitmask,
     pack_allow_bitmask_jnp,
@@ -59,8 +58,12 @@ def test_pack_unpack_roundtrip(rng):
 
 
 @pytest.mark.parametrize("metric", ["l2-squared", "dot", "cosine"])
-def test_fused_scan_masked_parity(rng, metric):
+def test_served_scan_masked_parity(rng, metric):
+    """Per-query allow bitmasks through the scan every flat store serves
+    (``"approx"``, the XLA unpack and where), against numpy."""
     import jax.numpy as jnp
+
+    from weaviate_tpu.ops.topk import chunked_topk
 
     b, n, d, k = 6, 1100, 48, 9
     q = rng.standard_normal((b, d)).astype(np.float32)
@@ -75,19 +78,19 @@ def test_fused_scan_masked_parity(rng, metric):
     if metric == "cosine":
         xin = x / np.maximum(np.linalg.norm(x, axis=1, keepdims=True),
                              1e-30)
-    fd, fi = fused_topk_scan(jnp.asarray(q), jnp.asarray(xin), k=k,
-                             metric=metric, allow_bits=bits)
+    fd, fi = chunked_topk(jnp.asarray(q), jnp.asarray(xin), k=k,
+                          chunk_size=512, metric=metric,
+                          selection="approx", allow_bits=bits)
     fd, fi = np.asarray(fd), np.asarray(fi)
     for r in range(b):
         ri, rd = masked_ref(q[r], x, allow[r], k, metric)
         assert np.array_equal(fi[r, :len(ri)], ri), (r, fi[r], ri)
-        assert np.all(fi[r, len(ri):] == -1)
+        assert np.all(fd[r, len(ri):] >= DEAD)
         assert np.allclose(fd[r, :len(ri)], rd, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("selection", ["approx", "exact", "fused"])
 @pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
-def test_store_batched_mask_parity(rng, selection, dtype_name):
+def test_store_batched_mask_parity(rng, dtype_name):
     import jax.numpy as jnp
 
     from weaviate_tpu.engine.store import DeviceVectorStore
@@ -96,8 +99,7 @@ def test_store_batched_mask_parity(rng, selection, dtype_name):
     corpus = rng.standard_normal((n, d)).astype(np.float32)
     q = rng.standard_normal((b, d)).astype(np.float32)
     st = DeviceVectorStore(dim=d, capacity=1024, chunk_size=256,
-                           dtype=jnp.dtype(dtype_name),
-                           selection=selection)
+                           dtype=jnp.dtype(dtype_name))
     st.add(corpus)
     allow = rng.random((b, n)) < 0.3
     allow[1, :] = False
@@ -113,11 +115,9 @@ def test_store_batched_mask_parity(rng, selection, dtype_name):
     for r in range(b):
         ri, rd = masked_ref(q[r], stored, allow[r], k)
         live = dists[r] < DEAD
-        assert live.sum() == len(ri), (selection, r, slots[r])
-        assert np.array_equal(slots[r][live], ri), (selection, r)
+        assert live.sum() == len(ri), (r, slots[r])
+        assert np.array_equal(slots[r][live], ri), r
         assert np.allclose(dists[r][live], rd, rtol=1e-3, atol=1e-3)
-        if selection == "fused":
-            assert np.all(slots[r][~live] == -1)
 
 
 def test_store_shared_mask_broadcast(rng):
@@ -128,7 +128,7 @@ def test_store_shared_mask_broadcast(rng):
     b, n, d, k = 4, 400, 16, 6
     corpus = rng.standard_normal((n, d)).astype(np.float32)
     q = rng.standard_normal((b, d)).astype(np.float32)
-    st = DeviceVectorStore(dim=d, capacity=512, selection="fused")
+    st = DeviceVectorStore(dim=d, capacity=512)
     st.add(corpus)
     shared = np.zeros(st.capacity, dtype=bool)
     shared[:n] = rng.random(n) < 0.4
@@ -186,8 +186,7 @@ def test_sharded_store_batched_mask(rng):
     b, n, d, k = 4, 600, 16, 5
     corpus = rng.standard_normal((n, d)).astype(np.float32)
     q = rng.standard_normal((b, d)).astype(np.float32)
-    st = DeviceVectorStore(dim=d, capacity=1024, chunk_size=64, mesh=mesh,
-                           selection="fused")
+    st = DeviceVectorStore(dim=d, capacity=1024, chunk_size=64, mesh=mesh)
     st.add(corpus)
     allow = rng.random((b, n)) < 0.25
     allow[0, :] = False
@@ -226,7 +225,7 @@ def test_batcher_mixed_drain_one_dispatch(rng):
 
     n, d, k = 300, 16, 5
     corpus = rng.standard_normal((n, d)).astype(np.float32)
-    idx = FlatIndex(dim=d, capacity=512, selection="fused")
+    idx = FlatIndex(dim=d, capacity=512)
     idx.add_batch(np.arange(n), corpus)
     qb, calls = _make_batcher(idx)
     nreq = 11
@@ -297,7 +296,7 @@ def test_batcher_selective_filter_goes_solo(rng):
 
     n, d, k = 300, 16, 4
     corpus = rng.standard_normal((n, d)).astype(np.float32)
-    idx = FlatIndex(dim=d, capacity=512, selection="fused")
+    idx = FlatIndex(dim=d, capacity=512)
     idx.add_batch(np.arange(n), corpus)
     qb, calls = _make_batcher(idx)
 
@@ -351,7 +350,7 @@ def _intake_index(rng, layout, n=300, d=8):
     from weaviate_tpu.engine.flat import FlatIndex
 
     corpus = rng.standard_normal((n, d)).astype(np.float32)
-    idx = FlatIndex(dim=d, capacity=512, selection="exact")
+    idx = FlatIndex(dim=d, capacity=512)
     space = n
     if layout == "shuffled":
         # slots not in doc-id order: the import arrives out of order, a
@@ -476,7 +475,7 @@ def test_coalesced_dispatch_counts_one_translation_a_request(rng):
     from weaviate_tpu.runtime.query_batcher import _Pending
 
     n, d, k = 300, 16, 4
-    idx = FlatIndex(dim=d, capacity=512, selection="fused")
+    idx = FlatIndex(dim=d, capacity=512)
     idx.add_batch(np.arange(n),
                   rng.standard_normal((n, d)).astype(np.float32))
     qb, calls = _make_batcher(idx)
@@ -522,7 +521,7 @@ def test_coalesced_dispatch_translates_a_mask_object_once(rng, quant,
 
     n, d, k = 300, 16, 4
     corpus = rng.standard_normal((n, d)).astype(np.float32)
-    idx = FlatIndex(dim=d, capacity=512, selection="fused",
+    idx = FlatIndex(dim=d, capacity=512,
                     **({"quantization": quant, "rescore_limit": 512}
                        if quant else {}))
     idx.add_batch(np.arange(n), corpus)

@@ -74,22 +74,6 @@ def test_flat_two_level_parity(rng, filtered):
     _assert_bit_identical(outs[0], outs[1], f"flat/{filtered}")
 
 
-def test_flat_two_level_parity_fused_selection(rng):
-    flat, hier = _meshes()
-    n, d, b, k = 2048, 32, 4, 10
-    x = rng.standard_normal((n, d)).astype(np.float32)
-    q = rng.standard_normal((b, d)).astype(np.float32)
-    valid = np.ones(n, dtype=bool)
-    valid[::7] = False
-    outs = []
-    for mesh in (flat, hier):
-        p = _place(mesh, x, valid, q)
-        outs.append(sharded_topk(
-            p["q"], p["x"], p["valid"], None, k=k, chunk_size=128,
-            metric="l2-squared", mesh=mesh, selection="fused"))
-    _assert_bit_identical(outs[0], outs[1], "flat/fused")
-
-
 def test_flat_two_level_parity_k_exceeds_live(rng):
     """k wider than the live candidate pool: the inf-padded DCN slices
     must never displace a real or masked candidate."""

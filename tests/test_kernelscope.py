@@ -467,13 +467,13 @@ _FAKE_EVENTS = [
     _meta("thread_name", 1, "XLA Ops", tid=1),
     _meta("thread_name", 1, "XLA Modules", tid=2),
     _meta("process_name", 7, "/host:CPU"),
-    _x(1, 1, "jit_fused_topk_scan.3", 0.0, 1500.0),
+    _x(1, 1, "jit__bq_scan_tiled.3", 0.0, 1500.0),
     _x(1, 1, "pq4_lut_matmul", 5000.0, 800.0),
     _x(1, 1, "fusion.42_misc", 5800.0, 100.0),
     # the module line repeats the ops' time: never counted twice
     _x(1, 2, "jit_bq_topk(123)", 0.0, 1500.0),
     # host threads: a lookalike name, and the program's stage annotations
-    _x(7, 30, "jit_fused_topk_scan.3", 0.0, 6000.0),
+    _x(7, 30, "jit__bq_scan_tiled.3", 0.0, 6000.0),
     _x(7, 31, "wtpu.rescore", 1500.0, 3000.0),
     _x(7, 32, "wtpu.idle", 1400.0, 400.0),
 ]
@@ -492,11 +492,11 @@ def test_capture_ranks_kernels_and_prunes(tmp_path):
     assert calls == [7]
     assert rec["ms"] == 7 and rec["raw_events"] == _FAKE_N
     ranked = [(k["kernel"], k["device_ms"]) for k in rec["kernels"]]
-    assert ranked == [("fused_topk_scan", 1.5), ("pq4_scan_reduce", 0.8),
+    assert ranked == [("bq_scan_reduce", 1.5), ("pq4_scan_reduce", 0.8),
                       ("other", 0.1)]
     assert rec["total_device_ms"] == pytest.approx(2.4)
     assert rec["kernels"][0]["top_events"][0]["name"] == \
-        "jit_fused_topk_scan.3"
+        "jit__bq_scan_tiled.3"
 
     # persisted, listed newest-first, pruned past keep=2
     kernelscope.capture_profile(8)
@@ -505,7 +505,7 @@ def test_capture_ranks_kernels_and_prunes(tmp_path):
     assert len(caps) == 2
     assert caps[0]["id"] == rec3["id"]
     loaded = kernelscope.load_capture(rec3["id"])
-    assert loaded["kernels"][0]["kernel"] == "fused_topk_scan"
+    assert loaded["kernels"][0]["kernel"] == "bq_scan_reduce"
     # path traversal is sanitized to a basename; junk ids load nothing
     assert kernelscope.load_capture("../../etc/passwd") is None
 
@@ -527,7 +527,7 @@ def test_profile_rest_endpoint(served, tmp_path):
 
     rec = client.request("GET", "/v1/debug/profile?ms=5")
     assert calls == [5]
-    assert rec["kernels"][0]["kernel"] == "fused_topk_scan"
+    assert rec["kernels"][0]["kernel"] == "bq_scan_reduce"
     assert client.request("GET", "/v1/debug/profile")["captures"][0][
         "id"] == rec["id"]
     full = client.request("GET", f"/v1/debug/profile?id={rec['id']}")

@@ -15,6 +15,9 @@ lazy ones inside functions included, and asserts two things:
 ``DEBT_D13`` (ROADMAP D13) lists the edges that point the wrong way, a
 lower layer reaching into one above it. Each is allowed only from the
 file that has it today, so a second one fails.
+
+The last test reads parameter names off the same trees: no callable
+from the engine up takes a ``selection``.
 """
 
 from __future__ import annotations
@@ -143,3 +146,24 @@ def test_unit_imports_only_what_the_table_allows(unit):
         f"{ {t: sorted(edges[t]) for t in set(edges) - ALLOWED[unit]} }, "
         f"edges gone {sorted(ALLOWED[unit] - set(edges))}: ALLOWED is the "
         "edge set as it is")
+
+
+def test_no_store_or_index_takes_a_selector():
+    """Which per-chunk selector a scan uses is decided in ``ops/topk.py``
+    and named ONCE (``engine/store.py`` ``SCAN_SELECTION``): nothing from
+    the engine up has a parameter a caller could set it by (PR 48)."""
+    takers = []
+    for unit in ("engine", "db", "schema"):
+        for path in UNITS[unit]:
+            with open(path, encoding="utf-8") as f:
+                tree = ast.parse(f.read(), path)
+            for node in ast.walk(tree):
+                if not isinstance(node, (ast.FunctionDef,
+                                         ast.AsyncFunctionDef, ast.Lambda)):
+                    continue
+                a = node.args
+                names = [x.arg for x in a.posonlyargs + a.args + a.kwonlyargs]
+                if "selection" in names:
+                    takers.append(f"{os.path.relpath(path, REPO_ROOT)}:"
+                                  f"{node.lineno}")
+    assert takers == []

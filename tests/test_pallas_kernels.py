@@ -161,104 +161,6 @@ def test_bq_scan_reduce_strided_argmin(rng):
             assert not np.any(~valid[ids[r][live]])
 
 
-def test_fused_topk_pairs_exact(rng):
-    """The survivor-merge kernel is exact top-k over (vals, ids) pairs,
-    masked entries excluded, unfilled slots (MASKED, -1)."""
-    vals = (rng.standard_normal((4, 3000)) ** 2).astype(np.float32)
-    ids = rng.permutation(3000).astype(np.int32)[None, :].repeat(4, 0)
-    vals[1, ::2] = MASKED_DISTANCE  # half masked
-    vals[3, 5:] = MASKED_DISTANCE  # fewer than k live
-    fd, fi = pk.fused_topk_pairs(jnp.asarray(vals), jnp.asarray(ids),
-                                 k=12, interpret=True)
-    fd, fi = np.asarray(fd), np.asarray(fi)
-    for r in range(4):
-        live = vals[r] < MASKED_DISTANCE * 0.5
-        order = np.argsort(vals[r][live], kind="stable")[:12]
-        want_d = vals[r][live][order]
-        m = len(want_d)
-        np.testing.assert_allclose(fd[r][:m], want_d, rtol=1e-6)
-        np.testing.assert_array_equal(fi[r][:m], ids[r][live][order])
-        assert (fi[r][m:] == -1).all()
-        assert (fd[r][m:] >= MASKED_DISTANCE * 0.5).all()
-
-
-def test_fused_topk_pairs_oversampled_k(rng):
-    """k up to 256 (two carry lane tiles): the quantized stores pull
-    rescore_limit*k candidates (160 at k=10) through this merge."""
-    vals = (rng.standard_normal((3, 2000)) ** 2).astype(np.float32)
-    ids = np.arange(2000, dtype=np.int32)[None, :].repeat(3, 0)
-    fd, fi = pk.fused_topk_pairs(jnp.asarray(vals), jnp.asarray(ids),
-                                 k=160, interpret=True)
-    want = np.argsort(vals, axis=1, kind="stable")[:, :160]
-    np.testing.assert_array_equal(np.asarray(fi), want)
-    with pytest.raises(ValueError):
-        pk.fused_topk_pairs(jnp.asarray(vals), jnp.asarray(ids), k=300)
-
-
-def test_bq_topk_fused_selection_exact_with_reduce1(rng):
-    """selection="fused" + reduce_l=1 makes the pallas BQ path bit-exact
-    vs the XLA fallback (no approx_max_k, no block-argmin loss)."""
-    from weaviate_tpu.ops import bq as bq_ops
-
-    x = jnp.asarray(rng.standard_normal((700, 64)).astype(np.float32))
-    q = jnp.asarray(rng.standard_normal((3, 64)).astype(np.float32))
-    valid = jnp.asarray(rng.random(700) > 0.3)
-    xw, qw = bq_ops.bq_encode(x), bq_ops.bq_encode(q)
-    d0, i0 = bq_ops.bq_topk(qw, xw, k=8, chunk_size=128, valid=valid)
-    d1, i1 = bq_ops.bq_topk(qw, xw, k=8, chunk_size=128, valid=valid,
-                            use_pallas=True, reduce_l=1, selection="fused")
-    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
-    # hamming ties are broken by row id in both exact paths
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-
-
-def test_pq4_topk_fused_selection(rng):
-    """selection="fused" on the PQ4 scan keeps the scan-reduce candidate
-    semantics (block-argmin survivors) but selects them exactly."""
-    from weaviate_tpu.ops import pq as pq_ops
-
-    n, d = 2000, 32
-    v = rng.standard_normal((n, d)).astype(np.float32)
-    q = rng.standard_normal((4, d)).astype(np.float32)
-    book = pq_ops.pq_fit(v, m=d // 4, k=16, iters=4)
-    codes = jnp.asarray(pq_ops.pq_encode(book, v))
-    d_a, i_a = pq_ops.pq4_topk(jnp.asarray(q), codes, book.centroids,
-                               k=10, reduce_l=1)
-    d_f, i_f = pq_ops.pq4_topk(jnp.asarray(q), codes, book.centroids,
-                               k=10, reduce_l=1, selection="fused")
-    # reduce_l=1 -> same candidate set; on CPU approx lowers exact, so the
-    # two selections must agree
-    np.testing.assert_array_equal(np.asarray(i_a), np.asarray(i_f))
-    np.testing.assert_allclose(np.asarray(d_a), np.asarray(d_f),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_quantized_store_fused_selection(rng):
-    """QuantizedVectorStore(selection="fused") end to end: scan-reduce ->
-    fused survivor top-k -> exact rescore, and the knob survives a
-    snapshot round-trip."""
-    from weaviate_tpu.engine.quantized import QuantizedVectorStore
-
-    n, d = 4000, 64
-    centers = rng.standard_normal((100, d)).astype(np.float32)
-    v = (centers[rng.integers(0, 100, n)]
-         + 0.3 * rng.standard_normal((n, d))).astype(np.float32)
-    q = (v[rng.integers(0, n, 5)]
-         + 0.05 * rng.standard_normal((5, d))).astype(np.float32)
-    gt = np.argsort(
-        (q ** 2).sum(-1)[:, None] - 2.0 * q @ v.T + (v ** 2).sum(-1)[None],
-        axis=1)[:, :10]
-    st = QuantizedVectorStore(dim=d, quantization="bq", rescore="host",
-                              capacity=1024, selection="fused")
-    st.use_pallas = True  # interpret-mode kernels on CPU
-    st.add(v)
-    dd, ii = st.search(q, k=10)
-    rec = np.mean([len(set(ii[r]) & set(gt[r])) / 10 for r in range(5)])
-    assert rec >= 0.9, rec
-    st2 = QuantizedVectorStore.restore(st.snapshot())
-    assert st2.selection == "fused"
-
-
 def test_bq_topk_twostage_matches_full(rng):
     from weaviate_tpu.ops import bq as bq_ops
 

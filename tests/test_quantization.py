@@ -240,6 +240,37 @@ def test_quantized_snapshot_restore(rng, quantization):
     assert got[0] == 42 and d[0] < 1e-3
 
 
+@pytest.mark.parametrize("legacy", ["fused", "exact"])
+@pytest.mark.parametrize("kind", ["bq", "pq", "sq", "epochs"])
+def test_a_legacy_snapshot_with_a_selection_restores(rng, kind, legacy):
+    """Snapshots written before PR 48 carry the selector a store was
+    built with. ``restore()`` reads past the key, whatever its value: the
+    twin answers as the store that never had one, and writes none."""
+    from weaviate_tpu.engine.epochs import EpochStore
+
+    x = clustered_data(rng, n=600, dim=32)
+    if kind == "epochs":
+        cls = EpochStore
+        store = cls(dim=32, epoch_rows=256, capacity=256, chunk_size=256)
+    else:
+        cls = QuantizedVectorStore
+        store = cls(dim=32, quantization=kind, capacity=1024,
+                    chunk_size=1024, pq_segments=8, pq_centroids=16,
+                    rescore_limit=8)
+        store.train(x)
+    store.add(x)
+    store.delete([5, 6])
+    snap = store.snapshot()
+    assert "selection" not in snap
+    twin = cls.restore(dict(snap, selection=legacy))
+    assert not hasattr(twin, "selection")
+    assert "selection" not in twin.snapshot()
+    d0, i0 = store.search(x[40:44], k=5)
+    d1, i1 = twin.search(x[40:44], k=5)
+    assert np.array_equal(i0, i1) and np.array_equal(d0, d1)
+    assert list(i0[:, 0]) == [40, 41, 42, 43]
+
+
 @pytest.mark.parametrize("quantization", ["bq", "sq"])
 def test_compress_twice_raises(rng, quantization):
     x = clustered_data(rng, n=300, dim=16)

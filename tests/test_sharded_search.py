@@ -40,39 +40,6 @@ def test_sharded_topk_matches_single_device(rng):
     assert np.array_equal(np.asarray(i_sh), np.asarray(i_ref))
 
 
-def test_sharded_fused_selection_matches_exact(rng):
-    """selection="fused" inside the SPMD local scan composes with the
-    unchanged _ici_merge_topk contract: per-shard fused top-k candidates
-    all_gather over ICI and merge to the same global result as the exact
-    single-device scan."""
-    mesh = make_mesh(8)
-    n, d, b, k = 2048, 32, 4, 10
-    x = rng.standard_normal((n, d)).astype(np.float32)
-    q = rng.standard_normal((b, d)).astype(np.float32)
-    valid = np.ones(n, dtype=bool)
-    valid[::7] = False
-
-    xs = shard_array(jnp.asarray(x), mesh)
-    vs = shard_array(jnp.asarray(valid), mesh)
-    qs = replicate_array(jnp.asarray(q), mesh)
-    d_sh, i_sh = sharded_topk(qs, xs, vs, None, k=k, chunk_size=128,
-                              metric="l2-squared", mesh=mesh,
-                              selection="fused")
-    d_ref, i_ref = chunked_topk(jnp.asarray(q), jnp.asarray(x), k=k,
-                                chunk_size=128, valid=jnp.asarray(valid),
-                                selection="exact")
-    np.testing.assert_allclose(np.asarray(d_sh), np.asarray(d_ref),
-                               rtol=1e-4, atol=1e-4)
-    assert np.array_equal(np.asarray(i_sh), np.asarray(i_ref))
-    # sharded store end to end with the fused scan
-    store = DeviceVectorStore(dim=16, capacity=256, chunk_size=32,
-                              mesh=mesh, selection="fused")
-    vecs = rng.standard_normal((100, 16)).astype(np.float32)
-    store.add(vecs)
-    dd, ii = store.search(vecs[42], k=5)
-    assert ii[0] == 42 and dd[0] < 1e-3
-
-
 def test_sharded_store_end_to_end(rng):
     mesh = make_mesh(8)
     store = DeviceVectorStore(dim=16, capacity=256, chunk_size=32, mesh=mesh)

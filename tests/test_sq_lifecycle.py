@@ -206,10 +206,9 @@ def test_a_mesh_sharded_database_refuses_the_class(tmp_path):
 @pytest.mark.parametrize("kw,match", [
     (dict(mesh="mesh"), "mesh"),
     (dict(prefix_bits=128), "prefix_bits"),
-    (dict(selection="fused"), "fused"),
     (dict(metric="hamming"), "hamming"),
     (dict(dim=20_000), "int32"),
-], ids=["mesh", "prefix", "fused", "metric", "too-wide"])
+], ids=["mesh", "prefix", "metric", "too-wide"])
 def test_the_store_refuses_what_it_cannot_scan(kw, match):
     if kw.get("mesh"):
         from weaviate_tpu.parallel.mesh import make_mesh

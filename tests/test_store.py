@@ -198,28 +198,3 @@ def test_failed_flush_async_surfaced_keeps_staged_rows(rng, monkeypatch):
     d, i = store.search(vecs[4], k=1)  # retry flush + search succeeds
     assert i[0] == slots[4]
     assert calls["n"] >= 2
-
-
-def test_store_fused_selection_search(rng):
-    """DeviceVectorStore(selection="fused"): in-kernel top-k through the
-    interpret-mode Pallas path, same results as the exact store."""
-    store_f = DeviceVectorStore(dim=16, capacity=128, chunk_size=128,
-                                selection="fused")
-    store_e = DeviceVectorStore(dim=16, capacity=128, chunk_size=128,
-                                selection="exact")
-    vecs = rng.standard_normal((90, 16)).astype(np.float32)
-    store_f.add(vecs)
-    store_e.add(vecs)
-    store_f.delete([7, 8])
-    store_e.delete([7, 8])
-    q = rng.standard_normal((3, 16)).astype(np.float32)
-    d_f, i_f = store_f.search(q, k=5)
-    d_e, i_e = store_e.search(q, k=5)
-    np.testing.assert_array_equal(i_e, i_f)
-    np.testing.assert_allclose(d_e, d_f, rtol=1e-4, atol=1e-4)
-    # allow-mask (gathered low-selectivity path) composes with fused
-    mask = np.zeros(128, dtype=bool)
-    mask[[1, 4, 9]] = True
-    d, i = store_f.search(q[0], k=5, allow_mask=mask)
-    live = i[i >= 0]
-    assert set(live.tolist()).issubset({1, 4, 9})

@@ -13,7 +13,15 @@ from weaviate_tpu.ops.topk import (
 
 
 def brute_topk(q, x, k, metric="l2-squared"):
-    d = ((q[:, None, :].astype(np.float64) - x[None, :, :].astype(np.float64)) ** 2).sum(-1)
+    """numpy reference in float64: (distances, ids) of the k nearest rows.
+    Cosine rows are normalized at insert, the query inside the scan."""
+    q, x = q.astype(np.float64), x.astype(np.float64)
+    if metric == "l2-squared":
+        d = ((q[:, None, :] - x[None, :, :]) ** 2).sum(-1)
+    elif metric == "dot":
+        d = -q @ x.T
+    else:
+        d = 1.0 - (q / np.linalg.norm(q, axis=1, keepdims=True)) @ x.T
     ids = np.argsort(d, axis=1, kind="stable")[:, :k]
     return np.take_along_axis(d, ids, axis=1), ids
 
@@ -28,47 +36,78 @@ def test_topk_smallest_sorted(rng):
     np.testing.assert_allclose(td, want, rtol=1e-6)
 
 
-def test_chunked_topk_matches_bruteforce(rng):
-    q = rng.standard_normal((5, 32)).astype(np.float32)
-    x = rng.standard_normal((256, 32)).astype(np.float32)
-    d, i = chunked_topk(jnp.asarray(q), jnp.asarray(x), k=10, chunk_size=64)
-    d, i = np.asarray(d), np.asarray(i)
-    want_d, want_i = brute_topk(q, x, 10)
-    np.testing.assert_allclose(d, want_d, rtol=1e-3, atol=1e-3)
-    # ids may differ on exact ties; check distance multiset instead of ids
-    assert set(i[0]).issubset(set(range(256)))
-    np.testing.assert_allclose(np.sort(d, axis=1), np.sort(want_d, axis=1), rtol=1e-3, atol=1e-3)
+@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("metric", ["l2-squared", "dot", "cosine"])
+@pytest.mark.parametrize("k", [1, 10, 37])
+def test_chunked_topk_matches_bruteforce(rng, k, metric, use_pallas):
+    """The served selector (``"approx"``: oversampled candidates a chunk,
+    exact carry merge) against numpy, through the XLA distances and the
+    Pallas distance tile (interpret mode here), four chunks."""
+    from weaviate_tpu.ops.distances import normalize
+
+    q = rng.standard_normal((5, 48)).astype(np.float32)
+    x = rng.standard_normal((512, 48)).astype(np.float32)
+    if metric == "cosine":
+        x = np.asarray(normalize(jnp.asarray(x)))
+    d, i = chunked_topk_distances(
+        jnp.asarray(q), jnp.asarray(x), k=k, chunk_size=128, metric=metric,
+        use_pallas=use_pallas, selection="approx")
+    want_d, want_i = brute_topk(q, x, k, metric)
+    np.testing.assert_array_equal(np.asarray(i), want_i)
+    np.testing.assert_allclose(np.asarray(d), want_d, rtol=1e-3, atol=1e-3)
 
 
-def test_chunked_topk_respects_valid_mask(rng):
+def test_chunked_topk_refuses_unknown_selection(rng):
+    """The selector has two values; the in-kernel ``"fused"`` one went in
+    PR 48 and must not run as something else in silence."""
+    q = jnp.asarray(rng.standard_normal((1, 8)).astype(np.float32))
+    x = jnp.asarray(rng.standard_normal((32, 8)).astype(np.float32))
+    with pytest.raises(ValueError, match="selection"):
+        chunked_topk_distances(q, x, k=4, chunk_size=32, selection="fused")
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_chunked_topk_respects_valid_mask(rng, use_pallas):
+    q = rng.standard_normal((3, 32)).astype(np.float32)
+    x = rng.standard_normal((384, 32)).astype(np.float32)
+    valid = rng.random(384) > 0.5
+    d, i = chunked_topk_distances(
+        jnp.asarray(q), jnp.asarray(x), k=8, chunk_size=128,
+        valid=jnp.asarray(valid), use_pallas=use_pallas, selection="approx")
+    i = np.asarray(i)
+    assert valid[i].all()
+    live = np.flatnonzero(valid)
+    _, want = brute_topk(q, x[live], 8)
+    np.testing.assert_array_equal(i, live[want])
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_chunked_topk_k_exceeds_live_rows(rng, use_pallas):
+    """Slots past the live rows carry MASKED_DISTANCE: every consumer
+    cuts there."""
     q = rng.standard_normal((2, 16)).astype(np.float32)
     x = rng.standard_normal((128, 16)).astype(np.float32)
     valid = np.zeros(128, dtype=bool)
-    valid[:10] = True  # only first 10 slots live
-    d, i = chunked_topk(jnp.asarray(q), jnp.asarray(x), k=5, chunk_size=32,
-                        valid=jnp.asarray(valid))
-    assert (np.asarray(i) < 10).all()
+    valid[:5] = True
+    d, i = chunked_topk_distances(
+        jnp.asarray(q), jnp.asarray(x), k=9, chunk_size=64,
+        valid=jnp.asarray(valid), use_pallas=use_pallas, selection="approx")
+    d, i = np.asarray(d), np.asarray(i)
+    assert (i[:, :5] >= 0).all() and (i[:, :5] < 5).all()
+    assert (d[:, :5] < 1e37).all() and (d[:, 5:] > 1e37).all()
 
 
-def test_chunked_topk_k_exceeds_live_rows(rng):
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_id_offset(rng, use_pallas):
     q = rng.standard_normal((1, 8)).astype(np.float32)
-    x = rng.standard_normal((64, 8)).astype(np.float32)
-    valid = np.zeros(64, dtype=bool)
-    valid[:3] = True
-    d, i = chunked_topk(jnp.asarray(q), jnp.asarray(x), k=8, chunk_size=64,
-                        valid=jnp.asarray(valid))
-    i = np.asarray(i)
-    live = i[np.asarray(d) < 1e37]
-    assert len(live) == 3
-    assert (i[0, 3:] == -1).all() or (np.asarray(d)[0, 3:] > 1e37).all()
-
-
-def test_id_offset(rng):
-    q = rng.standard_normal((1, 8)).astype(np.float32)
-    x = rng.standard_normal((32, 8)).astype(np.float32)
-    _, i = chunked_topk(jnp.asarray(q), jnp.asarray(x), k=4, chunk_size=32,
-                        id_offset=1000)
-    assert (np.asarray(i) >= 1000).all()
+    x = rng.standard_normal((16, 8)).astype(np.float32)
+    x = np.concatenate([x, x])  # exact duplicates -> distance ties
+    _, i = chunked_topk_distances(
+        jnp.asarray(q), jnp.asarray(x), k=6, chunk_size=32, id_offset=1000,
+        use_pallas=use_pallas, selection="approx")
+    _, want = brute_topk(q, x, 6)
+    # ties break to the lower row id, as the stable reference does
+    np.testing.assert_array_equal(np.asarray(i), want + 1000)
 
 
 def test_merge_topk(rng):
@@ -81,119 +120,6 @@ def test_merge_topk(rng):
                       jnp.concatenate([jnp.asarray(i1), jnp.asarray(i2)], axis=1), 4)
     np.testing.assert_allclose(np.asarray(d)[0], [0.1, 0.2, 0.3, 0.5], rtol=1e-6)
     assert list(np.asarray(i)[0]) == [3, 100, 101, 7]
-
-
-# -- selection="fused": in-kernel top-k (interpret mode on CPU) --------------
-
-
-@pytest.mark.parametrize("metric", ["l2-squared", "dot", "cosine"])
-@pytest.mark.parametrize("k", [1, 10, 37])
-def test_fused_matches_exact_selection(rng, metric, k):
-    """CPU interpret-mode parity: selection="fused" returns the same ids
-    AND distances as selection="exact" through the same Pallas distance
-    kernel, across metrics and mixed k."""
-    from weaviate_tpu.ops.distances import normalize
-
-    q = rng.standard_normal((5, 48)).astype(np.float32)
-    x = rng.standard_normal((512, 48)).astype(np.float32)
-    if metric == "cosine":
-        x = np.asarray(normalize(jnp.asarray(x)))
-    d_e, i_e = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=k, chunk_size=128, metric=metric,
-        use_pallas=True, selection="exact")
-    d_f, i_f = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=k, chunk_size=128, metric=metric,
-        selection="fused")
-    np.testing.assert_array_equal(np.asarray(i_e), np.asarray(i_f))
-    np.testing.assert_allclose(np.asarray(d_e), np.asarray(d_f),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_fused_respects_valid_mask(rng):
-    q = rng.standard_normal((3, 32)).astype(np.float32)
-    x = rng.standard_normal((384, 32)).astype(np.float32)
-    valid = rng.random(384) > 0.5
-    d_e, i_e = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=8, chunk_size=128,
-        valid=jnp.asarray(valid), use_pallas=True, selection="exact")
-    d_f, i_f = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=8, chunk_size=128,
-        valid=jnp.asarray(valid), selection="fused")
-    np.testing.assert_array_equal(np.asarray(i_e), np.asarray(i_f))
-    np.testing.assert_allclose(np.asarray(d_e), np.asarray(d_f),
-                               rtol=1e-5, atol=1e-5)
-    assert valid[np.asarray(i_f)].all()
-
-
-def test_fused_k_exceeds_live_rows(rng):
-    """Unfilled slots surface as (MASKED, -1) — never dead-row ids."""
-    q = rng.standard_normal((2, 16)).astype(np.float32)
-    x = rng.standard_normal((128, 16)).astype(np.float32)
-    valid = np.zeros(128, dtype=bool)
-    valid[:5] = True
-    d, i = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=9, chunk_size=64,
-        valid=jnp.asarray(valid), selection="fused")
-    d, i = np.asarray(d), np.asarray(i)
-    assert (i[:, :5] >= 0).all() and (i[:, :5] < 5).all()
-    assert (i[:, 5:] == -1).all()
-    assert (d[:, 5:] > 1e37).all()
-
-
-def test_fused_id_offset_and_ties(rng):
-    q = rng.standard_normal((1, 8)).astype(np.float32)
-    x = rng.standard_normal((16, 8)).astype(np.float32)
-    x = np.concatenate([x, x])  # exact duplicates -> distance ties
-    d_f, i_f = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=6, chunk_size=32,
-        id_offset=1000, selection="fused")
-    d_e, i_e = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=6, chunk_size=32,
-        id_offset=1000, use_pallas=True, selection="exact")
-    # ties break identically (lower row id first), offset applied
-    np.testing.assert_array_equal(np.asarray(i_e), np.asarray(i_f))
-    assert (np.asarray(i_f) >= 1000).all()
-
-
-def test_fused_unsupported_metric_falls_back(rng):
-    """Non-Pallas metrics degrade to the exact XLA scan, same results."""
-    q = rng.standard_normal((2, 12)).astype(np.float32)
-    x = rng.standard_normal((64, 12)).astype(np.float32)
-    d_f, i_f = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=5, chunk_size=64,
-        metric="manhattan", selection="fused")
-    d_e, i_e = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=5, chunk_size=64,
-        metric="manhattan", selection="exact")
-    np.testing.assert_array_equal(np.asarray(i_e), np.asarray(i_f))
-
-
-def test_fused_oversized_k_falls_back(rng):
-    """k > the fused carry width (128) degrades to the approx chunk path
-    (exact on CPU) instead of failing — search_by_distance widens k."""
-    q = rng.standard_normal((1, 8)).astype(np.float32)
-    x = rng.standard_normal((512, 8)).astype(np.float32)
-    d, i = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=200, chunk_size=256,
-        selection="fused")
-    want = np.argsort(((q[:, None] - x[None]) ** 2).sum(-1), axis=1)
-    assert set(np.asarray(i)[0, :50].tolist()) == set(want[0, :50].tolist())
-
-
-def test_fused_recall_100k(rng):
-    """Acceptance: recall@10 >= 0.99 vs exact f32 on a >=100k-row corpus
-    (exact by construction — this pins it end to end, CPU interpret)."""
-    n, d, b, k = 131072, 16, 4, 10
-    x = rng.standard_normal((n, d)).astype(np.float32)
-    q = rng.standard_normal((b, d)).astype(np.float32)
-    d_f, i_f = chunked_topk_distances(
-        jnp.asarray(q), jnp.asarray(x), k=k, chunk_size=8192,
-        selection="fused")
-    dist = (q ** 2).sum(-1)[:, None] - 2.0 * q @ x.T + (x ** 2).sum(-1)[None]
-    want = np.argsort(dist, axis=1, kind="stable")[:, :k]
-    recall = np.mean([len(set(np.asarray(i_f)[r]) & set(want[r])) / k
-                      for r in range(b)])
-    assert recall >= 0.99, recall
 
 
 def test_chunked_topk_indivisible_n(rng):

@@ -86,17 +86,44 @@ def test_chunked_topk_approx_1m(one_chip, monkeypatch):
 
 @pytest.mark.parametrize("masked", [False, True], ids=["plain", "allow_bits"])
 @pytest.mark.parametrize("b,k,d", [(8, 10, 128), (64, 100, 128), (64, 10, 768)])
-def test_fused_topk_scan(one_chip, masked, b, k, d):
+def test_chunked_topk_served(one_chip, monkeypatch, masked, b, k, d):
+    """The scan every flat store serves (Pallas distance tile, ``"approx"``
+    candidates a chunk, exact carry merge), plain and under per-query
+    allow bitmasks."""
+    from weaviate_tpu.ops.topk import chunked_topk_distances
+
+    monkeypatch.setattr(pk, "recommended", lambda: True)
+
     def fn(q, x, v, n, *bits):
-        return pk.fused_topk_scan(q, x, k=k, valid=v, x_sq_norms=n,
-                                  interpret=False,
-                                  allow_bits=bits[0] if bits else None)
+        return chunked_topk_distances(
+            q, x, k=k, chunk_size=8192, metric="l2-squared", valid=v,
+            x_sq_norms=n, use_pallas=True, selection="approx",
+            allow_bits=bits[0] if bits else None)
 
     shapes = [((b, d), jnp.bfloat16), ((N, d), jnp.bfloat16),
               ((N,), jnp.bool_), ((N,), jnp.float32)]
     if masked:
         shapes.append(((b, N // 32), jnp.uint32))
     _assert_kernel(_compile(fn, one_chip, *shapes))
+
+
+def test_shared_candidates_topk_batch(one_chip, monkeypatch):
+    """The store's gathered cutover at its widest in ``sift-flat-l2``:
+    ONE filter of capacity / 8 rows shared by a padded batch of 32."""
+    from weaviate_tpu.ops.candidates import shared_candidates_topk
+
+    monkeypatch.setattr(pk, "recommended", lambda: True)
+    rows, d, bucket = 262144, 128, 32768
+    fn = functools.partial(shared_candidates_topk, k=10,
+                           metric="l2-squared", use_pallas=True,
+                           selection="approx")
+    c = _compile(lambda q, slots, x, norms, valid: fn(
+        q, slots, x, row_norms=norms, valid=valid), one_chip,
+        ((32, d), jnp.float32), ((bucket,), jnp.int32),
+        ((rows, d), jnp.float32), ((rows,), jnp.float32),
+        ((rows,), jnp.bool_))
+    _assert_kernel(c)
+    assert not re.search(rf"= f32\[{rows},{d}\][^ ]* copy\(", c.as_text())
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["plain", "allow_bits"])
@@ -375,12 +402,6 @@ def test_pq4_lut_block(one_chip):
     fn = functools.partial(pk.pq4_lut_block, interpret=False)
     _assert_kernel(_compile(fn, one_chip, ((64, 32, 16), jnp.float32),
                             ((8192, 32), jnp.uint8)))
-
-
-def test_fused_topk_pairs(one_chip):
-    fn = functools.partial(pk.fused_topk_pairs, k=100, interpret=False)
-    _assert_kernel(_compile(fn, one_chip, ((64, 16384), jnp.float32),
-                            ((64, 16384), jnp.int32)))
 
 
 def test_bm25_block(one_chip):

@@ -42,8 +42,8 @@ NON_ARRAY_ATTRS = {"dtype", "shape", "ndim", "default_backend", "devices",
                    "ShapeDtypeStruct", "CostEstimate", "Precision"}
 #: repo helpers whose return values live on device (tuned to this tree)
 DEVICE_FUNCS = {
-    "chunked_topk_distances", "sharded_topk", "fused_topk_scan",
-    "fused_topk_pairs", "distance_block", "bq_hamming_block",
+    "chunked_topk_distances", "sharded_topk", "distance_block",
+    "bq_hamming_block",
     "bq_mxu_block", "pq4_lut_block", "pq4_recon_block", "shard_array",
     "replicate_array", "tracked_shard_array", "grow_rows", "normalize",
     "pack_allow_bitmask_jnp", "unpack_allow_bitmask", "bq_pack",

@@ -9,7 +9,7 @@ not enforce for you.
    ``tile_n = MASK_BLOCK`` at runtime precisely because of this).
 2. VMEM scratch budget — ``scratch_shapes`` entries whose dims resolve
    statically (literals, or names with documented repo bounds like the
-   fused scan's ``max_b = 1024``) must fit the ~16 MB VMEM with headroom
+   scans' ``max_b = 1024``) must fit the ~16 MB VMEM with headroom
    for operand tiles; an over-budget scratch is a Mosaic compile error
    on REAL hardware only (the interpreter happily allocates anything).
 3. No Python loops over traced values inside kernel bodies — ``for i in
@@ -43,7 +43,7 @@ DTYPE_BYTES = {
     "int8": 1, "uint8": 1, "bool_": 1, "float64": 8, "int64": 8,
 }
 #: documented repo bounds for symbolic scratch dims (ops/pallas_kernels:
-#: max_b block cap, _FUSED_PAIRS_MAX_K, lane-padded k)
+#: max_b block cap, the widest carried k, lane-padded k)
 DIM_BOUNDS = {"b": 1024, "pb": 1024, "k": 256, "pk": 256, "kk": 256}
 
 

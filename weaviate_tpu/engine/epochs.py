@@ -9,14 +9,13 @@ stack of IMMUTABLE device epochs plus one small ACTIVE epoch.
   fast path; when it reaches ``epoch_rows`` it is SEALED — a frozen
   array whose vectors the serving lock never has to guard again — and a
   fresh active epoch opens.
-- Reads fuse across the stack: every epoch runs the SAME scan kernels it
-  always did (``fused_topk_scan`` / bq / pq4 scan-reduce), and the
-  per-epoch survivor sets merge ON DEVICE with ``ops.topk.
-  merge_epoch_topk`` (``fused_topk_pairs`` under ``selection="fused"``)
-  — the ICI-merge pattern from ``parallel/sharded_search.py`` turned
-  inward, so no new Pallas kernels exist and multi-epoch results are
-  bit-identical to a single-buffer scan (the merge is exact; per-epoch
-  selection error never compounds).
+- Reads fuse across the stack: every epoch runs the SAME scan programs a
+  single-buffer store does (the chunked scan / bq / pq4 scan-reduce), and
+  the per-epoch survivor sets merge ON DEVICE with ``ops.topk.
+  merge_epoch_topk`` — the ICI-merge pattern from
+  ``parallel/sharded_search.py`` turned inward, so no new Pallas kernels
+  exist and multi-epoch results are bit-identical to a single-buffer
+  scan (the merge is exact; per-epoch selection error never compounds).
 - Deletes stay tombstone masks, but now they RECLAIM HBM: a background
   policy (``maintain()``, registered with ``runtime/cyclemanager.py`` by
   the database) folds tombstone-heavy sealed epochs — gather live rows
@@ -166,7 +165,6 @@ class EpochStore:
                  epoch_rows: int = 0, capacity: int = 8192,
                  dtype=None, mesh=None, chunk_size: int = 8192,
                  normalize_on_add: bool | None = None,
-                 selection: str = "approx",
                  quantization: str | None = None,
                  quant_kwargs: dict | None = None):
         import jax.numpy as jnp
@@ -182,7 +180,6 @@ class EpochStore:
         self.dtype = dtype or jnp.float32
         self.mesh = mesh
         self.chunk_size = chunk_size
-        self.selection = selection
         self.quantization = quantization
         self._quant_kwargs = dict(quant_kwargs or {})
         self.normalize_on_add = (
@@ -223,7 +220,6 @@ class EpochStore:
                     dim=self.dim, metric=self.metric,
                     quantization=self.quantization, capacity=capacity,
                     chunk_size=self.chunk_size, mesh=self.mesh,
-                    selection=self.selection,
                     normalize_on_add=self.normalize_on_add,
                     codebook=self._codebook,
                     component_suffix=f"@e{eid}",
@@ -233,7 +229,7 @@ class EpochStore:
                 dtype=self.dtype, mesh=self.mesh,
                 chunk_size=self.chunk_size,
                 normalize_on_add=self.normalize_on_add,
-                selection=self.selection, component=f"corpus@e{eid}")
+                component=f"corpus@e{eid}")
 
     def _open_epoch_locked(self) -> _Epoch:
         """Open a fresh active epoch at the current slot high-water.
@@ -539,8 +535,7 @@ class EpochStore:
         kernelscope.explain_note(
             "epochs", epochs=len(parts), merge_fanin=len(parts),
             k_merge=k, rescore_mode="none", queries=len(queries), k=k)
-        md, mi = merge_epoch_topk(tuple(parts), tuple(maps), k=k,
-                                  selection=self.selection)
+        md, mi = merge_epoch_topk(tuple(parts), tuple(maps), k=k)
 
         def _finish(d_np, i_np, _squeeze=squeeze):
             i_np = i_np.astype(np.int64, copy=False)
@@ -594,8 +589,7 @@ class EpochStore:
             "epochs", epochs=len(parts), merge_fanin=len(parts),
             k_merge=k_merge, k_cand=k_cand, rescore_mode=mode,
             queries=len(queries), k=k)
-        md, mi = merge_epoch_topk(tuple(parts), tuple(maps), k=k_merge,
-                                  selection=self.selection)
+        md, mi = merge_epoch_topk(tuple(parts), tuple(maps), k=k_merge)
         cap_total = self.capacity
         dim = self.dim
 
@@ -872,7 +866,6 @@ class EpochStore:
                 "metric": self.metric,
                 "dtype": jnp.dtype(self.dtype).name,
                 "chunk_size": self.chunk_size,
-                "selection": self.selection,
                 "epoch_rows": self.epoch_rows,
                 "quantization": self.quantization,
             }
@@ -892,7 +885,6 @@ class EpochStore:
             epoch_rows=snap.get("epoch_rows", 0),
             dtype=jnp.dtype(snap.get("dtype", "float32")),
             mesh=mesh, chunk_size=snap.get("chunk_size", 8192),
-            selection=snap.get("selection", "approx"),
             quantization=snap.get("quantization"),
             quant_kwargs=snap.get("quant_kwargs"), **kwargs)
         if snap.get("codebook") is not None:

@@ -67,16 +67,7 @@ def _compress_stage(quantization: str, stage: str):
 
 class FlatIndex:
     """Implements the reference ``VectorIndex`` contract
-    (adapters/repos/db/vector_index.go:24-45) for brute-force search.
-
-    ``selection`` picks the scan's top-k strategy ("approx" | "exact" |
-    "fused" — ops/topk.chunked_topk_distances docstring); "fused" runs
-    selection inside the Pallas scan kernel so distances never round-trip
-    through HBM. With ``quantization`` set it passes through to the
-    quantized store's SURVIVOR selection, which supports "approx" and
-    "fused" only (the compressed scan itself is always the scan-reduce
-    kernel) and falls back to approx when rescore_limit*k exceeds the
-    256-wide fused carry."""
+    (adapters/repos/db/vector_index.go:24-45) for brute-force search."""
 
     index_type = "flat"
     # the batched entry point accepts PER-QUERY allow lists (a sequence in
@@ -104,8 +95,7 @@ class FlatIndex:
     def __init__(self, dim: int, metric: str = "l2-squared", mesh=None,
                  dtype=None, capacity: int = 8192, chunk_size: int = 8192,
                  quantization: str | None = None, store=None,
-                 selection: str = "approx", epoch_rows: int = 0,
-                 memwatch=None, **quant_kwargs):
+                 epoch_rows: int = 0, memwatch=None, **quant_kwargs):
         import jax.numpy as jnp
 
         self.dim = dim
@@ -125,8 +115,7 @@ class FlatIndex:
             self.store = EpochStore(
                 dim=dim, metric=metric, epoch_rows=epoch_rows,
                 capacity=capacity, dtype=dtype, mesh=mesh,
-                chunk_size=chunk_size, selection=selection,
-                quantization=quantization,
+                chunk_size=chunk_size, quantization=quantization,
                 quant_kwargs=quant_kwargs or None)
         elif quantization:
             from weaviate_tpu.engine.quantized import QuantizedVectorStore
@@ -134,7 +123,7 @@ class FlatIndex:
             self.store = QuantizedVectorStore(
                 dim=dim, metric=metric, quantization=quantization,
                 capacity=capacity, chunk_size=chunk_size, mesh=mesh,
-                selection=selection, memwatch=memwatch, **quant_kwargs,
+                memwatch=memwatch, **quant_kwargs,
             )
         else:
             if quant_kwargs:
@@ -148,7 +137,6 @@ class FlatIndex:
                 dtype=dtype or jnp.float32,
                 mesh=mesh,
                 chunk_size=chunk_size,
-                selection=selection,
             )
         self._lock = threading.RLock()
         self._id_to_slot: dict[int, int] = {}
@@ -847,8 +835,8 @@ class FlatIndex:
             new = EpochStore(
                 dim=self.dim, metric=self.metric,
                 epoch_rows=old.epoch_rows, chunk_size=old.chunk_size,
-                mesh=old.mesh, selection=old.selection,
-                quantization=quantization, quant_kwargs=quant_kwargs)
+                mesh=old.mesh, quantization=quantization,
+                quant_kwargs=quant_kwargs)
         live = np.nonzero(snap["valid"])[0]
         live_vecs = snap["vectors"][live]
         if quantization == "pq":

@@ -1,4 +1,4 @@
-"""TPU bulk construction for HNSW (VERDICT r2 item 4a).
+"""TPU bulk construction for HNSW.
 
 The reference builds its graph by incremental insert (hnsw/insert.go:226):
 each vector runs an ef-search against the partial graph — inherently
@@ -7,9 +7,8 @@ is hours even in Go; in Python it is days. The TPU-first redesign turns
 construction into the workload the MXU is best at:
 
 1. **kNN graph on device**: every node's ``knn_k`` nearest neighbors come
-   from the batched exact chunked scan (ops/topk.py — 1Mx128 in ~2.5 ms per
-   1024-query batch on a v5e), not from graph walks. One pass per layer
-   over that layer's members.
+   from the batched chunked scan (ops/topk.py), not from graph walks. One
+   pass per layer over that layer's members.
 2. **Vectorized diversity heuristic**: the reference's
    selectNeighborsHeuristic (heuristic.go) runs per node over its
    candidates; here it runs BATCHED over thousands of nodes at once with a
@@ -413,19 +412,12 @@ def _device_knn(sub: np.ndarray, k_eff: int, metric: str,
     import jax
     import jax.numpy as jnp
 
+    from weaviate_tpu.engine.store import SCAN_SELECTION
+    from weaviate_tpu.ops.pallas_kernels import recommended
     from weaviate_tpu.ops.topk import chunked_topk_distances
 
     n = len(sub)
-
-    from weaviate_tpu.ops.pallas_kernels import recommended
-
     use_pallas = recommended()
-    # TPU: fold selection INTO the scan kernel (selection="fused" — the
-    # per-chunk approx_max_k pass plus its [qb, chunk] HBM round-trip was
-    # the dominant cost of the 1M bulk-build knn stage, VERDICT r5);
-    # chunked_topk_distances degrades it to "approx" if k_eff > the fused
-    # carry width. CPU backend: "approx" lowers to the exact XLA top_k.
-    selection = "fused" if use_pallas else "approx"
     if not use_pallas:
         # the XLA CPU scan materializes [qb, chunk] distance transients in
         # RAM — bound them (~64 MB) for the large-layer CPU fallback path
@@ -439,9 +431,9 @@ def _device_knn(sub: np.ndarray, k_eff: int, metric: str,
     # 1M queries reproducibly crashes the TPU worker, and per-slice fetches
     # stay small. Queries are dynamic-sliced FROM the device-resident
     # corpus (they ARE corpus rows) — zero query uploads. On the pallas
-    # path the fused kernel's [qb, chunk] distance tile must fit scoped
-    # VMEM, so blocks are capped at 1024 queries (the serving scan's
-    # shape), keeping the slice size by raising the block count.
+    # path the distance kernel's [qb, chunk] tile must fit scoped VMEM
+    # (8192 x 65536 does not, on the v5e), so blocks are capped at 1024
+    # queries, keeping the slice size by raising the block count.
     blocks_per_slice = 8
     if use_pallas and query_block > 1024:
         if query_block % 1024 == 0:
@@ -466,18 +458,16 @@ def _device_knn(sub: np.ndarray, k_eff: int, metric: str,
             _d, i = chunked_topk_distances(
                 qblk, xscan, k=k, chunk_size=cs,
                 metric=metric, valid=vd, x_sq_norms=norms,
-                selection=selection, use_pallas=use_pallas)
+                selection=SCAN_SELECTION, use_pallas=use_pallas)
             return i
         return jax.lax.map(one, qb).reshape(slice_rows, k)
 
     xd = jnp.asarray(x)
     vd = jnp.asarray(valid)
-    # the scan runs bf16 on the fused MXU kernel — the same storage/
-    # precision choice as the flat serving scan (recall envelope in
-    # BASELINE); candidate ids then feed the select stages, which also
-    # run at scan precision (bf16 inputs, f32 accumulation — recall
-    # parity pinned by the bench ef sweep). The f32 knn scan was 47.8 s
-    # of the 121 s 300k build (BASELINE r5).
+    # the scan runs bf16 on the Pallas distance kernel; candidate ids
+    # then feed the select stages, which also run at scan precision
+    # (bf16 inputs, f32 accumulation): float32 rows would take the
+    # kernel's multi-pass HIGHEST matmul.
     xscan = xd.astype(jnp.bfloat16) if use_pallas else xd
     # build-time scratch is the dominant transient HBM consumer at 1M
     # rows — ledger-tracked for exactly as long as the array lives, so

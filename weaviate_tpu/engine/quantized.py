@@ -200,10 +200,6 @@ class QuantizedVectorStore:
         # (ops/bq.py bq_topk_twostage). Single-device stores only — the
         # mesh path scans full codes per shard.
         prefix_bits: int | None = None,
-        # survivor selector for the fused scan-reduce kernels: "approx"
-        # (approx_max_k, default) or "fused" (exact in-kernel running-
-        # carry top-k — pallas_kernels.fused_topk_pairs)
-        selection: str = "approx",
         # HBM-ledger component suffix ("@e3" for epoch stores): codes/
         # prefix/rescore_rows register as "codes@e3" etc. so per-epoch
         # device bytes are individually visible and individually released
@@ -222,10 +218,6 @@ class QuantizedVectorStore:
                     "quantization='sq' has no mesh-sharded scan yet")
             if prefix_bits:
                 raise ValueError("prefix_bits requires quantization pq or bq")
-            if selection == "fused":
-                raise ValueError(
-                    "selection='fused' needs the pq4 scan-reduce kernel "
-                    "(pq_centroids <= 16) or quantization='bq'")
             if metric not in sq_ops.SQ_METRICS:
                 raise ValueError(
                     f"no scalar-quantized scan for metric {metric!r}")
@@ -237,19 +229,6 @@ class QuantizedVectorStore:
             rescore = "device" if mesh is None else "host"
         if rescore not in ("host", "device", "none"):
             raise ValueError(f"unknown rescore mode {rescore!r}")
-        if selection not in ("approx", "fused"):
-            # no "exact" here: the compressed scans go through the
-            # scan-reduce kernels whose survivor pass is approx or fused —
-            # reject rather than silently serving the approx path
-            raise ValueError(
-                f"quantized stores support selection 'approx' or 'fused', "
-                f"got {selection!r}")
-        if selection == "fused" and quantization == "pq" and pq_centroids > 16:
-            # the 8-bit reconstruct scan (pq_topk) has no fused survivor
-            # pass — reject rather than silently serving approx
-            raise ValueError(
-                "selection='fused' needs the pq4 scan-reduce kernel "
-                "(pq_centroids <= 16) or quantization='bq'")
         self.dim = dim
         self.metric = metric
         self.quantization = quantization
@@ -266,7 +245,6 @@ class QuantizedVectorStore:
         self.rescore = rescore
         self._memwatch = memwatch or MemoryMonitor()
         self.fetch_fn = fetch_fn
-        self.selection = selection
         if pq_segments:
             self.pq_segments = pq_segments
         else:
@@ -787,8 +765,7 @@ class QuantizedVectorStore:
                 queries_dev, qw, self.codes, valid, self.rescore_rows, cent,
                 k=per_dev_k, k_out=k_out, chunk_size=cs,
                 quantization=quant_key, metric=metric, mesh=self.mesh,
-                use_pallas=self.use_pallas, selection=self.selection,
-                allow_rows=allow_rows,
+                use_pallas=self.use_pallas, allow_rows=allow_rows,
             )
         if quant_key in ("pq4", "pq"):
             if self.prefix_t is not None:
@@ -798,14 +775,12 @@ class QuantizedVectorStore:
                     queries_dev, qp, self.codes, cent, self.prefix_t,
                     k=k_cand, refine=max(2, self.rescore_limit // 2),
                     metric=metric, valid=valid, m=self.pq_segments,
-                    use_pallas=self.use_pallas, selection=self.selection,
-                    allow_bits=allow_bits, **tail,
+                    use_pallas=self.use_pallas, allow_bits=allow_bits, **tail,
                 )
             if quant_key == "pq4":
                 return pq_ops.pq4_topk(
                     queries_dev, self.codes, cent, k=k_cand, chunk_size=cs,
-                    metric=metric, valid=valid, selection=self.selection,
-                    allow_bits=allow_bits, **tail,
+                    metric=metric, valid=valid, allow_bits=allow_bits, **tail,
                 )
             return pq_ops.pq_topk(
                 queries_dev, self.codes, cent, k=k_cand, chunk_size=cs,
@@ -817,13 +792,11 @@ class QuantizedVectorStore:
             return bq_ops.bq_topk_twostage(
                 qw, self.codes, self.prefix_t, k=k_cand,
                 refine=max(2, self.rescore_limit // 2), valid=valid,
-                use_pallas=self.use_pallas, selection=self.selection,
-                allow_bits=allow_bits, **tail,
+                use_pallas=self.use_pallas, allow_bits=allow_bits, **tail,
             )
         return bq_ops.bq_topk(
             qw, self.codes, k=k_cand, chunk_size=cs, valid=valid,
-            use_pallas=self.use_pallas, selection=self.selection,
-            allow_bits=allow_bits, **tail,
+            use_pallas=self.use_pallas, allow_bits=allow_bits, **tail,
         )
 
     def rescore_mode(self) -> str:
@@ -1084,7 +1057,7 @@ class QuantizedVectorStore:
         if self.mesh is not None:
             return None
         return (self.quantization, self.capacity, self.dim, self.metric,
-                self.selection, self.chunk_size, self.rescore_limit,
+                self.chunk_size, self.rescore_limit,
                 self.pq_segments, self.pq_centroids, self.prefix_words,
                 self.rescore_mode(), self.use_pallas)
 
@@ -1100,7 +1073,6 @@ class QuantizedVectorStore:
                 "pq_centroids": self.pq_centroids,
                 "rescore_limit": self.rescore_limit,
                 "rescore": self.rescore,
-                "selection": self.selection,
                 "prefix_bits": self.prefix_words * 32,
                 "chunk_size": self.chunk_size,
                 "codebook": (
@@ -1127,7 +1099,6 @@ class QuantizedVectorStore:
     @classmethod
     def restore(cls, snap: dict, mesh=None, **kwargs) -> "QuantizedVectorStore":
         kwargs.setdefault("rescore", snap.get("rescore", "host"))
-        kwargs.setdefault("selection", snap.get("selection", "approx"))
         if snap.get("prefix_bits"):
             kwargs.setdefault("prefix_bits", snap["prefix_bits"])
         store = cls(

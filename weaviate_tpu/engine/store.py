@@ -48,6 +48,11 @@ from weaviate_tpu.parallel.sharded_search import (
 
 _DEFAULT_CHUNK = 8192
 
+# The per-chunk selector of every store's scan (ops/topk.py
+# ``chunked_topk_distances``): approx_max_k candidates, 4x oversampled,
+# with exact carry merges. Non-TPU backends lower it to the exact top_k.
+SCAN_SELECTION = "approx"
+
 
 def _next_pow2(n: int) -> int:
     p = 1
@@ -204,7 +209,6 @@ class DeviceVectorStore:
         mesh=None,
         chunk_size: int = _DEFAULT_CHUNK,
         normalize_on_add: bool | None = None,
-        selection: str = "approx",
         component: str = "corpus",
     ):
         self.dim = dim
@@ -217,15 +221,6 @@ class DeviceVectorStore:
         self.dtype = dtype
         self.mesh = mesh
         self.chunk_size = chunk_size
-        # "approx" = per-chunk approx_max_k candidates (4x oversampled) with
-        # exact carry merges (≥0.999 recall@10, ~10x less selection time at
-        # 1M rows). "exact" opts into bit-exact lax.top_k per chunk (and is
-        # what non-TPU backends lower to anyway). "fused" folds EXACT
-        # selection into the Pallas scan kernel itself (ops/topk.py
-        # docstring) — [B, N] distances never round-trip through HBM; on
-        # non-TPU backends it runs through the Pallas interpreter, so keep
-        # it for tests/TPU serving, not CPU serving.
-        self.selection = selection
         self.n_shards = n_row_shards(mesh)
         # cosine provider normalizes at insert (reference stores normalized
         # vectors and uses the dot kernel: cosine_dist.go "cosine-dot")
@@ -662,7 +657,7 @@ class DeviceVectorStore:
                             self._operand(queries), vectors, k=k_eff,
                             chunk_size=cs, metric=metric, valid=valid,
                             x_sq_norms=norms, use_pallas=self.use_pallas,
-                            selection=self.selection,
+                            selection=SCAN_SELECTION,
                             allow_bits=allow_bits,
                         )
                     else:
@@ -670,7 +665,7 @@ class DeviceVectorStore:
                             self._operand(queries), vectors, valid, norms,
                             k=k_eff, chunk_size=cs, metric=metric,
                             mesh=self.mesh, use_pallas=self.use_pallas,
-                            selection=self.selection,
+                            selection=SCAN_SELECTION,
                             allow_rows=allow_rows_dev,
                         )
         # materialization (and its device-time attribution) lives in the
@@ -729,12 +724,12 @@ class DeviceVectorStore:
                 return chunked_topk_distances(
                     self._operand(queries), vectors, k=k_eff, chunk_size=cs,
                     metric=metric, valid=valid, x_sq_norms=norms,
-                    use_pallas=self.use_pallas, selection=self.selection,
+                    use_pallas=self.use_pallas, selection=SCAN_SELECTION,
                     allow_bits=allow_bits)
             return sharded_topk(
                 self._operand(queries), vectors, valid, norms, k=k_eff,
                 chunk_size=cs, metric=metric, mesh=self.mesh,
-                use_pallas=self.use_pallas, selection=self.selection,
+                use_pallas=self.use_pallas, selection=SCAN_SELECTION,
                 allow_rows=allow_rows_dev)
 
     def gathered_slots(self, slot_mask: np.ndarray) -> AllowSlots:
@@ -775,7 +770,7 @@ class DeviceVectorStore:
             self._operand(queries), slots, self.vectors,
             min(k, slots.shape[0]), metric, row_norms=self.sq_norms,
             valid=self.valid, use_pallas=self.use_pallas,
-            selection=self.selection,
+            selection=SCAN_SELECTION,
         )
 
     @staticmethod
@@ -860,7 +855,7 @@ class DeviceVectorStore:
         if self.mesh is not None:
             return None
         return ("flat", self.capacity, self.dim,
-                jnp.dtype(self.dtype).name, self.metric, self.selection,
+                jnp.dtype(self.dtype).name, self.metric,
                 self.chunk_size, self.use_pallas)
 
     @classmethod

@@ -52,8 +52,8 @@ def _auto_reduce_l(n: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk_size", "use_pallas",
-                                             "reduce_l", "selection",
-                                             "rescore_k", "rescore_metric"))
+                                             "reduce_l", "rescore_k",
+                                             "rescore_metric"))
 def bq_topk(
     q_words: jnp.ndarray,
     x_words: jnp.ndarray,
@@ -63,7 +63,6 @@ def bq_topk(
     id_offset: jnp.ndarray | int = 0,
     use_pallas: bool = False,
     reduce_l: int | None = None,
-    selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
     rescore_q: jnp.ndarray | None = None,
     rescore_rows: jnp.ndarray | None = None,
@@ -85,14 +84,9 @@ def bq_topk(
     per ``reduce_l`` rows (a true top-k member is dropped whenever two
     winners share a block; birthday-bound loss ~k^2/(2*N/reduce_l)) and
     the survivor selection uses ``approx_max_k`` (recall~0.95 per spec).
-    ``reduce_l=1`` removes only the block-argmin loss — with the default
-    ``selection="approx"`` the survivor selection still runs approx_max_k,
-    so the pallas path never matches the fallback bit-for-bit.
-    ``selection="fused"`` replaces that survivor pass with the exact
-    in-kernel running-carry fold (pallas_kernels.fused_topk_pairs), so the
-    only remaining loss is the block-argmin (and ``reduce_l=1`` + fused is
-    bit-exact); k above the 256-wide fused carry falls back to the approx
-    pass. Production callers oversample + rescore as
+    ``reduce_l=1`` removes only the block-argmin loss: the survivor
+    selection still runs approx_max_k, so the pallas path never matches
+    the fallback bit-for-bit. Production callers oversample + rescore as
     QuantizedVectorStore does, which absorbs the loss (measured recall
     deltas in PARITY.md).
 
@@ -126,7 +120,7 @@ def bq_topk(
         rl = reduce_l if reduce_l is not None else _auto_reduce_l(n)
         vals, ids = bq_scan_reduce(q_words, x_words, valid=valid,
                                    reduce_l=rl, allow_bits=allow_bits)
-        return tail(*select_survivors(vals, ids, k, selection, id_offset))
+        return tail(*select_survivors(vals, ids, k, id_offset))
 
     allow_rows = None
     if allow_bits is not None:
@@ -198,8 +192,7 @@ def bq_topk(
 
 
 @functools.partial(jax.jit, static_argnames=("k", "refine", "use_pallas",
-                                             "selection", "rescore_k",
-                                             "rescore_metric"))
+                                             "rescore_k", "rescore_metric"))
 def bq_topk_twostage(
     q_words: jnp.ndarray,
     x_words: jnp.ndarray,
@@ -209,7 +202,6 @@ def bq_topk_twostage(
     valid: jnp.ndarray | None = None,
     id_offset: jnp.ndarray | int = 0,
     use_pallas: bool = True,
-    selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
     rescore_q: jnp.ndarray | None = None,
     rescore_rows: jnp.ndarray | None = None,
@@ -226,9 +218,7 @@ def bq_topk_twostage(
     the row-major ``x_words`` [N, W] (contiguous row gathers) and scores
     exact hamming with one XOR+popcount over [B, R, W]. Exact top-k of
     stage 2 follows; the only approximation is stage-1 candidate recall
-    (tunable via ``refine`` and the prefix width). ``selection="fused"``
-    makes the stage-1 refine exact too (fused_topk_pairs instead of
-    approx_max_k, refine*k <= its 256-wide carry). ``rescore_*``: the
+    (tunable via ``refine`` and the prefix width). ``rescore_*``: the
     exact rescore as the program's last step, as in ``bq_topk``.
     """
     from weaviate_tpu.ops.candidates import rescore_tail
@@ -249,15 +239,9 @@ def bq_topk_twostage(
             reduce_l=_auto_reduce_l(n), transposed=True,
             allow_bits=allow_bits)
         r = min(refine * k, vals1.shape[1])
-        if selection == "fused" and r <= 256:
-            from weaviate_tpu.ops.pallas_kernels import fused_topk_pairs
-
-            cand_d1, cand = fused_topk_pairs(vals1, ids1, k=r)
-            cand = jnp.where(cand < 0, 0, cand)  # unfilled: masked below
-        else:
-            negd, pos = jax.lax.approx_max_k(-vals1, r, recall_target=0.95)
-            cand_d1 = -negd
-            cand = jnp.take_along_axis(ids1, pos, axis=1)  # [B, R] rows
+        negd, pos = jax.lax.approx_max_k(-vals1, r, recall_target=0.95)
+        cand_d1 = -negd
+        cand = jnp.take_along_axis(ids1, pos, axis=1)  # [B, R] rows
     else:
         # fallback top-k already returns the pruned candidate set, sorted
         cand_d1, ids1 = bq_topk(q_words[:, :wp], x_prefix_t.T,

@@ -147,7 +147,7 @@ def shared_candidates_topk(q, cand_slots, rows, k: int, metric: str, *,
 
     ``cand_slots`` [C] int32 global slots (-1 padding, C a power of
     two); the bucket is gathered ONCE to ``[C, d]`` and scanned with the
-    standard chunked kernel (fused Pallas top-k when eligible), then
+    standard chunked kernel, then
     bucket-local winner positions remap to global slots on device via
     ``row_ids`` — callers get global ids straight off the handle. This
     is the low-selectivity gathered path: total work is O(B·C), not

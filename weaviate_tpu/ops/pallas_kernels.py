@@ -780,355 +780,6 @@ def pq8_lookup_block(
     return out[:d, :n].T
 
 
-# -- fused distance + top-k scan ---------------------------------------------
-#
-# Round-6 tentpole: selection folded INTO the scan. The chunked serving scan
-# used to materialize every [B, chunk] distance tile to HBM and pay a
-# lax.top_k / approx_max_k per tile — measured at ~100x the raw matmul FLOP
-# time (VERDICT r2/r5: 118 s of the 199 s 1M-row bulk build, ~95% of device
-# time at 1M rows). Here each grid step computes its distance tile in VMEM
-# and folds it into a per-query running top-k carry held in VMEM scratch
-# across grid steps, so the [B, N] distances never leave the chip and the
-# per-chunk wide selection pass disappears entirely.
-#
-# The fold is EXACT top-k (ties break like lax.top_k: earlier row wins) via
-# threshold-bounded iterated extraction:
-#
-# 1. tau = current k-th best per query (carry is kept sorted ascending).
-# 2. count survivors d < tau per query; the max count over the batch bounds
-#    a dynamic-trip-count fori_loop — after the first few tiles tau is tight
-#    and almost every tile folds in O(1) extractions instead of k.
-# 3. each extraction takes the tile argmin (first occurrence), masks it, and
-#    does a sorted insert into the carry (roll-shift + two selects). Inserts
-#    of elements >= the k-th best are no-ops, so a stale tau only costs
-#    wasted passes, never correctness.
-#
-# k <= 128 (one lane tile of carry per query) for the distance scan;
-# the survivor-merge variant allows k <= 256 (two lane tiles) because the
-# quantized stores oversample to rescore_limit*k candidates before their
-# exact rescore. Dead/padded rows are excluded before the fold, so unfilled
-# carry slots surface as (MASKED_DISTANCE, -1).
-
-_FUSED_TOPK_MAX_K = 128
-_FUSED_PAIRS_MAX_K = 256
-
-
-def _fold_tile_topk(d, tile_ids, cd, ci, k, interpret):
-    """Fold one [B, T] distance tile (with explicit [B, T] int32 ids) into a
-    sorted-ascending top-k carry (cd [B, k] f32, ci [B, k] i32). Exact."""
-    b, t = d.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (b, t), 1)
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
-    far = jnp.int32(2 ** 30)
-    if interpret:
-        def roll1(a):
-            return jnp.roll(a, 1, axis=1)
-    else:
-        def roll1(a):
-            return pltpu.roll(a, 1, axis=1)
-    tau = cd[:, k - 1:k]
-    n_it = jnp.minimum(
-        jnp.max(jnp.sum((d < tau).astype(jnp.int32), axis=1)), k)
-
-    def body(_j, st):
-        work, cd_, ci_ = st
-        m = jnp.min(work, axis=1, keepdims=True)
-        pos = jnp.min(jnp.where(work == m, col, far), axis=1, keepdims=True)
-        hit = col == pos
-        e_id = jnp.min(jnp.where(hit, tile_ids, far), axis=1, keepdims=True)
-        work = jnp.where(hit, jnp.float32(MASKED_DISTANCE), work)
-        # sorted insert at #(cd <= m): after equals (stable — the earlier
-        # row keeps its spot, matching lax.top_k's lower-index-first ties);
-        # ins == k means m lost to every carried element -> no-op
-        ins = jnp.sum((cd_ <= m).astype(jnp.int32), axis=1, keepdims=True)
-        cd_ = jnp.where(kcol < ins, cd_, jnp.where(kcol == ins, m, roll1(cd_)))
-        ci_ = jnp.where(kcol < ins, ci_,
-                        jnp.where(kcol == ins, e_id, roll1(ci_)))
-        return work, cd_, ci_
-
-    _, cd, ci = jax.lax.fori_loop(0, n_it, body, (d, cd, ci))
-    return cd, ci
-
-
-def _fused_topk_kernel(metric: str, k: int, interpret: bool,
-                       masked: bool = False):
-    """Distance tile + in-VMEM top-k fold. refs: q [B,d], x [TILE,d],
-    valid [1,TILE] f32, xn [1,TILE] f32, (masked: am [TILE/512,B,16] i32
-    block-major packed per-query allow words), outs [B,k] f32 / [B,k]
-    i32, scratch carries cd [B,k] f32 / ci [B,k] i32 (persist across the
-    grid)."""
-
-    def kernel(q_ref, x_ref, valid_ref, xn_ref, *refs):
-        if masked:
-            am_ref, outd_ref, outi_ref, cd_ref, ci_ref = refs
-        else:
-            outd_ref, outi_ref, cd_ref, ci_ref = refs
-        step = pl.program_id(0)
-
-        @pl.when(step == 0)
-        def _():
-            cd_ref[:] = jnp.full(cd_ref.shape, MASKED_DISTANCE, jnp.float32)
-            ci_ref[:] = jnp.full(ci_ref.shape, -1, jnp.int32)
-
-        q = q_ref[:]
-        x = x_ref[:]
-        f32_exact = q.dtype == jnp.float32 and x.dtype == jnp.float32
-        dots = jax.lax.dot_general(
-            q, x,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=(jax.lax.Precision.HIGHEST if f32_exact
-                       else jax.lax.Precision.DEFAULT),
-        )
-        if metric == "l2-squared":
-            qf = q.astype(jnp.float32)
-            qn = jnp.sum(qf * qf, axis=1, keepdims=True)
-            d = jnp.maximum(qn - 2.0 * dots + xn_ref[:], 0.0)
-        elif metric == "dot":
-            d = -dots
-        else:  # cosine / cosine-dot: operands pre-normalized by the wrapper
-            d = 1.0 - dots
-        # exclude dead/padded rows entirely (they can never enter the carry,
-        # so k > live surfaces as (MASKED_DISTANCE, -1) — strictly cleaner
-        # than the unfused path's arbitrary dead-row ids)
-        b, t = d.shape
-        ok = valid_ref[:] > 0.5
-        if masked:
-            # per-query allow bitmask, unpacked tile-locally in VMEM and
-            # folded into the same validity epilogue — disallowed rows can
-            # never enter the carry, exactly like dead rows
-            bits = _mask_unpack_blocks(am_ref[:], interpret)
-            ok = jnp.logical_and(ok, bits > 0)
-        d = jnp.where(ok, d, jnp.float32(MASKED_DISTANCE))
-        base = step * t
-        tile_ids = base + jax.lax.broadcasted_iota(jnp.int32, (b, t), 1)
-        cd, ci = _fold_tile_topk(d, tile_ids, cd_ref[:], ci_ref[:], k,
-                                 interpret)
-        cd_ref[:] = cd
-        ci_ref[:] = ci
-        outd_ref[:] = cd
-        outi_ref[:] = ci
-
-    return kernel
-
-
-@functools.partial(
-    jax.jit, static_argnames=("metric", "k", "tile_n", "masked", "interpret"))
-def _fused_topk_tiled(q, x, valid_f, xn, am, metric, k, tile_n, masked,
-                      interpret):
-    b, d = q.shape
-    n = x.shape[0]
-    in_specs = [
-        pl.BlockSpec((b, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((tile_n, d), lambda i: (i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, tile_n), lambda i: (0, i),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, tile_n), lambda i: (0, i),
-                     memory_space=pltpu.VMEM),
-    ]
-    operands = (q, x, valid_f, xn)
-    if masked:
-        in_specs.append(_mask_block_spec(tile_n // MASK_BLOCK, b))
-        operands = operands + (am,)
-    return pl.pallas_call(
-        _fused_topk_kernel(metric, k, interpret, masked),
-        grid=(n // tile_n,),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((b, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((b, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((b, k), jnp.float32),
-            pltpu.VMEM((b, k), jnp.int32),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=2 * b * n * d,
-            bytes_accessed=q.size * q.dtype.itemsize
-            + x.size * x.dtype.itemsize + 2 * b * k * 4
-            + (b * n // 8 if masked else 0),
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(*operands)
-
-
-def fused_topk_scan(
-    q: jnp.ndarray,
-    x: jnp.ndarray,
-    k: int,
-    metric: str = "l2-squared",
-    valid: jnp.ndarray | None = None,
-    x_sq_norms: jnp.ndarray | None = None,
-    tile_n: int = 512,
-    interpret: bool | None = None,
-    allow_bits: jnp.ndarray | None = None,
-    allow_rows: jnp.ndarray | None = None,
-):
-    """Fused masked distance scan + EXACT top-k: q [B,d] vs x [N,d] ->
-    (dists [B,k] f32 ascending, row ids [B,k] i32, -1 where fewer than k
-    live rows). The [B, N] distance matrix never exists outside VMEM.
-
-    Same padding rules as ``distance_block``; k <= 128 (the carry is one
-    lane tile per query). Dead rows never surface — not even to fill out a
-    short result. Query batches above ``max_b`` are processed in
-    independent blocks so the resident q + [blk, tile_n] distance tile +
-    fold working set stay inside the ~16 MB VMEM budget at any serving
-    batch (the same cap hnsw_build applies to its query blocks).
-
-    ``allow_bits`` [B, >=ceil(N_512/32)] uint32 adds a PER-QUERY allow
-    bitmask (``pack_allow_bitmask`` layout) unpacked tile-locally in VMEM
-    and folded into the validity epilogue; ``allow_rows`` [B, N] bool is
-    the unpacked convenience form (packed on device — the sharded path
-    uses it after slicing its local columns). Masked scans force
-    tile_n = MASK_BLOCK so tiles cover whole packed blocks."""
-    if metric not in PALLAS_METRICS:
-        raise ValueError(f"no fused top-k kernel for metric {metric!r}")
-    if not 1 <= k <= _FUSED_TOPK_MAX_K:
-        raise ValueError(f"fused top-k requires 1 <= k <= 128, got {k}")
-    if interpret is None:
-        interpret = not recommended()
-    if allow_bits is None and allow_rows is not None:
-        allow_bits = pack_allow_bitmask_jnp(allow_rows)
-
-    max_b = 1024
-    if q.shape[0] > max_b:
-        parts = [
-            fused_topk_scan(q[s:s + max_b], x, k, metric=metric,
-                            valid=valid, x_sq_norms=x_sq_norms,
-                            tile_n=tile_n, interpret=interpret,
-                            allow_bits=(None if allow_bits is None
-                                        else allow_bits[s:s + max_b]))
-            for s in range(0, q.shape[0], max_b)
-        ]
-        return (jnp.concatenate([p[0] for p in parts]),
-                jnp.concatenate([p[1] for p in parts]))
-
-    b, d = q.shape
-    n = x.shape[0]
-    q = q.astype(jnp.float32) if q.dtype not in (jnp.float32, jnp.bfloat16) \
-        else q
-    if metric in ("cosine", "cosine-dot"):
-        from weaviate_tpu.ops.distances import normalize
-
-        q = normalize(q.astype(jnp.float32))
-
-    pb = _pad_to(max(b, 1), _SUBLANE)
-    pd = _pad_to(max(d, 1), _LANE)
-    if allow_bits is not None:
-        tile_n = MASK_BLOCK  # tiles must cover whole packed mask blocks
-    else:
-        tile_n = min(tile_n, _pad_to(max(n, 1), _LANE))
-    pn = _pad_to(max(n, 1), tile_n)
-
-    if (pb, pd) != (b, d):
-        q = jnp.pad(q, ((0, pb - b), (0, pd - d)))
-    if (pn, pd) != (n, d):
-        x = jnp.pad(x, ((0, pn - n), (0, pd - d)))
-
-    if valid is None:
-        valid_f = (jnp.arange(pn) < n).astype(jnp.float32)
-    else:
-        valid_f = jnp.pad(valid.astype(jnp.float32), (0, pn - n))
-    if x_sq_norms is None:
-        x32 = x.astype(jnp.float32)
-        xn = jnp.sum(x32 * x32, axis=1)
-    else:
-        xn = jnp.pad(x_sq_norms.astype(jnp.float32), (0, pn - n))
-
-    am = (None if allow_bits is None
-          else _block_major_mask(allow_bits, pb, pn))
-    out_d, out_i = _fused_topk_tiled(
-        q, x, valid_f[None, :], xn[None, :], am, metric, k, tile_n,
-        allow_bits is not None, interpret)
-    return out_d[:b], out_i[:b]
-
-
-def _fused_pairs_kernel(k: int, interpret: bool):
-    """Top-k fold over precomputed (vals, ids) tiles — the merge stage for
-    the quantized scan-reduce kernels' [B, ~N/L] survivor arrays."""
-
-    def kernel(v_ref, i_ref, outd_ref, outi_ref, cd_ref, ci_ref):
-        step = pl.program_id(0)
-
-        @pl.when(step == 0)
-        def _():
-            cd_ref[:] = jnp.full(cd_ref.shape, MASKED_DISTANCE, jnp.float32)
-            ci_ref[:] = jnp.full(ci_ref.shape, -1, jnp.int32)
-
-        cd, ci = _fold_tile_topk(v_ref[:], i_ref[:], cd_ref[:], ci_ref[:],
-                                 k, interpret)
-        cd_ref[:] = cd
-        ci_ref[:] = ci
-        outd_ref[:] = cd
-        outi_ref[:] = ci
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("k", "tile_m", "interpret"))
-def _fused_pairs_tiled(vals, ids, k, tile_m, interpret):
-    b, m = vals.shape
-    return pl.pallas_call(
-        _fused_pairs_kernel(k, interpret),
-        grid=(m // tile_m,),
-        in_specs=[
-            pl.BlockSpec((b, tile_m), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((b, tile_m), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((b, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((b, k), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((b, k), jnp.float32),
-            pltpu.VMEM((b, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(vals, ids)
-
-
-def fused_topk_pairs(
-    vals: jnp.ndarray,
-    ids: jnp.ndarray,
-    k: int,
-    tile_m: int = 2048,
-    interpret: bool | None = None,
-):
-    """EXACT top-k over explicit (vals [B,M] f32, ids [B,M] i32) candidate
-    pairs via the same in-VMEM running-carry fold as ``fused_topk_scan`` —
-    replaces the post-scan ``approx_max_k`` pass of the quantized
-    scan-reduce consumers. Entries at >= MASKED_DISTANCE never surface."""
-    if not 1 <= k <= _FUSED_PAIRS_MAX_K:
-        raise ValueError(f"fused pairs top-k requires 1 <= k <= 256, got {k}")
-    if interpret is None:
-        interpret = not recommended()
-    b, m = vals.shape
-    pb = _pad_to(max(b, 1), _SUBLANE)
-    tile_m = min(tile_m, _pad_to(max(m, 1), _LANE))
-    pm = _pad_to(max(m, 1), tile_m)
-    vals = vals.astype(jnp.float32)
-    if (pb, pm) != (b, m):
-        vals = jnp.pad(vals, ((0, pb - b), (0, pm - m)),
-                       constant_values=MASKED_DISTANCE)
-        ids = jnp.pad(ids.astype(jnp.int32), ((0, pb - b), (0, pm - m)),
-                      constant_values=-1)
-    out_d, out_i = _fused_pairs_tiled(vals, ids.astype(jnp.int32), k,
-                                      tile_m, interpret)
-    return out_d[:b], out_i[:b]
-
-
 _SCAN_ID_BITS = 6  # slice-id field width: reduce_l <= 64 strided slices
 
 

@@ -331,7 +331,7 @@ def pq_lut(q: jnp.ndarray, centroids: jnp.ndarray, metric: str, m: int):
 @functools.partial(jax.jit, static_argnames=("k", "refine", "metric", "m",
                                              "use_pallas",
                                              "chunk_budget_bytes",
-                                             "selection", "rescore_k"))
+                                             "rescore_k"))
 def pq_topk_twostage(
     q: jnp.ndarray,
     q_prefix_words: jnp.ndarray,
@@ -346,15 +346,14 @@ def pq_topk_twostage(
     m: int | None = None,
     use_pallas: bool = True,
     chunk_budget_bytes: int = 128 << 20,
-    selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
     rescore_rows: jnp.ndarray | None = None,
     rescore_k: int = 0,
 ):
-    """Two-stage PQ scan (the r4 verdict's "extend the prefix idea to PQ").
+    """Two-stage PQ scan: the BQ prefix idea extended to PQ.
 
     An exhaustive ADC scan pays 2*B*N*d MXU FLOPs no matter how small the
-    codes (BASELINE r4 roofline note) — pruning is the only way under it.
+    codes: pruning is the only way under it.
     Stage 1 scans a 128/256-bit transposed BQ SIGN prefix (built from the
     raw vectors at insert, ops/bq semantics; int8-MXU hamming via
     bq_scan_reduce) and keeps refine*k candidates; stage 2 gathers those
@@ -385,16 +384,9 @@ def pq_topk_twostage(
             reduce_l=bq_ops._auto_reduce_l(n), transposed=True,
             allow_bits=allow_bits)
         r = min(refine * k, vals1.shape[1])
-        if selection == "fused" and r <= 256:
-            # exact stage-1 refine via the in-kernel running-carry fold
-            from weaviate_tpu.ops.pallas_kernels import fused_topk_pairs
-
-            cand_d1, cand = fused_topk_pairs(vals1, ids1, k=r)
-            cand = jnp.where(cand < 0, 0, cand)  # unfilled: masked below
-        else:
-            negd, pos = jax.lax.approx_max_k(-vals1, r, recall_target=0.95)
-            cand_d1 = -negd
-            cand = jnp.take_along_axis(ids1, pos, axis=1)  # [B, R] rows
+        negd, pos = jax.lax.approx_max_k(-vals1, r, recall_target=0.95)
+        cand_d1 = -negd
+        cand = jnp.take_along_axis(ids1, pos, axis=1)  # [B, R] rows
     else:
         cand_d1, ids1 = bq_ops.bq_topk(
             q_prefix_words, prefix_t.T, k=min(refine * k, n), valid=valid,
@@ -455,8 +447,7 @@ def pq_topk_twostage(
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk_size", "metric", "m",
-                                             "reduce_l", "selection",
-                                             "rescore_k"))
+                                             "reduce_l", "rescore_k"))
 def pq4_topk(
     q: jnp.ndarray,
     codes: jnp.ndarray,
@@ -468,18 +459,14 @@ def pq4_topk(
     id_offset: jnp.ndarray | int = 0,
     m: int | None = None,
     reduce_l: int | None = None,
-    selection: str = "approx",
     allow_bits: jnp.ndarray | None = None,
     rescore_rows: jnp.ndarray | None = None,
     rescore_k: int = 0,
 ):
     """Compressed brute-force top-k over 4-bit codes via the fused ADC scan
     kernel (pallas_kernels.pq4_scan_reduce: per-query int8 LUT, one-hot
-    int8 matmul, in-kernel strided block-argmin), then a survivor
-    selection over the ~N/L candidates and an exact final top-k.
-    ``selection="approx"`` (default) runs one approx_max_k over the
-    survivors; ``"fused"`` folds them through the exact in-kernel
-    running-carry top-k (pallas_kernels.fused_topk_pairs) instead. Same
+    int8 matmul, in-kernel strided block-argmin), then one approx_max_k
+    over the ~N/L survivors and an exact final top-k. Same
     contract as pq_topk (``rescore_rows`` / ``rescore_k`` too);
     ``chunk_size`` is accepted for API compatibility."""
     from weaviate_tpu.ops.bq import _auto_reduce_l
@@ -495,5 +482,5 @@ def pq4_topk(
     from weaviate_tpu.ops.topk import select_survivors
 
     return rescore_tail(
-        *select_survivors(vals, ids, k, selection, id_offset), q,
+        *select_survivors(vals, ids, k, id_offset), q,
         rescore_rows, rescore_k, metric, valid=valid, allow_bits=allow_bits)

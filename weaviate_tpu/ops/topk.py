@@ -54,30 +54,22 @@ def approx_topk_smallest(dists: jnp.ndarray, ids: jnp.ndarray, k: int):
     return -neg_d, top_ids
 
 
-def select_survivors(vals, ids, k: int, selection: str = "approx",
-                     id_offset=0):
+def select_survivors(vals, ids, k: int, id_offset=0):
     """Final selection over a scan-reduce survivor array: vals [B, M] f32
     (dead entries at MASKED_DISTANCE), ids [B, M] i32 global rows.
 
-    The shared tail of the bq/pq4 fused-scan consumers: ``"approx"`` runs
-    one ``approx_max_k`` oversample (4x k) + exact merge; ``"fused"`` the
-    exact in-kernel running-carry fold (pallas_kernels.fused_topk_pairs,
-    k <= its 256-wide carry — larger k falls back to approx). Pads to
-    [B, k] with (MASKED_DISTANCE, -1) and applies ``id_offset`` to live
-    entries only."""
+    The shared tail of the bq/pq4 scan-reduce consumers: one
+    ``approx_max_k`` oversample (4x k) + exact merge. Pads to [B, k] with
+    (MASKED_DISTANCE, -1) and applies ``id_offset`` to live entries
+    only."""
     ncand = vals.shape[1]
     kk = min(k, ncand)
-    if selection == "fused" and kk <= 256:
-        from weaviate_tpu.ops.pallas_kernels import fused_topk_pairs
-
-        fd, fi = fused_topk_pairs(vals, ids, k=kk)
-    else:
-        if ncand > 4 * kk:
-            negd, pos = jax.lax.approx_max_k(-vals, min(4 * kk, ncand),
-                                             recall_target=0.95)
-            vals = -negd
-            ids = jnp.take_along_axis(ids, pos, axis=1)
-        fd, fi = topk_smallest(vals, ids, kk)
+    if ncand > 4 * kk:
+        negd, pos = jax.lax.approx_max_k(-vals, min(4 * kk, ncand),
+                                         recall_target=0.95)
+        vals = -negd
+        ids = jnp.take_along_axis(ids, pos, axis=1)
+    fd, fi = topk_smallest(vals, ids, kk)
     if kk < k:
         fd = jnp.pad(fd, ((0, 0), (0, k - kk)),
                      constant_values=MASKED_DISTANCE)
@@ -86,8 +78,8 @@ def select_survivors(vals, ids, k: int, selection: str = "approx",
     return fd, fi
 
 
-@functools.partial(jax.jit, static_argnames=("k", "selection"))
-def merge_epoch_topk(parts, slot_maps, k: int, selection: str = "approx"):
+@functools.partial(jax.jit, static_argnames=("k",))
+def merge_epoch_topk(parts, slot_maps, k: int):
     """Cross-epoch candidate merge (engine/epochs.py): the single-device
     twin of the ICI merge — per-epoch survivor sets become one global
     top-k without the distances ever leaving HBM.
@@ -98,11 +90,10 @@ def merge_epoch_topk(parts, slot_maps, k: int, selection: str = "approx"):
     epoch's rows but keeps global slots stable through its map). Each
     epoch's ids gather through its map, the candidate sets concatenate in
     epoch order (so distance ties resolve to the lower global slot, same
-    as a single-buffer scan), and the merge itself is EXACT:
-    ``fused_topk_pairs`` (the in-kernel running-carry fold) under
-    ``selection="fused"``, ``lax.top_k`` otherwise — per-epoch selection
-    error never compounds across epochs, mirroring the chunk-carry
-    contract of ``chunked_topk_distances``. Returns ``(d [B, k],
+    as a single-buffer scan), and the merge itself is EXACT
+    (``lax.top_k``): per-epoch selection error never compounds across
+    epochs, mirroring the chunk-carry contract of
+    ``chunked_topk_distances``. Returns ``(d [B, k],
     i [B, k])`` global ids, (MASKED_DISTANCE, -1) padded."""
     mapped_d, mapped_i = [], []
     for (d, i), smap in zip(parts, slot_maps):
@@ -114,12 +105,7 @@ def merge_epoch_topk(parts, slot_maps, k: int, selection: str = "approx"):
     cat_i = jnp.concatenate(mapped_i, axis=1)
     ncand = cat_d.shape[1]
     kk = min(k, ncand)
-    if selection == "fused" and kk <= 256:
-        from weaviate_tpu.ops.pallas_kernels import fused_topk_pairs
-
-        fd, fi = fused_topk_pairs(cat_d, cat_i, k=kk)
-    else:
-        fd, fi = topk_smallest(cat_d, cat_i, kk)
+    fd, fi = topk_smallest(cat_d, cat_i, kk)
     if kk < k:
         fd = jnp.pad(fd, ((0, 0), (0, k - kk)),
                      constant_values=MASKED_DISTANCE)
@@ -170,11 +156,10 @@ def chunked_topk_distances(
 
     ``allow_bits`` adds a PER-QUERY allow bitmask ([B, ceil(N_512/32)]
     uint32, ``pallas_kernels.pack_allow_bitmask`` layout) — the batched
-    filtered-search dataplane. The fused path unpacks it tile-locally in
-    VMEM; the XLA paths unpack once and fold a [B, chunk] where into each
-    tile. ``allow_rows`` ([B, N] bool) is the unpacked equivalent for
-    callers that already hold a sliced bool mask (the sharded local path);
-    pass at most one of the two.
+    filtered-search dataplane: unpacked once and folded into each tile
+    by a [B, chunk] where. ``allow_rows`` ([B, N] bool) is the unpacked
+    equivalent for callers that already hold a sliced bool mask (the
+    sharded local path); pass at most one of the two.
 
     ``row_ids`` ([N] int32) remaps scanned row POSITIONS to global ids on
     device before returning — the candidate plane's slot remap
@@ -185,8 +170,8 @@ def chunked_topk_distances(
     ``selection`` picks the per-chunk candidate selector:
 
     - ``"exact"``: ``lax.top_k`` over every [B, k+chunk] tile — bit-exact,
-      but at k~10-100 a wide top_k costs ~a sort and dominates the scan
-      (~95% of device time at 1M rows, VERDICT r2).
+      but at k~10-100 a wide top_k costs ~a sort and dominates the scan.
+      The reference the tests and ``classification/`` call.
     - ``"approx"``: ``lax.approx_max_k`` (the TPU PartialReduce bucketed
       argmin — Chern et al., the TPU-KNN paper) pulls an OVERSAMPLED
       candidate set (4x k) per chunk at O(chunk) with a tiny constant; the
@@ -195,41 +180,14 @@ def chunked_topk_distances(
       the only approximation is which candidates survive a chunk, and with
       4x oversampling measured recall@10 vs exact is ≥0.999. On non-TPU
       backends XLA lowers approx_max_k to an exact top_k, so CPU tests see
-      bit-exact results.
-    - ``"fused"``: selection happens INSIDE the Pallas scan kernel
-      (pallas_kernels.fused_topk_scan): each grid step folds its VMEM
-      distance tile into a per-query running top-k carry, so the [B, N]
-      distance matrix never round-trips through HBM and no per-chunk
-      top_k/approx_max_k pass exists at all. EXACT top-k semantics (ties
-      break like lax.top_k); unfilled slots surface as (MASKED, -1)
-      instead of arbitrary dead-row ids. Runs compiled on TPU and through
-      the Pallas interpreter elsewhere (tests; too slow to serve from on
-      CPU). Requires a Pallas metric and k <= 128 — other metrics fall
-      back to ``"exact"`` and k > 128 falls back to ``"approx"``
-      (``search_by_distance`` widens k past the carry width).
+      bit-exact results. What every store passes (``engine/store.py``
+      ``SCAN_SELECTION``).
     """
     n = x.shape[0]
     assert n % chunk_size == 0, f"corpus rows {n} not a multiple of chunk {chunk_size}"
-    if selection == "fused":
-        from weaviate_tpu.ops.pallas_kernels import (
-            _FUSED_TOPK_MAX_K,
-            PALLAS_METRICS,
-            fused_topk_scan,
-        )
-
-        if metric in PALLAS_METRICS and k <= _FUSED_TOPK_MAX_K:
-            d, i = fused_topk_scan(
-                q, x, k=k, metric=metric, valid=valid,
-                x_sq_norms=x_sq_norms, allow_bits=allow_bits,
-                allow_rows=allow_rows,
-            )
-            if row_ids is not None:
-                return d, jnp.where(
-                    i < 0, i, row_ids[jnp.clip(i, 0, n - 1)])
-            return d, jnp.where(i < 0, i, i + id_offset)
-        # degrade gracefully: non-Pallas metrics take the exact XLA scan,
-        # oversized k the approx per-chunk selection (same recall story)
-        selection = "approx" if metric in PALLAS_METRICS else "exact"
+    if selection not in ("exact", "approx"):
+        raise ValueError(
+            f"selection must be 'exact' or 'approx', got {selection!r}")
     num_chunks = n // chunk_size
     b = q.shape[0]
 

@@ -247,8 +247,8 @@ def _sharded_topk_jit(
     ``x``/``valid``/``x_sq_norms`` must be row-sharded over the mesh's
     row axes on their leading dim; ``q`` is replicated. ``allow_rows``
     ([B, N] bool — per-query filter masks) is sharded on its COLUMN dim,
-    row-aligned with the corpus: each device applies (and, for the fused
-    kernel, packs) only its own slice; the candidate merge is unchanged
+    row-aligned with the corpus: each device applies only its own slice;
+    the candidate merge is unchanged
     because masked rows simply never become candidates. Returns
     replicated (dists [B,k], global_ids [B,k]) where ids index the
     unsharded [N] row space.
@@ -325,7 +325,7 @@ def sharded_topk(q, x, valid, x_sq_norms, *, k, chunk_size, metric, mesh,
     jax.jit,
     static_argnames=(
         "k", "k_out", "chunk_size", "quantization", "metric", "mesh", "axis",
-        "use_pallas", "selection", "dcn_compact",
+        "use_pallas", "dcn_compact",
     ),
 )
 def _sharded_quantized_topk_jit(
@@ -343,7 +343,6 @@ def _sharded_quantized_topk_jit(
     mesh: Mesh,
     axis: str = SHARD_AXIS,
     use_pallas: bool = False,
-    selection: str = "approx",
     allow_rows: jnp.ndarray | None = None,
     dcn_compact: bool = False,
 ):
@@ -361,10 +360,7 @@ def _sharded_quantized_topk_jit(
     hierarchical one.
 
     ``q`` is replicated f32 (pre-normalized for cosine); ``q_words`` packed
-    query bits for bq. ``selection`` picks the per-shard survivor selector
-    for the bq/pq4 scan-reduce paths ("approx" = approx_max_k, "fused" =
-    exact in-kernel running-carry top-k); the merge contract is
-    unchanged either way. ``allow_rows`` [B, N] bool per-query filter
+    query bits for bq. ``allow_rows`` [B, N] bool per-query filter
     masks are COLUMN-sharded row-aligned with the codes; each device
     packs its slice to the kernel bitmask locally. Returns replicated
     (dists [B, k_out], global ids).
@@ -389,14 +385,13 @@ def _sharded_quantized_topk_jit(
         if quantization == "bq":
             d_c, i_c = bq_ops.bq_topk(
                 qw_, codes_, k=min(k, local_rows), chunk_size=chunk_size,
-                valid=valid_, use_pallas=use_pallas, selection=selection,
-                allow_bits=ab_,
+                valid=valid_, use_pallas=use_pallas, allow_bits=ab_,
             )
         elif quantization == "pq4":
             d_c, i_c = pq_ops.pq4_topk(
                 q_, codes_, cent_, k=min(k, local_rows),
                 chunk_size=chunk_size, metric=metric, valid=valid_,
-                selection=selection, allow_bits=ab_,
+                allow_bits=ab_,
             )
         else:
             d_c, i_c = pq_ops.pq_topk(
@@ -464,8 +459,8 @@ def _sharded_quantized_topk_jit(
 def sharded_quantized_topk(q, q_words, codes, valid, rescore_rows,
                            centroids, *, k, k_out, chunk_size,
                            quantization, metric, mesh, axis=SHARD_AXIS,
-                           use_pallas=False, selection="approx",
-                           allow_rows=None, dcn_compact=None):
+                           use_pallas=False, allow_rows=None,
+                           dcn_compact=None):
     """Span-wrapped dispatch of the compressed SPMD scan + merge."""
     if dcn_compact is None:
         dcn_compact = dcn_compact_default()
@@ -486,8 +481,7 @@ def sharded_quantized_topk(q, q_words, codes, valid, rescore_rows,
             q, q_words, codes, valid, rescore_rows, centroids, k=k,
             k_out=k_out, chunk_size=chunk_size, quantization=quantization,
             metric=metric, mesh=mesh, axis=axis, use_pallas=use_pallas,
-            selection=selection, allow_rows=allow_rows,
-            dcn_compact=dcn_compact)
+            allow_rows=allow_rows, dcn_compact=dcn_compact)
 
 
 def shard_array(arr, mesh: Mesh, dim: int = 0):

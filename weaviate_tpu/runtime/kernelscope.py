@@ -314,7 +314,6 @@ def merge_into_request(plan: dict | None) -> None:
 #: trace event names. Mirrors the device programs the hot path compiles
 #: (ops/pallas_kernels.py, ops/candidates.py, ops/topk.py, engine/ivf).
 KERNEL_REGISTRY: tuple[tuple[str, tuple[str, ...]], ...] = (
-    ("fused_topk_scan", ("fused_topk",)),
     ("bq_scan_reduce", ("bq_scan", "bq_mxu", "bq_hamming")),
     ("pq4_scan_reduce", ("pq4_scan", "pq4_lut", "pq4_recon")),
     ("ivf_probe", ("ivf", "probe", "centroid")),
