@@ -34,8 +34,17 @@ this file with it:
    the served test makes none), and no growth of ``cap`` (the store doubles
    it when EVERY list is full; ``insert`` raises instead).
 
-A filter (``allowed``: bool over row positions) removes rows from the
-candidates of both legs; it does not change which lists are probed."""
+6. **The cutoff** (upstream's ``flatSearchCutoff``, which its ``hnsw``
+   index has and a ``dynamic`` class configures under ``hnsw``). A filter
+   that allows FEWER rows than ``flat_search_cutoff`` is not probed at
+   all: the answer is the exhaustive scan over the allowed rows, wherever
+   they lie (lists or delta). The count is the filter's own, so the
+   answer does not depend on what else was asked at the same time. 0
+   turns the rule off.
+
+A filter (``allowed``: bool over row positions) at or over the cutoff
+removes rows from the candidates of both legs; it does not change which
+lists are probed."""
 
 from __future__ import annotations
 
@@ -105,15 +114,20 @@ def insert(member: np.ndarray, rows: np.ndarray, centroids: np.ndarray,
 def search(queries: np.ndarray, k: int, nprobe: int, metric: str,
            centroids: np.ndarray, rows: np.ndarray, member: np.ndarray,
            delta: np.ndarray | None = None,
-           allowed: np.ndarray | None = None):
+           allowed: np.ndarray | None = None,
+           flat_search_cutoff: int = 0):
     """Top-k of each query over the rows of its ``nprobe`` nearest lists
-    plus the delta's rows.
+    plus the delta's rows; under the cutoff (departure 6) over every
+    allowed row.
 
     ``rows`` [N, d] are all rows by position, raw; ``member`` [M] gives
     the list of the first M of them (those folded into lists), ``delta``
     the positions still in the delta buffer; ``allowed`` bool [N]. ->
     (positions [Q, k] int64, -1 where fewer than k candidates; distances
     [Q, k] float64, ascending, inf there)."""
+    if (allowed is not None and flat_search_cutoff
+            and int(np.count_nonzero(allowed)) < flat_search_cutoff):
+        return exact(queries, k, metric, rows, allowed)
     q = prepare(queries, metric)
     x = prepare(rows, metric)
     c = np.asarray(centroids, dtype=np.float64)

@@ -41,6 +41,10 @@ TOLERANCE = GLOVE["limits"]["distance_error_max"]
 FLOOR = GLOVE["limits"]["distance_scale_floor"]
 FILTER = {"property": "bucket", "operator": "less_than"}
 BOUNDS = (-1, 50, 10)   # -1: no filter
+# the class names no flatSearchCutoff, so upstream's default holds: at
+# 24,576 rows both filters allow fewer rows (12,288 and 2,458) and are
+# answered exactly, never by the probe (ISSUE 51)
+CUTOFF = 40_000
 
 
 def glove_class(name="Glove", **index_config) -> dict:
@@ -178,6 +182,10 @@ def served(tmp_path_factory):
         # through the upgrade, a retrain and a fold
         import_rows(grpc, "Glove", corpus, buckets, BATCH, ROWS)
         keep_spans()
+        # a tick that comes while the rows still arrive (the last batch a
+        # moment ago, by the clock) leaves the delta's tail where it is
+        seen["tick_while_writing"] = index().maintain(tick=True)
+        seen["delta_after_first_tick"] = len(store_facts(index())["delta"])
         seen["upgraded"] = index().upgraded
         seen["with_delta"] = dict(
             store_facts(index()),
@@ -185,8 +193,6 @@ def served(tmp_path_factory):
         newest = np.arange(ROWS - 8, ROWS)
         seen["newest_from_delta"] = answers(grpc, "Glove", corpus[newest], -1)
         # the fold a maintenance pass makes once the writes have paused
-        seen["tick_while_writing"] = index().maintain(tick=True)
-        seen["delta_after_first_tick"] = len(store_facts(index())["delta"])
         with tracing.trace("maintenance", force=True):
             assert server.db.cycles.run_now("epoch-maintenance")
         keep_spans()
@@ -273,9 +279,9 @@ def test_the_import_went_through_the_upgrade_one_retrain_and_one_fold(served):
 
 
 def test_a_tick_leaves_the_delta_alone_while_writes_arrive(served):
-    """The first tick after writes answers "work left" and folds nothing;
-    the pass that finds the writes paused folds (here ``run_now``, which
-    is not a tick)."""
+    """A tick that finds the last write a moment old answers "work
+    left" and folds nothing; the pass that finds the writes paused folds
+    (here ``run_now``, which is not a tick)."""
     assert served["tick_while_writing"] is True
     assert served["delta_after_first_tick"] == ROWS - FOLDED_AT
 
@@ -296,7 +302,7 @@ def test_served_answers_equal_the_reference(served, membership, state, bound):
     want = ivf_reference.search(
         served["queries"], K, facts["nprobe"], METRIC, facts["centroids"],
         served["corpus"], membership[state], delta=facts["delta"],
-        allowed=allowed(served, bound))
+        allowed=allowed(served, bound), flat_search_cutoff=CUTOFF)
     same_answers(facts["answers"][bound], want)
 
 
@@ -321,6 +327,27 @@ def test_recall_at_the_default_probe(served, state):
     recall = float((own <= want_d[:, -1:] * (1 + 1e-6) + 1e-9).mean())
     assert recall >= GLOVE["limits"]["recall_at_k_min"], recall
     assert served[state]["nprobe"] * 8 == served[state]["nlist"]
+
+
+@pytest.mark.parametrize("bound", [b for b in BOUNDS if b >= 0])
+@pytest.mark.parametrize("state", ["with_delta", "folded"])
+def test_filtered_recall_against_the_exact_filtered_topk(served, state,
+                                                         bound):
+    """Equality with the reference holds the program to the RULE; this
+    holds the rule to the truth: the exact top-k over the rows the filter
+    allows. Until ISSUE 51 nothing did, and a probe of an eighth of the
+    lists under a 1 % filter found two thirds of it."""
+    ok = allowed(served, bound)
+    want_i, want_d = ivf_reference.exact(served["queries"], K, METRIC,
+                                         served["corpus"], ok)
+    got_i, _ = served[state]["answers"][bound]
+    assert (got_i >= 0).all() and ok[got_i].all()
+    x = ivf_reference.prepare(served["corpus"], METRIC)
+    q = ivf_reference.prepare(served["queries"], METRIC)
+    own = 1.0 - np.einsum("qkd,qd->qk", x[got_i], q)
+    recall = float((own <= want_d[:, -1:] * (1 + 1e-6) + 1e-9).mean())
+    assert recall >= GLOVE["limits"]["recall_at_k_min"], recall
+    assert int(ok.sum()) < CUTOFF and recall == 1.0
 
 
 # -- (v) the tolerance is tight enough ----------------------------------------

@@ -9,6 +9,7 @@ import pytest
 
 from weaviate_tpu.engine.dynamic import DynamicIndex
 from weaviate_tpu.engine.flat import FlatIndex
+from weaviate_tpu.engine import ivf as ivf_mod
 from weaviate_tpu.engine.ivf import IVFIndex
 
 
@@ -458,9 +459,15 @@ def test_a_tick_folds_the_delta_only_once_the_writes_have_paused(rng):
     idx.add_batch(np.arange(600), vecs[:600])
     assert idx.trained and not idx.store._delta_slots
     idx.add_batch(np.arange(600, 700), vecs[600:])
-    assert idx.maintain(tick=True) is True      # rows arrived: work left
+    wrote = idx.store._last_write_t
+    pause = ivf_mod.TAIL_FOLD_PAUSE_S
+    # rows arrived a moment ago: work left, whatever the ticks before saw
+    assert idx.maintain(tick=True, now=wrote + 0.1) is True
+    assert idx.maintain(tick=True, now=wrote + pause - 0.1) is True
     assert len(idx.store._delta_slots) == 100
-    assert idx.maintain(tick=True) is True      # paused: folded
+    # paused by the clock: folded at the FIRST tick that comes after, also
+    # where the scheduler was kept from ticking all the while
+    assert idx.maintain(tick=True, now=wrote + pause) is True
     assert not idx.store._delta_slots
     assert idx.maintain(tick=True) is False     # nothing to do: back off
     idx.add_batch([700], vecs[:1])

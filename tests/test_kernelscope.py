@@ -280,8 +280,11 @@ def test_explain_ivf_filtered_plan_and_sync_async_parity():
     from weaviate_tpu.engine.ivf import IVFStore
 
     rng = np.random.default_rng(7)
+    # (flatSearchCutoff off: 256 allowed rows are under its default, and
+    # the exact route that would answer them has no probe plan to report)
     st = IVFStore(dim=16, nlist=8, nprobe=2, train_threshold=256,
-                  delta_threshold=64, quantization="pq")
+                  delta_threshold=64, quantization="pq",
+                  flat_search_cutoff=0)
     st.add(rng.standard_normal((512, 16)).astype(np.float32))
     assert st.trained
     qs = rng.standard_normal((3, 16)).astype(np.float32)

@@ -359,6 +359,36 @@ def test_ivf_probe_at_the_dynamic_cells_shapes(one_chip, b):
         (nlist * cap * 128 * 4) + gathered + (64 << 20)
 
 
+@pytest.mark.parametrize("bucket", [4096, 32768])
+def test_ivf_exact_route_at_the_filtered_cells_shapes(one_chip, bucket):
+    """``cohere-dynamic-cosine.filtered-c32``'s exact route under
+    ``flatSearchCutoff`` (ISSUE 51) as the served store launches it: a
+    block of 32 queries, a slot list of 4,096 or 32,768 (the mix's 1 % and
+    10 % filters at 262,144 rows), the slot map over 262,144 slots, lists
+    of 1,024 x 512 x 768 float32 and the delta buffer's 16,384 rows. The
+    chip's compiler takes it, copies neither the list tensor nor its
+    layout, and holds at most the rows it gathers from the lists and
+    from the delta beside its arguments."""
+    from weaviate_tpu.engine.ivf import _ivf_flat_cutoff_topk
+
+    nlist, cap, d, k, b = 1024, 512, 768, 128, 32
+    f32 = jnp.float32
+
+    def fn(q, slots, slot_map, vecs, delta_vecs):
+        return _ivf_flat_cutoff_topk(q, slots, slot_map, vecs, delta_vecs,
+                                     k, "cosine")
+
+    c = _compile(fn, one_chip, ((b, d), f32), ((bucket,), jnp.int32),
+                 ((262144,), jnp.int32), ((nlist, cap, d), f32),
+                 ((16384, d), f32))
+    text = c.as_text()
+    assert text.count(f"[{b},{k}]{{1,0") >= 2          # [b, k] goes back
+    assert not re.search(
+        rf"= f32\[(?:{nlist},{cap}|{nlist * cap}),{d}\][^ ]* copy\(", text)
+    assert c.memory_analysis().temp_size_in_bytes <= \
+        2 * bucket * d * 4 + (8 << 20)
+
+
 @pytest.mark.parametrize("b_pad", [1, 16, 32])
 def test_filtered_dispatch_programs(one_chip, monkeypatch, b_pad):
     """The filtered cell's two programs since PR 40, at its shapes

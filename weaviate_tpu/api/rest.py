@@ -195,6 +195,19 @@ def _index_config_from_json(index_type: str | None, d: dict | None):
                 f"vectorIndexConfig.threshold must be an int >= 1, got "
                 f"{threshold!r}")
         out.flat_to_ann_threshold = threshold
+    # upstream's flatSearchCutoff is a key of the hnsw config: the top
+    # level of an hnsw class, the nested ``hnsw`` block of a dynamic one
+    hnsw_block = d.get("hnsw") if out.index_type == "dynamic" else d
+    if isinstance(hnsw_block, dict) and "flatSearchCutoff" in hnsw_block:
+        cutoff = hnsw_block["flatSearchCutoff"]
+        if (not isinstance(cutoff, int) or isinstance(cutoff, bool)
+                or cutoff < 0):
+            where = ("vectorIndexConfig.hnsw.flatSearchCutoff"
+                     if out.index_type == "dynamic"
+                     else "vectorIndexConfig.flatSearchCutoff")
+            raise ValueError(
+                f"{where} must be an int >= 0, got {cutoff!r}")
+        out.flat_search_cutoff = cutoff
     if out.index_type == "dynamic":
         # upstream nests a dynamic class's two regimes (``hnsw``, ``flat``)
         # with compressions of their own; here the class takes ONE, at
@@ -290,6 +303,9 @@ def class_to_wire(cfg: CollectionConfig) -> dict:
         }
         if ix.index_type == "dynamic":
             out["threshold"] = ix.flat_to_ann_threshold
+            out["hnsw"] = {"flatSearchCutoff": ix.flat_search_cutoff}
+        elif ix.index_type in ("hnsw", "ivf"):
+            out["flatSearchCutoff"] = ix.flat_search_cutoff
         return out
 
     inv = cfg.inverted

@@ -235,6 +235,10 @@ class Collection:
                     ("rescore_limit", vc.index.rescore_limit),
                     ("nprobe", vc.index.ivf_nprobe),
                     ("threshold", vc.index.flat_to_ann_threshold),
+                    # upstream's flatSearchCutoff under its two names
+                    # here: the IVF / dynamic index's and the graph's
+                    ("flat_search_cutoff", vc.index.flat_search_cutoff),
+                    ("flat_cutoff", vc.index.flat_search_cutoff),
                 ):
                     # 0 is meaningful (= auto); only skip absent values
                     if hasattr(idx, attr) and value is not None:

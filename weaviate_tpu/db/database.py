@@ -301,6 +301,8 @@ class Database:
                     vc.index.rescore_limit = vc_new.index.rescore_limit
                     vc.index.flat_to_ann_threshold = \
                         vc_new.index.flat_to_ann_threshold
+                    vc.index.flat_search_cutoff = \
+                        vc_new.index.flat_search_cutoff
                     vc.index.ivf_nprobe = vc_new.index.ivf_nprobe
                     if vc_new.index.quantization and \
                             not vc.index.quantization:

@@ -92,6 +92,7 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None, memwatch=None):
         # silently landing a sharded corpus on one device
         return IVFIndex(nlist=cfg.ivf_nlist, nprobe=cfg.ivf_nprobe,
                         mesh=mesh,
+                        flat_search_cutoff=cfg.flat_search_cutoff,
                         quantization=cfg.quantization,
                         pq_segments=cfg.pq_segments,
                         pq_centroids=cfg.pq_centroids,
@@ -113,6 +114,7 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None, memwatch=None):
             dim=dim, metric=cfg.metric,
             max_connections=cfg.max_connections,
             ef_construction=cfg.ef_construction, ef=cfg.ef,
+            flat_cutoff=cfg.flat_search_cutoff,
         )
     if cfg.index_type == "dynamic":
         # the ANN regime on TPU is IVF (SURVEY §7 step 5), entered via the
@@ -132,6 +134,7 @@ def _make_vector_index(vc: VectorConfig, dim: int, mesh=None, memwatch=None):
             )
         return DynamicIndex(
             threshold=cfg.flat_to_ann_threshold, mesh=mesh,
+            flat_search_cutoff=cfg.flat_search_cutoff,
             nlist=cfg.ivf_nlist, nprobe=cfg.ivf_nprobe,
             dtype=jnp.bfloat16 if cfg.storage_dtype == "bfloat16" else jnp.float32,
             # an sq class stays flat, as a bq one does: exact until

@@ -19,7 +19,8 @@ import threading
 import numpy as np
 
 from weaviate_tpu.engine.flat import FlatIndex
-from weaviate_tpu.engine.ivf import IVFIndex, maintain_stage
+from weaviate_tpu.engine.ivf import (DEFAULT_FLAT_SEARCH_CUTOFF, IVFIndex,
+                                     maintain_stage)
 
 
 class DynamicIndex:
@@ -32,6 +33,7 @@ class DynamicIndex:
                  chunk_size: int = 8192, nlist: int = 0, nprobe: int = 0,
                  upgrade_quantization: str | None = None,
                  upgradable: bool = True,
+                 flat_search_cutoff: int = DEFAULT_FLAT_SEARCH_CUTOFF,
                  **flat_kwargs):
         self.dim = dim
         self.metric = metric
@@ -48,6 +50,9 @@ class DynamicIndex:
         # scan the IVF index has no form of: it stays flat at any size,
         # as a bq class does once its store is quantized)
         self._upgradable = upgradable
+        # upstream's hnsw.flatSearchCutoff, for the ANN index this class
+        # upgrades into (the flat regime is exact whatever the filter)
+        self._flat_search_cutoff = int(flat_search_cutoff)
         self._lock = threading.RLock()
         # captured so the runtime flat->IVF upgrade (which runs on an
         # insert thread, outside any shard owner scope) keeps the new
@@ -64,6 +69,17 @@ class DynamicIndex:
     @property
     def upgraded(self) -> bool:
         return isinstance(self._impl, IVFIndex)
+
+    @property
+    def flat_search_cutoff(self) -> int:
+        return self._flat_search_cutoff
+
+    @flat_search_cutoff.setter
+    def flat_search_cutoff(self, cutoff: int) -> None:
+        with self._lock:
+            self._flat_search_cutoff = int(cutoff)
+            if self.upgraded:
+                self._impl.flat_search_cutoff = int(cutoff)
 
     def should_upgrade(self) -> bool:
         """Reference ShouldUpgrade (dynamic/index.go:348). Mesh-sharded and
@@ -102,6 +118,7 @@ class DynamicIndex:
                            nlist=self._nlist, nprobe=self._nprobe,
                            train_threshold=max(self.threshold, 256),
                            dtype=getattr(flat.store, "dtype", None),
+                           flat_search_cutoff=self._flat_search_cutoff,
                            quantization=self._upgrade_quantization)
         if live:
             ids = slot_to_id[live]
@@ -153,6 +170,7 @@ class DynamicIndex:
         snap["dynamic_upgraded"] = self.upgraded
         snap["dynamic_upgrade_quantization"] = self._upgrade_quantization
         snap["dynamic_upgradable"] = self._upgradable
+        snap["flat_search_cutoff"] = self._flat_search_cutoff
         return snap
 
     @classmethod
@@ -167,6 +185,8 @@ class DynamicIndex:
         idx._chunk_size = snap.get("chunk_size", 8192)
         idx._upgrade_quantization = snap.get("dynamic_upgrade_quantization")
         idx._upgradable = snap.get("dynamic_upgradable", True)
+        idx._flat_search_cutoff = snap.get("flat_search_cutoff",
+                                           DEFAULT_FLAT_SEARCH_CUTOFF)
         idx._lock = threading.RLock()
         from weaviate_tpu.runtime import hbm_ledger
 

@@ -382,14 +382,31 @@ class FlatIndex:
         ``pack_allow_bitmask`` of the translated [B, capacity] block.
         Caller holds ``_lock``.
 
-        A row refers to its mask's packed row on the device: kept from
-        an earlier dispatch (``hit``), or built now, once a mask OBJECT
-        however many rows carry it (``shared``) and kept where the mask
-        cannot change (``miss``; else ``uncached``); unfiltered and
-        padded rows share one all-ones row. The rows are stacked by one
-        program on the device, so a dispatch whose masks are all known
-        translates, packs and uploads nothing. The ``store.mask_pack``
-        span (the dispatch's ``mask_pack`` stage) is this whole step."""
+        A row refers to its mask's packed row on the device
+        (``_packed_rows``); the rows are stacked by one program on the
+        device, so a dispatch whose masks are all known translates,
+        packs and uploads nothing. The ``store.mask_pack`` span (the
+        dispatch's ``mask_pack`` stage) is this whole step."""
+        with tracing.span("store.mask_pack", stage="mask_pack",
+                          queries=len(lists)) as sp:
+            rows, hits, misses, distinct = self._packed_rows(lists)
+            bits = stack_allow_rows(*rows)
+            hbm_ledger.ledger.track("allow_bitmask", bits,
+                                    **self._operand_cache().owner)
+            sp.set(hits=hits, misses=misses, distinct=distinct)
+        return AllowBits(bits)
+
+    def _packed_rows(self, lists, translated: dict | None = None):
+        """Each entry of ``lists`` as its mask's packed bitmap row ON
+        THE DEVICE -> (rows, hits, misses, distinct masks). Caller holds
+        ``_lock``.
+
+        A row is kept from an earlier dispatch (``hit``), or built now,
+        once a mask OBJECT however many rows carry it (``shared``) and
+        kept where the mask cannot change (``miss``; else ``uncached``);
+        unfiltered and padded rows share one all-ones row.
+        ``translated``: slot masks the caller has made of some of the
+        lists already, by ``id`` of the list."""
         from weaviate_tpu.ops.pallas_kernels import (mask_pad_cols,
                                                      pack_allow_bitmask)
 
@@ -398,55 +415,51 @@ class FlatIndex:
         n_cols = mask_pad_cols(capacity)
         stamp = (self._slot_gen, capacity)
         cache = self._operand_cache()
-        with tracing.span("store.mask_pack", stage="mask_pack",
-                          queries=len(lists)) as sp:
-            rows: list = [None] * len(lists)
-            first: dict[int, int] = {}  # id(mask) -> the first row with it
-            build = []                  # (row, mask, keep) to translate
-            hits = shared = 0
-            ones = None                 # unfiltered and padded rows' one
-            for r, a in enumerate(lists):
-                if a is None:
-                    if ones is None:
-                        ones = cache.ones(stamp, lambda: placement.put(
-                            pack_allow_bitmask(
-                                np.ones(capacity, dtype=bool), n_cols)[0],
-                            store.device))
-                    rows[r] = ones
-                elif first.setdefault(id(a), r) != r:
-                    shared += 1
+        rows: list = [None] * len(lists)
+        first: dict[int, int] = {}  # id(mask) -> the first row with it
+        build = []                  # (row, mask, keep) to translate
+        hits = shared = 0
+        ones = None                 # unfiltered and padded rows' one
+        for r, a in enumerate(lists):
+            if a is None:
+                if ones is None:
+                    ones = cache.ones(stamp, lambda: placement.put(
+                        pack_allow_bitmask(
+                            np.ones(capacity, dtype=bool), n_cols)[0],
+                        store.device))
+                rows[r] = ones
+            elif first.setdefault(id(a), r) != r:
+                shared += 1
+            else:
+                keep = stable_mask(a)
+                e = cache.get(a, stamp) if keep else None
+                if e is not None and e.bits is not None:
+                    rows[r] = e.bits
+                    hits += 1
                 else:
-                    keep = stable_mask(a)
-                    e = cache.get(a, stamp) if keep else None
-                    if e is not None and e.bits is not None:
-                        rows[r] = e.bits
-                        hits += 1
-                    else:
-                        build.append((r, a, keep))
-            if build:
-                block = np.zeros((len(build), capacity), dtype=bool)
-                for j, (_r, a, _keep) in enumerate(build):
+                    build.append((r, a, keep))
+        if build:
+            block = np.zeros((len(build), capacity), dtype=bool)
+            for j, (_r, a, _keep) in enumerate(build):
+                m = (translated or {}).get(id(a))
+                if m is None:
                     m = self._allow_mask(a)
-                    block[j, :len(m)] = m
-                packed = pack_allow_bitmask(block, n_cols)
-                for j, (r, a, keep) in enumerate(build):
-                    rows[r] = placement.put(packed[j], store.device)
-                    if keep:
-                        cache.attach(a, stamp, bits=rows[r])
-            for r, a in enumerate(lists):
-                if rows[r] is None:
-                    rows[r] = rows[first[id(a)]]
-            bits = stack_allow_rows(*rows)
-            hbm_ledger.ledger.track("allow_bitmask", bits,
-                                    **cache.owner)
-            misses = sum(keep for _r, _a, keep in build)
-            for result, n in (("hit", hits), ("miss", misses),
-                              ("shared", shared),
-                              ("uncached", len(build) - misses)):
-                if n:
-                    filter_operand_total.labels("bitmask", result).inc(n)
-            sp.set(hits=hits, misses=misses, distinct=len(first))
-        return AllowBits(bits)
+                block[j, :len(m)] = m
+            packed = pack_allow_bitmask(block, n_cols)
+            for j, (r, a, keep) in enumerate(build):
+                rows[r] = placement.put(packed[j], store.device)
+                if keep:
+                    cache.attach(a, stamp, bits=rows[r])
+        for r, a in enumerate(lists):
+            if rows[r] is None:
+                rows[r] = rows[first[id(a)]]
+        misses = sum(keep for _r, _a, keep in build)
+        for result, n in (("hit", hits), ("miss", misses),
+                          ("shared", shared),
+                          ("uncached", len(build) - misses)):
+            if n:
+                filter_operand_total.labels("bitmask", result).inc(n)
+        return rows, hits, misses, len(first)
 
     def _shared_operand(self, allow):
         """ONE allow list for the whole batch (None = unfiltered) ->

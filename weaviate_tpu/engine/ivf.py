@@ -51,14 +51,17 @@ import math
 import threading
 import time
 import weakref
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from weaviate_tpu.engine.filter_operands import stable_mask
 from weaviate_tpu.engine.flat import FlatIndex
-from weaviate_tpu.engine.store import (DeviceVectorStore, _next_pow2,
-                                       normalize_allow_mask)
+from weaviate_tpu.engine.store import (AllowSlots, DeviceVectorStore,
+                                       _next_pow2, normalize_allow_mask,
+                                       stack_allow_rows)
 from weaviate_tpu.ops.candidates import (gather_rescore_topk,
                                          masked_candidate_topk)
 from weaviate_tpu.ops.distances import (MASKED_DISTANCE, normalize,
@@ -67,16 +70,62 @@ from weaviate_tpu.ops.kmeans import kmeans_assign, kmeans_fit
 from weaviate_tpu.ops.pallas_kernels import _MASK_WORDS, allow_bits_for_ids
 from weaviate_tpu.ops.topk import topk_smallest
 from weaviate_tpu.runtime import hbm_ledger, kernelscope, placement, tracing
-from weaviate_tpu.runtime.metrics import (ivf_candidate_rows_total,
-                                          ivf_delta_rows, ivf_list_capacity,
-                                          ivf_lists, ivf_live_rows,
+from weaviate_tpu.runtime.metrics import (filter_operand_total,
+                                          ivf_candidate_rows_total,
+                                          ivf_cutoff_programs_total,
+                                          ivf_cutoff_rows_total,
+                                          ivf_delta_rows,
+                                          ivf_filtered_requests_total,
+                                          ivf_list_capacity, ivf_lists,
+                                          ivf_live_rows,
                                           ivf_maintain_seconds,
+                                          ivf_probe_dispatches_total,
                                           ivf_probe_programs_total,
                                           ivf_probed_lists_total,
                                           ivf_queries_total)
 from weaviate_tpu.runtime.transfer import DeviceResultHandle
 
 _SUPPORTED_METRICS = ("l2-squared", "dot", "cosine", "cosine-dot")
+
+#: upstream's ``flatSearchCutoff`` at its default: a filter that allows
+#: fewer live rows than this is answered by an exact scan over them, never
+#: by the probe (0 turns the rule off)
+DEFAULT_FLAT_SEARCH_CUTOFF = 40_000
+
+#: a maintenance tick folds a part-filled delta once no write has come
+#: for this long (the scheduler's base tick). By the clock and not from
+#: one tick to the next: after an import the scheduler's one thread is
+#: held for half a minute by the LSM's flushes and by driftwatch's seal,
+#: and the fold then fell into the first seconds a settled server served
+#: (12 of 15 windows of ``cohere-dynamic-cosine.filtered-c32``, PERF.md)
+TAIL_FOLD_PAUSE_S = 5.0
+
+
+class IVFAllow(NamedTuple):
+    """A dispatch's per-query filters as ``IVFIndex`` routed them
+    (``IVFIndex._bitmask_operand``), every operand already on the device.
+
+    ``exact``: one ``(slots, count, rows)`` a DISTINCT mask under the
+    cutoff: its slot list (``AllowSlots``' form: ascending, then -1, a
+    pow2 bucket), the live rows it allows, and the block's rows that
+    carry it. ``order``: the block's other rows, the probe's, in the
+    order the probe takes them (filtered rows first, then unfiltered
+    and padded ones), followed by the exact route's (they only fill the
+    last chunk); ``n_probe`` of them want the probe's answer and
+    ``n_filtered`` of those carry a filter. ``bits``: the probe rows'
+    packed masks in ``order``, one ``[chunk, W]`` block a chunk of the
+    probe (a raw mask the store packed itself: one ``[1, W]`` or ``[B,
+    W]`` array; None: none of them is filtered). ``delta_allow``: the block's masks over the DELTA
+    buffer's slots, bool ``[B, delta capacity]`` in the block's own row
+    order, None where the delta holds no row (the window's state) or no
+    probe row is filtered: the one operand still made on the host a
+    dispatch, a few thousand bits a row."""
+    exact: tuple
+    order: np.ndarray
+    n_probe: int
+    n_filtered: int
+    bits: tuple | jax.Array | None
+    delta_allow: np.ndarray | None
 
 
 @contextlib.contextmanager
@@ -295,11 +344,84 @@ def _ivf_probe_topk(q, centroids, c_norms, list_vecs, list_valid, list_slots,
     return masked_candidate_topk(d, ids, min(k, nprobe * cap))
 
 
+def _exact_over_rows(q, rows, live, slots, k: int, metric: str):
+    """The exact route's arithmetic over the gathered rows ``[C, d]``
+    (the probe's: float32 rows at HIGHEST, cosine 1 - dot on unit rows,
+    l2 from the rows' own norms), dead and padded positions at
+    ``MASKED_DISTANCE``, exact top-k with ties to the lower position: the
+    slot list ascends, so to the lower slot."""
+    q32 = q.astype(jnp.float32)
+    if metric in ("cosine", "cosine-dot"):
+        q32 = normalize(q32)
+    dots = jnp.einsum(
+        "bd,cd->bc", q32, rows.astype(jnp.float32),
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if rows.dtype == jnp.float32
+                   else jax.lax.Precision.DEFAULT))
+    if metric == "l2-squared":
+        r32 = rows.astype(jnp.float32)
+        d = jnp.maximum(
+            jnp.sum(q32 * q32, axis=-1)[:, None] - 2.0 * dots
+            + jnp.sum(r32 * r32, axis=-1)[None, :], 0.0)
+    elif metric == "dot":
+        d = -dots
+    else:
+        d = 1.0 - dots
+    d = jnp.where(live[None, :], d, MASKED_DISTANCE)
+    ids = jnp.broadcast_to(jnp.where(live, slots, -1)[None, :], d.shape)
+    return masked_candidate_topk(d, ids, min(k, slots.shape[0]))
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric"))
+def _ivf_flat_cutoff_topk(q, slots, slot_map, list_vecs, delta_vecs,
+                          k: int, metric: str):
+    """The exact route under ``flatSearchCutoff``, ONE program a distinct
+    mask: ``slots`` [C] int32 (the mask's allowed live slots ascending,
+    then -1; C a power of two) are looked up in ``slot_map`` [capacity]
+    (a slot's position: ``list * cap + pos`` in the posting lists,
+    ``-2 - delta slot`` in the delta buffer, -1 dead), their rows
+    gathered from where they lie and scored against the whole block
+    ``q`` [B, d]; rows of the block that carry another mask are dropped
+    on the host. O(C * d) bytes whatever the lists hold. ONE variant
+    whether the delta holds rows or not (a second gather that finds
+    nothing when it is empty): a variant a state would be loaded in
+    whatever window first meets the state a fold leaves (my chip runs,
+    PR 51: 24-30 programs loaded in the first two seconds of a window
+    whose warm-up had ended before the delta's tail was folded)."""
+    nlist, cap, _ = list_vecs.shape
+    n = slot_map.shape[0]
+    loc = jnp.where((slots >= 0) & (slots < n),
+                    slot_map[jnp.clip(slots, 0, n - 1)], -1)
+    in_list = loc >= 0
+    lp = jnp.clip(loc, 0, nlist * cap - 1)
+    dp = jnp.clip(-2 - loc, 0, delta_vecs.shape[0] - 1)
+    rows = jnp.where(in_list[:, None], list_vecs[lp // cap, lp % cap],
+                     delta_vecs[dp].astype(list_vecs.dtype))
+    return _exact_over_rows(q, rows, in_list | (loc <= -2), slots, k,
+                            metric)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric"))
+def _ivf_flat_cutoff_topk_rows(q, slots, rows_by_slot, k: int, metric: str):
+    """The exact route where the lists hold codes (residual PQ): the
+    float32 rows lie by SLOT in the rescore tier, so no position is
+    looked up; ``slots`` and the answer as ``_ivf_flat_cutoff_topk``."""
+    n = rows_by_slot.shape[0]
+    live = (slots >= 0) & (slots < n)
+    return _exact_over_rows(q, rows_by_slot[jnp.clip(slots, 0, n - 1)],
+                            live, slots, k, metric)
+
+
 class IVFStore:
     """DeviceVectorStore-compatible store backed by IVF posting lists plus a
     brute-force delta buffer. Slot ids are append-order and stable."""
 
     mesh = None  # single-replica; collection-level sharding distributes IVF
+    # ``search_async`` takes filters an index prepared on the device: a
+    # shared slot list under the cutoff (``AllowSlots``, what
+    # ``gathered_slots`` builds) and a routed dispatch (``IVFAllow``)
+    takes_allow_operands = True
 
     def __init__(self, dim: int, metric: str = "l2-squared",
                  capacity: int = 8192, chunk_size: int = 8192,
@@ -312,7 +434,8 @@ class IVFStore:
                  pq_segments: int | None = None,
                  pq_centroids: int = 16,
                  rescore_limit: int = 16,
-                 retrain_factor: float = 4.0):
+                 retrain_factor: float = 4.0,
+                 flat_search_cutoff: int = DEFAULT_FLAT_SEARCH_CUTOFF):
         if metric not in _SUPPORTED_METRICS:
             raise ValueError(
                 f"ivf supports {_SUPPORTED_METRICS}, not {metric!r}")
@@ -333,6 +456,9 @@ class IVFStore:
         self.train_threshold = train_threshold
         self.delta_threshold = delta_threshold
         self.query_chunk = query_chunk
+        # upstream's flatSearchCutoff: a filter that allows fewer live
+        # rows is answered exactly over them (``gathered_slots``); 0 = off
+        self.flat_search_cutoff = int(flat_search_cutoff)
         # Residual IVF-PQ residency: posting lists hold uint8 codes of
         # x - centroid[assign]; oversampled candidates rescore EXACTLY on
         # device against the _rescore_rows tier. The host f32 mirror
@@ -391,12 +517,21 @@ class IVFStore:
         # zeros, never read), uploaded at the first such search and kept:
         # made a dispatch it was an eager one-op program beside the probe
         self._no_bits = None
-        # writes seen, and as of the last maintenance tick: a tick
-        # folds a part-filled delta only once the writes have paused
-        self._writes = 0
-        self._writes_at_tick = 0
+        # when the last write came (``time.monotonic``): a tick folds a
+        # part-filled delta only once the writes have paused
+        self._last_write_t = 0.0
         # slot -> ("delta", dslot) | ("list", flat_idx)
         self._slot_loc: dict[int, tuple] = {}
+        # the same map as an array over the slots, kept with the dict:
+        # ``list * cap + pos`` in the lists, ``-2 - dslot`` in the delta,
+        # -1 dead. The exact route under the cutoff reads it ON THE
+        # DEVICE (``slot_map``): uploaded again when ``_layout_gen`` has
+        # moved, which every write, fold, retrain and growth of ``cap``
+        # does (slots are stable across a fold, positions are not)
+        self._loc_np = np.full(max(capacity, 1024), -1, np.int32)
+        self._layout_gen = 0
+        self._slot_map = None
+        self._slot_map_gen = -1
         # list tensors (allocated at train time)
         self.centroids = None  # jnp [nlist, d]
         self._centroids_np = None  # host twin (assign/residuals/spill)
@@ -435,6 +570,10 @@ class IVFStore:
             else int(self._rescore_rows.nbytes),
             owner=self._hbm_owner, dtype=jnp.dtype(self.dtype).name)
         hbm_ledger.ledger.set_keyed(
+            self._hbm_keys, "slot_map",
+            0 if self._slot_map is None else int(self._slot_map.nbytes),
+            owner=self._hbm_owner, dtype="int32")
+        hbm_ledger.ledger.set_keyed(
             self._hbm_keys, "host_mirror",
             0 if self._host_rows is None else int(self._host_rows.nbytes),
             owner=self._hbm_owner, dtype="float32", placement="host")
@@ -468,7 +607,7 @@ class IVFStore:
             slots = np.arange(self._count, self._count + len(vectors),
                               dtype=np.int64)
             self._count += len(vectors)
-            self._writes += 1
+            self._last_write_t = time.monotonic()
             self._remember_rows(slots, vectors)
             self._add_to_delta(slots, vectors)
             self._maybe_reorganize()
@@ -520,6 +659,22 @@ class IVFStore:
         for g, d in zip(slots.tolist(), dslots.tolist()):
             self._delta_slots[int(d)] = int(g)
             self._slot_loc[int(g)] = ("delta", int(d))
+        self._set_loc(slots, -2 - np.asarray(dslots, dtype=np.int64))
+
+    def _set_loc(self, slots, loc) -> None:
+        """Positions of ``slots`` in the array twin of ``_slot_loc``
+        (``loc``: an array, or one value for all). Caller holds
+        ``_lock``."""
+        slots = np.asarray(slots, dtype=np.int64)
+        if not len(slots):
+            return
+        top = int(slots.max())
+        if top >= len(self._loc_np):
+            grown = np.full(_next_pow2(top + 1), -1, np.int32)
+            grown[:len(self._loc_np)] = self._loc_np
+            self._loc_np = grown
+        self._loc_np[slots] = loc
+        self._layout_gen += 1
 
     def _punch_hole(self, flat_idx: int):
         """Record a freed list position for hole-first refill. Caller
@@ -536,7 +691,7 @@ class IVFStore:
             vectors = vectors[None, :]
         with self._lock:
             self._count = max(self._count, int(slots.max()) + 1 if len(slots) else 0)
-            self._writes += 1
+            self._last_write_t = time.monotonic()
             self._remember_rows(slots, vectors)
             delta_upd_d, delta_upd_v = [], []
             fresh_s, fresh_v = [], []
@@ -565,8 +720,10 @@ class IVFStore:
     def delete(self, slots) -> None:
         slots = np.atleast_1d(np.asarray(slots, dtype=np.int64))
         with self._lock:
-            self._writes += 1
+            self._last_write_t = time.monotonic()
             clear_flat, delta_del = [], []
+            self._set_loc(slots[(slots >= 0) & (slots < len(self._loc_np))],
+                          -1)
             for s in slots.tolist():
                 loc = self._slot_loc.pop(int(s), None)
                 if loc is None:
@@ -660,7 +817,7 @@ class IVFStore:
             self._publish_gauges()
             self.train_seconds += time.perf_counter() - t0
 
-    def maintain(self, tick: bool = False) -> bool:
+    def maintain(self, tick: bool = False, now: float | None = None) -> bool:
         """Incremental maintenance hook (db/shard.py epoch maintenance):
         fold the delta into lists; RETRAIN only when the corpus outgrew
         its partition (live count >= retrain_factor x live-at-train — the
@@ -673,11 +830,14 @@ class IVFStore:
         they are, a full delta is folded on the write path, and a fold
         at an arbitrary moment of an import would make the lists depend
         on when a tick fell) and folds it at the first tick that finds
-        the writes paused. -> whether work was done or is left for the
-        next tick (the cyclemanager's backoff signal)."""
+        the writes paused: none for ``TAIL_FOLD_PAUSE_S`` by the clock
+        (``now``: ``time.monotonic()``), however long the scheduler's
+        one thread was kept from ticking meanwhile. -> whether work was
+        done or is left for the next tick (the cyclemanager's backoff
+        signal)."""
         with self._lock:
-            moved = self._writes != self._writes_at_tick
-            self._writes_at_tick = self._writes
+            now = time.monotonic() if now is None else now
+            moved = now - self._last_write_t < TAIL_FOLD_PAUSE_S
             if not self.trained:
                 if len(self._slot_loc) >= self.train_threshold:
                     self.train()
@@ -904,10 +1064,12 @@ class IVFStore:
                 self._put(m_buf))
         for s, fi in zip(slots.tolist(), flat_idx.tolist()):
             self._slot_loc[int(s)] = ("list", int(fi))
+        self._set_loc(slots, flat_idx)
         return spilled
 
     def _grow_cap(self):
-        """Double per-list capacity (repack on host — rare, amortized)."""
+        """Double per-list capacity (repack on host — rare, amortized).
+        Caller holds ``_lock``."""
         old_cap = self.list_cap
         new_cap = old_cap * 2
         pad = new_cap - old_cap
@@ -942,6 +1104,9 @@ class IVFStore:
             if loc[0] == "list":
                 l, p = divmod(loc[1], old_cap)
                 self._slot_loc[s] = ("list", l * new_cap + p)
+        listed = self._loc_np >= 0
+        self._loc_np[listed] += (self._loc_np[listed] // old_cap) * pad
+        self._layout_gen += 1
 
     def flush_delta(self):
         """Merge the delta buffer into posting lists (memtable flush) —
@@ -1027,79 +1192,199 @@ class IVFStore:
         return self.search_async(queries, k, allow_mask,
                                  nprobe=nprobe).result()
 
+    def probe_chunk(self) -> int:
+        """Query rows ONE probe program takes. 16 in both served cells;
+        at 768-d a chunk's slabs are 3.2 GB, which the chip's program
+        never holds whole (`memory_peak_bytes` 2.5 GB beside 1.6 GB of
+        lists: PERF.md, PR 51), so no rule cuts it by bytes."""
+        return max(1, self.query_chunk)
+
+    def exact_route(self, m_allowed: int) -> bool:
+        """The cutoff rule (upstream's ``flatSearchCutoff``): a filter
+        that allows fewer than ``flat_search_cutoff`` live rows of a
+        TRAINED store is answered exactly over those rows, never by the
+        probe. An untrained store scans its delta buffer, which is exact
+        already; a cutoff of 0 turns the rule off."""
+        return (m_allowed < self.flat_search_cutoff and self.trained
+                and (self.list_vecs is not None
+                     or self._rescore_rows is not None))
+
+    def gathered_slots(self, slot_mask: np.ndarray) -> AllowSlots:
+        """ONE filter's operand by the cutoff rule, as the flat store's
+        method of the same name gives its own cutover's: under the
+        cutoff the allowed slots ascending in a pow2 bucket on the
+        device (then -1), for the exact route; else ``slots`` None and
+        the masked probe serves it. Called with ``_lock`` held, or by an
+        index that holds its own over every write."""
+        m_allowed = int(np.count_nonzero(slot_mask))
+        if not self.exact_route(m_allowed):
+            return AllowSlots(None, m_allowed)
+        slot_buf = np.full(1 << max(7, (m_allowed - 1).bit_length()), -1,
+                           dtype=np.int32)
+        slot_buf[:m_allowed] = np.flatnonzero(slot_mask)
+        return AllowSlots(self._put(slot_buf), m_allowed)
+
+    def slot_map(self):
+        """``_loc_np`` over the slot space on the device, uploaded again
+        where a write, a fold, a retrain or a growth of ``cap`` has moved
+        a row since (``_layout_gen``). Caller holds ``_lock``."""
+        n = self.capacity
+        if (self._slot_map is None or self._slot_map_gen != self._layout_gen
+                or self._slot_map.shape[0] != n):
+            buf = np.full(n, -1, np.int32)
+            w = min(n, len(self._loc_np))
+            buf[:w] = self._loc_np[:w]
+            self._slot_map = self._put(buf)
+            self._slot_map_gen = self._layout_gen
+            hbm_ledger.ledger.set_keyed(
+                self._hbm_keys, "slot_map", int(self._slot_map.nbytes),
+                owner=self._hbm_owner, dtype="int32")
+        return self._slot_map
+
+    def delta_rows(self):
+        """(delta slots, their global slots) of the rows the delta
+        buffer holds. Caller holds ``_lock``."""
+        gmap = self._delta_gmap[:self.delta.capacity]
+        ds = np.flatnonzero(gmap >= 0)
+        return ds, gmap[ds]
+
+    def _routed(self, allow_mask, b: int) -> IVFAllow:
+        """What ``search_async`` was handed, in ``IVFAllow``'s terms. A
+        raw mask is routed here: ONE mask for the block by the cutoff
+        rule (``gathered_slots``), a ``[B, capacity]`` block of them to
+        the masked probe, packed on the host a dispatch as it always was
+        (an index that prepared its operands hands an ``IVFAllow`` or an
+        ``AllowSlots`` and nothing is packed). Caller holds ``_lock``."""
+        rows = np.arange(b)
+        if isinstance(allow_mask, IVFAllow):
+            return allow_mask
+        if allow_mask is None:
+            return IVFAllow((), rows, b, 0, None, None)
+        if not isinstance(allow_mask, (AllowSlots, np.ndarray)):
+            raise TypeError(
+                f"an IVF store takes a bool mask, AllowSlots or IVFAllow, "
+                f"not {type(allow_mask).__name__}")
+        if not isinstance(allow_mask, AllowSlots) and allow_mask.ndim == 1:
+            op = self.gathered_slots(allow_mask)
+            if op.slots is not None:
+                allow_mask = op
+        if isinstance(allow_mask, AllowSlots):
+            return IVFAllow(((allow_mask.slots, allow_mask.count, rows),),
+                            rows, 0, 0, None, None)
+        from weaviate_tpu.ops.pallas_kernels import (mask_pad_cols,
+                                                     pack_allow_bitmask)
+
+        bits = self._put(pack_allow_bitmask(
+            allow_mask, mask_pad_cols(self.capacity)))
+        hbm_ledger.ledger.track("allow_bitmask", bits, **self._hbm_owner)
+        return IVFAllow((), rows, b, b, bits,
+                        self._delta_allow(allow_mask, b)
+                        if self.delta.live_count() > 0 else None)
+
     def search_async(self, queries: np.ndarray, k: int,
                      allow_mask: np.ndarray | None = None,
                      nprobe: int | None = None) -> DeviceResultHandle:
-        """Dispatch-only twin of ``search``: both legs — the exact delta
-        scan (``epoch_scan``, ids remapped to global ON DEVICE) and the
-        probe (+ residual-PQ exact rescore via the candidate plane) —
-        launch under ``_lock`` and merge on device; results stay
-        device-resident in the returned handle. ``allow_mask`` takes the
-        DeviceVectorStore forms: [cap] bool shared, or [B, cap] bool
-        per-query (packed once to block-strided ``allow_bits`` and folded
-        per candidate inside the probe — B differently-filtered requests
-        run as ONE device program, which is what lets the QueryBatcher
-        coalesce filtered IVF traffic)."""
+        """Dispatch-only twin of ``search``: every leg launches under
+        ``_lock`` and the results stay device-resident in the returned
+        handle. ``allow_mask`` takes the DeviceVectorStore forms, [cap]
+        bool shared or [B, cap] bool per-query, and what an index
+        prepared on the device (``AllowSlots``, ``IVFAllow``).
+
+        Two routes (``IVFAllow``): rows whose filter allows fewer live
+        rows than ``flat_search_cutoff`` are answered EXACTLY, one
+        program a distinct mask over the whole block
+        (``_ivf_flat_cutoff_topk``: the allowed rows gathered by slot
+        from the lists and the delta); the others by the delta buffer's
+        exact scan (``epoch_scan``, ids remapped to global on the
+        device) merged with the probe (+ residual-PQ exact rescore via
+        the candidate plane), per-query ``allow_bits`` folded a
+        candidate inside the probe, in chunks of ``probe_chunk`` rows
+        with the rows that want the probe's answer FIRST, so a block of
+        32 whose half took the exact route probes once, not twice. The
+        legs' answers are joined a row on the host (``_finish``)."""
         queries = np.asarray(queries, dtype=np.float32)
         squeeze = queries.ndim == 1
         if squeeze:
             queries = queries[None, :]
         b = len(queries)
-        allow_mask = normalize_allow_mask(allow_mask, b)
+        if not isinstance(allow_mask, IVFAllow):
+            allow_mask = normalize_allow_mask(allow_mask, b)
         np_probe, gather = 0, ""
         with tracing.span("ivf.search", queries=b, k=k,
                           filtered=allow_mask is not None) as sp, \
                 self._lock:
+            plan = self._routed(allow_mask, b)
+            exact_out = []            # (dists, slots) a distinct mask
             delta_leg = None          # (dists, slots) where the delta holds rows
             outs_d, outs_i = [], []   # the probe's, a chunk of queries each
-            if self.delta.live_count() > 0:
+            delta_live = self.delta.live_count() > 0
+            metric = self.metric
+            if plan.exact:
+                gathered = sum(count for _s, count, _r in plan.exact)
+                with tracing.span("ivf.flat_cutoff", masks=len(plan.exact),
+                                  rows=gathered, queries=b,
+                                  with_delta=delta_live):
+                    q_all = self._put(queries)
+                    if delta_live:
+                        self.delta.flush_staged()
+                    for slots, _count, _rows in plan.exact:
+                        if self.quantization:
+                            exact_out.append(_ivf_flat_cutoff_topk_rows(
+                                q_all, slots, self._rescore_rows, k,
+                                metric))
+                        else:
+                            exact_out.append(_ivf_flat_cutoff_topk(
+                                q_all, slots, self.slot_map(),
+                                self.list_vecs, self.delta.vectors, k,
+                                metric))
+                ivf_cutoff_rows_total.inc(gathered)
+                ivf_cutoff_programs_total.inc(len(plan.exact))
+                ivf_filtered_requests_total.labels("flat_cutoff").inc(
+                    sum(len(rows) for _s, _c, rows in plan.exact))
+            if plan.n_filtered:
+                ivf_filtered_requests_total.labels("probe").inc(
+                    plan.n_filtered)
+            probes = (self.trained and self._fill is not None
+                      and int(self._fill.sum()) > 0)
+            lists_probed = min((nprobe or self._effective_nprobe()),
+                               self.nlist) if probes else 0
+            # the rows the delta's scan and the probe take: those that
+            # want their answer first, then whatever fills the last chunk
+            chunk = self.probe_chunk() if probes else max(b, 1)
+            m = min(b, -(-plan.n_probe // chunk) * chunk)
+            np_probe = lists_probed if m else 0
+            qp = queries[plan.order[:m]]
+            if m and delta_live:
+                d_allow = plan.delta_allow
+                if d_allow is not None and d_allow.ndim == 2:
+                    d_allow = d_allow[plan.order[:m]]
                 dd, di = self.delta.epoch_scan(
-                    queries, min(k, self.delta.capacity),
-                    self._delta_allow(allow_mask, b))
+                    qp, min(k, self.delta.capacity), d_allow)
                 gd = self._put(self._delta_gmap)
                 di = jnp.where(di >= 0,
                                gd[jnp.clip(di, 0, gd.shape[0] - 1)], -1)
                 delta_leg = (jnp.where(di >= 0, dd, MASKED_DISTANCE),
                              di.astype(jnp.int32))
-            if (self.trained and self._fill is not None
-                    and int(self._fill.sum()) > 0):
-                np_probe = min((nprobe or self._effective_nprobe()),
-                               self.nlist)
-                use_allow = allow_mask is not None
-                if use_allow:
-                    from weaviate_tpu.ops.pallas_kernels import (
-                        mask_pad_cols, pack_allow_bitmask)
-
-                    bits = self._put(pack_allow_bitmask(
-                        allow_mask, mask_pad_cols(self.capacity)))
-                    hbm_ledger.ledger.track("allow_bitmask", bits,
-                                            **self._hbm_owner)
-                else:
-                    if self._no_bits is None:
-                        self._no_bits = self._put(
-                            np.zeros((1, _MASK_WORDS), np.uint32))
-                    bits = self._no_bits
+            if m and probes:
+                use_allow = plan.bits is not None
+                if not use_allow and self._no_bits is None:
+                    self._no_bits = self._put(
+                        np.zeros((1, _MASK_WORDS), np.uint32))
                 k_cand = k * self.rescore_limit if self.quantization else k
                 k_eff = min(k_cand, np_probe * self.list_cap)
                 # how the probe reads its lists: whole ``[cap, d]`` slabs
                 # of rows, or slabs of codes and then the survivors' rows
                 gather = "codes" if self.quantization else "slab"
-                # EXPLAIN: the probe plan, host ints only (no device
-                # reads — G1 stays empty); a no-op unless a sink is
-                # installed for this dispatch
-                kernelscope.explain_note(
-                    "ivf", nprobe=np_probe, nlist=self.nlist,
-                    lists_frac=(round(np_probe / self.nlist, 6)
-                                if self.nlist else 0.0),
-                    candidates=k_eff,
-                    rescored=(k_eff if self.quantization else 0),
-                    quantized=bool(self.quantization),
-                    filtered=bool(use_allow), queries=b, k=k,
-                    delta_leg=delta_leg is not None, gather=gather)
-                for s in range(0, b, self.query_chunk):
-                    q_dev = self._put(queries[s:s + self.query_chunk])
-                    bch = (bits if bits.shape[0] == 1
-                           else bits[s:s + self.query_chunk])
+                for n, s in enumerate(range(0, m, chunk)):
+                    q_dev = self._put(qp[s:s + chunk])
+                    if not use_allow:
+                        bch = self._no_bits
+                    elif isinstance(plan.bits, tuple):
+                        bch = plan.bits[n]    # stacked a chunk already
+                    elif plan.bits.shape[0] == 1:
+                        bch = plan.bits
+                    else:
+                        bch = plan.bits[s:s + chunk]
                     if self.quantization:
                         _, cand = _ivf_probe_topk_pq(
                             q_dev, self.centroids, self._c_norms,
@@ -1120,17 +1405,39 @@ class IVFStore:
                             np_probe, self.metric, use_allow)
                     outs_d.append(qd)
                     outs_i.append(qs_)
-                ivf_queries_total.inc(b)
-                ivf_probed_lists_total.inc(b * np_probe)
-                ivf_candidate_rows_total.inc(b * np_probe * self.list_cap)
+                # EXPLAIN: the probe plan, host ints only (no device
+                # reads — G1 stays empty); a no-op unless a sink is
+                # installed for this dispatch
+                kernelscope.explain_note(
+                    "ivf", nprobe=np_probe, nlist=self.nlist,
+                    lists_frac=(round(np_probe / self.nlist, 6)
+                                if self.nlist else 0.0),
+                    candidates=k_eff,
+                    rescored=(k_eff if self.quantization else 0),
+                    quantized=bool(self.quantization),
+                    filtered=bool(use_allow), queries=m, k=k,
+                    delta_leg=delta_leg is not None, gather=gather)
+                ivf_queries_total.inc(m)
+                ivf_probed_lists_total.inc(m * np_probe)
+                ivf_candidate_rows_total.inc(m * np_probe * self.list_cap)
                 ivf_probe_programs_total.inc(len(outs_d))
+                ivf_probe_dispatches_total.inc()
+            route = ("both" if plan.exact and plan.n_filtered
+                     else "flat_cutoff" if plan.exact
+                     else "probe" if plan.n_filtered else "")
             sp.set(nprobe=np_probe, nlist=self.nlist,
                    list_cap=self.list_cap,
                    delta_rows=len(self._delta_slots),
                    candidates=np_probe * self.list_cap, gather=gather)
+            if route:
+                sp.set(route=route, cutoff=self.flat_search_cutoff,
+                       allowed=sum(c for _s, c, _r in plan.exact))
             kernelscope.explain_note(
-                "ivf", merge_legs=(delta_leg is not None) + bool(outs_d))
-            if delta_leg is None and not outs_d:
+                "ivf", merge_legs=(delta_leg is not None) + bool(outs_d),
+                route=route or "unfiltered",
+                cutoff=self.flat_search_cutoff,
+                exact_masks=len(plan.exact))
+            if delta_leg is None and not outs_d and not exact_out:
                 d_e = np.full((b, k), MASKED_DISTANCE, np.float32)
                 i_e = np.full((b, k), -1, np.int64)
                 return DeviceResultHandle.ready(
@@ -1151,22 +1458,39 @@ class IVFStore:
                                 for pair in zip(delta_leg, probe_leg))
                 arrays = topk_smallest(cat_d, cat_i,
                                        min(k, cat_d.shape[1]))
+            n_scan = len(arrays)      # the delta's and the probe's arrays
+            arrays = tuple(arrays) + tuple(
+                a for pair in exact_out for a in pair)
 
-        def _finish(*host, _k=k, _squeeze=squeeze):
-            d_np, i_np = (host if len(host) == 2 else
-                          (np.concatenate(host[0::2]),
-                           np.concatenate(host[1::2])))
-            d_np = np.asarray(d_np, dtype=np.float32)
-            i_np = np.asarray(i_np, dtype=np.int64)
-            i_np = np.where(d_np >= MASKED_DISTANCE, -1, i_np)
-            if d_np.shape[1] < _k:  # pad to k like the flat store contract
-                pad = _k - d_np.shape[1]
-                d_np = np.pad(d_np, ((0, 0), (0, pad)),
-                              constant_values=MASKED_DISTANCE)
-                i_np = np.pad(i_np, ((0, 0), (0, pad)), constant_values=-1)
+        # what the host's half needs of the plan: which rows each leg
+        # answered (the operands themselves stay with the dispatch)
+        probe_rows = plan.order[:plan.n_probe]
+        exact_rows = [rows for _slots, _count, rows in plan.exact]
+
+        def _finish(*host, _k=k, _squeeze=squeeze, _n_scan=n_scan):
+            # a row each from the leg that answered it, padded to k like
+            # the flat store's contract
+            d_out = np.full((b, _k), MASKED_DISTANCE, np.float32)
+            i_out = np.full((b, _k), -1, np.int64)
+
+            def take(rows, d_np, i_np, at):
+                kk = min(_k, d_np.shape[1])
+                d_out[rows, :kk] = d_np[at, :kk]
+                i_out[rows, :kk] = i_np[at, :kk]
+
+            if _n_scan:
+                scan = host[:_n_scan]
+                d_np, i_np = (scan if _n_scan == 2 else
+                              (np.concatenate(scan[0::2]),
+                               np.concatenate(scan[1::2])))
+                take(probe_rows, d_np, i_np, slice(len(probe_rows)))
+            for g, rows in enumerate(exact_rows):
+                take(rows, host[_n_scan + 2 * g], host[_n_scan + 2 * g + 1],
+                     rows)
+            i_out = np.where(d_out >= MASKED_DISTANCE, -1, i_out)
             if _squeeze:
-                return d_np[0], i_np[0]
-            return d_np, i_np
+                return d_out[0], i_out[0]
+            return d_out, i_out
 
         lists_frac = (np_probe / self.nlist) if self.nlist else 0.0
         return DeviceResultHandle(
@@ -1234,6 +1558,7 @@ class IVFStore:
                 "pq_centroids": self.pq_centroids,
                 "rescore_limit": self.rescore_limit,
                 "retrain_factor": self.retrain_factor,
+                "flat_search_cutoff": self.flat_search_cutoff,
                 "pq_codebook": (np.asarray(self.codebook.centroids)
                                 if self.codebook is not None else None),
             }
@@ -1262,7 +1587,9 @@ class IVFStore:
                     pq_segments=snap.get("pq_segments"),
                     pq_centroids=snap.get("pq_centroids", 16),
                     rescore_limit=snap.get("rescore_limit", 16),
-                    retrain_factor=snap.get("retrain_factor", 4.0))
+                    retrain_factor=snap.get("retrain_factor", 4.0),
+                    flat_search_cutoff=snap.get(
+                        "flat_search_cutoff", DEFAULT_FLAT_SEARCH_CUTOFF))
         slots = np.asarray(snap["live_slots"], dtype=np.int64)
         vecs = np.asarray(snap["live_vectors"], dtype=np.float32)
         store._count = snap["count"]
@@ -1330,18 +1657,162 @@ class IVFIndex(FlatIndex):
         super().__init__(dim=dim, metric=metric, capacity=capacity,
                          chunk_size=chunk_size, store=store)
 
+    # -- upstream's flatSearchCutoff ------------------------------------------
+
+    @property
+    def flat_search_cutoff(self) -> int:
+        """A filter that allows fewer live rows than this is answered by
+        an exact scan over them, never by the probe (0: off). The rule is
+        applied a REQUEST, from its own mask and the slot table alone
+        (``IVFStore.gathered_slots``), so an answer does not depend on
+        what the request was coalesced with."""
+        return self.store.flat_search_cutoff
+
+    @flat_search_cutoff.setter
+    def flat_search_cutoff(self, cutoff: int) -> None:
+        with self._lock:
+            self.store.flat_search_cutoff = int(cutoff)
+            self._slots_moved()   # the kept operands were routed by the old one
+
+    def _exact_operand(self, allow, cache, stamp, translated: dict):
+        """One DISTINCT allow list's route -> (``AllowSlots`` for the
+        exact route or None for the probe, where the operand came from).
+        A mask that cannot change keeps its route with its operand: a
+        slot list under the cutoff (kept here), a packed row over it
+        (kept by ``_packed_rows``, which finds the slot mask made here
+        in ``translated``). Caller holds ``_lock``."""
+        keep = stable_mask(allow)
+        e = cache.get(allow, stamp) if keep else None
+        if e is not None and e.slots is not None:
+            return AllowSlots(e.slots, e.slot_count), "hit"
+        if e is not None and e.bits is not None:
+            return None, ""
+        slot_mask = self._allow_mask(allow)
+        op = self.store.gathered_slots(slot_mask)
+        if op.slots is None:
+            translated[id(allow)] = slot_mask
+            return None, ""
+        if keep:
+            cache.attach(allow, stamp, slots=op.slots, slot_count=op.count)
+        return op, "miss" if keep else "uncached"
+
+    def _bitmask_operand(self, lists) -> IVFAllow:
+        """Per-query allow lists (None = unfiltered) -> the dispatch as
+        the store takes it, every operand on the device. Caller holds
+        ``_lock``; the ``store.mask_pack`` span is this whole step.
+
+        Each DISTINCT mask is routed once by the cutoff rule
+        (``_exact_operand``): under it, its rows share ONE slot list and
+        ONE exact program; the others are the probe's, their packed rows
+        looked up or built as a flat index does (``_packed_rows``) and
+        stacked a CHUNK of the probe, in the order the probe takes them.
+        A dispatch whose masks are all known translates, packs and
+        uploads nothing (but the delta buffer's few thousand bits a row
+        while it holds rows: ``_delta_block``)."""
+        store = self.store
+        b = len(lists)
+        stamp = (self._slot_gen, store.capacity)
+        cache = self._operand_cache()
+        with tracing.span("store.mask_pack", stage="mask_pack",
+                          queries=b) as sp:
+            routes: dict[int, tuple | None] = {}   # id(mask) -> exact entry
+            translated: dict[int, np.ndarray] = {}
+            filtered, plain = [], []               # the probe's rows
+            counts = {"hit": 0, "miss": 0, "shared": 0, "uncached": 0}
+            for r, a in enumerate(lists):
+                if a is None:
+                    plain.append(r)
+                    continue
+                if id(a) not in routes:
+                    op, result = self._exact_operand(a, cache, stamp,
+                                                     translated)
+                    routes[id(a)] = None if op is None else (
+                        op.slots, op.count, [r])
+                    if op is not None:
+                        counts[result] += 1
+                    else:
+                        filtered.append(r)
+                elif routes[id(a)] is None:
+                    filtered.append(r)
+                else:
+                    routes[id(a)][2].append(r)
+                    counts["shared"] += 1
+            for result, n in counts.items():
+                if n:
+                    filter_operand_total.labels("gathered", result).inc(n)
+            exact = tuple((slots, count, np.asarray(rows))
+                          for slots, count, rows in
+                          (e for e in routes.values() if e is not None))
+            probe = filtered + plain
+            probing = np.zeros(b, dtype=bool)
+            probing[probe] = True
+            order = np.concatenate([np.asarray(probe, dtype=np.int64),
+                                    np.flatnonzero(~probing)])
+            bits = delta_allow = None
+            hits = misses = 0
+            if probe:
+                # the probe of a filtered dispatch always takes bits, the
+                # all-ones row for an unfiltered or padded row: ONE probe
+                # variant a block size, also where every filtered row of
+                # the drain went the exact route (met once in a few
+                # hundred dispatches, so no warm-up would have loaded a
+                # second one)
+                chunk = store.probe_chunk()
+                m = min(b, -(-len(probe) // chunk) * chunk)
+                rows, hits, misses, _ = self._packed_rows(
+                    [lists[r] if probing[r] else None for r in order[:m]],
+                    translated)
+                bits = tuple(stack_allow_rows(*rows[s:s + chunk])
+                             for s in range(0, m, chunk))
+                for block in bits:
+                    hbm_ledger.ledger.track("allow_bitmask", block,
+                                            **cache.owner)
+                if store.delta.live_count() > 0:
+                    delta_allow = self._delta_block(lists, filtered)
+            sp.set(hits=hits + counts["hit"],
+                   misses=misses + counts["miss"], distinct=len(routes),
+                   exact_masks=len(exact))
+        return IVFAllow(exact, order, len(probe), len(filtered), bits,
+                        delta_allow)
+
+    def _delta_block(self, lists, filtered) -> np.ndarray:
+        """The probe rows' masks over the DELTA buffer's slots, bool
+        ``[B, delta capacity]``: each distinct mask looked up at the doc
+        ids of the rows the delta holds (a few thousand at most), all
+        ones for the other rows (the store folds its live flags in).
+        Caller holds ``_lock``."""
+        store = self.store
+        ds, gslots = store.delta_rows()
+        docs = self._slot_to_id_safe(gslots)
+        block = np.ones((len(lists), store.delta.capacity), dtype=bool)
+        seen: dict[int, np.ndarray] = {}
+        for r in filtered:
+            a = lists[r]
+            col = seen.get(id(a))
+            if col is None:
+                if a.dtype == np.bool_:
+                    col = ((docs >= 0) & (docs < len(a))
+                           & a[np.clip(docs, 0, max(len(a) - 1, 0))]
+                           if len(a) else np.zeros(len(docs), dtype=bool))
+                else:
+                    col = np.isin(docs, a)
+                seen[id(a)] = col
+            block[r] = False
+            block[r, ds] = col
+        return block
+
     def train(self, nlist: int | None = None):
         """Force coarse training now (normally automatic at threshold)."""
         with self._lock:
             self.store.train(force_nlist=nlist)
 
-    def maintain(self, tick: bool = False) -> bool:
+    def maintain(self, tick: bool = False, now: float | None = None) -> bool:
         """Incremental maintenance (db/shard.py epoch_maintenance): the
         delta folded, a retrain only past the drift gate — never a
-        compaction-triggered full rebuild. ``tick`` and the result as
-        ``IVFStore.maintain``."""
+        compaction-triggered full rebuild. ``tick``, ``now`` and the
+        result as ``IVFStore.maintain``."""
         with self._lock:
-            return self.store.maintain(tick=tick)
+            return self.store.maintain(tick=tick, now=now)
 
     def compress(self, quantization: str = "pq", **quant_kwargs) -> None:
         """Runtime switch to residual-PQ residency: fit a codebook on the

@@ -943,6 +943,29 @@ ivf_probe_programs_total = registry.counter(
     "Probe programs launched: a dispatch's block is cut into chunks of "
     "at most query_chunk rows, one program each; one increment a "
     "dispatch")
+ivf_probe_dispatches_total = registry.counter(
+    "weaviate_tpu_ivf_probe_dispatches_total",
+    "Dispatches that probed posting lists (a filtered dispatch whose "
+    "rows all took the exact route under flatSearchCutoff probes none), "
+    "one increment a dispatch that probed")
+ivf_filtered_requests_total = registry.counter(
+    "weaviate_tpu_ivf_filtered_requests_total",
+    "Filtered query rows an IVF index answered, by the route its rule "
+    "took (engine/ivf.py): flat_cutoff = the allow list holds fewer live "
+    "rows than flatSearchCutoff and the answer is the EXACT top-k over "
+    "them (gathered by slot from the posting lists and the delta); probe "
+    "= the masked probe. One increment a route a dispatch", ("route",))
+ivf_cutoff_rows_total = registry.counter(
+    "weaviate_tpu_ivf_cutoff_rows_total",
+    "Rows the exact route under flatSearchCutoff gathered and scored: "
+    "the allowed live rows of each distinct mask a dispatch answered "
+    "exactly (one program a mask, whatever the rows that carry it), one "
+    "increment a dispatch that took the route")
+ivf_cutoff_programs_total = registry.counter(
+    "weaviate_tpu_ivf_cutoff_programs_total",
+    "Exact-route programs launched (jit__ivf_flat_cutoff_topk): one a "
+    "distinct mask under flatSearchCutoff a dispatch; one increment a "
+    "dispatch that took the route")
 ivf_lists = registry.gauge(
     "weaviate_tpu_ivf_lists",
     "Posting lists of a trained IVF index (set at train and flush)",

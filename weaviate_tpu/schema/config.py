@@ -98,6 +98,11 @@ class VectorIndexConfig:
     max_connections: int = 32
     # dynamic index upgrade threshold (dynamic/index.go:348)
     flat_to_ann_threshold: int = 10_000
+    # upstream's hnsw.flatSearchCutoff: a filter that allows fewer rows
+    # than this is answered by an exact scan over them, the ANN index
+    # bypassed (0: never). Read by the graph index and by the IVF index
+    # a dynamic class upgrades into (engine/ivf.py)
+    flat_search_cutoff: int = 40_000
     # ivf
     ivf_nlist: int = 0  # 0 = auto
     ivf_nprobe: int = 0  # 0 = auto
@@ -126,6 +131,12 @@ class VectorIndexConfig:
             raise ValueError(
                 f"pq trainingLimit must be an int >= centroids "
                 f"({self.pq_centroids}), got {self.pq_training_limit!r}")
+        if (not isinstance(self.flat_search_cutoff, int)
+                or isinstance(self.flat_search_cutoff, bool)
+                or self.flat_search_cutoff < 0):
+            raise ValueError(
+                f"flatSearchCutoff must be an int >= 0, got "
+                f"{self.flat_search_cutoff!r}")
         if (not isinstance(self.sq_training_limit, int)
                 or isinstance(self.sq_training_limit, bool)
                 or self.sq_training_limit < 1):
