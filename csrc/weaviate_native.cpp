@@ -1070,3 +1070,570 @@ int64_t wn_varint_encode_many(const uint64_t* vals, const int64_t* offs,
 }
 
 }  // extern "C"
+
+// ---- Search reply encoder -------------------------------------------------
+// The reply of a plain gRPC Search, from the stored frames of its results
+// to the bytes of a weaviate.v1.SearchReply in ONE call: msgpack in,
+// protobuf wire format out, no Python object a result. It writes what
+// api/grpc/server.py ``_fill_result`` + ``_to_value`` build (its fallback
+// and the oracle tests/test_reply_encoder.py holds it to):
+//   SearchReply   { took=1 f32, results=2 }
+//   SearchResult  { properties=1, metadata=2 }
+//   PropertiesResult { target_collection=3, non_ref_props=11 {fields=1} }
+//   MetadataResult   { id=1, creation_time_unix=3 (+4), last_update=5 (+6),
+//                      distance=7 (+8), certainty=9 (+10), score=11 (+12),
+//                      vector_bytes=19, vectors=23 {name=1, vector_bytes=3} }
+// Whatever it does not write (a map or bin value, a number under a date, a
+// list of mixed kinds, a time past int64) it DECLINES (-1), and a frame it
+// cannot walk is refused (-2): either way the caller answers the whole
+// request by the Python path, which answers or raises as it always did.
+// Every read is bounds-checked: the frames are on-disk input.
+
+namespace {
+
+typedef std::vector<uint8_t> Out;
+
+inline void pb_varint(Out& o, uint64_t v) {
+    while (v >= 0x80) { o.push_back((uint8_t)(v | 0x80)); v >>= 7; }
+    o.push_back((uint8_t)v);
+}
+
+inline void pb_bytes(Out& o, uint32_t tag, const uint8_t* s, size_t n) {
+    pb_varint(o, tag);
+    pb_varint(o, n);
+    o.insert(o.end(), s, s + n);
+}
+
+inline void pb_f32(Out& o, uint8_t tag, float f) {
+    uint8_t b[4];
+    memcpy(b, &f, 4);
+    o.push_back(tag);
+    o.insert(o.end(), b, b + 4);
+}
+
+// a length-delimited sub-message: one byte is kept for its length and
+// the body is moved up where it needs more
+inline size_t pb_open(Out& o, uint32_t tag) {
+    pb_varint(o, tag);
+    o.push_back(0);
+    return o.size();
+}
+
+inline void pb_close(Out& o, size_t body) {
+    uint64_t len = o.size() - body;
+    if (len < 0x80) { o[body - 1] = (uint8_t)len; return; }
+    uint8_t v[10];
+    int n = 0;
+    while (len >= 0x80) { v[n++] = (uint8_t)(len | 0x80); len >>= 7; }
+    v[n++] = (uint8_t)len;
+    o[body - 1] = v[0];
+    o.insert(o.begin() + body, v + 1, v + n);
+}
+
+// strict UTF-8, as Python's decoder: no overlong form, no surrogate,
+// nothing past U+10FFFF
+bool utf8_ok(const uint8_t* s, size_t n) {
+    size_t i = 0;
+    while (i < n) {
+        uint8_t c = s[i];
+        if (c < 0x80) { ++i; continue; }
+        size_t need;
+        uint32_t cp;
+        if (c >= 0xC2 && c <= 0xDF) { need = 1; cp = c & 0x1F; }
+        else if (c >= 0xE0 && c <= 0xEF) { need = 2; cp = c & 0x0F; }
+        else if (c >= 0xF0 && c <= 0xF4) { need = 3; cp = c & 0x07; }
+        else return false;
+        if (n - i <= need) return false;
+        for (size_t j = 1; j <= need; ++j) {
+            uint8_t d = s[i + j];
+            if ((d & 0xC0) != 0x80) return false;
+            cp = (cp << 6) | (d & 0x3F);
+        }
+        if (need == 2 && (cp < 0x800 || (cp >= 0xD800 && cp <= 0xDFFF)))
+            return false;
+        if (need == 3 && (cp < 0x10000 || cp > 0x10FFFF)) return false;
+        i += need + 1;
+    }
+    return true;
+}
+
+// -- msgpack reader (every encoding; what is built from it is decided above)
+enum { MP_NIL, MP_BOOL, MP_UINT, MP_NINT, MP_FLOAT, MP_STR, MP_BIN, MP_ARR,
+       MP_MAP, MP_EXT };
+
+struct MpTok {
+    int type;
+    uint64_t u;        // MP_UINT; MP_BOOL (0 / 1); MP_ARR / MP_MAP count
+    int64_t i;         // MP_NINT (negative)
+    double f;          // MP_FLOAT
+    const uint8_t* s;  // MP_STR / MP_BIN / MP_EXT payload
+    uint32_t len;
+};
+
+struct MpReader {
+    const uint8_t* p;
+    const uint8_t* end;
+
+    bool take(size_t n, const uint8_t** at) {
+        if ((size_t)(end - p) < n) return false;
+        *at = p;
+        p += n;
+        return true;
+    }
+    bool be(size_t n, uint64_t* v) {
+        const uint8_t* at;
+        if (!take(n, &at)) return false;
+        uint64_t x = 0;
+        for (size_t k = 0; k < n; ++k) x = (x << 8) | at[k];
+        *v = x;
+        return true;
+    }
+    // one token: a scalar whole, a str / bin / ext with its payload, an
+    // array / map as its count with the reader at its first element
+    bool next(MpTok* t) {
+        const uint8_t* at;
+        if (!take(1, &at)) return false;
+        uint8_t c = *at;
+        uint64_t v = 0;
+        if (c <= 0x7f) { t->type = MP_UINT; t->u = c; return true; }
+        if (c >= 0xe0) { t->type = MP_NINT; t->i = (int8_t)c; return true; }
+        if (c >= 0xa0 && c <= 0xbf) {
+            t->type = MP_STR; t->len = c & 0x1f;
+            return take(t->len, &t->s);
+        }
+        if (c >= 0x90 && c <= 0x9f) { t->type = MP_ARR; t->u = c & 0x0f; return true; }
+        if (c >= 0x80 && c <= 0x8f) { t->type = MP_MAP; t->u = c & 0x0f; return true; }
+        switch (c) {
+        case 0xc0: t->type = MP_NIL; return true;
+        case 0xc2: t->type = MP_BOOL; t->u = 0; return true;
+        case 0xc3: t->type = MP_BOOL; t->u = 1; return true;
+        case 0xc4: case 0xc5: case 0xc6:
+            if (!be((size_t)1 << (c - 0xc4), &v)) return false;
+            t->type = MP_BIN; t->len = (uint32_t)v;
+            return take(t->len, &t->s);
+        case 0xc7: case 0xc8: case 0xc9:
+            if (!be((size_t)1 << (c - 0xc7), &v)) return false;
+            t->type = MP_EXT; t->len = (uint32_t)v;
+            return take((size_t)t->len + 1, &t->s);
+        case 0xca: {
+            if (!be(4, &v)) return false;
+            uint32_t b = (uint32_t)v; float f;
+            memcpy(&f, &b, 4);
+            t->type = MP_FLOAT; t->f = f; return true;
+        }
+        case 0xcb:
+            if (!be(8, &v)) return false;
+            memcpy(&t->f, &v, 8);
+            t->type = MP_FLOAT; return true;
+        case 0xcc: case 0xcd: case 0xce: case 0xcf:
+            if (!be((size_t)1 << (c - 0xcc), &v)) return false;
+            t->type = MP_UINT; t->u = v; return true;
+        case 0xd0: case 0xd1: case 0xd2: case 0xd3: {
+            size_t n = (size_t)1 << (c - 0xd0);
+            if (!be(n, &v)) return false;
+            int64_t x = n == 1 ? (int64_t)(int8_t)v
+                      : n == 2 ? (int64_t)(int16_t)v
+                      : n == 4 ? (int64_t)(int32_t)v : (int64_t)v;
+            if (x >= 0) { t->type = MP_UINT; t->u = (uint64_t)x; }
+            else { t->type = MP_NINT; t->i = x; }
+            return true;
+        }
+        case 0xd4: case 0xd5: case 0xd6: case 0xd7: case 0xd8:
+            t->type = MP_EXT; t->len = 1u << (c - 0xd4);
+            return take((size_t)t->len + 1, &t->s);
+        case 0xd9: case 0xda: case 0xdb:
+            if (!be((size_t)1 << (c - 0xd9), &v)) return false;
+            t->type = MP_STR; t->len = (uint32_t)v;
+            return take(t->len, &t->s);
+        case 0xdc: case 0xdd:
+            if (!be(c == 0xdc ? 2 : 4, &v)) return false;
+            t->type = MP_ARR; t->u = v; return true;
+        case 0xde: case 0xdf:
+            if (!be(c == 0xde ? 2 : 4, &v)) return false;
+            t->type = MP_MAP; t->u = v; return true;
+        }
+        return false;  // 0xc1: never used
+    }
+    // step over one whole value
+    bool skip(int depth) {
+        MpTok t;
+        if (!next(&t)) return false;
+        if (t.type != MP_ARR && t.type != MP_MAP) return true;
+        if (depth >= 32) return false;
+        uint64_t n = t.type == MP_MAP ? 2 * t.u : t.u;
+        for (uint64_t k = 0; k < n; ++k)
+            if (!skip(depth + 1)) return false;
+        return true;
+    }
+};
+
+// what ``_to_value`` looks at of a property's DataType
+enum { T_OTHER = 0, T_INT = 1, T_DATE = 2, T_UUID = 3, T_INT_ARRAY = 4,
+       T_DATE_ARRAY = 5, T_UUID_ARRAY = 6 };
+
+const int DECLINED = -1, MALFORMED = -2;
+const double TWO63 = 9223372036854775808.0;
+
+inline bool is_number(const MpTok& t) {
+    return t.type == MP_UINT || t.type == MP_NINT || t.type == MP_FLOAT
+        || t.type == MP_BOOL;
+}
+
+// int(x) of a number token, where it is an int64
+inline bool as_i64(const MpTok& t, int64_t* v) {
+    switch (t.type) {
+    case MP_BOOL: *v = (int64_t)t.u; return true;
+    case MP_UINT: if (t.u > (uint64_t)INT64_MAX) return false;
+        *v = (int64_t)t.u; return true;
+    case MP_NINT: *v = t.i; return true;
+    case MP_FLOAT: if (!(t.f > -TWO63 && t.f < TWO63)) return false;
+        *v = (int64_t)t.f; return true;   // toward zero, as int() does
+    }
+    return false;
+}
+
+inline double as_f64(const MpTok& t) {
+    return t.type == MP_FLOAT ? t.f : t.type == MP_NINT ? (double)t.i
+         : (double)t.u;
+}
+
+inline void put_le64(Out& o, const void* v) {
+    const uint8_t* b = (const uint8_t*)v;
+    o.insert(o.end(), b, b + 8);
+}
+
+// a list's elements -> ListValue, by the first rule of ``_to_value``
+// that holds for all of them
+int put_list(Out& o, MpReader& r, uint64_t n, int dtype) {
+    if ((uint64_t)(r.end - r.p) < n) return MALFORMED;  // a byte an element
+    MpReader first = r;
+    bool all_bool = true, all_int = true, all_num = true, all_str = true;
+    MpTok t;
+    for (uint64_t k = 0; k < n; ++k) {
+        if (!r.next(&t)) return MALFORMED;
+        if (t.type == MP_ARR || t.type == MP_MAP) return DECLINED;
+        all_bool &= t.type == MP_BOOL;
+        all_int &= t.type == MP_UINT || t.type == MP_NINT;
+        all_num &= is_number(t);
+        all_str &= t.type == MP_STR;
+    }
+    r = first;
+    size_t kind;
+    if (n == 0) {
+        pb_close(o, pb_open(o, 0x42));                    // text_values {}
+    } else if (all_bool) {
+        kind = pb_open(o, 0x1A);                          // bool_values
+        pb_varint(o, 0x0A);
+        pb_varint(o, n);
+        for (uint64_t k = 0; k < n; ++k) { r.next(&t); o.push_back((uint8_t)t.u); }
+        pb_close(o, kind);
+    } else if (dtype == T_INT_ARRAY || all_int) {
+        if (!all_num) return DECLINED;
+        kind = pb_open(o, 0x3A);                          // int_values
+        pb_varint(o, 0x0A);
+        pb_varint(o, 8 * n);
+        for (uint64_t k = 0; k < n; ++k) {
+            int64_t v;
+            r.next(&t);
+            if (!as_i64(t, &v)) return DECLINED;
+            put_le64(o, &v);
+        }
+        pb_close(o, kind);
+    } else if (all_num) {
+        kind = pb_open(o, 0x12);                          // number_values
+        pb_varint(o, 0x0A);
+        pb_varint(o, 8 * n);
+        for (uint64_t k = 0; k < n; ++k) {
+            r.next(&t);
+            double v = as_f64(t);
+            put_le64(o, &v);
+        }
+        pb_close(o, kind);
+    } else {
+        if (!all_str) return DECLINED;                    // str(e) of a non-str
+        kind = pb_open(o, dtype == T_DATE_ARRAY ? 0x2A    // date_values
+                        : dtype == T_UUID_ARRAY ? 0x32    // uuid_values
+                        : 0x42);                          // text_values
+        for (uint64_t k = 0; k < n; ++k) {
+            r.next(&t);
+            if (!utf8_ok(t.s, t.len)) return MALFORMED;
+            pb_bytes(o, 0x0A, t.s, t.len);
+        }
+        pb_close(o, kind);
+    }
+    return 0;
+}
+
+// one property value -> the body of a weaviate.v1.Value
+int put_value(Out& o, MpReader& r, int dtype) {
+    MpTok t;
+    if (!r.next(&t)) return MALFORMED;
+    switch (t.type) {
+    case MP_NIL:
+        o.push_back(0x60); o.push_back(0);                // null_value
+        return 0;
+    case MP_BOOL:
+        o.push_back(0x18); o.push_back((uint8_t)t.u);     // bool_value
+        return 0;
+    case MP_UINT: case MP_NINT: case MP_FLOAT:
+        if (dtype == T_INT) {
+            int64_t v;
+            if (!as_i64(t, &v)) return DECLINED;          // Python raises
+            o.push_back(0x40);                            // int_value
+            pb_varint(o, (uint64_t)v);
+        } else if (dtype == T_DATE) {
+            return DECLINED;                              // str(number)
+        } else {
+            double v = as_f64(t);
+            o.push_back(0x09);                            // number_value
+            put_le64(o, &v);
+        }
+        return 0;
+    case MP_STR:
+        if (!utf8_ok(t.s, t.len)) return MALFORMED;
+        pb_bytes(o, dtype == T_DATE ? 0x32 : dtype == T_UUID ? 0x3A : 0x6A,
+                 t.s, t.len);                             // date / uuid / text
+        return 0;
+    case MP_ARR: {
+        size_t lv = pb_open(o, 0x2A);                     // list_value
+        int rc = put_list(o, r, t.u, dtype);
+        if (rc) return rc;
+        pb_close(o, lv);
+        return 0;
+    }
+    }
+    return DECLINED;  // a map (geo, object), bin, ext
+}
+
+struct Name { const uint8_t* s; size_t len; };
+
+inline bool same(const Name& a, const uint8_t* s, size_t len) {
+    return a.len == len && memcmp(a.s, s, len) == 0;
+}
+
+// the request's and the class's part of a reply, unpacked from ``spec``
+// (native/__init__.py ``search_reply_spec`` packs it)
+struct ReplySpec {
+    uint32_t flags;
+    float took;
+    Name collection;
+    std::vector<Name> vectors;     // MetadataRequest.vectors
+    std::vector<Name> props;       // the class's properties ...
+    std::vector<uint8_t> types;    // ... and the T_* of each
+    std::vector<Name> wanted;      // the requested properties
+    bool all_props;
+
+    bool names(const uint8_t*& p, const uint8_t* end, std::vector<Name>* out,
+               std::vector<uint8_t>* kinds) {
+        if (end - p < 2) return false;
+        uint16_t n; memcpy(&n, p, 2); p += 2;
+        for (uint16_t k = 0; k < n; ++k) {
+            if (kinds) {
+                if (end - p < 1) return false;
+                kinds->push_back(*p++);
+            }
+            if (end - p < 2) return false;
+            uint16_t len; memcpy(&len, p, 2); p += 2;
+            if (end - p < len) return false;
+            out->push_back(Name{p, len});
+            p += len;
+        }
+        return true;
+    }
+    bool parse(const uint8_t* p, size_t n) {
+        const uint8_t* end = p + n;
+        if (n < 9) return false;
+        memcpy(&flags, p, 4); memcpy(&took, p + 4, 4);
+        all_props = p[8] != 0;
+        p += 9;
+        std::vector<Name> one;
+        if (!names(p, end, &one, nullptr) || one.size() != 1) return false;
+        collection = one[0];
+        return names(p, end, &vectors, nullptr)
+            && names(p, end, &props, &types)
+            && names(p, end, &wanted, nullptr) && p == end;
+    }
+};
+
+enum { F_META = 1, F_UUID = 2, F_VECTOR = 4, F_CREATED = 8, F_UPDATED = 16,
+       F_DISTANCE = 32, F_CERTAINTY = 64, F_SCORE = 128 };
+
+int put_result(Out& o, const ReplySpec& sp, const uint8_t* f, size_t flen,
+               bool has_d, double d, bool has_s, double s) {
+    // the frame (storage/objects.py): u8 version | u64 doc id | u64 created
+    // | u64 updated | 16 B uuid | u32 n | n x (u16 len, name, u32 dim,
+    // dim x f32) | u32 props_len | msgpack(properties)
+    if (flen < 45 || f[0] != 1) return MALFORMED;
+    uint64_t created, updated;
+    memcpy(&created, f + 9, 8);
+    memcpy(&updated, f + 17, 8);
+    const uint8_t* uid = f + 25;
+    uint32_t n_vecs;
+    memcpy(&n_vecs, f + 41, 4);
+    size_t off = 45;
+    const uint8_t* dflt = nullptr;  // the unnamed vector's floats
+    size_t dflt_len = 0;
+    std::vector<std::pair<const uint8_t*, size_t>> named(sp.vectors.size(),
+                                                         {nullptr, 0});
+    for (uint32_t v = 0; v < n_vecs; ++v) {
+        if (flen - off < 2) return MALFORMED;
+        uint16_t nlen; memcpy(&nlen, f + off, 2); off += 2;
+        if (flen - off < nlen) return MALFORMED;
+        const uint8_t* name = f + off; off += nlen;
+        if (!utf8_ok(name, nlen)) return MALFORMED;
+        if (flen - off < 4) return MALFORMED;
+        uint32_t dim; memcpy(&dim, f + off, 4); off += 4;
+        if ((uint64_t)(flen - off) < 4 * (uint64_t)dim) return MALFORMED;
+        if (nlen == 0) { dflt = f + off; dflt_len = 4 * (size_t)dim; }
+        for (size_t w = 0; w < sp.vectors.size(); ++w)
+            if (same(sp.vectors[w], name, nlen))
+                named[w] = {f + off, 4 * (size_t)dim};
+        off += 4 * (size_t)dim;
+    }
+    if (flen - off < 4) return MALFORMED;
+    uint32_t plen; memcpy(&plen, f + off, 4); off += 4;
+    if (flen - off < plen) return MALFORMED;
+    MpReader r{f + off, f + off + plen};
+
+    size_t result = pb_open(o, 0x12);                     // SearchReply.results
+    size_t props = pb_open(o, 0x0A);                      // .properties
+    MpTok t;
+    if (!r.next(&t)) return MALFORMED;
+    if (t.type != MP_MAP) return DECLINED;
+    size_t fields = 0;                                    // .non_ref_props
+    size_t hint = 0;  // stored keys mostly follow the class's order
+    for (uint64_t k = 0; k < t.u; ++k) {
+        MpTok key;
+        if (!r.next(&key)) return MALFORMED;
+        if (key.type != MP_STR) return DECLINED;
+        bool want = sp.all_props;
+        for (size_t w = 0; !want && w < sp.wanted.size(); ++w)
+            want = same(sp.wanted[w], key.s, key.len);
+        if (!want) {
+            if (!r.skip(0)) return MALFORMED;
+            continue;
+        }
+        if (!utf8_ok(key.s, key.len)) return MALFORMED;
+        int dtype = T_OTHER;
+        for (size_t w = 0, np = sp.props.size(); w < np; ++w) {
+            size_t at = hint + w < np ? hint + w : hint + w - np;
+            if (same(sp.props[at], key.s, key.len)) {
+                dtype = sp.types[at];
+                hint = at + 1 < np ? at + 1 : 0;
+                break;
+            }
+        }
+        if (!fields) fields = pb_open(o, 0x5A);
+        size_t entry = pb_open(o, 0x0A);                  // fields entry
+        pb_bytes(o, 0x0A, key.s, key.len);
+        size_t value = pb_open(o, 0x12);
+        int rc = put_value(o, r, dtype);
+        if (rc) return rc;
+        pb_close(o, value);
+        pb_close(o, entry);
+    }
+    if (r.p != r.end) return MALFORMED;                   // msgpack: ExtraData
+    if (fields) pb_close(o, fields);
+    pb_bytes(o, 0x1A, sp.collection.s, sp.collection.len);  // target_collection
+    pb_close(o, props);
+
+    const uint32_t fl = sp.flags;
+    bool touched = false;  // an assignment makes the message present
+    size_t md = pb_open(o, 0x12);                         // .metadata
+    if (!(fl & F_META) || (fl & F_UUID)) {
+        static const char hex[] = "0123456789abcdef";
+        uint8_t id[36];
+        for (int b = 0, c = 0; b < 16; ++b) {
+            if (b == 4 || b == 6 || b == 8 || b == 10) id[c++] = '-';
+            id[c++] = (uint8_t)hex[uid[b] >> 4];
+            id[c++] = (uint8_t)hex[uid[b] & 15];
+        }
+        pb_bytes(o, 0x0A, id, 36);
+        touched = true;
+    }
+    if (fl & F_META) {
+        if ((fl & F_VECTOR) && dflt) {
+            if (dflt_len) pb_bytes(o, 154, dflt, dflt_len);  // vector_bytes
+            touched = true;
+        }
+        for (size_t w = 0; w < named.size(); ++w) {
+            if (!named[w].first) continue;
+            size_t v = pb_open(o, 186);                   // vectors
+            if (sp.vectors[w].len)
+                pb_bytes(o, 0x0A, sp.vectors[w].s, sp.vectors[w].len);
+            if (named[w].second)
+                pb_bytes(o, 0x1A, named[w].first, named[w].second);
+            pb_close(o, v);
+            touched = true;
+        }
+        if (fl & F_CREATED) {
+            if (created > (uint64_t)INT64_MAX) return DECLINED;
+            if (created) { o.push_back(0x18); pb_varint(o, created); }
+            o.push_back(0x20); o.push_back(1);
+            touched = true;
+        }
+        if (fl & F_UPDATED) {
+            if (updated > (uint64_t)INT64_MAX) return DECLINED;
+            if (updated) { o.push_back(0x28); pb_varint(o, updated); }
+            o.push_back(0x30); o.push_back(1);
+            touched = true;
+        }
+        if (has_d && (fl & F_DISTANCE)) {
+            float v = (float)d; uint32_t bits; memcpy(&bits, &v, 4);
+            if (bits) pb_f32(o, 0x3D, v);
+            o.push_back(0x40); o.push_back(1);
+            touched = true;
+        }
+        if (has_d && (fl & F_CERTAINTY)) {
+            double c = 1.0 - d / 2.0;
+            float v = (float)(c > 0.0 ? c : 0.0);         // max(0.0, c)
+            uint32_t bits; memcpy(&bits, &v, 4);
+            if (bits) pb_f32(o, 0x4D, v);
+            o.push_back(0x50); o.push_back(1);
+            touched = true;
+        }
+        if (has_s && (fl & F_SCORE)) {
+            float v = (float)s; uint32_t bits; memcpy(&bits, &v, 4);
+            if (bits) pb_f32(o, 0x5D, v);
+            o.push_back(0x60); o.push_back(1);
+            touched = true;
+        }
+    }
+    if (touched) pb_close(o, md);
+    else o.resize(md - 2);                                // tag + length byte
+    pb_close(o, result);
+    return 0;
+}
+
+}  // namespace
+
+extern "C" {
+
+// frames[i] (frame_lens[i] bytes) is result i's stored object; distances /
+// scores are doubles with a presence byte each (either pair may be null:
+// no result has one). -> the reply's length, with *out pointing at bytes
+// that stay valid until this THREAD's next call; DECLINED or MALFORMED
+// (above) where the Python path has to answer.
+int64_t wn_search_reply_encode(
+        const uint8_t* const* frames, const int64_t* frame_lens, int64_t n,
+        const double* distances, const uint8_t* has_distance,
+        const double* scores, const uint8_t* has_score,
+        const uint8_t* spec, int64_t spec_len, const uint8_t** out) {
+    static thread_local Out buf;
+    ReplySpec sp;
+    if (!sp.parse(spec, (size_t)spec_len)) return MALFORMED;
+    buf.clear();
+    uint32_t bits; memcpy(&bits, &sp.took, 4);
+    if (bits) pb_f32(buf, 0x0D, sp.took);
+    for (int64_t i = 0; i < n; ++i) {
+        int rc = put_result(
+            buf, sp, frames[i], (size_t)frame_lens[i],
+            has_distance && has_distance[i], distances ? distances[i] : 0.0,
+            has_score && has_score[i], scores ? scores[i] : 0.0);
+        if (rc) return rc;
+    }
+    *out = buf.data();
+    return (int64_t)buf.size();
+}
+
+}  // extern "C"

@@ -384,7 +384,7 @@ class NativeDataPlane:
             self.dp.post_raw(item.token, b"", 12,
                              f"unknown method {item.method}")
             return
-        from weaviate_tpu.api.grpc.server import ApiError
+        from weaviate_tpu.api.grpc.server import ApiError, reply_bytes
 
         req_type = _REQ_TYPES[method]
         ctx = _Ctx()
@@ -396,7 +396,7 @@ class NativeDataPlane:
                 req = req_type.FromString(item.payload)
                 reply = handler(req, ctx)
                 tailboard.complete(200)
-                self.dp.post_raw(item.token, reply.SerializeToString())
+                self.dp.post_raw(item.token, reply_bytes(reply))
                 # a Search that fell back on an unregistered collection
                 # registers it so the NEXT plain query takes the fast path
                 if method == "Search" and req.collection:

@@ -20,8 +20,10 @@ import grpc
 import numpy as np
 from google.protobuf import json_format
 
+from weaviate_tpu import native
 from weaviate_tpu.api.grpc import v1_pb2 as pb
 from weaviate_tpu.filters.filters import Filter, Operator
+from weaviate_tpu.runtime import metrics
 from weaviate_tpu.schema.config import DataType
 
 logger = logging.getLogger(__name__)
@@ -297,6 +299,92 @@ def _f32_bytes(vec) -> bytes:
     return np.asarray(vec, dtype="<f4").tobytes()
 
 
+#: the property types the native reply encoder writes, as the kinds
+#: ``_to_value`` tells apart. A class with any other type (geoCoordinates,
+#: blob, object, cref, one this table has not met) among the properties a
+#: request could return is answered by ``_fill_result``, whole.
+_REPLY_KINDS = {
+    DataType.TEXT: native.REPLY_OTHER,
+    DataType.TEXT_ARRAY: native.REPLY_OTHER,
+    DataType.NUMBER: native.REPLY_OTHER,
+    DataType.NUMBER_ARRAY: native.REPLY_OTHER,
+    DataType.BOOL: native.REPLY_OTHER,
+    DataType.BOOL_ARRAY: native.REPLY_OTHER,
+    DataType.INT: native.REPLY_INT,
+    DataType.INT_ARRAY: native.REPLY_INT_ARRAY,
+    DataType.DATE: native.REPLY_DATE,
+    DataType.DATE_ARRAY: native.REPLY_DATE_ARRAY,
+    DataType.UUID: native.REPLY_UUID,
+    DataType.UUID_ARRAY: native.REPLY_UUID_ARRAY,
+}
+
+_REPLY_META_FLAGS = (
+    ("uuid", native.REPLY_ID), ("vector", native.REPLY_VECTOR),
+    ("creation_time_unix", native.REPLY_CREATED),
+    ("last_update_time_unix", native.REPLY_UPDATED),
+    ("distance", native.REPLY_DISTANCE),
+    ("certainty", native.REPLY_CERTAINTY), ("score", native.REPLY_SCORE))
+
+_REPLY_ENCODED = {
+    key: metrics.grpc_reply_encode_total.labels(*key)
+    for key in (("native", ""), ("python", "no_native"),
+                ("python", "request"), ("python", "schema"),
+                ("python", "value"))}
+
+
+def reply_bytes(reply) -> bytes:
+    """A handler's reply on the wire: a Search's may come encoded
+    already (``_native_reply``), every other is a message."""
+    return reply if type(reply) is bytes else reply.SerializeToString()
+
+
+def _native_reply(col, results, meta_req, props_req,
+                  start: float) -> tuple[bytes | None, str]:
+    """A plain Search's reply from the stored frames of its results, in
+    ONE native call (``native.search_reply_encode``): -> (the bytes of
+    the ``pb.SearchReply`` that ``_fill_result`` would have built a
+    result, ""), or (None, why ``_fill_result`` has to build it). What
+    decides is the class and the frames, never a knob; a result whose
+    object has gone since the search is left out, as there."""
+    if not native.available():
+        return None, "no_native"
+    wanted = None
+    if props_req is not None and not props_req.return_all_nonref_properties:
+        wanted = set(props_req.non_ref_properties) or None
+    props = []
+    for p in col.config.properties:
+        if wanted is None or p.name in wanted:
+            kind = _REPLY_KINDS.get(p.data_type)
+            if kind is None:
+                return None, "schema"
+            props.append((p.name, kind))
+    frames, live = [], []
+    for r in results:
+        if r.frame is not None:
+            frames.append(r.frame)
+            live.append(r)
+        elif r.attached:
+            return None, "value"  # an object someone set: no frame to read
+    flags, vectors, distances, scores = 0, (), None, None
+    if meta_req is not None:
+        flags = native.REPLY_META
+        for field, bit in _REPLY_META_FLAGS:
+            if getattr(meta_req, field):
+                flags |= bit
+        vectors = list(meta_req.vectors)
+        if flags & (native.REPLY_DISTANCE | native.REPLY_CERTAINTY):
+            distances = [r.distance for r in live]
+        if flags & native.REPLY_SCORE:
+            scores = [r.score for r in live]
+    raw = native.search_reply_encode(
+        frames, native.search_reply_spec(
+            col.config.name, flags, vectors, props,
+            None if wanted is None else sorted(wanted),
+            time.perf_counter() - start),
+        distances, scores)
+    return raw, "" if raw is not None else "value"
+
+
 class GrpcServer:
     """``db``: node-local Database (or anything exposing get_collection).
     ``modules``: optional module Provider for nearText / generative /
@@ -335,7 +423,7 @@ class GrpcServer:
                 # stages from the wire to the reply
                 self._wrap(fn, verbs[name], name, staged=name == "Search"),
                 request_deserializer=req_types[name].FromString,
-                response_serializer=lambda resp: resp.SerializeToString(),
+                response_serializer=reply_bytes,
             )
         # the interceptor costs nothing the benchmark can see (cell 1
         # with and without it, PERF.md PR 25), so it carries no switch
@@ -557,7 +645,7 @@ class GrpcServer:
         tenant = req.tenant or None
         # identity for the always-on phase histograms (tailboard top-K
         # guard clamps the label values)
-        from weaviate_tpu.runtime import tailboard
+        from weaviate_tpu.runtime import tailboard, tracing
 
         tailboard.annotate(collection=req.collection, tenant=tenant)
         limit = req.limit or 10
@@ -662,7 +750,6 @@ class GrpcServer:
         if results is not None:
             results = results[:limit]
 
-        reply = pb.SearchReply()
         meta_req = req.metadata if req.HasField("metadata") else None
         props_req = req.properties if req.HasField("properties") else None
         # pre-1.23 clients set neither api flag and read the deprecated
@@ -675,8 +762,25 @@ class GrpcServer:
         if results is not None and rerank is not None:
             results = self._rerank(col, results, rerank)
 
+        # ONE encoder answers a request: the native one a plain Search (a
+        # search, a modern client, nothing built over the objects), from
+        # the frames as stored; ``_fill_result`` every other, and any
+        # request the native one declines
+        group_by = req.HasField("group_by")
+        if results is None or group_by or legacy_props \
+                or generative is not None or rerank is not None:
+            raw, why = None, "request"
+        else:
+            raw, why = _native_reply(col, results, meta_req, props_req, start)
+        path = "python" if raw is None else "native"
+        _REPLY_ENCODED[path, why].inc()
+        tracing.annotate(reply_path=path)
+        if raw is not None:
+            return raw
+
+        reply = pb.SearchReply()
         dtype_of = {p.name: p.data_type for p in col.config.properties}
-        if results is not None and req.HasField("group_by"):
+        if results is not None and group_by:
             self._group_results(col, reply, results, req.group_by,
                                 meta_req, props_req, dtype_of)
         elif results is not None:

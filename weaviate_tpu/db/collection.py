@@ -33,27 +33,51 @@ logger = logging.getLogger(__name__)
 
 
 class SearchResult:
-    __slots__ = ("uuid", "distance", "score", "object", "shard",
+    """One hit. ``frame`` is its object as stored (``StorageObject.
+    to_bytes``), read AFTER the search; ``object`` decodes it at the
+    first read by whoever reads one (rerank, group-by, REST / GraphQL,
+    the gRPC reply's Python path), once. A plain gRPC Search's reply is
+    encoded from the frames and decodes none (api/grpc/server.py)."""
+
+    __slots__ = ("uuid", "distance", "score", "frame", "_object", "shard",
                  "rerank_score")
 
-    def __init__(self, uuid, distance=None, score=None, object=None, shard=None):
+    def __init__(self, uuid, distance=None, score=None, object=None,
+                 shard=None, frame=None):
         self.uuid = uuid
         self.distance = distance
         self.score = score
-        self.object = object
+        self._object = object
+        self.frame = frame
         self.shard = shard
         self.rerank_score = None  # set by the reranker module path
+
+    @property
+    def object(self) -> StorageObject | None:
+        obj = self._object
+        if obj is None and self.frame is not None:
+            # two threads may both decode: the objects are equal, one stays
+            obj = self._object = StorageObject.from_bytes(self.frame)
+        return obj
+
+    @object.setter
+    def object(self, obj: StorageObject | None) -> None:
+        self._object, self.frame = obj, None
+
+    @property
+    def attached(self) -> bool:
+        """Whether the object was read already, decoded or not."""
+        return self.frame is not None or self._object is not None
 
     def __repr__(self):
         return f"SearchResult({self.uuid}, dist={self.distance}, score={self.score})"
 
 
 def _remote_result(item: dict, shard_name: str) -> "SearchResult":
-    raw = item.get("object")
     return SearchResult(
         uuid=item["uuid"], distance=item.get("distance"),
         score=item.get("score"), shard=shard_name,
-        object=StorageObject.from_bytes(raw) if raw else None)
+        frame=item.get("object") or None)
 
 
 def _timed(query_type: str):
@@ -921,7 +945,7 @@ class Collection:
         whose object has gone since the search keeps ``object = None``."""
         missing: dict[str, list[SearchResult]] = {}
         for r in results:
-            if r.object is None:
+            if not r.attached:
                 missing.setdefault(r.shard, []).append(r)
         if not missing:
             return
@@ -932,10 +956,10 @@ class Collection:
             for name, rs in missing.items():
                 if self._is_local(name):
                     reads += 1
-                    objs = self._load_shard(name).get_objects(
+                    raws = self._load_shard(name).get_frames(
                         [r.uuid for r in rs], routes)
-                    for r, obj in zip(rs, objs):
-                        r.object = obj
+                    for r, raw in zip(rs, raws):
+                        r.frame = raw
                 else:
                     from weaviate_tpu.cluster.transport import RpcError
 
@@ -952,8 +976,7 @@ class Collection:
                                        shard=name, detail=str(e))
                         continue
                     for r, raw in zip(rs, raws):
-                        r.object = StorageObject.from_bytes(raw) \
-                            if raw else None
+                        r.frame = raw or None
             sp.set(reads=reads, array_keys=routes.get("array", 0),
                    scalar_keys=routes.get("scalar", 0))
 
@@ -1718,7 +1741,7 @@ class Collection:
         # leg results (see text/hybrid.py); materialize fresh results so
         # concurrent queries sharing leg objects never race on .score
         fused = [SearchResult(uuid=r.uuid, distance=r.distance, score=s,
-                              object=r.object, shard=r.shard)
+                              object=r._object, shard=r.shard, frame=r.frame)
                  for s, r in fuse(legs, weights, k)]
         if autocut > 0 and fused:
             from weaviate_tpu.query.autocut import autocut_results

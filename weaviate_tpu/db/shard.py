@@ -866,15 +866,20 @@ class Shard:
             return None
         return StorageObject.from_bytes(raw)
 
+    def get_frames(self, uuids: list[str],
+                   routes: dict | None = None) -> list[bytes | None]:
+        """The stored frame (``StorageObject.to_bytes``) of every uuid,
+        None for one that is gone, in ONE ``kv.get_many``: a Search's
+        reply reads its objects here, one lock acquisition and one
+        search a segment a request, not a result, and decodes none
+        (``routes``: ``Bucket.get_many``'s tally)."""
+        return self.objects.get_many([u.encode() for u in uuids], routes)
+
     def get_objects(self, uuids: list[str],
                     routes: dict | None = None) -> list[StorageObject | None]:
-        """``[get_object(u) for u in uuids]`` in ONE ``kv.get_many``: a
-        Search's reply reads its objects here, one lock acquisition and
-        one search a segment a request, not a result (``routes``:
-        ``Bucket.get_many``'s tally)."""
-        raws = self.objects.get_many([u.encode() for u in uuids], routes)
+        """``[get_object(u) for u in uuids]`` over one :meth:`get_frames`."""
         return [None if raw is None else StorageObject.from_bytes(raw)
-                for raw in raws]
+                for raw in self.get_frames(uuids, routes)]
 
     def exists(self, uuid: str) -> bool:
         return self.docid.get(uuid.encode()) is not None

@@ -2,13 +2,20 @@
 
 The reference keeps its runtime in Go with hand-written SIMD only for
 distances; our TPU compute path is JAX/Pallas, and the host-side hot loops
-— doc-id set algebra, posting-block codecs, cross-shard merge — live in
-C++ (csrc/weaviate_native.cpp). Loading strategy:
+— doc-id set algebra, posting-block codecs, cross-shard merge, the storage
+object's frame encoder on the import path and, on the read path, the
+encoder of a plain gRPC Search's reply from the stored frames of its
+results (``search_reply_encode``) — live in C++
+(csrc/weaviate_native.cpp). Loading strategy:
 
 1. use ``libweaviate_native.so`` next to this file if present,
-2. else try to build it with g++ (one-time, ~1s, cached on disk),
-3. else fall back to the numpy implementations below (same semantics,
-   used on machines without a toolchain and as the conformance oracle).
+2. else try to build it with g++ (one-time, ~1s, cached on disk; nothing
+   is installed),
+3. else fall back to the numpy / Python implementations (same semantics,
+   used on machines without a toolchain and as the conformance oracle:
+   the ones below, and for the reply encoder ``_fill_result`` of
+   api/grpc/server.py, which answers whatever request the encoder
+   declines as well).
 
 ``available()`` reports which path is active; set ``WEAVIATE_TPU_NO_NATIVE=1``
 to force the numpy fallbacks (used by tests to cross-check both paths).
@@ -18,6 +25,7 @@ from __future__ import annotations
 
 import ctypes
 import os
+import struct
 import subprocess
 import threading
 
@@ -140,6 +148,7 @@ def _load():
             fn = getattr(lib, name)
             fn.argtypes = args
             fn.restype = res
+        _bind_reply_encoder()
         _lib = lib
         return _lib
 
@@ -367,6 +376,107 @@ def storobj_encode_batch(uuid_strs: list[bytes], props_blobs: list[bytes],
     # materializes just that frame — no whole-buffer duplicate)
     return [out[frame_offs[i]:frame_offs[i + 1]].tobytes()
             for i in range(n)]
+
+
+# ---- Search reply encoder -------------------------------------------------
+
+#: what a property's DataType is to the encoder (csrc ``T_*``): the kinds
+#: ``api/grpc/server.py::_to_value`` tells apart; everything else it
+#: writes (text, number, boolean and their arrays) is REPLY_OTHER
+(REPLY_OTHER, REPLY_INT, REPLY_DATE, REPLY_UUID, REPLY_INT_ARRAY,
+ REPLY_DATE_ARRAY, REPLY_UUID_ARRAY) = range(7)
+#: the MetadataRequest as ``flags`` (csrc ``F_*``); REPLY_META: the
+#: request carries one at all (without it a result has its id alone)
+(REPLY_META, REPLY_ID, REPLY_VECTOR, REPLY_CREATED, REPLY_UPDATED,
+ REPLY_DISTANCE, REPLY_CERTAINTY, REPLY_SCORE) = (1 << b for b in range(8))
+
+_SPEC_HEAD = struct.Struct("<IfB")
+_U16 = struct.Struct("<H")
+_reply_encode = None
+
+
+def _bind_reply_encoder() -> None:
+    """``wn_search_reply_encode`` through a SECOND handle on the library
+    that keeps the interpreter lock across the call (``PyDLL``): the call
+    is tens of microseconds on a request thread, and a thread that lets
+    the lock go under 32 others waits a switch interval or more to have
+    it back (a ctypes merge cost a fan-out 20 ms a request that way on
+    the chip's host: PERF.md section 6, PR 34)."""
+    global _reply_encode
+    fn = ctypes.PyDLL(_SO).wn_search_reply_encode
+    # the int64 and double arrays arrive struct-packed, as bytes: a
+    # tenth of what a ctypes array costs to fill
+    fn.argtypes = [ctypes.POINTER(ctypes.c_char_p), ctypes.c_char_p,
+                   ctypes.c_int64, ctypes.c_char_p, ctypes.c_char_p,
+                   ctypes.c_char_p, ctypes.c_char_p,
+                   ctypes.c_char_p, ctypes.c_int64,
+                   ctypes.POINTER(ctypes.c_void_p)]
+    fn.restype = ctypes.c_int64
+    _reply_encode = fn
+
+
+def _packed_names(names, kinds=None) -> bytes:
+    parts = [_U16.pack(len(names))]
+    for i, name in enumerate(names):
+        raw = name.encode("utf-8")
+        if kinds is not None:
+            parts.append(bytes((kinds[i],)))
+        parts += (_U16.pack(len(raw)), raw)
+    return b"".join(parts)
+
+
+def search_reply_spec(collection: str, flags: int, vectors, props,
+                      wanted, took: float) -> bytes:
+    """The request's and the class's part of a reply, as
+    :func:`search_reply_encode` takes it: ``flags`` the REPLY_* bits of
+    the MetadataRequest, ``vectors`` its named vectors, ``props`` the
+    class's ``(name, REPLY_* kind)`` pairs, ``wanted`` the requested
+    property names (None: all), ``took`` the reply's."""
+    return b"".join((
+        _SPEC_HEAD.pack(flags, took, wanted is None),
+        _packed_names((collection,)), _packed_names(vectors),
+        _packed_names([p[0] for p in props], [p[1] for p in props]),
+        _packed_names(wanted or ())))
+
+
+def _optional_doubles(values, n: int):
+    """-> (double[n] packed, presence bytes) of a list of float-or-None."""
+    if values is None:
+        return None, None
+    if None in values:
+        return (struct.pack(f"<{n}d", *[v or 0.0 for v in values]),
+                bytes([v is not None for v in values]))
+    return struct.pack(f"<{n}d", *values), b"\x01" * n
+
+
+def search_reply_encode(frames: list[bytes], spec: bytes,
+                        distances=None, scores=None) -> bytes | None:
+    """The bytes of a ``weaviate.v1.SearchReply`` over the stored
+    ``frames`` (``StorageObject.to_bytes``) of a Search's results, in ONE
+    native call: what ``_fill_result`` builds a result, field for field
+    (its fallback, and the oracle of tests/test_reply_encoder.py), with
+    no object, dict or message a result. ``distances`` / ``scores``: a
+    float or None a frame. None where the library is absent, where a
+    frame holds a value the encoder does not write (a map, a bin, a
+    number under a date) and where a frame cannot be walked: the caller
+    answers the WHOLE request by the Python path, which answers or
+    raises as it always did."""
+    if _load() is None:
+        return None
+    n = len(frames)
+    dists, has_dist = _optional_doubles(distances, n)
+    scrs, has_score = _optional_doubles(scores, n)
+    out = ctypes.c_void_p()
+    try:
+        pointers = (ctypes.c_char_p * n)(*frames)
+    except TypeError:  # a frame that is not ``bytes``: not ours to read
+        return None
+    size = _reply_encode(
+        pointers, struct.pack(f"<{n}q", *map(len, frames)), n,
+        dists, has_dist, scrs, has_score, spec, len(spec),
+        ctypes.byref(out))
+    # the bytes are the calling thread's until its next call
+    return None if size < 0 else ctypes.string_at(out, size)
 
 
 # ---- batch text analyzer --------------------------------------------------

@@ -749,6 +749,20 @@ fanout_route_total = registry.counter(
     "list; a shard whose searches ride no batcher; a drain retired "
     "under the request)",
     ("collection", "route"))
+grpc_reply_encode_total = registry.counter(
+    "weaviate_tpu_grpc_reply_encode_total",
+    "gRPC Search replies by the encoder that built them, one inc a "
+    "reply: native = the stored frames of the results to the reply's "
+    "bytes in one call of the native library (no object, dict or "
+    "message a result), python = a StorageObject and a protobuf "
+    "message a result (api/grpc/server.py _fill_result). reason says "
+    "why python answered: no_native (the library did not build or "
+    "WEAVIATE_TPU_NO_NATIVE), request (group_by, rerank, generative, a "
+    "pre-1.23 client, a fetch without a search), schema (a property "
+    "the request could return is a geoCoordinates, blob, object or "
+    "cref), value (a stored frame holds a value the encoder does not "
+    "write, or cannot be walked); empty for native",
+    ("path", "reason"))
 fanout_width = registry.histogram(
     "weaviate_tpu_fanout_width",
     "Local shards searched by one fanned-out request", (),
